@@ -18,7 +18,6 @@ _LOCK = threading.Lock()
 _LIBS = {
     # lib name -> source files
     "shm_store": ["shm_store.cc"],
-    "scheduler": ["scheduler.cc"],
 }
 
 

@@ -384,17 +384,6 @@ class NodeAgent:
         env = dict(os.environ)
         env["RAY_TPU_NODE_ID"] = self.node_id
         env["RAY_TPU_WORKER_ID"] = worker_id
-        # Lazy heavy imports in workers (reference: Ray workers import
-        # `ray` only; torch/tf load when a task first uses them). Site
-        # hooks that pre-import jax at interpreter startup (e.g. a TPU
-        # plugin's sitecustomize) cost seconds per fork and serialize
-        # actor creation; strip matching PYTHONPATH entries so workers
-        # start in ~0.3s and tasks that use jax pay its import lazily.
-        strip = config.worker_pythonpath_exclude
-        if strip and env.get("PYTHONPATH"):
-            keep = [p for p in env["PYTHONPATH"].split(os.pathsep)
-                    if not any(s and s in p for s in strip.split(","))]
-            env["PYTHONPATH"] = os.pathsep.join(keep)
         # The framework must be importable by `-m ray_tpu...` no matter
         # where the DRIVER ran from (it may have put ray_tpu on sys.path
         # itself): pin our own package root onto the worker's path.

@@ -275,11 +275,13 @@ class WorkerHandler:
                     continue
             idle_rounds = 0
             # Device telemetry rides the same batch, throttled to ~1/s;
-            # None until something in this process imports jax (the
-            # snapshot itself never triggers the import).
+            # None until this worker's own code has initialised a JAX
+            # backend (the snapshot is never the first touch: that would
+            # take the chip, or pre-empt jax.distributed.initialize).
             device = None
             now = time.monotonic()
-            if device_telemetry.jax_loaded() and now - last_dev_ship >= 1.0:
+            if device_telemetry.backend_initialized() \
+                    and now - last_dev_ship >= 1.0:
                 try:
                     device = device_telemetry.snapshot()
                     last_dev_ship = now

@@ -799,8 +799,9 @@ class LocalBackend:
         return []
 
     def device_stats(self, fresh: bool = False) -> list[dict]:
-        """This process's JAX/XLA device view (a stub until something
-        imports jax — the snapshot never triggers the import itself)."""
+        """This process's JAX/XLA device view (a stub until the process
+        has initialised a JAX backend — the snapshot is never the first
+        touch)."""
         from ray_tpu.util import device_telemetry
 
         snap = device_telemetry.snapshot()

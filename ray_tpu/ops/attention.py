@@ -17,6 +17,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.flash_attention import flash_block, flash_causal_attention
 
 # Sequence length at/above which the flash kernel pays for itself.
 # Measured on v5e (GPT-2 small, batch 16): at seq 1024 the Pallas kernel
@@ -53,24 +56,45 @@ def causal_attention(
     softmax_scale: float | None = None,
     use_flash: bool | None = None,
 ) -> jax.Array:
-    """[B, T, H, D] causal attention with automatic kernel selection."""
+    """[B, T, H, D] causal attention with automatic kernel selection.
+
+    ``use_flash=None`` picks the Pallas kernel from what can be observed
+    before calling it: an accelerator backend, a sequence long enough to
+    pay for it, and a length the kernel can tile. ``use_flash=True``
+    insists on it (and raises on a length it cannot tile)."""
     t = q.shape[1]
-    explicit = use_flash is True
     if use_flash is None:
         use_flash = (
             t >= _FLASH_MIN_SEQ
-            and jax.default_backend() not in ("cpu",)
+            and jax.default_backend() != "cpu"
+            and flash_block(1024, t) is not None
         )
     if use_flash:
-        try:
-            from ray_tpu.ops.flash_attention import flash_causal_attention
-
-            return flash_causal_attention(q, k, v, softmax_scale=softmax_scale)
-        except (ImportError, NotImplementedError):
-            if explicit:
-                # The caller asked for flash by name; do not silently degrade.
-                raise
+        return _mesh_flash_attention(q, k, v, softmax_scale)
     return xla_causal_attention(q, k, v, softmax_scale=softmax_scale)
+
+
+def _mesh_flash_attention(q, k, v, softmax_scale):
+    """The flash kernel under the mesh the computation is traced in.
+
+    A Mosaic kernel cannot be partitioned by GSPMD ("wrap the call in a
+    shard_map"), so on a mesh of several devices the call is mapped by
+    hand: batch over the data axes, heads over ``tp`` — the layout the
+    activations already have — and each device runs the kernel on its
+    own block. Attention mixes nothing across batch or heads, so no
+    collective is needed inside."""
+    flash = functools.partial(flash_causal_attention,
+                              softmax_scale=softmax_scale)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return flash(q, k, v)
+    batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+    spec = P(batch_axes or None, None,
+             "tp" if "tp" in mesh.axis_names else None, None)
+    return jax.shard_map(
+        flash, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 # -- KV-cache writes (serving decode path) ----------------------------------

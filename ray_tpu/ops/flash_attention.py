@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu._compat import pallas_tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -167,7 +166,7 @@ def _flash_fwd(q, k, v, *, block_q: int, block_k: int, softmax_scale: float,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -370,7 +369,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, block_q: int, block_k: int,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -399,7 +398,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, block_q: int, block_k: int,
                 (None, block_q, d), lambda bhi, a, b_: (bhi, a, 0)),
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            compiler_params=pallas_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -464,16 +463,22 @@ def flash_causal_attention(
     )
 
 
+def flash_block(requested: int, t: int) -> int | None:
+    """Largest divisor of t that is <= requested and a multiple of 8, the
+    TPU sublane tile (so T=1536 -> 768 with the 1024 default). None when
+    t has no such divisor (primes etc.): the kernel cannot tile it, and
+    `causal_attention`'s auto path picks XLA attention from this answer
+    before calling anything."""
+    for block in range(min(requested, t) // 8 * 8, 7, -8):
+        if t % block == 0:
+            return block
+    return None
+
+
 def _fit_block(requested: int, t: int) -> int:
-    """Largest divisor of t that is <= requested (so any T works, e.g.
-    T=1536 -> 768 with the 1024 default). Degenerate T whose largest
-    usable divisor is < 8 (primes etc.) can't tile the TPU lane layout —
-    raise so `causal_attention`'s auto path falls back to XLA attention."""
-    block = min(requested, t)
-    while block > 1 and t % block:
-        block -= 1
-    if block < 8:
-        raise NotImplementedError(
-            f"seq len {t} has no block divisor >= 8 (<= {requested})"
-        )
+    block = flash_block(requested, t)
+    if block is None:
+        raise ValueError(
+            f"flash attention cannot tile seq len {t}: no multiple-of-8 "
+            f"divisor <= {requested}")
     return block

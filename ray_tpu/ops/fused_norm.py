@@ -43,8 +43,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ray_tpu._compat import pallas_tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 LN_EPS = 1e-5    # matches models/gpt2.py _layer_norm
 RMS_EPS = 1e-6   # matches models/llama.py _rms_norm
@@ -151,7 +150,7 @@ def _norm_fwd(x2d, scale, bias, *, block: int, eps: float, rms: bool,
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     stat_spec = pl.BlockSpec((block, 1), lambda i: (i, 0))
     stat_shape = jax.ShapeDtypeStruct((r, 1), jnp.float32)
-    params = pallas_tpu_compiler_params(dimension_semantics=("parallel",))
+    params = pltpu.CompilerParams(dimension_semantics=("parallel",))
     if rms:
         KERNEL_INVOCATIONS["rms_fwd"] += 1
         y, rstd = pl.pallas_call(
@@ -202,18 +201,32 @@ def _norm_bwd_kernel(x_ref, mu_ref, rstd_ref, scale_ref, dy_ref, dres_ref,
     if dres_ref is not None:
         dx = dx + dres_ref[:].astype(jnp.float32)
     dx_ref[:] = dx.astype(dx_ref.dtype)
-    dscale_ref[:] = jnp.sum(dy32 * xhat, axis=0, keepdims=True)
+    dscale_ref[:] = _column_partials(dy32 * xhat)
     if dbias_ref is not None:
-        dbias_ref[:] = jnp.sum(dy32, axis=0, keepdims=True)
+        dbias_ref[:] = _column_partials(dy32)
+
+
+# Rows of one dscale/dbias partials block: an fp32 sublane tile. A
+# one-row block of an [n_blocks, D] array is refused by the TPU lowering
+# (the second-to-last block dim must be a multiple of 8), so every
+# row-block writes a full tile.
+_PARTIAL_ROWS = 8
+
+
+def _column_partials(v):
+    """[block, D] -> [_PARTIAL_ROWS, D]: sums rows that share a sublane,
+    so the reduction is plain vector adds with no cross-sublane step;
+    the XLA sum after the kernel folds the tile's rows with the rest."""
+    return jnp.sum(v.reshape(-1, _PARTIAL_ROWS, v.shape[-1]), axis=0)
 
 
 def _norm_bwd(x2d, mu, rstd, scale, dy, dres, *, block: int, rms: bool,
               interpret: bool):
     """-> (dx [R, D], dscale [D] fp32, dbias [D] fp32 | None).
 
-    dscale/dbias come back as per-row-block fp32 partials ([n_blocks, D])
-    that one XLA sum collapses — the same partials-then-reduce shape as
-    the flash backward's dQ path."""
+    dscale/dbias come back as per-row-block fp32 partials (one sublane
+    tile each: [n_blocks * 8, D]) that one XLA sum collapses — the same
+    partials-then-reduce shape as the flash backward's dQ path."""
     r, d = x2d.shape
     n_blocks = r // block
     with_res = dres is not None
@@ -222,8 +235,9 @@ def _norm_bwd(x2d, mu, rstd, scale, dy, dres, *, block: int, rms: bool,
     row_spec = pl.BlockSpec((block, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     stat_spec = pl.BlockSpec((block, 1), lambda i: (i, 0))
-    part_spec = pl.BlockSpec((1, d), lambda i: (i, 0))
-    part_shape = jax.ShapeDtypeStruct((n_blocks, d), jnp.float32)
+    part_spec = pl.BlockSpec((_PARTIAL_ROWS, d), lambda i: (i, 0))
+    part_shape = jax.ShapeDtypeStruct(
+        (n_blocks * _PARTIAL_ROWS, d), jnp.float32)
 
     inputs, in_specs = [x2d], [row_spec]
     if not rms:
@@ -258,7 +272,7 @@ def _norm_bwd(x2d, mu, rstd, scale, dy, dres, *, block: int, rms: bool,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*inputs)
@@ -303,7 +317,7 @@ def _gelu_call(kernel, args, r, d, block, dtype, name, interpret):
         in_specs=[row_spec] * len(args),
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((r, d), dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)

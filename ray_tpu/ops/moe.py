@@ -24,7 +24,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from ray_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

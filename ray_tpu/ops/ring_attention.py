@@ -21,7 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ray_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG_INF = -1e30

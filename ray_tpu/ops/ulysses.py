@@ -15,7 +15,7 @@ import functools
 from typing import Callable
 
 import jax
-from ray_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import xla_causal_attention

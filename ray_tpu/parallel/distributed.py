@@ -98,13 +98,14 @@ def initialize(
     """
     import jax
 
+    from ray_tpu.util.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if platform is not None:
         os.environ["JAX_PLATFORMS"] = platform
         jax.config.update("jax_platforms", platform)
     if num_cpu_devices is not None:
-        from ray_tpu._compat import set_num_cpu_devices
-
-        set_num_cpu_devices(num_cpu_devices)
+        jax.config.update("jax_num_cpu_devices", num_cpu_devices)
 
     if world_size == 1 and coordinator_address is None:
         return  # single-process: nothing to rendezvous

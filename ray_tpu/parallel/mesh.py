@@ -25,9 +25,9 @@ import math
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from ray_tpu._compat import AxisType, make_mesh
+from ray_tpu.util.compile_cache import ensure_compile_cache
 
 # Outermost -> innermost. ep shares the dims between sp and tp so MoE models
 # can all_to_all over experts without a dedicated physical axis.
@@ -95,18 +95,16 @@ def build_mesh(
     from the in/out shardings and ``with_sharding_constraint`` hints, which is
     the idiomatic "annotate and let the compiler insert collectives" recipe.
     """
+    ensure_compile_cache()
     config = config or auto_mesh_config()
     devices = list(config.devices) if config.devices is not None else jax.devices()
     sizes = config.axis_sizes(len(devices))
-    mesh_devices = (
-        make_mesh(
-            tuple(sizes[a] for a in AXIS_ORDER),
-            AXIS_ORDER,
-            axis_types=(axis_types,) * len(AXIS_ORDER),
-            devices=devices,
-        )
+    return jax.make_mesh(
+        tuple(sizes[a] for a in AXIS_ORDER),
+        AXIS_ORDER,
+        axis_types=(axis_types,) * len(AXIS_ORDER),
+        devices=devices,
     )
-    return mesh_devices
 
 
 def build_hybrid_mesh(
@@ -179,10 +177,8 @@ def build_hybrid_mesh(
         dcn_pp * sizes["pp"], dcn_dp * sizes["dp"], sizes["fsdp"],
         sizes["ep"], sizes["sp"], sizes["tp"],
     )
-    from ray_tpu._compat import mesh as _mesh
-
-    return _mesh(stacked.reshape(final_shape), AXIS_ORDER,
-                 axis_types=(axis_types,) * len(AXIS_ORDER))
+    return Mesh(stacked.reshape(final_shape), AXIS_ORDER,
+                axis_types=(axis_types,) * len(AXIS_ORDER))
 
 
 def single_device_mesh() -> Mesh:

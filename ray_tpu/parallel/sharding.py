@@ -73,24 +73,24 @@ def with_logical_constraint(
     mesh: Mesh | None = None,
     rules: Mapping[str, tuple[str, ...] | None] | None = None,
 ) -> jax.Array:
-    """``with_sharding_constraint`` by logical names; no-op outside jit/mesh."""
-    mesh = mesh or _current_mesh()
-    if mesh is None or mesh.empty:
+    """``with_sharding_constraint`` by logical names.
+
+    Without an explicit ``mesh`` the constraint binds to the mesh the
+    computation is traced under (``jax.sharding.set_mesh``, which
+    ``make_train_step`` / ``make_init_fn`` enter around trace and call).
+    With no mesh in context — a one-device program such as the serving
+    engine's step — there is nothing to constrain against and ``x`` is
+    returned as is. A jit over several devices that is NOT traced under
+    its mesh therefore gets no activation constraints: trace it under
+    ``set_mesh``.
+    """
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, logical_spec(logical_axes, rules))
     )
-
-
-def _current_mesh() -> Mesh | None:
-    # Abstract mesh from the surrounding jit, if any.
-    try:
-        env = jax.sharding.get_abstract_mesh()
-        if env is not None and not env.empty:
-            return env
-    except Exception:
-        pass
-    return None
 
 
 def shard_pytree(tree, sharding_tree, mesh: Mesh):

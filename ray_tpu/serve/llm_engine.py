@@ -44,6 +44,7 @@ tokens_total}``.
 from __future__ import annotations
 
 import heapq
+import logging
 import os
 import threading
 import time
@@ -56,6 +57,8 @@ from ray_tpu.util import failpoints
 from ray_tpu.util import goodput as _goodput
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
 
 # How many consecutive decode-step failures fail the active streams
 # (each failure already surfaced; three in a row means the step itself
@@ -161,6 +164,9 @@ class LLMEngine:
         import jax
         import numpy as np
 
+        from ray_tpu.util.compile_cache import ensure_compile_cache
+
+        ensure_compile_cache()
         if max_prompt_len > cache_len:
             raise ValueError(
                 f"max_prompt_len={max_prompt_len} must fit the cache "
@@ -245,6 +251,10 @@ class LLMEngine:
         self._stop = False
         self._seq = 0
         self._step_errors_row = 0
+        # The first prefill/decode failure's traceback is logged once: a
+        # compile failure or an OOM on the chip repeats every step (the
+        # donated cache is gone), and only the first one says why.
+        self._failure_logged = False
         # Last wall-clock instant a token batch reached the streams —
         # the previous edge of the inter-token-latency (TPOT) gap.
         # None until the first prefill delivers (the first decode step
@@ -347,6 +357,7 @@ class LLMEngine:
             failpoints.hit("serve.llm.before_admit")
             self._prefill_batch(batch, slots)
         except BaseException as e:  # noqa: BLE001 — requeue, bounded
+            self._log_first_failure("prefill")
             with self._lock:
                 for req in batch:
                     req.retries += 1
@@ -404,6 +415,14 @@ class LLMEngine:
                 if req.remaining <= 0 or tok == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
             self._last_tokens_at = now
+
+    def _log_first_failure(self, what: str) -> None:
+        """Call from an ``except`` block: logs the active traceback the
+        first time the engine's device path fails."""
+        if not self._failure_logged:
+            self._failure_logged = True
+            logger.exception("llm engine %s failed (first failure; later "
+                             "ones are only counted)", what)
 
     def step_cost(self) -> dict:
         """Cost-account the compiled decode step (util/xla_cost):
@@ -470,8 +489,9 @@ class LLMEngine:
             # The one intentional sync per decode step (tokens fan out
             # to streams from host memory).  # analyze: ignore[JX002]
             nxt = np.asarray(nxt)  # analyze: ignore[JX002]
-        except BaseException:
+        except BaseException as e:
             tracing.finish_span(step_span, "ERROR: step")
+            self._log_first_failure("decode step")
             self._step_errors_row += 1
             self.stats_counters["errors"] += 1
             if self._step_errors_row >= _MAX_STEP_ERRORS:
@@ -481,7 +501,7 @@ class LLMEngine:
                         if req is not None:
                             self._finish_locked(
                                 req, error="decode step failing "
-                                "repeatedly", slot=slot)
+                                f"repeatedly: {e!r}", slot=slot)
                 self._step_errors_row = 0
             raise
         self._step_errors_row = 0

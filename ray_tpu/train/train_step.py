@@ -23,6 +23,27 @@ Params = Any
 TrainState = dict[str, Any]  # {'params', 'opt': {'mu','nu'}, 'step'}
 
 
+class _MeshBound:
+    """A jitted function that traces, lowers and runs under the mesh it
+    was built for, so ``get_abstract_mesh()`` inside the traced body is
+    that mesh: the models' ``with_logical_constraint`` calls bind to it
+    and the flash kernel is ``shard_map``-ped over it. Traced under no
+    mesh, both would silently do nothing and every chip would compute
+    the global batch."""
+
+    def __init__(self, jitted, mesh: Mesh):
+        self._jitted = jitted
+        self._mesh = mesh
+
+    def __call__(self, *args, **kwargs):
+        with jax.sharding.set_mesh(self._mesh):
+            return self._jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        with jax.sharding.set_mesh(self._mesh):
+            return self._jitted.lower(*args, **kwargs)
+
+
 def state_shardings(param_shardings: Params, mesh: Mesh) -> TrainState:
     """Optimizer state mirrors the param tree => shardings are shared."""
     return {
@@ -48,7 +69,9 @@ def make_init_fn(
             "step": jnp.zeros((), jnp.int32),
         }
 
-    return jax.jit(init, out_shardings=state_shardings(param_shardings, mesh))
+    return _MeshBound(
+        jax.jit(init, out_shardings=state_shardings(param_shardings, mesh)),
+        mesh)
 
 
 def make_train_step(
@@ -90,12 +113,14 @@ def make_train_step(
             metrics.update(extra_metrics(new_params, batch))
         return new_state, metrics
 
-    return jax.jit(
-        step,
-        in_shardings=(st_shard, batch_shardings),
-        out_shardings=(st_shard, NamedSharding(mesh, P())),
-        donate_argnums=(0,),
-    )
+    return _MeshBound(
+        jax.jit(
+            step,
+            in_shardings=(st_shard, batch_shardings),
+            out_shardings=(st_shard, NamedSharding(mesh, P())),
+            donate_argnums=(0,),
+        ),
+        mesh)
 
 
 def batch_sharding(mesh: Mesh, spec: P | None = None) -> NamedSharding:

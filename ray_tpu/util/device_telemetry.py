@@ -16,11 +16,12 @@ device. Three surfaces:
   to the pure-Python stack sampler (``util/stack_sampler``) when
   ``jax.profiler`` is unavailable or fails.
 
-Everything degrades to a stub when jax is not loaded: this module NEVER
-imports jax itself (workers fork fast precisely because jax loads
-lazily; a node agent must never initialize a TPU backend and steal the
-chip from its workers). ``snapshot(force=True)`` opts a process in
-explicitly.
+Everything degrades to a stub until the process has initialised a JAX
+backend itself: this module NEVER imports jax and never makes the first
+backend touch (workers fork fast precisely because jax loads lazily; a
+node agent, or a worker that merely imported jax, must never take the
+chip from the process that needs it). ``snapshot(force=True)`` opts a
+process in explicitly.
 """
 
 from __future__ import annotations
@@ -61,6 +62,20 @@ def jax_loaded() -> bool:
     """Has something in this process already imported jax? (We piggyback
     on their import; we never trigger one.)"""
     return "jax" in sys.modules
+
+
+def backend_initialized() -> bool:
+    """Has this process's OWN code already initialised a JAX backend?
+
+    ``jax.local_devices()`` initialises one if none is: on a TPU host
+    that takes the chip in whichever process asked first, and in a
+    trainer rank still waiting for its coordinator it happens before
+    ``jax.distributed.initialize``, which that call forbids. Telemetry
+    must never be that first touch, so an unforced snapshot waits until
+    the backend exists — an imported jax is not enough."""
+    xla_bridge = sys.modules.get("jax._src.xla_bridge")
+    return (jax_loaded() and xla_bridge is not None
+            and xla_bridge.backends_are_initialized())
 
 
 def _install_listeners() -> None:
@@ -162,10 +177,11 @@ def _stub(ts: float, error: str | None = None) -> Dict[str, Any]:
 
 def snapshot(force: bool = False) -> Dict[str, Any]:
     """Current device view of THIS process. A stub (``available: False``)
-    when jax was never imported here — pass ``force=True`` to import it
-    (drivers/benchmarks that want the telemetry to pull jax in)."""
+    until the process's own code has initialised a JAX backend (see
+    ``backend_initialized``) — pass ``force=True`` to import jax and
+    initialise one (drivers/benchmarks that own their chip)."""
     ts = time.time()
-    if not force and not jax_loaded():
+    if not force and not backend_initialized():
         return _stub(ts)
     try:
         import jax

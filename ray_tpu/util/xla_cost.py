@@ -36,10 +36,8 @@ PEAK_HBM_GBPS = {
     "v5p": 2765.0,
     "v6 lite": 1640.0,
     "v6e": 1640.0,
-    "cpu": 50.0,  # nominal DDR, so the roofline still renders off-TPU
+    "cpu": 50.0,  # nominal DDR: tier-1's step-anatomy tests run off-TPU
 }
-
-DEFAULT_HBM_GBPS = 819.0  # unknown accelerator: assume v5e
 
 
 def jax_loaded() -> bool:
@@ -49,11 +47,15 @@ def jax_loaded() -> bool:
 
 
 def peak_hbm_bytes_per_s(device_kind: str) -> float:
+    """Peak HBM bytes/s of one chip of this kind; an unknown kind is an
+    error, never a default (same rule as ``peak_flops_per_chip``)."""
     kind = (device_kind or "").lower()
     for key, gbps in PEAK_HBM_GBPS.items():
         if key in kind:
             return gbps * 1e9
-    return DEFAULT_HBM_GBPS * 1e9
+    raise ValueError(
+        f"no peak HBM bandwidth known for device kind {device_kind!r}; "
+        f"add it to PEAK_HBM_GBPS with its source")
 
 
 def stub(reason: str = "jax not loaded") -> Dict[str, Any]:
@@ -113,8 +115,12 @@ def analyze_compiled(compiled: Any,
     # dependency-free, but util must not import scripts at module load.
     from ray_tpu.scripts.measure import peak_flops_per_chip
 
-    peak_flops = peak_flops_per_chip(kind)
-    peak_bw = peak_hbm_bytes_per_s(kind)
+    try:
+        peak_flops = peak_flops_per_chip(kind)
+        peak_bw = peak_hbm_bytes_per_s(kind)
+    except ValueError as exc:
+        # No roofline against a guessed peak: callers ship the stub.
+        return stub(str(exc))
     intensity = flops / bytes_accessed if bytes_accessed > 0 else 0.0
     ridge = peak_flops / peak_bw if peak_bw > 0 else 0.0
     out: Dict[str, Any] = {

@@ -19,16 +19,6 @@ from ray_tpu import train
 from ray_tpu.cluster.cluster_utils import Cluster
 from ray_tpu.train import session
 
-# Multi-process GSPMD over the CPU backend ("Multiprocess computations
-# aren't implemented on the CPU backend") landed after the 0.4 series;
-# on older jax the distributed-CPU simulation cannot run at all.
-_jax_version = tuple(int(x) for x in __import__("jax").__version__
-                     .split(".")[:2])
-multiprocess_cpu = pytest.mark.skipif(
-    _jax_version < (0, 5),
-    reason="multiprocess CPU collectives need jax >= 0.5",
-)
-
 
 @pytest.fixture(scope="module")
 def two_node_cluster():
@@ -43,7 +33,6 @@ def two_node_cluster():
     cluster.shutdown()
 
 
-@multiprocess_cpu
 def test_two_process_mesh_train_step(two_node_cluster):
     # The loop is defined inline so cloudpickle ships it by value to the
     # worker processes (test modules aren't importable there).
@@ -117,7 +106,6 @@ def test_two_process_mesh_train_step(two_node_cluster):
     assert np.isfinite(m["loss1"]) and np.isfinite(m["loss2"])
 
 
-@multiprocess_cpu
 def test_multiprocess_sharded_checkpoint_resume(two_node_cluster, tmp_path_factory):
     """2-process fsdp-sharded save -> resume-mid-training roundtrip.
 
