@@ -18,6 +18,11 @@ def init(address: str | None = None, **kwargs):
     address=None -> in-process backend (single node).
     address="tcp://host:port" -> cluster backend (control-plane address).
     """
+    # Where jax is already loaded, count its compiles from here on
+    # (device_telemetry.compile_log); never imports jax itself.
+    from ray_tpu.util import device_telemetry
+
+    device_telemetry.ensure_listeners()
     return _worker.init(address, **kwargs)
 
 

@@ -199,36 +199,44 @@ def _block(x: jax.Array, p: Params, cfg: GPT2Config) -> jax.Array:
     h, hd = cfg.n_head, cfg.head_dim
     dt = cfg.dtype
 
-    y, x_skip = _norm_residual(x, p["ln1_scale"], p["ln1_bias"], cfg)
-    qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
-    q, k_, v_ = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, hd)
-    k_ = k_.reshape(b, t, h, hd)
-    v_ = v_.reshape(b, t, h, hd)
-    if cfg.attention_impl == "ring" and cfg.mesh is not None:
-        from ray_tpu.ops.ring_attention import ring_causal_attention
+    with jax.named_scope("ln"):
+        y, x_skip = _norm_residual(x, p["ln1_scale"], p["ln1_bias"], cfg)
+    with jax.named_scope("attn_proj"):
+        qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
+        q, k_, v_ = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, t, h, hd)
+        k_ = k_.reshape(b, t, h, hd)
+        v_ = v_.reshape(b, t, h, hd)
+    with jax.named_scope("attn"):
+        if cfg.attention_impl == "ring" and cfg.mesh is not None:
+            from ray_tpu.ops.ring_attention import ring_causal_attention
 
-        attn = ring_causal_attention(q, k_, v_, cfg.mesh, axis="sp")
-    elif cfg.attention_impl == "ulysses" and cfg.mesh is not None:
-        from ray_tpu.ops.ulysses import ulysses_attention
+            attn = ring_causal_attention(q, k_, v_, cfg.mesh, axis="sp")
+        elif cfg.attention_impl == "ulysses" and cfg.mesh is not None:
+            from ray_tpu.ops.ulysses import ulysses_attention
 
-        attn = ulysses_attention(q, k_, v_, cfg.mesh, axis="sp")
-    else:
-        attn = causal_attention(q, k_, v_, use_flash=cfg.use_flash)
-    attn = attn.reshape(b, t, d)
-    x = x_skip + attn @ p["attn_out_w"].astype(dt) + p["attn_out_b"].astype(dt)
+            attn = ulysses_attention(q, k_, v_, cfg.mesh, axis="sp")
+        else:
+            attn = causal_attention(q, k_, v_, use_flash=cfg.use_flash)
+    with jax.named_scope("attn_proj"):
+        attn = attn.reshape(b, t, d)
+        x = x_skip + attn @ p["attn_out_w"].astype(dt) \
+            + p["attn_out_b"].astype(dt)
     x = with_logical_constraint(x, ("batch", "seq", None))
 
-    y, x_skip = _norm_residual(x, p["ln2_scale"], p["ln2_bias"], cfg)
-    y = y @ p["mlp_in_w"].astype(dt) + p["mlp_in_b"].astype(dt)
-    y = with_logical_constraint(y, ("batch", "seq", "mlp"))
-    if cfg.fused_norm:
-        from ray_tpu.ops.fused_norm import fused_gelu
+    with jax.named_scope("ln"):
+        y, x_skip = _norm_residual(x, p["ln2_scale"], p["ln2_bias"], cfg)
+    with jax.named_scope("mlp"):
+        y = y @ p["mlp_in_w"].astype(dt) + p["mlp_in_b"].astype(dt)
+        y = with_logical_constraint(y, ("batch", "seq", "mlp"))
+        if cfg.fused_norm:
+            from ray_tpu.ops.fused_norm import fused_gelu
 
-        y = fused_gelu(y)
-    else:
-        y = jax.nn.gelu(y, approximate=True)
-    x = x_skip + y @ p["mlp_out_w"].astype(dt) + p["mlp_out_b"].astype(dt)
+            y = fused_gelu(y)
+        else:
+            y = jax.nn.gelu(y, approximate=True)
+        x = x_skip + y @ p["mlp_out_w"].astype(dt) \
+            + p["mlp_out_b"].astype(dt)
     x = with_logical_constraint(x, ("batch", "seq", None))
     return x
 
@@ -237,7 +245,8 @@ def gpt2_hidden(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array
     """tokens [B, T] int32 -> final-layernormed hidden states [B, T, D]."""
     _, t = tokens.shape
     dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[:t]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[:t]
     x = with_logical_constraint(x, ("batch", "seq", None))
 
     block_fn = lambda carry, p: (_block(carry, p, cfg), None)
@@ -256,11 +265,13 @@ def gpt2_hidden(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Array
                 x, jax.tree.map(lambda a: a[i], params["blocks"])
             )
 
-    if cfg.fused_norm:
-        from ray_tpu.ops.fused_norm import fused_layer_norm
+    with jax.named_scope("ln"):
+        if cfg.fused_norm:
+            from ray_tpu.ops.fused_norm import fused_layer_norm
 
-        return fused_layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    return _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+            return fused_layer_norm(
+                x, params["lnf_scale"], params["lnf_bias"])
+        return _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
 
 
 def _head_dtype(cfg: GPT2Config):
@@ -271,11 +282,15 @@ def gpt2_forward(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Arra
     """tokens [B, T] int32 -> logits [B, T, V] (fp32 unless cfg.logits_dtype)."""
     x = gpt2_hidden(params, tokens, cfg)
     # Tied LM head; fp32 logits by default for a stable loss.
-    logits = jnp.einsum(
-        "btd,vd->btv", x, params["wte"].astype(cfg.dtype),
+    with jax.named_scope("head"):
+        return _tied_head(x, params["wte"], cfg)
+
+
+def _tied_head(x: jax.Array, wte: jax.Array, cfg: GPT2Config) -> jax.Array:
+    return jnp.einsum(
+        "btd,vd->btv", x, wte.astype(cfg.dtype),
         preferred_element_type=_head_dtype(cfg),
     )
-    return logits
 
 
 def _chunked_ce(x: jax.Array, wte: jax.Array, targets: jax.Array,
@@ -329,18 +344,20 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
     """
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if cfg.ce_vocab_chunks > 1:
-        x = gpt2_hidden(params, inputs, cfg)
-        return _chunked_ce(x, params["wte"], targets, cfg)
-    logits = gpt2_forward(params, inputs, cfg)
-    # CE via logsumexp - picked logit: one reduction pass over [B,T,V]
-    # instead of materializing log_softmax (measured ~2x faster fwd on
-    # v5e at V=50k; the softmax only appears in the backward). The
-    # reductions run in fp32 even when cfg.logits_dtype is bf16.
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked)
+    x = gpt2_hidden(params, inputs, cfg)
+    with jax.named_scope("head_loss"):
+        if cfg.ce_vocab_chunks > 1:
+            return _chunked_ce(x, params["wte"], targets, cfg)
+        # CE via logsumexp - picked logit: one reduction pass over
+        # [B,T,V] instead of materializing log_softmax (measured ~2x
+        # faster fwd on v5e at V=50k; the softmax only appears in the
+        # backward). The reductions run in fp32 even when
+        # cfg.logits_dtype is bf16.
+        logits = _tied_head(x, params["wte"], cfg).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.mean(lse - picked)
 
 
 # -- autoregressive decoding (serving path) --------------------------------
@@ -392,37 +409,53 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
     cursor = jnp.mod(pos, cache_len)
     valid = jnp.minimum(pos + 1, cache_len)
     wpe_pos = jnp.clip(pos, 0, cfg.seq_len - 1)
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[wpe_pos]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens] \
+            + params["wpe"].astype(dt)[wpe_pos]
 
     from ray_tpu.ops.attention import (cache_write_token,
                                        cached_decode_attention)
 
     def block(x, layer):
         p, k_cache, v_cache = layer
-        y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-        qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
-        q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-        k_cache = cache_write_token(
-            k_cache, k_new.reshape(s, 1, h, hd), cursor)
-        v_cache = cache_write_token(
-            v_cache, v_new.reshape(s, 1, h, hd), cursor)
-        attn = cached_decode_attention(
-            q.reshape(s, h, hd), k_cache, v_cache, valid, dt)
-        x = x + attn.reshape(s, d) @ p["attn_out_w"].astype(dt) \
-            + p["attn_out_b"].astype(dt)
-        y = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-        y = y @ p["mlp_in_w"].astype(dt) + p["mlp_in_b"].astype(dt)
-        y = jax.nn.gelu(y, approximate=True)
-        x = x + y @ p["mlp_out_w"].astype(dt) + p["mlp_out_b"].astype(dt)
+        with jax.named_scope("ln"):
+            y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+        with jax.named_scope("attn_proj"):
+            qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
+            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("cache_write"):
+            k_cache = cache_write_token(
+                k_cache, k_new.reshape(s, 1, h, hd), cursor)
+            v_cache = cache_write_token(
+                v_cache, v_new.reshape(s, 1, h, hd), cursor)
+        with jax.named_scope("attn"):
+            attn = cached_decode_attention(
+                q.reshape(s, h, hd), k_cache, v_cache, valid, dt)
+        with jax.named_scope("attn_proj"):
+            x = x + attn.reshape(s, d) @ p["attn_out_w"].astype(dt) \
+                + p["attn_out_b"].astype(dt)
+        x = _mlp_block(x, p, dt)
         return x, (k_cache, v_cache)
 
     x, (k_all, v_all) = jax.lax.scan(
         block, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    logits = jnp.einsum(
-        "sd,vd->sv", x, params["wte"].astype(dt),
-        preferred_element_type=jnp.float32)
+    with jax.named_scope("ln"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "sd,vd->sv", x, params["wte"].astype(dt),
+            preferred_element_type=jnp.float32)
     return logits, {"k": k_all, "v": v_all}
+
+
+def _mlp_block(x: jax.Array, p: Params, dt) -> jax.Array:
+    """Second half of a serving block: norm, MLP, residual."""
+    with jax.named_scope("ln"):
+        y = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
+    with jax.named_scope("mlp"):
+        y = y @ p["mlp_in_w"].astype(dt) + p["mlp_in_b"].astype(dt)
+        y = jax.nn.gelu(y, approximate=True)
+        return x + y @ p["mlp_out_w"].astype(dt) + p["mlp_out_b"].astype(dt)
 
 
 # jax-hot-path: traced into the engine's single compiled prefill lane
@@ -442,35 +475,41 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
     r, p_len = tokens.shape
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
     dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[:p_len]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(dt)[tokens] \
+            + params["wpe"].astype(dt)[:p_len]
     from ray_tpu.ops.attention import cache_write_prompt
 
     def block(x, layer):
         p, k_cache, v_cache = layer
-        y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-        qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
-        q, k_, v_ = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(r, p_len, h, hd)
-        k_ = k_.reshape(r, p_len, h, hd)
-        v_ = v_.reshape(r, p_len, h, hd)
-        attn = causal_attention(q, k_, v_, use_flash=False)
-        k_cache = cache_write_prompt(k_cache, k_, slots)
-        v_cache = cache_write_prompt(v_cache, v_, slots)
-        x = x + attn.reshape(r, p_len, d) @ p["attn_out_w"].astype(dt) \
-            + p["attn_out_b"].astype(dt)
-        y = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-        y = y @ p["mlp_in_w"].astype(dt) + p["mlp_in_b"].astype(dt)
-        y = jax.nn.gelu(y, approximate=True)
-        x = x + y @ p["mlp_out_w"].astype(dt) + p["mlp_out_b"].astype(dt)
+        with jax.named_scope("ln"):
+            y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+        with jax.named_scope("attn_proj"):
+            qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
+            q, k_, v_ = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(r, p_len, h, hd)
+            k_ = k_.reshape(r, p_len, h, hd)
+            v_ = v_.reshape(r, p_len, h, hd)
+        with jax.named_scope("attn"):
+            attn = causal_attention(q, k_, v_, use_flash=False)
+        with jax.named_scope("cache_write"):
+            k_cache = cache_write_prompt(k_cache, k_, slots)
+            v_cache = cache_write_prompt(v_cache, v_, slots)
+        with jax.named_scope("attn_proj"):
+            x = x + attn.reshape(r, p_len, d) @ p["attn_out_w"].astype(dt) \
+                + p["attn_out_b"].astype(dt)
+        x = _mlp_block(x, p, dt)
         return x, (k_cache, v_cache)
 
     x, (k_all, v_all) = jax.lax.scan(
         block, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]  # [R, D]
-    logits = jnp.einsum(
-        "rd,vd->rv", last, params["wte"].astype(dt),
-        preferred_element_type=jnp.float32)
+    with jax.named_scope("ln"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("head"):
+        last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]  # [R, D]
+        logits = jnp.einsum(
+            "rd,vd->rv", last, params["wte"].astype(dt),
+            preferred_element_type=jnp.float32)
     return logits, {"k": k_all, "v": v_all}
 
 

@@ -27,7 +27,7 @@ KNOWN_BENCHES = frozenset({
     "task_overhead", "memory_pressure", "chaos_soak", "scalebench",
     "drain_recovery_ms", "serve_latency", "input_pipeline", "goodput",
     "analyze", "gang_recovery", "llm_serving", "streaming_dataflow",
-    "signal_plane", "fleet_scaling", "trace_plane", "step_anatomy",
+    "signal_plane", "fleet_scaling", "step_anatomy",
 })
 
 
@@ -313,35 +313,6 @@ def record_signal_plane(*, agreement: dict, query_p50_ms: float,
         entry["ring"] = dict(ring)
     if slo is not None:
         entry["slo"] = dict(slo)
-    entry.update(extra)
-    entry["committed_to"] = record_if_on_chip(dict(entry), path)
-    return entry
-
-
-def record_trace_plane(*, decomposition: dict, ttft_p50_ms: float,
-                       overhead: dict, store: dict | None = None,
-                       device: str = "", path: str | None = None,
-                       **extra) -> dict:
-    """Trace-plane evidence (``scripts/trace_bench.py``): the TTFT
-    decomposition agreement verdict (the flight recorder's windowed
-    TTFT p50 must match the client stopwatch within 5%, the per-phase
-    p50s must sum to it, and the dominant phase must be NAMED — a
-    decomposition that disagrees with the stopwatch is worse than
-    none), the recorder's TTFT p50, the tracing hot-path overhead
-    ratios (untraced requests on a tracing-enabled process must run at
-    baseline speed), and the bounded-store section (churn growth +
-    per-cause drop counts). Committed to the evidence trail only on an
-    accelerator; returns the entry (with ``committed_to``) either
-    way."""
-    entry: dict = {
-        "bench": "trace_plane",
-        "device": device,
-        "decomposition": dict(decomposition),
-        "ttft_p50_ms": float(ttft_p50_ms),
-        "overhead": dict(overhead),
-    }
-    if store is not None:
-        entry["store"] = dict(store)
     entry.update(extra)
     entry["committed_to"] = record_if_on_chip(dict(entry), path)
     return entry
@@ -682,24 +653,6 @@ def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
             if not _is_num(obj.get("series")):
                 errs.append("signal_plane line missing numeric "
                             "series count")
-        elif obj["bench"] == "trace_plane":
-            # The line's claim is "the flight recorder tells the
-            # truth cheaply": the decomposition-vs-stopwatch verdict,
-            # the recorder's own TTFT p50, and the untraced hot-path
-            # ratio are all load-bearing.
-            decomp = obj.get("decomposition")
-            if not (isinstance(decomp, dict)
-                    and isinstance(decomp.get("ok"), bool)):
-                errs.append("trace_plane line missing boolean "
-                            "decomposition.ok")
-            if not _is_num(obj.get("ttft_p50_ms")):
-                errs.append("trace_plane line missing numeric "
-                            "ttft_p50_ms")
-            overhead = obj.get("overhead")
-            if not (isinstance(overhead, dict)
-                    and _is_num(overhead.get("untraced_ratio"))):
-                errs.append("trace_plane line missing numeric "
-                            "overhead.untraced_ratio")
         elif obj["bench"] == "step_anatomy":
             # The line's claim is "we know where the step wall went and
             # how close to peak the chip ran": the MFU number, the
@@ -960,7 +913,6 @@ REGRESS_GATES: dict[str, tuple[str, float]] = {
     "goodput.goodput_pct": ("higher", 0.15),
     "serve_latency.client.p99_ms": ("lower", 0.50),
     "signal_plane.query_p50_ms": ("lower", 0.50),
-    "trace_plane.ttft_p50_ms": ("lower", 0.50),
 }
 
 

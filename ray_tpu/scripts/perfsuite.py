@@ -198,11 +198,6 @@ def main() -> None:
                          "agreement vs client ledger + bounded-ring "
                          "memory proof + seeded SLO burn with exactly "
                          "one burning and one recovery pubsub event)")
-    ap.add_argument("--traces", action="store_true",
-                    help="add the trace-plane point (TTFT "
-                         "decomposition vs the client stopwatch, "
-                         "bounded assembly store, tracing hot-path "
-                         "overhead ratios)")
     ap.add_argument("--anatomy", action="store_true",
                     help="add the step-anatomy point (cost-model-vs-"
                          "analytic FLOPs agreement on two model "
@@ -261,9 +256,6 @@ def main() -> None:
     if args.signals:
         steps.append([sys.executable, "-m",
                       "ray_tpu.scripts.signal_bench", "--out", args.out])
-    if args.traces:
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.trace_bench", "--out", args.out])
     if args.anatomy:
         steps.append([sys.executable, "-m",
                       "ray_tpu.scripts.anatomy_bench", "--out", args.out])

@@ -79,6 +79,14 @@ class Replica:
         # disambiguates the in-process replicas of the local backend.
         self._replica_tag = f"{os.getpid()}-{id(self) & 0xFFFF:x}"
 
+    def stop_callable(self) -> bool:
+        """Before a deliberate retire: a callable that owns a thread
+        (``LLMEngine``'s loop) stops and joins it. ``kill`` alone leaves
+        it running on the local backend, and a process that exits while
+        the loop is inside a device step aborts."""
+        stop = getattr(self.callable, "shutdown_engine", None)
+        return bool(stop()) if stop is not None else False
+
     def _target(self, method: str):
         return (self.callable if method == "__call__"
                 else getattr(self.callable, method))
@@ -116,15 +124,16 @@ class Replica:
             _obs.record_shed(dep, "replica")
             return {"__serve_envelope__": 1, "shed": "replica",
                     "phases": {"queue_wait": queue_wait}}
+        # Span only when the REQUEST carries trace context: the caller
+        # made the sampling decision, and a span under an explicit
+        # parent is recorded whatever the process-wide switch says.
         trace_ctx = request_meta.get("trace_ctx")
-        if trace_ctx:
-            tracing.enable()  # the caller traces: continue here
         span_cm = (tracing.span(
             f"serve.replica:{dep}.{method}",
             {"deployment": dep, "replica": self._replica_tag,
              "queue_wait_ms": round(queue_wait * 1e3, 3)},
             parent=trace_ctx, cat="serve")
-            if trace_ctx and tracing.is_enabled() else nullcontext())
+            if trace_ctx else nullcontext())
         # Gauge emits happen INSIDE the lock: counter capture and
         # publish must be atomic, or two concurrent completions can
         # publish out of order and strand the gauge at a stale nonzero
@@ -254,8 +263,7 @@ class ServeController:
             self.apps[name] = app
             self._bump_locked()
         # Rolling replace: retire old replicas after the new set is live.
-        for r in old:
-            self._kill_replica(r)
+        self._retire_replicas(old)
         return self.config_version
 
     def _start_replica(self, app: dict):
@@ -277,14 +285,34 @@ class ServeController:
         except Exception:
             pass
 
+    @classmethod
+    def _retire_replicas(cls, handles):
+        """Kill replicas that are alive and no longer wanted, after
+        their callables stopped what they run on their own. Callers
+        publish the routing table WITHOUT these replicas first, so no
+        new request is routed to one while it stops. All are asked at
+        once: the wait is one engine's join, not one per replica."""
+        stops = []
+        for h in handles:
+            try:
+                stops.append(h.stop_callable.remote())
+            except Exception:
+                pass
+        try:
+            if stops:  # a stop that failed must not cut the others' wait
+                ray_tpu.wait(stops, num_returns=len(stops), timeout=45)
+        except Exception:
+            pass
+        for h in handles:
+            cls._kill_replica(h)
+
     def delete_deployment(self, name: str):
         with self._lock:
             app = self.apps.pop(name, None)
             if app:
                 self._bump_locked()
         if app:
-            for r in app["replicas"]:
-                self._kill_replica(r)
+            self._retire_replicas(app["replicas"])
         return True
 
     def _bump_locked(self):
@@ -394,8 +422,9 @@ class ServeController:
             while len(alive) + len(started) < target:
                 started.append(self._start_replica(app))
                 changed = True
+            retired = []
             while len(alive) > target:
-                self._kill_replica(alive.pop())
+                retired.append(alive.pop())
                 changed = True
             alive.extend(started)
 
@@ -411,6 +440,8 @@ class ServeController:
                     # replicas started for it would leak forever.
                     for r in started:
                         self._kill_replica(r)
+            # after the table without them is out (or the app is gone)
+            self._retire_replicas(retired)
 
     # -- per-node HTTP proxies (http_state.py:30 analog) ---------------------
 
@@ -759,19 +790,16 @@ def routed_call(deployment_name: str, method: str, args: tuple, kwargs: dict,
     meta["deployment"] = deployment_name
     deadline_ts = meta.get("deadline_ts")
     trace_parent = meta.get("trace_ctx")
-    if trace_parent:
-        tracing.enable()  # the caller traces: continue here
     t0 = time.time()
     # Span only when the REQUEST carries trace context (same guard as
-    # the replica): tracing.enable() above ratchets the process-global
-    # flag, and gating on is_enabled() alone would make one traced
-    # request flip this router into recording a root span for every
-    # untraced request thereafter — flooding the head's span ring.
+    # the replica): an untraced request must never open a root span
+    # here, whatever the process-wide switch says — that would flood
+    # the head's span ring.
     span_cm = (tracing.span(
         f"serve.route:{deployment_name}",
         {"deployment": deployment_name, "method": method},
         parent=trace_parent, cat="serve")
-        if trace_parent and tracing.is_enabled() else nullcontext())
+        if trace_parent else nullcontext())
     try:
         with span_cm as route_span:
             if route_span is not None:
@@ -959,9 +987,7 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
     backend belongs to the CLIENT side)."""
     meta = dict(request_meta or {})
     trace_parent = meta.get("trace_ctx")
-    if trace_parent:
-        tracing.enable()  # the caller traces: continue here
-    if not (trace_parent and tracing.is_enabled()):
+    if not trace_parent:
         yield from _stream_call_impl(deployment_name, args, kwargs, meta,
                                      backend, poll_s, keepalive_every)
         return
@@ -1121,10 +1147,11 @@ class DeploymentHandle:
         meta: dict = {}
         if self.deadline_s is not None:
             meta["deadline_ts"] = time.time() + self.deadline_s
-        if tracing.is_enabled():
-            ctx = tracing.current_context()
-            if ctx:
-                meta["trace_ctx"] = ctx
+        # A call made inside a recorded span carries its context (the
+        # span exists only if its trace is sampled).
+        ctx = tracing.current_context()
+        if ctx:
+            meta["trace_ctx"] = ctx
         return meta or None
 
     def remote(self, *args, **kwargs):
@@ -1249,10 +1276,10 @@ def make_asgi_app():
                 continue
         meta: dict = {}
         # An upstream traceparent joins the caller's trace ONLY when
-        # the operator enabled tracing here (RAY_TPU_TRACING_ENABLED /
-        # tracing.enable()): the sampling decision belongs to the
-        # server — an unauthenticated header must not be able to
-        # switch on process-wide span recording.
+        # the operator enabled tracing here (RAY_TPU_TRACING_ENABLED or
+        # the switch in ``util/tracing``): the sampling decision belongs
+        # to the server — an unauthenticated header must not be able to
+        # start a trace.
         parent = (tracing.parse_traceparent(headers.get("traceparent"))
                   if tracing.is_enabled() else None)
         if parent is not None:
@@ -1269,9 +1296,8 @@ def make_asgi_app():
         # event-loop thread would corrupt a thread-local span stack's
         # restore order. Created only for requests that CARRY a
         # traceparent (the route/replica guards mirror this): serving
-        # traces follow the caller's sampling decision — a proxy whose
-        # tracing flag got ratcheted on by one propagated request must
-        # not start recording every untraced request.
+        # traces follow the caller's sampling decision — a proxy with
+        # tracing enabled does not record every untraced request.
         http_span = (tracing.start_span(
             f"serve.http:{scope['path']}",
             {"deployment": name, "path": scope["path"]},

@@ -198,24 +198,35 @@ class LLMEngine:
                 f"max_prompt_len={self.max_prompt_len} exceeds the "
                 f"model's position window (seq_len={cfg.seq_len})")
         self._cfg = cfg
-        self.params = init(jax.random.PRNGKey(seed), cfg)
+        # Where the engine's own set-up goes, in seconds (llm_stats):
+        # the two below, then the first call of each jitted program
+        # (trace + compile or cache load + run), stamped where it runs.
+        t0 = time.perf_counter()
+        self.params = jax.block_until_ready(
+            init(jax.random.PRNGKey(seed), cfg))
+        t1 = time.perf_counter()
         # One scratch slot past max_batch: inactive prefill rows write
         # their pad garbage there, keeping the prefill shape fixed.
-        self._cache = init_cache(cfg, self.max_batch + 1, self.cache_len)
+        self._cache = jax.block_until_ready(
+            init_cache(cfg, self.max_batch + 1, self.cache_len))
+        self._init_s = {"params": t1 - t0,
+                        "cache": time.perf_counter() - t1}
         self._compiles = {"decode": 0, "prefill": 0}
 
         def step_fn(params, cache, tokens, pos):
             self._compiles["decode"] += 1  # trace-time: fires per compile
             logits, cache = decode(params, cache, tokens, pos, cfg)
-            return (self._jnp.argmax(logits, axis=-1).astype(
-                self._jnp.int32), cache)
+            with jax.named_scope("head"):
+                return (self._jnp.argmax(logits, axis=-1).astype(
+                    self._jnp.int32), cache)
 
         def prefill_fn(params, cache, tokens, slots, lengths):
             self._compiles["prefill"] += 1
             logits, cache = prefill(params, cache, tokens, slots,
                                     lengths, cfg)
-            return (self._jnp.argmax(logits, axis=-1).astype(
-                self._jnp.int32), cache)
+            with jax.named_scope("head"):
+                return (self._jnp.argmax(logits, axis=-1).astype(
+                    self._jnp.int32), cache)
 
         # Donate the cache: the engine holds the ONLY reference and the
         # step replaces it, so XLA can update in place (2x HBM saved on
@@ -266,9 +277,17 @@ class LLMEngine:
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
             "errors": 0, "tokens_out": 0, "queue_peak": 0,
             "occupancy_sum": 0, "ring_wraps": 0,
+            # The prefill lane's fill: batches run, requests in them,
+            # their (truncated) prompt tokens, and the tokens the fixed
+            # [prefill_rows, max_prompt_len] lane computed for them.
+            # (prefill_rows_real: llm_stats() already has the setting
+            # under "prefill_rows".)
+            "prefill_batches": 0, "prefill_rows_real": 0,
+            "prefill_tokens_real": 0, "prefill_tokens_lane": 0,
         }
-        threading.Thread(target=self._loop, daemon=True,
-                         name="llm-engine-loop").start()
+        self._loop_thread = threading.Thread(
+            target=self._loop, daemon=True, name="llm-engine-loop")
+        self._loop_thread.start()
 
     # -- scheduler loop ----------------------------------------------------
 
@@ -290,9 +309,11 @@ class LLMEngine:
                 # in _step_once); this tick records the loop survival.
                 _metrics.count_loop_restart("llm.engine")
             if time.monotonic() - self._last_reap > 5.0:
-                self._reap_streams()
+                with tracing.device_span("llm.loop.reap"):
+                    self._reap_streams()
             if not did:
-                self._wake.wait(0.02)
+                with tracing.device_span("llm.loop.wait"):
+                    self._wake.wait(0.02)
                 self._wake.clear()
 
     def _push_queued_locked(self, req: _Request):
@@ -323,11 +344,15 @@ class LLMEngine:
             self._finish_locked(req, shed="decode")
 
     def _admit_once(self) -> bool:
-        with self._lock:
+        # The loop's phases are device_spans (profiler annotations on
+        # the device's clock; PERF.md lists the names, which readers of
+        # a captured profile rely on).
+        with tracing.device_span("llm.admit") as ds, self._lock:
             now = time.time()
             self._shed_expired_locked(now)
             free = [i for i in range(self.max_batch)
                     if self._slot_req[i] is None]
+            ds.set_metadata(queued=self._n_queued, free=len(free))
             if not free or not self._n_queued:
                 return False
             take = min(len(free), self.prefill_rows)
@@ -377,44 +402,56 @@ class LLMEngine:
         np = self._np
         rows = self.prefill_rows
         p_len = self.max_prompt_len
-        toks = np.zeros((rows, p_len), np.int32)
-        slot_idx = np.full(rows, self.max_batch, np.int32)  # scratch row
-        lengths = np.ones(rows, np.int32)
-        for i, req in enumerate(batch):
-            prompt = req.prompt[-p_len:]  # truncate to the lane window
-            toks[i, :len(prompt)] = prompt
-            slot_idx[i] = slots[i]
-            lengths[i] = len(prompt)
-        first, self._cache = self._prefill_fn(
-            self.params, self._cache, self._jnp.asarray(toks),
-            self._jnp.asarray(slot_idx), self._jnp.asarray(lengths))
-        # The one intentional sync per prefill: first tokens must reach
-        # the streams now.  # analyze: ignore[JX002]
-        first = np.asarray(first)  # analyze: ignore[JX002]
-        now = time.time()
-        _obs.record_decode_tokens(self._dep, len(batch))
-        with self._lock:
+        t0 = time.perf_counter()
+        with tracing.device_span("llm.prefill.dispatch") as ds:
+            toks = np.zeros((rows, p_len), np.int32)
+            slot_idx = np.full(rows, self.max_batch, np.int32)  # scratch row
+            lengths = np.ones(rows, np.int32)
             for i, req in enumerate(batch):
-                slot = slots[i]
-                tok = int(first[i])
-                self._tokens[slot] = tok
-                self._pos[slot] = int(lengths[i])
-                self._slot_req[slot] = req
-                req.remaining = req.max_new - 1
-                self.stats_counters["admitted"] += 1
-                self.stats_counters["tokens_out"] += 1
-                req.stream.n_tokens += 1
-                req.stream.pending.append([tok])
-                req.stream.event.set()
-                # TTFT: submit -> first token available for delivery.
-                _obs.record_ttft(self._dep, max(0.0, now - req.submitted))
-                # First token exists: prefill phase ends HERE (the TTFT
-                # decomposition keys on the prefill span's end), decode
-                # phase runs until the terminal transition.
-                self._phase_span_locked(req, "llm.decode")
-                if req.remaining <= 0 or tok == self.eos_token:
-                    self._finish_locked(req, done=True, slot=slot)
-            self._last_tokens_at = now
+                prompt = req.prompt[-p_len:]  # truncate to the lane window
+                toks[i, :len(prompt)] = prompt
+                slot_idx[i] = slots[i]
+                lengths[i] = len(prompt)
+            tokens_real = int(lengths[:len(batch)].sum())
+            ds.set_metadata(rows=len(batch), tokens_real=tokens_real)
+            first, self._cache = self._prefill_fn(
+                self.params, self._cache, self._jnp.asarray(toks),
+                self._jnp.asarray(slot_idx), self._jnp.asarray(lengths))
+        with tracing.device_span("llm.prefill.sync"):
+            # The one intentional sync per prefill: first tokens must
+            # reach the streams now.  # analyze: ignore[JX002]
+            first = np.asarray(first)  # analyze: ignore[JX002]
+        self._init_s.setdefault("first_prefill", time.perf_counter() - t0)
+        now = time.time()
+        with tracing.device_span("llm.prefill.fanout"):
+            _obs.record_decode_tokens(self._dep, len(batch))
+            with self._lock:
+                c = self.stats_counters
+                c["prefill_batches"] += 1
+                c["prefill_rows_real"] += len(batch)
+                c["prefill_tokens_real"] += tokens_real
+                c["prefill_tokens_lane"] += rows * p_len
+                for i, req in enumerate(batch):
+                    slot = slots[i]
+                    tok = int(first[i])
+                    self._tokens[slot] = tok
+                    self._pos[slot] = int(lengths[i])
+                    self._slot_req[slot] = req
+                    req.remaining = req.max_new - 1
+                    c["admitted"] += 1
+                    c["tokens_out"] += 1
+                    req.stream.n_tokens += 1
+                    req.stream.pending.append([tok])
+                    req.stream.event.set()
+                    # TTFT: submit -> first token available for delivery.
+                    _obs.record_ttft(self._dep, max(0.0, now - req.submitted))
+                    # First token exists: prefill phase ends HERE (the TTFT
+                    # decomposition keys on the prefill span's end), decode
+                    # phase runs until the terminal transition.
+                    self._phase_span_locked(req, "llm.decode")
+                    if req.remaining <= 0 or tok == self.eos_token:
+                        self._finish_locked(req, done=True, slot=slot)
+                self._last_tokens_at = now
 
     def _log_first_failure(self, what: str) -> None:
         """Call from an ``except`` block: logs the active traceback the
@@ -444,7 +481,7 @@ class LLMEngine:
 
     def _step_once(self) -> bool:  # jax-hot-path  # step-timed
         np = self._np
-        with self._lock:
+        with tracing.device_span("llm.step.select") as ds, self._lock:
             now = time.time()
             # Deadline eviction happens at the step boundary: the slot
             # frees NOW, before compute, and the shed is typed.
@@ -455,6 +492,7 @@ class LLMEngine:
                     self._finish_locked(req, shed="decode", slot=slot)
             active = [i for i in range(self.max_batch)
                       if self._slot_req[i] is not None]
+            ds.set_metadata(occupancy=len(active))
             if not active:
                 return False
             # Per-decode-step span: ONE per engine step (not one per
@@ -481,14 +519,21 @@ class LLMEngine:
             # the site stays armed — that would be the hang the
             # never-hang contract forbids.
             failpoints.hit("serve.llm.before_step")
-            nxt, self._cache = self._step_fn(
-                self.params, self._cache, self._jnp.asarray(self._tokens),
-                self._jnp.asarray(self._pos))
+            # epoch_ns ties this thread's clock (time.time_ns, the span
+            # store's) to the profile's: a reader takes the offset as
+            # the median over these anchors.
+            with tracing.device_span("llm.step.dispatch",
+                                     epoch_ns=time.time_ns()):
+                nxt, self._cache = self._step_fn(
+                    self.params, self._cache,
+                    self._jnp.asarray(self._tokens),
+                    self._jnp.asarray(self._pos))
             # Anatomy host phase ends when the async dispatch returns.
             t_dispatch = time.perf_counter()
-            # The one intentional sync per decode step (tokens fan out
-            # to streams from host memory).  # analyze: ignore[JX002]
-            nxt = np.asarray(nxt)  # analyze: ignore[JX002]
+            with tracing.device_span("llm.step.sync"):
+                # The one intentional sync per decode step (tokens fan
+                # out to streams from host memory).
+                nxt = np.asarray(nxt)  # analyze: ignore[JX002]
         except BaseException as e:
             tracing.finish_span(step_span, "ERROR: step")
             self._log_first_failure("decode step")
@@ -506,6 +551,19 @@ class LLMEngine:
             raise
         self._step_errors_row = 0
         step_s = time.perf_counter() - t0
+        self._init_s.setdefault("first_step", step_s)
+        with tracing.device_span("llm.step.fanout") as ds:
+            self._step_fanout(active, nxt, step_s,
+                              max(0.0, t_dispatch - t0), step_span, ds)
+        if self.step_throttle_s:
+            time.sleep(self.step_throttle_s)
+        return True
+
+    def _step_fanout(self, active: List[int], nxt, step_s: float,
+                     host_s: float, step_span: Optional[dict], ds) -> None:
+        """After the step's sync: tokens to their streams under the lock,
+        then the step's metrics, anatomy and store span (all of it is
+        the ``llm.step.fanout`` device span ``ds``)."""
         with self._lock:
             produced = 0
             for slot in active:
@@ -535,14 +593,14 @@ class LLMEngine:
             itl = step_s if self._last_tokens_at is None \
                 else max(0.0, done_at - self._last_tokens_at)
             self._last_tokens_at = done_at
+        ds.set_metadata(tokens=produced)
         _obs.record_decode_step(self._dep, step_s, len(active), produced)
         _obs.record_decode_itl(self._dep, itl, produced)
         # Step anatomy: host = dispatch wall, compute = the sync wall
-        # after it (the np.asarray above IS the device wait); a
+        # after it (the step's np.asarray IS the device wait); a
         # single-replica engine has no gang barrier, so sync is 0 and
         # host + compute partition step_s exactly. MFU rides along
         # once step_cost() has attached the HLO cost model.
-        host_s = max(0.0, t_dispatch - t0)
         mfu = None
         if self._step_cost_flops > 0 and step_s > host_s:
             from ray_tpu.util import xla_cost as _xla_cost
@@ -560,9 +618,6 @@ class LLMEngine:
         if step_span is not None:
             step_span["attributes"]["tokens"] = produced
             tracing.finish_span(step_span)
-        if self.step_throttle_s:
-            time.sleep(self.step_throttle_s)
-        return True
 
     def _phase_span_locked(self, req: _Request, name: Optional[str],
                            status: str = "OK") -> None:
@@ -573,7 +628,7 @@ class LLMEngine:
         if req.span is not None:
             tracing.finish_span(req.span, status)
             req.span = None
-        if name is not None and req.trace_ctx and tracing.is_enabled():
+        if name is not None and req.trace_ctx:
             req.span = tracing.start_span(
                 name, {"rid": req.rid, "deployment": self._dep},
                 parent=req.trace_ctx, cat="llm")
@@ -651,9 +706,10 @@ class LLMEngine:
         # by Replica.handle_request); read on THIS thread, before the
         # request crosses to the engine loop's.
         trace_ctx = (_obs.current_request() or {}).get("trace_ctx")
-        if trace_ctx:
-            tracing.enable()  # the caller traces: continue here
         with self._lock:
+            if self._stop:
+                # the loop has ended: nothing would ever serve it
+                raise RuntimeError("llm engine is stopped")
             if self._n_queued >= self.max_queue:
                 _obs.record_shed(self._dep, "decode")
                 self.stats_counters["shed"] += 1
@@ -798,6 +854,7 @@ class LLMEngine:
             "active": active,
             "queued": queued,
             "compiles": dict(self._compiles),
+            "init_s": dict(self._init_s),
             "mean_occupancy": round(c["occupancy_sum"] / steps, 3)
             if steps else 0.0,
             **c,
@@ -814,9 +871,29 @@ class LLMEngine:
     def check_health(self) -> str:
         return "ok"
 
+    def _fail_unserved(self, error: str) -> None:
+        """End every request still queued or in a slot with ``error``:
+        once the loop is gone its caller would wait for the kill."""
+        with self._lock:
+            for slot in range(self.max_batch):
+                req = self._slot_req[slot]
+                if req is not None:
+                    self._finish_locked(req, error=error, slot=slot)
+            for _, _, req in self._queue:
+                if not req.stream.done:
+                    self._n_queued -= 1
+                    self._finish_locked(req, error=error)
+
     def shutdown_engine(self) -> bool:
-        self._stop = True
+        """Stop the loop and wait for its thread (a step or a prefill in
+        flight ends first); ``serve.shutdown()`` calls this through the
+        replica. True once the thread has ended."""
+        with self._lock:  # ``llm_submit`` reads it under the same lock
+            self._stop = True
         self._wake.set()
+        self._loop_thread.join(timeout=30.0)
+        if not self._loop_thread.is_alive():
+            self._fail_unserved("engine stopped")
         _metrics.retract_loop_series(["llm.engine"])
         # The engine's per-step anatomy gauges (MFU / phase seconds)
         # must not outlive it on the scrape (LC001 discipline).
@@ -824,4 +901,4 @@ class LLMEngine:
             _goodput.retract_trial(f"serve:{self._dep}")
         except Exception:
             pass
-        return True
+        return not self._loop_thread.is_alive()

@@ -46,7 +46,13 @@ def adamw_update(
     opt_state: dict[str, Params],
     step: jax.Array,
 ):
-    """One AdamW step with global-norm clipping. Returns (params, opt_state, lr)."""
+    """One AdamW step with global-norm clipping. Returns
+    (params, opt_state, lr, gnorm). Traced under the scope ``adamw``."""
+    with jax.named_scope("adamw"):
+        return _adamw_update(cfg, grads, params, opt_state, step)
+
+
+def _adamw_update(cfg, grads, params, opt_state, step):
     gnorm = jnp.sqrt(
         sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
     )

@@ -121,6 +121,10 @@ class _Session:
             cat="train")
 
     def report(self, metrics: dict, checkpoint: Optional[Checkpoint] = None):
+        with _tracing.device_span("train.report"):
+            self._report(metrics, checkpoint)
+
+    def _report(self, metrics: dict, checkpoint: Optional[Checkpoint]):
         now = time.perf_counter()
         self.iteration += 1
         interval = max(0.0, now - self._phase_t0)

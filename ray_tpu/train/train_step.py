@@ -18,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel.sharding import logical_sharding
 from ray_tpu.train.optim import AdamWConfig, adamw_init, adamw_update
+from ray_tpu.util import tracing
 
 Params = Any
 TrainState = dict[str, Any]  # {'params', 'opt': {'mu','nu'}, 'step'}
@@ -36,7 +37,8 @@ class _MeshBound:
         self._mesh = mesh
 
     def __call__(self, *args, **kwargs):
-        with jax.sharding.set_mesh(self._mesh):
+        with tracing.device_span("train.step.dispatch"), \
+                jax.sharding.set_mesh(self._mesh):
             return self._jitted(*args, **kwargs)
 
     def lower(self, *args, **kwargs):

@@ -19,11 +19,18 @@ DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
 def ensure_compile_cache() -> str:
-    """Point JAX at the persistent cache; returns the directory in use."""
+    """Point JAX at the persistent cache; returns the directory in use.
+    Every main path comes through here before its first compile, so
+    this is also where the compile listeners attach
+    (``device_telemetry.compile_log``)."""
+    import jax
+
+    from ray_tpu.util import device_telemetry
+
+    device_telemetry.ensure_listeners()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
 
     if jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)

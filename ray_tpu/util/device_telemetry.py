@@ -10,7 +10,9 @@ device. Three surfaces:
   counters, as a plain dict that rides the worker-events RPC batch.
 * compile counters — ``jax.monitoring`` listeners counting backend
   compiles / compile seconds and (persistent) compilation-cache
-  hits/misses, installed once per process on first snapshot.
+  hits/misses, installed once per process where the main path first
+  touches JAX (``ensure_compile_cache``, ``ray_tpu.init``) or on first
+  snapshot; ``compile_log()`` keeps the last compiles one by one.
 * ``capture(duration_s)`` — a timed ``jax.profiler.trace()`` window
   returning the trace directory as ``{relpath: bytes}``, falling back
   to the pure-Python stack sampler (``util/stack_sampler``) when
@@ -26,12 +28,13 @@ process in explicitly.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 _lock = threading.Lock()
 _listeners_installed = False
@@ -51,6 +54,17 @@ _counts = {
     "cache_misses": 0,
     "compile_requests": 0,
 }
+# The last compiles one by one (guarded-by: _lock), oldest first:
+# {"epoch_ns", "seconds", "cache", "fun_name"}. ``cache`` is "hit" (the
+# program came from the persistent cache; ``seconds`` is then the load),
+# "miss" (the cache was asked and the program compiled) or None (the
+# cache was not asked).
+_COMPILE_LOG_MAX = 512
+_compile_log: collections.deque = collections.deque(maxlen=_COMPILE_LOG_MAX)
+# What the persistent cache said about the compile in flight on THIS
+# thread: jax reports the request and the hit as events before the
+# compile's duration, on the compiling thread.
+_pending = threading.local()
 
 # Keys copied out of device.memory_stats() when present (TPU/GPU
 # backends; CPU returns None).
@@ -103,10 +117,12 @@ def _install_listeners() -> None:
         def on_event(name: str, **kw):
             if name.endswith("/cache_hits"):
                 key = "cache_hits"
+                _pending.cache = "hit"
             elif name.endswith("/cache_misses"):
                 key = "cache_misses"
             elif name.endswith("/compile_requests_use_cache"):
                 key = "compile_requests"
+                _pending.cache = "miss"  # until a hit says otherwise
             else:
                 return
             with _lock:
@@ -114,9 +130,15 @@ def _install_listeners() -> None:
 
         def on_duration(name: str, secs: float, **kw):
             if name.endswith("/backend_compile_duration"):
+                entry = {"epoch_ns": time.time_ns(),
+                         "seconds": float(secs),
+                         "cache": getattr(_pending, "cache", None),
+                         "fun_name": kw.get("fun_name")}
+                _pending.cache = None
                 with _lock:
                     _counts["backend_compiles"] += 1
                     _counts["compile_seconds"] += float(secs)
+                    _compile_log.append(entry)
 
         ok = True
         if not _event_registered:
@@ -144,10 +166,12 @@ def _install_listeners() -> None:
 
 def ensure_listeners() -> bool:
     """Attach the compile-counter listeners as soon as jax is importable
-    in this process (idempotent, never imports jax itself). Workers call
-    this from their event-flush tick, so counting starts within ~250ms
-    of jax appearing — compiles issued before the attach (typically the
-    first task's very first jit) are not retroactively countable."""
+    in this process (idempotent, never imports jax itself).
+    ``ensure_compile_cache`` and ``ray_tpu.init`` call this, so on the
+    main paths counting starts before the first program compiles;
+    workers also call it from their event-flush tick, within ~250ms of
+    jax appearing — compiles issued before the attach are not
+    retroactively countable."""
     if not jax_loaded():
         return False
     _install_listeners()
@@ -159,6 +183,15 @@ def compile_counts() -> Dict[str, Any]:
         out = dict(_counts)
     out["compile_seconds"] = round(out["compile_seconds"], 4)
     return out
+
+
+def compile_log() -> List[Dict[str, Any]]:
+    """The last compiles of this process, oldest first (at most
+    ``_COMPILE_LOG_MAX``): when each ended (epoch ns), how long it took,
+    whether the persistent cache was hit or missed, and the function's
+    name where ``jax.monitoring`` passes one."""
+    with _lock:
+        return [dict(e) for e in _compile_log]
 
 
 def _stub(ts: float, error: str | None = None) -> Dict[str, Any]:

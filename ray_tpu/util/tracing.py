@@ -7,7 +7,7 @@ execution, so one trace follows a request through submit → schedule →
 run, across processes. The environment ships only the OpenTelemetry API
 (no SDK), so the span model here is self-contained but OTel-shaped:
 trace_id/span_id/parent_id hex ids, name, start/end ns, attributes,
-status — exportable as JSON lines or a Chrome trace.
+status — exportable as a Chrome trace.
 
 Usage:
     from ray_tpu.util import tracing
@@ -16,6 +16,21 @@ Usage:
         ref = f.remote()              # submit/execute spans attach under it
     spans = tracing.collect()         # this process's finished spans
     tracing.export_chrome_trace("/tmp/trace.json")
+
+Two sinks. The span store above stamps ``time.time_ns()`` and follows a
+request across threads and processes. The second sink is the JAX
+profiler's own trace: :func:`device_span` (always) and :func:`span`
+(while a span is being recorded) enter a ``jax.profiler.TraceAnnotation``
+on the calling thread, so that whoever captures a profile
+(``device_telemetry.capture``, XProf) sees the program's phases on the
+device's clock, with no switch to find. This module never imports jax:
+the annotation exists only where something else already loaded it.
+
+The process-wide switch (``enable``/``disable``) gates ROOT spans only.
+A span with a parent — an explicit ``parent`` context that came in on a
+request, or the thread's current span — is recorded whether or not the
+switch is on: the caller made the sampling decision, and what nests
+under a recorded span belongs to its trace. ``suppressed()`` still wins.
 
 Worker-side spans ride the existing worker-events batching to the node
 agent and head (``rpc_worker_events`` → LOGS-style aggregation), queryable
@@ -26,6 +41,8 @@ from __future__ import annotations
 
 import json
 import os
+import random
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -54,8 +71,17 @@ def is_enabled() -> bool:
     return _enabled
 
 
+# Ids come from a generator seeded from the OS once per process (and
+# again in a forked child). ``os.urandom`` per id releases the GIL, and
+# on a process whose other threads are waiting for it (an engine's
+# pollers, just woken) every span then costs a GIL handoff: 1.0 ms a
+# decode step between two of the loop's phases on the chip (PR 24).
+_ids = random.Random(os.urandom(16))
+os.register_at_fork(after_in_child=lambda: _ids.seed(os.urandom(16)))
+
+
 def _new_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    return "%0*x" % (2 * nbytes, _ids.getrandbits(8 * nbytes))
 
 
 def _record(span: dict) -> None:
@@ -139,6 +165,48 @@ def current_context() -> Optional[dict]:
     return {"trace_id": s["trace_id"], "span_id": s["span_id"]}
 
 
+class _NoSpan:
+    """What :func:`device_span` returns where jax is not loaded."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def device_span(name: str, **attrs):
+    """A span on the device's clock: a ``jax.profiler.TraceAnnotation``
+    where jax is already in ``sys.modules``, nothing otherwise. Always
+    on: an inactive annotation costs well under a microsecond and the
+    profiler decides whether anything is recorded (``attrs`` are only
+    formatted while a profile is being taken). Same-thread only.
+    ``with device_span(...) as ds`` gives an object whose
+    ``ds.set_metadata(**attrs)`` adds what is known only inside."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def _sampled(parent: Optional[dict]) -> bool:
+    """Is a span with this explicit ``parent`` recorded? Roots obey the
+    switch; a span under a parent follows its parent's trace."""
+    if is_suppressed():
+        return False
+    if parent is None:
+        parent = current_context()
+    return bool(parent) or _enabled
+
+
 def _make_span(name: str, attributes: Optional[Dict[str, Any]],
                parent: Optional[dict], cat: Optional[str]) -> dict:
     s = {
@@ -165,7 +233,7 @@ def start_span(name: str, attributes: Optional[Dict[str, Any]] = None,
     async code (where interleaved coroutines on one thread would
     corrupt a context-manager span's restore order). Pass ``parent={}``
     to force a fresh root. Close with :func:`finish_span`."""
-    if not _enabled or is_suppressed():
+    if not _sampled(parent):
         return None
     if parent is None:
         parent = current_context()
@@ -189,8 +257,9 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None,
     process (or None to nest under this thread's active span).
     ``cat`` labels the span's Chrome-trace category (default "span");
     the Serve request path uses ``cat="serve"`` so request traces are
-    filterable from task spans in one merged timeline."""
-    if not _enabled or is_suppressed():
+    filterable from task spans in one merged timeline. A recorded span
+    is also written into the profiler's trace (:func:`device_span`)."""
+    if not _sampled(parent):
         yield None
         return
     if parent is None:
@@ -199,7 +268,8 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None,
     prev = getattr(_current, "span", None)
     _current.span = s
     try:
-        yield s
+        with device_span(name):
+            yield s
     except BaseException as e:
         s["status"] = f"ERROR: {type(e).__name__}"
         raise
@@ -254,78 +324,6 @@ def drain() -> List[dict]:
     """Pop this process's finished spans (used by the worker's event
     flusher to ship spans to the node agent in batches)."""
     return collect(clear=True)
-
-
-def export_jsonl(path: str) -> int:
-    spans = collect()
-    with open(path, "w") as f:
-        for s in spans:
-            f.write(json.dumps(s) + "\n")
-    return len(spans)
-
-
-def export_otel(spans: Optional[List[dict]] = None,
-                tracer_name: str = "ray_tpu") -> int:
-    """Re-emit finished spans through the OpenTelemetry API (reference
-    tracing_helper.py emits OTel spans directly). The environment ships
-    only the OTel API — with no provider configured this is a no-op by
-    OTel's own design; when the application installs a provider (OTLP,
-    Jaeger, ...), the same call exports there.
-
-    Span-id note: an SDK always mints fresh span ids (the API offers no
-    way to force ours), so the TREE is preserved by re-emitting in
-    topological order and parenting each child under the freshly created
-    parent span; only spans whose parent is outside the batch fall back
-    to a remote NonRecordingSpan context with the original ids."""
-    import opentelemetry.trace as ot
-    from opentelemetry.trace import (
-        NonRecordingSpan,
-        SpanContext,
-        TraceFlags,
-        set_span_in_context,
-    )
-
-    spans = spans if spans is not None else collect()
-    tracer = ot.get_tracer(tracer_name)
-    by_id = {s["span_id"]: s for s in spans}
-    created: Dict[str, Any] = {}  # our span_id -> emitted otel span
-    n = 0
-
-    def emit(s: dict):
-        nonlocal n
-        sid = s["span_id"]
-        if sid in created:
-            return created[sid]
-        parent_id = s.get("parent_id")
-        ctx = None
-        if parent_id:
-            if parent_id in by_id:
-                # In-batch parent: emit it first, nest under ITS fresh id.
-                ctx = set_span_in_context(emit(by_id[parent_id]))
-            else:
-                ctx = set_span_in_context(NonRecordingSpan(SpanContext(
-                    trace_id=int(s["trace_id"], 16),
-                    span_id=int(parent_id, 16),
-                    is_remote=True,
-                    trace_flags=TraceFlags(TraceFlags.SAMPLED),
-                )))
-        otel_span = tracer.start_span(
-            s["name"], context=ctx, start_time=s.get("start_ns"),
-            attributes={k: str(v) for k, v in
-                        (s.get("attributes") or {}).items()},
-        )
-        if s.get("status") and s["status"] != "OK":
-            from opentelemetry.trace import Status, StatusCode
-
-            otel_span.set_status(Status(StatusCode.ERROR, s["status"]))
-        otel_span.end(end_time=s.get("end_ns"))
-        created[sid] = otel_span
-        n += 1
-        return otel_span
-
-    for s in spans:
-        emit(s)
-    return n
 
 
 def chrome_events(spans: List[dict]) -> List[dict]:

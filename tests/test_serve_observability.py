@@ -147,9 +147,17 @@ def test_trace_propagation_http_traceparent():
     resp.read()
     conn.close()
 
-    spans = [s for s in tracing.collect()
-             if s["trace_id"] == trace_id and s.get("cat") == "serve"]
-    names = {s["name"] for s in spans}
+    # The proxy finishes its http span after it has sent the reply, so
+    # the client can be here first (it was, in a whole run under load):
+    # wait for the span, do not race it.
+    deadline = time.monotonic() + 10
+    while True:
+        spans = [s for s in tracing.collect()
+                 if s["trace_id"] == trace_id and s.get("cat") == "serve"]
+        names = {s["name"] for s in spans}
+        if "serve.http:/traced" in names or time.monotonic() > deadline:
+            break
+        time.sleep(0.02)
     assert "serve.http:/traced" in names
     assert "serve.route:HttpTraced" in names
     assert any(n.startswith("serve.replica:HttpTraced") for n in names)
