@@ -373,9 +373,25 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 #   ``[rows, prompt_len]`` chunked-prefill lane writing each prompt's
 #   K/V into its slot's cache rows and sampling the FIRST token from the
 #   last real position's logits;
-# * ``gpt2_decode_step``  — one token for every slot: write this token's
-#   K/V at the slot's ring cursor (``lax.dynamic_update_slice`` vmapped
-#   over slots), attend over the valid cache window, next-token logits.
+# * ``gpt2_decode_step``  — one token for every slot: attend over the
+#   valid cache window and this token's own K/V, next-token logits, and
+#   this token's K/V written at the slot's ring cursor.
+#
+# The stacked cache never travels through the layer loop as ``lax.scan``'s
+# ``xs -> ys``; a step writes only its new rows, in place in the buffer
+# the engine donates (the contract and its reasons: ``ops/attention.py``).
+# Which side of the loop writes them was settled per program by reading
+# what the TPU's compiler makes of it (``tests/test_serving_programs_v5e.py``):
+#
+# * decode reads each layer's block as a read-only ``xs``, the loop's
+#   ``ys`` are only the new rows ``[n_layer, S, H, hd]``, and one
+#   ``cache_write`` after the loop puts them at the cursors; attention
+#   takes the new token's score and value beside the old window
+#   (``cached_decode_attention``), so it is the same softmax over the same
+#   keys as a write-then-read. (With the cache in the loop's carry the
+#   compiler re-laid the whole cache out, padded, for the one-row writes.)
+# * prefill reads no cache, so its loop carries the stacked cache and each
+#   layer writes its ``[P, H, hd]`` row blocks in place.
 #
 # Ring semantics: the write cursor is ``pos % cache_len`` and the
 # attention mask covers ``min(pos + 1, cache_len)`` entries — a
@@ -398,10 +414,12 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
     """One decode iteration for every slot.
 
     tokens [S] int32 (the slot's current token), pos [S] int32 (its
-    absolute position). Writes each token's K/V at the slot's ring
-    cursor, attends over the valid window, and returns
-    (logits [S, V] fp32, new cache). Free slots simply compute garbage
-    into their own cache rows — the fixed shape is the point."""
+    absolute position). Attends over the valid window with each token's
+    own K/V in the place its ring cursor names, writes those rows there
+    after the layer loop, and returns (logits [S, V] fp32, new cache:
+    the given one with S rows a layer changed, in place when donated).
+    Free slots simply compute garbage into their own cache rows — the
+    fixed shape is the point."""
     s = tokens.shape[0]
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
     cache_len = cache["k"].shape[2]
@@ -417,35 +435,36 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
                                        cached_decode_attention)
 
     def block(x, layer):
-        p, k_cache, v_cache = layer
+        p, k_cache, v_cache = layer  # read only: the rows go out as ys
         with jax.named_scope("ln"):
             y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         with jax.named_scope("attn_proj"):
             qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-        with jax.named_scope("cache_write"):
-            k_cache = cache_write_token(
-                k_cache, k_new.reshape(s, 1, h, hd), cursor)
-            v_cache = cache_write_token(
-                v_cache, v_new.reshape(s, 1, h, hd), cursor)
+            k_new = k_new.reshape(s, h, hd).astype(k_cache.dtype)
+            v_new = v_new.reshape(s, h, hd).astype(v_cache.dtype)
         with jax.named_scope("attn"):
             attn = cached_decode_attention(
-                q.reshape(s, h, hd), k_cache, v_cache, valid, dt)
+                q.reshape(s, h, hd), k_cache, v_cache, k_new, v_new,
+                cursor, valid, dt)
         with jax.named_scope("attn_proj"):
             x = x + attn.reshape(s, d) @ p["attn_out_w"].astype(dt) \
                 + p["attn_out_b"].astype(dt)
         x = _mlp_block(x, p, dt)
-        return x, (k_cache, v_cache)
+        return x, (k_new, v_new)
 
-    x, (k_all, v_all) = jax.lax.scan(
+    x, (k_rows, v_rows) = jax.lax.scan(
         block, x, (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("cache_write"):
+        cache = {"k": cache_write_token(cache["k"], k_rows, cursor),
+                 "v": cache_write_token(cache["v"], v_rows, cursor)}
     with jax.named_scope("ln"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     with jax.named_scope("head"):
         logits = jnp.einsum(
             "sd,vd->sv", x, params["wte"].astype(dt),
             preferred_element_type=jnp.float32)
-    return logits, {"k": k_all, "v": v_all}
+    return logits, cache
 
 
 def _mlp_block(x: jax.Array, p: Params, dt) -> jax.Array:
@@ -480,8 +499,9 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
             + params["wpe"].astype(dt)[:p_len]
     from ray_tpu.ops.attention import cache_write_prompt
 
-    def block(x, layer):
-        p, k_cache, v_cache = layer
+    def block(carry, layer):
+        x, k_all, v_all = carry  # the stacked cache, written in place
+        p, i = layer
         with jax.named_scope("ln"):
             y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         with jax.named_scope("attn_proj"):
@@ -493,16 +513,17 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
         with jax.named_scope("attn"):
             attn = causal_attention(q, k_, v_, use_flash=False)
         with jax.named_scope("cache_write"):
-            k_cache = cache_write_prompt(k_cache, k_, slots)
-            v_cache = cache_write_prompt(v_cache, v_, slots)
+            k_all = cache_write_prompt(k_all, i, k_, slots)
+            v_all = cache_write_prompt(v_all, i, v_, slots)
         with jax.named_scope("attn_proj"):
             x = x + attn.reshape(r, p_len, d) @ p["attn_out_w"].astype(dt) \
                 + p["attn_out_b"].astype(dt)
         x = _mlp_block(x, p, dt)
-        return x, (k_cache, v_cache)
+        return (x, k_all, v_all), None
 
-    x, (k_all, v_all) = jax.lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, k_all, v_all), _ = jax.lax.scan(
+        block, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cfg.n_layer)))
     with jax.named_scope("ln"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     with jax.named_scope("head"):

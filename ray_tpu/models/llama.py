@@ -274,7 +274,10 @@ def llama_loss(params: Params, batch: dict[str, jax.Array],
 # only ``n_kv_head`` heads are cached (``[n_layer, slots, cache_len,
 # n_kv_head, head_dim]`` in the activation dtype, bf16 by default), and
 # query-head groups re-read the shared KV at attention time, so the GQA
-# bandwidth saving carries straight into serving HBM footprint.
+# bandwidth saving carries straight into serving HBM footprint. The
+# stacked cache stays in place across the layer loop exactly as there:
+# decode's loop reads it and hands out the new rows, written after the
+# loop; prefill's loop carries it and writes each layer's rows in place.
 
 
 def llama_init_cache(cfg: LlamaConfig, slots: int, cache_len: int) -> Params:  # decode-path
@@ -316,35 +319,38 @@ def llama_decode_step(params: Params, cache: Params, tokens: jax.Array,
                                        cached_decode_attention)
 
     def block(x, layer):
-        p, k_cache, v_cache = layer
+        p, k_cache, v_cache = layer  # read only: the rows go out as ys
         y = _rms_norm(x, p["attn_norm"])
         q = _rope_at((y @ p["wq"].astype(dt)).reshape(s, nh, hd),
                      pos, cfg.rope_theta)
         k_new = _rope_at((y @ p["wk"].astype(dt)).reshape(s, nkv, hd),
-                         pos, cfg.rope_theta)
-        v_new = (y @ p["wv"].astype(dt)).reshape(s, nkv, hd)
-        k_cache = cache_write_token(k_cache, k_new[:, None], cursor)
-        v_cache = cache_write_token(v_cache, v_new[:, None], cursor)
+                         pos, cfg.rope_theta).astype(k_cache.dtype)
+        v_new = (y @ p["wv"].astype(dt)).reshape(
+            s, nkv, hd).astype(v_cache.dtype)
         # GQA: expand the cached KV heads to the query heads at read
         # time (the cache itself stays n_kv_head wide).
         rep = nh // nkv
         attn = cached_decode_attention(
             q, jnp.repeat(k_cache, rep, axis=2),
-            jnp.repeat(v_cache, rep, axis=2), valid, dt)
+            jnp.repeat(v_cache, rep, axis=2),
+            jnp.repeat(k_new, rep, axis=1), jnp.repeat(v_new, rep, axis=1),
+            cursor, valid, dt)
         x = x + attn.reshape(s, nh * hd) @ p["wo"].astype(dt)
         y = _rms_norm(x, p["mlp_norm"])
         gate = y @ p["w_gate"].astype(dt)
         up = y @ p["w_up"].astype(dt)
         x = x + (jax.nn.silu(gate) * up) @ p["w_down"].astype(dt)
-        return x, (k_cache, v_cache)
+        return x, (k_new, v_new)
 
-    x, (k_all, v_all) = jax.lax.scan(
+    x, (k_rows, v_rows) = jax.lax.scan(
         block, x, (params["blocks"], cache["k"], cache["v"]))
+    cache = {"k": cache_write_token(cache["k"], k_rows, cursor),
+             "v": cache_write_token(cache["v"], v_rows, cursor)}
     x = _rms_norm(x, params["final_norm"])
     logits = jnp.einsum(
         "sd,dv->sv", x, params["lm_head"].astype(dt),
         preferred_element_type=jnp.float32)
-    return logits, {"k": k_all, "v": v_all}
+    return logits, cache
 
 
 # jax-hot-path: traced into the engine's single compiled prefill lane
@@ -361,16 +367,17 @@ def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
     x = params["embed"].astype(dt)[tokens]
     from ray_tpu.ops.attention import cache_write_prompt
 
-    def block(x, layer):
-        p, k_cache, v_cache = layer
+    def block(carry, layer):
+        x, k_all, v_all = carry  # the stacked cache, written in place
+        p, i = layer
         y = _rms_norm(x, p["attn_norm"])
         q = _rope((y @ p["wq"].astype(dt)).reshape(r, p_len, nh, hd),
                   cfg.rope_theta)
         k_ = _rope((y @ p["wk"].astype(dt)).reshape(r, p_len, nkv, hd),
                    cfg.rope_theta)
         v_ = (y @ p["wv"].astype(dt)).reshape(r, p_len, nkv, hd)
-        k_cache = cache_write_prompt(k_cache, k_, slots)
-        v_cache = cache_write_prompt(v_cache, v_, slots)
+        k_all = cache_write_prompt(k_all, i, k_, slots)
+        v_all = cache_write_prompt(v_all, i, v_, slots)
         rep = nh // nkv
         attn = causal_attention(
             q, jnp.repeat(k_, rep, axis=2), jnp.repeat(v_, rep, axis=2),
@@ -380,10 +387,11 @@ def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
         gate = y @ p["w_gate"].astype(dt)
         up = y @ p["w_up"].astype(dt)
         x = x + (jax.nn.silu(gate) * up) @ p["w_down"].astype(dt)
-        return x, (k_cache, v_cache)
+        return (x, k_all, v_all), None
 
-    x, (k_all, v_all) = jax.lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, k_all, v_all), _ = jax.lax.scan(
+        block, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rms_norm(x, params["final_norm"])
     last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]
     logits = jnp.einsum(

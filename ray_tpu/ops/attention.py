@@ -97,55 +97,88 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
     )(q, k, v)
 
 
-# -- KV-cache writes (serving decode path) ----------------------------------
+# -- KV cache of the serving path ---------------------------------------------
 #
 # Shared by the GPT-2 and Llama decode APIs (``models/gpt2.py`` /
 # ``models/llama.py``): the head-count axis differs (full vs GQA
-# ``n_kv_head``) but the cursor-write contract is identical, so it lives
-# here once.
+# ``n_kv_head``) but the contract is identical, so it lives here once.
+#
+# The cache is ONE stacked array ``[n_layer, S, L, H, hd]`` per K and V,
+# donated by the engine. Inside the layer loop it is only read; the only
+# bytes written in a step are the new rows, and they go into the stacked
+# buffer in place: never cut a layer's ``[S, L, H, hd]`` block out of the
+# stack, write into it and put it back (the cache as ``lax.scan``'s
+# ``xs -> ys``), which copies the whole cache twice a step (PERF.md
+# section 6, PR 25). Both writes are static-count ``dynamic_update_slice``s
+# whose update is row-sized. On the TPU the stacked cache lies with
+# ``cache_len`` minor-most (the runtime's compact layout where ``H x hd``
+# would pad), and XLA keeps that layout through these updates; a scatter,
+# or the same updates under a ``fori_loop``, make it re-lay out the whole
+# cache around the write.
 
 
 # decode-path  # jax-hot-path: the KV cache stays in the activation dtype
 def cache_write_token(cache: jax.Array, rows: jax.Array,
                       cursor: jax.Array) -> jax.Array:
-    """Per-slot ring-cursor write of ONE token's K or V rows.
+    """Ring-cursor write of ONE token's K or V rows, every layer at once,
+    after the layer loop.
 
-    cache [S, L, H, hd], rows [S, 1, H, hd], cursor [S] int32 — each
-    slot's row lands at its own cursor (vmapped dynamic_update_slice)."""
-    return jax.vmap(
-        lambda c, r, i: jax.lax.dynamic_update_slice(
-            c, r.astype(c.dtype), (i, 0, 0))
-    )(cache, rows, cursor)
+    cache [N, S, L, H, hd], rows [N, S, H, hd] (the loop's stacked new
+    rows), cursor [S] int32 — slot ``s``'s rows land at
+    ``cache[:, s, cursor[s]]``; nothing else of the cache is touched."""
+    rows = rows.astype(cache.dtype)
+    for s in range(rows.shape[1]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[:, s, None, None], (0, s, cursor[s], 0, 0))
+    return cache
 
 
 # decode-path  # jax-hot-path: the KV cache stays in the activation dtype
-def cache_write_prompt(cache: jax.Array, rows: jax.Array,
+def cache_write_prompt(cache: jax.Array, layer: jax.Array, rows: jax.Array,
                        slots: jax.Array) -> jax.Array:
-    """Prefill-lane write: row block ``rows[i]`` ([P, H, hd]) lands at
-    rows ``[0, P)`` of cache slot ``slots[i]``. Sequential over the
-    (small, static) prefill-row axis — each write must see the prior
-    ones, and distinct slots make the order immaterial."""
-    def body(i, c):
-        return jax.lax.dynamic_update_slice(
-            c, rows[i][None].astype(c.dtype), (slots[i], 0, 0, 0))
-    return jax.lax.fori_loop(0, rows.shape[0], body, cache)
+    """Prefill-lane write of one layer's rows, inside the layer loop
+    (which carries the stacked cache): row block ``rows[i]`` ([P, H, hd])
+    lands at ``cache[layer, slots[i], 0:P]`` of cache [N, S, L, H, hd].
+    Sequential over the (small, static) prefill-row axis — each write
+    must see the prior ones, and distinct slots make the order
+    immaterial (rows that share the scratch slot write only garbage
+    there)."""
+    rows = rows.astype(cache.dtype)
+    for i in range(rows.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[i, None, None], (layer, slots[i], 0, 0, 0))
+    return cache
 
 
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                            valid: jax.Array, out_dtype) -> jax.Array:
-    """One query token per slot over the slot's ring-cache window.
+                            k_new: jax.Array, v_new: jax.Array,
+                            cursor: jax.Array, valid: jax.Array,
+                            out_dtype) -> jax.Array:
+    """One query token per slot over the slot's ring-cache window, the
+    token itself included, WITHOUT its row being in the cache yet.
 
-    q [S, H, hd]; k/v [S, L, H, hd] (GQA callers expand KV heads to the
-    query heads first); valid [S] = live cache entries (the ring mask).
-    fp32 scores/softmax, output cast to the activation dtype — shared
-    by both model families' decode steps so the masking/scaling
-    contract lives here once."""
+    q [S, H, hd]; k/v [S, L, H, hd], the layer's cache as it was before
+    this step (read only); k_new/v_new [S, H, hd], this token's rows in
+    the cache's dtype (GQA callers expand KV heads to the query heads
+    first, here as there); cursor [S] = the ring row this token will
+    take; valid [S] = live cache entries with it (the ring mask, always
+    > cursor). The score at the cursor is the new token's and its value
+    is added beside the window's, so it is the same softmax over the
+    same keys as if the row had been written first — a wrapped ring
+    drops the row the cursor overwrites, as ever. fp32 scores/softmax,
+    output cast to the activation dtype — shared by both model families'
+    decode steps so the masking/scaling contract lives here once."""
     hd = q.shape[-1]
-    scores = jnp.einsum(
-        "shd,slhd->shl", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) / (hd ** 0.5)
-    mask = jnp.arange(k.shape[1])[None, :] < valid[:, None]  # [S, L]
-    weights = jax.nn.softmax(
-        jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
-    out = jnp.einsum("shl,slhd->shd", weights, v.astype(jnp.float32))
+    q = q.astype(jnp.float32)
+    idx = jnp.arange(k.shape[1])
+    at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
+    mask = (idx[None, :] < valid[:, None])[:, None, :]
+    scores = jnp.einsum("shd,slhd->shl", q, k.astype(jnp.float32))
+    score_new = jnp.sum(q * k_new.astype(jnp.float32), axis=-1)  # [S, H]
+    scores = jnp.where(at_cursor, score_new[..., None], scores) / (hd ** 0.5)
+    weights = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    weight_new = jnp.sum(jnp.where(at_cursor, weights, 0.0), axis=-1)
+    out = jnp.einsum("shl,slhd->shd", jnp.where(at_cursor, 0.0, weights),
+                     v.astype(jnp.float32))
+    out = out + weight_new[..., None] * v_new.astype(jnp.float32)
     return out.astype(out_dtype)

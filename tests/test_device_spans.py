@@ -430,6 +430,107 @@ def test_scopes_of_the_serving_programs(which):
     assert BLOCK | {"cache_write", "head"} <= _scopes_in(lowered)
 
 
+# -- the cache stays in place across the layer loop (PR 25) ----------------------
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else (value,)):
+            inner = getattr(item, "jaxpr", item)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _cache_moves(jaxpr, cache_shape):
+    """Every way ``jaxpr`` (and what it calls) makes a value of the stacked
+    cache's shape other than by writing rows into one: the faults of the
+    contract. A row write is a ``dynamic_update_slice`` or scatter whose
+    update is smaller than ONE slot's block of ONE layer."""
+    slot_block = 1
+    for dim in cache_shape[2:]:
+        slot_block *= dim
+    faults = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        outs = [getattr(v.aval, "shape", None) for v in eqn.outvars]
+        if name == "scan":
+            carries = eqn.params["num_carry"]
+            if cache_shape in outs[carries:]:
+                faults.append("the cache is a stacked output (ys) of the "
+                              "layer loop")
+        elif cache_shape in outs and not list(_sub_jaxprs(eqn)):
+            rows = name == "dynamic_update_slice" or name.startswith("scatter")
+            update = eqn.invars[2 if name.startswith("scatter") else 1].aval
+            if not rows:
+                faults.append(f"{name} makes a whole cache")
+            elif update.size >= slot_block:
+                faults.append(f"{name} writes {update.shape}, not rows")
+        for inner in _sub_jaxprs(eqn):
+            faults += _cache_moves(inner, cache_shape)
+    return faults
+
+
+def _serving_program(family, which):
+    from ray_tpu.models import llama
+
+    if family == "gpt2":
+        cfg, init, init_cache = TINY, gpt2.gpt2_init, gpt2.gpt2_init_cache
+        decode, prefill = gpt2.gpt2_decode_step, gpt2.gpt2_prefill
+    else:
+        cfg = dataclasses.replace(llama.LlamaConfig.tiny(),
+                                  dtype=jnp.float32)
+        init, init_cache = llama.llama_init, llama.llama_init_cache
+        decode, prefill = llama.llama_decode_step, llama.llama_prefill
+    params = init(jax.random.PRNGKey(0), cfg)
+    cache = init_cache(cfg, 3, 32)
+    if which == "decode":
+        return jax.make_jaxpr(lambda p, c, t, n: decode(p, c, t, n, cfg))(
+            params, cache, jnp.zeros(3, jnp.int32),
+            jnp.zeros(3, jnp.int32)), cache["k"].shape
+    return jax.make_jaxpr(lambda p, c, t, s, n: prefill(p, c, t, s, n, cfg))(
+        params, cache, jnp.zeros((2, 8), jnp.int32),
+        jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32)), cache["k"].shape
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_the_cache_is_only_written_by_rows(family, which):
+    """No stacked output of the layer loop has the cache's shape, and
+    whatever makes a cache-shaped value anywhere in the program writes
+    rows into the one it was given. (What the TPU's compiler makes of it,
+    no layer-sized copy in the loop and the temp space, is the
+    compile-only rehearsal's to say: PERF.md section 6, PR 25.)"""
+    closed, cache_shape = _serving_program(family, which)
+    assert _cache_moves(closed.jaxpr, cache_shape) == []
+    loops = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(loops) == 1 and loops[0].params["length"] == cache_shape[0]
+
+
+def test_the_guard_refuses_the_cache_through_xs_and_ys():
+    """The form this PR took out: each layer's block cut out of the stack
+    as ``xs``, written, and stacked again as ``ys``."""
+    cache = gpt2.gpt2_init_cache(TINY, 3, 32)["k"]
+    rows = jnp.ones((3, 1) + cache.shape[3:], cache.dtype)
+
+    def through(cache):
+        def layer(x, block):
+            return x, jax.lax.dynamic_update_slice(block, rows, (0, 5, 0, 0))
+        return jax.lax.scan(layer, 0.0, cache)[1]
+
+    faults = _cache_moves(jax.make_jaxpr(through)(cache).jaxpr, cache.shape)
+    assert faults == ["the cache is a stacked output (ys) of the layer loop"]
+
+    def whole_layer(cache):   # in the carry, but a block at a time
+        def layer(c, i):
+            block = jax.lax.dynamic_index_in_dim(c, i, 0) + 1.0
+            return jax.lax.dynamic_update_slice(c, block, (i, 0, 0, 0, 0)), None
+        return jax.lax.scan(layer, cache, jnp.arange(cache.shape[0]))[0]
+
+    faults = _cache_moves(jax.make_jaxpr(whole_layer)(cache).jaxpr,
+                          cache.shape)
+    assert len(faults) == 1 and "not rows" in faults[0]
+
+
 # -- the compile log --------------------------------------------------------------
 
 
@@ -452,12 +553,13 @@ def test_compile_log_sees_one_miss_then_one_hit(tmp_path):
         def pr24_logged_fn(x):
             return jnp.sin(x) * 24.0 + 1.0
 
-        n0 = len(device_telemetry.compile_log())
         t0 = time.time_ns()
         for _ in range(2):
             jax.jit(pr24_logged_fn)(jnp.arange(8.0)).block_until_ready()
             jax.clear_caches()           # the in-memory cache, not the disk
-        mine = [e for e in device_telemetry.compile_log()[n0:]
+        # by name, not by position: the log keeps the last 512 compiles,
+        # and a worker that has run other files first has filled it
+        mine = [e for e in device_telemetry.compile_log()
                 if "pr24_logged_fn" in (e["fun_name"] or "")]
     finally:
         for k, v in saved.items():

@@ -154,6 +154,268 @@ def test_engine_generate_matches_naive_both_models():
             eng.shutdown_engine()
 
 
+# -- the cache contract, held to a plain oracle ----------------------------------
+#
+# The oracle is the form the serving functions had before the cache stopped
+# travelling through the layer loop (PR 25): a Python loop over the layers
+# that takes the layer's block of the cache, writes the new rows at the
+# cursor (or at rows [0, P) of the target slot), attends over the block
+# under the mask ``idx < valid``, and stacks the blocks again. Each family
+# gives it its own projections; the cache logic is written once.
+
+
+def _oracle_attention(q, k, v, valid):
+    """q [S, H, hd] over k/v [S, L, H, hd], rows idx < valid[s]."""
+    scores = jnp.einsum("shd,slhd->shl", q, k) / (q.shape[-1] ** 0.5)
+    mask = jnp.arange(k.shape[1])[None, :] < valid[:, None]
+    weights = jax.nn.softmax(
+        jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
+    return jnp.einsum("shl,slhd->shd", weights, v)
+
+
+class _Gpt2Oracle:
+    name, cfg = "gpt2", GPT2_FP32
+    init, init_cache = gpt2.gpt2_init, gpt2.gpt2_init_cache
+    prefill, decode = gpt2.gpt2_prefill, gpt2.gpt2_decode_step
+
+    @staticmethod
+    def embed(params, tokens, pos, cfg):
+        return params["wte"][tokens] + params["wpe"][
+            jnp.clip(pos, 0, cfg.seq_len - 1)]
+
+    @staticmethod
+    def qkv(x, p, pos, cfg):
+        y = gpt2._layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+        q, k, v = jnp.split(y @ p["attn_qkv_w"] + p["attn_qkv_b"], 3, -1)
+        heads = lambda a: a.reshape(*a.shape[:-1], cfg.n_head, cfg.head_dim)
+        return heads(q), heads(k), heads(v)
+
+    @staticmethod
+    def finish(x, attn, p, cfg):
+        x = x + attn @ p["attn_out_w"] + p["attn_out_b"]
+        return gpt2._mlp_block(x, p, x.dtype)
+
+    @staticmethod
+    def head(x, params):
+        x = gpt2._layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        return x @ params["wte"].T
+
+
+class _LlamaOracle:
+    name, cfg = "llama", LLAMA_FP32
+    init, init_cache = llama.llama_init, llama.llama_init_cache
+    prefill, decode = llama.llama_prefill, llama.llama_decode_step
+
+    @staticmethod
+    def embed(params, tokens, pos, cfg):
+        return params["embed"][tokens]
+
+    @staticmethod
+    def qkv(x, p, pos, cfg):
+        y = llama._rms_norm(x, p["attn_norm"])
+        nh, nkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+        q = (y @ p["wq"]).reshape(*y.shape[:-1], nh, hd)
+        k = (y @ p["wk"]).reshape(*y.shape[:-1], nkv, hd)
+        v = (y @ p["wv"]).reshape(*y.shape[:-1], nkv, hd)
+        if y.ndim == 2:  # decode: one token a slot, at its own position
+            rope = lambda a: llama._rope_at(a, pos, cfg.rope_theta)
+        else:            # prefill: positions 0..P-1
+            rope = lambda a: llama._rope(a, cfg.rope_theta)
+        return rope(q), rope(k), v
+
+    @staticmethod
+    def finish(x, attn, p, cfg):
+        x = x + attn @ p["wo"]
+        y = llama._rms_norm(x, p["mlp_norm"])
+        return x + (jax.nn.silu(y @ p["w_gate"]) * (y @ p["w_up"])) \
+            @ p["w_down"]
+
+    @staticmethod
+    def head(x, params):
+        return llama._rms_norm(x, params["final_norm"]) @ params["lm_head"]
+
+
+FAMILIES = pytest.mark.parametrize(
+    "fam", [_Gpt2Oracle, _LlamaOracle], ids=lambda f: f.name)
+
+
+def _oracle_decode(fam, params, cache, tokens, pos):
+    cfg = fam.cfg
+    s, cache_len = tokens.shape[0], cache["k"].shape[2]
+    cursor, valid = pos % cache_len, jnp.minimum(pos + 1, cache_len)
+    rep = cfg.n_head // cache["k"].shape[3]
+    x = fam.embed(params, tokens, pos, cfg)
+    ks, vs = [], []
+    for i in range(cfg.n_layer):
+        p = jax.tree.map(lambda a: a[i], params["blocks"])
+        q, k_new, v_new = fam.qkv(x, p, pos, cfg)
+        k = cache["k"][i].at[jnp.arange(s), cursor].set(k_new)
+        v = cache["v"][i].at[jnp.arange(s), cursor].set(v_new)
+        attn = _oracle_attention(q, jnp.repeat(k, rep, axis=2),
+                                 jnp.repeat(v, rep, axis=2), valid)
+        x = fam.finish(x, attn.reshape(s, -1), p, cfg)
+        ks.append(k)
+        vs.append(v)
+    return fam.head(x, params), {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+
+
+def _oracle_prefill(fam, params, cache, tokens, slots, lengths):
+    cfg = fam.cfg
+    r, p_len = tokens.shape
+    rep = cfg.n_head // cache["k"].shape[3]
+    x = fam.embed(params, tokens, jnp.arange(p_len), cfg)
+    causal = jnp.tril(jnp.ones((p_len, p_len), bool))
+    ks, vs = [], []
+    for i in range(cfg.n_layer):
+        p = jax.tree.map(lambda a: a[i], params["blocks"])
+        q, k_, v_ = fam.qkv(x, p, None, cfg)
+        k, v = cache["k"][i], cache["v"][i]
+        for row in range(r):
+            k = k.at[slots[row], :p_len].set(k_[row])
+            v = v.at[slots[row], :p_len].set(v_[row])
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k_, rep, 2)) \
+            / (cfg.head_dim ** 0.5)
+        weights = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", weights, jnp.repeat(v_, rep, 2))
+        x = fam.finish(x, attn.reshape(r, p_len, -1), p, cfg)
+        ks.append(k)
+        vs.append(v)
+    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]
+    return fam.head(last, params), {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+
+
+def _garbage_cache(fam, slots, cache_len, seed):
+    """A cache whose every row holds noise, as a recycled slot's does."""
+    cache = fam.init_cache(fam.cfg, slots, cache_len)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return {n: 3.0 * jax.random.normal(k, cache[n].shape, cache[n].dtype)
+            for n, k in zip(("k", "v"), keys)}
+
+
+def _live_rows(cache, live, pos):
+    """What a later step may read: rows < min(pos + 1, L) of live slots."""
+    cache_len = cache["k"].shape[2]
+    return [np.asarray(cache[n][:, s, :min(int(pos[s]) + 1, cache_len)])
+            for n in ("k", "v") for s in live]
+
+
+CACHE_LEN = 8
+LIVE = (0, 2)            # slots 1 and 3 are free and hold garbage
+START = np.array([5, 0, 2, 0], np.int32)   # slot 0 wraps first, at pos 8
+
+
+@FAMILIES
+@pytest.mark.parametrize("steps", [2, 4, 14],
+                         ids=["before_wrap", "wrap_step", "ten_after_wrap"])
+def test_decode_step_matches_oracle(fam, steps):
+    """Logits and the cache's live rows agree with the scan-through
+    oracle to 1e-5 before the ring wraps, on the step whose cursor wraps
+    to row 0 (slot 0: pos 8 in a cache of 8), and ten steps later, when
+    both live slots have wrapped."""
+    params = fam.init(jax.random.PRNGKey(3), fam.cfg)
+    got_cache = _garbage_cache(fam, 4, CACHE_LEN, seed=7)
+    want_cache = jax.tree.map(jnp.copy, got_cache)
+    step = jax.jit(lambda p, c, t, n: fam.decode(p, c, t, n, fam.cfg))
+    oracle = jax.jit(lambda p, c, t, n: _oracle_decode(fam, p, c, t, n))
+    rng = np.random.default_rng(11)
+    advance = np.isin(np.arange(4), LIVE).astype(np.int32)
+    # The live slots' earlier rows are whatever the noise is: both sides
+    # start from the same cache, so the window is the same on both.
+    for i in range(steps):
+        pos = START + i * advance   # a new array a step: jax may alias it
+        tokens = jnp.asarray(rng.integers(1, 200, 4), jnp.int32)
+        got, got_cache = step(params, got_cache, tokens, jnp.asarray(pos))
+        want, want_cache = oracle(params, want_cache, tokens,
+                                  jnp.asarray(pos))
+    assert int(pos[0]) == START[0] + steps - 1
+    wrapped = [int(pos[s]) >= CACHE_LEN for s in LIVE]
+    assert wrapped == {2: [False, False], 4: [True, False],
+                       14: [True, True]}[steps]
+    np.testing.assert_allclose(np.asarray(got)[list(LIVE)],
+                               np.asarray(want)[list(LIVE)],
+                               rtol=1e-5, atol=1e-5)
+    for g, w in zip(_live_rows(got_cache, LIVE, pos),
+                    _live_rows(want_cache, LIVE, pos)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+@FAMILIES
+def test_free_slots_garbage_never_reaches_live_logits(fam):
+    """Two caches that differ in every row of the free slots (and in what
+    the free slots are fed) give the live slots the same logits."""
+    params = fam.init(jax.random.PRNGKey(3), fam.cfg)
+    a = _garbage_cache(fam, 4, CACHE_LEN, seed=7)
+    b = _garbage_cache(fam, 4, CACHE_LEN, seed=8)
+    live = jnp.asarray(LIVE)
+    b = {n: b[n].at[:, live].set(a[n][:, live]) for n in ("k", "v")}
+    pos = jnp.asarray(START + np.array([6, 0, 1, 0]), jnp.int32)  # 0 wrapped
+    tok_a = jnp.asarray([17, 0, 23, 0], jnp.int32)
+    tok_b = jnp.asarray([17, 99, 23, 5], jnp.int32)
+    pos_b = pos.at[jnp.asarray([1, 3])].set(jnp.asarray([6, 40]))
+    la, _ = fam.decode(params, a, tok_a, pos, fam.cfg)
+    lb, _ = fam.decode(params, b, tok_b, pos_b, fam.cfg)
+    np.testing.assert_array_equal(np.asarray(la)[list(LIVE)],
+                                  np.asarray(lb)[list(LIVE)])
+
+
+@FAMILIES
+def test_prefill_scratch_rows_leave_other_slots_untouched(fam):
+    """Two real rows and two rows pointed at the scratch slot: the real
+    rows' slots hold the oracle's K/V in rows [0, P) and their old rows
+    beyond, and every other slot but the scratch one is as it was."""
+    params = fam.init(jax.random.PRNGKey(3), fam.cfg)
+    before = _garbage_cache(fam, 6, 16, seed=9)     # slot 5 is the scratch
+    tokens = np.zeros((4, 8), np.int32)
+    tokens[0, :5], tokens[1, :3] = PROMPT, [7, 1, 4]
+    slots = jnp.asarray([3, 1, 5, 5], jnp.int32)
+    lengths = jnp.asarray([5, 3, 1, 1], jnp.int32)
+    got, after = fam.prefill(params, jax.tree.map(jnp.copy, before),
+                             jnp.asarray(tokens), slots, lengths, fam.cfg)
+    want, oracle_after = _oracle_prefill(
+        fam, params, before, jnp.asarray(tokens), slots, lengths)
+    np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2],
+                               rtol=1e-5, atol=1e-5)
+    for n in ("k", "v"):
+        for slot in (0, 2, 4):
+            np.testing.assert_array_equal(np.asarray(after[n][:, slot]),
+                                          np.asarray(before[n][:, slot]))
+        for slot in (3, 1):
+            np.testing.assert_allclose(
+                np.asarray(after[n][:, slot, :8]),
+                np.asarray(oracle_after[n][:, slot, :8]),
+                rtol=1e-5, atol=1e-5)
+            np.testing.assert_array_equal(
+                np.asarray(after[n][:, slot, 8:]),
+                np.asarray(before[n][:, slot, 8:]))
+
+
+@FAMILIES
+def test_decode_after_prefill_reads_the_rows_prefill_wrote(fam):
+    """Prefill then one decode step agree with the oracle's pair, and the
+    step's logits move when a prefilled row of the live slot is changed
+    (so it is the cache the step reads, not a copy of the prompt)."""
+    params = fam.init(jax.random.PRNGKey(3), fam.cfg)
+    cache = fam.init_cache(fam.cfg, 4, 16)
+    tokens = np.zeros((2, 8), np.int32)
+    tokens[0, :5] = PROMPT
+    args = (jnp.asarray(tokens), jnp.asarray([2, 3], jnp.int32),
+            jnp.asarray([5, 1], jnp.int32))
+    first, got_cache = fam.prefill(params, cache, *args, fam.cfg)
+    want_first, want_cache = _oracle_prefill(fam, params, cache, *args)
+    cur = jnp.zeros(4, jnp.int32).at[2].set(jnp.argmax(first[0]))
+    pos = jnp.zeros(4, jnp.int32).at[2].set(5)
+    got, _ = fam.decode(params, got_cache, cur, pos, fam.cfg)
+    want, _ = _oracle_decode(fam, params, want_cache, cur, pos)
+    np.testing.assert_allclose(np.asarray(first[0]),
+                               np.asarray(want_first[0]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-5, atol=1e-5)
+    bent = {"k": got_cache["k"].at[:, 2, 1].add(1.0), "v": got_cache["v"]}
+    moved, _ = fam.decode(params, bent, cur, pos, fam.cfg)
+    assert float(jnp.max(jnp.abs(moved[2] - got[2]))) > 1e-3
+
+
 # -- scheduler: slots, admission, deadlines ---------------------------------
 
 

@@ -1,0 +1,135 @@
+"""What the TPU's compiler makes of the two serving programs (PR 25).
+
+Compile-only, for one described v5e chip, at GPT-2 XL's published widths
+and the benchmark deployment's shapes (8 slots and the scratch one, a
+1024-row cache, a [4, 768] prefill lane): nothing runs, so nothing here is
+a time. It holds what ``test_the_cache_is_only_written_by_rows`` cannot
+see from the jaxpr: that XLA keeps the stacked cache's layout through the
+row writes (a scatter, or the same updates under a ``fori_loop``, made it
+re-lay out the whole cache around them), so the layer loop moves no
+layer-sized block and the program's temp space holds no second cache.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import gpt2
+
+XL = gpt2.GPT2Config(vocab_size=50304, n_layer=48, n_head=25, d_model=1600,
+                     seq_len=1024)
+SLOTS, CACHE_LEN, ROWS, PROMPT_LEN = 9, 1024, 4, 768
+LAYER_BLOCK = SLOTS * CACHE_LEN * XL.n_head * XL.head_dim
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such
+    a compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: gpt2.gpt2_init(jax.random.PRNGKey(0), XL)))
+    cache = sds(jax.eval_shape(
+        lambda: gpt2.gpt2_init_cache(XL, SLOTS, CACHE_LEN)))
+    programs = {
+        "decode": (lambda p, c, t, n: gpt2.gpt2_decode_step(p, c, t, n, XL),
+                   (params, cache, i32(SLOTS), i32(SLOTS))),
+        "prefill": (lambda p, c, t, s, n: gpt2.gpt2_prefill(
+            p, c, t, s, n, XL),
+                    (params, cache, i32(ROWS, PROMPT_LEN), i32(ROWS),
+                     i32(ROWS))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+                for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+BLOCK_DIMS = f"{SLOTS},{CACHE_LEN},{XL.n_head},{XL.head_dim}"
+PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple")
+
+
+def _loop_bodies(hlo_text):
+    """The computations a ``while`` runs as its body."""
+    bodies = set(re.findall(r"\bwhile\(.*?body=(%[\w.\-]+)", hlo_text))
+    for block in hlo_text.split("\n\n"):
+        if block.lstrip().split(" ", 1)[0] in bodies:
+            yield block
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_layer_sized_block_is_moved_inside_the_layer_loop(compiled, which):
+    """In the parent's programs the loop held ``copy.31/33/34/35`` (a
+    layer's block of the cache re-laid out on its way in and out), a
+    ``dynamic-slice`` that cut it out of the stack and a
+    ``dynamic-update-slice`` that put it back. Now nothing in a loop body
+    makes a layer's block, and what makes a whole cache there (the
+    prefill's in-place row writes) takes no layer's block to do it."""
+    made_block = re.compile(
+        rf"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[(1,)?{BLOCK_DIMS}\]\S* ([\w\-]+)\(")
+    made_cache = re.compile(
+        rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[{XL.n_layer},{BLOCK_DIMS}\]\S* "
+        r"([\w\-]+)\((.*)")
+    moved, lines = [], 0
+    for body in _loop_bodies(compiled[which].as_text()):
+        blocks = set()
+        for line in body.splitlines()[1:]:
+            lines += 1
+            m = made_block.match(line)
+            if m:
+                blocks.add(m.group(1))
+                if m.group(3) not in PASSES_ON:
+                    moved.append(line.strip()[:140])
+        for line in body.splitlines()[1:]:
+            m = made_cache.match(line)
+            if m and m.group(1) not in PASSES_ON and \
+                    blocks & set(re.findall(r"%[\w.\-]+", m.group(2))):
+                moved.append(line.strip()[:140])
+    assert lines > 20, "found no layer loop to read"
+    assert moved == []
+
+
+@pytest.mark.parametrize("which,parent_gb", [("decode", 6.03),
+                                             ("prefill", 6.49)])
+def test_temp_space_holds_no_second_cache(compiled, which, parent_gb):
+    """The stacked cache is 2.83 GB and is updated in the donated buffer;
+    the bfloat16 copy of the weights, 3.1 GB, stays in temp space (the
+    next PR's). The parent's programs took 6.03 and 6.49 GB."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = 2 * XL.n_layer * LAYER_BLOCK * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 3.6e9 < parent_gb * 1e9
