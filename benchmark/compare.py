@@ -62,7 +62,7 @@ def check_serve(run, engine: dict) -> dict:
     sample = config["reference_check"]
     lens = [min(n, engine["max_prompt_len"]) for n in sample["prompt_lens"]]
     n_follow = int(sample["follow"])
-    vocab = config["vocab_size"]
+    vocab = run.family.shape(config)["vocab"]
     rng = run.rng("reference_check")
     rows = np.arange(len(lens))
     prompts = np.zeros((len(lens), engine["max_prompt_len"]), np.int32)

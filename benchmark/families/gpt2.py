@@ -5,9 +5,22 @@ The configuration file holds the released ``config.json``'s keys
 (``n_layer``, ``n_embd`` ...) and, under ``assumed``, what the file had to
 set beyond them. Nothing here sets ``param_dtype`` or a path flag the file
 does not name: how the program stores and runs the model is its business.
+
+This file is also the one place that knows the family's arithmetic: the
+kinds and the metric readers ask it for parameters, cache bytes, the bytes
+of a decode step, the operations of a trained token and the attention
+calls of a step, and hold no formula of their own. The formulas are
+``benchmark/shapes.py``'s. ``README.md`` beside this file lists the whole
+interface and who calls what.
 """
 
 from __future__ import annotations
+
+from benchmark import shapes
+
+# The keys a configuration file of this family may carry under ``assumed``
+# (beside notes whose key ends in ``why``).
+ASSUMED = frozenset({"vocab_rows", "remat", "scan_layers", "use_flash"})
 
 # Block weights: the system's name -> the released checkpoint's name.
 BLOCK_NAMES = {
@@ -32,6 +45,47 @@ def shape(config: dict) -> dict:
         "n_positions": config["n_positions"],
         "kv_dtype_bytes": 2,
     }
+
+
+def param_count(config: dict) -> int:
+    """Parameters as the system holds them (padding rows included)."""
+    sh = shape(config)
+    return shapes.gpt2_param_count(
+        sh["n_layer"], sh["d_model"], sh["vocab_rows"], sh["n_positions"])
+
+
+def cache_bytes(config: dict, slots: int, cache_len: int) -> float:
+    """Bytes of a K/V cache of ``slots`` slots, by shape."""
+    sh = shape(config)
+    return slots * shapes.kv_bytes_per_slot(
+        sh["n_layer"], sh["d_model"], cache_len, sh["kv_dtype_bytes"])
+
+
+def decode_step_bytes(config: dict, weight_bytes: float, occupancy: float,
+                      mean_context: float, counters: dict) -> float:
+    """Bytes one decode step must read. A dense model reads every weight
+    once whatever was decoded, so the window's ``counters`` (the engine's
+    ``llm_stats()`` at ``open`` and ``close``) are not looked at."""
+    sh = shape(config)
+    return shapes.decode_step_bytes(
+        weight_bytes, occupancy, mean_context, sh["n_layer"], sh["d_model"],
+        sh["kv_dtype_bytes"])
+
+
+def train_flops_per_token(config: dict) -> float:
+    """Forward + backward operations one trained token requires."""
+    sh = shape(config)
+    return shapes.train_flops_per_token(
+        sh["n_layer"], sh["d_model"], sh["vocab"], sh["n_positions"])
+
+
+def attention_calls(config: dict, rows: int) -> tuple:
+    """The causal attention a training step of ``rows`` rows requires:
+    the ``(batch, heads, seq, head_dim)`` of one call, and how many
+    forward (and as many backward) calls a step makes."""
+    sh = shape(config)
+    return (rows, sh["n_head"], sh["n_positions"], sh["head_dim"]), \
+        sh["n_layer"]
 
 
 def system_config(config: dict):

@@ -13,6 +13,7 @@ import time
 from benchmark.loading import sibling
 
 common = sibling(__file__, "serve_common.py")
+TOLERANCES = common.TOLERANCES
 
 
 def run(run) -> None:

@@ -16,6 +16,10 @@ from __future__ import annotations
 import math
 import time
 
+# The keys of the configuration's ``tolerance`` that the serving kinds'
+# comparisons (``compare.check_serve``, ``check_engine_tokens``) read.
+TOLERANCES = ("serve_logits_rel_l2", "serve_token_regret_rms")
+
 
 def draw_sizes(traffic: dict, n: int):
     """``n`` (prompt_len, max_tokens) pairs from the traffic's own seed."""
@@ -46,7 +50,7 @@ def make_requests(run, n: int) -> list:
     the vocabulary from ``--seed``."""
     lens, new = draw_sizes(run.traffic, n)
     rng = run.rng("requests")
-    vocab = run.config["vocab_size"]
+    vocab = run.family.shape(run.config)["vocab"]
     return [{"i": i, "prompt": rng.integers(0, vocab, int(lens[i])).tolist(),
              "prompt_len": int(lens[i]), "asked": int(new[i])}
             for i in range(n)]
@@ -97,17 +101,13 @@ def _bytes_in_use(run) -> int:
 
 def _weight_bytes(run, engine_bytes: int, engine: dict) -> float:
     """Bytes of weights as the engine stores them: what the engine added
-    to the device less its cache (by shape), snapped to the nearest whole
-    number of bytes a parameter (1, 2 or 4) so that the figure is a shape
-    figure and not an allocator reading."""
-    from benchmark import shapes
-
-    sh = run.family.shape(run.config)
-    n_params = shapes.gpt2_param_count(
-        sh["n_layer"], sh["d_model"], sh["vocab_rows"], sh["n_positions"])
-    cache = (engine["max_batch"] + 1) * shapes.kv_bytes_per_slot(
-        sh["n_layer"], sh["d_model"], engine["cache_len"],
-        sh["kv_dtype_bytes"])
+    to the device less its cache (the family's ``cache_bytes``, by shape)
+    over the family's ``param_count``, snapped to the nearest whole number
+    of bytes a parameter (1, 2 or 4) so that the figure is a shape figure
+    and not an allocator reading."""
+    n_params = run.family.param_count(run.config)
+    cache = run.family.cache_bytes(
+        run.config, engine["max_batch"] + 1, engine["cache_len"])
     per_param = (engine_bytes - cache) / n_params
     snapped = min((1, 2, 4, 6, 8), key=lambda b: abs(b - per_param))
     run.say("engine_memory", engine_bytes=engine_bytes, cache_bytes=cache,

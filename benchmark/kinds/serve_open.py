@@ -18,6 +18,7 @@ import time
 from benchmark.loading import sibling
 
 common = sibling(__file__, "serve_common.py")
+TOLERANCES = common.TOLERANCES
 
 
 def arrival_offsets(traffic: dict, n: int):

@@ -13,6 +13,10 @@ from __future__ import annotations
 
 import time
 
+# The keys of the configuration's ``tolerance`` that this kind's comparison
+# (``compare.check_train``) reads.
+TOLERANCES = ("train_loss_rel", "train_grad_norm_rel")
+
 
 def doc_lengths(traffic: dict, n_tokens: int, rng) -> "list":
     """Document lengths ``unit * k``, k Zipf(exponent) cut at ``cut``,
@@ -154,7 +158,7 @@ def run(run) -> None:
     from ray_tpu import train
 
     traffic, cell = run.traffic, run.params
-    row_tokens = run.config["n_positions"] + 1
+    row_tokens = run.family.shape(run.config)["n_positions"] + 1
     with run.span("bench.make_pool"):
         pool = make_pool(traffic, cell["pool_rows"], row_tokens,
                          run.rng("pool"))

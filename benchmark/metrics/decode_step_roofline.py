@@ -1,12 +1,12 @@
 """The decode step's share of its roofline, which memory bandwidth bounds:
-bytes one step must read (every weight once as stored, and the live K/V
-rows of the occupied slots, by ``shapes.decode_step_bytes``) over the
-chip's peak bytes/s, over the median device busy time of one execution of
-the decode program (device trace). Occupancy and context are the window's
-means: slots occupied per step from the engine's counters, context per
-decoded token from the clients' records."""
+bytes one step must read (by the family's ``decode_step_bytes``: for a
+dense model every weight once as stored, and the live K/V rows of the
+occupied slots) over the chip's peak bytes/s, over the median device busy
+time of one execution of the decode program (device trace). Occupancy and
+context are the window's means: slots occupied per step from the engine's
+counters, context per decoded token from the clients' records."""
 
-from benchmark import peaks, shapes, stats, trace
+from benchmark import peaks, stats, trace
 
 
 def mean_context(run) -> float | None:
@@ -37,12 +37,11 @@ def read(run):
     context = mean_context(run)
     if not busy or context is None:
         return None
-    sh = run.family.shape(run.config)
     occupancy = (b["occupancy_sum"] - a["occupancy_sum"]) \
         / (b["steps"] - a["steps"])
-    need = shapes.decode_step_bytes(
-        run.raw["weight_bytes"], occupancy, context, sh["n_layer"],
-        sh["d_model"], sh["kv_dtype_bytes"])
+    need = run.family.decode_step_bytes(
+        run.config, run.raw["weight_bytes"], occupancy, context,
+        {"open": a, "close": b})
     least = need / peaks.peak(run.device_kind)["bytes_per_s"]
     run.say("decode_roofline", bytes_per_step=need, occupancy=occupancy,
             mean_context=context, least_ms=least * 1e3,

@@ -1,8 +1,10 @@
 """The flash kernels' share of their roofline: the least time the chip
-could take for the attention a step requires (one causal forward and one
-backward per layer on this chip's rows, by ``shapes.flash_forward`` /
-``flash_backward``; the larger of operations over peak FLOP/s and bytes
-over peak bytes/s, call by call) over the kernels' measured time per step.
+could take for the attention a step requires (the family's
+``attention_calls``: the shape of one causal call on this chip's rows and
+how many forward, and as many backward, calls a step makes; each by
+``shapes.flash_forward`` / ``flash_backward``, the larger of operations
+over peak FLOP/s and bytes over peak bytes/s, call by call) over the
+kernels' measured time per step.
 A forward recomputed in the backward pass lowers the share: recomputation
 is not required work."""
 
@@ -10,14 +12,12 @@ from benchmark import peaks, shapes, trace
 
 
 def required_seconds(run) -> tuple:
-    sh = run.family.shape(run.config)
     peak = peaks.peak(run.device_kind)
-    rows = run.raw["rows_per_step"] // run.chips
-    args = (rows, sh["n_head"], sh["n_positions"], sh["head_dim"])
-    fwd, fwd_by = shapes.roofline_seconds(*shapes.flash_forward(*args), peak)
-    bwd, bwd_by = shapes.roofline_seconds(*shapes.flash_backward(*args), peak)
-    return sh["n_layer"] * (fwd + bwd), {"forward": fwd_by,
-                                        "backward": bwd_by}
+    call, calls = run.family.attention_calls(
+        run.config, run.raw["rows_per_step"] // run.chips)
+    fwd, fwd_by = shapes.roofline_seconds(*shapes.flash_forward(*call), peak)
+    bwd, bwd_by = shapes.roofline_seconds(*shapes.flash_backward(*call), peak)
+    return calls * (fwd + bwd), {"forward": fwd_by, "backward": bwd_by}
 
 
 def read(run):

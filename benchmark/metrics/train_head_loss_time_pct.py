@@ -1,7 +1,7 @@
 """Share of the training step's busy time on the first device that ran
-under the scope ``head_loss`` (the tied head and the cross-entropy of
-``gpt2_loss``, forward and backward); the whole by-scope table goes to
-the earlier line ``train_by_scope`` (device trace)."""
+under the scope ``head_loss`` (the output head and the cross-entropy of
+the model's loss function, forward and backward); the whole by-scope
+table goes to the earlier line ``train_by_scope`` (device trace)."""
 
 from benchmark import program_trace
 

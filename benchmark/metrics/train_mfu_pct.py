@@ -1,8 +1,8 @@
-"""Model FLOP/s utilization: operations a trained token requires
-(``shapes.train_flops_per_token``, from the configuration's shape) times
+"""Model FLOP/s utilization: operations a trained token requires (the
+family's ``train_flops_per_token``, from the configuration's shape) times
 this run's tokens per second per chip, over the chip's published peak."""
 
-from benchmark import peaks, shapes
+from benchmark import peaks
 from benchmark.loading import sibling
 
 
@@ -10,7 +10,5 @@ def read(run):
     rate = sibling(__file__, "train_tokens_per_s_chip.py").read(run)
     if rate is None:
         return None
-    sh = run.family.shape(run.config)
-    need = shapes.train_flops_per_token(
-        sh["n_layer"], sh["d_model"], sh["vocab"], sh["n_positions"])
+    need = run.family.train_flops_per_token(run.config)
     return 100.0 * need * rate / peaks.peak(run.device_kind)["flops_per_s"]

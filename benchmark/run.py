@@ -11,15 +11,20 @@ One process holds the cell's chips. It finds everything by name, from
   can share, under its own);
 * the configuration: the ``file`` its entry in ``configs`` names, whose
   ``family`` picks ``benchmark/families/<family>.py`` (how the system is
-  built from the file) and ``benchmark/reference/<family>.py``;
+  built from the file, and every count of parameters, bytes and
+  operations that is that architecture's) and
+  ``benchmark/reference/<family>.py``;
 * the traffic mix: ``benchmark/traffic/<name>.json``, whose ``kind`` picks
   ``benchmark/kinds/<kind>.py`` (the generator and the measuring loop);
 * each metric: ``benchmark/metrics/<name>.py``, one ``read(run)`` from the
   run's spans, counters, trace and raw timings to one number, or None
   where there is nothing to read (the metric is then left out).
 
-There is no ``if`` on a cell's, configuration's or metric's name anywhere
-in the harness: a later PR adds files and entries and edits nothing.
+There is no ``if`` on a cell's, configuration's, metric's or family's name
+anywhere in the harness, and no formula or configuration key of one family
+in a kind or a reader: a later PR adds files and entries, of any family,
+and edits nothing. ``benchmark/families/README.md`` lists what a family's
+two files define and who calls each function.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, in a traced run,

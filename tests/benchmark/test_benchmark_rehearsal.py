@@ -2,15 +2,17 @@
 
 Each kind (``train_packed``, ``serve_closed``, ``serve_open``) runs through
 the same command the driver uses, in a temporary copy of the benchmark to
-which a third configuration, three traffic mixes, four cells (one on four
-devices) and a new metric were added AS FILES AND ENTRIES, with no edit to
-a file that was there (``benchmark_toy.make_root``). A rehearsal finds
-wrong paths and control flow; it is not evidence for chips, and the
+which a third configuration, a configuration of ANOTHER FAMILY (its family
+file and its plain reference with it), four traffic mixes, six cells (one
+on four devices) and a new metric were added AS FILES AND ENTRIES, with no
+edit to a file that was there (``benchmark_toy.make_root``). A rehearsal
+finds wrong paths and control flow; it is not evidence for chips, and the
 command prints no metric value in it.
 """
 
 import json
 import os
+import statistics
 import subprocess
 import sys
 import types
@@ -49,6 +51,14 @@ def rehearse(root, capsys, cell, trace, seconds=2.5):
     ("toy_open", 0, {"setup_s", "ttft_p90_ms"}),
     ("toy_open", 1, {"gen_late_ms_p99", "serve_queue_ms_p50",
                      "serve_prefill_ms_p50", "serve_handle_ms_p50"}),
+    # the family added as files: the toy reference decides `correct`
+    ("toy_llama_train", 0, {"setup_s", "train_tokens_per_s_chip"}),
+    ("toy_llama_train", 1, {"toy_slices", "train_stall_pct",
+                            "train_median_slice_tokens_per_s_chip"}),
+    ("toy_llama_closed", 0, {"setup_s", "serve_out_tokens_per_s",
+                             "itl_p99_ms"}),
+    ("toy_llama_closed", 1, {"serve_decode_step_ms_p50",
+                             "serve_batch_occupancy_pct"}),
 ])
 def test_rehearsal_of_each_kind(root, capsys, cell, trace, names):
     code, last, earlier = rehearse(root, capsys, cell, trace)
@@ -64,9 +74,17 @@ def test_rehearsal_of_each_kind(root, capsys, cell, trace, names):
     assert last["device"]["platform"] == "cpu"
     assert not any('"event": "compile_in_window"' in line
                    for line in earlier)
-    if cell.startswith("toy_train"):
+    if "train" in cell:
         # every slice's time is on an earlier line
         assert sum('"event": "slice"' in line for line in earlier) >= 2
+    if cell == "toy_llama_closed":
+        # The engine's cache by the family's own shape: 4 + 1 slots of 64
+        # rows, K and V of 2 layers x 2 key/value heads (of 4 query heads)
+        # x 16, in bfloat16. A cache as wide as the model would be twice it.
+        said = [json.loads(line[len("[bench] "):]) for line in earlier
+                if '"event": "engine_memory"' in line]
+        assert [m["cache_bytes"] for m in said] \
+            == [5 * 64 * 2 * 2 * 2 * 16 * 2]
 
 
 def test_values_exist_inside_the_process_and_the_added_metric_is_read(root):
@@ -78,12 +96,66 @@ def test_values_exist_inside_the_process_and_the_added_metric_is_read(root):
     assert result["metrics"]["toy_slices"]["value"] == len(
         run.raw["slice_seconds"])
     assert result["metrics"]["toy_slices"]["unit"] == "slices"
-    assert 0 <= result["metrics"]["train_stall_pct"]["value"] < 100
+    # 100 * (1 - slices * median / sum): under 0 whenever the median slice
+    # is longer than the mean one (-0.0034 on four chips: ledger, PR 25),
+    # so no `0 <=`. This toy's slices are a few milliseconds on a CPU that
+    # runs five other test files: it has read -2.3 (PR 24's whole run), and
+    # no range short of what the formula allows any slice times holds it.
+    # What a stall-free run can read is held on slice times that say so:
+    # test_a_run_without_a_stall_reads_near_zero_on_both_sides, below.
+    slices = run.raw["slice_seconds"]
+    assert result["metrics"]["train_stall_pct"]["value"] == pytest.approx(
+        100 * (1 - len(slices) * statistics.median(slices) / sum(slices)))
     assert run.compiles_in_window == 0
     # the run's own output file holds every slice
     with open(run.log_path) as f:
         events = [json.loads(line)["event"] for line in f]
     assert events.count("slice") == len(run.raw["slice_seconds"])
+
+
+@pytest.mark.parametrize("slices, low, high", [
+    # Slices that all lie within a share e of their median put the reading
+    # between -100 e / (1 - e) and 100 e / (1 + e), whatever their order or
+    # number. The chip's own (PERF.md section 6, PR 23: 298 of 299 slices
+    # took 1.26455-1.26597 s, e = 0.0006): inside +-0.06.
+    ([1.26455, 1.26597, 1.26520, 1.26530, 1.26525, 1.26590, 1.26460],
+     -0.06, 0.06),
+    # the median longer than the mean: a little UNDER zero, as the ledger's
+    # four-chip line reads (-0.0034); the old `0 <=` refused a sound run
+    ([1.2652, 1.2652, 1.2652, 1.2652, 1.2640, 1.2640], -0.04, -0.03),
+    # e = 0.01: between -1.0101 and 0.9901
+    ([1.00, 1.01, 0.99, 1.00, 1.01, 0.99, 1.00], -1.0102, 0.9902),
+    # and a stall is outside it: one slice of seven twice as long reads
+    # 100 / 8, far over what e = 0.01 allows
+    ([1.0, 1.0, 1.0, 2.0, 1.0, 1.0, 1.0], 12.49, 12.51),
+])
+def test_a_run_without_a_stall_reads_near_zero_on_both_sides(
+        root, slices, low, high):
+    reader = bench_run.load_module(os.path.join(
+        root, "benchmark", "metrics", "train_stall_pct.py"))
+    run = types.SimpleNamespace(raw={"slice_seconds": slices,
+                                     "window_whole_s": sum(slices)})
+    value = reader.read(run)
+    assert low < value < high
+    median = statistics.median(slices)
+    e = max(abs(s - median) for s in slices) / median
+    if e < 0.5:
+        assert -100 * e / (1 - e) - 1e-9 <= value <= 100 * e / (1 + e) + 1e-9
+    else:  # the stall: outside what the other six slices' e = 0 allows
+        assert value > 100 * 0.01 / 1.01
+
+
+def test_a_family_that_no_test_names_rehearses(tmp_path, capsys):
+    """The files of one more family dropped into the copy
+    (``make_root(third_family=True)``), its cell run through the command:
+    the harness finds the family and its reference by the configuration's
+    word alone."""
+    third = benchmark_toy.make_root(str(tmp_path), third_family=True)
+    code, last, earlier = rehearse(third, capsys, "toy_third_closed", 0)
+    assert code == 0 and last["correct"] is True, earlier[-3:]
+    assert last["attempted"] > 0 and last["failed"] == 0
+    assert {"setup_s", "serve_out_tokens_per_s", "itl_p99_ms"} \
+        <= set(last["rehearsal"]["metric_names"])
 
 
 def test_a_compile_inside_the_window_is_counted(root):
