@@ -1,6 +1,7 @@
 """Model zoo: pure-JAX pytree models designed for pjit sharding.
 
-Flagship: GPT-2 (the BASELINE.json north-star workload). Models are plain
+Flagship: GPT-2 (the BASELINE.json north-star workload); Nemotron-H (a
+hybrid of Mamba-2, attention and latent-MoE layers) is served only. Models are plain
 functions over parameter pytrees — no framework Module state — so the same
 code runs under any mesh and any rules table.
 """
@@ -11,6 +12,11 @@ from ray_tpu.models.llama import (
     llama_forward,
     llama_init,
     llama_loss,
+)
+from ray_tpu.models.nemotron_h import (
+    NemotronHConfig,
+    nemotron_h_forward,
+    nemotron_h_init,
 )
 from ray_tpu.models.moe import (
     MoEConfig,
@@ -23,6 +29,7 @@ __all__ = [
     "GPT2Config",
     "LlamaConfig",
     "MoEConfig",
+    "NemotronHConfig",
     "gpt2_forward",
     "gpt2_init",
     "gpt2_loss",
@@ -32,4 +39,6 @@ __all__ = [
     "moe_forward",
     "moe_init",
     "moe_loss",
+    "nemotron_h_forward",
+    "nemotron_h_init",
 ]

@@ -1,0 +1,583 @@
+"""Nemotron-H: a hybrid of Mamba-2 mixers, attention and latent
+mixture-of-experts layers, served through ``LLMEngine``.
+
+The layer list is data: ``pattern`` holds one character a layer, ``M`` a
+Mamba-2 mixer, ``*`` grouped-query attention (no position embedding: the
+Mamba layers carry order), ``E`` a latent MoE (sigmoid router over every
+expert of the model, top-k, experts in a ``latent``-wide space between one
+down- and one up-projection, a shared expert beside them at full width).
+Every layer is ``x + mixer(RMSNorm(x))``; after the last, ``norm_f`` and an
+untied head. ``benchmark/reference/nemotron_h.py`` writes the equations out
+plainly; the tests hold this file to it.
+
+What differs from the dense families here:
+
+* **Two kinds of state in one cache.** ``nemotron_h_init_cache`` gives a
+  pytree: ``k`` / ``v`` rows for the ``*`` layers (the ring contract of
+  ``ops/attention.py``), and for every ``M`` layer a convolution tail
+  ``conv`` and a float32 SSM state (``ssm``, one array a layer), which
+  have no ring: a prefill
+  overwrites them whole, by slot, and leaves exactly the state after each
+  row's ``length`` real tokens (padded positions take ``dt = 0``). The
+  engine donates the pytree; every layer writes its part in place, and no
+  layer-sized block is copied in or out of the layer loop (PR 25's rule).
+* **An expert share.** ``experts_held = (first, count)``: the chip holds
+  ``count`` of the ``n_experts`` the router scores, and computes its own
+  experts' part of each ``E`` layer (``ops/moe.dropless_experts``). What
+  the absent experts would add is left out, here and in the reference.
+* **Weights stored in bfloat16**, as the checkpoint publishes them; the
+  router, softmax statistics, ``dt`` / ``A`` / decays and the SSM state
+  are float32.
+* **Counters.** ``nemotron_h_decode_step`` returns a third value, a dict
+  of int32 scalars (``experts_hit``, ``expert_rows``) over the step's
+  ``E`` layers, which the engine fetches with the step's tokens.
+
+Multi-token prediction (the checkpoint's extra ``*E`` head) is not loaded:
+a deployment without speculative decoding does not serve it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
+                                   cached_decode_attention, causal_attention)
+from ray_tpu.ops.moe import dropless_experts, route
+
+Params = dict[str, Any]
+
+PUBLISHED_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072
+    d_model: int = 4096
+    pattern: str = PUBLISHED_PATTERN
+    eps: float = 1e-5
+    # `*`: grouped-query attention, no position embedding
+    n_head: int = 32
+    n_kv_head: int = 2
+    head_dim: int = 128
+    # `M`: Mamba-2 mixer
+    mamba_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128  # how a prefill blocks the scan; no result moves
+    # `E`: latent MoE
+    n_experts: int = 512       # the router's width: every expert of the model
+    experts_held: tuple = (0, 512)  # (first, count) of the experts held here
+    top_k: int = 22
+    latent: int = 1024
+    expert_ff: int = 2688
+    shared_ff: int = 5376
+    routed_scale: float = 5.0
+    dtype: Any = jnp.bfloat16        # activations and matmuls
+    param_dtype: Any = jnp.bfloat16  # as the checkpoint stores them
+    ssm_state_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        bad = set(self.pattern) - set("M*E")
+        if bad or not self.pattern:
+            raise ValueError(f"pattern {self.pattern!r}: want M, * and E")
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} outside "
+                             f"the router's {self.n_experts}")
+        if self.n_head % self.n_kv_head or \
+                self.mamba_heads % self.ssm_groups:
+            raise ValueError("heads must divide into their groups")
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+    def serving_stats(self) -> dict:
+        """What ``llm_stats()`` says of the model beside its counters."""
+        return {"expert_layers": self.count("E"),
+                "experts_held": self.experts_held[1]}
+
+    @classmethod
+    def tiny(cls, **kw) -> "NemotronHConfig":
+        """All three kinds of layer at a size a CPU test runs."""
+        base = dict(vocab_size=256, d_model=64, pattern="ME*EM", n_head=4,
+                    n_kv_head=2, head_dim=16, mamba_heads=8,
+                    mamba_head_dim=16, ssm_groups=2, ssm_state=16,
+                    chunk_size=8, n_experts=8, experts_held=(0, 4), top_k=3,
+                    latent=32, expert_ff=48, shared_ff=96)
+        base.update(kw)
+        return cls(**base)
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key, shape, std, dtype):
+    # under jit the float32 draw is never held whole beside its cast
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _layer_init(key, kind: str, cfg: NemotronHConfig) -> Params:
+    d, pd = cfg.d_model, cfg.param_dtype
+    out_std = 0.02 / math.sqrt(len(cfg.pattern))  # rescale_prenorm_residual
+    keys = iter(jax.random.split(key, 12))
+    p = {"norm": jnp.ones((d,), pd)}
+    if kind == "M":
+        h, di = cfg.mamba_heads, cfg.d_inner
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (h,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        dt = jnp.maximum(dt, 1e-4)
+        p.update(
+            in_proj=_normal(next(keys), (d, 2 * di + 2 * cfg.ssm_groups
+                                         * cfg.ssm_state + h), 0.02, pd),
+            conv_w=jax.random.uniform(
+                next(keys), (cfg.conv_kernel, cfg.conv_dim), jnp.float32,
+                -0.5, 0.5).astype(pd),
+            conv_b=_normal(next(keys), (cfg.conv_dim,), 0.02, pd),
+            dt_bias=(dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+            a_log=jnp.log(jax.random.uniform(
+                next(keys), (h,), jnp.float32, 1.0, 16.0)).astype(pd),
+            d_skip=jnp.ones((h,), pd),
+            gate_norm=jnp.ones((di,), pd),
+            out_proj=_normal(next(keys), (di, d), out_std, pd))
+    elif kind == "*":
+        q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
+        p.update(wq=_normal(next(keys), (d, q), 0.02, pd),
+                 wk=_normal(next(keys), (d, kv), 0.02, pd),
+                 wv=_normal(next(keys), (d, kv), 0.02, pd),
+                 wo=_normal(next(keys), (q, d), out_std, pd))
+    else:
+        held, lat, ff = cfg.experts_held[1], cfg.latent, cfg.expert_ff
+        p.update(
+            router=_normal(next(keys), (d, cfg.n_experts), 0.02, pd),
+            router_bias=_normal(next(keys), (cfg.n_experts,), 0.05, pd),
+            w_down=_normal(next(keys), (d, lat), 0.02, pd),
+            w_up=_normal(next(keys), (lat, d), out_std, pd),
+            w1=_normal(next(keys), (held, lat, ff), 0.02, pd),
+            w2=_normal(next(keys), (held, ff, lat), 0.02, pd),
+            shared_w1=_normal(next(keys), (d, cfg.shared_ff), 0.02, pd),
+            shared_w2=_normal(next(keys), (cfg.shared_ff, d), out_std, pd))
+    return p
+
+
+def nemotron_h_init(rng: jax.Array, cfg: NemotronHConfig) -> Params:
+    """Seeded weights in ``cfg.param_dtype`` (bfloat16 as published), one
+    dict a layer, built layer by layer: no float32 copy of anything larger
+    than the matrix being drawn ever exists."""
+    keys = jax.random.split(rng, len(cfg.pattern) + 2)
+    pd = cfg.param_dtype
+    return {
+        "embed": _normal(keys[0], (cfg.vocab_size, cfg.d_model), 0.02, pd),
+        "layers": [_layer_init(keys[2 + i], kind, cfg)
+                   for i, kind in enumerate(cfg.pattern)],
+        "norm_f": jnp.ones((cfg.d_model,), pd),
+        "lm_head": _normal(keys[1], (cfg.d_model, cfg.vocab_size), 0.02, pd),
+    }
+
+
+# -- the parts ----------------------------------------------------------------
+
+
+def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+def _gated_group_norm(y, z, w, cfg: NemotronHConfig):
+    """``RMSNorm_groups(y * silu(z)) * w``: the norm over each of the
+    ``ssm_groups`` groups of channels. y, z [..., d_inner]."""
+    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = yf.reshape(*yf.shape[:-1], cfg.ssm_groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.eps)
+    return (grouped.reshape(yf.shape) * w.astype(jnp.float32)).astype(
+        cfg.dtype)
+
+
+def _ssm_inputs(p: Params, proj: jax.Array, cfg: NemotronHConfig):
+    """``in_proj``'s output split: z [.., d_inner], xBC [.., conv_dim],
+    and ``dt`` before its softplus [.., H]."""
+    di = cfg.d_inner
+    return (proj[..., :di], proj[..., di:di + cfg.conv_dim],
+            proj[..., di + cfg.conv_dim:])
+
+
+def _ssm_split(conv: jax.Array, cfg: NemotronHConfig):
+    """The convolution's output split: x [.., H, P], B and C [.., G, N]."""
+    di, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
+    lead = conv.shape[:-1]
+    return (conv[..., :di].reshape(*lead, cfg.mamba_heads,
+                                   cfg.mamba_head_dim),
+            conv[..., di:di + gn].reshape(*lead, cfg.ssm_groups,
+                                          cfg.ssm_state),
+            conv[..., di + gn:].reshape(*lead, cfg.ssm_groups,
+                                        cfg.ssm_state))
+
+
+def _dt_and_a(p: Params, dt_raw: jax.Array):
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    return dt, -jnp.exp(p["a_log"].astype(jnp.float32))
+
+
+def _mamba_step(p: Params, y: jax.Array, tail: jax.Array, state: jax.Array,
+                cfg: NemotronHConfig):
+    """One token a slot. y [S, D] (normed), tail [K-1, S, C] the last
+    inputs of the convolution, state [S, H, P, N] float32. -> (the mixer's
+    output [S, D], the new tail, the new state)."""
+    dt_ = cfg.dtype
+    s = y.shape[0]
+    rep = cfg.mamba_heads // cfg.ssm_groups
+    with jax.named_scope("ssm_proj"):
+        z, xbc, dt_raw = _ssm_inputs(p, y @ p["in_proj"].astype(dt_), cfg)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate(
+            [tail, xbc.astype(tail.dtype)[None]], axis=0)  # [K, S, C]
+        conv = jnp.einsum("ksc,kc->sc", window.astype(jnp.float32),
+                          p["conv_w"].astype(jnp.float32)) \
+            + p["conv_b"].astype(jnp.float32)
+        xs, b, c = _ssm_split(jax.nn.silu(conv).astype(dt_), cfg)
+    with jax.named_scope("ssm_update"):
+        dt, a = _dt_and_a(p, dt_raw)  # [S, H], [H]
+        xf = xs.astype(jnp.float32)
+        bh = jnp.repeat(b.astype(jnp.float32), rep, axis=1)  # [S, H, N]
+        ch = jnp.repeat(c.astype(jnp.float32), rep, axis=1)
+        state = state.astype(jnp.float32) \
+            * jnp.exp(dt * a)[:, :, None, None] \
+            + (dt[..., None] * xf)[..., None] * bh[:, :, None, :]
+        # multiply and reduce beside the update: one pass over the state
+        yh = jnp.sum(state * ch[:, :, None, :], axis=-1) \
+            + p["d_skip"].astype(jnp.float32)[None, :, None] * xf
+    with jax.named_scope("ssm_norm"):
+        yn = _gated_group_norm(yh.reshape(s, cfg.d_inner), z,
+                               p["gate_norm"], cfg)
+    with jax.named_scope("ssm_proj"):
+        out = yn @ p["out_proj"].astype(dt_)
+    return out, window[1:], state.astype(cfg.ssm_state_dtype)
+
+
+def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig):
+    """The recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = S_t C_t`` over whole rows from an empty state, blocked in
+    chunks of ``chunk_size`` (inside a chunk a masked product, between
+    chunks the state): xs [R, T, H, P], dt [R, T, H] float32 (0 at padded
+    positions: they leave the state as it is), a [H], b / c [R, T, G, N].
+    -> (y [R, T, H, P] float32, the state after the row [R, H, P, N])."""
+    r, t, h, pdim = xs.shape
+    g, n = b.shape[2], b.shape[3]
+    rep, q = h // g, min(cfg.chunk_size, t)
+    pad = (-t) % q
+    if pad:
+        xs, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (
+            v.ndim - 2)) for v in (xs, dt, b, c))
+    nc = (t + pad) // q
+    mm = cfg.dtype
+    f32 = jnp.float32
+    # [R, nc, Q, ...], heads split into (group, heads of the group)
+    xdt = (xs.astype(f32) * dt[..., None]).reshape(r, nc, q, g, rep, pdim)
+    b = b.reshape(r, nc, q, g, n).astype(mm)
+    c = c.reshape(r, nc, q, g, n).astype(mm)
+    cum = jnp.cumsum((dt * a).reshape(r, nc, q, g, rep), axis=2)
+    # inside a chunk: y_i += sum_{j <= i} (C_i . B_j) exp(cum_i - cum_j) xdt_j
+    cb = jnp.einsum("rcign,rcjgn->rcgij", c, b, preferred_element_type=f32)
+    gap = cum[:, :, :, None] - cum[:, :, None]  # [R, nc, i, j, G, rep]
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None, None]
+    decay = jnp.where(causal, jnp.exp(jnp.where(causal, gap, 0.0)), 0.0)
+    mix = cb.transpose(0, 1, 3, 4, 2)[..., None] * decay  # [R,nc,i,j,G,rep]
+    y = jnp.einsum("rcijgh,rcjghp->rcighp", mix.astype(mm), xdt.astype(mm),
+                   preferred_element_type=f32)
+    # what each chunk adds to the state, decayed to the chunk's end
+    to_end = jnp.exp(cum[:, :, -1:] - cum)  # [R, nc, Q, G, rep]
+    add = jnp.einsum("rcjghp,rcjgn->rcghpn",
+                     (xdt * to_end[..., None]).astype(mm), b,
+                     preferred_element_type=f32)
+    through = jnp.exp(cum[:, :, -1])  # [R, nc, G, rep]: a whole chunk's decay
+
+    def chunk(state, inp):
+        add_c, through_c = inp
+        return state * through_c[..., None, None] + add_c, state
+
+    state, before = jax.lax.scan(
+        chunk, jnp.zeros((r, g, rep, pdim, n), f32),
+        (add.transpose(1, 0, 2, 3, 4, 5), through.transpose(1, 0, 2, 3)))
+    before = before.transpose(1, 0, 2, 3, 4, 5)  # the state entering a chunk
+    y = y + jnp.einsum("rcign,rcghpn->rcighp", c, before.astype(mm),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    y = y.reshape(r, t + pad, h, pdim)[:, :t]
+    return y, state.reshape(r, h, pdim, n)
+
+
+def _mamba_rows(p: Params, y: jax.Array, lengths: jax.Array,
+                cfg: NemotronHConfig):
+    """Whole rows from an empty state. y [R, T, D] (normed), lengths [R]:
+    the real tokens of each row. -> (the mixer's output [R, T, D], the
+    convolution's tail after ``length`` tokens [K-1, R, C], the state
+    after ``length`` tokens [R, H, P, N])."""
+    dt_ = cfg.dtype
+    r, t, _ = y.shape
+    k = cfg.conv_kernel
+    with jax.named_scope("ssm_proj"):
+        z, xbc, dt_raw = _ssm_inputs(p, y @ p["in_proj"].astype(dt_), cfg)
+    with jax.named_scope("conv"):
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+        w = p["conv_w"].astype(jnp.float32)
+        conv = sum(padded[:, j:j + t].astype(jnp.float32) * w[j]
+                   for j in range(k)) + p["conv_b"].astype(jnp.float32)
+        xs, b, c = _ssm_split(jax.nn.silu(conv).astype(dt_), cfg)
+        # the inputs at length - (K-1) .. length - 1, zero before the row
+        at = lengths[None, :] + jnp.arange(k - 1)[:, None]  # into `padded`
+        tail = padded[jnp.arange(r)[None, :], at]  # [K-1, R, C]
+    with jax.named_scope("ssm_scan"):
+        dt, a = _dt_and_a(p, dt_raw)
+        dt = jnp.where(jnp.arange(t)[None, :, None] < lengths[:, None, None],
+                       dt, 0.0)
+        yh, state = _ssd_scan(xs, dt, a, b, c, cfg)
+        yh = yh + p["d_skip"].astype(jnp.float32)[None, None, :, None] \
+            * xs.astype(jnp.float32)
+    with jax.named_scope("ssm_norm"):
+        yn = _gated_group_norm(yh.reshape(r, t, cfg.d_inner), z,
+                               p["gate_norm"], cfg)
+    with jax.named_scope("ssm_proj"):
+        out = yn @ p["out_proj"].astype(dt_)
+    return out, tail, state.astype(cfg.ssm_state_dtype)
+
+
+def _moe(p: Params, y: jax.Array, cfg: NemotronHConfig,
+         live: jax.Array | None = None):
+    """The latent MoE over rows y [T, D] (normed): the held experts' part
+    of the routed output plus the shared expert; rows that ``live`` [T]
+    says are padding are routed nowhere. -> (out [T, D], the pairs each
+    held expert took [count])."""
+    dt_ = cfg.dtype
+    with jax.named_scope("router"):
+        ids, weights = route(y, p["router"], p["router_bias"], cfg.top_k,
+                             cfg.routed_scale)
+    with jax.named_scope("latent_proj"):
+        h = y @ p["w_down"].astype(dt_)
+    routed, counts = dropless_experts(
+        h, ids, weights, p["w1"], p["w2"], first=cfg.experts_held[0],
+        activation=_relu2, live=live)
+    with jax.named_scope("latent_proj"):
+        out = routed.astype(dt_) @ p["w_up"].astype(dt_)
+    with jax.named_scope("shared_expert"):
+        out = out + _relu2(y @ p["shared_w1"].astype(dt_)) \
+            @ p["shared_w2"].astype(dt_)
+    return out, counts
+
+
+def _counters(counts: list) -> dict:
+    """Over a step's ``E`` layers: the held experts that took at least one
+    row, and the token-expert pairs that landed here."""
+    if not counts:
+        return {"experts_hit": jnp.int32(0), "expert_rows": jnp.int32(0)}
+    stacked = jnp.stack(counts)
+    return {"experts_hit": jnp.sum(stacked > 0, dtype=jnp.int32),
+            "expert_rows": jnp.sum(stacked, dtype=jnp.int32)}
+
+
+# -- the cache and the serving functions --------------------------------------
+
+
+def nemotron_h_init_cache(cfg: NemotronHConfig, slots: int,
+                          cache_len: int) -> Params:  # decode-path
+    """K/V rows for the ``*`` layers (a ring, as the other families'), and
+    for the ``M`` layers the convolution's tail and the SSM state (no
+    ring). K/V and the tails are stacked over their layers; the tail lies
+    [layer, K-1, slot, channel]: slots and channels are the minor
+    dimensions, which tile. The SSM state is one array a layer (a tuple):
+    a decode step rewrites a layer's whole state, and only with the layer's
+    state as a buffer of its own does XLA fuse the update and the readout
+    ``S_t C_t`` into one pass over it; as a slice of a stacked array it is
+    read twice and written once (compile-only for a v5e, PR 28)."""
+    kv = (cfg.count("*"), slots, cache_len, cfg.n_kv_head, cfg.head_dim)
+    n_m = cfg.count("M")
+    return {
+        "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+        "conv": jnp.zeros((n_m, cfg.conv_kernel - 1, slots, cfg.conv_dim),
+                          cfg.dtype),
+        "ssm": tuple(jnp.zeros((slots, cfg.mamba_heads, cfg.mamba_head_dim,
+                                cfg.ssm_state), cfg.ssm_state_dtype)
+                     for _ in range(n_m)),
+    }
+
+
+# jax-hot-path: traced into the engine's single compiled decode step
+def nemotron_h_decode_step(params: Params, cache: Params, tokens: jax.Array,
+                           pos: jax.Array, cfg: NemotronHConfig
+                           ) -> tuple[jax.Array, Params, dict]:
+    """One decode iteration for every slot: tokens [S] int32, pos [S]
+    int32 -> (logits [S, V] fp32, new cache, counters). Every row is
+    computed, free slots and the scratch one too (their states stay
+    finite: the recurrence decays), so the counters count what the step
+    really routed. The K/V part keeps ``gpt2_decode_step``'s ring
+    contract; with no position embedding a wrapped ring is a window."""
+    s = tokens.shape[0]
+    nh, nkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    dt_ = cfg.dtype
+    cache_len = cache["k"].shape[2]
+    cursor = jnp.mod(pos, cache_len)
+    valid = jnp.minimum(pos + 1, cache_len)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt_)[tokens]
+    conv_all, ssm_all = cache["conv"], list(cache["ssm"])
+    k_rows, v_rows, counts = [], [], []
+    i_m = i_a = 0
+    for kind, p in zip(cfg.pattern, params["layers"]):
+        with jax.named_scope("ln"):
+            y = _rms_norm(x, p["norm"], cfg.eps)
+        if kind == "M":
+            out, tail, state = _mamba_step(p, y, conv_all[i_m],
+                                           ssm_all[i_m], cfg)
+            with jax.named_scope("state_write"):
+                conv_all = jax.lax.dynamic_update_slice(
+                    conv_all, tail[None].astype(conv_all.dtype),
+                    (i_m, 0, 0, 0))
+                ssm_all[i_m] = state
+            i_m += 1
+        elif kind == "*":
+            with jax.named_scope("attn_proj"):
+                q = (y @ p["wq"].astype(dt_)).reshape(s, nh, hd)
+                k_new = (y @ p["wk"].astype(dt_)).reshape(
+                    s, nkv, hd).astype(cache["k"].dtype)
+                v_new = (y @ p["wv"].astype(dt_)).reshape(
+                    s, nkv, hd).astype(cache["v"].dtype)
+            with jax.named_scope("attn"):
+                attn = cached_decode_attention(
+                    q, cache["k"][i_a], cache["v"][i_a], k_new, v_new,
+                    cursor, valid, dt_)
+            with jax.named_scope("attn_proj"):
+                out = attn.reshape(s, nh * hd) @ p["wo"].astype(dt_)
+            k_rows.append(k_new)
+            v_rows.append(v_new)
+            i_a += 1
+        else:
+            out, c = _moe(p, y, cfg)
+            counts.append(c)
+        x = x + out
+    k_all, v_all = cache["k"], cache["v"]
+    if k_rows:
+        with jax.named_scope("cache_write"):
+            k_all = cache_write_token(k_all, jnp.stack(k_rows), cursor)
+            v_all = cache_write_token(v_all, jnp.stack(v_rows), cursor)
+    with jax.named_scope("ln"):
+        x = _rms_norm(x, params["norm_f"], cfg.eps)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("sd,dv->sv", x, params["lm_head"].astype(dt_),
+                            preferred_element_type=jnp.float32)
+    return logits, {"k": k_all, "v": v_all, "conv": conv_all,
+                    "ssm": tuple(ssm_all)}, _counters(counts)
+
+
+def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
+          cfg: NemotronHConfig, cache: Params | None = None,
+          slots: jax.Array | None = None):
+    """Whole rows through every layer: tokens [R, T], lengths [R]. With a
+    cache, each layer writes its part of rows' state into ``slots`` in
+    place. -> (hidden [R, T, D] before ``norm_f``, the cache)."""
+    r, t = tokens.shape
+    nh, nkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    dt_ = cfg.dtype
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt_)[tokens]
+    # a padded lane's other positions are not routed: no expert computes them
+    real = jnp.arange(t)[None, :] < lengths[:, None]
+    if cache is not None:
+        k_all, v_all, conv_all = cache["k"], cache["v"], cache["conv"]
+        ssm_all = list(cache["ssm"])
+    i_m = i_a = 0
+    for kind, p in zip(cfg.pattern, params["layers"]):
+        with jax.named_scope("ln"):
+            y = _rms_norm(x, p["norm"], cfg.eps)
+        if kind == "M":
+            out, tail, state = _mamba_rows(p, y, lengths, cfg)
+            if cache is not None:
+                with jax.named_scope("state_write"):
+                    tail = tail.astype(conv_all.dtype)
+                    for i in range(r):  # by slot; distinct but the scratch
+                        conv_all = jax.lax.dynamic_update_slice(
+                            conv_all, tail[None, :, i:i + 1],
+                            (i_m, 0, slots[i], 0))
+                        ssm_all[i_m] = jax.lax.dynamic_update_slice(
+                            ssm_all[i_m], state[i:i + 1],
+                            (slots[i], 0, 0, 0))
+            i_m += 1
+        elif kind == "*":
+            with jax.named_scope("attn_proj"):
+                q = (y @ p["wq"].astype(dt_)).reshape(r, t, nh, hd)
+                k_ = (y @ p["wk"].astype(dt_)).reshape(r, t, nkv, hd)
+                v_ = (y @ p["wv"].astype(dt_)).reshape(r, t, nkv, hd)
+            with jax.named_scope("attn"):
+                rep = nh // nkv
+                attn = causal_attention(
+                    q, jnp.repeat(k_, rep, axis=2),
+                    jnp.repeat(v_, rep, axis=2), use_flash=False)
+            if cache is not None:
+                with jax.named_scope("cache_write"):
+                    k_all = cache_write_prompt(k_all, i_a, k_, slots)
+                    v_all = cache_write_prompt(v_all, i_a, v_, slots)
+            with jax.named_scope("attn_proj"):
+                out = attn.reshape(r, t, nh * hd) @ p["wo"].astype(dt_)
+            i_a += 1
+        else:
+            out, _ = _moe(p, y.reshape(r * t, -1), cfg, real.reshape(-1))
+            out = out.reshape(r, t, -1)
+        x = x + out
+    if cache is not None:
+        cache = {"k": k_all, "v": v_all, "conv": conv_all,
+                 "ssm": tuple(ssm_all)}
+    return x, cache
+
+
+# jax-hot-path: traced into the engine's single compiled prefill lane
+def nemotron_h_prefill(params: Params, cache: Params, tokens: jax.Array,
+                       slots: jax.Array, lengths: jax.Array,
+                       cfg: NemotronHConfig) -> tuple[jax.Array, Params]:
+    """The prefill lane (fixed [R, P] shape): the full causal forward over
+    the padded prompts; each row's K/V rows ``[0, P)`` go to its slot as in
+    ``gpt2_prefill``, and its slot's ``conv`` and ``ssm`` are overwritten
+    whole with the state after the row's ``length`` real tokens, whatever
+    the slot held. Logits at each prompt's last real token."""
+    r, p_len = tokens.shape
+    x, cache = _rows(params, tokens, lengths, cfg, cache, slots)
+    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]
+    with jax.named_scope("ln"):
+        last = _rms_norm(last, params["norm_f"], cfg.eps)
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "rd,dv->rv", last, params["lm_head"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32)
+    return logits, cache
+
+
+def nemotron_h_forward(params: Params, tokens: jax.Array,
+                       cfg: NemotronHConfig) -> jax.Array:
+    """Logits [R, T, V] float32 of whole rows, no cache (tests)."""
+    lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
+    x, _ = _rows(params, tokens, lengths, cfg)
+    x = _rms_norm(x, params["norm_f"], cfg.eps)
+    return jnp.einsum("rtd,dv->rtv", x, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)

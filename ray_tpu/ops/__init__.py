@@ -13,7 +13,8 @@ from ray_tpu.ops.ring_attention import (
     ring_flash_attention_local,
 )
 from ray_tpu.ops.ulysses import ulysses_attention, ulysses_attention_local
-from ray_tpu.ops.moe import init_moe_params, moe_ffn, moe_ffn_ep
+from ray_tpu.ops.moe import (dropless_experts, init_moe_params, moe_ffn,
+                             moe_ffn_ep, route)
 
 __all__ = [
     "causal_attention",
@@ -24,7 +25,9 @@ __all__ = [
     "ring_flash_attention_local",
     "ulysses_attention",
     "ulysses_attention_local",
+    "dropless_experts",
     "init_moe_params",
     "moe_ffn",
     "moe_ffn_ep",
+    "route",
 ]

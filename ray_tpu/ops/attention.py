@@ -165,10 +165,16 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     > cursor). The score at the cursor is the new token's and its value
     is added beside the window's, so it is the same softmax over the
     same keys as if the row had been written first — a wrapped ring
-    drops the row the cursor overwrites, as ever. fp32 scores/softmax,
+    drops the row the cursor overwrites, as ever. Where k/v hold FEWER
+    heads than q (grouped queries: [S, L, G, hd] and [S, G, hd], H a
+    multiple of G) each K/V head serves its H // G query heads as it lies,
+    with no expanded copy of the window. fp32 scores/softmax,
     output cast to the activation dtype — shared by both model families'
     decode steps so the masking/scaling contract lives here once."""
     hd = q.shape[-1]
+    if k.shape[2] != q.shape[1]:
+        return _grouped_decode_attention(q, k, v, k_new, v_new, cursor,
+                                         valid, out_dtype)
     q = q.astype(jnp.float32)
     idx = jnp.arange(k.shape[1])
     at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
@@ -182,3 +188,25 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      v.astype(jnp.float32))
     out = out + weight_new[..., None] * v_new.astype(jnp.float32)
     return out.astype(out_dtype)
+
+
+def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
+                              out_dtype):
+    """``cached_decode_attention`` for G K/V heads under H = G * R query
+    heads: the same softmax over the same keys, the window read once."""
+    s, h, hd = q.shape
+    g = k.shape[2]
+    q = q.astype(jnp.float32).reshape(s, g, h // g, hd)
+    idx = jnp.arange(k.shape[1])
+    at_cursor = (idx[None, :] == cursor[:, None])[:, None, None, :]
+    mask = (idx[None, :] < valid[:, None])[:, None, None, :]
+    scores = jnp.einsum("sgrd,slgd->sgrl", q, k.astype(jnp.float32))
+    score_new = jnp.einsum("sgrd,sgd->sgr", q, k_new.astype(jnp.float32))
+    scores = jnp.where(at_cursor, score_new[..., None], scores) / (hd ** 0.5)
+    weights = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    weight_new = jnp.sum(jnp.where(at_cursor, weights, 0.0), axis=-1)
+    out = jnp.einsum("sgrl,slgd->sgrd", jnp.where(at_cursor, 0.0, weights),
+                     v.astype(jnp.float32))
+    out = out + weight_new[..., None] \
+        * v_new.astype(jnp.float32)[:, :, None, :]
+    return out.reshape(s, h, hd).astype(out_dtype)

@@ -15,6 +15,13 @@ the reference, first-class here. Design (switch-style top-1 / top-2):
 
 The dense path (``moe_ffn``) works on any mesh; ``moe_ffn_ep`` adds the
 all_to_all when an ``ep`` axis exists.
+
+Beside that capacity path (training; it drops tokens over the capacity)
+lives the dropless SHARE layer of the serving path: ``route`` scores every
+expert of the model, ``dropless_experts`` computes the part of the result
+that the experts held on this chip give, for every token routed to them,
+whatever the imbalance. It has no exchange: on one chip there is none, and
+nothing here stands in for absent chips.
 """
 
 from __future__ import annotations
@@ -170,3 +177,113 @@ def moe_ffn_ep_local(px, p_router, p_win, p_wout, *, n_experts: int,
     out = jnp.einsum("tec,ecd->td", combine, expert_out)
     aux = jax.lax.pmean(aux, axis)
     return out.reshape(b, t, d).astype(px.dtype), aux
+
+
+# -- the dropless share layer (serving path) ----------------------------------
+#
+# An expert layer that is told which experts it holds: the router keeps the
+# model's width and its experts per token, and this chip computes what its
+# own experts add for the tokens routed to them. What the absent experts
+# would have added is left out (the chips that hold them add it).
+
+# Up to this many rows a grouped product's row tile is at least as tall as
+# the whole batch, so "every held expert over every row" does the same MXU
+# passes and reads each expert's weights once, without the sort.
+DENSE_ROWS = 128
+
+
+def route(x: jax.Array, w_gate: jax.Array, bias: jax.Array, top_k: int,
+          scale: float) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid router over ALL experts, in float32.
+
+    x [T, D], w_gate [D, E], bias [E] (the score correction: it moves the
+    choice, not the weight). -> ids [T, K] int32, weights [T, K] float32:
+    the chosen experts' own scores, normalised over the chosen and times
+    ``scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return ids.astype(jnp.int32), w
+
+
+def dropless_experts(h: jax.Array, ids: jax.Array, weights: jax.Array,
+                     w1: jax.Array, w2: jax.Array, *, first: int,
+                     activation, live: jax.Array | None = None
+                     ) -> tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed layer, nothing dropped.
+
+    h [T, D] rows, ids / weights [T, K] over all experts (``route``),
+    w1 [E, D, F] and w2 [E, F, D] the experts ``first .. first + E`` held
+    here; ``activation`` between the two products; ``live`` [T] bool, where
+    given, says which rows are real (a padded lane's other rows are routed
+    nowhere and get zeros). -> (out [T, D] float32:
+    ``sum_k weights[t, k] * expert_{ids[t, k]}(h[t])`` over the pairs whose
+    expert is held, and counts [E] int32: the pairs each held expert took).
+
+    Two static shapes of one result, chosen by the row count alone. Past
+    ``DENSE_ROWS`` rows the pairs are sorted by expert, absent ones last,
+    and the two products are ``jax.lax.ragged_dot`` over the held experts'
+    groups, so rows of absent experts are not computed. Up to it every held
+    expert is applied to every row in one batched product and the routing
+    weights mask it: with so few rows a group's row tile would cover them
+    all anyway. bfloat16 products accumulate in float32."""
+    n_held = w1.shape[0]
+    local = ids - first
+    held = (local >= 0) & (local < n_held)
+    if live is not None:
+        held = held & live[:, None]
+    weights = jnp.where(held, weights, 0.0)
+    share = _grouped_share if ids.shape[0] > DENSE_ROWS else _batched_share
+    return share(h, local, held, weights, w1.astype(h.dtype),
+                 w2.astype(h.dtype), activation)
+
+
+def _grouped_share(h, local, held, weights, w1, w2, activation):
+    """The pairs sorted by expert, the absent and the padding last; two
+    grouped products over the held experts' groups; each pair's row back
+    at its token."""
+    t, k = local.shape
+    n_held = w1.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        key = jnp.where(held, local, n_held).reshape(-1)
+        order = jnp.argsort(key)
+        counts = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                         dtype=jnp.int32)
+        rows = h[order // k]  # [T * K, D], sorted by expert
+    with jax.named_scope("experts"):
+        a = activation(jax.lax.ragged_dot(rows, w1, counts,
+                                          preferred_element_type=h.dtype))
+        y = jax.lax.ragged_dot(a, w2, counts, preferred_element_type=h.dtype)
+    with jax.named_scope("moe_combine"):
+        back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        # rows past the held groups were not computed: whatever the product
+        # left there is not a number to weigh
+        y = jnp.where(held[..., None], y[back].reshape(t, k, -1), 0)
+        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
+    return out, counts
+
+
+def _batched_share(h, local, held, weights, w1, w2, activation):
+    """Every held expert over every row in one batched product a matrix,
+    masked by each row's weight on each expert."""
+    with jax.named_scope("moe_dispatch"):
+        onehot = (local[..., None] == jnp.arange(w1.shape[0])) \
+            & held[..., None]  # [T, K, E]
+        counts = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+        by_expert = jnp.sum(
+            jnp.where(onehot, weights[..., None], 0.0), axis=1)  # [T, E]
+    with jax.named_scope("experts"):
+        a = activation(jnp.einsum(
+            "td,edf->etf", h, w1,
+            preferred_element_type=jnp.float32)).astype(h.dtype)
+        # (its result in the rows' type: XLA's CPU backend cannot run a
+        # batched bfloat16 product into float32 behind another one)
+        y = jnp.einsum("etf,efd->etd", a, w2)
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("te,etd->td", by_expert, y.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+    return out, counts

@@ -134,7 +134,15 @@ def _model_bundle(model: str, config, preset: str):
                          else m.LlamaConfig.small())
         return (cfg, m.llama_init, m.llama_init_cache, m.llama_prefill,
                 m.llama_decode_step)
-    raise ValueError(f"unknown model family {model!r} (want gpt2|llama)")
+    if model == "nemotron_h":
+        from ray_tpu.models import nemotron_h as m
+
+        cfg = config or (m.NemotronHConfig.tiny() if preset == "tiny"
+                         else m.NemotronHConfig())
+        return (cfg, m.nemotron_h_init, m.nemotron_h_init_cache,
+                m.nemotron_h_prefill, m.nemotron_h_decode_step)
+    raise ValueError(
+        f"unknown model family {model!r} (want gpt2|llama|nemotron_h)")
 
 
 class LLMEngine:
@@ -212,13 +220,28 @@ class LLMEngine:
         self._init_s = {"params": t1 - t0,
                         "cache": time.perf_counter() - t1}
         self._compiles = {"decode": 0, "prefill": 0}
+        # A family's decode step may return, beside logits and cache, a
+        # dict of int32 scalars that count what the step did (a sparse
+        # model's experts hit). They ride behind the step's tokens in the
+        # ONE array the step syncs on, and add up in stats_counters; a
+        # family that returns none runs the program it always ran.
+        self._step_counters: tuple = ()
+        # What the model says of itself beside its counters (llm_stats).
+        self._model_stats = dict(
+            getattr(cfg, "serving_stats", lambda: {})())
 
         def step_fn(params, cache, tokens, pos):
             self._compiles["decode"] += 1  # trace-time: fires per compile
-            logits, cache = decode(params, cache, tokens, pos, cfg)
+            logits, cache, *counted = decode(params, cache, tokens, pos, cfg)
             with jax.named_scope("head"):
-                return (self._jnp.argmax(logits, axis=-1).astype(
-                    self._jnp.int32), cache)
+                nxt = self._jnp.argmax(logits, axis=-1).astype(
+                    self._jnp.int32)
+            if counted:
+                self._step_counters = tuple(sorted(counted[0]))
+                nxt = self._jnp.concatenate([nxt, self._jnp.stack(
+                    [counted[0][k] for k in self._step_counters]).astype(
+                        self._jnp.int32)])
+            return nxt, cache
 
         def prefill_fn(params, cache, tokens, slots, lengths):
             self._compiles["prefill"] += 1
@@ -242,7 +265,7 @@ class LLMEngine:
         # opt-in via step_cost(): the extra XLA compile is not free.
         self._cost_fn = jax.jit(
             lambda params, cache, tokens, pos: decode(
-                params, cache, tokens, pos, cfg))
+                params, cache, tokens, pos, cfg)[:2])
         self._step_cost: Optional[dict] = None
         self._step_cost_flops = 0.0
 
@@ -583,6 +606,9 @@ class LLMEngine:
                 if req.remaining <= 0 or tok == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
             self.stats_counters["steps"] += 1
+            for i, key in enumerate(self._step_counters):
+                self.stats_counters[key] = self.stats_counters.get(key, 0) \
+                    + int(nxt[self.max_batch + 1 + i])
             self.stats_counters["tokens_out"] += produced
             self.stats_counters["occupancy_sum"] += len(active)
             # ITL (TPOT): delivery-to-delivery gap. All slots advance
@@ -857,6 +883,7 @@ class LLMEngine:
             "init_s": dict(self._init_s),
             "mean_occupancy": round(c["occupancy_sum"] / steps, 3)
             if steps else 0.0,
+            **self._model_stats,
             **c,
         }
 

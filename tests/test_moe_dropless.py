@@ -1,0 +1,198 @@
+"""The dropless share layer of ``ops/moe.py`` (``route``,
+``dropless_experts``): nothing dropped at any imbalance, experts that are
+absent contribute nothing, the two static shapes (grouped and batched) give
+one result, the counters count what a hand can count, and the shares of all
+chips add up to the uncut layer."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.loading import load_module
+from ray_tpu.models import nemotron_h as nh
+from ray_tpu.ops import moe
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+reference = load_module(os.path.join(REPO, "benchmark", "reference",
+                                     "nemotron_h.py"))
+T, D, F, E, K = 24, 16, 20, 12, 3
+relu2 = lambda x: jnp.square(jax.nn.relu(x))
+# the row count alone chooses the path: T rows take the batched product,
+# six copies of them (144, past ``DENSE_ROWS``) the grouped one
+PATHS = pytest.mark.parametrize(
+    "copies", [6, 1], ids=["grouped", "batched"])
+
+
+def copied(layer, copies):
+    return jnp.tile(layer["h"], (copies, 1))
+
+
+@pytest.fixture(scope="module")
+def layer():
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    return {"h": jax.random.normal(ks[0], (T, D)),
+            "w1": jax.random.normal(ks[1], (E, D, F)) * 0.3,
+            "w2": jax.random.normal(ks[2], (E, F, D)) * 0.3,
+            "gate": jax.random.normal(ks[3], (D, E)),
+            "bias": jax.random.normal(ks[4], (E,)) * 0.3}
+
+
+def by_hand(h, ids, weights, w1, w2, first):
+    """Token by token, pair by pair, in numpy."""
+    out = np.zeros((h.shape[0], w2.shape[2]), np.float64)
+    counts = np.zeros(w1.shape[0], np.int64)
+    for t in range(h.shape[0]):
+        for e, w in zip(np.asarray(ids[t]), np.asarray(weights[t])):
+            if first <= e < first + w1.shape[0]:
+                a = np.maximum(np.asarray(h[t], np.float64)
+                               @ np.asarray(w1[e - first], np.float64), 0) ** 2
+                out[t] += w * (a @ np.asarray(w2[e - first], np.float64))
+                counts[e - first] += 1
+    return out, counts
+
+
+def test_route_is_the_references_router(layer):
+    ids, w = moe.route(layer["h"], layer["gate"], layer["bias"], K, 5.0)
+    s = jax.nn.sigmoid(layer["h"] @ layer["gate"])
+    want_ids = np.argsort(-np.asarray(s + layer["bias"]), axis=-1)[:, :K]
+    assert [sorted(r) for r in np.asarray(ids).tolist()] \
+        == [sorted(r) for r in want_ids.tolist()]
+    # the weights are the scores WITHOUT the bias, normalised, times 5
+    chosen = np.take_along_axis(np.asarray(s), np.asarray(ids), axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(w), 5.0 * chosen / chosen.sum(-1, keepdims=True),
+        rtol=1e-5)
+    assert ids.dtype == jnp.int32 and w.dtype == jnp.float32
+
+
+@PATHS
+@pytest.mark.parametrize("first, held", [(0, 12), (0, 4), (4, 4), (8, 4)])
+def test_a_share_is_its_experts_part_of_the_layer(layer, copies, first,
+                                                  held):
+    h = copied(layer, copies)
+    ids, w = moe.route(h, layer["gate"], layer["bias"], K, 5.0)
+    got, counts = moe.dropless_experts(
+        h, ids, w, layer["w1"][first:first + held],
+        layer["w2"][first:first + held], first=first, activation=relu2)
+    want, want_counts = by_hand(h, ids, w,
+                                layer["w1"][first:first + held],
+                                layer["w2"][first:first + held], first)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-4)
+    assert np.asarray(counts).tolist() == want_counts.tolist()
+    assert int(counts.sum()) == int(((np.asarray(ids) >= first)
+                                     & (np.asarray(ids) < first + held)).sum())
+
+
+@PATHS
+def test_nothing_is_dropped_when_every_token_takes_one_expert(layer,
+                                                              copies):
+    """The worst imbalance: every token on expert 2 (and on two absent
+    ones). A capacity layer would drop most of them."""
+    h, n = copied(layer, copies), T * copies
+    ids = jnp.tile(jnp.asarray([[2, 9, 11]], jnp.int32), (n, 1))
+    w = jnp.tile(jnp.asarray([[0.5, 0.3, 0.2]]), (n, 1))
+    got, counts = moe.dropless_experts(
+        h, ids, w, layer["w1"][:4], layer["w2"][:4], first=0,
+        activation=relu2)
+    want = 0.5 * relu2(h @ layer["w1"][2]) @ layer["w2"][2]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    assert np.asarray(counts).tolist() == [0, 0, n, 0]
+    assert int((counts > 0).sum()) == 1  # experts_hit, by hand
+
+
+@PATHS
+def test_tokens_sent_only_to_absent_experts_add_nothing(layer, copies):
+    ids = jnp.tile(jnp.asarray([[5, 9, 11]], jnp.int32), (T * copies, 1))
+    w = jnp.full((T * copies, K), 1.0 / K)
+    got, counts = moe.dropless_experts(
+        copied(layer, copies), ids, w, layer["w1"][:4], layer["w2"][:4],
+        first=0, activation=relu2)
+    assert float(jnp.abs(got).max()) == 0.0
+    assert int(counts.sum()) == 0
+
+
+@PATHS
+def test_rows_that_are_padding_are_routed_nowhere(layer, copies):
+    """A padded lane's other rows take no expert: zeros for them, the
+    real rows' result as it was, and the counts count real pairs only."""
+    h = copied(layer, copies)
+    ids, w = moe.route(h, layer["gate"], layer["bias"], K, 5.0)
+    live = jnp.arange(T * copies) % 3 != 1
+    args = (h, ids, w, layer["w1"][:6], layer["w2"][:6])
+    every, _ = moe.dropless_experts(*args, first=0, activation=relu2)
+    got, counts = moe.dropless_experts(*args, first=0, activation=relu2,
+                                       live=live)
+    np.testing.assert_allclose(np.asarray(got[live]),
+                               np.asarray(every[live]), atol=2e-5)
+    assert float(jnp.abs(got[~live]).max()) == 0.0
+    assert int(counts.sum()) == int((np.asarray(ids)[np.asarray(live)]
+                                     < 6).sum())
+
+
+def test_the_shape_chooses_the_path_and_not_the_result(layer):
+    """Up to DENSE_ROWS rows every held expert runs over every row; past
+    them the pairs are sorted and grouped. The caller has no say, and the
+    result is one: 144 rows that are six copies of 24 give, grouped, six
+    copies of what the 24 give batched."""
+    assert moe.DENSE_ROWS == 128
+
+    def share(h):
+        ids, w = moe.route(h, layer["gate"], layer["bias"], K, 5.0)
+        return moe.dropless_experts(h, ids, w, layer["w1"][:6],
+                                    layer["w2"][:6], first=0,
+                                    activation=relu2)
+
+    few, many = copied(layer, 1), copied(layer, 6)
+    (batched, few_counts), (grouped, many_counts) = share(few), share(many)
+    np.testing.assert_allclose(np.asarray(grouped),
+                               np.tile(np.asarray(batched), (6, 1)),
+                               atol=2e-4)
+    assert np.asarray(many_counts).tolist() \
+        == (6 * np.asarray(few_counts)).tolist()
+    assert "ragged_dot" in str(jax.make_jaxpr(lambda h: share(h)[0])(many))
+    assert "ragged_dot" not in str(jax.make_jaxpr(
+        lambda h: share(h)[0])(few))
+
+
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
+    """The share is tied to the model: at ``tiny``, the routed parts that
+    the two shares of the 8 experts give, plus the shared expert and what
+    every chip computes alike counted once, equal the reference's layer
+    with every expert held."""
+    cfg = nh.NemotronHConfig.tiny(dtype=jnp.float32,
+                                  param_dtype=jnp.float32,
+                                  experts_held=(0, 8))
+    p = nh._layer_init(jax.random.PRNGKey(4), "E", cfg)
+    # at its initial scale the routed part is a thousandth of the shared
+    # expert's: make it count
+    p = {**p, **{k: 8.0 * p[k] for k in ("w1", "w2", "w_up")}}
+    y = jax.random.normal(jax.random.PRNGKey(5), (2, 9, cfg.d_model))
+    whole = reference.latent_moe(
+        {"gate_w": p["router"], "e_score_correction_bias": p["router_bias"],
+         "fc1_latent_proj": p["w_down"], "fc2_latent_proj": p["w_up"],
+         "experts_up": p["w1"], "experts_down": p["w2"],
+         "shared_up": p["shared_w1"], "shared_down": p["shared_w2"]},
+        y, top_k=cfg.top_k, routed_scale=cfg.routed_scale, first_expert=0)
+    flat = y.reshape(-1, cfg.d_model)
+    parts, rows = [], 0
+    for first in (0, 4):  # two chips, four experts each
+        share = nh.NemotronHConfig.tiny(
+            dtype=jnp.float32, param_dtype=jnp.float32,
+            experts_held=(first, 4))
+        mine = {**p, "w1": p["w1"][first:first + 4],
+                "w2": p["w2"][first:first + 4]}
+        out, counts = nh._moe(mine, flat, share)
+        parts.append(out)
+        rows += int(counts.sum())
+    # every chip adds the shared expert: count it once
+    shared = nh._relu2(flat @ p["shared_w1"]) @ p["shared_w2"]
+    total = parts[0] + parts[1] - shared
+    scale = float(jnp.abs(whole).max())
+    assert float(jnp.abs(total.reshape(y.shape) - whole).max()) < 1e-5 * scale
+    assert rows == flat.shape[0] * cfg.top_k  # every pair landed somewhere
+    # and one share alone is not the layer
+    assert float(jnp.abs(parts[0].reshape(y.shape) - whole).max()) \
+        > 1e-2 * scale

@@ -1,0 +1,454 @@
+"""``models/nemotron_h.py`` against its plain reference
+(``benchmark/reference/nemotron_h.py``), at ``tiny`` size in float32 on
+seeded random weights: the whole forward pass, prefill then decoding through
+the cache (K/V rows and both Mamba states), the state's handling (a padded
+lane, a re-used slot, a free slot), and the engine serving it through
+``serve.run`` / ``handle.stream`` with its counters."""
+
+import dataclasses
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.loading import load_module
+from ray_tpu.models import gpt2
+from ray_tpu.models import nemotron_h as nh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+reference = load_module(os.path.join(REPO, "benchmark", "reference",
+                                     "nemotron_h.py"))
+family = load_module(os.path.join(REPO, "benchmark", "families",
+                                  "nemotron_h.py"))
+CFG = nh.NemotronHConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def ref_kwargs(cfg):
+    return dict(pattern=cfg.pattern, eps=cfg.eps, n_head=cfg.n_head,
+                n_kv_head=cfg.n_kv_head, head_dim=cfg.head_dim,
+                mamba_heads=cfg.mamba_heads,
+                mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
+                ssm_state=cfg.ssm_state, top_k=cfg.top_k,
+                routed_scale=cfg.routed_scale,
+                first_expert=cfg.experts_held[0])
+
+
+def to_ref(params, cfg):
+    return {"embeddings": params["embed"], "lm_head": params["lm_head"],
+            "norm_f": params["norm_f"],
+            "layers": [{ref: p[name] for name, ref
+                        in family.LAYER_NAMES[kind].items()}
+                       for kind, p in zip(cfg.pattern, params["layers"])]}
+
+
+def moved(params, seed=6):
+    """Every weight moved off its initial value: the norm scales start at
+    one, and a dropped or swapped scale would go unseen."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
+    return jax.tree.map(
+        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
+        params)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return moved(nh.nemotron_h_init(jax.random.PRNGKey(0), CFG))
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jnp.asarray(np.random.default_rng(1).integers(
+        0, CFG.vocab_size, (3, 40), dtype=np.int32))
+
+
+@pytest.fixture(scope="module")
+def want(params, tokens):
+    return reference.forward(to_ref(params, CFG), tokens, **ref_kwargs(CFG))
+
+
+def test_the_tiny_preset_holds_all_three_kinds_of_layer():
+    assert set(CFG.pattern) == {"M", "*", "E"}
+    assert CFG.experts_held[1] < CFG.n_experts  # a share, not the whole
+    assert nh.NemotronHConfig().pattern == nh.PUBLISHED_PATTERN
+    assert len(nh.PUBLISHED_PATTERN) == 88
+    assert [nh.PUBLISHED_PATTERN.count(k) for k in "ME*"] == [40, 40, 8]
+    with pytest.raises(ValueError, match="pattern"):
+        nh.NemotronHConfig.tiny(pattern="MXE")
+    with pytest.raises(ValueError, match="experts_held"):
+        nh.NemotronHConfig.tiny(experts_held=(6, 4))
+
+
+def test_weights_are_stored_in_bfloat16_by_default():
+    cfg = nh.NemotronHConfig.tiny()
+    params = nh.nemotron_h_init(jax.random.PRNGKey(0), cfg)
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(params))
+    cache = nh.nemotron_h_init_cache(cfg, 3, 16)
+    assert [s.dtype for s in cache["ssm"]] == [jnp.float32] * 2
+    assert cache["conv"].dtype == cache["k"].dtype == jnp.bfloat16
+    assert cache["k"].shape == (1, 3, 16, 2, 16)
+    assert cache["conv"].shape == (2, 3, 3, cfg.conv_dim)
+    assert [s.shape for s in cache["ssm"]] == [(3, 8, 16, 16)] * 2
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_programs_hold_the_precision_the_file_states(program):
+    """``correct`` cannot see the experts in float8 or the state in
+    bfloat16 through the logits (PERF.md section 7: both lie under the
+    rounding of bfloat16 activations), so the stated precision is held
+    here, by the programs' own types: from the benchmark's configuration
+    file, weights and products in bfloat16 and nothing narrower anywhere,
+    and a float32 state in and out (the router's float32 scores are
+    ``tests/test_moe_dropless.py``'s)."""
+    from benchmark.loading import load_json
+
+    config = load_json(os.path.join(
+        REPO, "benchmark", "configs", "nemotron3-super-120b-a12b.json"))
+    stated = family.system_config(config)
+    assert config["assumed"]["ssm_state_dtype"] == "float32"
+    assert (stated.param_dtype, stated.dtype, stated.ssm_state_dtype) \
+        == (jnp.bfloat16, jnp.bfloat16, jnp.float32)
+    cfg = nh.NemotronHConfig.tiny()  # the same defaults, at a CPU's size
+    assert (cfg.param_dtype, cfg.dtype, cfg.ssm_state_dtype) \
+        == (stated.param_dtype, stated.dtype, stated.ssm_state_dtype)
+    params = jax.eval_shape(
+        lambda: nh.nemotron_h_init(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: nh.nemotron_h_init_cache(cfg, 3, 16))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode":
+        fn = lambda p, c, t, n: nh.nemotron_h_decode_step(p, c, t, n, cfg)
+        args = (params, cache, i32(3), i32(3))
+    else:
+        fn = lambda p, c, t, s, n: nh.nemotron_h_prefill(p, c, t, s, n, cfg)
+        args = (params, cache, i32(2, 16), i32(2), i32(2))
+    text = str(jax.make_jaxpr(fn)(*args))
+    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
+    assert {"bf16", "f32"} <= types
+    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
+                                                  "i4", "u4"))}, types
+    new_cache = jax.eval_shape(fn, *args)[1]
+    assert [s.dtype for s in new_cache["ssm"]] == [jnp.float32] * 2
+
+
+def test_forward_agrees_with_the_reference(params, tokens, want):
+    got = nh.nemotron_h_forward(params, tokens, CFG)
+    assert got.shape == want.shape == (3, 40, CFG.vocab_size)
+    assert float(jnp.abs(got - want).max()) < 1e-4
+    # a prompt longer than one chunk, and not a multiple of it
+    assert tokens.shape[1] > 2 * CFG.chunk_size
+    assert tokens.shape[1] % CFG.chunk_size == 0
+    odd = nh.nemotron_h_forward(params, tokens[:, :37], CFG)
+    assert float(jnp.abs(odd - want[:, :37]).max()) < 1e-4
+
+
+def test_the_reference_without_a_mechanism_is_another_model(params, tokens,
+                                                            want):
+    """The controls: each of the model's own mechanisms moves the result
+    by far more than the comparisons' 1e-4."""
+    ref = to_ref(params, CFG)
+    kw = ref_kwargs(CFG)
+    # the score correction moves the choice of experts
+    flat = {**ref, "layers": [
+        {**p, "e_score_correction_bias":
+         jnp.zeros_like(p["e_score_correction_bias"])}
+        if "gate_w" in p else p for p in ref["layers"]]}
+    for other in (reference.forward(ref, tokens, **{**kw, "routed_scale": 1.0}),
+                  reference.forward(ref, tokens, **{**kw, "top_k": 2}),
+                  reference.forward(ref, tokens, **{**kw, "first_expert": 4}),
+                  reference.forward(flat, tokens, **kw)):
+        assert float(jnp.abs(other - want).max()) > 1e-2
+
+
+def serve_rows(params, tokens, lengths, steps, cache=None, slots=None,
+               cfg=CFG, lane=32):
+    """Prefill rows of ``lengths`` in a ``lane``-wide padded lane, then
+    ``steps`` decode steps fed ``tokens``' own continuation: the logits at
+    each row's last prompt token and after every step."""
+    r = len(lengths)
+    n_slots = 4
+    lengths = jnp.asarray(lengths, jnp.int32)
+    prompts = np.zeros((r, lane), np.int32)
+    for i, n in enumerate(np.asarray(lengths)):
+        prompts[i, :n] = np.asarray(tokens)[i, :n]
+    if cache is None:
+        cache = nh.nemotron_h_init_cache(cfg, n_slots, 64)
+    slots = jnp.arange(r) if slots is None else jnp.asarray(slots)
+    logits, cache = nh.nemotron_h_prefill(
+        params, cache, jnp.asarray(prompts), slots, lengths, cfg)
+    out = [logits]
+    for s in range(steps):
+        pos = jnp.zeros((n_slots,), jnp.int32).at[slots].set(lengths + s)
+        toks = jnp.zeros((n_slots,), jnp.int32).at[slots].set(
+            tokens[jnp.arange(r), lengths + s])
+        logits, cache, _ = nh.nemotron_h_decode_step(
+            params, cache, toks, pos, cfg)
+        out.append(logits[slots])
+    return jnp.stack(out, axis=1), cache
+
+
+@pytest.mark.parametrize("rows", [24, 144], ids=["batched", "grouped"])
+def test_the_routed_experts_keep_bfloat16s_precision(rows):
+    """What ``correct`` cannot see through the logits, held by numbers a
+    layer at a time: with the program's own types the routed part of an
+    ``E`` layer lies within 1e-2 of itself computed in float32 from the
+    same bfloat16-valued weights and the same routing (4e-3 and 5e-3
+    measured); with the experts' matrices rounded to float8, the control of
+    the configuration file's ``reason``, it lies 4e-2 off."""
+    tool = load_module(os.path.join(REPO, "benchmark", "tools",
+                                    "serve_check_many.py"))
+    cfg = nh.NemotronHConfig.tiny()
+    p = nh._layer_init(jax.random.PRNGKey(4), "E", cfg)
+    # the routed part alone, at a size that counts beside rounding
+    p = {**p, "shared_w2": jnp.zeros_like(p["shared_w2"]),
+         **{k: 8 * p[k] for k in ("w1", "w2")}}
+    y = jax.random.normal(jax.random.PRNGKey(5),
+                          (rows, cfg.d_model)).astype(cfg.dtype)
+    widen = lambda tree: jax.tree.map(lambda x: x.astype(jnp.float32), tree)
+    want, want_counts = nh._moe(widen(p), widen(y), CFG)
+
+    def off(layer):
+        got, counts = nh._moe(layer, y, cfg)
+        assert counts.tolist() == want_counts.tolist()  # one routing
+        return float(jnp.linalg.norm(widen(got) - want)
+                     / jnp.linalg.norm(want))
+
+    assert int(want_counts.sum()) > rows and off(p) < 1e-2
+    assert off({**p, **tool.rounded({k: p[k] for k in ("w1", "w2")}, 3)}) \
+        > 2.5e-2
+
+
+def test_a_thousand_steps_keep_the_state_a_prefill_computes(params):
+    """The SSM state's precision, held by numbers where the benchmark's
+    check cannot afford to (it rounds the state nine times; the error of a
+    narrower state builds over a long answer): after 1024 single steps the
+    state, in the type the configuration states, is the state one prefill
+    over the same tokens computes, to 1e-5 of its size (5e-7 measured). The
+    control, the state held in bfloat16, drifts 6e-3 to 2e-2."""
+    n = 1024
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (1, n), 0,
+                                CFG.vocab_size)
+
+    def drift(cfg):
+        def step(cache, at):
+            tok, t = at
+            zero = jnp.zeros((), jnp.int32)
+            _, cache, _ = nh.nemotron_h_decode_step(
+                params, cache, jnp.stack([tok, zero]), jnp.stack([t, zero]),
+                cfg)
+            return cache, ()
+
+        stepped, _ = jax.jit(lambda cache: jax.lax.scan(
+            step, cache, (tokens[0], jnp.arange(n, dtype=jnp.int32))))(
+                nh.nemotron_h_init_cache(cfg, 2, n))
+        _, filled = nh.nemotron_h_prefill(
+            params, nh.nemotron_h_init_cache(cfg, 2, n), tokens,
+            jnp.asarray([0]), jnp.asarray([n]), cfg)
+        wide = lambda s: np.asarray(s[0], np.float64)
+        return [np.linalg.norm(wide(a) - wide(b)) / np.linalg.norm(wide(b))
+                for a, b in zip(stepped["ssm"], filled["ssm"])]
+
+    assert CFG.ssm_state_dtype == nh.NemotronHConfig().ssm_state_dtype
+    assert max(drift(CFG)) < 1e-5
+    assert min(drift(dataclasses.replace(
+        CFG, ssm_state_dtype=jnp.bfloat16))) > 2e-3
+
+
+@pytest.mark.parametrize("lane", [32, 48])
+def test_prefill_then_decode_through_the_cache(params, tokens, want, lane):
+    """Rows of different ``length`` in one padded lane, then 10 decode
+    steps: every logits row is the reference's at that position. The wider
+    lane holds 144 rows for the experts, past ``DENSE_ROWS``: the grouped
+    products, where the narrower one runs the batched ones."""
+    lengths = [30, 19, 5]
+    got, _ = serve_rows(params, tokens, lengths, 10, lane=lane)
+    for i, n in enumerate(lengths):
+        assert float(jnp.abs(got[i] - want[i, n - 1:n + 10]).max()) < 1e-4
+
+
+def test_a_reused_slot_loses_its_old_state_whole(params, tokens, want):
+    """Serve rows, step them, then prefill OTHER rows into the same slots
+    (in another order, beside a scratch row): what the slots held before
+    leaves no trace in K/V, convolution tail or SSM state."""
+    _, cache = serve_rows(params, tokens, [30, 19, 5], 6)
+    assert all(float(jnp.abs(s[:3]).min()) > 0
+               for s in cache["ssm"])  # used, not empty
+    again = tokens[::-1]
+    lengths = [7, 28, 16]
+    got, _ = serve_rows(params, again, lengths, 8, cache=cache,
+                        slots=[2, 0, 1])
+    want_again = want[::-1]
+    for i, n in enumerate(lengths):
+        assert float(jnp.abs(got[i] - want_again[i, n - 1:n + 8]).max()) \
+            < 1e-4
+
+
+def test_prefill_state_is_the_state_of_single_steps(params, tokens):
+    """The convolution tail and the SSM state a prefill of ``length``
+    tokens leaves (padded lane, chunked scan) are those of ``length``
+    decode steps from an empty slot, in every ``M`` layer."""
+    lengths = [21, 2, 9]  # 2: shorter than the convolution's tail
+    _, by_prefill = serve_rows(params, tokens, lengths, 0)
+    cache = nh.nemotron_h_init_cache(CFG, 4, 64)
+    reached = {}
+    for t in range(max(lengths)):
+        pos = jnp.full((4,), t, jnp.int32)
+        toks = jnp.concatenate([tokens[:, t], jnp.zeros((1,), jnp.int32)])
+        _, cache, _ = nh.nemotron_h_decode_step(params, cache, toks, pos, CFG)
+        for i, n in enumerate(lengths):
+            if n == t + 1:
+                reached[i] = {"ssm": jnp.stack(cache["ssm"])[:, i],
+                              "conv": cache["conv"][:, :, i]}
+    for i in range(3):
+        assert float(jnp.abs(jnp.stack(by_prefill["ssm"])[:, i]
+                             - reached[i]["ssm"]).max()) < 1e-5
+        assert float(jnp.abs(by_prefill["conv"][:, :, i]
+                             - reached[i]["conv"]).max()) < 1e-5
+    # the scratch slot was never written
+    assert float(jnp.abs(jnp.stack(by_prefill["ssm"])[:, 3]).max()) == 0
+
+
+def test_inactive_prefill_rows_write_to_the_scratch_slot(params, tokens):
+    """A lane with one real row and one inactive row (length 1, slot =
+    the scratch one, as the engine fills it): the other slots' state and
+    K/V rows are as they were."""
+    _, cache = serve_rows(params, tokens, [30, 19, 5], 2)
+    stacked = lambda c: {**c, "ssm": jnp.stack(c["ssm"])}
+    before = jax.tree.map(np.asarray, stacked(cache))
+    prompts = np.zeros((2, 32), np.int32)
+    prompts[0, :12] = np.asarray(tokens)[1, :12]
+    _, after = nh.nemotron_h_prefill(
+        params, cache, jnp.asarray(prompts), jnp.asarray([1, 3]),
+        jnp.asarray([12, 1]), CFG)
+    after = stacked(after)
+    for name, slot_axis in (("k", 1), ("v", 1), ("ssm", 1), ("conv", 2)):
+        for slot in (0, 2):
+            np.testing.assert_array_equal(
+                np.take(np.asarray(after[name]), slot, axis=slot_axis),
+                np.take(before[name], slot, axis=slot_axis))
+        assert not np.array_equal(
+            np.take(np.asarray(after[name]), 1, axis=slot_axis),
+            np.take(before[name], 1, axis=slot_axis))
+
+
+def test_a_free_slot_stepped_500_times_stays_finite(params):
+    step = jax.jit(lambda c, t, n: nh.nemotron_h_decode_step(
+        params, c, t, n, CFG)[:2])
+    cache = nh.nemotron_h_init_cache(CFG, 2, 8)
+    toks, pos = jnp.asarray([7, 0]), jnp.zeros((2,), jnp.int32)
+    for _ in range(500):  # a free slot: its token and position stand still
+        logits, cache = step(cache, toks, pos)
+    assert bool(jnp.isfinite(logits).all())
+    assert all(bool(jnp.isfinite(x).all()) for x in jax.tree.leaves(cache))
+    assert float(jnp.abs(jnp.stack(cache["ssm"])).max()) < 1e3
+
+
+def test_the_kv_part_keeps_the_ring_contract(params, tokens):
+    """A position past ``cache_len`` wraps; with no position embedding
+    that is a window of ``cache_len`` rows."""
+    cache = nh.nemotron_h_init_cache(CFG, 1, 8)
+    for t in range(12):
+        _, cache, _ = nh.nemotron_h_decode_step(
+            params, cache, tokens[0, t][None], jnp.asarray([t]), CFG)
+    assert bool(jnp.isfinite(cache["k"]).all())
+    # rows 8..11 overwrote rows 0..3
+    fresh = nh.nemotron_h_init_cache(CFG, 1, 16)
+    for t in range(12):
+        _, fresh, _ = nh.nemotron_h_decode_step(
+            params, fresh, tokens[0, t][None], jnp.asarray([t]), CFG)
+    np.testing.assert_allclose(np.asarray(cache["k"][0, 0, :4]),
+                               np.asarray(fresh["k"][0, 0, 8:12]),
+                               atol=1e-5)
+
+
+# -- the engine ---------------------------------------------------------------
+
+
+@pytest.fixture
+def runtime():
+    import ray_tpu
+    from ray_tpu import serve
+
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4)
+    yield serve
+    try:
+        serve.shutdown()
+    except Exception:
+        pass
+    ray_tpu.shutdown()
+
+
+def test_the_engine_serves_the_references_greedy_continuation(runtime):
+    """``LLMEngine(model="nemotron_h")`` through ``serve.run`` /
+    ``handle.stream`` in float32: token for token the reference's greedy
+    choice, two compiled programs, and the step's counters in
+    ``llm_stats()`` without a second sync."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    import ray_tpu
+
+    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
+    handle = runtime.run(dep.bind(
+        model="nemotron_h", config=CFG, seed=3, max_batch=3, cache_len=32,
+        max_prompt_len=16, prefill_rows=2))
+    params = nh.nemotron_h_init(jax.random.PRNGKey(3), CFG)
+    ref, kw = to_ref(params, CFG), ref_kwargs(CFG)
+    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
+    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
+    for prompt in prompts:
+        toks = list(prompt)
+        for _ in range(6):  # causal: one padded shape serves every length
+            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
+            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
+        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
+        assert served == toks[len(prompt):]
+    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
+    assert stats["compiles"] == {"decode": 1, "prefill": 1}
+    assert stats["model"] == "nemotron_h"
+    assert stats["expert_layers"] == 2 and stats["experts_held"] == 4
+    # every step runs max_batch + 1 rows through 2 expert layers, top 3:
+    # at most 4 * 3 pairs a layer land here, at most 4 experts are hit
+    steps = stats["steps"]
+    assert steps >= 10
+    assert 0 < stats["experts_hit"] <= steps * 2 * 4
+    assert stats["experts_hit"] <= stats["expert_rows"] <= steps * 2 * 12
+    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
+
+
+def test_a_dense_familys_engine_and_decode_program_are_as_before():
+    """GPT-2 returns no counters: its engine reports no new key, and the
+    engine's step program is the model's own decode step and an argmax,
+    nothing joined to its tokens."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg = dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=jnp.float32)
+    eng = LLMEngine(model="gpt2", config=cfg, max_batch=2, cache_len=16,
+                    max_prompt_len=8)
+    try:
+        assert len(eng.generate([1, 2, 3], 4)) == 4
+        stats = eng.llm_stats()
+        assert not {"experts_hit", "expert_rows", "expert_layers",
+                    "experts_held"} & set(stats)
+        assert eng._step_counters == ()
+        toks, pos = jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32)
+        before = eng._compiles["decode"]
+        engine_jaxpr = jax.make_jaxpr(eng._step_fn)(
+            eng.params, eng._cache, toks, pos)
+        eng._compiles["decode"] = before  # tracing it again counted one
+
+        def plain(params, cache, tokens, pos):
+            logits, cache = gpt2.gpt2_decode_step(params, cache, tokens,
+                                                  pos, cfg)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+        plain_jaxpr = jax.make_jaxpr(jax.jit(plain, donate_argnums=(1,)))(
+            eng.params, eng._cache, toks, pos)
+        shapes = lambda j: [v.aval.str_short() for v in j.jaxpr.outvars]
+        assert shapes(engine_jaxpr) == shapes(plain_jaxpr)
+        count = lambda j: str(j).count(" = ")
+        assert count(engine_jaxpr) == count(plain_jaxpr)
+        assert "concatenate" not in str(engine_jaxpr).split("argmax")[-1]
+    finally:
+        eng.shutdown_engine()

@@ -1,0 +1,166 @@
+"""What the TPU's compiler makes of the hybrid model's two serving
+programs (PR 28).
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/nemotron3-super-120b-a12b.json`` and the cell's shapes
+(64 slots and the scratch one, a 2048-row cache, a [4, 1024] prefill lane):
+nothing runs, so nothing here is a time. It holds that both programs fit
+the chip beside their arguments, that the donated cache (K/V rows, the
+convolution tails, the float32 SSM state) is updated in its own buffers,
+and that no program copies a layer's expert stack or the whole state: XLA's
+choices decide that, not the jaxpr.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import nemotron_h as nh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SLOTS, CACHE_LEN, ROWS, PROMPT_LEN = 65, 2048, 4, 1024
+HBM = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "nemotron_h.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "nemotron3-super-120b-a12b.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: nh.nemotron_h_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(
+        lambda: nh.nemotron_h_init_cache(cfg, SLOTS, CACHE_LEN)))
+    programs = {
+        "decode": (lambda p, c, t, n: nh.nemotron_h_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(SLOTS), i32(SLOTS))),
+        "prefill": (lambda p, c, t, s, n: nh.nemotron_h_prefill(
+            p, c, t, s, n, cfg),
+                    (params, cache, i32(ROWS, PROMPT_LEN), i32(ROWS),
+                     i32(ROWS))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+                for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
+                                                        which):
+    """4.65 B bfloat16 parameters (9.30 GB) and 1.52 GB of cache are the
+    arguments; the cache is aliased to the output, so it is held once."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = 2 * nbytes((1, SLOTS, CACHE_LEN, 2, 128), 2) \
+        + nbytes((5, 3, SLOTS, cfg.conv_dim), 2) \
+        + nbytes((5, SLOTS, 128, 64, 128), 4)
+    assert cache_bytes == 1_519_431_680
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert 10.7e9 < mem.argument_size_in_bytes < 10.9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    # the step holds next to nothing of its own; the lane's sorted rows
+    # and chunked scan stay under 2.5 GB
+    assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 2.5e9}[which]
+
+
+SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                   r"([\w\-]+)\(")
+PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple",
+             "fusion", "dynamic-update-slice", "custom-call", "while",
+             "conditional", "call", "opt-barrier")
+
+
+def _unfused_lines(hlo_text):
+    """The instructions that make an array of their own: those of every
+    computation but the ones a ``fusion`` calls (inside a fusion a slice or
+    a convert is a step of one loop, not a buffer)."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    for block in hlo_text.split("\n\n"):
+        if block.lstrip().split(" ", 1)[0] not in fused:
+            yield from block.splitlines()[1:]
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
+    """A layer's expert stack is 128 x 1024 x 2688 bfloat16 (705 MB a
+    matrix) and the SSM state 5 arrays of 65 x 128 x 64 x 128 float32 (1.36 GB; a
+    layer's 273 MB). No ``copy``, ``transpose``, ``convert`` or slice in
+    either program makes an array of their size: the experts are read where
+    they lie, and the state is rewritten inside its donated buffer (fusions
+    and in-place ``dynamic-update-slice``s pass it on)."""
+    stack = nbytes((128, 1024, 2688), 1)  # elements
+    layer_state = nbytes((SLOTS, 128, 64, 128), 1)
+    theirs = {stack, layer_state, 5 * layer_state}
+    sizes = {"bf16", "f32"}
+    moved, lines = [], 0
+    for line in _unfused_lines(compiled[which].as_text()):
+        m = SHAPE.match(line)
+        if not m or m.group(1) not in sizes:
+            continue
+        lines += 1
+        dims = [int(d) for d in m.group(2).split(",")]
+        if nbytes(dims, 1) in theirs and m.group(3) not in PASSES_ON:
+            moved.append(line.strip()[:150])
+    assert lines > 200, "read no program"
+    assert moved == []
+
+
+def test_the_decode_step_runs_batched_products_and_the_lane_grouped_ones(
+        compiled):
+    """65 rows: every held expert over every row, one batched product a
+    matrix and no sort. 4096 rows: the pairs sorted by expert and the TPU's
+    grouped product (a ``ragged-dot`` custom call), so rows of absent
+    experts are not computed."""
+    decode, prefill = (compiled[k].as_text() for k in ("decode", "prefill"))
+    assert "ragged" not in decode
+    assert prefill.count("ragged-dot") >= 10  # 2 products x 5 layers
