@@ -325,9 +325,12 @@ def serve_leg(sizes: dict) -> dict:
     setup_s = time.perf_counter() - t0
     in_use = memory_by_device("bytes_in_use")
     if in_use is not None and len(in_use) > 1:
-        # One replica, one chip: the engine's fp32 params and bf16 K/V
-        # cache are on device 0 and on no other (whose train state is gone).
-        engine_bytes = 4 * cfg.n_params + 2 * 2 * cfg.n_layer * (
+        # One replica, one chip: the engine's params (as it says it
+        # stores them) and bf16 K/V cache are on device 0 and on no other
+        # (whose train state is gone).
+        held = ray_tpu.get(handle.llm_stats.remote(),
+                           timeout=60)["param_bytes"]
+        engine_bytes = sum(held.values()) + 2 * 2 * cfg.n_layer * (
             eng["max_batch"] + 1) * eng["cache_len"] * cfg.d_model
         require(in_use[0] - max(in_use[1:]) >= 0.9 * engine_bytes,
                 f"engine ({engine_bytes} bytes) is not on device 0 alone: "

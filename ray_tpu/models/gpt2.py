@@ -93,6 +93,16 @@ class GPT2Config:
         per_layer = 12 * d * d + 13 * d  # qkv+proj+mlp weights & biases + 2 LN
         return v * d + s * d + l * per_layer + 2 * d
 
+    def serving_dtypes(self, params: Params) -> Params:
+        """For each leaf of ``params``, the type in which ``gpt2_prefill``
+        and ``gpt2_decode_step`` consume it, as a tree like ``params``:
+        what an engine stores (``_SERVED_IN_DTYPE``, beside the programs).
+        Training keeps ``param_dtype``: the optimizer needs the master."""
+        dt = jnp.dtype(self.dtype)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: dt if path[-1].key in _SERVED_IN_DTYPE
+            else x.dtype, params)
+
     @classmethod
     def small(cls) -> "GPT2Config":
         return cls()  # 124M
@@ -405,6 +415,21 @@ def gpt2_init_cache(cfg: GPT2Config, slots: int, cache_len: int) -> Params:  # d
     the cache rides ``cfg.dtype``, never fp32)."""
     shape = (cfg.n_layer, slots, cache_len, cfg.n_head, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+# What an engine stores in ``cfg.dtype`` (``GPT2Config.serving_dtypes``): the
+# leaves whose every use in ``gpt2_decode_step`` and ``gpt2_prefill`` is
+# ``.astype(cfg.dtype)`` of the whole leaf. An engine never updates a weight,
+# so that cast has the same operand and the same result at every step for as
+# long as it lives: it stores the result once (the very cast the step
+# applied, so every product sees the operands it saw) and the ``.astype(dt)``
+# below are then no-ops. A weight the programs come to use that way belongs
+# here too (tests/test_serving_params.py reads their jaxprs for a cast of an
+# input this list leaves out); the LayerNorm scales and biases are multiplied
+# in float32 as they are held, and do not.
+_SERVED_IN_DTYPE = frozenset({
+    "wte", "wpe", "attn_qkv_w", "attn_qkv_b", "attn_out_w", "attn_out_b",
+    "mlp_in_w", "mlp_in_b", "mlp_out_w", "mlp_out_b"})
 
 
 # jax-hot-path: traced into the engine's single compiled decode step

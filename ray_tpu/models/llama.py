@@ -64,6 +64,15 @@ class LlamaConfig:
         assert self.n_head % self.n_kv_head == 0, "GQA needs even groups"
         assert self.d_model % self.n_head == 0
 
+    def serving_dtypes(self, params: Params) -> Params:
+        """For each leaf of ``params``, the type in which ``llama_prefill``
+        and ``llama_decode_step`` consume it: what an engine stores
+        (``_SERVED_IN_DTYPE``; see ``GPT2Config.serving_dtypes``)."""
+        dt = jnp.dtype(self.dtype)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: dt if path[-1].key in _SERVED_IN_DTYPE
+            else x.dtype, params)
+
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
@@ -299,6 +308,14 @@ def _rope_at(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+# What an engine stores in ``cfg.dtype`` (``LlamaConfig.serving_dtypes``): the
+# leaves whose every use in ``llama_decode_step`` and ``llama_prefill`` is
+# ``.astype(cfg.dtype)`` of the whole leaf (see ``gpt2._SERVED_IN_DTYPE``);
+# the RMSNorm scales are multiplied in float32 and are not among them.
+_SERVED_IN_DTYPE = frozenset({
+    "embed", "lm_head", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"})
 
 
 # jax-hot-path: traced into the engine's single compiled decode step

@@ -110,6 +110,14 @@ class NemotronHConfig:
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
 
+    def serving_dtypes(self, params: Params) -> Params:
+        """How an engine stores ``params``: as ``nemotron_h_init`` made
+        them (see ``GPT2Config.serving_dtypes``). The matrices are held in the
+        type the two programs multiply with, and the small leaves the
+        programs widen (router, ``a_log``, ``dt_bias``, ``d_skip``, norms)
+        stay in the type the configuration's file states for them."""
+        return jax.tree.map(lambda x: x.dtype, params)
+
     def serving_stats(self) -> dict:
         """What ``llm_stats()`` says of the model beside its counters."""
         return {"expert_layers": self.count("E"),

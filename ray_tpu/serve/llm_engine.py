@@ -145,8 +145,34 @@ def _model_bundle(model: str, config, preset: str):
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h)")
 
 
+def _stored_params(init, key, cfg):
+    """The family's seeded parameters, each leaf in the type in which the
+    family's two programs consume it (``cfg.serving_dtypes``: the family
+    knows its leaves, the engine does not), and the bytes held by stored
+    type. Leaf by leaf, each source let go as soon as its cast exists, so
+    that construction holds one leaf's copy beside the tree and the engine
+    ends with ONE copy of the weights."""
+    import jax
+
+    params = init(key, cfg)
+    leaves, treedef = jax.tree.flatten(params)
+    dtypes = treedef.flatten_up_to(cfg.serving_dtypes(params))
+    del params  # from here on `leaves` holds the only references
+    held: Dict[str, int] = {}
+    for i, dt in enumerate(dtypes):
+        if leaves[i].dtype != dt:
+            leaves[i] = jax.block_until_ready(leaves[i].astype(dt))
+        held[str(dt)] = held.get(str(dt), 0) + leaves[i].nbytes
+    return jax.block_until_ready(treedef.unflatten(leaves)), held
+
+
 class LLMEngine:
     """The deployment callable: one decode engine per replica.
+
+    The engine never updates a weight, so it stores each parameter leaf
+    once in the type its two programs would cast it to at every step (the
+    family's ``serving_dtypes``) and keeps nothing of what it was cast
+    from: the step reads the bytes it multiplies with and no others.
 
     Deploy it like any Serve class::
 
@@ -210,8 +236,8 @@ class LLMEngine:
         # the two below, then the first call of each jitted program
         # (trace + compile or cache load + run), stamped where it runs.
         t0 = time.perf_counter()
-        self.params = jax.block_until_ready(
-            init(jax.random.PRNGKey(seed), cfg))
+        self.params, self._param_bytes = _stored_params(
+            init, jax.random.PRNGKey(seed), cfg)
         t1 = time.perf_counter()
         # One scratch slot past max_batch: inactive prefill rows write
         # their pad garbage there, keeping the prefill shape fixed.
@@ -881,6 +907,7 @@ class LLMEngine:
             "queued": queued,
             "compiles": dict(self._compiles),
             "init_s": dict(self._init_s),
+            "param_bytes": dict(self._param_bytes),
             "mean_occupancy": round(c["occupancy_sum"] / steps, 3)
             if steps else 0.0,
             **self._model_stats,
