@@ -42,8 +42,8 @@ import re
 import sys
 import time
 
-# GPT-2 small as bench.py shapes it (the one configuration with a chip
-# record), with the attention the library chooses by itself.
+# GPT-2 small as the benchmark's cell train_gpt2s_1chip shapes it, with
+# the attention the library chooses by itself.
 FULL = {
     "model": {"remat": "dots", "scan_layers": False, "use_flash": None},
     "per_chip": 16, "steps": 5, "min_mosaic_calls": 24,
@@ -412,7 +412,6 @@ def main(argv=None) -> int:
         jax.config.update("jax_num_cpu_devices", args.cpu_rehearsal)
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.scripts.measure import device_summary
     from ray_tpu.util.compile_cache import ensure_compile_cache
 
     cache_dir = ensure_compile_cache()
@@ -421,7 +420,9 @@ def main(argv=None) -> int:
         lambda event, **_: cache.update([event.rsplit("/", 1)[-1]])
         if event.startswith("/jax/compilation_cache/") else None)
 
-    device = device_summary()
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
     say(f"device: {json.dumps(device)}")
     if rehearsal:
         say("CPU REHEARSAL at toy size: control flow only, kernels "

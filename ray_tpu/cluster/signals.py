@@ -81,12 +81,10 @@ _NAMED_SIGNALS: Dict[str, tuple] = {
                     None, {}),
     "queue_depth_trend": ("trend", "ray_tpu_serve_router_queue_depth",
                           None, {}),
-    # Step anatomy plane (round 19). mfu averages across rank series
-    # (summing ranks would report a 2-rank gang at 40% as 80%);
-    # step_p99 is the classic per-report step residual; sync_ratio is
-    # the sync phase's share of the per-rank anatomy gauges — the
-    # "gang is waiting, not computing" burn signal.
-    "mfu": ("gauge_mean", "ray_tpu_mfu_percent", None, {}),
+    # Step anatomy plane (round 19). step_p99 is the classic
+    # per-report step residual; sync_ratio is the sync phase's share
+    # of the per-rank anatomy gauges — the "gang is waiting, not
+    # computing" burn signal.
     "step_p99": ("quantile", "ray_tpu_train_step_phase_seconds",
                  0.99, {"phase": "step"}),
     "sync_ratio": ("gauge_ratio", "ray_tpu_step_phase_seconds",
@@ -94,7 +92,7 @@ _NAMED_SIGNALS: Dict[str, tuple] = {
 }
 
 _GENERIC_OPS = ("rate", "delta", "gauge_avg", "gauge_max", "gauge_last",
-                "gauge_mean", "trend", "p50", "p90", "p95", "p99")
+                "trend", "p50", "p90", "p95", "p99")
 
 _SLO_RE = re.compile(
     r"^\s*(?P<sig>[a-zA-Z_][a-zA-Z0-9_]*)"
@@ -145,7 +143,8 @@ def parse_slo(expr: str) -> dict:
         signal = named
     # Unit scaling AFTER signal resolution: a family measured in
     # percent (``..._percent``) takes `< 40%` literally as 40, not
-    # 0.4 — `mfu{trial="x"} < 40% over 120s` must mean what it says.
+    # 0.4 — `gauge_avg(ray_tpu_worker_cpu_percent) > 90% over 120s`
+    # must mean what it says.
     if unit == "ms":
         threshold /= 1e3
     elif unit == "%":
@@ -409,26 +408,6 @@ class MetricsRing:
             return out
         return out.get("")
 
-    def gauge_mean_over_window(self, name: str, window_s: float,
-                               match: Optional[dict] = None,
-                               group_by: Optional[str] = None):
-        """Mean ACROSS matched series of each series' window average.
-        ``gauge_over_window`` sums series (per-node CPU semantics);
-        utilization families like MFU need the mean — summing would
-        report a 2-rank gang at 40% each as 80%."""
-        _, start = self._anchor(window_s)
-        per_group: Dict[str, List[float]] = {}
-        for labels, samples in self._matched(name, start, match):
-            key = (_labels_get(labels, group_by) or "") if group_by \
-                else ""
-            vals = [v for _, v in samples]
-            per_group.setdefault(key, []).append(
-                sum(vals) / len(vals))
-        out = {k: sum(v) / len(v) for k, v in per_group.items()}
-        if group_by:
-            return out
-        return out.get("")
-
     def trend(self, name: str, window_s: float,
               match: Optional[dict] = None) -> Optional[float]:
         """Per-second growth of a gauge over the window: (second-half
@@ -582,12 +561,6 @@ class SignalPlane:
                 return {"ok": True, "op": op, "name": name,
                         "value": value,
                         "window_s": self.ring.window_span(window_s)}
-            if op == "gauge_mean":
-                value = self.ring.gauge_mean_over_window(
-                    name, window_s, match, group_by)
-                return {"ok": True, "op": op, "name": name,
-                        "value": value,
-                        "window_s": self.ring.window_span(window_s)}
             if op == "trend":
                 value = self.ring.trend(name, window_s, match)
                 return {"ok": True, "op": op, "name": name,
@@ -672,8 +645,6 @@ class SignalPlane:
         if kind in ("gauge_avg", "gauge_max", "gauge_last"):
             return self.ring.gauge_over_window(
                 a, window_s, kind[len("gauge_"):], match)
-        if kind == "gauge_mean":
-            return self.ring.gauge_mean_over_window(a, window_s, match)
         if kind == "trend":
             return self.ring.trend(a, window_s, match)
         if kind == "gauge_ratio":
@@ -854,24 +825,19 @@ class SignalPlane:
             if down:
                 entry["downtime_s"] = round(down, 1)
             train[trial] = entry
-        # Step anatomy: windowed MFU per trial plus the straggler
-        # verdict from the per-rank phase gauges (the same attributor
-        # train_stats uses, so top and stats can never disagree).
+        # Step anatomy: the straggler verdict from the per-rank phase
+        # gauges (the same attributor train_stats uses, so top and
+        # stats can never disagree).
         from ray_tpu.util.goodput import (
             ANATOMY_PHASES,
             straggler_attribution,
         )
 
-        mfu_by_trial = ring.gauge_mean_over_window(
-            "ray_tpu_mfu_percent", window_s, group_by="trial") or {}
         anat_trials = set(ring.gauge_over_window(
             "ray_tpu_step_phase_seconds", window_s, "last",
             group_by="trial") or {})
-        for trial in sorted(
-                (set(mfu_by_trial) | anat_trials) - {""}):
+        for trial in sorted(anat_trials - {""}):
             entry = train.setdefault(trial, {})
-            if mfu_by_trial.get(trial) is not None:
-                entry["mfu_pct"] = round(mfu_by_trial[trial], 2)
             rank_phases: Dict[str, Dict[str, float]] = {}
             for phase in ANATOMY_PHASES:
                 per_rank = ring.gauge_over_window(

@@ -779,21 +779,17 @@ const RENDER = {
       const anat = r.anatomy || {};
       return Object.entries(anat.ranks || {}).map(([rank, phases]) => ({
         trial: r.name, rank, phases,
-        mfu: (anat.mfu_pct || {})[rank],
         straggler: anat.straggler,
       }));
     });
     if (anatRows.length) {
       wrap.appendChild(el("h3", "", "step anatomy (per rank)"));
       wrap.appendChild(table(
-        ["trial", "rank", "mfu %", "data_wait", "host", "compute",
-         "sync", "verdict"],
+        ["trial", "rank", "data_wait", "host", "compute", "sync",
+         "verdict"],
         anatRows, (r, c) => {
           if (c === "trial") return el("td", "", r.trial);
           if (c === "rank") return el("td", "", r.rank);
-          if (c === "mfu %") return el("td",
-            r.mfu != null && r.mfu < 40 ? "warn" : "",
-            r.mfu != null ? r.mfu.toFixed(1) : "—");
           if (["data_wait", "host", "compute", "sync"].includes(c)) {
             const v = (r.phases || {})[c];
             return el("td", "mono",

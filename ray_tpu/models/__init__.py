@@ -1,6 +1,6 @@
 """Model zoo: pure-JAX pytree models designed for pjit sharding.
 
-Flagship: GPT-2 (the BASELINE.json north-star workload); Nemotron-H (a
+Flagship: GPT-2 (the benchmark's training cells); Nemotron-H (a
 hybrid of Mamba-2, attention and latent-MoE layers) is served only. Models are plain
 functions over parameter pytrees — no framework Module state — so the same
 code runs under any mesh and any rules table.

@@ -1,7 +1,8 @@
 """GPT-2 in pure JAX, sharding-annotated, scan-over-layers, remat-able.
 
-This is the flagship training workload (BASELINE.json: GPT-2 Train benchmark,
-target >=45% MFU on a v4 slice). Design choices for TPU:
+This is the flagship training workload: the benchmark's cells
+``train_gpt2s_1chip`` and ``train_gpt2xl_4chip`` (``BENCHMARK.json``; what
+they measured is in ``PERF_LEDGER.jsonl``). Design choices for TPU:
 
 * Parameters are a plain pytree of arrays plus a parallel pytree of *logical
   axis names* (``gpt2_param_axes``); physical shardings come from

@@ -56,9 +56,9 @@ _MAX_BLOCK_ROWS = 256
 # row block instead of blowing the ~16 MB VMEM.
 _BLOCK_BYTES = 2 * 1024 * 1024
 
-# Trace-time kernel-launch counters, keyed by kernel name. Tests and
-# fused_norm_bench read these to assert the Pallas path (vs the XLA
-# fallback) was actually taken; machine-independent by construction.
+# Trace-time kernel-launch counters, keyed by kernel name. Tests read
+# these to assert the Pallas path (vs the XLA fallback) was actually
+# taken; machine-independent by construction.
 KERNEL_INVOCATIONS: collections.Counter = collections.Counter()
 
 

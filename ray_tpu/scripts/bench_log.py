@@ -1,12 +1,12 @@
-"""Persist on-chip benchmark evidence (VERDICT r5 next-round item 1a).
+"""Persist on-chip evidence from the control-plane harnesses.
 
-Every successful on-chip measurement from ``bench.py`` and
-``scripts/tpu_sweep.py`` is appended as one JSON line to a committed
-``BENCH_TPU_SESSIONS.jsonl`` at the repo root, so perf claims have a
-timestamped, in-repo evidence trail instead of living only in session
-logs. Override the destination with ``RAY_TPU_BENCH_LOG`` (tests point
-it at a tmp file; CI containers without a writable checkout can point it
-at /tmp or set it empty to disable).
+Each ``record_*`` helper appends one JSON line to a committed
+``BENCH_TPU_SESSIONS.jsonl`` at the repo root when its harness ran on an
+accelerator. Speeds are not kept here: those come from ``python3 -m
+benchmark.run`` and live in ``PERF_LEDGER.jsonl``. Override the
+destination with ``RAY_TPU_BENCH_LOG`` (tests point it at a tmp file; CI
+containers without a writable checkout can point it at /tmp or set it
+empty to disable).
 
 Appending is best-effort by design: a benchmark must never fail because
 the evidence file is unwritable.
@@ -26,8 +26,8 @@ FILENAME = "BENCH_TPU_SESSIONS.jsonl"
 KNOWN_BENCHES = frozenset({
     "task_overhead", "memory_pressure", "chaos_soak", "scalebench",
     "drain_recovery_ms", "serve_latency", "input_pipeline", "goodput",
-    "analyze", "gang_recovery", "llm_serving", "streaming_dataflow",
-    "signal_plane", "fleet_scaling", "step_anatomy",
+    "analyze", "gang_recovery", "streaming_dataflow", "signal_plane",
+    "fleet_scaling",
 })
 
 
@@ -199,31 +199,6 @@ def record_serve_latency(*, client: dict, server: dict, agreement: dict,
         "mode": mode,
         "connections": int(connections),
         "n_requests": int(n_requests),
-        "client": dict(client),
-        "server": dict(server),
-        "agreement": dict(agreement),
-    }
-    entry.update(extra)
-    entry["committed_to"] = record_if_on_chip(dict(entry), path)
-    return entry
-
-
-def record_llm_serving(*, client: dict, server: dict, agreement: dict,
-                       streams: int, tokens_s: float, device: str = "",
-                       path: str | None = None, **extra) -> dict:
-    """Continuous-batching LLM serving evidence (``serve_bench --llm``):
-    client-measured TTFT p50/p99 + aggregate tokens/s over N concurrent
-    token streams, the engine-side metric view of the same streams, and
-    the agreement verdict (count-exact TTFT/token totals, quantile
-    agreement, the single-compiled-shape assertion) — a one-sided
-    throughput claim is exactly what this bench exists to prevent.
-    Committed to the evidence trail only on an accelerator; returns the
-    entry (with ``committed_to``) either way."""
-    entry: dict = {
-        "bench": "llm_serving",
-        "device": device,
-        "streams": int(streams),
-        "tokens_s": float(tokens_s),
         "client": dict(client),
         "server": dict(server),
         "agreement": dict(agreement),
@@ -471,12 +446,10 @@ def _is_num(v) -> bool:
 def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
     """Schema errors for one parsed JSONL entry ([] = valid).
 
-    Three valid shapes:
+    Two valid shapes:
     * header — ``{"schema": <str>, ...}``; ONLY the first line of the
       file (``allow_header=True``) may take this shape, so a 'schema'
       key on a data line can't smuggle it past validation;
-    * throughput point — ``script`` (+``config``) lines from bench.py /
-      tpu_sweep.py: need ts, a non-CPU device, tok/s and MFU numbers;
     * named bench — ``bench`` lines from the record_* helpers: need ts
       and a non-CPU device.
     """
@@ -499,9 +472,6 @@ def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
     elif device.lower() == "cpu":
         errs.append("'device' is cpu — CPU numbers must not enter the "
                     "on-chip evidence trail")
-    # 'bench' takes precedence: the record_* helpers also stamp a
-    # provenance 'script' key (chaos_soak, serve_bench), which must not
-    # route their lines into the throughput-point schema.
     if "bench" in obj:
         if obj["bench"] not in KNOWN_BENCHES:
             errs.append(f"unknown bench {obj['bench']!r}")
@@ -618,25 +588,6 @@ def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
                     or not obj.get("trigger"):
                 errs.append("gang_recovery line missing 'trigger' "
                             "(drain | node_death)")
-        elif obj["bench"] == "llm_serving":
-            # The headline IS ttft + throughput, cross-checked: a line
-            # without both views and the verdict is an unverified
-            # serving claim.
-            client = obj.get("client")
-            if not (isinstance(client, dict)
-                    and _is_num(client.get("ttft_p50_ms"))
-                    and _is_num(client.get("ttft_p99_ms"))):
-                errs.append("llm_serving line missing numeric "
-                            "client.ttft_p50_ms/ttft_p99_ms")
-            if not _is_num(obj.get("tokens_s")):
-                errs.append("llm_serving line missing numeric tokens_s")
-            if not isinstance(obj.get("server"), dict):
-                errs.append("llm_serving line missing server dict")
-            agreement = obj.get("agreement")
-            if not (isinstance(agreement, dict)
-                    and isinstance(agreement.get("ok"), bool)):
-                errs.append("llm_serving line missing boolean "
-                            "agreement.ok")
         elif obj["bench"] == "signal_plane":
             # The line's claim is "the history ring answers truthfully
             # and cheaply": the windowed-vs-client agreement verdict,
@@ -653,36 +604,6 @@ def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
             if not _is_num(obj.get("series")):
                 errs.append("signal_plane line missing numeric "
                             "series count")
-        elif obj["bench"] == "step_anatomy":
-            # The line's claim is "we know where the step wall went and
-            # how close to peak the chip ran": the MFU number, the
-            # phase partition (which must actually SUM to the step
-            # wall — a decomposition that doesn't partition is a
-            # narrative, not an accounting), and the cost-model-vs-
-            # measured agreement verdict are all load-bearing.
-            if not _is_num(obj.get("mfu")):
-                errs.append("step_anatomy line missing numeric mfu")
-            wall = obj.get("step_wall_s")
-            phases = obj.get("phases")
-            if not _is_num(wall):
-                errs.append("step_anatomy line missing numeric "
-                            "step_wall_s")
-            if not (isinstance(phases, dict) and phases
-                    and all(_is_num(v) for v in phases.values())):
-                errs.append("step_anatomy line missing numeric "
-                            "phases dict")
-            elif _is_num(wall):
-                total = sum(phases.values())
-                if abs(total - wall) > max(1e-6, 0.01 * wall):
-                    errs.append(
-                        f"step_anatomy phases sum to {total:.6f}s but "
-                        f"step_wall_s is {wall:.6f}s — the phases must "
-                        f"partition the step wall exactly")
-            agreement = obj.get("agreement")
-            if not (isinstance(agreement, dict)
-                    and isinstance(agreement.get("ok"), bool)):
-                errs.append("step_anatomy line missing boolean "
-                            "agreement.ok")
         elif obj["bench"] == "serve_latency":
             # A serve latency line must carry both views AND the
             # agreement verdict — a client-only (or server-only) number
@@ -703,20 +624,9 @@ def check_line(obj: object, *, allow_header: bool = False) -> list[str]:
                     and isinstance(agreement.get("ok"), bool)):
                 errs.append("serve_latency line missing boolean "
                             "agreement.ok")
-    elif "script" in obj:
-        if obj["script"] not in ("bench", "tpu_sweep"):
-            errs.append(f"unknown script {obj['script']!r}")
-        if not isinstance(obj.get("config"), str):
-            errs.append("script line missing 'config'")
-        if not any(_is_num(obj.get(k))
-                   for k in ("tok_s", "tokens_per_sec_per_chip")):
-            errs.append("script line missing tok_s/"
-                        "tokens_per_sec_per_chip")
-        if not any(_is_num(obj.get(k)) for k in ("mfu", "value")):
-            errs.append("script line missing mfu/value")
     else:
-        errs.append("neither a header ('schema'), a throughput point "
-                    "('script'), nor a named bench ('bench')")
+        errs.append("neither a header ('schema') nor a named bench "
+                    "('bench')")
     return errs
 
 
@@ -749,54 +659,11 @@ def main(argv: list[str] | None = None) -> int:
                     help="validate every line of the evidence file "
                          "against the expected schema; exit 1 on any "
                          "malformed line")
-    ap.add_argument("--regress", metavar="FRESH", default=None,
-                    help="perf-regression sentinel: diff a fresh "
-                         "perfsuite artifact (MICROBENCH-shaped JSON) "
-                         "against the committed MICROBENCH.json; exit "
-                         "1 on any gated metric moving past tolerance "
-                         "or any committed-true 'ok' verdict going "
-                         "false")
-    ap.add_argument("--against", metavar="COMMITTED", default=None,
-                    help="baseline artifact for --regress (default: "
-                         "HEAD's MICROBENCH.json via git, falling back "
-                         "to the working-tree file)")
     ap.add_argument("path", nargs="?", default=None,
                     help=f"evidence file (default: committed {FILENAME})")
     args = ap.parse_args(argv)
-    if args.regress:
-        try:
-            with open(args.regress) as f:
-                fresh = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"bench_log regress: cannot read fresh artifact "
-                  f"{args.regress}: {e}")
-            return 1
-        if args.against:
-            try:
-                with open(args.against) as f:
-                    committed = json.load(f)
-            except (OSError, ValueError) as e:
-                print(f"bench_log regress: cannot read baseline "
-                      f"{args.against}: {e}")
-                return 1
-        else:
-            committed = _committed_microbench()
-            if committed is None:
-                print("bench_log regress: no committed MICROBENCH.json "
-                      "to diff against — nothing to gate")
-                return 0
-        problems = regress_check(fresh, committed)
-        if problems:
-            for p in problems:
-                print(f"bench_log regress: {p}")
-            print(f"bench_log regress: FAIL ({len(problems)} "
-                  f"regression(s) vs committed artifact)")
-            return 1
-        print("bench_log regress: OK (no gated metric regressed, no "
-              "committed verdict went false)")
-        return 0
     if not args.check:
-        ap.error("nothing to do (pass --check or --regress)")
+        ap.error("nothing to do (pass --check)")
     path = args.path or default_path()
     try:
         problems = check_file(path)
@@ -864,139 +731,6 @@ def record_drain_recovery(proactive_drain_ms: float,
     entry.update(extra)
     entry["committed_to"] = record_if_on_chip(dict(entry), path)
     return entry
-
-
-def record_step_anatomy(*, mfu: float, phases: dict, step_wall_s: float,
-                        agreement: dict, straggler: dict | None = None,
-                        device: str = "", path: str | None = None,
-                        **extra) -> dict:
-    """Step-anatomy evidence (``scripts/anatomy_bench.py``): the
-    cost-model MFU, the exact phase partition of one step's wall
-    (data_wait / host / compute / sync must sum to ``step_wall_s``),
-    the cost-model-vs-measured agreement verdict, and — when a seeded
-    straggler ran — the attribution verdict. Committed to the evidence
-    trail only on a real accelerator; returns the entry (with
-    ``committed_to``) either way."""
-    entry: dict = {
-        "bench": "step_anatomy",
-        "device": device,
-        "mfu": round(float(mfu), 2),
-        "step_wall_s": float(step_wall_s),
-        "phases": {k: float(v) for k, v in dict(phases).items()},
-        "agreement": dict(agreement),
-    }
-    if straggler:
-        entry["straggler"] = dict(straggler)
-    entry.update(extra)
-    entry["committed_to"] = record_if_on_chip(dict(entry), path)
-    return entry
-
-
-# --------------------------------------------------------------------------
-# Perf-regression sentinel (round 19): diff a fresh perfsuite artifact
-# against the committed MICROBENCH.json. A perf number nobody compares
-# is a perf number that silently rots — this is the comparison, run as
-# the last perfsuite stage and as
-# ``python -m ray_tpu.scripts.bench_log --regress FRESH [--against OLD]``.
-# --------------------------------------------------------------------------
-
-# Numeric gates: dotted section path -> (direction, relative tolerance).
-# direction "higher" = the committed value is a floor (fresh may not
-# drop more than tol below it); "lower" = a ceiling (fresh may not rise
-# more than tol above it). Tolerances are deliberately loose — the
-# sentinel exists to catch the 2x cliff nobody noticed, not to flake on
-# scheduler jitter.
-REGRESS_GATES: dict[str, tuple[str, float]] = {
-    "step_anatomy.mfu": ("higher", 0.25),
-    "step_anatomy.step_wall_s": ("lower", 0.25),
-    "step_anatomy.cost_model.flops_ratio": ("lower", 0.25),
-    "goodput.goodput_pct": ("higher", 0.15),
-    "serve_latency.client.p99_ms": ("lower", 0.50),
-    "signal_plane.query_p50_ms": ("lower", 0.50),
-}
-
-
-def _dig(obj, dotted: str):
-    for part in dotted.split("."):
-        if not isinstance(obj, dict) or part not in obj:
-            return None
-        obj = obj[part]
-    return obj
-
-
-def _ok_paths(obj, prefix: str = "") -> dict[str, bool]:
-    """Every boolean-valued 'ok' key in a nested artifact, by dotted
-    path — the generic invariant: a check that passed in the committed
-    artifact must not start failing in a fresh run."""
-    out: dict[str, bool] = {}
-    if isinstance(obj, dict):
-        for k, v in obj.items():
-            p = f"{prefix}.{k}" if prefix else str(k)
-            if k == "ok" and isinstance(v, bool):
-                out[p] = v
-            else:
-                out.update(_ok_paths(v, p))
-    return out
-
-
-def regress_check(fresh: dict, committed: dict) -> list[str]:
-    """Regressions in a fresh perfsuite artifact relative to the
-    committed one ([] = clean). Two rules: (1) numeric gates — a
-    REGRESS_GATES metric present in BOTH artifacts must not move in the
-    bad direction by more than its relative tolerance; (2) verdict
-    preservation — any boolean 'ok' that is true in the committed
-    artifact and present in the fresh one must still be true. Sections
-    or metrics absent from either side are skipped (a fresh artifact
-    that only ran one stage gates only that stage)."""
-    problems: list[str] = []
-    for dotted, (direction, tol) in REGRESS_GATES.items():
-        old = _dig(committed, dotted)
-        new = _dig(fresh, dotted)
-        if not (_is_num(old) and _is_num(new)) or old == 0:
-            continue
-        if direction == "higher":
-            floor = old * (1.0 - tol)
-            if new < floor:
-                problems.append(
-                    f"{dotted}: {new:.4g} fell below committed "
-                    f"{old:.4g} by more than {tol:.0%} "
-                    f"(floor {floor:.4g})")
-        else:
-            ceil = old * (1.0 + tol)
-            if new > ceil:
-                problems.append(
-                    f"{dotted}: {new:.4g} rose above committed "
-                    f"{old:.4g} by more than {tol:.0%} "
-                    f"(ceiling {ceil:.4g})")
-    fresh_oks = _ok_paths(fresh)
-    for path, was_ok in _ok_paths(committed).items():
-        if was_ok and fresh_oks.get(path) is False:
-            problems.append(
-                f"{path}: was true in the committed artifact, false "
-                f"in the fresh run")
-    return problems
-
-
-def _committed_microbench() -> dict | None:
-    """The committed MICROBENCH.json — preferring HEAD's copy via git
-    (so a fresh-run-overwritten working file still diffs against what
-    was actually committed), falling back to the working tree."""
-    import subprocess
-
-    root = os.path.dirname(default_path())
-    try:
-        out = subprocess.run(
-            ["git", "show", "HEAD:MICROBENCH.json"], cwd=root,
-            capture_output=True, text=True, timeout=30)
-        if out.returncode == 0 and out.stdout.strip():
-            return json.loads(out.stdout)
-    except Exception:
-        pass
-    try:
-        with open(os.path.join(root, "MICROBENCH.json")) as f:
-            return json.load(f)
-    except Exception:
-        return None
 
 
 if __name__ == "__main__":

@@ -362,7 +362,6 @@ def _print_top(top, window):
     train = top.get("train") or {}
     for trial, t in sorted(train.items()):
         gp = t.get("goodput_pct")
-        mfu = t.get("mfu_pct")
         strag = t.get("straggler")
         strag_s = ""
         if strag and strag.get("cause") != "balanced":
@@ -370,7 +369,6 @@ def _print_top(top, window):
                        f"{strag.get('cause')}")
         print(f"trial {trial}: {t.get('reports_per_s', 0)} reports/s"
               + (f", goodput {gp}%" if gp is not None else "")
-              + (f", mfu {mfu:.1f}%" if mfu is not None else "")
               + strag_s)
     for name, s in sorted(slos.items()):
         v = s.get("value")
@@ -658,11 +656,6 @@ def cmd_train(args):
                              for r, s in ranks.items())
             print(f"    rank step: {line}")
         anat = t.get("anatomy") or {}
-        mfu = anat.get("mfu_pct") or {}
-        if mfu:
-            line = "  ".join(f"r{r}={v:.1f}%"
-                             for r, v in sorted(mfu.items()))
-            print(f"    mfu: {line}")
         for rank, phases in sorted((anat.get("ranks") or {}).items()):
             line = "  ".join(f"{p}={s * 1e3:.1f}ms"
                              for p, s in phases.items())

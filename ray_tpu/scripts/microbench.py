@@ -1,6 +1,6 @@
 """Core-API microbenchmarks against the cluster backend.
 
-The control-plane counterpart of ``bench.py``: measures the task/actor/
+Control-plane counts on a host, not chip speeds: measures the task/actor/
 object hot paths the way the reference's perf suite does
 (``python/ray/_private/ray_perf.py:93-236``, driven nightly by
 ``release/microbenchmark/run_microbenchmark.py:14-31``) — tasks/s sync and

@@ -2,9 +2,9 @@
 
 Round-4 verdict weak #1: committed perf numbers disagreed because
 microbench and scalebench ran at different times and SCALING.md's table
-was hand-copied. This driver runs microbench + scalebench +
-pipeline_bench back-to-back in one invocation, stamps every section with
-a shared timestamp + host config, writes the single merged
+was hand-copied. This driver runs microbench + scalebench back-to-back
+in one invocation, stamps every section with a shared timestamp + host
+config, writes the single merged
 MICROBENCH.json, and REGENERATES the measured table inside SCALING.md
 from that artifact (between the GENERATED markers) so the doc can never
 drift from the data again.
@@ -41,7 +41,6 @@ def _render_table(artifact: dict) -> str:
     m = artifact.get("metrics", {})
     s = artifact.get("scalability", {})
     h = artifact.get("head_scale", {})
-    p = artifact.get("pipeline", {})
     meta = artifact.get("meta", {})
 
     def mv(key):
@@ -120,18 +119,6 @@ def _render_table(artifact: dict) -> str:
             f"| head RSS growth | {hv('rss_growth_mb')} |",
             f"| head handler CPU total | {hv('head_handler_total_s')} |",
         ]
-    if p:
-        lines += [
-            "",
-            "| Pipeline (CPU, 8 virt devices) | step ms | ticks | "
-            "bubble | XLA temp MiB |",
-            "|---|---|---|---|---|",
-        ]
-        for key in sorted(p):
-            e = p[key]
-            lines.append(
-                f"| {key} | {e['step_ms']} | {e['ticks']} | "
-                f"{e['bubble_frac']} | {e['xla_temp_mb']} |")
     lines.append(END)
     return "\n".join(lines)
 
@@ -165,29 +152,15 @@ def main() -> None:
     ap.add_argument("--queued", type=int, default=0,
                     help="parked-queue audit depth for scalebench")
     ap.add_argument("--skip-head-scale", action="store_true")
-    ap.add_argument("--skip-pipeline", action="store_true")
     ap.add_argument("--skip-analyze", action="store_true",
                     help="skip the static-analysis gate stage (runs by "
                          "default: cheap, and a perf artifact from a "
                          "tree with unbaselined concurrency findings "
                          "is not evidence)")
-    ap.add_argument("--fused-norm", action="store_true",
-                    help="add the fused-norm kernel microbench point "
-                         "(CPU interpret shape coverage + op counts)")
     ap.add_argument("--serve", action="store_true",
                     help="add the serve request-path point "
                          "(concurrent-stream harness + client/server "
                          "latency cross-check)")
-    ap.add_argument("--llm", action="store_true",
-                    help="add the continuous-batching LLM serving "
-                         "point (concurrent token streams + TTFT "
-                         "cross-check + single-compiled-shape "
-                         "assertion; machine-independent step/churn/"
-                         "shed counts)")
-    ap.add_argument("--llm-streams", type=int, default=400,
-                    help="stream count for the --llm stage (the full "
-                         "10k envelope runs via serve_bench --llm "
-                         "directly)")
     ap.add_argument("--input-pipeline", action="store_true",
                     dest="input_pipeline",
                     help="add the training-goodput point "
@@ -198,13 +171,6 @@ def main() -> None:
                          "agreement vs client ledger + bounded-ring "
                          "memory proof + seeded SLO burn with exactly "
                          "one burning and one recovery pubsub event)")
-    ap.add_argument("--anatomy", action="store_true",
-                    help="add the step-anatomy point (cost-model-vs-"
-                         "analytic FLOPs agreement on two model "
-                         "families, exact phase partition, seeded-"
-                         "straggler attribution) and run the perf-"
-                         "regression sentinel against the committed "
-                         "artifact as the final stage")
     ap.add_argument("--dataflow", action="store_true",
                     help="add the streaming-dataflow point "
                          "(generation->training pipeline past store "
@@ -213,8 +179,7 @@ def main() -> None:
     args = ap.parse_args()
 
     # Each stage runs in its own subprocess: benchmark isolation (no
-    # leaked cluster state between stages) and jax platform independence
-    # (pipeline_bench forces cpu).
+    # leaked cluster state between stages).
     env = dict(os.environ)
     steps = []
     if not args.skip_analyze:
@@ -233,20 +198,9 @@ def main() -> None:
          "--queued", str(args.queued), "--out", args.out]
         + ([] if args.skip_head_scale else ["--head-scale"]),
     ]
-    if not args.skip_pipeline:
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.pipeline_bench", "--out", args.out])
-    if args.fused_norm:
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.fused_norm_bench", "--out", args.out])
     if args.serve:
         steps.append([sys.executable, "-m",
                       "ray_tpu.scripts.serve_bench", "--out", args.out])
-    if args.llm:
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.serve_bench", "--llm",
-                      "--streams", str(args.llm_streams),
-                      "--out", args.out])
     if args.input_pipeline:
         steps.append([sys.executable, "-m",
                       "ray_tpu.scripts.input_bench", "--out", args.out])
@@ -256,14 +210,6 @@ def main() -> None:
     if args.signals:
         steps.append([sys.executable, "-m",
                       "ray_tpu.scripts.signal_bench", "--out", args.out])
-    if args.anatomy:
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.anatomy_bench", "--out", args.out])
-        # Sentinel last: diff the fresh artifact (every section above
-        # has landed in --out by now) against the committed
-        # MICROBENCH.json; a regression fails the suite.
-        steps.append([sys.executable, "-m",
-                      "ray_tpu.scripts.bench_log", "--regress", args.out])
     for argv in steps:
         print(f"perfsuite: {' '.join(argv[2:])}", file=sys.stderr,
               flush=True)

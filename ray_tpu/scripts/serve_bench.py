@@ -361,469 +361,6 @@ def run(mode: str = "http", connections: int = 8,
                 os.environ["RAY_TPU_TRACING_ENABLED"] = prev_trace_env
 
 
-LLM_DEPLOYMENT = "serve_bench_llm"
-
-
-def run_llm(streams: int = 10_000, max_new_tokens: int = 8,
-            max_batch: int = 64, cache_len: int = 64,
-            max_prompt_len: int = 16, prefill_rows: int = 8,
-            cluster: bool = False, chaos: bool = False,
-            chaos_streams: int = 2_000, stream_lanes: int = 8,
-            shed_probes: int = 4, collectors: int = 8,
-            deadline_s: float = 900.0) -> dict:
-    """Continuous-batching serving harness: N concurrent token streams
-    through one GPT-2 engine deployment.
-
-    Every stream is submitted up front (all N are OPEN concurrently:
-    slots decode, the rest queue in the engine's admission lane) and
-    drained by collector threads batch-polling the engine — plus a few
-    lanes through the REAL streaming transports (``handle.stream`` +
-    chunked HTTP) to prove order/completeness on the user-facing path.
-    Client-side TTFT/token counts cross-check against the engine-side
-    ``ray_tpu_serve_decode_*`` histograms (count-exact, quantile
-    agreement), and the engine must report EXACTLY one compiled decode
-    shape and one prefill shape after the whole run — a per-request
-    recompile anywhere fails the bench. ``chaos=True`` adds a second
-    pass under a seeded partition schedule committing p99-TTFT-under-
-    partition with zero hung streams."""
-    import ray_tpu
-    from ray_tpu import serve
-    from ray_tpu.serve import _observability as obs
-    from ray_tpu.serve import _private as sp
-
-    ray_tpu.shutdown()
-    cluster_obj = None
-    if cluster:
-        from ray_tpu.cluster.cluster_utils import Cluster
-
-        cluster_obj = Cluster()
-        cluster_obj.add_node(num_cpus=4)
-        cluster_obj.add_node(num_cpus=4)
-        cluster_obj.wait_for_nodes()
-        ray_tpu.init(cluster_obj.address)
-    else:
-        ray_tpu.init(num_cpus=8)
-
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    eng = serve.deployment(
-        name=LLM_DEPLOYMENT, num_replicas=1, max_concurrent_queries=64,
-        route_prefix="/llm")(LLMEngine)
-    try:
-        handle = serve.run(eng.bind(
-            model="gpt2", max_batch=max_batch, cache_len=cache_len,
-            max_prompt_len=max_prompt_len, prefill_rows=prefill_rows,
-            max_new_tokens=max_new_tokens,
-            max_queue=streams + chaos_streams + 1024,
-            deployment=LLM_DEPLOYMENT))
-        # Warm-up (compiles prefill + decode) BEFORE the metric
-        # snapshot so the timed run measures serving, not compilation.
-        warm = ray_tpu.get(
-            handle.remote({"tokens": [3, 1, 4, 1, 5],
-                           "max_tokens": max_new_tokens}), timeout=300)
-        assert len(warm["tokens"]) == max_new_tokens
-        backend = _llm_backend()
-        port = serve.start_http_proxy()
-
-        result = {
-            "streams": streams,
-            "max_batch": max_batch,
-            "max_new_tokens": max_new_tokens,
-            "backend": "cluster" if cluster else "local",
-        }
-        main_pass = _llm_drive(
-            backend, sp, obs, handle, port, streams=streams,
-            max_new_tokens=max_new_tokens, stream_lanes=stream_lanes,
-            shed_probes=shed_probes, collectors=collectors,
-            deadline_s=deadline_s)
-        result.update(main_pass)
-        stats = _llm_rpc(backend, sp, "llm_stats", ())
-        result["engine"] = {
-            k: stats[k] for k in (
-                "steps", "admitted", "completed", "shed", "errors",
-                "tokens_out", "mean_occupancy", "queue_peak",
-                "ring_wraps", "compiles")}
-        # THE single-compiled-shape assertion: after warm-up + N
-        # streams + lanes + probes of assorted prompt/generation
-        # lengths, the engine traced each jitted shape exactly once.
-        result["one_compiled_shape"] = (
-            stats["compiles"] == {"decode": 1, "prefill": 1})
-        result["agreement"]["one_compiled_shape"] = \
-            result["one_compiled_shape"]
-        result["agreement"]["ok"] = all(result["agreement"].values())
-
-        if chaos:
-            chaos_pass = _llm_chaos_pass(
-                backend, sp, obs, handle, port, cluster_obj,
-                streams=chaos_streams, max_new_tokens=max_new_tokens,
-                collectors=collectors)
-            result["chaos"] = chaos_pass
-            stats2 = _llm_rpc(backend, sp, "llm_stats", ())
-            # Still one shape after the chaos pass rode the same engine.
-            result["chaos"]["one_compiled_shape"] = (
-                stats2["compiles"] == {"decode": 1, "prefill": 1})
-        return result
-    finally:
-        try:
-            serve.shutdown()
-        except Exception:
-            pass
-        ray_tpu.shutdown()
-        if cluster_obj is not None:
-            cluster_obj.shutdown()
-
-
-def _llm_backend():
-    from ray_tpu._private import worker as _worker
-
-    return _worker.backend()
-
-
-def _llm_rpc(backend, sp, method: str, args: tuple, timeout: float = 60.0):
-    """Bare engine call pinned to the (single) replica."""
-    [aid] = sp._stream_replicas(backend, LLM_DEPLOYMENT, refresh=True)
-    return sp._stream_rpc(backend, aid, method, args, {}, None,
-                          timeout=timeout)
-
-
-def _llm_drive(backend, sp, obs, handle, port, *, streams: int,
-               max_new_tokens: int, stream_lanes: int, shed_probes: int,
-               collectors: int, deadline_s: float,
-               label: str = "main") -> dict:
-    """One load pass: submit `streams` requests up front, drain with
-    batch-polling collectors, run transport lanes + shed probes, then
-    cross-check client vs engine-side metrics."""
-    import random as _random
-
-    from ray_tpu import serve
-    from ray_tpu.serve._observability import RequestShedError
-
-    rng = _random.Random(f"serve_bench_llm:{label}")
-    # Quiesce before the baseline snapshot: on the cluster backend the
-    # warm-up's (or the prior pass's) observations ship on the 0.25s
-    # worker-event cadence — snapshotting mid-flight would leak their
-    # tokens into this pass's delta and fail the exact cross-check.
-    last = None
-    quiesce_deadline = time.monotonic() + 15.0
-    while time.monotonic() < quiesce_deadline:
-        cur = sum(obs.sum_counter(
-            obs.parse_prometheus(obs.metrics_text()),
-            "ray_tpu_serve_decode_tokens_total", "deployment",
-            deployment=LLM_DEPLOYMENT).values())
-        if last is not None and cur == last:
-            break
-        last = cur
-        time.sleep(0.4)
-    before = obs.parse_prometheus(obs.metrics_text())
-    [aid] = sp._stream_replicas(backend, LLM_DEPLOYMENT, refresh=True)
-
-    # -- transport lanes FIRST: real handle.stream + chunked HTTP prove
-    # order/completeness on the user-facing paths. They run before the
-    # bulk load on purpose — at 10k queued streams a lane's TTFT is the
-    # whole admission queue, which only measures the queue again while
-    # starving the HTTP client's socket timeout.
-    lock = threading.Lock()
-    lane_results = {"handle_ok": 0, "http_ok": 0, "lane_errors": []}
-    lane_tokens = [0]
-
-    def lane(kind: str, idx: int):
-        prompt = [idx + 1, 7, 11]
-        try:
-            if kind == "handle":
-                toks = [t for ch in handle.stream(prompt, max_new_tokens)
-                        for t in ch]
-                assert len(toks) == max_new_tokens, toks
-                with lock:
-                    lane_results["handle_ok"] += 1
-                    lane_tokens[0] += len(toks)
-            else:
-                conn = _Stream(port)
-                conn._conn.timeout = 300.0
-                try:
-                    body = json.dumps({"tokens": prompt,
-                                       "max_tokens": max_new_tokens})
-                    conn._conn.request(
-                        "POST", "/llm", body=body.encode(),
-                        headers={"Content-Type": "application/json",
-                                 serve.STREAM_HEADER: "1"})
-                    resp = conn._conn.getresponse()
-                    toks = []
-                    tail = None
-                    while True:
-                        line = resp.readline()
-                        if not line:
-                            break
-                        obj = json.loads(line)
-                        toks.extend(obj.get("tokens") or ())
-                        if obj.get("done"):
-                            tail = obj
-                    assert resp.status == 200 and tail \
-                        and len(toks) == max_new_tokens, (
-                            resp.status, tail, toks)
-                    with lock:
-                        lane_results["http_ok"] += 1
-                        lane_tokens[0] += len(toks)
-                finally:
-                    conn.close()
-        except Exception as e:  # noqa: BLE001
-            with lock:
-                lane_results["lane_errors"].append(f"{kind}: {e!r}")
-
-    lane_threads = [
-        threading.Thread(target=lane,
-                         args=("handle" if i % 2 == 0 else "http", i))
-        for i in range(stream_lanes)]
-    for t in lane_threads:
-        t.start()
-    for t in lane_threads:
-        t.join()
-
-    # -- bulk submit: every stream is open before the first is drained.
-    t0 = time.perf_counter()
-    submit_ts: dict = {}
-    rids: list = []
-    batch_size = 250
-    prompts = [[rng.randrange(1, 200) for _ in range(rng.randint(3, 8))]
-               for _ in range(streams)]
-    for lo in range(0, streams, batch_size):
-        batch = [{"tokens": p, "max_tokens": max_new_tokens}
-                 for p in prompts[lo:lo + batch_size]]
-        got = sp._stream_rpc(backend, aid, "llm_submit_many", (batch,),
-                             {}, None, timeout=120.0)
-        now = time.perf_counter()
-        for rid in got:
-            submit_ts[rid] = now
-            rids.append(rid)
-    submit_wall = time.perf_counter() - t0
-
-    # -- collectors: batch-poll until every stream terminates.
-    ttft_s: dict = {}
-    tokens_got: dict = {r: 0 for r in rids}
-    done_rids: set = set()
-    hung: list = []
-    shard = max(1, (len(rids) + collectors - 1) // collectors)
-
-    def collect(shard_rids):
-        open_rids = list(shard_rids)
-        deadline = time.monotonic() + deadline_s
-        while open_rids and time.monotonic() < deadline:
-            chunk_rids = open_rids[:256]
-            rest = open_rids[256:]
-            try:
-                polled = sp._stream_rpc(
-                    backend, aid, "llm_poll", (chunk_rids,), {}, None,
-                    timeout=60.0)
-            except Exception:
-                time.sleep(0.2)  # partition window: retry
-                continue
-            now = time.perf_counter()
-            still_open = []
-            with lock:
-                for rid in chunk_rids:
-                    resp = polled.get(rid) or {}
-                    got = sum(len(c) for c in resp.get("chunks") or ())
-                    if got and rid not in ttft_s:
-                        ttft_s[rid] = now - submit_ts[rid]
-                    tokens_got[rid] += got
-                    if resp.get("done"):
-                        done_rids.add(rid)
-                    else:
-                        still_open.append(rid)
-            # Rotate: the unpolled remainder goes first so every open
-            # stream is polled fairly. The inter-round sleep matters:
-            # collectors share the replica's GIL with the engine loop,
-            # and a tight poll spin visibly slows the decode steps.
-            open_rids = rest + still_open
-            time.sleep(0.05)
-        with lock:
-            hung.extend(r for r in open_rids if r not in done_rids)
-
-    threads = [threading.Thread(
-        target=collect, args=(rids[i * shard:(i + 1) * shard],))
-        for i in range(collectors)]
-    for t in threads:
-        t.start()
-
-    # -- typed shed probes: an already-dead budget must shed, not run.
-    shed_seen = 0
-    for _ in range(shed_probes):
-        try:
-            list(handle.options(deadline_s=0.0).stream([1, 2, 3], 4))
-        except RequestShedError:
-            shed_seen += 1
-        except Exception:  # noqa: BLE001 — anything else is not a shed
-            pass
-
-    for t in threads:
-        t.join()
-    wall = time.perf_counter() - t0
-
-    client_tokens = sum(tokens_got.values()) + lane_tokens[0]
-    n_done = len(done_rids)
-    ttft_vals = sorted(ttft_s.values())
-    client = {
-        "streams_done": n_done,
-        "ttft_count": len(ttft_vals),
-        "ttft_p50_ms": _percentile_ms(ttft_vals, 0.50)
-        if ttft_vals else None,
-        "ttft_p99_ms": _percentile_ms(ttft_vals, 0.99)
-        if ttft_vals else None,
-        "tokens": client_tokens,
-        "submit_wall_s": round(submit_wall, 3),
-    }
-
-    # -- engine-side view: settle, then diff against the pre-run scrape.
-    expected_streams = streams + lane_results["handle_ok"] \
-        + lane_results["http_ok"]
-    delta = {}
-    settle = time.monotonic() + 30.0
-    ttft_dist = None
-    while time.monotonic() < settle:
-        delta = obs.diff_parsed(
-            before, obs.parse_prometheus(obs.metrics_text()))
-        ttft_dist = obs.histogram_dist(
-            delta, "ray_tpu_serve_decode_ttft_seconds",
-            deployment=LLM_DEPLOYMENT)
-        toks_counted = sum(obs.sum_counter(
-            delta, "ray_tpu_serve_decode_tokens_total", "deployment",
-            deployment=LLM_DEPLOYMENT).values())
-        if ttft_dist and ttft_dist["count"] >= expected_streams \
-                and toks_counted >= client_tokens:
-            break
-        time.sleep(0.25)
-    step_dist = obs.histogram_dist(
-        delta, "ray_tpu_serve_decode_step_seconds",
-        deployment=LLM_DEPLOYMENT)
-    occ_dist = obs.histogram_dist(
-        delta, "ray_tpu_serve_decode_batch_occupancy",
-        deployment=LLM_DEPLOYMENT)
-    sheds = obs.sum_counter(delta, "ray_tpu_serve_shed_total", "reason",
-                            deployment=LLM_DEPLOYMENT)
-    server_tokens = int(sum(obs.sum_counter(
-        delta, "ray_tpu_serve_decode_tokens_total", "deployment",
-        deployment=LLM_DEPLOYMENT).values()))
-    server = {"ttft_count": int(ttft_dist["count"]) if ttft_dist else 0,
-              "tokens": server_tokens,
-              "steps": int(step_dist["count"]) if step_dist else 0,
-              "mean_occupancy": round(occ_dist["sum"] / occ_dist["count"],
-                                      3) if occ_dist else None,
-              "shed": {k: int(v) for k, v in sheds.items()}}
-    if ttft_dist:
-        for q, key in ((0.50, "ttft_p50_ms"), (0.99, "ttft_p99_ms")):
-            v = obs.quantile_from_buckets(ttft_dist, q)
-            server[key] = round(v * 1e3, 3) if v is not None else None
-
-    def within(client_ms, server_ms):
-        if client_ms is None or server_ms is None or not ttft_dist:
-            return False
-        tol = max(obs.bucket_width_at(ttft_dist, client_ms / 1e3) * 1e3,
-                  0.35 * client_ms, 50.0)
-        return abs(client_ms - server_ms) <= tol
-
-    agreement = {
-        "all_streams_done": n_done == streams and not hung,
-        "ttft_count_exact": (ttft_dist is not None
-                             and int(ttft_dist["count"])
-                             == expected_streams),
-        "tokens_exact": server_tokens == client_tokens,
-        "ttft_p50_within_tol": within(client["ttft_p50_ms"],
-                                      server.get("ttft_p50_ms")),
-        "ttft_p99_within_tol": within(client["ttft_p99_ms"],
-                                      server.get("ttft_p99_ms")),
-        # A dead-on-arrival budget sheds typed at the replica boundary
-        # (reason=replica) or in the engine (reason=decode) — either
-        # way it must land in the shed family, never execute.
-        "sheds_typed": shed_probes == 0
-        or sum(sheds.values()) >= shed_seen > 0,
-        "lanes_ok": not lane_results["lane_errors"]
-        and lane_results["handle_ok"] + lane_results["http_ok"]
-        == stream_lanes,
-    }
-    agreement["ok"] = all(agreement.values())
-    return {
-        "client": client,
-        "server": server,
-        "agreement": agreement,
-        "hung_streams": len(hung),
-        "tokens_s": round(client_tokens / wall, 1),
-        "wall_s": round(wall, 2),
-        "lanes": lane_results,
-        "shed_probes": {"sent": shed_probes, "shed_typed": shed_seen},
-    }
-
-
-def _llm_chaos_pass(backend, sp, obs, handle, port, cluster_obj, *,
-                    streams: int, max_new_tokens: int,
-                    collectors: int) -> dict:
-    """The PR-5 partition schedule over a live stream load: seeded
-    head<->node cuts (healed inside the reconnect window) while streams
-    decode — p99 TTFT under partition is the committed number, and a
-    single hung stream fails the pass."""
-    from ray_tpu.util import failpoints
-
-    rng = failpoints.seeded_rng("serve_bench_llm_chaos")
-    stop = threading.Event()
-    cuts = {"n": 0}
-
-    def partition_loop():
-        while not stop.is_set():
-            time.sleep(rng.uniform(1.0, 2.0))
-            if stop.is_set():
-                return
-            try:
-                if cluster_obj is not None and len(cluster_obj.nodes) > 1:
-                    victim = cluster_obj.nodes[-1]
-                    cluster_obj.partition([["head"], [victim]])
-                    time.sleep(rng.uniform(0.4, 1.0))
-                    cluster_obj.heal()
-                else:
-                    # Local backend: no network to cut — delay the
-                    # engine loop instead so the pass still runs under
-                    # injected fault pressure.
-                    failpoints.set_failpoints(
-                        {"serve.llm.before_step": "delay:0.05"})
-                    time.sleep(rng.uniform(0.4, 1.0))
-                    failpoints.set_failpoints(
-                        {"serve.llm.before_step": None})
-                cuts["n"] += 1
-            except Exception:
-                return
-
-    injector = threading.Thread(target=partition_loop, daemon=True)
-    injector.start()
-    try:
-        # No shed probes under partition: a probe racing a cut can fail
-        # with a connection error instead of the typed shed, which is
-        # correct behavior but not this pass's claim — shed typing is
-        # the MAIN pass's assertion; this pass asserts zero hangs.
-        # Lanes are off too: a lane failing FAST mid-cut is correct
-        # (fail fast, never hang) but leaves an engine-side stream the
-        # client-side count can no longer match exactly.
-        out = _llm_drive(
-            backend, sp, obs, handle, port, streams=streams,
-            max_new_tokens=max_new_tokens, stream_lanes=0,
-            shed_probes=0, collectors=collectors, deadline_s=600.0,
-            label="chaos")
-    finally:
-        stop.set()
-        injector.join(timeout=30.0)
-        if cluster_obj is not None:
-            try:
-                cluster_obj.heal()
-            except Exception:
-                pass
-        failpoints.set_failpoints({"serve.llm.before_step": None})
-    return {
-        "streams": streams,
-        "partitions": cuts["n"],
-        "p99_under_partition_ms": out["client"]["ttft_p99_ms"],
-        "hung_streams": out["hung_streams"],
-        "tokens_s": out["tokens_s"],
-        "agreement": out["agreement"],
-        "zero_hung": out["hung_streams"] == 0,
-    }
-
-
 def _collect_spans(ray_tpu):
     """This process's spans + the backend's span store (cluster: spans
     ship over the worker-events plane to the head)."""
@@ -858,61 +395,9 @@ def main() -> None:
     ap.add_argument("--cluster", action="store_true",
                     help="run against a real multiprocess cluster "
                          "backend (events ship over the worker plane)")
-    ap.add_argument("--llm", action="store_true",
-                    help="continuous-batching LLM mode: N concurrent "
-                         "token streams through a GPT-2 engine "
-                         "deployment, TTFT/tokens-s cross-check + the "
-                         "single-compiled-shape assertion")
-    ap.add_argument("--streams", type=int, default=10_000,
-                    help="concurrent token streams for --llm")
-    ap.add_argument("--max-batch", type=int, default=64,
-                    help="engine decode slots for --llm")
-    ap.add_argument("--max-new", type=int, default=8,
-                    help="tokens generated per stream for --llm")
-    ap.add_argument("--chaos", action="store_true",
-                    help="with --llm: add a second pass under a seeded "
-                         "partition schedule (commits p99 TTFT under "
-                         "partition; any hung stream fails)")
     args = ap.parse_args()
 
     from ray_tpu.scripts import bench_log
-
-    if args.llm:
-        res = run_llm(streams=args.streams, max_batch=args.max_batch,
-                      max_new_tokens=args.max_new, cluster=args.cluster,
-                      chaos=args.chaos)
-        if res["client"]["ttft_p50_ms"] is not None:
-            entry = bench_log.record_llm_serving(
-                client=res["client"], server=res["server"],
-                agreement=res["agreement"], streams=res["streams"],
-                tokens_s=res["tokens_s"], device=_device_kind(),
-                script="serve_bench", engine=res["engine"],
-                hung_streams=res["hung_streams"])
-            res["evidence"] = {k: entry[k] for k in ("committed_to",)
-                               if k in entry}
-        if args.out:
-            payload = {}
-            if os.path.exists(args.out):
-                with open(args.out) as f:
-                    try:
-                        payload = json.load(f)
-                    except ValueError:
-                        payload = {}
-            payload["llm_serving"] = res
-            with open(args.out, "w") as f:
-                json.dump(payload, f, indent=1, sort_keys=True)
-                f.write("\n")
-        print(json.dumps(res, indent=1, default=str))
-        bad = (not res["agreement"]["ok"] or res["hung_streams"]
-               or (args.chaos and not (
-                   res["chaos"]["zero_hung"]
-                   and res["chaos"]["agreement"]["ok"]
-                   and res["chaos"]["one_compiled_shape"])))
-        if bad:
-            print("serve_bench --llm: FAILED (disagreement or hung "
-                  "streams); see 'agreement'", file=sys.stderr)
-            sys.exit(1)
-        return
 
     res = run(mode=args.mode, connections=args.connections,
               requests_per_conn=args.requests, sleep_ms=args.sleep_ms,

@@ -11,9 +11,8 @@ engine, mounted as an ordinary Serve deployment callable:
   and a fixed ``[prefill_rows, max_prompt_len]`` chunked-prefill lane
   (``models/gpt2.py`` / ``models/llama.py`` decode APIs). Per-engine
   compile counters (trace-time side effects, the ``fused_norm`` test
-  idiom) prove no per-request recompile ever happens —
-  ``serve_bench --llm`` asserts ``compiles == {decode: 1, prefill: 1}``
-  after 10k streams.
+  idiom) prove no per-request recompile ever happens — the benchmark's
+  serving cells require ``compiles == {decode: 1, prefill: 1}``.
 * **Slot-indexed ring KV-cache in device memory.** Per-slot write
   cursors via ``lax.dynamic_update_slice``; the cache rides the model's
   activation dtype (bf16 — no fp32 copy) and, for Llama, the GQA
@@ -284,16 +283,6 @@ class LLMEngine:
             "ignore", message="Some donated buffers were not usable")
         self._step_fn = jax.jit(step_fn, donate_argnums=(1,))
         self._prefill_fn = jax.jit(prefill_fn, donate_argnums=(1,))
-        # Step-anatomy cost model (round 19): a counter-free twin of
-        # the decode step for xla_cost lowering — lowering _step_fn
-        # itself would re-run its traced body and bump the
-        # compile-counter invariant serve_bench asserts on. Lazy and
-        # opt-in via step_cost(): the extra XLA compile is not free.
-        self._cost_fn = jax.jit(
-            lambda params, cache, tokens, pos: decode(
-                params, cache, tokens, pos, cfg)[:2])
-        self._step_cost: Optional[dict] = None
-        self._step_cost_flops = 0.0
 
         self._tokens = np.zeros(self.max_batch + 1, np.int32)
         self._pos = np.zeros(self.max_batch + 1, np.int32)
@@ -510,24 +499,6 @@ class LLMEngine:
             logger.exception("llm engine %s failed (first failure; later "
                              "ones are only counted)", what)
 
-    def step_cost(self) -> dict:
-        """Cost-account the compiled decode step (util/xla_cost):
-        FLOPs / bytes / roofline from the HLO, computed once and
-        cached. Opt-in — the lowering pays one extra XLA compile, so
-        the decode loop never does this on its own; once called, every
-        subsequent step's anatomy event carries MFU."""
-        if self._step_cost is None:
-            from ray_tpu.util import xla_cost as _xla_cost
-
-            cost = _xla_cost.step_cost(
-                self._cost_fn, self.params, self._cache,
-                self._jnp.asarray(self._tokens),
-                self._jnp.asarray(self._pos))
-            self._step_cost = cost
-            if cost.get("available"):
-                self._step_cost_flops = float(cost.get("flops", 0.0))
-        return self._step_cost
-
     def _step_once(self) -> bool:  # jax-hot-path  # step-timed
         np = self._np
         with tracing.device_span("llm.step.select") as ds, self._lock:
@@ -651,20 +622,12 @@ class LLMEngine:
         # Step anatomy: host = dispatch wall, compute = the sync wall
         # after it (the step's np.asarray IS the device wait); a
         # single-replica engine has no gang barrier, so sync is 0 and
-        # host + compute partition step_s exactly. MFU rides along
-        # once step_cost() has attached the HLO cost model.
-        mfu = None
-        if self._step_cost_flops > 0 and step_s > host_s:
-            from ray_tpu.util import xla_cost as _xla_cost
-
-            mfu = _xla_cost.mfu_percent(
-                self._step_cost_flops, step_s - host_s)
+        # host + compute partition step_s exactly.
         try:
             _goodput.record_anatomy(
                 f"serve:{self._dep}", 0,
                 {"data_wait": 0.0, "host": host_s,
-                 "compute": max(0.0, step_s - host_s), "sync": 0.0},
-                mfu=mfu)
+                 "compute": max(0.0, step_s - host_s), "sync": 0.0})
         except Exception:
             pass
         if step_span is not None:
@@ -949,7 +912,7 @@ class LLMEngine:
         if not self._loop_thread.is_alive():
             self._fail_unserved("engine stopped")
         _metrics.retract_loop_series(["llm.engine"])
-        # The engine's per-step anatomy gauges (MFU / phase seconds)
+        # The engine's per-step anatomy gauges (phase seconds)
         # must not outlive it on the scrape (LC001 discipline).
         try:
             _goodput.retract_trial(f"serve:{self._dep}")

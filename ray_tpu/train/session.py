@@ -22,9 +22,8 @@ Step anatomy (round 19): a train_fn that runs its step through
 report's step wall partitioned exactly into ``data_wait`` / ``host``
 (dispatch until device launch) / ``compute`` (synced device wall) /
 ``sync`` (the residual: this rank's wait for the slowest rank), shipped
-as per-rank ``ray_tpu_step_phase_seconds`` gauges; attach the compiled
-HLO's cost via :func:`set_step_cost` and ``ray_tpu_mfu_percent`` is
-exported too.
+as per-rank ``ray_tpu_step_phase_seconds`` gauges. These are seconds; a
+rate or an MFU comes from ``python3 -m benchmark.run`` on the chip.
 """
 
 from __future__ import annotations
@@ -37,7 +36,6 @@ from typing import Any, Optional
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.util import goodput as _goodput
 from ray_tpu.util import tracing as _tracing
-from ray_tpu.util import xla_cost as _xla_cost
 
 _local = threading.local()
 
@@ -103,13 +101,7 @@ class _Session:
         # keeps the classic data_wait/step residual accounting).
         self._host_s = 0.0
         self._compute_s = 0.0
-        self._anat_steps = 0
         self._anat_recorded = False
-        # Cost model attached via set_step_cost: per-step FLOPs for
-        # this rank's shard, from the compiled HLO (util/xla_cost).
-        self._step_flops = 0.0
-        self._cost_kind: Optional[str] = None
-        self._cost_devs = 1
         self._step_span = None
         self._open_step_span()
 
@@ -180,22 +172,15 @@ class _Session:
             compute = min(self._compute_s,
                           max(0.0, wall - data_wait - host))
             sync = max(0.0, wall - data_wait - host - compute)
-            mfu = None
-            if self._step_flops > 0 and compute > 0:
-                mfu = _xla_cost.mfu_percent(
-                    self._step_flops * max(1, self._anat_steps),
-                    compute, device_kind=self._cost_kind,
-                    n_devices=self._cost_devs)
             try:
                 _goodput.record_anatomy(
                     self.trial, self.world_rank,
                     {"data_wait": data_wait, "host": host,
-                     "compute": compute, "sync": sync}, mfu=mfu)
+                     "compute": compute, "sync": sync})
             except Exception:
                 pass
         self._host_s = 0.0
         self._compute_s = 0.0
-        self._anat_steps = 0
         self._anat_recorded = False
         _tracing.finish_span(self._step_span)
         self._open_step_span()
@@ -291,7 +276,6 @@ def add_step_anatomy(host_s: float, compute_s: float) -> None:
         return
     s._host_s += max(0.0, float(host_s))
     s._compute_s += max(0.0, float(compute_s))
-    s._anat_steps += 1
     s._anat_recorded = True
 
 
@@ -306,24 +290,3 @@ def timed_step(step_fn, *args: Any, **kwargs: Any):  # step-timed
     compute = time.perf_counter() - t0 - host
     add_step_anatomy(host, compute)
     return out
-
-
-def set_step_cost(cost, device_kind: Optional[str] = None,
-                  n_devices: int = 1) -> None:
-    """Attach the per-step cost model for this rank's shard so
-    ``report()`` can export MFU: ``cost`` is either FLOPs per step (a
-    number) or the dict returned by ``xla_cost.step_cost`` on the
-    compiled step function. A no-op outside a train session or when
-    the cost dict is an off-jax stub."""
-    s = getattr(_local, "session", None)
-    if s is None:
-        return
-    if isinstance(cost, dict):
-        if not cost.get("available"):
-            return
-        if device_kind is None:
-            device_kind = cost.get("device_kind")
-        cost = cost.get("flops", 0.0)
-    s._step_flops = max(0.0, float(cost or 0.0))
-    s._cost_kind = device_kind
-    s._cost_devs = max(1, int(n_devices))

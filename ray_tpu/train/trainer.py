@@ -613,7 +613,7 @@ class DataParallelTrainer:
                     time.sleep(0.2)
         finally:
             # Session stop: the trial's per-rank gauge series (step
-            # time, MFU, anatomy phases) must not outlive the trial on
+            # time, anatomy phases) must not outlive the trial on
             # the scrape (LC001 discipline — the local backend's worker
             # threads never die to trigger the agent's sweep).
             try:

@@ -51,7 +51,7 @@ Passes (rule-id prefix):
   timer reads must bracket a real host sync (``block_until_ready`` /
   ``.item()`` / ``np.asarray`` / ``float()`` of a device scalar) — an
   unsynced wall around async dispatch times the launch, not the
-  device, and the MFU/anatomy plane built on it would be fiction; a
+  device, and the anatomy plane built on it would be fiction; a
   marked region that times nothing is a stale annotation.
 * ``trace-propagation`` (TP) — manual flight-recorder spans
   (``tracing.start_span``) must be closable: never-finished local

@@ -5,7 +5,7 @@ cannot check: a wall-clock interval around asynchronously-dispatched
 device work measures *dispatch*, not *compute*, unless a real host sync
 sits between the timer reads. The bug shape is silent and flattering —
 an unsynced loop reports a 40x "speedup" (the launch latency) and the
-MFU gauge reads garbage. The ``# step-timed`` marker (on or directly
+compute phase reads garbage. The ``# step-timed`` marker (on or directly
 above a ``def``, same idiom as ``# jax-hot-path``) declares a function
 whose timer reads bracket device work; this pass makes the sync
 requirement static:
@@ -15,7 +15,7 @@ requirement static:
   forms) with no recognizable host sync between the FIRST and LAST
   read: ``jax.block_until_ready`` / ``.item()`` / ``np.asarray`` /
   ``np.array`` / ``jax.device_get`` / a builtin ``float(...)`` of a
-  device value (the ``measure.py`` idiom) / a ``*sync*``-named helper
+  device value / a ``*sync*``-named helper
   (``_block_sync``). Whatever the interval is timing, it is not synced
   device work.
 * **TH002** — a ``# step-timed`` function with fewer than two timer
@@ -128,7 +128,7 @@ def timing_pass(mod: ParsedModule) -> List:
                 f"between timer reads (lines {first[0]}-{last[0]}) "
                 f"with no host sync between them: around async "
                 f"dispatch this times the launch, not the device — "
-                f"the MFU/anatomy numbers built on it are fiction",
+                f"the anatomy seconds built on it are fiction",
                 "force completion before the closing read "
                 "(jax.block_until_ready on the step outputs, or "
                 "float() a device scalar)")

@@ -3,7 +3,7 @@
 The cache key includes the directory, so a cache that moves never hits.
 One rule, applied wherever the main path first touches JAX
 (``build_mesh``, ``LLMEngine``, ``parallel.distributed.initialize``,
-``chip_smoke.py``, ``bench.py``): an operator who sets
+``chip_smoke.py``): an operator who sets
 ``JAX_COMPILATION_CACHE_DIR`` owns the location (JAX reads the variable
 itself and nothing here overrides it); otherwise the cache is the fixed
 ``<checkout>/.jax_cache``. No other path is ever set in code.

@@ -181,21 +181,16 @@ def record_step(trial: str, rank: int, phases: Dict[str, float]) -> None:
     _emit({"k": "step", "t": str(trial), "r": int(rank), "p": phases})
 
 
-def record_anatomy(trial: str, rank: int, phases: Dict[str, float],
-                   mfu: Optional[float] = None) -> None:
+def record_anatomy(trial: str, rank: int,
+                   phases: Dict[str, float]) -> None:
     """One instrumented step's anatomy decomposition for one rank
     (``data_wait`` / ``host`` / ``compute`` / ``sync`` — the session
     computes ``sync`` as the residual, so the phases partition the
-    instrumented step wall exactly). ``mfu`` is the cost-model MFU
-    percent when a step cost is attached. Per-rank gauges, retracted
-    on worker death and session stop."""
+    instrumented step wall exactly). Per-rank gauges, retracted on
+    worker death and session stop."""
     phases = {p: max(0.0, float(s)) for p, s in phases.items()
               if p in ANATOMY_PHASES}
-    ev: dict = {"k": "anat", "t": str(trial), "r": int(rank),
-                "p": phases}
-    if mfu is not None:
-        ev["m"] = float(mfu)
-    _emit(ev)
+    _emit({"k": "anat", "t": str(trial), "r": int(rank), "p": phases})
 
 
 def record_downtime(trial: str, cause: str, seconds: float) -> None:
@@ -239,9 +234,8 @@ def straggler_attribution(rank_phases: Dict[str, Dict[str, float]],
     the largest delta vs that median. Below ``min_excess_frac`` of the
     baseline the gang is ``balanced`` — no rank gets accused of noise.
 
-    One implementation shared by ``train_stats``, ``ray-tpu top`` and
-    the anatomy bench, so they can never disagree about who the
-    straggler is."""
+    One implementation shared by ``train_stats`` and ``ray-tpu top``,
+    so they can never disagree about who the straggler is."""
     if not rank_phases or len(rank_phases) < 2:
         return None
 
@@ -446,11 +440,6 @@ def apply_events(events: List[dict], node_id: str,
                             float(sec),
                             tags={"node_id": node_id, "trial": trial,
                                   "phase": phase, "rank": rank})
-                if ev.get("m") is not None:
-                    _metrics.TRAIN_MFU_PERCENT.set(
-                        float(ev["m"]),
-                        tags={"node_id": node_id, "trial": trial,
-                              "rank": rank})
                 gauge_keys.append(("anat", trial, rank))
             elif kind == "down":
                 _metrics.TRAIN_DOWNTIME_SECONDS.inc(
@@ -496,15 +485,12 @@ def retract_gauges(keys, node_id: str) -> None:
                                   "phase": phase, "rank": key[2]})
                     except Exception:
                         pass
-                _metrics.TRAIN_MFU_PERCENT.remove(tags={
-                    "node_id": node_id, "trial": key[1], "rank": key[2]})
             elif key[0] == "trial":
                 # Session-stop sweep: drop EVERY per-rank child of the
                 # trial from this process's registry (the local backend
                 # runs workers as threads — nothing dies to trigger the
                 # agent's worker-death retraction).
                 for fam in (_metrics.TRAIN_RANK_STEP_SECONDS,
-                            _metrics.TRAIN_MFU_PERCENT,
                             _metrics.TRAIN_STEP_ANATOMY_SECONDS):
                     for ld in fam.series():
                         if ld.get("trial") == key[1]:
@@ -523,7 +509,7 @@ def retract_gauges(keys, node_id: str) -> None:
 
 def retract_trial(trial: str, node_id: str = _LOCAL_NODE) -> None:
     """Session stop: retract the trial's per-rank gauge series (step
-    time, MFU, anatomy phases) from this process's registry. The
+    time, anatomy phases) from this process's registry. The
     trainer calls this when a trial finishes; on the cluster backend
     the agent's worker-death sweep covers its copies."""
     retract_gauges([("trial", str(trial))], node_id)
@@ -701,23 +687,13 @@ def train_stats(parsed: Optional[dict] = None) -> dict:
             if ld.get("trial") == trial:
                 anat_ranks.setdefault(ld.get("rank", "?"), {})[
                     ld.get("phase", "?")] = round(val, 6)
-        mfu: Dict[str, float] = {}
-        for labels, val in (parsed.get(
-                "ray_tpu_mfu_percent") or {}).items():
-            ld = dict(labels)
-            if ld.get("trial") == trial:
-                mfu[ld.get("rank", "?")] = round(val, 3)
-        if anat_ranks or mfu:
-            anatomy: dict = {}
-            if anat_ranks:
-                anatomy["ranks"] = {
-                    r: dict(sorted(anat_ranks[r].items()))
-                    for r in sorted(anat_ranks)}
-                verdict = straggler_attribution(anat_ranks)
-                if verdict:
-                    anatomy["straggler"] = verdict
-            if mfu:
-                anatomy["mfu_pct"] = dict(sorted(mfu.items()))
+        if anat_ranks:
+            anatomy: dict = {"ranks": {
+                r: dict(sorted(anat_ranks[r].items()))
+                for r in sorted(anat_ranks)}}
+            verdict = straggler_attribution(anat_ranks)
+            if verdict:
+                anatomy["straggler"] = verdict
             entry["anatomy"] = anatomy
         downtime = obs.sum_counter(
             parsed, "ray_tpu_train_downtime_seconds_total", "cause",

@@ -667,17 +667,9 @@ TRAIN_EVENTS_DROPPED = Counter(
     tag_keys=("node_id",),
 )
 
-# -- step anatomy plane (round 19: MFU accounting + per-rank phase
-# decomposition). Both are per-entity gauges: retracted on worker
-# death and session stop via goodput.retract_gauges / retract_trial.
-TRAIN_MFU_PERCENT = Gauge(
-    "ray_tpu_mfu_percent",
-    "Model-FLOPs utilization per rank: XLA cost-model FLOPs per step "
-    "(util/xla_cost, from the compiled HLO — not a hand formula) over "
-    "measured device-compute seconds, against the measure.py per-chip "
-    "peak; retracted on worker death and session stop",
-    tag_keys=("node_id", "trial", "rank"),
-)
+# -- step anatomy plane (round 19: per-rank phase decomposition, in
+# seconds). A per-entity gauge: retracted on worker death and session
+# stop via goodput.retract_gauges / retract_trial.
 TRAIN_STEP_ANATOMY_SECONDS = Gauge(
     "ray_tpu_step_phase_seconds",
     "Most recent step-anatomy decomposition per rank: data_wait / host "

@@ -21,8 +21,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import gpt2, llama
-from ray_tpu.scripts import bench_log
+from ray_tpu.models import gpt2, llama, nemotron_h
 from ray_tpu.serve import _observability as obs
 from ray_tpu.serve._observability import RequestShedError
 from ray_tpu.serve.llm_engine import LLMEngine
@@ -56,6 +55,16 @@ def _clean_between_tests():
 GPT2_FP32 = dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=jnp.float32)
 LLAMA_FP32 = dataclasses.replace(llama.LlamaConfig.tiny(),
                                  dtype=jnp.float32)
+NEMOTRON_FP32 = nemotron_h.NemotronHConfig.tiny(
+    dtype=jnp.float32, param_dtype=jnp.float32)
+# Every family the engine serves: its float32 tiny config and the
+# full-context forward its served tokens are held to.
+SERVED = {
+    "gpt2": (GPT2_FP32, gpt2.gpt2_forward),
+    "llama": (LLAMA_FP32, llama.llama_forward),
+    "nemotron_h": (NEMOTRON_FP32, nemotron_h.nemotron_h_forward),
+}
+every_family = pytest.mark.parametrize("model", list(SERVED))
 PROMPT = [5, 9, 2, 17, 3]
 
 
@@ -69,9 +78,22 @@ def _naive_generate(forward, params, prompt, n, cfg):
     return toks[len(prompt):]
 
 
+def _compiled(forward, params, cfg, width):
+    """``forward`` as ONE compiled program for ``_naive_generate``: the
+    families are causal, so a forward padded to ``width`` serves every
+    length up to it."""
+    fwd = jax.jit(lambda tokens: forward(params, tokens, cfg))
+
+    def padded(_params, tokens, _cfg):
+        n = tokens.shape[1]
+        return fwd(jnp.pad(tokens, ((0, 0), (0, width - n))))[:, :n]
+
+    return padded
+
+
 def _engine(**kw):
     kw.setdefault("model", "gpt2")
-    kw.setdefault("config", GPT2_FP32)
+    kw.setdefault("config", SERVED[kw["model"]][0])
     kw.setdefault("max_batch", 4)
     kw.setdefault("cache_len", 32)
     kw.setdefault("max_prompt_len", 8)
@@ -139,19 +161,18 @@ def test_decode_parity_llama_vs_naive():
     assert got == want
 
 
-def test_engine_generate_matches_naive_both_models():
+@pytest.mark.parametrize("model", ["gpt2", "llama"])
+def test_engine_generate_matches_naive(model):
     """The whole engine (admission -> prefill lane -> batched decode)
-    reproduces the naive loop for BOTH model families."""
-    for model, mod, cfg, fwd, init in (
-            ("gpt2", gpt2, GPT2_FP32, gpt2.gpt2_forward, gpt2.gpt2_init),
-            ("llama", llama, LLAMA_FP32, llama.llama_forward,
-             llama.llama_init)):
-        eng = _engine(model=model, config=cfg)
-        try:
-            want = _naive_generate(fwd, eng.params, PROMPT, 6, cfg)
-            assert eng.generate(PROMPT, 6) == want, model
-        finally:
-            eng.shutdown_engine()
+    reproduces the naive loop (the hybrid family's engine is held to its
+    float32 reference in test_nemotron_h.py)."""
+    cfg, fwd = SERVED[model]
+    eng = _engine(model=model)
+    try:
+        want = _naive_generate(fwd, eng.params, PROMPT, 6, cfg)
+        assert eng.generate(PROMPT, 6) == want
+    finally:
+        eng.shutdown_engine()
 
 
 # -- the cache contract, held to a plain oracle ----------------------------------
@@ -419,11 +440,14 @@ def test_decode_after_prefill_reads_the_rows_prefill_wrote(fam):
 # -- scheduler: slots, admission, deadlines ---------------------------------
 
 
-def test_slot_recycle_and_admission_queue():
+@every_family
+def test_slot_recycle_and_admission_queue(model):
     """More concurrent requests than slots: the overflow QUEUES (never
     errors), slots recycle as streams finish, and every request gets
-    its full generation."""
-    eng = _engine(max_batch=2, prefill_rows=2)
+    its full generation — the one it would get alone, whatever the slot
+    held before it (a K/V row or a recurrent state)."""
+    cfg, fwd = SERVED[model]
+    eng = _engine(model=model, max_batch=2, prefill_rows=2)
     try:
         results: dict = {}
         errors: list = []
@@ -442,7 +466,10 @@ def test_slot_recycle_and_admission_queue():
             t.join()
         assert not errors, errors
         assert len(results) == 8
-        assert all(len(v) == 5 for v in results.values())
+        alone = _compiled(fwd, eng.params, cfg, 8)
+        for i, got in results.items():
+            assert got == _naive_generate(
+                alone, None, [i + 1, 7, 11], 5, None), i
         st = eng.llm_stats()
         assert st["admitted"] == 8          # every request held a slot
         assert st["admitted"] > eng.max_batch  # ... by recycling
@@ -521,12 +548,16 @@ def test_admission_full_queue_sheds_typed():
         eng.shutdown_engine()
 
 
-def test_cancel_frees_slot_and_queue():
+@every_family
+def test_cancel_frees_slot_and_queue(model):
     """llm_cancel drops a queued request and evicts an active one (the
     abandoned-caller path generate() uses on timeout): slot freed,
-    stream terminates with a 'cancelled' error, engine keeps serving."""
-    eng = _engine(max_batch=1, prefill_rows=1, max_new_tokens=100,
-                  max_new_cap=200, step_throttle_s=0.01)
+    stream terminates with a 'cancelled' error, engine keeps serving —
+    and the next request in that slot starts from a clean state."""
+    cfg, fwd = SERVED[model]
+    eng = _engine(model=model, max_batch=1, prefill_rows=1,
+                  max_new_tokens=100, max_new_cap=200,
+                  step_throttle_s=0.01)
     try:
         active = eng.llm_submit(PROMPT, 100)
         deadline = time.monotonic() + 30.0
@@ -540,15 +571,18 @@ def test_cancel_frees_slot_and_queue():
         assert not eng.llm_cancel(active)  # already gone
         resp = eng.llm_next(active, timeout_s=2.0)
         assert resp["done"] and resp["error"] == "cancelled"
-        assert len(eng.generate(PROMPT, 3)) == 3  # slot reusable
+        other = [7, 1, 30]  # the one slot, reused mid-generation
+        assert eng.generate(other, 3) == _naive_generate(
+            fwd, eng.params, other, 3, cfg)
     finally:
         eng.shutdown_engine()
 
 
-def test_ring_cache_wrap():
+@every_family
+def test_ring_cache_wrap(model):
     """Generation past cache_len wraps the ring cursor (sliding-window
     attention) instead of erroring."""
-    eng = _engine(max_batch=2, cache_len=8, max_prompt_len=8,
+    eng = _engine(model=model, max_batch=2, cache_len=8, max_prompt_len=8,
                   max_new_tokens=20, max_new_cap=64)
     try:
         out = eng.generate([1, 2, 3], 20)
@@ -558,11 +592,12 @@ def test_ring_cache_wrap():
         eng.shutdown_engine()
 
 
-def test_compile_counters_single_shape():
+@every_family
+def test_compile_counters_single_shape(model):
     """Assorted prompt lengths and generation lengths all ride the SAME
     two compiled shapes — the no-per-request-recompile claim, asserted
-    via trace-time counters."""
-    eng = _engine(max_batch=4)
+    via trace-time counters: the engine owns two programs and no more."""
+    eng = _engine(model=model, max_batch=4)
     try:
         for prompt, n in (([1], 1), ([1, 2, 3], 4), (list(range(1, 9)),
                                                      6), ([9, 9], 2)):
@@ -733,29 +768,6 @@ def test_blocking_lane_deadline_shed_mid_decode():
             {"tokens": PROMPT, "max_tokens": 500}), timeout=120)
     assert "shed" in repr(ei.value).lower(), repr(ei.value)
     assert time.monotonic() - t0 < 60.0  # shed, not a 500-token wait
-
-
-def test_llm_serving_evidence_lint():
-    """record_llm_serving emits the shape bench_log --check demands; a
-    TTFT-less or verdict-less line fails the lint."""
-    assert "llm_serving" in bench_log.KNOWN_BENCHES
-    entry = bench_log.record_llm_serving(
-        client={"ttft_p50_ms": 12.5, "ttft_p99_ms": 80.1},
-        server={"ttft_count": 100, "tokens": 800},
-        agreement={"ok": True}, streams=100, tokens_s=5000.0,
-        device="tpu", path="")
-    entry.pop("committed_to")
-    entry["ts"] = 123.0  # stamped by record() at append time
-    assert bench_log.check_line(entry) == []
-    bad = dict(entry)
-    bad["client"] = {}
-    assert any("ttft_p50_ms" in e for e in bench_log.check_line(bad))
-    bad2 = dict(entry)
-    bad2.pop("agreement")
-    assert any("agreement.ok" in e for e in bench_log.check_line(bad2))
-    bad3 = dict(entry)
-    bad3.pop("tokens_s")
-    assert any("tokens_s" in e for e in bench_log.check_line(bad3))
 
 
 # -- cluster backend + ray:// proxy (runs LAST: tears down the module

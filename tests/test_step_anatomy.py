@@ -1,8 +1,6 @@
-"""Step anatomy plane (round 19): XLA cost-model accounting
-(``util/xla_cost``), exact per-rank step decomposition with MFU export,
-head-side straggler attribution, the ``bench_log --regress``
-perf-regression sentinel, the ``timing`` (TH) analyze family, and the
-gauge-retraction discipline for the new per-rank families.
+"""Step anatomy plane (round 19): exact per-rank step decomposition in
+seconds, head-side straggler attribution, the ``timing`` (TH) analyze
+family, and the gauge-retraction discipline for the per-rank family.
 
 Test order matters (``-p no:randomly`` keeps definition order): the
 cluster-federation test tears down the module's local runtime, so it
@@ -24,7 +22,6 @@ from ray_tpu.serve import _observability as obs
 from ray_tpu.train import _observability as tob
 from ray_tpu.train import session
 from ray_tpu.util import metrics
-from ray_tpu.util import xla_cost
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -39,92 +36,7 @@ def _snapshot():
     return obs.parse_prometheus(metrics.prometheus_text())
 
 
-# -- xla_cost: static cost accounting ---------------------------------------
-
-
-def test_xla_cost_stub_shape_off_jax():
-    s = xla_cost.stub("no jax")
-    assert s == {"available": False, "reason": "no jax"}
-    # Objects without .lower (not a jitted callable) degrade to a stub,
-    # never raise.
-    res = xla_cost.step_cost(lambda x: x, 1)
-    assert res["available"] is False
-
-
-def test_xla_cost_agrees_with_analytic_on_both_families():
-    jax = pytest.importorskip("jax")
-    from ray_tpu.models.gpt2 import (
-        GPT2Config,
-        gpt2_flops_per_token,
-        gpt2_init,
-        gpt2_loss,
-        gpt2_shardings,
-    )
-    from ray_tpu.models.llama import (
-        LlamaConfig,
-        llama_flops_per_token,
-        llama_init,
-        llama_loss,
-        llama_shardings,
-    )
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.train.train_step import make_init_fn, make_train_step
-
-    import jax.numpy as jnp
-
-    cases = [
-        ("gpt2",
-         GPT2Config(vocab_size=256, n_layer=2, n_head=4, d_model=128,
-                    seq_len=64, remat=False),
-         gpt2_init, gpt2_loss, gpt2_shardings, gpt2_flops_per_token),
-        ("llama",
-         LlamaConfig(vocab_size=256, n_layer=2, n_head=4, n_kv_head=2,
-                     d_model=128, seq_len=64, remat=False),
-         llama_init, llama_loss, llama_shardings,
-         llama_flops_per_token),
-    ]
-    for name, cfg, init, loss, shard, flops_fn in cases:
-        mesh = build_mesh(MeshConfig(fsdp=-1))
-        shardings = shard(cfg, mesh)
-        st = make_init_fn(lambda r: init(r, cfg), shardings, mesh)(
-            jax.random.key(0))
-        step_fn = make_train_step(
-            lambda p, b: loss(p, b, cfg), shardings, mesh)
-        n_batch = max(8, jax.device_count())
-        batch = {"tokens": jax.random.randint(
-            jax.random.key(1), (n_batch, cfg.seq_len + 1), 0,
-            cfg.vocab_size, jnp.int32)}
-        cost = xla_cost.step_cost(step_fn, st, batch)
-        assert cost["available"], (name, cost)
-        assert cost["flops"] > 0 and cost["bytes_accessed"] > 0
-        assert cost["intensity_flops_per_byte"] > 0
-        assert cost["roofline"] in ("compute-bound", "memory-bound")
-        # cost_analysis() accounts the per-partition program: under
-        # fsdp over N devices the HLO sees 1/N of the batch, so the
-        # analytic comparison is against the per-device share (the
-        # same convention mfu_percent's n_devices=1 default uses).
-        analytic = (flops_fn(cfg) * n_batch * cfg.seq_len
-                    / jax.device_count())
-        ratio = cost["flops"] / analytic
-        # Generous band (the same one anatomy_bench gates on): the 6N
-        # estimate ignores softmax/norm/optimizer FLOPs; agreement
-        # means same order of magnitude, same model.
-        assert 0.25 <= ratio <= 4.0, (name, ratio)
-
-
-def test_mfu_percent_math():
-    # 1e12 FLOPs in 1s on a 0.5 TFLOP/s cpu chip = 200% (nominal peak).
-    assert xla_cost.mfu_percent(
-        1e12, 1.0, device_kind="cpu") == pytest.approx(200.0)
-    # Scales down with device count, guards degenerate inputs.
-    assert xla_cost.mfu_percent(
-        1e12, 1.0, device_kind="cpu",
-        n_devices=2) == pytest.approx(100.0)
-    assert xla_cost.mfu_percent(0.0, 1.0) == 0.0
-    assert xla_cost.mfu_percent(1e12, 0.0) == 0.0
-
-
-# -- session: exact partition + MFU export ----------------------------------
+# -- session: exact partition -----------------------------------------------
 
 
 def test_anatomy_phases_partition_step_wall_exactly():
@@ -134,7 +46,6 @@ def test_anatomy_phases_partition_step_wall_exactly():
         results_queue=queue.Queue(), checkpoint=None,
         dataset_shards=None, trial_info={"trial_id": "anat-t"})
     try:
-        session.set_step_cost(1e6)
         for _ in range(3):
             session.add_data_wait(0.002)
             time.sleep(0.002)
@@ -152,7 +63,7 @@ def test_anatomy_phases_partition_step_wall_exactly():
     for ev, wall in zip(anats, walls):
         assert set(ev["p"]) == {"data_wait", "host", "compute", "sync"}
         assert sum(ev["p"].values()) == pytest.approx(wall, abs=1e-9)
-        assert ev.get("m") is not None  # MFU rides the anat event
+        assert set(ev) == {"k", "t", "r", "p"}  # seconds, nothing else
     tob.retract_trial("anat-t")
 
 
@@ -226,7 +137,7 @@ def test_seeded_straggler_attributed_through_local_trainer():
     # Session-stop discipline (LC001): fit()'s finally retracted the
     # trial's per-rank gauges from the local registry.
     parsed = _snapshot()
-    for fam in ("ray_tpu_step_phase_seconds", "ray_tpu_mfu_percent",
+    for fam in ("ray_tpu_step_phase_seconds",
                 "ray_tpu_train_rank_step_seconds"):
         leftover = [dict(lb) for lb in (parsed.get(fam) or {})
                     if dict(lb).get("trial") == "train"]
@@ -235,17 +146,17 @@ def test_seeded_straggler_attributed_through_local_trainer():
 
 def test_retract_trial_clears_anatomy_gauges():
     tob.record_anatomy("rt-t", 0, {"data_wait": 0.01, "host": 0.01,
-                                   "compute": 0.05, "sync": 0.0},
-                       mfu=33.0)
+                                   "compute": 0.05, "sync": 0.0})
     tob.record_step("rt-t", 0, {"step": 0.07})
     parsed = _snapshot()
     assert any(dict(lb).get("trial") == "rt-t"
                for lb in parsed.get("ray_tpu_step_phase_seconds") or {})
     assert any(dict(lb).get("trial") == "rt-t"
-               for lb in parsed.get("ray_tpu_mfu_percent") or {})
+               for lb in parsed.get("ray_tpu_train_rank_step_seconds")
+               or {})
     tob.retract_trial("rt-t")
     parsed = _snapshot()
-    for fam in ("ray_tpu_step_phase_seconds", "ray_tpu_mfu_percent",
+    for fam in ("ray_tpu_step_phase_seconds",
                 "ray_tpu_train_rank_step_seconds"):
         assert not any(dict(lb).get("trial") == "rt-t"
                        for lb in parsed.get(fam) or {}), fam
@@ -254,100 +165,20 @@ def test_retract_trial_clears_anatomy_gauges():
 
 def test_train_stats_carries_anatomy_and_straggler():
     tob.record_anatomy("ts-t", 0, {"data_wait": 0.01, "host": 0.01,
-                                   "compute": 0.05, "sync": 0.05},
-                       mfu=40.0)
+                                   "compute": 0.05, "sync": 0.05})
     tob.record_anatomy("ts-t", 1, {"data_wait": 0.01, "host": 0.01,
-                                   "compute": 0.11, "sync": 0.0},
-                       mfu=18.0)
+                                   "compute": 0.11, "sync": 0.0})
     try:
         entry = state.train_stats()["trials"]["ts-t"]
         anat = entry["anatomy"]
         assert set(anat["ranks"]) == {"0", "1"}
-        assert anat["mfu_pct"]["1"] == pytest.approx(18.0)
+        assert set(anat) == {"ranks", "straggler"}
+        assert anat["ranks"]["1"]["compute"] == pytest.approx(0.11)
         assert anat["straggler"]["rank"] == "1"
         assert anat["straggler"]["cause"] == "compute-bound"
     finally:
         tob.retract_trial("ts-t")
         tob.drain_events()
-
-
-# -- perf-regression sentinel ------------------------------------------------
-
-
-def _artifact(**over):
-    art = {"step_anatomy": {
-        "mfu": 40.0, "step_wall_s": 0.5,
-        "cost_model": {"flops_ratio": 1.1, "ok": True},
-        "agreement": {"ok": True},
-    }, "goodput": {"goodput_pct": 95.0}}
-    art["step_anatomy"].update(over)
-    return art
-
-
-def test_regress_check_identity_clean_and_seeded_trips():
-    base = _artifact()
-    assert bench_log.regress_check(_artifact(), base) == []
-    slow = _artifact(mfu=20.0, step_wall_s=1.2)
-    problems = bench_log.regress_check(slow, base)
-    assert any("mfu" in p for p in problems)
-    assert any("step_wall_s" in p for p in problems)
-    # Verdict preservation: a committed-true 'ok' flipping false trips,
-    # wherever it nests.
-    flipped = _artifact()
-    flipped["step_anatomy"]["cost_model"]["ok"] = False
-    assert any("cost_model.ok" in p
-               for p in bench_log.regress_check(flipped, base))
-    # Sections absent from the fresh artifact gate nothing.
-    assert bench_log.regress_check(
-        {"goodput": {"goodput_pct": 95.0}}, base) == []
-
-
-def test_regress_main_exit_codes(tmp_path, capsys):
-    bp = tmp_path / "base.json"
-    fp = tmp_path / "fresh.json"
-    sp = tmp_path / "seeded.json"
-    bp.write_text(json.dumps(_artifact()))
-    fp.write_text(json.dumps(_artifact()))
-    sp.write_text(json.dumps(_artifact(mfu=10.0)))
-    assert bench_log.main(
-        ["--regress", str(fp), "--against", str(bp)]) == 0
-    assert bench_log.main(
-        ["--regress", str(sp), "--against", str(bp)]) == 1
-    out = capsys.readouterr().out
-    assert "FAIL" in out and "mfu" in out
-    # Unreadable fresh artifact is a loud failure, not a silent pass.
-    assert bench_log.main(
-        ["--regress", str(tmp_path / "nope.json"),
-         "--against", str(bp)]) == 1
-
-
-# -- evidence line shape -----------------------------------------------------
-
-
-def test_bench_log_step_anatomy_line_shape(tmp_path):
-    path = str(tmp_path / "ev.jsonl")
-    entry = bench_log.record_step_anatomy(
-        mfu=41.2, step_wall_s=0.2,
-        phases={"data_wait": 0.02, "host": 0.03, "compute": 0.13,
-                "sync": 0.02},
-        agreement={"ok": True},
-        straggler={"rank": 1, "cause": "compute-bound"},
-        device="tpu", path=path)
-    assert entry["committed_to"] == path
-    line = json.loads(open(path).read().splitlines()[0])
-    assert bench_log.check_line(line) == []
-
-    # Phases that do not sum to the step wall fail the lint: the
-    # decomposition must partition, not narrate.
-    bad = dict(line, phases={"data_wait": 0.02, "host": 0.03,
-                             "compute": 0.05, "sync": 0.02})
-    assert any("partition" in e for e in bench_log.check_line(bad))
-    bad2 = dict(line)
-    bad2.pop("agreement")
-    assert any("agreement" in e for e in bench_log.check_line(bad2))
-    bad3 = dict(line)
-    bad3.pop("mfu")
-    assert any("mfu" in e for e in bench_log.check_line(bad3))
 
 
 def test_analyze_line_tolerates_and_reports_timing_family(tmp_path):
@@ -424,22 +255,20 @@ def test_timing_pass_accepts_synced_walls():
 
 def test_timing_pass_repo_instrumented_regions_clean():
     """The live `# step-timed` regions (session.timed_step, the engine
-    step, measure.py, anatomy_bench) must satisfy their own pass."""
+    step) must satisfy their own pass."""
     from ray_tpu.util.analyze.core import PASSES, ParsedModule
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     marked = []
     for rel in ("ray_tpu/train/session.py",
-                "ray_tpu/serve/llm_engine.py",
-                "ray_tpu/scripts/measure.py",
-                "ray_tpu/scripts/anatomy_bench.py"):
+                "ray_tpu/serve/llm_engine.py"):
         path = os.path.join(root, rel)
         src = open(path).read()
         if "# step-timed" in src:
             marked.append(rel)
             mod = ParsedModule(path, rel, src, ast.parse(src))
             assert PASSES["timing"](mod) == [], rel
-    assert len(marked) == 4  # the annotations exist and stay
+    assert len(marked) == 2  # the annotations exist and stay
 
 
 # -- named signals + grafana -------------------------------------------------
@@ -448,19 +277,21 @@ def test_timing_pass_repo_instrumented_regions_clean():
 def test_named_signals_parse_with_percent_semantics():
     from ray_tpu.cluster.signals import parse_slo
 
-    s = parse_slo('mfu{trial="x"} < 40% over 120s')
-    # Percent against a *_percent family stays in gauge units (40, not
-    # 0.4) — the threshold the grammar promises.
-    assert s["threshold"] == pytest.approx(40.0)
-    assert s["signal"][0] == "gauge_mean"
+    s = parse_slo("gauge_avg(ray_tpu_worker_cpu_percent) > 90% over 120s")
+    # Percent against a *_percent family stays in gauge units (90, not
+    # 0.9) — the threshold the grammar promises.
+    assert s["threshold"] == pytest.approx(90.0)
+    assert s["signal"][0] == "gauge_avg"
     assert s["window_s"] == 120.0
+    with pytest.raises(ValueError):
+        parse_slo('mfu{trial="x"} < 40% over 120s')  # no such signal
     assert parse_slo("sync_ratio < 25% over 60s")["threshold"] == \
         pytest.approx(0.25)
     assert parse_slo("step_p99 < 500ms")["threshold"] == \
         pytest.approx(0.5)
 
 
-def test_signal_plane_evaluates_mfu_and_sync_ratio():
+def test_signal_plane_evaluates_sync_ratio():
     from ray_tpu.cluster.signals import SignalPlane
 
     plane = SignalPlane(history_s=600.0, scrape_interval_s=1.0,
@@ -471,10 +302,6 @@ def test_signal_plane_evaluates_mfu_and_sync_ratio():
 
     for t in range(5):
         plane.ring.ingest(float(t), {
-            "ray_tpu_mfu_percent": {
-                lbl(node_id="n", trial="x", rank="0"): 40.0,
-                lbl(node_id="n", trial="x", rank="1"): 12.0,
-            },
             "ray_tpu_step_phase_seconds": {
                 lbl(node_id="n", trial="x", phase="sync",
                     rank="0"): 0.03,
@@ -482,13 +309,9 @@ def test_signal_plane_evaluates_mfu_and_sync_ratio():
                     rank="0"): 0.07,
             },
         })
-    plane.register_slo("mfu-floor", 'mfu{trial="x"} < 40% over 60s')
     plane.register_slo("sync-share", "sync_ratio < 20% over 60s")
     plane.evaluate_slos(5.0)
     st = plane.slo_status()["slos"]
-    # MFU is the mean ACROSS ranks of per-rank window averages — two
-    # ranks at 40 and 12 read 26, not 52.
-    assert st["mfu-floor"]["value"] == pytest.approx(26.0)
     assert st["sync-share"]["value"] == pytest.approx(0.3)
     assert st["sync-share"]["state"] == "burning"
 
@@ -497,8 +320,8 @@ def test_grafana_registry_covers_new_families():
     from ray_tpu.util.grafana import generate_dashboard
 
     titles = [p["title"] for p in generate_dashboard()["panels"]]
-    for family in ("ray_tpu_mfu_percent", "ray_tpu_step_phase_seconds"):
-        assert any(family in t for t in titles), family
+    assert any("ray_tpu_step_phase_seconds" in t for t in titles)
+    assert not any("mfu" in t.lower() for t in titles)
 
 
 # -- cluster backend: anatomy federation + dead-rank retraction --------------
@@ -506,9 +329,9 @@ def test_grafana_registry_covers_new_families():
 
 def test_cluster_anatomy_federates_and_retracts_on_worker_death():
     """Cluster backend: anat events ship over the worker-events plane,
-    the agent's replay exports the per-rank MFU/phase gauges on the
+    the agent's replay exports the per-rank phase gauges on the
     federated scrape, and a dead worker's series are retracted by the
-    agent's sweep (the new families ride the same gauge_keys ledger as
+    agent's sweep (the family rides the same gauge_keys ledger as
     rank_step)."""
     from ray_tpu.cluster.cluster_utils import Cluster
     from ray_tpu.cluster.gcs_client import GcsClient
@@ -521,7 +344,6 @@ def test_cluster_anatomy_federates_and_retracts_on_worker_death():
     gcs = GcsClient(c.address)
     try:
         def train_fn(config):
-            session.set_step_cost(1e9)
             for _ in range(120):
                 session.timed_step(time.sleep, 0.05)
                 session.report({})
@@ -547,13 +369,10 @@ def test_cluster_anatomy_federates_and_retracts_on_worker_death():
         def anat_series(p):
             # Earlier LOCAL-backend tests share this pytest process's
             # registry; the agent owns only its own node's series.
-            out = []
-            for fam in ("ray_tpu_step_phase_seconds",
-                        "ray_tpu_mfu_percent"):
-                out += [dict(lb) for lb in (p.get(fam) or {})
-                        if dict(lb).get("trial") == "train"
-                        and dict(lb).get("node_id") != "local"]
-            return out
+            return [dict(lb) for lb in
+                    (p.get("ray_tpu_step_phase_seconds") or {})
+                    if dict(lb).get("trial") == "train"
+                    and dict(lb).get("node_id") != "local"]
 
         # The gauges federate while the gang is training — the agent
         # replays the workers' shipped anat events live...
