@@ -380,10 +380,11 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 # * ``gpt2_init_cache``   — slot-indexed ring KV-cache in device memory,
 #   ``[n_layer, slots, cache_len, n_head, head_dim]`` in the activation
 #   dtype (bf16 by default — no fp32 cache copy ever materializes);
-# * ``gpt2_prefill``      — the second jitted shape: a fixed
-#   ``[rows, prompt_len]`` chunked-prefill lane writing each prompt's
-#   K/V into its slot's cache rows and sampling the FIRST token from the
-#   last real position's logits;
+# * ``gpt2_prefill_chunk`` — the second jitted shape: ``[rows, C]`` tokens
+#   of a prompt at a start offset, writing their K/V into the slot's cache
+#   rows, attending over what earlier chunks left there, and giving the
+#   logits of the chunk's last real position (the last chunk's sample the
+#   FIRST token); ``gpt2_prefill`` is whole prompts through it;
 # * ``gpt2_decode_step``  — one token for every slot: attend over the
 #   valid cache window and this token's own K/V, next-token logits, and
 #   this token's K/V written at the slot's ring cursor.
@@ -401,8 +402,9 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 #   (``cached_decode_attention``), so it is the same softmax over the same
 #   keys as a write-then-read. (With the cache in the loop's carry the
 #   compiler re-laid the whole cache out, padded, for the one-row writes.)
-# * prefill reads no cache, so its loop carries the stacked cache and each
-#   layer writes its ``[P, H, hd]`` row blocks in place.
+# * a prefill chunk's loop carries the stacked cache: each layer writes
+#   its ``[C, H, hd]`` row blocks in place and then cuts the slot's key
+#   window (not a layer's block) out of the stack it carries.
 #
 # Ring semantics: the write cursor is ``pos % cache_len`` and the
 # attention mask covers ``min(pos + 1, cache_len)`` entries — a
@@ -503,27 +505,36 @@ def _mlp_block(x: jax.Array, p: Params, dt) -> jax.Array:
         return x + y @ p["mlp_out_w"].astype(dt) + p["mlp_out_b"].astype(dt)
 
 
-# jax-hot-path: traced into the engine's single compiled prefill lane
-def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
-                 slots: jax.Array, lengths: jax.Array, cfg: GPT2Config
-                 ) -> tuple[jax.Array, Params]:
-    """Chunked-prefill lane: the engine's SECOND (and only other) jitted
-    shape.
+# jax-hot-path: traced into the engine's single compiled prefill program
+def gpt2_prefill_chunk(params: Params, cache: Params, tokens: jax.Array,
+                       slots: jax.Array, start: jax.Array,
+                       lengths: jax.Array, cfg: GPT2Config,
+                       window: int | None = None
+                       ) -> tuple[jax.Array, Params]:
+    """A chunk of a prompt: the engine's SECOND (and only other) jitted
+    shape, run once for every C tokens of a request.
 
-    tokens [R, P] int32 zero-padded prompts, slots [R] int32 (each row's
-    target cache slot; point unused rows at a scratch slot), lengths [R]
-    int32. Runs the full causal forward over the padded window, writes
-    rows ``[0, P)`` of each target slot's K/V cache, and returns
-    (logits [R, V] fp32 at each prompt's last real token, new cache).
-    Rows past a prompt's length hold pad garbage; the decode mask never
-    reads them — the slot's own later writes overwrite them in order."""
-    r, p_len = tokens.shape
+    tokens [R, C] int32, row r's prompt tokens ``start[r] .. start[r] + C``
+    (zero-padded past the prompt), slots [R] int32 (each row's cache slot),
+    start [R] int32 (a multiple of C), lengths [R] int32 (the chunk's real
+    tokens). Writes rows ``[start, start + C)`` of each slot's K/V and
+    attends each query ``start + i`` over its slot's rows ``<= start + i``
+    (what earlier chunks left there and the chunk's own), among the first
+    ``window`` rows (static; the whole slot when None). Returns (logits
+    [R, V] fp32 at each chunk's last real token, new cache); the last
+    chunk's are the prompt's. Rows past a prompt's length hold pad
+    garbage; the decode mask never reads them — the slot's own later
+    writes overwrite them in order."""
+    r, c = tokens.shape
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
     dt = cfg.dtype
+    window = window or cache["k"].shape[2]
+    pos = jnp.clip(start[:, None] + jnp.arange(c)[None, :], 0,
+                   cfg.seq_len - 1)
     with jax.named_scope("embed"):
-        x = params["wte"].astype(dt)[tokens] \
-            + params["wpe"].astype(dt)[:p_len]
-    from ray_tpu.ops.attention import cache_write_prompt
+        x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[pos]
+    from ray_tpu.ops.attention import (cache_write_prompt,
+                                       cached_chunk_attention)
 
     def block(carry, layer):
         x, k_all, v_all = carry  # the stacked cache, written in place
@@ -533,16 +544,17 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
         with jax.named_scope("attn_proj"):
             qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
             q, k_, v_ = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(r, p_len, h, hd)
-            k_ = k_.reshape(r, p_len, h, hd)
-            v_ = v_.reshape(r, p_len, h, hd)
-        with jax.named_scope("attn"):
-            attn = causal_attention(q, k_, v_, use_flash=False)
         with jax.named_scope("cache_write"):
-            k_all = cache_write_prompt(k_all, i, k_, slots)
-            v_all = cache_write_prompt(v_all, i, v_, slots)
+            k_all = cache_write_prompt(
+                k_all, i, k_.reshape(r, c, h, hd), slots, start)
+            v_all = cache_write_prompt(
+                v_all, i, v_.reshape(r, c, h, hd), slots, start)
+        with jax.named_scope("attn"):
+            attn = cached_chunk_attention(
+                q.reshape(r, c, h, hd), k_all, v_all, i, slots, start,
+                window)
         with jax.named_scope("attn_proj"):
-            x = x + attn.reshape(r, p_len, d) @ p["attn_out_w"].astype(dt) \
+            x = x + attn.reshape(r, c, d) @ p["attn_out_w"].astype(dt) \
                 + p["attn_out_b"].astype(dt)
         x = _mlp_block(x, p, dt)
         return (x, k_all, v_all), None
@@ -553,11 +565,23 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
     with jax.named_scope("ln"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     with jax.named_scope("head"):
-        last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]  # [R, D]
+        last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, c - 1)]  # [R, D]
         logits = jnp.einsum(
             "rd,vd->rv", last, params["wte"].astype(dt),
             preferred_element_type=jnp.float32)
     return logits, {"k": k_all, "v": v_all}
+
+
+def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
+                 slots: jax.Array, lengths: jax.Array, cfg: GPT2Config
+                 ) -> tuple[jax.Array, Params]:
+    """Whole padded prompts tokens [R, P] through ``gpt2_prefill_chunk``,
+    chunk after chunk from row 0 of each slot (``models/prefill.py``):
+    (logits [R, V] fp32 at each prompt's last real token, new cache)."""
+    from ray_tpu.models.prefill import whole_prompts
+
+    return whole_prompts(gpt2_prefill_chunk, params, cache, tokens, slots,
+                         lengths, cfg)
 
 
 def gpt2_flops_per_token(cfg: GPT2Config, seq_len: int | None = None) -> float:

@@ -278,7 +278,7 @@ def llama_loss(params: Params, batch: dict[str, jax.Array],
 # -- autoregressive decoding (serving path) --------------------------------
 #
 # Same contract as the GPT-2 decode API (``models/gpt2.py``): one jitted
-# decode step over a fixed slot batch + one jitted chunked-prefill lane,
+# decode step over a fixed slot batch + one jitted prefill chunk,
 # with a slot-indexed ring KV-cache. The cache rides the GQA layout —
 # only ``n_kv_head`` heads are cached (``[n_layer, slots, cache_len,
 # n_kv_head, head_dim]`` in the activation dtype, bf16 by default), and
@@ -286,7 +286,8 @@ def llama_loss(params: Params, batch: dict[str, jax.Array],
 # bandwidth saving carries straight into serving HBM footprint. The
 # stacked cache stays in place across the layer loop exactly as there:
 # decode's loop reads it and hands out the new rows, written after the
-# loop; prefill's loop carries it and writes each layer's rows in place.
+# loop; a prefill chunk's loop carries it, writes each layer's rows in
+# place and reads the slot's key window back.
 
 
 def llama_init_cache(cfg: LlamaConfig, slots: int, cache_len: int) -> Params:  # decode-path
@@ -370,36 +371,42 @@ def llama_decode_step(params: Params, cache: Params, tokens: jax.Array,
     return logits, cache
 
 
-# jax-hot-path: traced into the engine's single compiled prefill lane
-def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
-                  slots: jax.Array, lengths: jax.Array, cfg: LlamaConfig
-                  ) -> tuple[jax.Array, Params]:
-    """Chunked-prefill lane (fixed [R, P] shape): full causal forward
-    over the padded prompts, K/V written into each row's target slot,
-    logits at each prompt's last real token. Same pad-garbage contract
-    as gpt2_prefill."""
-    r, p_len = tokens.shape
+# jax-hot-path: traced into the engine's single compiled prefill program
+def llama_prefill_chunk(params: Params, cache: Params, tokens: jax.Array,
+                        slots: jax.Array, start: jax.Array,
+                        lengths: jax.Array, cfg: LlamaConfig,
+                        window: int | None = None
+                        ) -> tuple[jax.Array, Params]:
+    """A chunk of a prompt (fixed [R, C] shape): tokens at positions
+    ``start + i``, K/V rows ``[start, start + C)`` written into each row's
+    slot, queries over the slot's rows ``<= start + i`` (the K/V heads read
+    as they lie), logits at the chunk's last real token. Same contract as
+    gpt2_prefill_chunk."""
+    r, c = tokens.shape
     nh, nkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt = cfg.dtype
+    window = window or cache["k"].shape[2]
+    pos = (start[:, None] + jnp.arange(c)[None, :]).reshape(r * c)
     x = params["embed"].astype(dt)[tokens]
-    from ray_tpu.ops.attention import cache_write_prompt
+    from ray_tpu.ops.attention import (cache_write_prompt,
+                                       cached_chunk_attention)
+
+    def rope(x, heads):
+        return _rope_at(x.reshape(r * c, heads, hd), pos,
+                        cfg.rope_theta).reshape(r, c, heads, hd)
 
     def block(carry, layer):
         x, k_all, v_all = carry  # the stacked cache, written in place
         p, i = layer
         y = _rms_norm(x, p["attn_norm"])
-        q = _rope((y @ p["wq"].astype(dt)).reshape(r, p_len, nh, hd),
-                  cfg.rope_theta)
-        k_ = _rope((y @ p["wk"].astype(dt)).reshape(r, p_len, nkv, hd),
-                   cfg.rope_theta)
-        v_ = (y @ p["wv"].astype(dt)).reshape(r, p_len, nkv, hd)
-        k_all = cache_write_prompt(k_all, i, k_, slots)
-        v_all = cache_write_prompt(v_all, i, v_, slots)
-        rep = nh // nkv
-        attn = causal_attention(
-            q, jnp.repeat(k_, rep, axis=2), jnp.repeat(v_, rep, axis=2),
-            use_flash=False)
-        x = x + attn.reshape(r, p_len, nh * hd) @ p["wo"].astype(dt)
+        q = rope(y @ p["wq"].astype(dt), nh)
+        k_ = rope(y @ p["wk"].astype(dt), nkv)
+        v_ = (y @ p["wv"].astype(dt)).reshape(r, c, nkv, hd)
+        k_all = cache_write_prompt(k_all, i, k_, slots, start)
+        v_all = cache_write_prompt(v_all, i, v_, slots, start)
+        attn = cached_chunk_attention(q, k_all, v_all, i, slots, start,
+                                      window)
+        x = x + attn.reshape(r, c, nh * hd) @ p["wo"].astype(dt)
         y = _rms_norm(x, p["mlp_norm"])
         gate = y @ p["w_gate"].astype(dt)
         up = y @ p["w_up"].astype(dt)
@@ -410,11 +417,22 @@ def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
         block, (x, cache["k"], cache["v"]),
         (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rms_norm(x, params["final_norm"])
-    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]
+    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, c - 1)]
     logits = jnp.einsum(
         "rd,dv->rv", last, params["lm_head"].astype(dt),
         preferred_element_type=jnp.float32)
     return logits, {"k": k_all, "v": v_all}
+
+
+def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
+                  slots: jax.Array, lengths: jax.Array, cfg: LlamaConfig
+                  ) -> tuple[jax.Array, Params]:
+    """Whole padded prompts tokens [R, P] through ``llama_prefill_chunk``
+    (``models/prefill.py``): logits at each prompt's last real token."""
+    from ray_tpu.models.prefill import whole_prompts
+
+    return whole_prompts(llama_prefill_chunk, params, cache, tokens, slots,
+                         lengths, cfg)
 
 
 def llama_flops_per_token(cfg: LlamaConfig,

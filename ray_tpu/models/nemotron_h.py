@@ -16,8 +16,8 @@ What differs from the dense families here:
   pytree: ``k`` / ``v`` rows for the ``*`` layers (the ring contract of
   ``ops/attention.py``), and for every ``M`` layer a convolution tail
   ``conv`` and a float32 SSM state (``ssm``, one array a layer), which
-  have no ring: a prefill
-  overwrites them whole, by slot, and leaves exactly the state after each
+  have no ring: a prompt's first chunk begins them anew, every chunk
+  continues from them and leaves, by slot, exactly the state after its
   row's ``length`` real tokens (padded positions take ``dt = 0``). The
   engine donates the pytree; every layer writes its part in place, and no
   layer-sized block is copied in or out of the layer loop (PR 25's rule).
@@ -46,7 +46,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
+                                   cached_chunk_attention,
                                    cached_decode_attention, causal_attention)
 from ray_tpu.ops.moe import dropless_experts, route
 
@@ -288,12 +290,13 @@ def _mamba_step(p: Params, y: jax.Array, tail: jax.Array, state: jax.Array,
     return out, window[1:], state.astype(cfg.ssm_state_dtype)
 
 
-def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig):
+def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig, state=None):
     """The recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
-    ``y_t = S_t C_t`` over whole rows from an empty state, blocked in
-    chunks of ``chunk_size`` (inside a chunk a masked product, between
-    chunks the state): xs [R, T, H, P], dt [R, T, H] float32 (0 at padded
-    positions: they leave the state as it is), a [H], b / c [R, T, G, N].
+    ``y_t = S_t C_t`` over rows from ``state`` [R, H, P, N] float32 (None:
+    empty), blocked in chunks of ``chunk_size`` (inside a chunk a masked
+    product, between chunks the state): xs [R, T, H, P], dt [R, T, H]
+    float32 (0 at padded positions: they leave the state as it is), a [H],
+    b / c [R, T, G, N].
     -> (y [R, T, H, P] float32, the state after the row [R, H, P, N])."""
     r, t, h, pdim = xs.shape
     g, n = b.shape[2], b.shape[3]
@@ -329,8 +332,10 @@ def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig):
         add_c, through_c = inp
         return state * through_c[..., None, None] + add_c, state
 
+    state = jnp.zeros((r, g, rep, pdim, n), f32) if state is None \
+        else state.astype(f32).reshape(r, g, rep, pdim, n)
     state, before = jax.lax.scan(
-        chunk, jnp.zeros((r, g, rep, pdim, n), f32),
+        chunk, state,
         (add.transpose(1, 0, 2, 3, 4, 5), through.transpose(1, 0, 2, 3)))
     before = before.transpose(1, 0, 2, 3, 4, 5)  # the state entering a chunk
     y = y + jnp.einsum("rcign,rcghpn->rcighp", c, before.astype(mm),
@@ -340,30 +345,34 @@ def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig):
 
 
 def _mamba_rows(p: Params, y: jax.Array, lengths: jax.Array,
-                cfg: NemotronHConfig):
-    """Whole rows from an empty state. y [R, T, D] (normed), lengths [R]:
-    the real tokens of each row. -> (the mixer's output [R, T, D], the
-    convolution's tail after ``length`` tokens [K-1, R, C], the state
-    after ``length`` tokens [R, H, P, N])."""
+                cfg: NemotronHConfig, tail: jax.Array | None = None,
+                state: jax.Array | None = None):
+    """Rows of T tokens that continue from ``tail`` [R, K-1, C] (the
+    convolution's last inputs) and ``state`` [R, H, P, N]; None for both:
+    rows that begin. y [R, T, D] (normed), lengths [R]: the real tokens of
+    each row. -> (the mixer's output [R, T, D], the convolution's tail
+    after ``length`` tokens [K-1, R, C], the state after ``length`` tokens
+    [R, H, P, N]); with no real token, the tail and state given."""
     dt_ = cfg.dtype
     r, t, _ = y.shape
     k = cfg.conv_kernel
     with jax.named_scope("ssm_proj"):
         z, xbc, dt_raw = _ssm_inputs(p, y @ p["in_proj"].astype(dt_), cfg)
     with jax.named_scope("conv"):
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))) if tail is None \
+            else jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
         w = p["conv_w"].astype(jnp.float32)
         conv = sum(padded[:, j:j + t].astype(jnp.float32) * w[j]
                    for j in range(k)) + p["conv_b"].astype(jnp.float32)
         xs, b, c = _ssm_split(jax.nn.silu(conv).astype(dt_), cfg)
-        # the inputs at length - (K-1) .. length - 1, zero before the row
+        # the inputs at length - (K-1) .. length - 1, the old tail before 0
         at = lengths[None, :] + jnp.arange(k - 1)[:, None]  # into `padded`
         tail = padded[jnp.arange(r)[None, :], at]  # [K-1, R, C]
     with jax.named_scope("ssm_scan"):
         dt, a = _dt_and_a(p, dt_raw)
         dt = jnp.where(jnp.arange(t)[None, :, None] < lengths[:, None, None],
                        dt, 0.0)
-        yh, state = _ssd_scan(xs, dt, a, b, c, cfg)
+        yh, state = _ssd_scan(xs, dt, a, b, c, cfg, state)
         yh = yh + p["d_skip"].astype(jnp.float32)[None, None, :, None] \
             * xs.astype(jnp.float32)
     with jax.named_scope("ssm_norm"):
@@ -502,51 +511,74 @@ def nemotron_h_decode_step(params: Params, cache: Params, tokens: jax.Array,
 
 def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
           cfg: NemotronHConfig, cache: Params | None = None,
-          slots: jax.Array | None = None):
-    """Whole rows through every layer: tokens [R, T], lengths [R]. With a
-    cache, each layer writes its part of rows' state into ``slots`` in
-    place. -> (hidden [R, T, D] before ``norm_f``, the cache)."""
+          slots: jax.Array | None = None, start: jax.Array | None = None,
+          window: int | None = None):
+    """Rows of T tokens through every layer: tokens [R, T], lengths [R].
+    Without a cache, whole rows from nothing. With one, row r is a chunk
+    of a prompt at positions ``start[r] + i``: every layer continues from
+    its part of ``slots[r]``'s state (K/V rows ``< start``; the
+    convolution's tail and the SSM state, or none where ``start == 0``)
+    and leaves there, in place, its state after the row's real tokens.
+    -> (hidden [R, T, D] before ``norm_f``, the cache)."""
     r, t = tokens.shape
     nh, nkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     dt_ = cfg.dtype
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt_)[tokens]
-    # a padded lane's other positions are not routed: no expert computes them
+    # a padded chunk's other positions are not routed: no expert computes them
     real = jnp.arange(t)[None, :] < lengths[:, None]
     if cache is not None:
         k_all, v_all, conv_all = cache["k"], cache["v"], cache["conv"]
         ssm_all = list(cache["ssm"])
+        window = window or k_all.shape[2]
+        goes_on = start > 0  # [R]: the slot holds this prompt's state
     i_m = i_a = 0
     for kind, p in zip(cfg.pattern, params["layers"]):
         with jax.named_scope("ln"):
             y = _rms_norm(x, p["norm"], cfg.eps)
-        if kind == "M":
-            out, tail, state = _mamba_rows(p, y, lengths, cfg)
-            if cache is not None:
-                with jax.named_scope("state_write"):
-                    tail = tail.astype(conv_all.dtype)
-                    for i in range(r):  # by slot; distinct but the scratch
-                        conv_all = jax.lax.dynamic_update_slice(
-                            conv_all, tail[None, :, i:i + 1],
-                            (i_m, 0, slots[i], 0))
-                        ssm_all[i_m] = jax.lax.dynamic_update_slice(
-                            ssm_all[i_m], state[i:i + 1],
-                            (slots[i], 0, 0, 0))
+        if kind == "M" and cache is None:
+            out, _, _ = _mamba_rows(p, y, lengths, cfg)
+        elif kind == "M":
+            with jax.named_scope("conv"):  # its left context, by slot
+                tail = jnp.stack([jax.lax.dynamic_slice(
+                    conv_all, (i_m, 0, slots[i], 0),
+                    (1, cfg.conv_kernel - 1, 1, cfg.conv_dim))[0, :, 0]
+                    for i in range(r)])  # [R, K-1, C]
+                tail = jnp.where(goes_on[:, None, None], tail, 0)
+            with jax.named_scope("ssm_scan"):  # its first state, by slot
+                state = jnp.concatenate([jax.lax.dynamic_slice(
+                    ssm_all[i_m], (slots[i], 0, 0, 0),
+                    (1,) + ssm_all[i_m].shape[1:]) for i in range(r)])
+                state = jnp.where(goes_on[:, None, None, None], state, 0)
+            out, tail, state = _mamba_rows(p, y, lengths, cfg, tail, state)
+            with jax.named_scope("state_write"):
+                tail = tail.astype(conv_all.dtype)
+                for i in range(r):  # by slot; distinct but the scratch
+                    conv_all = jax.lax.dynamic_update_slice(
+                        conv_all, tail[None, :, i:i + 1],
+                        (i_m, 0, slots[i], 0))
+                    ssm_all[i_m] = jax.lax.dynamic_update_slice(
+                        ssm_all[i_m], state[i:i + 1],
+                        (slots[i], 0, 0, 0))
             i_m += 1
         elif kind == "*":
             with jax.named_scope("attn_proj"):
                 q = (y @ p["wq"].astype(dt_)).reshape(r, t, nh, hd)
                 k_ = (y @ p["wk"].astype(dt_)).reshape(r, t, nkv, hd)
                 v_ = (y @ p["wv"].astype(dt_)).reshape(r, t, nkv, hd)
-            with jax.named_scope("attn"):
-                rep = nh // nkv
-                attn = causal_attention(
-                    q, jnp.repeat(k_, rep, axis=2),
-                    jnp.repeat(v_, rep, axis=2), use_flash=False)
-            if cache is not None:
+            if cache is None:
+                with jax.named_scope("attn"):
+                    rep = nh // nkv
+                    attn = causal_attention(
+                        q, jnp.repeat(k_, rep, axis=2),
+                        jnp.repeat(v_, rep, axis=2), use_flash=False)
+            else:
                 with jax.named_scope("cache_write"):
-                    k_all = cache_write_prompt(k_all, i_a, k_, slots)
-                    v_all = cache_write_prompt(v_all, i_a, v_, slots)
+                    k_all = cache_write_prompt(k_all, i_a, k_, slots, start)
+                    v_all = cache_write_prompt(v_all, i_a, v_, slots, start)
+                with jax.named_scope("attn"):
+                    attn = cached_chunk_attention(
+                        q, k_all, v_all, i_a, slots, start, window)
             with jax.named_scope("attn_proj"):
                 out = attn.reshape(r, t, nh * hd) @ p["wo"].astype(dt_)
             i_a += 1
@@ -560,18 +592,25 @@ def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
     return x, cache
 
 
-# jax-hot-path: traced into the engine's single compiled prefill lane
-def nemotron_h_prefill(params: Params, cache: Params, tokens: jax.Array,
-                       slots: jax.Array, lengths: jax.Array,
-                       cfg: NemotronHConfig) -> tuple[jax.Array, Params]:
-    """The prefill lane (fixed [R, P] shape): the full causal forward over
-    the padded prompts; each row's K/V rows ``[0, P)`` go to its slot as in
-    ``gpt2_prefill``, and its slot's ``conv`` and ``ssm`` are overwritten
-    whole with the state after the row's ``length`` real tokens, whatever
-    the slot held. Logits at each prompt's last real token."""
-    r, p_len = tokens.shape
-    x, cache = _rows(params, tokens, lengths, cfg, cache, slots)
-    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, p_len - 1)]
+# jax-hot-path: traced into the engine's single compiled prefill program
+def nemotron_h_prefill_chunk(params: Params, cache: Params,
+                             tokens: jax.Array, slots: jax.Array,
+                             start: jax.Array, lengths: jax.Array,
+                             cfg: NemotronHConfig, window: int | None = None
+                             ) -> tuple[jax.Array, Params]:
+    """A chunk of a prompt (fixed [R, C] shape; ``gpt2_prefill_chunk``'s
+    contract for the K/V rows): tokens at positions ``start + i``, the
+    first ``lengths`` of them real. The Mamba layers carry the slot's state
+    across chunks: a chunk takes the slot's ``conv`` tail as the
+    convolution's left context and its ``ssm`` state as the scan's first
+    state, and leaves both as they stand after its real tokens;
+    ``start == 0`` begins from an empty tail and a zero state, whatever
+    the slot held. Padded positions take ``dt = 0`` and are routed to no
+    expert. Logits at the chunk's last real token."""
+    r, c = tokens.shape
+    x, cache = _rows(params, tokens, lengths, cfg, cache, slots, start,
+                     window)
+    last = x[jnp.arange(r), jnp.clip(lengths - 1, 0, c - 1)]
     with jax.named_scope("ln"):
         last = _rms_norm(last, params["norm_f"], cfg.eps)
     with jax.named_scope("head"):
@@ -579,6 +618,18 @@ def nemotron_h_prefill(params: Params, cache: Params, tokens: jax.Array,
             "rd,dv->rv", last, params["lm_head"].astype(cfg.dtype),
             preferred_element_type=jnp.float32)
     return logits, cache
+
+
+def nemotron_h_prefill(params: Params, cache: Params, tokens: jax.Array,
+                       slots: jax.Array, lengths: jax.Array,
+                       cfg: NemotronHConfig) -> tuple[jax.Array, Params]:
+    """Whole padded prompts tokens [R, P] through
+    ``nemotron_h_prefill_chunk`` (``models/prefill.py``): each row's slot
+    ends with the K/V rows of the whole window and with the ``conv`` tail
+    and ``ssm`` state after the row's ``length`` real tokens, whatever it
+    held. Logits at each prompt's last real token."""
+    return whole_prompts(nemotron_h_prefill_chunk, params, cache, tokens,
+                         slots, lengths, cfg)
 
 
 def nemotron_h_forward(params: Params, tokens: jax.Array,

@@ -135,19 +135,54 @@ def cache_write_token(cache: jax.Array, rows: jax.Array,
 
 # decode-path  # jax-hot-path: the KV cache stays in the activation dtype
 def cache_write_prompt(cache: jax.Array, layer: jax.Array, rows: jax.Array,
-                       slots: jax.Array) -> jax.Array:
-    """Prefill-lane write of one layer's rows, inside the layer loop
-    (which carries the stacked cache): row block ``rows[i]`` ([P, H, hd])
-    lands at ``cache[layer, slots[i], 0:P]`` of cache [N, S, L, H, hd].
-    Sequential over the (small, static) prefill-row axis — each write
-    must see the prior ones, and distinct slots make the order
-    immaterial (rows that share the scratch slot write only garbage
-    there)."""
+                       slots: jax.Array, start: jax.Array) -> jax.Array:
+    """Prefill write of one layer's rows, inside the layer loop (which
+    carries the stacked cache): row block ``rows[i]`` ([C, H, hd]) lands
+    at ``cache[layer, slots[i], start[i] : start[i] + C]`` of cache
+    [N, S, L, H, hd]. Sequential over the (small, static) row axis —
+    each write must see the prior ones, and distinct slots make the order
+    immaterial (rows that share a scratch slot write only garbage there)."""
     rows = rows.astype(cache.dtype)
     for i in range(rows.shape[0]):
         cache = jax.lax.dynamic_update_slice(
-            cache, rows[i, None, None], (layer, slots[i], 0, 0, 0))
+            cache, rows[i, None, None], (layer, slots[i], start[i], 0, 0))
     return cache
+
+
+def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
+                           layer: jax.Array, slots: jax.Array,
+                           start: jax.Array, window: int) -> jax.Array:
+    """A chunk of C prompt tokens a row over the row's own slot, AFTER the
+    chunk's K/V rows were written there (``cache_write_prompt``).
+
+    q [R, C, H, hd], the queries of positions ``start[r] + i``; k_all /
+    v_all the stacked cache [N, S, L, G, hd] the layer loop carries (H a
+    multiple of G: each K/V head serves its H // G query heads as it lies);
+    slots, start [R] int32. Query i sees keys ``<= start + i`` among the
+    slot's first ``window`` rows (static; the caller's bound on
+    ``start + C``): the rows earlier chunks of the same prompt left, and
+    the chunk's own up to itself. Only that window is cut out of the
+    stack, R blocks of [window, G, hd]; the cache itself is not touched.
+    Operands in the cache's type, float32 scores and softmax, output in
+    q's type: ``xla_causal_attention``'s arithmetic on a wider key axis."""
+    r, c, h, hd = q.shape
+    g = k_all.shape[3]
+
+    def rows_of(cache):
+        return jnp.stack([jax.lax.dynamic_slice(
+            cache, (layer, slots[i], 0, 0, 0), (1, 1, window, g, hd))[0, 0]
+            for i in range(r)])
+
+    k, v = rows_of(k_all), rows_of(v_all)  # [R, W, G, hd]
+    q = q.reshape(r, c, g, h // g, hd)
+    scores = jnp.einsum("rqgpd,rkgd->rgpqk", q.astype(k.dtype), k,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    seen = jnp.arange(window)[None, None, :] \
+        <= (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [R, C, W]
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None, None], scores, -1e30), axis=-1)
+    out = jnp.einsum("rgpqk,rkgd->rqgpd", probs.astype(v.dtype), v)
+    return out.reshape(r, c, h, hd).astype(q.dtype)
 
 
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,

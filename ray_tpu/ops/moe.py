@@ -186,10 +186,17 @@ def moe_ffn_ep_local(px, p_router, p_win, p_wout, *, n_experts: int,
 # own experts add for the tokens routed to them. What the absent experts
 # would have added is left out (the chips that hold them add it).
 
-# Up to this many rows a grouped product's row tile is at least as tall as
-# the whole batch, so "every held expert over every row" does the same MXU
-# passes and reads each expert's weights once, without the sort.
-DENSE_ROWS = 128
+# Up to this many rows "every held expert over every row" is the cheaper
+# of the two forms on a v5e: it reads each expert's weights once, at the
+# memory's pace (one 256-token prefill chunk of the Nemotron cell: 7.05 GB
+# of experts in 9.4 ms), where the TPU's grouped product pays a cost a
+# group that does not shrink with the rows (2.8 ms a product of 128 groups
+# at 5,632 rows against 5 ms at 90,112: 28 ms a chunk; PERF.md section 6,
+# PR 31). An engine's prefill chunk is this many tokens of one request
+# (``models/prefill.py``), so a chunk and a decode step take the batched
+# form and only a wider lane the grouped one. Past it the batched form's
+# wasted products (every expert over every row) cost more than the sort.
+DENSE_ROWS = 256
 
 
 def route(x: jax.Array, w_gate: jax.Array, bias: jax.Array, top_k: int,

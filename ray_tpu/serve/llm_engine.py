@@ -8,11 +8,17 @@ state, with requests admitted into (and evicted from) the running batch
 engine, mounted as an ordinary Serve deployment callable:
 
 * **Two compiled shapes, ever.** A fixed ``[max_batch]`` decode step
-  and a fixed ``[prefill_rows, max_prompt_len]`` chunked-prefill lane
-  (``models/gpt2.py`` / ``models/llama.py`` decode APIs). Per-engine
-  compile counters (trace-time side effects, the ``fused_norm`` test
-  idiom) prove no per-request recompile ever happens — the benchmark's
-  serving cells require ``compiles == {decode: 1, prefill: 1}``.
+  and ONE prefill chunk: ``[1, C]`` tokens of one request at a start
+  offset (``models/*.py``: ``<family>_prefill_chunk``), executed
+  ``ceil(len / C)`` times a prompt, so a prefill costs what its prompt
+  costs and not what the longest allowed prompt would. C is the
+  engine's (``models/prefill.py``). ``prefill_rows`` is a scheduling
+  bound, not a shape: a turn admits up to that many waiting requests,
+  and every chunk of each runs before the next decode step, so a slot
+  is never half-prefilled when a step runs. Per-engine compile counters
+  (trace-time side effects, the ``fused_norm`` test idiom) prove no
+  per-request recompile ever happens — the benchmark's serving cells
+  require ``compiles == {decode: 1, prefill: 1}``.
 * **Slot-indexed ring KV-cache in device memory.** Per-slot write
   cursors via ``lax.dynamic_update_slice``; the cache rides the model's
   activation dtype (bf16 — no fp32 copy) and, for Llama, the GQA
@@ -117,21 +123,21 @@ class _Request:
 
 
 def _model_bundle(model: str, config, preset: str):
-    """(config, init, init_cache, prefill, decode_step) for a model
+    """(config, init, init_cache, prefill_chunk, decode_step) for a model
     family — resolved lazily so importing this module never pulls jax."""
     if model == "gpt2":
         from ray_tpu.models import gpt2 as m
 
         cfg = config or (m.GPT2Config.tiny() if preset == "tiny"
                          else m.GPT2Config.small())
-        return (cfg, m.gpt2_init, m.gpt2_init_cache, m.gpt2_prefill,
+        return (cfg, m.gpt2_init, m.gpt2_init_cache, m.gpt2_prefill_chunk,
                 m.gpt2_decode_step)
     if model == "llama":
         from ray_tpu.models import llama as m
 
         cfg = config or (m.LlamaConfig.tiny() if preset == "tiny"
                          else m.LlamaConfig.small())
-        return (cfg, m.llama_init, m.llama_init_cache, m.llama_prefill,
+        return (cfg, m.llama_init, m.llama_init_cache, m.llama_prefill_chunk,
                 m.llama_decode_step)
     if model == "nemotron_h":
         from ray_tpu.models import nemotron_h as m
@@ -139,7 +145,7 @@ def _model_bundle(model: str, config, preset: str):
         cfg = config or (m.NemotronHConfig.tiny() if preset == "tiny"
                          else m.NemotronHConfig())
         return (cfg, m.nemotron_h_init, m.nemotron_h_init_cache,
-                m.nemotron_h_prefill, m.nemotron_h_decode_step)
+                m.nemotron_h_prefill_chunk, m.nemotron_h_decode_step)
     raise ValueError(
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h)")
 
@@ -173,6 +179,15 @@ class LLMEngine:
     family's ``serving_dtypes``) and keeps nothing of what it was cast
     from: the step reads the bytes it multiplies with and no others.
 
+    Its two programs are the ``[max_batch + 1]`` decode step and the
+    ``[1, prefill_chunk]`` prefill chunk; a prompt runs the second once
+    for every ``prefill_chunk`` tokens (``llm_stats()``:
+    ``prefill_chunks`` executions for ``prefill_rows_real`` requests).
+    ``prefill_rows`` bounds how many requests one turn admits between
+    two decode steps; ``prefill_chunk`` defaults to the rule of
+    ``models/prefill.py`` (tests at toy widths pass a small one), and the
+    cache must hold whole chunks up to ``max_prompt_len``.
+
     Deploy it like any Serve class::
 
         eng = serve.deployment(name="llm", max_concurrent_queries=64)(
@@ -193,16 +208,24 @@ class LLMEngine:
                  max_new_tokens: int = 16, max_new_cap: int = 512,
                  max_queue: int = 8192, eos_token: Optional[int] = None,
                  step_throttle_s: float = 0.0,
-                 deployment: Optional[str] = None):
+                 deployment: Optional[str] = None,
+                 prefill_chunk: Optional[int] = None):
         import jax
         import numpy as np
 
+        from ray_tpu.models.prefill import chunk_len, key_window
         from ray_tpu.util.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
-        if max_prompt_len > cache_len:
+        # The chunk is the engine's, by rule (models/prefill.py); the
+        # argument is for tests at toy widths, where a rule made for a
+        # chip's ridge would never cut a prompt.
+        chunk = int(prefill_chunk or chunk_len(max_prompt_len))
+        window = key_window(max_prompt_len, chunk)
+        if chunk < 1 or window > cache_len:
             raise ValueError(
-                f"max_prompt_len={max_prompt_len} must fit the cache "
+                f"max_prompt_len={max_prompt_len} in chunks of {chunk} "
+                f"writes {window} rows of a slot: they must fit the cache "
                 f"(cache_len={cache_len})")
         self._np = np
         self._jnp = jax.numpy
@@ -211,6 +234,7 @@ class LLMEngine:
         self.cache_len = int(cache_len)
         self.max_prompt_len = int(max_prompt_len)
         self.prefill_rows = max(1, min(int(prefill_rows), self.max_batch))
+        self.prefill_chunk = chunk
         self.max_new_tokens = int(max_new_tokens)
         self.max_new_cap = int(max_new_cap)
         self.max_queue = int(max_queue)
@@ -222,7 +246,7 @@ class LLMEngine:
         self._dep = deployment or "llm"
         self._dep_explicit = deployment is not None
 
-        cfg, init, init_cache, prefill, decode = _model_bundle(
+        cfg, init, init_cache, prefill_chunk_fn, decode = _model_bundle(
             model, config, preset)
         if model == "gpt2" and self.max_prompt_len > cfg.seq_len:
             # gpt2's learned position table bounds the prefill window;
@@ -238,8 +262,10 @@ class LLMEngine:
         self.params, self._param_bytes = _stored_params(
             init, jax.random.PRNGKey(seed), cfg)
         t1 = time.perf_counter()
-        # One scratch slot past max_batch: inactive prefill rows write
-        # their pad garbage there, keeping the prefill shape fixed.
+        # One slot past max_batch that no request ever holds: a padded
+        # lane's unused rows wrote there. The chunk program has no such
+        # row, but the decode step's shape includes the slot (and the
+        # benchmark counts it as cache), so it stays.
         self._cache = jax.block_until_ready(
             init_cache(cfg, self.max_batch + 1, self.cache_len))
         self._init_s = {"params": t1 - t0,
@@ -268,10 +294,15 @@ class LLMEngine:
                         self._jnp.int32)])
             return nxt, cache
 
-        def prefill_fn(params, cache, tokens, slots, lengths):
+        def prefill_fn(params, cache, packed):
+            # packed [1, chunk + 3] int32: the chunk's tokens, then its
+            # slot, start and real tokens (ONE host array an execution:
+            # each upload is a turn of the GIL among the streams' pollers)
             self._compiles["prefill"] += 1
-            logits, cache = prefill(params, cache, tokens, slots,
-                                    lengths, cfg)
+            logits, cache = prefill_chunk_fn(
+                params, cache, packed[:, :chunk], packed[:, chunk],
+                packed[:, chunk + 1], packed[:, chunk + 2], cfg,
+                window=window)
             with jax.named_scope("head"):
                 return (self._jnp.argmax(logits, axis=-1).astype(
                     self._jnp.int32), cache)
@@ -315,13 +346,15 @@ class LLMEngine:
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
             "errors": 0, "tokens_out": 0, "queue_peak": 0,
             "occupancy_sum": 0, "ring_wraps": 0,
-            # The prefill lane's fill: batches run, requests in them,
-            # their (truncated) prompt tokens, and the tokens the fixed
-            # [prefill_rows, max_prompt_len] lane computed for them.
-            # (prefill_rows_real: llm_stats() already has the setting
-            # under "prefill_rows".)
+            # The prefill lane's fill: admission turns that ran a
+            # prefill, requests in them, their (truncated) prompt tokens,
+            # executions of the chunk program (chunks a request =
+            # prefill_chunks / prefill_rows_real) and the tokens those
+            # computed (prefill_chunk each). (prefill_rows_real:
+            # llm_stats() already has the setting under "prefill_rows".)
             "prefill_batches": 0, "prefill_rows_real": 0,
             "prefill_tokens_real": 0, "prefill_tokens_lane": 0,
+            "prefill_chunks": 0,
         }
         self._loop_thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine-loop")
@@ -437,28 +470,36 @@ class LLMEngine:
         return True
 
     def _prefill_batch(self, batch: List[_Request], slots: List[int]):  # jax-hot-path
+        """Every admitted request's prompt, whole, before the next decode
+        step: ``ceil(len / prefill_chunk)`` executions of the one chunk
+        program each, dispatched back to back (the device runs them in
+        order on the cache each hands the next), then one sync on each
+        request's last chunk's token, in admission order."""
         np = self._np
-        rows = self.prefill_rows
-        p_len = self.max_prompt_len
+        chunk = self.prefill_chunk
         t0 = time.perf_counter()
         with tracing.device_span("llm.prefill.dispatch") as ds:
-            toks = np.zeros((rows, p_len), np.int32)
-            slot_idx = np.full(rows, self.max_batch, np.int32)  # scratch row
-            lengths = np.ones(rows, np.int32)
-            for i, req in enumerate(batch):
-                prompt = req.prompt[-p_len:]  # truncate to the lane window
-                toks[i, :len(prompt)] = prompt
-                slot_idx[i] = slots[i]
-                lengths[i] = len(prompt)
-            tokens_real = int(lengths[:len(batch)].sum())
-            ds.set_metadata(rows=len(batch), tokens_real=tokens_real)
-            first, self._cache = self._prefill_fn(
-                self.params, self._cache, self._jnp.asarray(toks),
-                self._jnp.asarray(slot_idx), self._jnp.asarray(lengths))
+            first, lengths, n_chunks = [], [], 0
+            for req, slot in zip(batch, slots):
+                # truncate to the longest prompt the slot's rows hold
+                prompt = req.prompt[-self.max_prompt_len:]
+                lengths.append(len(prompt))
+                for at in range(0, len(prompt), chunk):
+                    piece = prompt[at:at + chunk]
+                    packed = np.zeros((1, chunk + 3), np.int32)
+                    packed[0, :len(piece)] = piece
+                    packed[0, chunk:] = slot, at, len(piece)
+                    tok, self._cache = self._prefill_fn(
+                        self.params, self._cache, packed)
+                    n_chunks += 1
+                first.append(tok)  # the last chunk's
+            tokens_real = sum(lengths)
+            ds.set_metadata(rows=len(batch), tokens_real=tokens_real,
+                            chunks=n_chunks)
         with tracing.device_span("llm.prefill.sync"):
-            # The one intentional sync per prefill: first tokens must
+            # The one intentional sync per request: first tokens must
             # reach the streams now.  # analyze: ignore[JX002]
-            first = np.asarray(first)  # analyze: ignore[JX002]
+            first = [int(np.asarray(tok)[0]) for tok in first]  # analyze: ignore[JX002]
         self._init_s.setdefault("first_prefill", time.perf_counter() - t0)
         now = time.time()
         with tracing.device_span("llm.prefill.fanout"):
@@ -468,12 +509,13 @@ class LLMEngine:
                 c["prefill_batches"] += 1
                 c["prefill_rows_real"] += len(batch)
                 c["prefill_tokens_real"] += tokens_real
-                c["prefill_tokens_lane"] += rows * p_len
+                c["prefill_chunks"] += n_chunks
+                c["prefill_tokens_lane"] += n_chunks * chunk
                 for i, req in enumerate(batch):
                     slot = slots[i]
-                    tok = int(first[i])
+                    tok = first[i]
                     self._tokens[slot] = tok
-                    self._pos[slot] = int(lengths[i])
+                    self._pos[slot] = lengths[i]
                     self._slot_req[slot] = req
                     req.remaining = req.max_new - 1
                     c["admitted"] += 1
@@ -866,6 +908,7 @@ class LLMEngine:
             "cache_len": self.cache_len,
             "max_prompt_len": self.max_prompt_len,
             "prefill_rows": self.prefill_rows,
+            "prefill_chunk": self.prefill_chunk,
             "active": active,
             "queued": queued,
             "compiles": dict(self._compiles),

@@ -229,9 +229,10 @@ def test_no_phase_is_left_open_when_a_step_raises(tmp_path, how):
 
 def test_prefill_lane_counters_count_exactly():
     """Seeded prompts of 3, 8 and 11 tokens (the last truncated to the
-    8-token lane) and one whose first prefill is refused (failpoint
-    ``serve.llm.before_admit``) and retried: only prefills that ran
-    count, each as a whole lane."""
+    8 a slot's prompt rows hold) and one whose first prefill is refused
+    (failpoint ``serve.llm.before_admit``) and retried: only prefills that
+    ran count, each execution of the chunk program as its 8 tokens
+    whatever ``prefill_rows`` is (a scheduling bound, not a shape)."""
     import numpy as np
 
     rng = np.random.default_rng(24)
@@ -247,7 +248,8 @@ def test_prefill_lane_counters_count_exactly():
     assert st["prefill_batches"] == 4            # the refused one never ran
     assert st["prefill_rows_real"] == st["admitted"] == 4
     assert st["prefill_tokens_real"] == 3 + 8 + 8 + 5
-    assert st["prefill_tokens_lane"] == 4 * 2 * 8
+    assert st["prefill_chunks"] == 4 and st["prefill_chunk"] == 8
+    assert st["prefill_tokens_lane"] == 4 * 8
     assert st["prefill_rows"] == 2               # the setting, as before
     assert set(st["init_s"]) == {"params", "cache", "first_prefill",
                                  "first_step"}

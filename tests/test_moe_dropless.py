@@ -21,9 +21,9 @@ reference = load_module(os.path.join(REPO, "benchmark", "reference",
 T, D, F, E, K = 24, 16, 20, 12, 3
 relu2 = lambda x: jnp.square(jax.nn.relu(x))
 # the row count alone chooses the path: T rows take the batched product,
-# six copies of them (144, past ``DENSE_ROWS``) the grouped one
+# twelve copies of them (288, past ``DENSE_ROWS``) the grouped one
 PATHS = pytest.mark.parametrize(
-    "copies", [6, 1], ids=["grouped", "batched"])
+    "copies", [12, 1], ids=["grouped", "batched"])
 
 
 def copied(layer, copies):
@@ -135,9 +135,9 @@ def test_rows_that_are_padding_are_routed_nowhere(layer, copies):
 def test_the_shape_chooses_the_path_and_not_the_result(layer):
     """Up to DENSE_ROWS rows every held expert runs over every row; past
     them the pairs are sorted and grouped. The caller has no say, and the
-    result is one: 144 rows that are six copies of 24 give, grouped, six
-    copies of what the 24 give batched."""
-    assert moe.DENSE_ROWS == 128
+    result is one: 288 rows that are twelve copies of 24 give, grouped,
+    twelve copies of what the 24 give batched."""
+    assert moe.DENSE_ROWS == 256
 
     def share(h):
         ids, w = moe.route(h, layer["gate"], layer["bias"], K, 5.0)
@@ -145,13 +145,13 @@ def test_the_shape_chooses_the_path_and_not_the_result(layer):
                                     layer["w2"][:6], first=0,
                                     activation=relu2)
 
-    few, many = copied(layer, 1), copied(layer, 6)
+    few, many = copied(layer, 1), copied(layer, 12)
     (batched, few_counts), (grouped, many_counts) = share(few), share(many)
     np.testing.assert_allclose(np.asarray(grouped),
-                               np.tile(np.asarray(batched), (6, 1)),
+                               np.tile(np.asarray(batched), (12, 1)),
                                atol=2e-4)
     assert np.asarray(many_counts).tolist() \
-        == (6 * np.asarray(few_counts)).tolist()
+        == (12 * np.asarray(few_counts)).tolist()
     assert "ragged_dot" in str(jax.make_jaxpr(lambda h: share(h)[0])(many))
     assert "ragged_dot" not in str(jax.make_jaxpr(
         lambda h: share(h)[0])(few))

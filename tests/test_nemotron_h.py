@@ -173,7 +173,7 @@ def serve_rows(params, tokens, lengths, steps, cache=None, slots=None,
     for i, n in enumerate(np.asarray(lengths)):
         prompts[i, :n] = np.asarray(tokens)[i, :n]
     if cache is None:
-        cache = nh.nemotron_h_init_cache(cfg, n_slots, 64)
+        cache = nh.nemotron_h_init_cache(cfg, n_slots, max(64, lane))
     slots = jnp.arange(r) if slots is None else jnp.asarray(slots)
     logits, cache = nh.nemotron_h_prefill(
         params, cache, jnp.asarray(prompts), slots, lengths, cfg)
@@ -188,7 +188,7 @@ def serve_rows(params, tokens, lengths, steps, cache=None, slots=None,
     return jnp.stack(out, axis=1), cache
 
 
-@pytest.mark.parametrize("rows", [24, 144], ids=["batched", "grouped"])
+@pytest.mark.parametrize("rows", [24, 288], ids=["batched", "grouped"])
 def test_the_routed_experts_keep_bfloat16s_precision(rows):
     """What ``correct`` cannot see through the logits, held by numbers a
     layer at a time: with the program's own types the routed part of an
@@ -255,11 +255,11 @@ def test_a_thousand_steps_keep_the_state_a_prefill_computes(params):
         CFG, ssm_state_dtype=jnp.bfloat16))) > 2e-3
 
 
-@pytest.mark.parametrize("lane", [32, 48])
+@pytest.mark.parametrize("lane", [32, 96])
 def test_prefill_then_decode_through_the_cache(params, tokens, want, lane):
     """Rows of different ``length`` in one padded lane, then 10 decode
     steps: every logits row is the reference's at that position. The wider
-    lane holds 144 rows for the experts, past ``DENSE_ROWS``: the grouped
+    lane holds 288 rows for the experts, past ``DENSE_ROWS``: the grouped
     products, where the narrower one runs the batched ones."""
     lengths = [30, 19, 5]
     got, _ = serve_rows(params, tokens, lengths, 10, lane=lane)

@@ -1,0 +1,245 @@
+"""A prompt is prefilled in chunks (PR 31): for every family the engine
+serves, ``<family>_prefill_chunk`` run chunk after chunk leaves what one
+whole-window pass leaves (first-token logits, K/V rows, and for the hybrid
+family the convolution's tail and the SSM state), a first chunk begins
+anew whatever the slot held, and the engine built on it serves the
+full-context forward's tokens from its two compiled programs, counting its
+chunks.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import gpt2, llama, nemotron_h
+from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
+from ray_tpu.serve.llm_engine import LLMEngine
+
+F32 = jnp.float32
+# (float32 tiny config, init, init_cache, prefill_chunk, whole-window
+# prefill, full-context forward); the hybrid's scan blocks by 4 so that a
+# chunk of 4 and a window of 16 block alike.
+FAMILIES = {
+    "gpt2": (dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=F32),
+             gpt2.gpt2_init, gpt2.gpt2_init_cache, gpt2.gpt2_prefill_chunk,
+             gpt2.gpt2_prefill, gpt2.gpt2_forward),
+    "llama": (dataclasses.replace(llama.LlamaConfig.tiny(), dtype=F32),
+              llama.llama_init, llama.llama_init_cache,
+              llama.llama_prefill_chunk, llama.llama_prefill,
+              llama.llama_forward),
+    "nemotron_h": (nemotron_h.NemotronHConfig.tiny(
+        dtype=F32, param_dtype=F32, chunk_size=4),
+        nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_init_cache,
+        nemotron_h.nemotron_h_prefill_chunk, nemotron_h.nemotron_h_prefill,
+        nemotron_h.nemotron_h_forward),
+}
+every_family = pytest.mark.parametrize("family", list(FAMILIES))
+CHUNK, MAX_PROMPT, CACHE_LEN, SLOTS = 4, 16, 24, 4
+
+
+def _params(family):
+    cfg, init = FAMILIES[family][:2]
+    return init(jax.random.PRNGKey(31), cfg)
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(1, 200, n).astype(np.int32)
+
+
+def _used_cache(family, seed):
+    """A cache every part of which holds another request's leavings."""
+    cfg, _, init_cache = FAMILIES[family][:3]
+    rng = np.random.default_rng(seed)
+    return jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+        init_cache(cfg, SLOTS, CACHE_LEN))
+
+
+def _in_chunks(family, params, cache, prompt, slot):
+    """As the engine runs one request: ``ceil(len / CHUNK)`` calls of the
+    chunk function with R = 1. -> (the last call's logits [V], the cache)."""
+    cfg, chunk_fn = FAMILIES[family][0], FAMILIES[family][3]
+    run = jax.jit(lambda c, t, at, n: chunk_fn(
+        params, c, t, jnp.full(1, slot, jnp.int32), at, n, cfg,
+        window=key_window(MAX_PROMPT, CHUNK)))
+    for at in range(0, len(prompt), CHUNK):
+        piece = prompt[at:at + CHUNK]
+        toks = np.zeros((1, CHUNK), np.int32)
+        toks[0, :len(piece)] = piece
+        logits, cache = run(cache, jnp.asarray(toks),
+                            jnp.full(1, at, jnp.int32),
+                            jnp.full(1, len(piece), jnp.int32))
+    return logits[0], cache
+
+
+def _whole(family, params, cache, prompt, slot):
+    """One pass over the whole padded window: the chunk function at
+    C = MAX_PROMPT, which is the lane the engine compiled before."""
+    cfg, chunk_fn = FAMILIES[family][0], FAMILIES[family][3]
+    toks = np.zeros((1, MAX_PROMPT), np.int32)
+    toks[0, :len(prompt)] = prompt
+    logits, cache = chunk_fn(
+        params, cache, jnp.asarray(toks), jnp.full(1, slot, jnp.int32),
+        jnp.zeros(1, jnp.int32), jnp.full(1, len(prompt), jnp.int32), cfg,
+        window=MAX_PROMPT)
+    return logits[0], cache
+
+
+def _assert_same_state(family, got, want, slot, n):
+    """The slot's real K/V rows and its whole Mamba state agree; every
+    other slot is bit for bit what it was in both."""
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        name = jax.tree_util.keystr(path)
+        if a.ndim == 5:          # k / v [layer, slot, row, head, hd]
+            np.testing.assert_allclose(a[:, slot, :n], b[:, slot, :n],
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+            others = [s for s in range(SLOTS) if s != slot]
+            np.testing.assert_array_equal(a[:, others], b[:, others])
+        elif "conv" in name:     # [layer, K-1, slot, channel]
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                       err_msg=name)
+        else:                    # ssm, one array a layer: [slot, H, P, N]
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                       err_msg=name)
+
+
+@every_family
+@pytest.mark.parametrize("n", [CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK,
+                               MAX_PROMPT])
+def test_chunks_leave_what_the_whole_window_leaves(family, n):
+    params, prompt = _params(family), _prompt(n, seed=n)
+    cache = _used_cache(family, seed=5)
+    got, got_cache = _in_chunks(family, params, cache, prompt, slot=2)
+    want, want_cache = _whole(family, params, cache, prompt, slot=2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    _assert_same_state(family, got_cache, want_cache, 2, n)
+    # and the whole-window logits are the full-context forward's
+    cfg, forward = FAMILIES[family][0], FAMILIES[family][5]
+    full = forward(params, jnp.asarray(prompt)[None], cfg)[0, -1]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(full),
+                               rtol=2e-4, atol=2e-4)
+
+
+@every_family
+def test_a_first_chunk_begins_anew_whatever_the_slot_held(family):
+    """``start == 0``: the K/V rows a used slot holds are not seen, its
+    convolution tail and SSM state are not continued."""
+    cfg, _, init_cache = FAMILIES[family][:3]
+    params, prompt = _params(family), _prompt(2 * CHUNK + 1, seed=3)
+    used, _ = _in_chunks(family, params, _used_cache(family, seed=8),
+                         prompt, slot=1)
+    fresh, _ = _in_chunks(family, params,
+                          init_cache(cfg, SLOTS, CACHE_LEN), prompt, slot=1)
+    np.testing.assert_array_equal(np.asarray(used), np.asarray(fresh))
+
+
+@every_family
+def test_the_whole_window_form_is_a_loop_over_the_chunk_function(family):
+    """``<family>_prefill`` on [R, P] (what the benchmark's reference check
+    calls) cut into chunks gives what it gives in one chunk, rows of
+    different lengths and a scratch row together, with ONE traced copy of
+    the layers."""
+    cfg, _, _, chunk_fn, whole, _ = FAMILIES[family]
+    params = _params(family)
+    lens = [MAX_PROMPT - 3, CHUNK, 1]
+    toks = np.zeros((3, MAX_PROMPT), np.int32)
+    for i, n in enumerate(lens):
+        toks[i, :n] = _prompt(n, seed=10 + i)
+    args = (jnp.asarray(toks), jnp.asarray([3, 0, 2], jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+    cache = _used_cache(family, seed=2)
+    want, want_cache = whole(params, cache, *args, cfg)
+    got, got_cache = whole_prompts(chunk_fn, params, cache, *args, cfg,
+                                   chunk=CHUNK)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.ndim == 5:  # K/V: a prompt's own rows (past them, pad garbage)
+            a, b = (np.concatenate([x[:, slot, :n] for slot, n in
+                                    zip((3, 0, 2), lens)], 1) for x in (a, b))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    jaxpr = jax.make_jaxpr(lambda c: whole_prompts(
+        chunk_fn, params, c, *args, cfg, chunk=CHUNK))(cache)
+    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in
+             ("while", "scan")]
+    assert len(loops) == 1  # the chunks; the layers are inside it
+
+
+def _engine(family, **kw):
+    kw.setdefault("max_batch", 2)
+    return LLMEngine(model=family, config=FAMILIES[family][0], seed=31,
+                     cache_len=CACHE_LEN, max_prompt_len=MAX_PROMPT,
+                     prefill_chunk=CHUNK, **kw)
+
+
+def _naive(family, params, prompt, n):
+    cfg, forward = FAMILIES[family][0], FAMILIES[family][5]
+    fwd = jax.jit(lambda t: forward(params, t, cfg))
+    toks = [int(t) for t in prompt]
+    for _ in range(n):
+        padded = np.zeros((1, CACHE_LEN), np.int32)
+        padded[0, :len(toks)] = toks
+        toks.append(int(jnp.argmax(fwd(jnp.asarray(padded))[0,
+                                                            len(toks) - 1])))
+    return toks[len(prompt):]
+
+
+@every_family
+@pytest.mark.parametrize("n", [CHUNK + 2, 2 * CHUNK + 3])
+def test_the_engine_serves_the_full_forward_across_chunk_boundaries(
+        family, n):
+    """Prompts that cross one and two chunk boundaries: greedy tokens of
+    the deployed loop equal the full-context forward's, first token and
+    decode steps after it (which read the rows every chunk wrote)."""
+    eng = _engine(family)
+    try:
+        prompt = _prompt(n, seed=20 + n).tolist()
+        assert eng.generate(prompt, 5) == _naive(family, eng.params,
+                                                 prompt, 5)
+        assert eng.llm_stats()["prefill_chunks"] == -(-n // CHUNK)
+    finally:
+        eng.shutdown_engine()
+
+
+@every_family
+def test_one_two_and_three_chunks_run_one_program_and_add_up(family):
+    """``prefill_rows`` 2 bounds a turn's admissions and shapes nothing:
+    two compiled programs whatever the chunk count, a chunk counted per
+    execution and CHUNK lane tokens each."""
+    eng = _engine(family, prefill_rows=2)
+    try:
+        lens = [CHUNK - 1, 2 * CHUNK, 2 * CHUNK + 1, MAX_PROMPT + 5]
+        for i, n in enumerate(lens):
+            assert len(eng.generate(_prompt(n, seed=i).tolist(), 2)) == 2
+        st = eng.llm_stats()
+    finally:
+        eng.shutdown_engine()
+    assert st["compiles"] == {"decode": 1, "prefill": 1}
+    assert st["prefill_chunk"] == CHUNK and st["prefill_rows"] == 2
+    assert st["prefill_rows_real"] == st["prefill_batches"] == 4
+    assert st["prefill_chunks"] == 1 + 2 + 3 + MAX_PROMPT // CHUNK
+    assert st["prefill_tokens_lane"] == st["prefill_chunks"] * CHUNK
+    assert st["prefill_tokens_real"] == sum(lens[:3]) + MAX_PROMPT
+
+
+def test_the_chunk_is_the_engines_by_rule_and_must_fit_the_cache():
+    """256 tokens, or the longest prompt if shorter; a slot's rows must
+    hold whole chunks up to the longest prompt."""
+    assert chunk_len(768) == chunk_len(1024) == 256 and chunk_len(16) == 16
+    assert key_window(768, 256) == 768 and key_window(700, 256) == 768
+    with pytest.raises(ValueError, match="must fit the cache"):
+        LLMEngine(model="gpt2", config=FAMILIES["gpt2"][0], cache_len=18,
+                  max_prompt_len=MAX_PROMPT + 1, prefill_chunk=CHUNK)
+    eng = LLMEngine(model="gpt2", config=FAMILIES["gpt2"][0], cache_len=32,
+                    max_prompt_len=16)
+    try:
+        assert eng.llm_stats()["prefill_chunk"] == 16
+    finally:
+        eng.shutdown_engine()

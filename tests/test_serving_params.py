@@ -39,7 +39,8 @@ PROMPT = [5, 9, 2, 17, 3]
 
 
 def _family(name):
-    """(cfg, init, init_cache, prefill, decode), as the engine gets them."""
+    """(cfg, init, init_cache, prefill_chunk, decode), as the engine gets
+    them."""
     return _model_bundle(name, CONFIGS[name], "tiny")
 
 
@@ -55,14 +56,12 @@ def _names(tree):
 
 
 def _lane(prompt):
-    """The engine's prefill arguments for one request in slot 0."""
-    toks = np.zeros((ROWS, PROMPT_LEN), np.int32)
+    """The engine's prefill arguments for one request in slot 0: its one
+    chunk (tokens, slot, start, real tokens)."""
+    toks = np.zeros((1, PROMPT_LEN), np.int32)
     toks[0, :len(prompt)] = prompt
-    slots = np.full(ROWS, MAX_BATCH, np.int32)
-    slots[0] = 0
-    lengths = np.ones(ROWS, np.int32)
-    lengths[0] = len(prompt)
-    return jnp.asarray(toks), jnp.asarray(slots), jnp.asarray(lengths)
+    return (jnp.asarray(toks), jnp.zeros(1, jnp.int32),
+            jnp.zeros(1, jnp.int32), jnp.full(1, len(prompt), jnp.int32))
 
 
 def _run(name, params, steps):
@@ -146,7 +145,8 @@ def _programs(name, params):
     for closed in (
             jax.make_jaxpr(lambda p, c, t, at: decode(p, c, t, at, cfg)[:2])(
                 params, cache, i32, i32),
-            jax.make_jaxpr(lambda p, c, t, s, m: prefill(p, c, t, s, m, cfg))(
+            jax.make_jaxpr(
+                lambda p, c, t, s, at, m: prefill(p, c, t, s, at, m, cfg))(
                 params, cache, *_lane(PROMPT))):
         yield closed.jaxpr, set(closed.jaxpr.invars[:n])
 

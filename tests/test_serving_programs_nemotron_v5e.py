@@ -3,8 +3,9 @@ programs (PR 28).
 
 Compile-only, for one described v5e chip, at the published widths of
 ``benchmark/configs/nemotron3-super-120b-a12b.json`` and the cell's shapes
-(64 slots and the scratch one, a 2048-row cache, a [4, 1024] prefill lane):
-nothing runs, so nothing here is a time. It holds that both programs fit
+(64 slots and the scratch one, a 2048-row cache, prompts of up to 1024
+tokens in the engine's [1, 256] prefill chunks, PR 31; a [4, 1024] lane
+before): nothing runs, so nothing here is a time. It holds that both programs fit
 the chip beside their arguments, that the donated cache (K/V rows, the
 convolution tails, the float32 SSM state) is updated in its own buffers,
 and that no program copies a layer's expert stack or the whole state: XLA's
@@ -23,9 +24,11 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import nemotron_h as nh
+from ray_tpu.models.prefill import chunk_len, key_window
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SLOTS, CACHE_LEN, ROWS, PROMPT_LEN = 65, 2048, 4, 1024
+SLOTS, CACHE_LEN, PROMPT_LEN = 65, 2048, 1024
+CHUNK = chunk_len(PROMPT_LEN)  # as the engine derives it: 256
 HBM = 15.75 * 2 ** 30
 
 
@@ -72,10 +75,9 @@ def compiled(one_chip, cfg):
     programs = {
         "decode": (lambda p, c, t, n: nh.nemotron_h_decode_step(
             p, c, t, n, cfg), (params, cache, i32(SLOTS), i32(SLOTS))),
-        "prefill": (lambda p, c, t, s, n: nh.nemotron_h_prefill(
-            p, c, t, s, n, cfg),
-                    (params, cache, i32(ROWS, PROMPT_LEN), i32(ROWS),
-                     i32(ROWS))),
+        "prefill": (lambda p, c, t, s, at, n: nh.nemotron_h_prefill_chunk(
+            p, c, t, s, at, n, cfg, window=key_window(PROMPT_LEN, CHUNK)),
+                    (params, cache, i32(1, CHUNK), i32(1), i32(1), i32(1))),
     }
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -108,9 +110,9 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.alias_size_in_bytes >= cache_bytes
     assert 10.7e9 < mem.argument_size_in_bytes < 10.9e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
-    # the step holds next to nothing of its own; the lane's sorted rows
-    # and chunked scan stay under 2.5 GB
-    assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 2.5e9}[which]
+    # the step holds next to nothing of its own; a chunk's sorted rows
+    # and scan 0.10 GB, where the [4, 1024] lane's took 1.20 GB
+    assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 0.3e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
@@ -155,12 +157,59 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
     assert moved == []
 
 
-def test_the_decode_step_runs_batched_products_and_the_lane_grouped_ones(
-        compiled):
-    """65 rows: every held expert over every row, one batched product a
-    matrix and no sort. 4096 rows: the pairs sorted by expert and the TPU's
-    grouped product (a ``ragged-dot`` custom call), so rows of absent
-    experts are not computed."""
-    decode, prefill = (compiled[k].as_text() for k in ("decode", "prefill"))
-    assert "ragged" not in decode
-    assert prefill.count("ragged-dot") >= 10  # 2 products x 5 layers
+def test_both_programs_run_batched_products_and_a_wide_lane_grouped_ones(
+        compiled, one_chip, cfg):
+    """65 rows a step, 256 a chunk (``ops/moe.DENSE_ROWS``): every held
+    expert over every row, one batched product a matrix and no sort, in
+    both of the engine's programs. The whole-window form on two prompts of
+    1024 (what the benchmark's reference check calls: 512 rows a chunk)
+    sorts the pairs by expert and runs the TPU's grouped product (a
+    ``ragged-dot`` custom call), and is ONE traced copy of the layers
+    looped over its four chunks: ten grouped products, not forty."""
+    for which in ("decode", "prefill"):
+        assert "ragged" not in compiled[which].as_text()
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    params = sds(jax.eval_shape(
+        lambda: nh.nemotron_h_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(
+        lambda: nh.nemotron_h_init_cache(cfg, 3, CACHE_LEN)))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        whole = jax.jit(lambda p, c, t, s, n: nh.nemotron_h_prefill(
+            p, c, t, s, n, cfg), donate_argnums=(1,)).lower(
+                params, cache, i32(2, PROMPT_LEN), i32(2), i32(2)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    grouped = len(re.findall(r" custom-call\(.*ragged-dot", whole.as_text()))
+    # (a product may take more than one call; two copies would take twice)
+    assert 2 * cfg.count("E") <= grouped < 4 * cfg.count("E")
+    assert whole.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
+    """K/V rows and a layer's SSM state (1.50 of the cache's 1.52 GB): each
+    shape has one layout in the chunk program, and it is the decode
+    program's. The chunk reads the slot's rows and state out of the buffers
+    it then writes, and neither is re-laid out around that. The 20 MB of
+    convolution tails are the exception the compiler makes for a chunk of
+    one row: it takes them whole into fast memory (``S(1)``) in a tiling of
+    their own, writes the slot's five tails there and copies them back,
+    once an execution and not a layer."""
+    def layouts(shape, which):
+        # (a trailing S(n) names a memory space, not a layout)
+        return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+            shape + r"(\{[^}]*\})", compiled[which].as_text())}
+
+    for shape in (f"bf16\\[1,{SLOTS},{CACHE_LEN},2,128\\]",
+                  f"f32\\[{SLOTS},128,64,128\\]"):
+        assert len(layouts(shape, "prefill")) == 1, shape
+        assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
+    tails = compiled["prefill"].as_text()
+    assert len(re.findall(r" copy\(%c__conv__", tails)) <= 1

@@ -2,8 +2,9 @@
 
 Compile-only, for one described v5e chip, at GPT-2 XL's published widths
 and the benchmark deployment's shapes (8 slots and the scratch one, a
-1024-row cache, a [4, 768] prefill lane): nothing runs, so nothing here is
-a time. It holds what ``test_the_cache_is_only_written_by_rows`` cannot
+1024-row cache, prompts of up to 768 tokens in the engine's [1, 256]
+prefill chunks, PR 31; a [4, 768] lane before): nothing runs, so nothing
+here is a time. It holds what ``test_the_cache_is_only_written_by_rows`` cannot
 see from the jaxpr: that XLA keeps the stacked cache's layout through the
 row writes (a scatter, or the same updates under a ``fori_loop``, made it
 re-lay out the whole cache around them), so the layer loop moves no
@@ -21,10 +22,12 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import gpt2
+from ray_tpu.models.prefill import chunk_len, key_window
 
 XL = gpt2.GPT2Config(vocab_size=50304, n_layer=48, n_head=25, d_model=1600,
                      seq_len=1024)
-SLOTS, CACHE_LEN, ROWS, PROMPT_LEN = 9, 1024, 4, 768
+SLOTS, CACHE_LEN, PROMPT_LEN = 9, 1024, 768
+CHUNK = chunk_len(PROMPT_LEN)  # as the engine derives it: 256
 LAYER_BLOCK = SLOTS * CACHE_LEN * XL.n_head * XL.head_dim
 
 
@@ -66,10 +69,9 @@ def compiled(one_chip):
     programs = {
         "decode": (lambda p, c, t, n: gpt2.gpt2_decode_step(p, c, t, n, XL),
                    (params, cache, i32(SLOTS), i32(SLOTS))),
-        "prefill": (lambda p, c, t, s, n: gpt2.gpt2_prefill(
-            p, c, t, s, n, XL),
-                    (params, cache, i32(ROWS, PROMPT_LEN), i32(ROWS),
-                     i32(ROWS))),
+        "prefill": (lambda p, c, t, s, at, n: gpt2.gpt2_prefill_chunk(
+            p, c, t, s, at, n, XL, window=key_window(PROMPT_LEN, CHUNK)),
+                    (params, cache, i32(1, CHUNK), i32(1), i32(1), i32(1))),
     }
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -134,9 +136,25 @@ def test_temp_space_holds_no_second_cache_and_no_copy_of_the_weights(
     weights come in as the engine stores them, 3.14 GB, and no bfloat16
     copy of them is made (3.15 and 3.37 GB of temp space until PR 29): what
     is left is the embedding laid out for the head (0.16 GB) and the
-    lane's activations, 0.17 and 0.24 GB."""
+    activations, 0.17 GB in both: the chunk's are under the [4, 768]
+    lane's 0.24 GB."""
     mem = compiled[which].memory_analysis()
     cache_bytes = 2 * XL.n_layer * LAYER_BLOCK * 2
     assert mem.alias_size_in_bytes >= cache_bytes
-    assert mem.temp_size_in_bytes < 0.4e9
+    assert mem.temp_size_in_bytes < {"decode": 0.4e9, "prefill": 0.24e9}[which]
     assert mem.argument_size_in_bytes < cache_bytes + 2.02 * XL.n_params
+
+
+def test_the_chunk_keeps_the_cache_in_the_layout_the_step_reads(compiled):
+    """The chunk program writes its rows into, and cuts its key window out
+    of, the stacked cache its layer loop carries. Everywhere either program
+    names an array of the cache's shape it has ONE layout, the same in both
+    (on this runtime ``cache_len`` minor-most): no program re-lays the
+    cache out around a write, and what a chunk leaves is what the step
+    reads."""
+    # (a trailing S(n) names a memory space, not a layout)
+    layouts = {which: {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+        rf"bf16\[{XL.n_layer},{BLOCK_DIMS}\](\{{[^}}]*\}})", prog.as_text())}
+        for which, prog in compiled.items()}
+    assert len(layouts["prefill"]) == 1, layouts
+    assert layouts["prefill"] == layouts["decode"]
