@@ -1,10 +1,9 @@
 """Published peaks of one chip, keyed by ``device_kind`` as JAX reports it.
 
 Source: Google Cloud documentation, "TPU v5e" system architecture page:
-197 TFLOP/s in bf16, 16 GB of HBM at 819 GB/s per chip. (Copied, with its
-source, from ``ray_tpu/scripts/measure.py`` ``PEAK_TFLOPS`` and
-``ray_tpu/util/xla_cost.py`` ``PEAK_HBM_GBPS``; the originals are listed
-for deletion in PERF.md.) A kind that is not here is an error, never a
+197 TFLOP/s in bf16, 16 GB of HBM at 819 GB/s per chip. Since PR 30 this is
+the repository's one table of peaks (the two it was copied from went with
+the scripts that held them). A kind that is not here is an error, never a
 default.
 """
 

@@ -24,6 +24,45 @@ def median(values) -> float | None:
     return float(statistics.median(xs)) if xs else None
 
 
+def spread(values) -> float | None:
+    """The distance between the first and the third quartile, as
+    ``statistics.quantiles(values, n=4)`` gives them, as a share of the
+    median: what a bound is set from and judged by."""
+    xs = list(values)
+    if len(xs) < 2:
+        return None
+    q = statistics.quantiles(xs, n=4)
+    med = statistics.median(xs)
+    return (q[2] - q[0]) / med if med else None
+
+
+def driver_spreads(sets) -> tuple:
+    """The two spreads the driver judges a bound by, from sets of runs of
+    one cell. Tightness: the mean of the sets' spreads, each set without
+    its run farthest from its median (a bound is too tight where this is
+    over half of it). Looseness: the widest spread of the sets as they are
+    (a bound over eight times this, and over 1 %, is too loose)."""
+    trimmed = []
+    for runs in sets:
+        med = statistics.median(runs)
+        far = max(range(len(runs)), key=lambda i: abs(runs[i] - med))
+        trimmed.append(spread(runs[:far] + runs[far + 1:]))
+    return sum(trimmed) / len(trimmed), max(spread(runs) for runs in sets)
+
+
+def bound_from_spreads(spreads, factor: float = 2.5, floor: float = 0.01,
+                       step: float = 0.005) -> float:
+    """The bound a metric gets from the spreads of its sets of runs (every
+    cell that reports it, the widest deciding): ``factor`` times the widest,
+    at least ``floor``, rounded up to a whole number of ``step``s. With
+    2.5 (ISSUE 33's rule) every whole set's spread is under 0.4 of the
+    bound; a set without its farthest run spreads about half as much, so
+    the driver's tightness reading comes to about a fifth of the bound and
+    its looseness reading to 2.5 times under it: room on both sides."""
+    raw = max(floor, factor * max(spreads))
+    return round(math.ceil(raw / step - 1e-9) * step, 6)
+
+
 def window_rate(slice_seconds, tokens_per_slice: float, chips: int
                 ) -> float | None:
     """All the tokens of the window's whole slices over all the time those

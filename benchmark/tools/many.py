@@ -29,14 +29,9 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
 
-
-def spread(values: list) -> float | None:
-    if len(values) < 2:
-        return None
-    q = statistics.quantiles(values, n=4)
-    med = statistics.median(values)
-    return (q[2] - q[0]) / med if med else None
+from benchmark.stats import spread  # noqa: E402 - no JAX behind it
 
 
 def root_with(workload: str, sets: list) -> str:

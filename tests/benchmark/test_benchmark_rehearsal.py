@@ -77,6 +77,19 @@ def test_rehearsal_of_each_kind(root, capsys, cell, trace, names):
     if "train" in cell:
         # every slice's time is on an earlier line
         assert sum('"event": "slice"' in line for line in earlier) >= 2
+    if "closed" in cell:
+        # the replay's two lines, and the hand-out's order among the checks
+        # (a rehearsal says what admitted_in_order read and is not held to
+        # it: serve_closed.check_replay says why)
+        said = {json.loads(line[len("[bench] "):])["event"]:
+                json.loads(line[len("[bench] "):]) for line in earlier
+                if line.startswith("[bench] ")}
+        assert {"admission_pattern", "itl_quantiles", "hand_out",
+                "admitted_in_order_not_held_in_rehearsal"} <= set(said)
+        assert said["itl_quantiles"]["gaps"] == sum(
+            said["itl_quantiles"]["prefills_inside"].values()) > 0
+        assert said["hand_out"]["taken"] >= last["attempted"]
+        assert "sent_in_order" in [c[0] for c in said["checks"]["checks"]]
     if cell == "toy_llama_closed":
         # The engine's cache by the family's own shape: 4 + 1 slots of 64
         # rows, K and V of 2 layers x 2 key/value heads (of 4 query heads)

@@ -25,8 +25,13 @@ from benchmark.loading import load_module
 REPO = benchmark_toy.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-WIDTHS = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|"
-                    r"head_dim|n_embd|n_inner|expansion|experts_per)")
+# What ``reduced`` may never name: a width. ``hidden`` counts only where
+# it is not the published DEPTH key's (``num_hidden_layers``: until PR 33
+# the word anywhere in a key refused it, so a family had to list its depth
+# cut under another key).
+WIDTHS = re.compile(r"(hidden(?!_layers)|intermediate|latent|state|proj|"
+                    r"_dim$|_rank$|head_dim|n_embd|n_inner|expansion|"
+                    r"experts_per)")
 
 
 # The cells and end-to-end metrics the benchmark has accepted. A later PR
@@ -41,11 +46,17 @@ ACCEPTED_CELLS = [
 ACCEPTED_END_TO_END = [  # ... and the accepted cells each reports in
     ("train_tokens_per_s_chip", "tokens/s/chip", "higher", 0.01,
      ["train_gpt2s_1chip", "train_gpt2xl_4chip"]),
-    # 0.02 until PR 26: since PR 25 the rate moves 2-3 % with how many
-    # ended requests share a prefill batch (PERF.md section 6, PR 26)
-    ("serve_out_tokens_per_s", "tokens/s", "higher", 0.05,
+    # 0.02 until PR 26, 0.05 until PR 33: the closed loop hands its pool
+    # out in one order now, so which ended requests share a prefill turn
+    # repeats; two sets of 6 a closed cell spread 1.29 % and 0.76 % (GPT-2
+    # XL) and 0.85 % and 1.44 % (Nemotron, which decides): 2.5 x 1.44 %
+    # rounded up to half a percent (PERF.md section 2; my chip runs, PR 33)
+    ("serve_out_tokens_per_s", "tokens/s", "higher", 0.04,
      ["serve_gpt2xl_decode_sat"]),
-    ("itl_p99_ms", "ms", "lower", 0.01, ["serve_gpt2xl_decode_sat"]),
+    # 0.01 until PR 33, set from ten pairs on one machine; the driver's
+    # notes on PR 29 and PR 31 and PR 32's `unresolved` said it could not
+    # hold. The same two sets spread 1.42 % and 0.71 %: 2.5 x 1.42 %
+    ("itl_p99_ms", "ms", "lower", 0.04, ["serve_gpt2xl_decode_sat"]),
     ("ttft_p90_ms", "ms", "lower", 0.08, ["serve_gpt2xl_prompt_rate"]),
     ("setup_s", "s", "lower", 0.1, []),  # every cell: no list
 ]
@@ -80,6 +91,23 @@ def kind_of(root, workload):
 
 def line(text):
     return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+@pytest.mark.parametrize("key, is_width", [
+    # depth and counts: a cut of these is a cut of scale and may be listed
+    ("num_hidden_layers", False), ("n_layer", False), ("num_layers", False),
+    ("hybrid_override_pattern", False), ("n_routed_experts", False),
+    ("vocab_size", False), ("max_position_embeddings", False),
+    # widths: refused, as before
+    ("hidden_size", True), ("mamba_hidden_act_dim", True),
+    ("intermediate_size", True), ("moe_intermediate_size", True),
+    ("moe_latent_size", True), ("ssm_state_size", True),
+    ("kv_lora_rank", True), ("head_dim", True), ("n_embd", True),
+    ("n_inner", True), ("num_experts_per_tok", True),
+    ("mamba_expansion", True), ("q_proj_width", True),
+])
+def test_reduced_may_name_a_depth_and_never_a_width(key, is_width):
+    assert bool(WIDTHS.search(key)) is is_width
 
 
 def test_top_level_keys_and_command(spec):
