@@ -1,7 +1,9 @@
 """Model zoo: pure-JAX pytree models designed for pjit sharding.
 
 Flagship: GPT-2 (the benchmark's training cells); Nemotron-H (a
-hybrid of Mamba-2, attention and latent-MoE layers) is served only. Models are plain
+hybrid of Mamba-2, attention and latent-MoE layers) and Granite 4.0-H (a
+Mamba-2 mixer or attention, then gated experts, in every layer) are served
+only. Models are plain
 functions over parameter pytrees — no framework Module state — so the same
 code runs under any mesh and any rules table.
 """
@@ -12,6 +14,11 @@ from ray_tpu.models.llama import (
     llama_forward,
     llama_init,
     llama_loss,
+)
+from ray_tpu.models.granite_hybrid import (
+    GraniteHybridConfig,
+    granite_hybrid_forward,
+    granite_hybrid_init,
 )
 from ray_tpu.models.nemotron_h import (
     NemotronHConfig,
@@ -27,12 +34,15 @@ from ray_tpu.models.moe import (
 
 __all__ = [
     "GPT2Config",
+    "GraniteHybridConfig",
     "LlamaConfig",
     "MoEConfig",
     "NemotronHConfig",
     "gpt2_forward",
     "gpt2_init",
     "gpt2_loss",
+    "granite_hybrid_forward",
+    "granite_hybrid_init",
     "llama_forward",
     "llama_init",
     "llama_loss",

@@ -2,7 +2,8 @@
 mixture-of-experts layers, served through ``LLMEngine``.
 
 The layer list is data: ``pattern`` holds one character a layer, ``M`` a
-Mamba-2 mixer, ``*`` grouped-query attention (no position embedding: the
+Mamba-2 mixer (``ops/mamba2.py``, the one ``models/granite_hybrid.py`` calls
+too), ``*`` grouped-query attention (no position embedding: the
 Mamba layers carry order), ``E`` a latent MoE (sigmoid router over every
 expert of the model, top-k, experts in a ``latent``-wide space between one
 down- and one up-projection, a shared expert beside them at full width).
@@ -47,10 +48,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.prefill import whole_prompts
+from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
                                    cached_chunk_attention,
                                    cached_decode_attention, causal_attention)
-from ray_tpu.ops.moe import dropless_experts, route
+from ray_tpu.ops.moe import dropless_experts, held_counters, route
 
 Params = dict[str, Any]
 
@@ -103,11 +105,20 @@ class NemotronHConfig:
 
     @property
     def d_inner(self) -> int:
-        return self.mamba_heads * self.mamba_head_dim
+        return self.mamba.d_inner
 
     @property
     def conv_dim(self) -> int:
-        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+        return self.mamba.conv_dim
+
+    @property
+    def mamba(self) -> mamba2.Mamba2Dims:
+        """The ``M`` layers' sizes, as ``ops/mamba2.py`` takes them."""
+        return mamba2.Mamba2Dims(
+            heads=self.mamba_heads, head_dim=self.mamba_head_dim,
+            groups=self.ssm_groups, state=self.ssm_state,
+            kernel=self.conv_kernel, block=self.chunk_size, eps=self.eps,
+            dtype=self.dtype, state_dtype=self.ssm_state_dtype)
 
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
@@ -152,23 +163,7 @@ def _layer_init(key, kind: str, cfg: NemotronHConfig) -> Params:
     keys = iter(jax.random.split(key, 12))
     p = {"norm": jnp.ones((d,), pd)}
     if kind == "M":
-        h, di = cfg.mamba_heads, cfg.d_inner
-        dt = jnp.exp(jax.random.uniform(
-            next(keys), (h,), jnp.float32, math.log(1e-3), math.log(1e-1)))
-        dt = jnp.maximum(dt, 1e-4)
-        p.update(
-            in_proj=_normal(next(keys), (d, 2 * di + 2 * cfg.ssm_groups
-                                         * cfg.ssm_state + h), 0.02, pd),
-            conv_w=jax.random.uniform(
-                next(keys), (cfg.conv_kernel, cfg.conv_dim), jnp.float32,
-                -0.5, 0.5).astype(pd),
-            conv_b=_normal(next(keys), (cfg.conv_dim,), 0.02, pd),
-            dt_bias=(dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
-            a_log=jnp.log(jax.random.uniform(
-                next(keys), (h,), jnp.float32, 1.0, 16.0)).astype(pd),
-            d_skip=jnp.ones((h,), pd),
-            gate_norm=jnp.ones((di,), pd),
-            out_proj=_normal(next(keys), (di, d), out_std, pd))
+        p.update(mamba2.mixer_init(keys, d, cfg.mamba, pd, _normal, out_std))
     elif kind == "*":
         q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
         p.update(wq=_normal(next(keys), (d, q), 0.02, pd),
@@ -217,172 +212,6 @@ def _relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
 
 
-def _gated_group_norm(y, z, w, cfg: NemotronHConfig):
-    """``RMSNorm_groups(y * silu(z)) * w``: the norm over each of the
-    ``ssm_groups`` groups of channels. y, z [..., d_inner]."""
-    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    grouped = yf.reshape(*yf.shape[:-1], cfg.ssm_groups, -1)
-    grouped = grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.eps)
-    return (grouped.reshape(yf.shape) * w.astype(jnp.float32)).astype(
-        cfg.dtype)
-
-
-def _ssm_inputs(p: Params, proj: jax.Array, cfg: NemotronHConfig):
-    """``in_proj``'s output split: z [.., d_inner], xBC [.., conv_dim],
-    and ``dt`` before its softplus [.., H]."""
-    di = cfg.d_inner
-    return (proj[..., :di], proj[..., di:di + cfg.conv_dim],
-            proj[..., di + cfg.conv_dim:])
-
-
-def _ssm_split(conv: jax.Array, cfg: NemotronHConfig):
-    """The convolution's output split: x [.., H, P], B and C [.., G, N]."""
-    di, gn = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
-    lead = conv.shape[:-1]
-    return (conv[..., :di].reshape(*lead, cfg.mamba_heads,
-                                   cfg.mamba_head_dim),
-            conv[..., di:di + gn].reshape(*lead, cfg.ssm_groups,
-                                          cfg.ssm_state),
-            conv[..., di + gn:].reshape(*lead, cfg.ssm_groups,
-                                        cfg.ssm_state))
-
-
-def _dt_and_a(p: Params, dt_raw: jax.Array):
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))
-    return dt, -jnp.exp(p["a_log"].astype(jnp.float32))
-
-
-def _mamba_step(p: Params, y: jax.Array, tail: jax.Array, state: jax.Array,
-                cfg: NemotronHConfig):
-    """One token a slot. y [S, D] (normed), tail [K-1, S, C] the last
-    inputs of the convolution, state [S, H, P, N] float32. -> (the mixer's
-    output [S, D], the new tail, the new state)."""
-    dt_ = cfg.dtype
-    s = y.shape[0]
-    rep = cfg.mamba_heads // cfg.ssm_groups
-    with jax.named_scope("ssm_proj"):
-        z, xbc, dt_raw = _ssm_inputs(p, y @ p["in_proj"].astype(dt_), cfg)
-    with jax.named_scope("conv"):
-        window = jnp.concatenate(
-            [tail, xbc.astype(tail.dtype)[None]], axis=0)  # [K, S, C]
-        conv = jnp.einsum("ksc,kc->sc", window.astype(jnp.float32),
-                          p["conv_w"].astype(jnp.float32)) \
-            + p["conv_b"].astype(jnp.float32)
-        xs, b, c = _ssm_split(jax.nn.silu(conv).astype(dt_), cfg)
-    with jax.named_scope("ssm_update"):
-        dt, a = _dt_and_a(p, dt_raw)  # [S, H], [H]
-        xf = xs.astype(jnp.float32)
-        bh = jnp.repeat(b.astype(jnp.float32), rep, axis=1)  # [S, H, N]
-        ch = jnp.repeat(c.astype(jnp.float32), rep, axis=1)
-        state = state.astype(jnp.float32) \
-            * jnp.exp(dt * a)[:, :, None, None] \
-            + (dt[..., None] * xf)[..., None] * bh[:, :, None, :]
-        # multiply and reduce beside the update: one pass over the state
-        yh = jnp.sum(state * ch[:, :, None, :], axis=-1) \
-            + p["d_skip"].astype(jnp.float32)[None, :, None] * xf
-    with jax.named_scope("ssm_norm"):
-        yn = _gated_group_norm(yh.reshape(s, cfg.d_inner), z,
-                               p["gate_norm"], cfg)
-    with jax.named_scope("ssm_proj"):
-        out = yn @ p["out_proj"].astype(dt_)
-    return out, window[1:], state.astype(cfg.ssm_state_dtype)
-
-
-def _ssd_scan(xs, dt, a, b, c, cfg: NemotronHConfig, state=None):
-    """The recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
-    ``y_t = S_t C_t`` over rows from ``state`` [R, H, P, N] float32 (None:
-    empty), blocked in chunks of ``chunk_size`` (inside a chunk a masked
-    product, between chunks the state): xs [R, T, H, P], dt [R, T, H]
-    float32 (0 at padded positions: they leave the state as it is), a [H],
-    b / c [R, T, G, N].
-    -> (y [R, T, H, P] float32, the state after the row [R, H, P, N])."""
-    r, t, h, pdim = xs.shape
-    g, n = b.shape[2], b.shape[3]
-    rep, q = h // g, min(cfg.chunk_size, t)
-    pad = (-t) % q
-    if pad:
-        xs, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (
-            v.ndim - 2)) for v in (xs, dt, b, c))
-    nc = (t + pad) // q
-    mm = cfg.dtype
-    f32 = jnp.float32
-    # [R, nc, Q, ...], heads split into (group, heads of the group)
-    xdt = (xs.astype(f32) * dt[..., None]).reshape(r, nc, q, g, rep, pdim)
-    b = b.reshape(r, nc, q, g, n).astype(mm)
-    c = c.reshape(r, nc, q, g, n).astype(mm)
-    cum = jnp.cumsum((dt * a).reshape(r, nc, q, g, rep), axis=2)
-    # inside a chunk: y_i += sum_{j <= i} (C_i . B_j) exp(cum_i - cum_j) xdt_j
-    cb = jnp.einsum("rcign,rcjgn->rcgij", c, b, preferred_element_type=f32)
-    gap = cum[:, :, :, None] - cum[:, :, None]  # [R, nc, i, j, G, rep]
-    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None, None]
-    decay = jnp.where(causal, jnp.exp(jnp.where(causal, gap, 0.0)), 0.0)
-    mix = cb.transpose(0, 1, 3, 4, 2)[..., None] * decay  # [R,nc,i,j,G,rep]
-    y = jnp.einsum("rcijgh,rcjghp->rcighp", mix.astype(mm), xdt.astype(mm),
-                   preferred_element_type=f32)
-    # what each chunk adds to the state, decayed to the chunk's end
-    to_end = jnp.exp(cum[:, :, -1:] - cum)  # [R, nc, Q, G, rep]
-    add = jnp.einsum("rcjghp,rcjgn->rcghpn",
-                     (xdt * to_end[..., None]).astype(mm), b,
-                     preferred_element_type=f32)
-    through = jnp.exp(cum[:, :, -1])  # [R, nc, G, rep]: a whole chunk's decay
-
-    def chunk(state, inp):
-        add_c, through_c = inp
-        return state * through_c[..., None, None] + add_c, state
-
-    state = jnp.zeros((r, g, rep, pdim, n), f32) if state is None \
-        else state.astype(f32).reshape(r, g, rep, pdim, n)
-    state, before = jax.lax.scan(
-        chunk, state,
-        (add.transpose(1, 0, 2, 3, 4, 5), through.transpose(1, 0, 2, 3)))
-    before = before.transpose(1, 0, 2, 3, 4, 5)  # the state entering a chunk
-    y = y + jnp.einsum("rcign,rcghpn->rcighp", c, before.astype(mm),
-                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
-    y = y.reshape(r, t + pad, h, pdim)[:, :t]
-    return y, state.reshape(r, h, pdim, n)
-
-
-def _mamba_rows(p: Params, y: jax.Array, lengths: jax.Array,
-                cfg: NemotronHConfig, tail: jax.Array | None = None,
-                state: jax.Array | None = None):
-    """Rows of T tokens that continue from ``tail`` [R, K-1, C] (the
-    convolution's last inputs) and ``state`` [R, H, P, N]; None for both:
-    rows that begin. y [R, T, D] (normed), lengths [R]: the real tokens of
-    each row. -> (the mixer's output [R, T, D], the convolution's tail
-    after ``length`` tokens [K-1, R, C], the state after ``length`` tokens
-    [R, H, P, N]); with no real token, the tail and state given."""
-    dt_ = cfg.dtype
-    r, t, _ = y.shape
-    k = cfg.conv_kernel
-    with jax.named_scope("ssm_proj"):
-        z, xbc, dt_raw = _ssm_inputs(p, y @ p["in_proj"].astype(dt_), cfg)
-    with jax.named_scope("conv"):
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))) if tail is None \
-            else jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-        w = p["conv_w"].astype(jnp.float32)
-        conv = sum(padded[:, j:j + t].astype(jnp.float32) * w[j]
-                   for j in range(k)) + p["conv_b"].astype(jnp.float32)
-        xs, b, c = _ssm_split(jax.nn.silu(conv).astype(dt_), cfg)
-        # the inputs at length - (K-1) .. length - 1, the old tail before 0
-        at = lengths[None, :] + jnp.arange(k - 1)[:, None]  # into `padded`
-        tail = padded[jnp.arange(r)[None, :], at]  # [K-1, R, C]
-    with jax.named_scope("ssm_scan"):
-        dt, a = _dt_and_a(p, dt_raw)
-        dt = jnp.where(jnp.arange(t)[None, :, None] < lengths[:, None, None],
-                       dt, 0.0)
-        yh, state = _ssd_scan(xs, dt, a, b, c, cfg, state)
-        yh = yh + p["d_skip"].astype(jnp.float32)[None, None, :, None] \
-            * xs.astype(jnp.float32)
-    with jax.named_scope("ssm_norm"):
-        yn = _gated_group_norm(yh.reshape(r, t, cfg.d_inner), z,
-                               p["gate_norm"], cfg)
-    with jax.named_scope("ssm_proj"):
-        out = yn @ p["out_proj"].astype(dt_)
-    return out, tail, state.astype(cfg.ssm_state_dtype)
-
-
 def _moe(p: Params, y: jax.Array, cfg: NemotronHConfig,
          live: jax.Array | None = None):
     """The latent MoE over rows y [T, D] (normed): the held experts' part
@@ -406,40 +235,18 @@ def _moe(p: Params, y: jax.Array, cfg: NemotronHConfig,
     return out, counts
 
 
-def _counters(counts: list) -> dict:
-    """Over a step's ``E`` layers: the held experts that took at least one
-    row, and the token-expert pairs that landed here."""
-    if not counts:
-        return {"experts_hit": jnp.int32(0), "expert_rows": jnp.int32(0)}
-    stacked = jnp.stack(counts)
-    return {"experts_hit": jnp.sum(stacked > 0, dtype=jnp.int32),
-            "expert_rows": jnp.sum(stacked, dtype=jnp.int32)}
-
-
 # -- the cache and the serving functions --------------------------------------
 
 
 def nemotron_h_init_cache(cfg: NemotronHConfig, slots: int,
                           cache_len: int) -> Params:  # decode-path
-    """K/V rows for the ``*`` layers (a ring, as the other families'), and
-    for the ``M`` layers the convolution's tail and the SSM state (no
-    ring). K/V and the tails are stacked over their layers; the tail lies
-    [layer, K-1, slot, channel]: slots and channels are the minor
-    dimensions, which tile. The SSM state is one array a layer (a tuple):
-    a decode step rewrites a layer's whole state, and only with the layer's
-    state as a buffer of its own does XLA fuse the update and the readout
-    ``S_t C_t`` into one pass over it; as a slice of a stacked array it is
-    read twice and written once (compile-only for a v5e, PR 28)."""
+    """K/V rows for the ``*`` layers (a ring, as the other families'),
+    stacked over their layers, and for the ``M`` layers the convolution's
+    tail and the SSM state, which have no ring
+    (``ops/mamba2.init_state`` says how they lie and why)."""
     kv = (cfg.count("*"), slots, cache_len, cfg.n_kv_head, cfg.head_dim)
-    n_m = cfg.count("M")
-    return {
-        "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-        "conv": jnp.zeros((n_m, cfg.conv_kernel - 1, slots, cfg.conv_dim),
-                          cfg.dtype),
-        "ssm": tuple(jnp.zeros((slots, cfg.mamba_heads, cfg.mamba_head_dim,
-                                cfg.ssm_state), cfg.ssm_state_dtype)
-                     for _ in range(n_m)),
-    }
+    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+            **mamba2.init_state(cfg.mamba, cfg.count("M"), slots)}
 
 
 # jax-hot-path: traced into the engine's single compiled decode step
@@ -467,13 +274,8 @@ def nemotron_h_decode_step(params: Params, cache: Params, tokens: jax.Array,
         with jax.named_scope("ln"):
             y = _rms_norm(x, p["norm"], cfg.eps)
         if kind == "M":
-            out, tail, state = _mamba_step(p, y, conv_all[i_m],
-                                           ssm_all[i_m], cfg)
-            with jax.named_scope("state_write"):
-                conv_all = jax.lax.dynamic_update_slice(
-                    conv_all, tail[None].astype(conv_all.dtype),
-                    (i_m, 0, 0, 0))
-                ssm_all[i_m] = state
+            out, conv_all, ssm_all[i_m] = mamba2.step_through_cache(
+                p, y, conv_all, ssm_all[i_m], i_m, cfg.mamba)
             i_m += 1
         elif kind == "*":
             with jax.named_scope("attn_proj"):
@@ -506,7 +308,7 @@ def nemotron_h_decode_step(params: Params, cache: Params, tokens: jax.Array,
         logits = jnp.einsum("sd,dv->sv", x, params["lm_head"].astype(dt_),
                             preferred_element_type=jnp.float32)
     return logits, {"k": k_all, "v": v_all, "conv": conv_all,
-                    "ssm": tuple(ssm_all)}, _counters(counts)
+                    "ssm": tuple(ssm_all)}, held_counters(counts)
 
 
 def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
@@ -537,29 +339,11 @@ def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
         with jax.named_scope("ln"):
             y = _rms_norm(x, p["norm"], cfg.eps)
         if kind == "M" and cache is None:
-            out, _, _ = _mamba_rows(p, y, lengths, cfg)
+            out, _, _ = mamba2.mamba_rows(p, y, lengths, cfg.mamba)
         elif kind == "M":
-            with jax.named_scope("conv"):  # its left context, by slot
-                tail = jnp.stack([jax.lax.dynamic_slice(
-                    conv_all, (i_m, 0, slots[i], 0),
-                    (1, cfg.conv_kernel - 1, 1, cfg.conv_dim))[0, :, 0]
-                    for i in range(r)])  # [R, K-1, C]
-                tail = jnp.where(goes_on[:, None, None], tail, 0)
-            with jax.named_scope("ssm_scan"):  # its first state, by slot
-                state = jnp.concatenate([jax.lax.dynamic_slice(
-                    ssm_all[i_m], (slots[i], 0, 0, 0),
-                    (1,) + ssm_all[i_m].shape[1:]) for i in range(r)])
-                state = jnp.where(goes_on[:, None, None, None], state, 0)
-            out, tail, state = _mamba_rows(p, y, lengths, cfg, tail, state)
-            with jax.named_scope("state_write"):
-                tail = tail.astype(conv_all.dtype)
-                for i in range(r):  # by slot; distinct but the scratch
-                    conv_all = jax.lax.dynamic_update_slice(
-                        conv_all, tail[None, :, i:i + 1],
-                        (i_m, 0, slots[i], 0))
-                    ssm_all[i_m] = jax.lax.dynamic_update_slice(
-                        ssm_all[i_m], state[i:i + 1],
-                        (slots[i], 0, 0, 0))
+            out, conv_all, ssm_all[i_m] = mamba2.rows_through_cache(
+                p, y, lengths, conv_all, ssm_all[i_m], i_m, slots, goes_on,
+                cfg.mamba)
             i_m += 1
         elif kind == "*":
             with jax.named_scope("attn_proj"):

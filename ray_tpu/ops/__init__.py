@@ -14,7 +14,7 @@ from ray_tpu.ops.ring_attention import (
 )
 from ray_tpu.ops.ulysses import ulysses_attention, ulysses_attention_local
 from ray_tpu.ops.moe import (dropless_experts, init_moe_params, moe_ffn,
-                             moe_ffn_ep, route)
+                             moe_ffn_ep, route, route_topk_softmax)
 
 __all__ = [
     "causal_attention",
@@ -30,4 +30,5 @@ __all__ = [
     "moe_ffn",
     "moe_ffn_ep",
     "route",
+    "route_topk_softmax",
 ]

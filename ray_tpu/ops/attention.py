@@ -151,7 +151,8 @@ def cache_write_prompt(cache: jax.Array, layer: jax.Array, rows: jax.Array,
 
 def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
                            layer: jax.Array, slots: jax.Array,
-                           start: jax.Array, window: int) -> jax.Array:
+                           start: jax.Array, window: int,
+                           scale: float | None = None) -> jax.Array:
     """A chunk of C prompt tokens a row over the row's own slot, AFTER the
     chunk's K/V rows were written there (``cache_write_prompt``).
 
@@ -164,7 +165,9 @@ def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     the chunk's own up to itself. Only that window is cut out of the
     stack, R blocks of [window, G, hd]; the cache itself is not touched.
     Operands in the cache's type, float32 scores and softmax, output in
-    q's type: ``xla_causal_attention``'s arithmetic on a wider key axis."""
+    q's type: ``xla_causal_attention``'s arithmetic on a wider key axis.
+    ``scale`` multiplies the scores where a model publishes its own
+    constant; None is ``hd ** -0.5``."""
     r, c, h, hd = q.shape
     g = k_all.shape[3]
 
@@ -176,7 +179,8 @@ def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     k, v = rows_of(k_all), rows_of(v_all)  # [R, W, G, hd]
     q = q.reshape(r, c, g, h // g, hd)
     scores = jnp.einsum("rqgpd,rkgd->rgpqk", q.astype(k.dtype), k,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
+                        preferred_element_type=jnp.float32) \
+        * (hd ** -0.5 if scale is None else scale)
     seen = jnp.arange(window)[None, None, :] \
         <= (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [R, C, W]
     probs = jax.nn.softmax(
@@ -188,7 +192,8 @@ def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             k_new: jax.Array, v_new: jax.Array,
                             cursor: jax.Array, valid: jax.Array,
-                            out_dtype) -> jax.Array:
+                            out_dtype, scale: float | None = None
+                            ) -> jax.Array:
     """One query token per slot over the slot's ring-cache window, the
     token itself included, WITHOUT its row being in the cache yet.
 
@@ -205,18 +210,21 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     multiple of G) each K/V head serves its H // G query heads as it lies,
     with no expanded copy of the window. fp32 scores/softmax,
     output cast to the activation dtype — shared by both model families'
-    decode steps so the masking/scaling contract lives here once."""
+    decode steps so the masking/scaling contract lives here once.
+    ``scale`` multiplies the scores where a model publishes its own
+    constant; None divides them by ``hd ** 0.5`` as ever."""
     hd = q.shape[-1]
     if k.shape[2] != q.shape[1]:
         return _grouped_decode_attention(q, k, v, k_new, v_new, cursor,
-                                         valid, out_dtype)
+                                         valid, out_dtype, scale)
     q = q.astype(jnp.float32)
     idx = jnp.arange(k.shape[1])
     at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
     mask = (idx[None, :] < valid[:, None])[:, None, :]
     scores = jnp.einsum("shd,slhd->shl", q, k.astype(jnp.float32))
     score_new = jnp.sum(q * k_new.astype(jnp.float32), axis=-1)  # [S, H]
-    scores = jnp.where(at_cursor, score_new[..., None], scores) / (hd ** 0.5)
+    scores = _scaled(jnp.where(at_cursor, score_new[..., None], scores),
+                     hd, scale)
     weights = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
     weight_new = jnp.sum(jnp.where(at_cursor, weights, 0.0), axis=-1)
     out = jnp.einsum("shl,slhd->shd", jnp.where(at_cursor, 0.0, weights),
@@ -225,8 +233,13 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(out_dtype)
 
 
+def _scaled(scores, hd: int, scale: float | None):
+    # None keeps the division the compiled steps have always held
+    return scores / (hd ** 0.5) if scale is None else scores * scale
+
+
 def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
-                              out_dtype):
+                              out_dtype, scale=None):
     """``cached_decode_attention`` for G K/V heads under H = G * R query
     heads: the same softmax over the same keys, the window read once."""
     s, h, hd = q.shape
@@ -237,7 +250,8 @@ def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
     mask = (idx[None, :] < valid[:, None])[:, None, None, :]
     scores = jnp.einsum("sgrd,slgd->sgrl", q, k.astype(jnp.float32))
     score_new = jnp.einsum("sgrd,sgd->sgr", q, k_new.astype(jnp.float32))
-    scores = jnp.where(at_cursor, score_new[..., None], scores) / (hd ** 0.5)
+    scores = _scaled(jnp.where(at_cursor, score_new[..., None], scores),
+                     hd, scale)
     weights = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
     weight_new = jnp.sum(jnp.where(at_cursor, weights, 0.0), axis=-1)
     out = jnp.einsum("sgrl,slgd->sgrd", jnp.where(at_cursor, 0.0, weights),

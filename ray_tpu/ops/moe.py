@@ -17,8 +17,9 @@ The dense path (``moe_ffn``) works on any mesh; ``moe_ffn_ep`` adds the
 all_to_all when an ``ep`` axis exists.
 
 Beside that capacity path (training; it drops tokens over the capacity)
-lives the dropless SHARE layer of the serving path: ``route`` scores every
-expert of the model, ``dropless_experts`` computes the part of the result
+lives the dropless SHARE layer of the serving path: ``route`` (sigmoid
+scores) or ``route_topk_softmax`` (softmax over the chosen logits) scores
+every expert of the model, ``dropless_experts`` computes the part of the result
 that the experts held on this chip give, for every token routed to them,
 whatever the imbalance. It has no exchange: on one chip there is none, and
 nothing here stands in for absent chips.
@@ -216,6 +217,18 @@ def route(x: jax.Array, w_gate: jax.Array, bias: jax.Array, top_k: int,
     return ids.astype(jnp.int32), w
 
 
+def route_topk_softmax(x: jax.Array, w_gate: jax.Array, top_k: int
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Top-k of the experts' float32 logits, softmax over the chosen.
+
+    x [T, D], w_gate [D, E] over ALL experts. -> ids [T, K] int32,
+    weights [T, K] float32 that sum to one a row."""
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, ids = jax.lax.top_k(logits, top_k)
+    return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
 def dropless_experts(h: jax.Array, ids: jax.Array, weights: jax.Array,
                      w1: jax.Array, w2: jax.Array, *, first: int,
                      activation, live: jax.Array | None = None
@@ -246,6 +259,17 @@ def dropless_experts(h: jax.Array, ids: jax.Array, weights: jax.Array,
     share = _grouped_share if ids.shape[0] > DENSE_ROWS else _batched_share
     return share(h, local, held, weights, w1.astype(h.dtype),
                  w2.astype(h.dtype), activation)
+
+
+def held_counters(counts: list) -> dict:
+    """Over a step's expert layers (``dropless_experts``' counts, one [E]
+    a layer): the held experts that took at least one row, and the
+    token-expert pairs that landed here."""
+    if not counts:
+        return {"experts_hit": jnp.int32(0), "expert_rows": jnp.int32(0)}
+    stacked = jnp.stack(counts)
+    return {"experts_hit": jnp.sum(stacked > 0, dtype=jnp.int32),
+            "expert_rows": jnp.sum(stacked, dtype=jnp.int32)}
 
 
 def _grouped_share(h, local, held, weights, w1, w2, activation):

@@ -146,8 +146,16 @@ def _model_bundle(model: str, config, preset: str):
                          else m.NemotronHConfig())
         return (cfg, m.nemotron_h_init, m.nemotron_h_init_cache,
                 m.nemotron_h_prefill_chunk, m.nemotron_h_decode_step)
+    if model == "granite_hybrid":
+        from ray_tpu.models import granite_hybrid as m
+
+        cfg = config or (m.GraniteHybridConfig.tiny() if preset == "tiny"
+                         else m.GraniteHybridConfig())
+        return (cfg, m.granite_hybrid_init, m.granite_hybrid_init_cache,
+                m.granite_hybrid_prefill_chunk, m.granite_hybrid_decode_step)
     raise ValueError(
-        f"unknown model family {model!r} (want gpt2|llama|nemotron_h)")
+        f"unknown model family {model!r} "
+        f"(want gpt2|llama|nemotron_h|granite_hybrid)")
 
 
 def _stored_params(init, key, cfg):
@@ -277,6 +285,10 @@ class LLMEngine:
         # ONE array the step syncs on, and add up in stats_counters; a
         # family that returns none runs the program it always ran.
         self._step_counters: tuple = ()
+        # What a family's programs count in the cache itself (the leaves
+        # of ``cache["counted"]``, int32 scalars that never stop rising
+        # and so wrap): the last value read of each (_prefill_batch).
+        self._counted_seen: Dict[str, int] = {}
         # What the model says of itself beside its counters (llm_stats).
         self._model_stats = dict(
             getattr(cfg, "serving_stats", lambda: {})())
@@ -500,6 +512,10 @@ class LLMEngine:
             # The one intentional sync per request: first tokens must
             # reach the streams now.  # analyze: ignore[JX002]
             first = [int(np.asarray(tok)[0]) for tok in first]  # analyze: ignore[JX002]
+            # what the programs have counted in the cache up to here
+            # (a sparse model's token-expert pairs; most families: none)
+            counted = {key: int(np.asarray(n)) for key, n in  # analyze: ignore[JX002]
+                       self._cache.get("counted", {}).items()}
         self._init_s.setdefault("first_prefill", time.perf_counter() - t0)
         now = time.time()
         with tracing.device_span("llm.prefill.fanout"):
@@ -511,6 +527,10 @@ class LLMEngine:
                 c["prefill_tokens_real"] += tokens_real
                 c["prefill_chunks"] += n_chunks
                 c["prefill_tokens_lane"] += n_chunks * chunk
+                for key, n in counted.items():
+                    c[key] = c.get(key, 0) + (
+                        n - self._counted_seen.get(key, 0)) % 2 ** 32
+                self._counted_seen = counted
                 for i, req in enumerate(batch):
                     slot = slots[i]
                     tok = first[i]

@@ -12,12 +12,15 @@ import numpy as np
 import pytest
 
 from benchmark.loading import load_module
+from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import nemotron_h as nh
 from ray_tpu.ops import moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 reference = load_module(os.path.join(REPO, "benchmark", "reference",
                                      "nemotron_h.py"))
+gated_reference = load_module(os.path.join(REPO, "benchmark", "reference",
+                                           "granite_hybrid.py"))
 T, D, F, E, K = 24, 16, 20, 12, 3
 relu2 = lambda x: jnp.square(jax.nn.relu(x))
 # the row count alone chooses the path: T rows take the batched product,
@@ -196,3 +199,100 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
     # and one share alone is not the layer
     assert float(jnp.abs(parts[0].reshape(y.shape) - whole).max()) \
         > 1e-2 * scale
+
+
+# -- the router that is a softmax over the chosen logits, gated experts -------
+
+
+def test_route_topk_softmax_is_the_references_gating(layer):
+    """The top K of the float32 logits and a softmax over those K: no
+    softmax over all first, no bias, weights that sum to one."""
+    ids, w = moe.route_topk_softmax(layer["h"], layer["gate"], K)
+    assert ids.shape == w.shape == (T, K)
+    assert ids.dtype == jnp.int32 and w.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+    want = gated_reference.gating(layer["h"], layer["gate"], K)  # [T, E]
+    dense = jnp.zeros((T, E)).at[jnp.arange(T)[:, None], ids].set(w)
+    np.testing.assert_allclose(np.asarray(dense), np.asarray(want),
+                               atol=1e-6)
+    # not the softmax over every expert cut to its top K
+    every = jax.nn.softmax(layer["h"] @ layer["gate"], axis=-1)
+    cut = jnp.take_along_axis(every, ids, axis=-1)
+    assert float(jnp.abs(cut - w).max()) > 1e-2
+    # float32 at the highest precision whatever the rows' type
+    low, _ = moe.route_topk_softmax(layer["h"].astype(jnp.bfloat16),
+                                    layer["gate"].astype(jnp.bfloat16), K)
+    text = str(jax.make_jaxpr(lambda h, g: moe.route_topk_softmax(h, g, K))(
+        layer["h"].astype(jnp.bfloat16), layer["gate"]))
+    assert "HIGHEST" in text and low.shape == (T, K)
+
+
+@PATHS
+def test_gated_experts_go_through_the_share_as_it_is(layer, copies):
+    """``w1`` [E, D, 2 F] and ``silu(a) * b`` between the two products:
+    both static shapes of ``dropless_experts`` give the reference's routed
+    part, an expert at a time. No new product."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    w1 = jax.random.normal(ks[0], (E, D, 2 * F)) * 0.3
+    h = copied(layer, copies)
+    ids, w = moe.route_topk_softmax(h, layer["gate"], K)
+    first, held = 3, 6
+    got, counts = moe.dropless_experts(
+        h, ids, w, w1[first:first + held], layer["w2"][first:first + held],
+        first=first, activation=gh._gate)
+    mine = gated_reference.gating(h, layer["gate"], K)[:, first:first + held]
+    want = sum(mine[:, e, None] * (gated_reference.gated(
+        h @ w1[first + e]) @ layer["w2"][first + e]) for e in range(held))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    assert np.asarray(counts).tolist() \
+        == np.asarray((mine > 0).sum(0)).tolist()
+    path = "ragged_dot" in str(jax.make_jaxpr(
+        lambda h: moe.dropless_experts(
+            h, ids, w, w1[:held], layer["w2"][:held], first=0,
+            activation=gh._gate)[0])(h))
+    assert path == (copies > 1)  # the row count alone chose
+
+
+def test_the_two_shares_of_a_gated_layer_add_up_to_the_uncut_layer():
+    """``experts_held`` (0, 4) and (4, 4) of 8: the routed parts the two
+    chips give, with the shared expert (which both compute) counted once,
+    are the reference's layer with every expert held."""
+    cfg = gh.GraniteHybridConfig.tiny(dtype=jnp.float32,
+                                      param_dtype=jnp.float32,
+                                      experts_held=(0, 8))
+    p = gh._layer_init(jax.random.PRNGKey(4), "mamba", cfg)
+    p = {**p, "w2": 8.0 * p["w2"]}  # at its seeded scale the routed part
+    # is a tenth of the shared expert's: make it count
+    y = jax.random.normal(jax.random.PRNGKey(5), (2, 9, cfg.d_model))
+    whole = gated_reference.experts(
+        {"router": p["router"], "experts_in": p["w1"],
+         "experts_out": p["w2"], "shared_in": p["shared_w1"],
+         "shared_out": p["shared_w2"]}, y, top_k=cfg.top_k, first_expert=0)
+    flat = y.reshape(-1, cfg.d_model)
+    parts, rows = [], 0
+    for first in (0, 4):  # two chips, four experts each
+        share = gh.GraniteHybridConfig.tiny(
+            dtype=jnp.float32, param_dtype=jnp.float32,
+            experts_held=(first, 4))
+        mine = {**p, "w1": p["w1"][first:first + 4],
+                "w2": p["w2"][first:first + 4]}
+        out, counts = gh._moe(mine, flat, share)
+        parts.append(out)
+        rows += int(counts.sum())
+    shared = gh._gate(flat @ p["shared_w1"]) @ p["shared_w2"]
+    total = parts[0] + parts[1] - shared
+    scale = float(jnp.abs(whole).max())
+    assert float(jnp.abs(total.reshape(y.shape) - whole).max()) < 1e-5 * scale
+    assert rows == flat.shape[0] * cfg.top_k  # every pair landed somewhere
+    assert float(jnp.abs(parts[0].reshape(y.shape) - whole).max()) \
+        > 1e-2 * scale
+
+
+def test_the_step_counters_count_hit_experts_and_pairs():
+    counts = [jnp.asarray([2, 0, 1, 0]), jnp.asarray([0, 0, 0, 5])]
+    got = moe.held_counters(counts)
+    assert {k: int(v) for k, v in got.items()} \
+        == {"experts_hit": 3, "expert_rows": 8}
+    assert {k: int(v) for k, v in moe.held_counters([]).items()} \
+        == {"experts_hit": 0, "expert_rows": 0}
+    assert all(v.dtype == jnp.int32 for v in got.values())

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2, llama, nemotron_h
+from ray_tpu.models import gpt2, granite_hybrid, llama, nemotron_h
 from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
 from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -35,6 +35,13 @@ FAMILIES = {
         nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_init_cache,
         nemotron_h.nemotron_h_prefill_chunk, nemotron_h.nemotron_h_prefill,
         nemotron_h.nemotron_h_forward),
+    "granite_hybrid": (granite_hybrid.GraniteHybridConfig.tiny(
+        dtype=F32, param_dtype=F32, chunk_size=4),
+        granite_hybrid.granite_hybrid_init,
+        granite_hybrid.granite_hybrid_init_cache,
+        granite_hybrid.granite_hybrid_prefill_chunk,
+        granite_hybrid.granite_hybrid_prefill,
+        granite_hybrid.granite_hybrid_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 CHUNK, MAX_PROMPT, CACHE_LEN, SLOTS = 4, 16, 24, 4
@@ -243,3 +250,52 @@ def test_the_chunk_is_the_engines_by_rule_and_must_fit_the_cache():
         assert eng.llm_stats()["prefill_chunk"] == 16
     finally:
         eng.shutdown_engine()
+
+
+def test_what_a_cache_counts_adds_up_in_llm_stats():
+    """A family's cache may carry ``counted``, int32 scalars its programs
+    add to in place (``prefill_expert_rows``): the engine reads them once
+    an admission turn and says in ``llm_stats()`` by how much each rose,
+    across a wrap of the 32 bits too; a family whose cache carries none
+    reports no such key."""
+    cfg = FAMILIES["granite_hybrid"][0]
+    eng = _engine("granite_hybrid")
+    try:
+        lens = [CHUNK - 1, 2 * CHUNK + 1, MAX_PROMPT]
+        prompts = [_prompt(n, seed=40 + n) for n in lens]
+        for prompt in prompts[:2]:
+            assert len(eng.generate(prompt.tolist(), 2)) == 2
+        # the counter is about to wrap
+        seen = eng.llm_stats()["prefill_expert_rows"]
+        eng._cache["counted"]["prefill_expert_rows"] = jnp.int32(2 ** 31 - 5)
+        eng._counted_seen = {"prefill_expert_rows": 2 ** 31 - 5}
+        assert len(eng.generate(prompts[2].tolist(), 2)) == 2
+        assert int(eng._cache["counted"]["prefill_expert_rows"]) < 0
+        st = eng.llm_stats()
+        # what the chunk function itself counts for the same prompts
+        cache = granite_hybrid.granite_hybrid_init_cache(cfg, SLOTS,
+                                                         CACHE_LEN)
+        for prompt in prompts:
+            for at in range(0, len(prompt), CHUNK):
+                piece = prompt[at:at + CHUNK]
+                toks = np.zeros((1, CHUNK), np.int32)
+                toks[0, :len(piece)] = piece
+                _, cache = granite_hybrid.granite_hybrid_prefill_chunk(
+                    eng.params, cache, jnp.asarray(toks),
+                    jnp.zeros(1, jnp.int32), jnp.full(1, at, jnp.int32),
+                    jnp.full(1, len(piece), jnp.int32), cfg,
+                    window=key_window(MAX_PROMPT, CHUNK))
+        want = int(cache["counted"]["prefill_expert_rows"])
+    finally:
+        eng.shutdown_engine()
+    assert st["prefill_chunks"] == 1 + 3 + 4
+    assert st["prefill_tokens_real"] == sum(lens)
+    assert 0 < seen < st["prefill_expert_rows"] == want
+    assert want <= sum(lens) * cfg.top_k * len(cfg.layer_types)
+    dense = _engine("gpt2")
+    try:
+        assert len(dense.generate(_prompt(6).tolist(), 2)) == 2
+        assert "counted" not in dense._cache
+        assert "prefill_expert_rows" not in dense.llm_stats()
+    finally:
+        dense.shutdown_engine()

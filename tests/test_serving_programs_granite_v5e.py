@@ -1,0 +1,192 @@
+"""What the TPU's compiler makes of Granite 4.0-H's two serving programs
+(PR 34).
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/granite-4.0-h-small.json`` and the shapes of the cell
+``serve_granite4hs_longdoc_sat`` (32 slots and the scratch one, rings of
+8448 rows, prompts of up to 8192 tokens in the engine's [1, 256] chunks
+over a key window of 8192): nothing runs, so nothing here is a time. It
+holds that both programs fit the chip beside their arguments (the decode
+step's float32 pass over 33 K/V windows included), that the donated cache
+is updated in its own buffers, and that no program copies a layer's expert
+stack or the whole state: XLA's choices decide that, not the jaxpr.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import granite_hybrid as gh
+from ray_tpu.models.prefill import chunk_len, key_window
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return load_json(os.path.join(
+        REPO, "benchmark", "deployments",
+        "granite4hs_1chip_b32.json"))["engine"]
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "granite_hybrid.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "granite-4.0-h-small.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg, engine):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = engine["max_batch"] + 1
+    chunk = chunk_len(engine["max_prompt_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (33, 256, 8192, 8448)
+    params = sds(jax.eval_shape(
+        lambda: gh.granite_hybrid_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: gh.granite_hybrid_init_cache(
+        cfg, slots, engine["cache_len"])))
+    programs = {
+        "decode": (lambda p, c, t, n: gh.granite_hybrid_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(slots), i32(slots))),
+        "prefill": (lambda p, c, t, s, at, n:
+                    gh.granite_hybrid_prefill_chunk(
+                        p, c, t, s, at, n, cfg, window=window),
+                    (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+                for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
+                                                        which):
+    """4.757 B bfloat16 parameters (9.51 GB) and 2.40 GB of cache are the
+    arguments; the cache is aliased to the output, so it is held once. The
+    step's float32 pass over 33 windows of 8448 K/V rows (2.3 GB if it
+    were an array) is fused into the products that read it."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = 2 * nbytes((1, 33, 8448, 8, 128), 2) \
+        + nbytes((9, 3, 33, cfg.mamba.conv_dim), 2) \
+        + nbytes((9, 33, 128, 64, 128), 4)
+    assert cache_bytes == 2_402_661_888
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert 11.9e9 < mem.argument_size_in_bytes < 11.95e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    # the step holds next to nothing of its own; a chunk holds its scores
+    # over the 8192-row window (268 MB in float32) and its experts' rows
+    assert mem.temp_size_in_bytes < {"decode": 0.1e9, "prefill": 0.6e9}[which]
+
+
+SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                   r"([\w\-]+)\(")
+PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple",
+             "fusion", "dynamic-update-slice", "custom-call", "while",
+             "conditional", "call", "opt-barrier")
+
+
+def _unfused_lines(hlo_text):
+    """The instructions that make an array of their own: those of every
+    computation but the ones a ``fusion`` calls (inside a fusion a slice or
+    a convert is a step of one loop, not a buffer)."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    for block in hlo_text.split("\n\n"):
+        if block.lstrip().split(" ", 1)[0] not in fused:
+            yield from block.splitlines()[1:]
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_expert_stack_and_no_whole_state_is_copied(compiled, which):
+    """A layer's expert stacks are 36 x 4096 x 1536 and 36 x 768 x 4096
+    bfloat16 (453 and 226 MB), the SSM state 9 arrays of 33 x 128 x 64 x 128
+    float32 (138 MB each), a K/V ring stack 33 x 8448 x 8 x 128 (571 MB).
+    No ``copy``, ``transpose``, ``convert`` or slice in either program
+    makes an array of their size: the experts are read where they lie, and
+    state and rings are rewritten inside their donated buffers."""
+    layer_state = nbytes((33, 128, 64, 128), 1)  # elements
+    theirs = {nbytes((36, 4096, 1536), 1), nbytes((36, 768, 4096), 1),
+              layer_state, 9 * layer_state, nbytes((33, 8448, 8, 128), 1)}
+    moved, lines = [], 0
+    for line in _unfused_lines(compiled[which].as_text()):
+        m = SHAPE.match(line)
+        if not m or m.group(1) not in {"bf16", "f32"}:
+            continue
+        lines += 1
+        dims = [int(d) for d in m.group(2).split(",")]
+        if nbytes(dims, 1) in theirs and m.group(3) not in PASSES_ON:
+            moved.append(line.strip()[:150])
+    assert lines > 200, "read no program"
+    assert moved == []
+
+
+def test_both_programs_run_the_batched_products(compiled):
+    """33 rows a step, 256 a chunk (``ops/moe.DENSE_ROWS``, which this
+    family shares with the other hybrid and does not move): every held
+    expert over every row, no sort and no grouped product in either of the
+    engine's programs."""
+    for which in ("decode", "prefill"):
+        assert "ragged" not in compiled[which].as_text()
+
+
+def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
+    """The K/V rings and a layer's SSM state (2.38 of the cache's 2.40 GB):
+    each shape has one layout in the chunk program, and it is the decode
+    program's, so neither is re-laid out between the two."""
+    def layouts(shape, which):
+        # (a trailing S(n) names a memory space, not a layout)
+        return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+            shape + r"(\{[^}]*\})", compiled[which].as_text())}
+
+    for shape in (r"bf16\[1,33,8448,8,128\]", r"f32\[33,128,64,128\]"):
+        assert len(layouts(shape, "prefill")) == 1, shape
+        assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
