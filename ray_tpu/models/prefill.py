@@ -1,4 +1,4 @@
-"""A prompt is prefilled in chunks: what the three served families share.
+"""A prompt is prefilled in chunks: what the served families share.
 
 Each family has ONE prefill function the serving engine compiles,
 ``<family>_prefill_chunk(params, cache, tokens[R, C], slots[R], start[R],

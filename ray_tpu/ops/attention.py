@@ -259,3 +259,184 @@ def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
     out = out + weight_new[..., None] \
         * v_new.astype(jnp.float32)[:, :, None, :]
     return out.reshape(s, h, hd).astype(out_dtype)
+
+
+# -- latent cache of the serving path ------------------------------------------
+#
+# Latent attention (MLA) caches, a token a layer, ONE row that every query
+# head shares: the normalised latent ``c`` (``rank`` numbers, from which a
+# head's key and value are products ``c W_uk,h`` and ``c W_uv,h``) beside the
+# rotated position key (``rope`` numbers). The stacked cache is
+# [N, S, L, 1, rank + rope]: a row is one "head" as wide as both, so
+# ``cache_write_token`` / ``cache_write_prompt`` above write it as they write
+# K/V rows. Its two attentions differ from the K/V ones in more than width.
+# At the shapes this cache exists for (a hundred heads over tens of thousands
+# of rows a slot) neither a float32 copy of a window nor a scores array over
+# the whole ring fits beside the weights, and the decode attention does as
+# many operations a byte as the chip's ridge, so both read the ring in
+# blocks of keys, in the cache's type, with a running softmax in float32,
+# and stop at the last block that holds a key anyone may see (a ``while``
+# whose trip count the positions decide: one compiled program, whose work
+# follows the keys in sight).
+
+# Keys a block, as measured on a v5e at 128 heads over rows of 576 (PERF.md
+# section 6, PR 38; a block's scores are [S, H, block] float32 in the step,
+# [H, C, block] in a chunk, and what a block costs is mostly their trips
+# to memory and back). One layer's step over 65 rings of 16896 rows:
+# 5.59 / 4.70 / 4.23 / 5.08 / 7.37 ms at 256 / 512 / 1024 / 1280 / 2048.
+# One layer's chunk of 256 queries over 4352 keys: 2.38 / 1.97 / 2.65 /
+# 3.12 / 5.63 ms at 128 / 256 / 384 / 512 / 1024 (a block that is no
+# multiple of 128 lanes costs five times as much).
+LATENT_DECODE_BLOCK = 1024
+LATENT_CHUNK_BLOCK = 256
+
+
+def _online_softmax(carry, scores, seen, product):
+    """One block of a running softmax: carry (m, l, acc) in float32,
+    scores float32 with ``seen`` the keys that count, ``product(p)`` the
+    block's weighted values for float32 probabilities p (the caller casts
+    them to its values' type)."""
+    m, l, acc = carry
+    scores = jnp.where(seen, scores, -1e30)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+    return (m_new, l * alpha + jnp.sum(p, axis=-1),
+            acc * alpha[..., None] + product(p))
+
+
+def _ring_block(j, block: int, n_rows: int):
+    """Block j of a ring of n_rows: the row it starts at, its rows'
+    indices, and which of them no earlier block gave (the last block is
+    moved back inside the ring, so some of its rows were read before)."""
+    at = jnp.minimum(j * block, n_rows - block)
+    idx = at + jnp.arange(block)
+    return at, idx, idx >= j * block
+
+
+# decode-path  # jax-hot-path: the latent cache stays in the activation dtype
+def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
+                            cache: jax.Array, layer: int, row_new: jax.Array,
+                            cursor: jax.Array, valid: jax.Array,
+                            scale: float, out_dtype,
+                            block: int = LATENT_DECODE_BLOCK) -> jax.Array:
+    """The ABSORBED form: one query token a slot over the slot's ring of
+    latent rows, the token itself included, WITHOUT its row being in the
+    cache yet (``cached_decode_attention``'s contract).
+
+    q_lat [S, H, rank], a head's query already multiplied into the latent
+    space (``q_nope_h W_uk,h^T``); q_pe [S, H, rope], rotated; cache the
+    stacked [N, S, L, 1, rank + rope] as it was before this step (read
+    only), ``layer`` the static index of this layer's rings; row_new
+    [S, rank + rope], this token's row in the cache's type; cursor [S] the
+    ring row it will take, valid [S] the live rows with it.
+    ``score = (q_lat . c + q_pe . k_pe) * scale``; -> [S, H, rank], the
+    probabilities' sum of the latents ``c`` (the caller multiplies it by
+    ``W_uv``). The new token's score and latent start the running softmax;
+    the ring is then read in blocks of ``block`` rows up to the longest
+    live context of any slot, the row at a slot's cursor left out (a
+    wrapped ring drops it, as ever). Operands in the cache's type; one
+    block's scores, the running statistics and the sums are the only
+    single-precision values (each such line carries its waiver of the
+    region's rule: none is as long as a ring)."""
+    s, h, rank = q_lat.shape
+    n_rows = cache.shape[2]
+    block = min(block, n_rows)
+    q = jnp.concatenate([q_lat, q_pe], axis=-1).astype(cache.dtype)
+    row_new = row_new.astype(cache.dtype)
+    score_new = jnp.einsum(
+        "shw,sw->sh", q, row_new,  # [S, H] scores of the new row
+        preferred_element_type=jnp.float32) * scale  # analyze: ignore[JX004]
+    # the running softmax's statistics and sums, [S, H] and [S, H, rank]
+    carry = (score_new, jnp.ones((s, h), jnp.float32),  # analyze: ignore[JX004]
+             jnp.broadcast_to(
+                 row_new[:, None, :rank].astype(jnp.float32),  # analyze: ignore[JX004]
+                 (s, h, rank)))
+
+    def body(j, carry):
+        at, idx, fresh = _ring_block(j, block, n_rows)
+        # two reads of the ring, each by the one product that uses it:
+        # a block sliced once for both is copied out first (76 MB a block
+        # at 65 slots; seen on the chip)
+        rows, latents = (jax.lax.dynamic_slice(
+            cache, (layer, 0, at, 0, 0),
+            (1, s, block, 1, width))[0, :, :, 0]
+            for width in (cache.shape[-1], rank))  # [S, B, W], [S, B, rank]
+        seen = (idx[None, :] < valid[:, None]) \
+            & (idx[None, :] != cursor[:, None]) & fresh[None, :]
+        # one block's scores [S, H, B] and its sums [S, H, rank]: the
+        # products' accumulators, not a copy of the rows
+        scores = jnp.einsum(
+            "shw,sbw->shb", q, rows,
+            preferred_element_type=jnp.float32) * scale  # analyze: ignore[JX004]
+        return _online_softmax(
+            carry, scores, seen[:, None, :],
+            lambda p: jnp.einsum(
+                "shb,sbr->shr", p.astype(latents.dtype), latents,
+                preferred_element_type=jnp.float32))  # analyze: ignore[JX004]
+
+    n_blocks = (jnp.max(valid) + block - 1) // block
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, carry)
+    return (acc / l[..., None]).astype(out_dtype)
+
+
+def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
+                           cache: jax.Array, layer: int, slots: jax.Array,
+                           start: jax.Array, w_uk: jax.Array,
+                           w_uv: jax.Array, scale: float,
+                           block: int = LATENT_CHUNK_BLOCK) -> jax.Array:
+    """The DECOMPRESSED form: a chunk of C prompt tokens a row over the
+    row's own slot, AFTER the chunk's latent rows were written there
+    (``cache_write_prompt``; ``cached_chunk_attention``'s contract).
+
+    q_nope [R, C, H, nope] and q_pe [R, C, H, rope] (rotated), the queries
+    of positions ``start[r] + i``; cache the stacked [N, S, L, 1, rank +
+    rope] the layer loop carries; w_uk [rank, H, nope] and w_uv [rank, H,
+    v] the products that make a head's key and value of a latent. Query i
+    sees the slot's rows ``<= start + i``. The ring is read in blocks of
+    ``block`` rows up to the last one that holds such a row (``start +
+    C``): each block's keys and values are decompressed (scope ``kv_up``),
+    scored against the chunk's queries and folded into a running softmax,
+    so the work follows the keys in sight and not the longest prompt. ->
+    [R, C, H, v] in q's type. Operands in the cache's type, float32
+    scores, statistics and sums."""
+    r, c, h, _ = q_nope.shape
+    rank, v_dim = w_uv.shape[0], w_uv.shape[-1]
+    n_rows = cache.shape[2]
+    block = min(block, n_rows)
+    dt_ = cache.dtype
+    w_uk, w_uv = w_uk.astype(dt_), w_uv.astype(dt_)
+
+    def one_row(i):  # over its own slot and its own keys
+        qn, qp = q_nope[i].astype(dt_), q_pe[i].astype(dt_)
+        sees = start[i] + jnp.arange(c)  # [C]
+
+        def body(j, carry):
+            at, idx, fresh = _ring_block(j, block, n_rows)
+            rows = jax.lax.dynamic_slice(
+                cache, (layer, slots[i], at, 0, 0),
+                (1, 1, block, 1, cache.shape[-1]))[0, 0, :, 0]  # [B, W]
+            with jax.named_scope("kv_up"):
+                k = jnp.einsum("br,rhd->bhd", rows[:, :rank], w_uk)
+                v = jnp.einsum("br,rhd->bhd", rows[:, :rank], w_uv)
+            seen = (idx[None, :] <= sees[:, None]) & fresh[None, :]  # [C, B]
+            scores = (jnp.einsum("chd,bhd->hcb", qn, k,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("chp,bp->hcb", qp, rows[:, rank:],
+                                   preferred_element_type=jnp.float32)) \
+                * scale
+            return _online_softmax(
+                carry, scores, seen[None],
+                lambda p: jnp.einsum("hcb,bhd->hcd", p.astype(v.dtype), v,
+                                     preferred_element_type=jnp.float32))
+
+        n_blocks = jnp.minimum(start[i] + c + block - 1,
+                               n_rows + block - 1) // block
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (
+            jnp.full((h, c), -1e30, jnp.float32),
+            jnp.zeros((h, c), jnp.float32),
+            jnp.zeros((h, c, v_dim), jnp.float32)))
+        return jnp.swapaxes(acc / l[..., None], 0, 1)  # [C, H, v]
+
+    # a few rows, each a loop of its own length
+    return jnp.stack([one_row(i) for i in range(r)]).astype(q_nope.dtype)

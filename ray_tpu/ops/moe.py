@@ -18,7 +18,8 @@ all_to_all when an ``ep`` axis exists.
 
 Beside that capacity path (training; it drops tokens over the capacity)
 lives the dropless SHARE layer of the serving path: ``route`` (sigmoid
-scores) or ``route_topk_softmax`` (softmax over the chosen logits) scores
+scores), ``route_topk_softmax`` (softmax over the chosen logits) or
+``route_group_limited`` (softmax over all, the best groups only) scores
 every expert of the model, ``dropless_experts`` computes the part of the result
 that the experts held on this chip give, for every token routed to them,
 whatever the imbalance. It has no exchange: on one chip there is none, and
@@ -227,6 +228,32 @@ def route_topk_softmax(x: jax.Array, w_gate: jax.Array, top_k: int
                      precision=jax.lax.Precision.HIGHEST)
     top, ids = jax.lax.top_k(logits, top_k)
     return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def route_group_limited(x: jax.Array, w_gate: jax.Array, top_k: int,
+                        n_group: int, topk_group: int, scale: float
+                        ) -> tuple[jax.Array, jax.Array]:
+    """Group-limited greedy router: a softmax over ALL experts in float32,
+    the choice held to a token's best groups.
+
+    x [T, D], w_gate [D, E]; the E experts lie in ``n_group`` groups of
+    E // n_group consecutive ids (one group a device where the experts are
+    spread out, so a token goes to at most ``topk_group`` devices). A
+    group's score is the largest softmax score among its experts; the
+    ``topk_group`` best groups keep their experts' scores and the others'
+    become 0; the ``top_k`` largest of what is left are chosen. -> ids
+    [T, K] int32, weights [T, K] float32: the chosen experts' own softmax
+    scores times ``scale``, NOT normalised over the chosen."""
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    t, e = scores.shape
+    by_group = scores.reshape(t, n_group, e // n_group)
+    _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
+    kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=1)  # [T, G]
+    left = jnp.where(kept[..., None], by_group, 0.0).reshape(t, e)
+    w, ids = jax.lax.top_k(left, top_k)
+    return ids.astype(jnp.int32), w * scale
 
 
 def dropless_experts(h: jax.Array, ids: jax.Array, weights: jax.Array,

@@ -153,9 +153,16 @@ def _model_bundle(model: str, config, preset: str):
                          else m.GraniteHybridConfig())
         return (cfg, m.granite_hybrid_init, m.granite_hybrid_init_cache,
                 m.granite_hybrid_prefill_chunk, m.granite_hybrid_decode_step)
+    if model == "deepseek_v2":
+        from ray_tpu.models import deepseek_v2 as m
+
+        cfg = config or (m.DeepseekV2Config.tiny() if preset == "tiny"
+                         else m.DeepseekV2Config())
+        return (cfg, m.deepseek_v2_init, m.deepseek_v2_init_cache,
+                m.deepseek_v2_prefill_chunk, m.deepseek_v2_decode_step)
     raise ValueError(
         f"unknown model family {model!r} "
-        f"(want gpt2|llama|nemotron_h|granite_hybrid)")
+        f"(want gpt2|llama|nemotron_h|granite_hybrid|deepseek_v2)")
 
 
 def _stored_params(init, key, cfg):

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2, granite_hybrid, llama, nemotron_h
+from ray_tpu.models import (deepseek_v2, gpt2, granite_hybrid, llama,
+                            nemotron_h)
 from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
 from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -42,6 +43,11 @@ FAMILIES = {
         granite_hybrid.granite_hybrid_prefill_chunk,
         granite_hybrid.granite_hybrid_prefill,
         granite_hybrid.granite_hybrid_forward),
+    "deepseek_v2": (deepseek_v2.DeepseekV2Config.tiny(
+        dtype=F32, param_dtype=F32),
+        deepseek_v2.deepseek_v2_init, deepseek_v2.deepseek_v2_init_cache,
+        deepseek_v2.deepseek_v2_prefill_chunk,
+        deepseek_v2.deepseek_v2_prefill, deepseek_v2.deepseek_v2_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 CHUNK, MAX_PROMPT, CACHE_LEN, SLOTS = 4, 16, 24, 4

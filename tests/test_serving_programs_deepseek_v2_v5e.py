@@ -1,0 +1,149 @@
+"""What the TPU's compiler makes of DeepSeek-V2's two serving programs
+(PR 38).
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/deepseek-v2.json`` and the shapes of the cell
+``serve_dsv2_longctx_sat`` (64 slots and the scratch one, rings of 16896
+latent rows, prompts of up to 16384 tokens in the engine's [1, 256]
+chunks): nothing runs, so nothing here is a time. It holds that both
+programs fit the chip beside their arguments with next to nothing of their
+own (no float32 copy of a window, no scores over a whole ring: both
+attentions read the ring in blocks), that the donated cache is updated in
+its own buffers and lies in ONE unpadded layout in both programs, and that
+the chunk's attention is a loop whose trip count the positions decide.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import deepseek_v2 as ds
+from ray_tpu.models.prefill import chunk_len, key_window
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 15.75 * 2 ** 30
+RING = r"bf16\[5,65,16896,1,576\]"
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return load_json(os.path.join(
+        REPO, "benchmark", "deployments", "dsv2_1chip_b64.json"))["engine"]
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "deepseek_v2.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "deepseek-v2.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg, engine):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = engine["max_batch"] + 1
+    chunk = chunk_len(engine["max_prompt_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (65, 256, 16384, 16896)
+    params = sds(jax.eval_shape(
+        lambda: ds.deepseek_v2_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: ds.deepseek_v2_init_cache(
+        cfg, slots, engine["cache_len"])))
+    programs = {
+        "decode": (lambda p, c, t, n: ds.deepseek_v2_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(slots), i32(slots))),
+        "prefill": (lambda p, c, t, s, at, n: ds.deepseek_v2_prefill_chunk(
+            p, c, t, s, at, n, cfg, window=window),
+            (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+                for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, which):
+    """3.145 B bfloat16 parameters (6.29 GB) and 6.33 GB of latent rings
+    are the arguments; the rings are aliased to the output, so they are
+    held once: 12.6 GB at rest, 79 % of the chip. As arrays a float32 copy
+    of one layer's rings would be 2.5 GB and a chunk's scores over its
+    whole 16384-row window 2.1 GB: read in blocks, the step keeps under
+    0.1 GB of its own and the chunk under 0.25 GB."""
+    mem = compiled[which].memory_analysis()
+    rings = 5 * 65 * 16896 * 576 * 2
+    assert rings == 6_325_862_400
+    assert mem.alias_size_in_bytes >= rings
+    assert 12.6e9 < mem.argument_size_in_bytes < 12.65e9
+    assert mem.argument_size_in_bytes / 16e9 > 0.78
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    assert mem.temp_size_in_bytes < {"decode": 0.1e9, "prefill": 0.25e9}[
+        which]
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_rings_are_read_in_blocks_where_they_lie(compiled, which):
+    """No float32 array as long as a ring, no second array of the rings'
+    size, and the block loop is there: a ``while`` in both programs."""
+    text = compiled[which].as_text()
+    assert len(text) > 100_000, "read no program"
+    assert not re.search(r"f32\[[\d,]*\b16896\b[\d,]*\]", text)
+    assert not re.search(r"f32\[[\d,]*\b16384\b[\d,]*\]", text)
+    assert not re.search(r"bf16\[65,16896,[\d,]*\]", text)  # a layer's rings
+    assert re.search(r" while\(", text)
+
+
+def test_the_chunk_keeps_the_rings_in_the_steps_unpadded_layout(compiled):
+    """One layout of the stacked rings in both programs, ring rows
+    minor-most (576 = 4.5 x 128 lanes would pad a row-major tile by a
+    ninth: 0.7 GB), so neither program re-lays the cache out."""
+    def layouts(which):
+        # (a trailing S(n) names a memory space, not a layout; a size-one
+        # axis may stand anywhere among the major ones)
+        found = {re.sub(r"S\(\d+\)", "", f) for f in re.findall(
+            RING + r"(\{[^}]*\})", compiled[which].as_text())}
+        return {re.sub(r"^\{2,4,(?:3,1|1,3),0", "{2,4,*,0", f)
+                for f in found}
+
+    assert len(layouts("prefill")) == 1
+    assert layouts("prefill") == layouts("decode")
+    assert next(iter(layouts("decode"))).startswith("{2,4,")
