@@ -360,6 +360,23 @@ class LLMEngine:
         # after a gap measures from the last delivery, so ITL includes
         # scheduling stalls between steps, not just compute).
         self._last_tokens_at: Optional[float] = None
+        # Enqueue first, wake later. A decode step's token is in its
+        # stream's ``pending`` (under the lock) when ``_step_fanout``
+        # returns, but the poller of a stream that goes on decoding is
+        # told only once the device has its next program: every woken
+        # ``llm_next`` wants the lock and the interpreter, one a slot,
+        # and ahead of the loop's own dispatch they kept the device
+        # waiting for it. The streams owed a wake-up wait here; only the
+        # loop's thread touches the list (``_flush_wakes``). Why no order
+        # of set, drain and clear loses a token or delivers one twice:
+        # the token is in ``pending`` under the lock BEFORE its event can
+        # be set, and ``llm_next`` drains and clears under the same lock;
+        # so a set that comes late finds either the token still pending
+        # (the woken poll takes it) or already drained by a poll that
+        # timed out or was woken for a neighbour token (the woken poll
+        # returns no chunk, as a time-out does, and ``stream_call`` goes
+        # round again).
+        self._wakes: List[_Stream] = []
         self._last_reap = time.monotonic()
         self.stats_counters = {
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
@@ -374,6 +391,12 @@ class LLMEngine:
             "prefill_batches": 0, "prefill_rows_real": 0,
             "prefill_tokens_real": 0, "prefill_tokens_lane": 0,
             "prefill_chunks": 0,
+            # Wake-ups of decode steps put off until after the next
+            # enqueue (a stream a step that goes on decoding), and how
+            # many of them were then set with a program on the device;
+            # the rest were set because nothing followed (a step that
+            # raised before its enqueue, a throttle, the idle wait).
+            "wakes_deferred": 0, "wakes_after_dispatch": 0,
         }
         self._loop_thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine-loop")
@@ -382,6 +405,20 @@ class LLMEngine:
     # -- scheduler loop ----------------------------------------------------
 
     def _loop(self):  # jax-hot-path
+        """The scheduler: admit, step, go round. A token is VISIBLE the
+        moment ``_step_fanout`` (or ``_prefill_batch``) appends it to its
+        stream under the lock: ``llm_poll`` and any ``llm_next`` that
+        drains see it from then on. Its poller is TOLD (the stream's
+        event set) at once for a first token and a terminal transition,
+        and for a decode step's token only after the next enqueue: once
+        the next step's ``_step_fn`` call has returned or an admission
+        turn's first chunk is dispatched, whichever comes first; and
+        where no program follows: when a step raises before its enqueue,
+        before ``step_throttle_s`` sleeps, before the idle wait. A turn
+        whose admission raised goes on to its step, and a request the
+        stop, a cancel or a shed ends is woken by its terminal
+        transition, so a token's wake-up never waits longer than one
+        turn's host work."""
         while not self._stop:
             did = False
             try:
@@ -402,9 +439,26 @@ class LLMEngine:
                 with tracing.device_span("llm.loop.reap"):
                     self._reap_streams()
             if not did:
+                # an idle engine owes nobody (every stream still on the
+                # list has ended, which woke it: this empties the list)
+                self._flush_wakes(enqueued=False)
                 with tracing.device_span("llm.loop.wait"):
                     self._wake.wait(0.02)
                 self._wake.clear()
+
+    def _flush_wakes(self, enqueued: bool) -> None:
+        """Set the event of every stream the last decode step put off
+        (loop thread only). ``enqueued``: a program was handed to the
+        device since, which is what the wake-ups waited for."""
+        wakes = self._wakes
+        if not wakes:
+            return
+        self._wakes = []
+        if enqueued:
+            # the loop's thread is this key's only writer
+            self.stats_counters["wakes_after_dispatch"] += len(wakes)
+        for st in wakes:
+            st.event.set()
 
     def _push_queued_locked(self, req: _Request):
         """Heap key = (deadline, seq): admission prefers deadline slack
@@ -511,6 +565,9 @@ class LLMEngine:
                     tok, self._cache = self._prefill_fn(
                         self.params, self._cache, packed)
                     n_chunks += 1
+                    # the device has work: tell the last step's streams
+                    # (after the turn's FIRST chunk; a no-op from then on)
+                    self._flush_wakes(enqueued=True)
                 first.append(tok)  # the last chunk's
             tokens_real = sum(lengths)
             ds.set_metadata(rows=len(batch), tokens_real=tokens_real,
@@ -620,10 +677,21 @@ class LLMEngine:
             # Anatomy host phase ends when the async dispatch returns.
             t_dispatch = time.perf_counter()
             with tracing.device_span("llm.step.sync"):
+                # The device has this step: wake the LAST step's streams
+                # now, so their pollers take the lock and the interpreter
+                # while the sync below waits (at the head of this span,
+                # not in one of its own: a turn stays select, dispatch,
+                # sync, fanout, and the host's share of it, select +
+                # dispatch + fanout, does not hold the wake-ups).
+                self._flush_wakes(enqueued=True)
                 # The one intentional sync per decode step (tokens fan
                 # out to streams from host memory).
                 nxt = np.asarray(nxt)  # analyze: ignore[JX002]
         except BaseException as e:
+            # A raise before the enqueue (an armed before_step, a step
+            # that fails to dispatch) makes nobody wait for the next
+            # attempt; after the enqueue this finds the list empty.
+            self._flush_wakes(enqueued=False)
             tracing.finish_span(step_span, "ERROR: step")
             self._log_first_failure("decode step")
             self._step_errors_row += 1
@@ -645,6 +713,7 @@ class LLMEngine:
             self._step_fanout(active, nxt, step_s,
                               max(0.0, t_dispatch - t0), step_span, ds)
         if self.step_throttle_s:
+            self._flush_wakes(enqueued=False)  # nothing follows for a while
             time.sleep(self.step_throttle_s)
         return True
 
@@ -652,7 +721,10 @@ class LLMEngine:
                      host_s: float, step_span: Optional[dict], ds) -> None:
         """After the step's sync: tokens to their streams under the lock,
         then the step's metrics, anatomy and store span (all of it is
-        the ``llm.step.fanout`` device span ``ds``)."""
+        the ``llm.step.fanout`` device span ``ds``). A token is visible
+        to every drain from its append here. A stream that ends with it
+        is woken here (``_finish_locked``); one that goes on decoding is
+        put on ``_wakes`` and woken after the next enqueue (``_loop``)."""
         with self._lock:
             produced = 0
             for slot in active:
@@ -668,9 +740,12 @@ class LLMEngine:
                 produced += 1
                 req.stream.n_tokens += 1
                 req.stream.pending.append([tok])
-                req.stream.event.set()
                 if req.remaining <= 0 or tok == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
+                else:
+                    self._wakes.append(req.stream)
+            # (the list was emptied when this step was enqueued)
+            self.stats_counters["wakes_deferred"] += len(self._wakes)
             self.stats_counters["steps"] += 1
             for i, key in enumerate(self._step_counters):
                 self.stats_counters[key] = self.stats_counters.get(key, 0) \
@@ -829,8 +904,13 @@ class LLMEngine:
         return resp
 
     def llm_next(self, rid: str, timeout_s: float = 2.0) -> dict:
-        """Long-poll one stream: blocks until >=1 chunk (or the terminal
-        transition) is available, up to ``timeout_s``."""
+        """Long-poll one stream: blocks until it is told of a chunk or
+        of the terminal transition, up to ``timeout_s``, then drains
+        whatever is pending. A decode step's token is pending from the
+        step's fan-out on, and its poller is told once the next program
+        is enqueued (``_loop``): a poll may therefore return a token it
+        was not woken for, and the wake-up that follows it no chunk
+        (``done`` false, no error), as after a time-out."""
         with self._lock:
             st = self._streams.get(rid)
         if st is None:

@@ -9,6 +9,7 @@ cluster/ray:// test tears down the module's local runtime, so it runs
 last.
 """
 
+import contextlib
 import dataclasses
 import json
 import threading
@@ -23,9 +24,10 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models import gpt2, llama, nemotron_h
 from ray_tpu.serve import _observability as obs
+from ray_tpu.serve import llm_engine
 from ray_tpu.serve._observability import RequestShedError
 from ray_tpu.serve.llm_engine import LLMEngine
-from ray_tpu.util import failpoints, metrics
+from ray_tpu.util import failpoints, metrics, tracing
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -680,6 +682,348 @@ def test_failpoint_admission_raise_recovers():
     finally:
         failpoints.reset()
         eng.shutdown_engine()
+
+
+# -- enqueue first, wake later (PR 40) ---------------------------------------
+#
+# A decode step's token is visible from its append under the lock; the
+# stream's poller is told after the next enqueue. These hold the order,
+# that no order of set, drain and clear loses or repeats a token, and
+# that nobody's wake-up is stranded.
+
+
+class _LoggedEvent(threading.Event):
+    """A stream's event that notes every ``set`` in the test's log."""
+
+    def __init__(self, log, stream):
+        super().__init__()
+        self._log, self._stream = log, stream
+
+    def set(self):
+        self._log.append(("set", self._stream))
+        super().set()
+
+
+def _log_sets(monkeypatch, log):
+    real = llm_engine._Stream.__init__
+
+    def init(st):
+        real(st)
+        st.event = _LoggedEvent(log, st)
+
+    monkeypatch.setattr(llm_engine._Stream, "__init__", init)
+
+
+class _Gate:
+    """A point at which the loop's thread stops until the test lets it
+    go on (``let``), or for good (``open``)."""
+
+    def __init__(self):
+        self._reached = threading.Semaphore(0)
+        self._go = threading.Semaphore(0)
+        self._open = False
+
+    def stop(self):
+        if not self._open:
+            self._reached.release()
+            assert self._go.acquire(timeout=30)
+
+    def reached(self):
+        assert self._reached.acquire(timeout=30)
+
+    def let(self):
+        self._go.release()
+
+    def open(self):
+        self._open = True
+        self._go.release()
+
+
+def _drain(eng, rid, timeout_s=2.0, took=None):
+    """Poll one stream to its end: (tokens, last response). ``took``
+    collects how long each poll lasted."""
+    out = []
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        t0 = time.monotonic()
+        resp = eng.llm_next(rid, timeout_s=timeout_s)
+        if took is not None:
+            took.append(time.monotonic() - t0)
+        for chunk in resp["chunks"]:
+            out.extend(chunk)
+        if resp["done"]:
+            return out, resp
+    raise AssertionError(f"stream {rid} did not end")
+
+
+@pytest.mark.parametrize("poll_s", [0.001, 2.0],
+                         ids=["polls_time_out", "polls_are_woken"])
+def test_concurrent_streams_get_their_own_tokens_once_in_order(poll_s):
+    """Twice as many streams as slots, a poller thread each, the
+    interpreter switching threads every 10 us: each stream's tokens are
+    the ones it would get alone, in order, none lost, none twice —
+    whether its polls are woken (late) or time out and drain first."""
+    import sys
+
+    cfg, fwd = SERVED["gpt2"]
+    eng = _engine(max_batch=4, max_new_cap=16)
+    asked = {i: ([i + 1, 7, 11, i + 2], 5 + i) for i in range(8)}
+    got, errors = {}, []
+
+    def one(i, rid):
+        try:
+            got[i], last = _drain(eng, rid, timeout_s=poll_s)
+            assert not last["error"] and not last["shed"], last
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        eng.generate(PROMPT, 2)
+        threads = [threading.Thread(
+            target=one, args=(i, eng.llm_submit(prompt, n)))
+            for i, (prompt, n) in asked.items()]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        st = eng.llm_stats()
+        alone = _compiled(fwd, eng.params, cfg, 32)
+    finally:
+        sys.setswitchinterval(interval)
+        eng.shutdown_engine()
+    assert not errors, errors
+    for i, (prompt, n) in asked.items():
+        assert got[i] == _naive_generate(alone, None, prompt, n, None), i
+    assert st["completed"] == 9 and st["errors"] == 0
+    # every token but a request's first and last was a put-off wake-up
+    assert st["wakes_deferred"] == sum(n - 2 for _, n in asked.values())
+
+
+def test_a_steps_streams_are_woken_after_the_next_enqueue(monkeypatch):
+    """Step n's wake-ups follow the return of call n + 1 and nothing
+    else; where an admission comes between them, its FIRST chunk's
+    dispatch, before the turn's other chunks and its sync."""
+    log = []
+    _log_sets(monkeypatch, log)
+    eng = _engine(max_batch=2, prefill_chunk=4, cache_len=64,
+                  max_new_cap=64)
+    try:
+        eng.generate(PROMPT, 2)          # both programs compiled
+        step, chunk = eng._step_fn, eng._prefill_fn
+
+        def logged_step(*a):
+            time.sleep(0.005)            # the second request arrives mid-decode
+            out = step(*a)
+            log.append(("enqueued", "step"))
+            return out
+
+        def logged_chunk(*a):
+            out = chunk(*a)
+            log.append(("enqueued", "chunk"))
+            return out
+
+        eng._step_fn, eng._prefill_fn = logged_step, logged_chunk
+        real_span = tracing.device_span
+
+        @contextlib.contextmanager
+        def span(name, **kw):
+            with real_span(name, **kw) as ds:
+                yield ds
+            if name == "llm.step.fanout":
+                log.append(("owed", list(eng._wakes)))
+            elif name == "llm.prefill.sync":
+                log.append(("synced",))
+
+        monkeypatch.setattr(tracing, "device_span", span)
+        del log[:]
+        a = eng.llm_submit(PROMPT, 40)
+        assert eng.llm_next(a, timeout_s=30.0)["chunks"]   # decoding now
+        b = eng.llm_submit([3, 1, 4, 1, 5, 9, 2, 6], 6)    # two chunks
+        assert len(_drain(eng, b)[0]) == 6
+        assert len(_drain(eng, a)[0]) == 39
+        st = eng.llm_stats()
+    finally:
+        eng.shutdown_engine()
+    # only the loop's thread wrote the log, so it is in program order
+    owed_at = [i for i, e in enumerate(log) if e[0] == "owed" and e[1]]
+    assert len(owed_at) >= 38            # a's steps but its last
+    after_a_chunk = 0
+    for i in owed_at:
+        owed = log[i][1]
+        assert log[i + 1][0] == "enqueued", log[i:i + 3]
+        woken = log[i + 2:i + 2 + len(owed)]
+        assert woken == [("set", st_) for st_ in owed]
+        if log[i + 1][1] == "chunk":
+            # the turn's second chunk and its sync come after the sets
+            rest = [e[:2] for e in log[i + 2 + len(owed):i + 5 + len(owed)]]
+            assert rest[:2] == [("enqueued", "chunk"), ("synced",)], rest
+            after_a_chunk += 1
+    assert after_a_chunk == 1
+    assert st["wakes_deferred"] == st["wakes_after_dispatch"] \
+        == sum(len(log[i][1]) for i in owed_at) == 38 + 4
+    # ... and no stream was told anywhere else: beside those, only the
+    # two first tokens and the two terminal transitions set an event
+    assert sum(e[0] == "set" for e in log) == 38 + 4 + 2 + 2
+
+
+@pytest.mark.parametrize("what", ["last_step", "failpoint", "step_fn",
+                                  "cancel", "shutdown"])
+def test_no_poller_waits_out_its_timeout(what):
+    """Whatever ends or interrupts a step, every ``llm_next`` comes back
+    within a second with tokens or the terminal state: no wake-up is
+    left on the list for a poll's ``timeout_s`` (20 s here) to find."""
+    eng = _engine(max_batch=2, max_new_cap=64)
+    took = {0: [], 1: []}
+    ends, errors = {}, []
+    try:
+        eng.generate(PROMPT, 2)
+        real, calls = eng._step_fn, []
+
+        def slow(*a):
+            calls.append(1)
+            time.sleep(0.003)            # keeps the streams in mid-flight
+            if what == "step_fn" and len(calls) == 6:
+                raise RuntimeError("injected")
+            return real(*a)
+
+        eng._step_fn = slow
+        rids = [eng.llm_submit([i + 2, 5, 8], 40) for i in (0, 1)]
+
+        def one(i):
+            try:
+                ends[i] = _drain(eng, rids[i], timeout_s=20.0, took=took[i])
+            except BaseException as e:  # noqa: BLE001
+                errors.append(repr(e))
+
+        threads = [threading.Thread(target=one, args=(i,)) for i in (0, 1)]
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 30
+        while len(calls) < 4 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        if what == "failpoint":
+            failpoints.arm("serve.llm.before_step", "raise,once")
+        elif what == "cancel":
+            assert eng.llm_cancel(rids[0])
+        elif what == "shutdown":
+            assert eng.shutdown_engine()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        st = eng.llm_stats()
+    finally:
+        failpoints.reset()
+        eng.shutdown_engine()
+    assert not errors, errors
+    assert max(took[0] + took[1]) < 1.0, (took, st)
+    for i in (0, 1):
+        tokens, last = ends[i]
+        if what == "shutdown":
+            assert last["error"] == "engine stopped" and len(tokens) < 40
+        elif what == "cancel" and i == 0:
+            assert last["error"] == "cancelled" and len(tokens) < 40
+        else:
+            assert len(tokens) == 40 and not last["error"], last
+    assert st["errors"] == {"last_step": 0, "failpoint": 1, "step_fn": 1,
+                            "cancel": 1, "shutdown": 2}[what]
+
+
+def test_a_late_wake_up_finds_nothing_and_harms_nothing():
+    """A poll that times out drains the token its stream's wake-up has
+    not announced yet; the late wake-up then ends the next poll at once
+    with no chunk and no error; every token arrives once."""
+    cfg, fwd = SERVED["gpt2"]
+    eng = _engine(max_batch=2)
+    before_call, before_sync = _Gate(), _Gate()
+    try:
+        eng.generate(PROMPT, 2)
+        real = eng._step_fn
+
+        class Unread:
+            """The step's tokens; the loop's sync stops at the gate."""
+
+            def __init__(self, nxt):
+                self.nxt = nxt
+
+            def __array__(self, *_a, **_kw):
+                before_sync.stop()
+                return np.asarray(self.nxt)
+
+        def gated(*a):
+            before_call.stop()
+            nxt, cache = real(*a)
+            return Unread(nxt), cache
+
+        eng._step_fn = gated
+        rid = eng.llm_submit(PROMPT, 8)
+        before_call.reached()             # step 1 not enqueued yet
+        first = eng.llm_next(rid, timeout_s=30.0)
+        before_call.let()
+        before_sync.reached()
+        before_sync.let()                 # step 1 fans out: token 2 pending
+        before_call.reached()             # ... and its wake-up is put off
+        t0 = time.monotonic()
+        second = eng.llm_next(rid, timeout_s=0.05)
+        assert time.monotonic() - t0 >= 0.05          # it was not woken
+        before_call.let()                 # step 2 enqueued: the late set
+        before_sync.reached()             # ... and step 2 not fanned out
+        t0 = time.monotonic()
+        third = eng.llm_next(rid, timeout_s=20.0)
+        assert time.monotonic() - t0 < 1.0            # woken, for nothing
+        before_call.open()
+        before_sync.open()
+        rest, last = _drain(eng, rid)
+        st = eng.llm_stats()
+        alone = _compiled(fwd, eng.params, cfg, 16)
+    finally:
+        before_call.open()
+        before_sync.open()
+        eng.shutdown_engine()
+    want = _naive_generate(alone, None, PROMPT, 8, None)
+    assert first["chunks"] == [want[:1]] and second["chunks"] == [want[1:2]]
+    assert third == {"chunks": [], "done": False, "shed": None,
+                     "error": None}
+    assert rest == want[2:] and not last["error"]
+    assert st["wakes_deferred"] == st["wakes_after_dispatch"] == 6
+
+
+@pytest.mark.parametrize("how, after_dispatch", [
+    ("plain", 9), ("throttled", 0), ("a_step_fails", 7)])
+def test_wake_counters_count_exactly(how, after_dispatch):
+    """Two requests of 5 and 8 tokens in two slots: every token but a
+    request's first (the prefill's) and last (the terminal transition's)
+    is a put-off wake-up, 3 + 6; all of them follow an enqueue unless
+    the engine sleeps between steps (none does) or a step fails before
+    its enqueue (the two streams decoding then are woken at the raise)."""
+    eng = _engine(max_batch=2, prefill_rows=2,
+                  step_throttle_s=0.001 if how == "throttled" else 0.0)
+    try:
+        eng.generate(PROMPT, 2)
+        before = eng.llm_stats()
+        if how == "a_step_fails":
+            real, raised = eng._step_fn, []
+
+            def flaky(*a):
+                # once, with both streams owed the last step's wake-up
+                if not raised and len(eng._wakes) == 2:
+                    raised.append(1)
+                    raise RuntimeError("injected")
+                return real(*a)
+
+            eng._step_fn = flaky
+        rids = eng.llm_submit_many([
+            {"tokens": [1, 2, 3], "max_tokens": 5},
+            {"tokens": [4, 5, 6, 7], "max_tokens": 8}])
+        assert [len(_drain(eng, rid)[0]) for rid in rids] == [5, 8]
+        st = eng.llm_stats()
+    finally:
+        eng.shutdown_engine()
+    assert st["wakes_deferred"] - before["wakes_deferred"] == 9
+    assert st["wakes_after_dispatch"] - before["wakes_after_dispatch"] \
+        == after_dispatch
 
 
 # -- streaming transports ---------------------------------------------------
