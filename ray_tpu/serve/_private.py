@@ -979,8 +979,9 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
     When the caller traces (``trace_ctx`` in the request meta), the
     whole stream is one ``serve.stream`` span: downstream hops — the
     replica's llm_submit span, the engine's queue/prefill/decode spans
-    — re-parent under it, and the first real token stamps the
-    client-observed TTFT on its attributes.
+    — re-parent under it, the first real token stamps the
+    client-observed TTFT on its attributes, and the stream's end what its
+    polls cost (``_stream_call_impl``'s tally).
 
     ``backend`` defaults to this process's backend; the ``ray://``
     proxy passes its own ClusterBackend explicitly (its process-global
@@ -989,7 +990,7 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
     trace_parent = meta.get("trace_ctx")
     if not trace_parent:
         yield from _stream_call_impl(deployment_name, args, kwargs, meta,
-                                     backend, poll_s, keepalive_every)
+                                     backend, poll_s, keepalive_every, None)
         return
     # Manual span (start_span/finish_span): the generator frame
     # interleaves with the consumer's code on one thread, so a
@@ -1004,10 +1005,11 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
     status = "OK"
     t0 = time.monotonic()
     first = True
+    chunks = _stream_call_impl(
+        deployment_name, args, kwargs, meta, backend, poll_s,
+        keepalive_every, None if span is None else span["attributes"])
     try:
-        for chunk in _stream_call_impl(deployment_name, args, kwargs,
-                                       meta, backend, poll_s,
-                                       keepalive_every):
+        for chunk in chunks:
             if first and span is not None and not (
                     isinstance(chunk, dict)
                     and chunk.get("__stream_keepalive__")):
@@ -1019,12 +1021,22 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
         status = f"ERROR: {type(e).__name__}"
         raise
     finally:
+        chunks.close()  # a consumer that left early: the tally is written
         tracing.finish_span(span, status)
 
 
 def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
                       request_meta: Optional[dict], backend,
-                      poll_s: float, keepalive_every: Optional[float]):
+                      poll_s: float, keepalive_every: Optional[float],
+                      tally: Optional[dict]):
+    """The stream itself. For a traced stream (``tally`` is its span's
+    attributes) what its polls cost is kept in locals, two clock reads
+    and three additions a poll, and written as the generator ends:
+    ``polls`` of ``llm_next``, the round trips' sum ``rpc_ns`` and the
+    sum ``held_ns`` of what the engine said it spent inside each.
+    Durations on both ends, so no clock is shared:
+    ``(rpc_ns - held_ns) / polls`` is one routed poll's way there and
+    back. An untraced stream pays a branch a poll."""
     if backend is None:
         from ray_tpu._private import worker as _worker
 
@@ -1077,36 +1089,49 @@ def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
     else:
         rid = resp
     last_yield = time.monotonic()
-    while True:
-        # Polls go meta-less (the legacy bare-result path): a long-poll
-        # is transport, not a request — it must not enter the request
-        # histograms or be shed by the replica's arrival check.
-        r = _stream_rpc(backend, aid, "llm_next", (rid,),
-                        {"timeout_s": poll_s}, None,
-                        timeout=poll_s + _STREAM_RPC_SLACK_S)
-        chunks = r.get("chunks") or ()
-        for chunk in chunks:
-            yield chunk
-        if chunks:
-            last_yield = time.monotonic()
-        elif keepalive_every is not None \
-                and time.monotonic() - last_yield >= keepalive_every:
-            # Deep-queued stream: nothing to say yet, but the consumer's
-            # transport (the ray:// proxy RPC) needs frames to not time
-            # out while the request waits for a slot.
-            yield STREAM_KEEPALIVE
-            last_yield = time.monotonic()
-        if r.get("done"):
-            shed = r.get("shed")
-            if shed:
-                raise RequestShedError(
-                    f"stream to {deployment_name!r} shed mid-decode",
-                    reason=shed)
-            err = r.get("error")
-            if err:
-                raise RuntimeError(
-                    f"stream to {deployment_name!r} failed: {err}")
-            return
+    timed = tally is not None
+    polls = rpc_ns = held_ns = t0 = 0
+    try:
+        while True:
+            # Polls go meta-less (the legacy bare-result path): a
+            # long-poll is transport, not a request — it must not enter
+            # the request histograms or be shed by the replica's arrival
+            # check.
+            if timed:
+                t0 = time.perf_counter_ns()
+            r = _stream_rpc(backend, aid, "llm_next", (rid,),
+                            {"timeout_s": poll_s}, None,
+                            timeout=poll_s + _STREAM_RPC_SLACK_S)
+            if timed:
+                rpc_ns += time.perf_counter_ns() - t0
+                polls += 1
+                held_ns += r.get("held_ns", 0)
+            chunks = r.get("chunks") or ()
+            for chunk in chunks:
+                yield chunk
+            if chunks:
+                last_yield = time.monotonic()
+            elif keepalive_every is not None \
+                    and time.monotonic() - last_yield >= keepalive_every:
+                # Deep-queued stream: nothing to say yet, but the
+                # consumer's transport (the ray:// proxy RPC) needs frames
+                # to not time out while the request waits for a slot.
+                yield STREAM_KEEPALIVE
+                last_yield = time.monotonic()
+            if r.get("done"):
+                shed = r.get("shed")
+                if shed:
+                    raise RequestShedError(
+                        f"stream to {deployment_name!r} shed mid-decode",
+                        reason=shed)
+                err = r.get("error")
+                if err:
+                    raise RuntimeError(
+                        f"stream to {deployment_name!r} failed: {err}")
+                return
+    finally:
+        if timed:
+            tally.update(polls=polls, rpc_ns=rpc_ns, held_ns=held_ns)
 
 
 class DeploymentHandle:

@@ -59,7 +59,6 @@ from typing import Dict, List, Optional
 from ray_tpu.serve import _observability as _obs
 from ray_tpu.serve._observability import RequestShedError
 from ray_tpu.util import failpoints
-from ray_tpu.util import goodput as _goodput
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import tracing
 
@@ -72,15 +71,22 @@ _MAX_STEP_ERRORS = 3
 # Abandoned-stream reap: a DONE stream nobody polls for this long is
 # dropped (the bench's fire-and-forget shed probes must not accumulate).
 _STREAM_TTL_S = 120.0
+# Upper edges of ``deliver_lag_hist``'s first seven buckets, in ms (the
+# eighth holds the rest): each twice the one before, so a lag's bucket is
+# the bit length of its count of quarter milliseconds.
+DELIVER_LAG_EDGES_MS = (0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0)
+_LAG_TOP = len(DELIVER_LAG_EDGES_MS)
 
 
 class _Stream:
     """One request's token stream: per-step chunks pending delivery plus
     the terminal state. ``event`` is set whenever there is something new
-    to deliver (chunks or the terminal transition)."""
+    to deliver (chunks or the terminal transition). ``visible_ns`` is
+    when ``pending`` last turned from empty to non-empty, ``last_poll``
+    the last drain, both ``time.perf_counter_ns()``."""
 
     __slots__ = ("pending", "done", "shed", "error", "delivered",
-                 "last_poll", "event", "n_tokens")
+                 "last_poll", "event", "n_tokens", "visible_ns")
 
     def __init__(self):
         self.pending: List[List[int]] = []
@@ -88,9 +94,10 @@ class _Stream:
         self.shed: Optional[str] = None
         self.error: Optional[str] = None
         self.delivered = False
-        self.last_poll = time.monotonic()
+        self.last_poll = time.perf_counter_ns()
         self.event = threading.Event()
         self.n_tokens = 0
+        self.visible_ns = 0
 
 
 class _Request:
@@ -377,6 +384,8 @@ class LLMEngine:
         # returns no chunk, as a time-out does, and ``stream_call`` goes
         # round again).
         self._wakes: List[_Stream] = []
+        # when the fan-out that filled ``_wakes`` made its tokens visible
+        self._fanout_ns = 0
         self._last_reap = time.monotonic()
         self.stats_counters = {
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
@@ -396,7 +405,27 @@ class LLMEngine:
             # many of them were then set with a program on the device;
             # the rest were set because nothing followed (a step that
             # raised before its enqueue, a throttle, the idle wait).
+            # Both are counted where the wake-ups are set, in one write
+            # (``_flush_wakes``), so no snapshot reads them a step apart.
             "wakes_deferred": 0, "wakes_after_dispatch": 0,
+            # The token's way out. Long-polls served (``llm_next``) and
+            # those that came back with no chunk and ``done`` false (a
+            # time-out, or the wake-up for a token an earlier poll took).
+            "next_calls": 0, "next_empty": 0,
+            # How long the drained chunks lay in ``pending``: drain time
+            # minus ``visible_ns``, summed and in the buckets of
+            # DELIVER_LAG_EDGES_MS (``llm_stats()`` adds ``deliver_chunks``,
+            # the buckets' sum). A drain that takes several chunks charges
+            # each the oldest's lag (one clock slot a stream): exact where
+            # a poll takes one, as a poller a stream does, an upper bound
+            # for a lane that batches.
+            "deliver_lag_ns": 0,
+            "deliver_lag_hist": [0] * (len(DELIVER_LAG_EDGES_MS) + 1),
+            # The part of that lag the loop chose: over the put-off
+            # wake-ups whose token still waited when they were set, set
+            # time minus the fan-out's. The rest of ``deliver_lag_ns``
+            # is the interpreter, the lock and the poller's thread.
+            "wake_defer_ns": 0,
         }
         self._loop_thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine-loop")
@@ -454,11 +483,22 @@ class LLMEngine:
         if not wakes:
             return
         self._wakes = []
-        if enqueued:
-            # the loop's thread is this key's only writer
-            self.stats_counters["wakes_after_dispatch"] += len(wakes)
+        waited = time.perf_counter_ns() - self._fanout_ns
+        waiting = 0
         for st in wakes:
+            # still pending after the clock was read: its drain comes
+            # later than this, so its lag holds at least ``waited``
+            if st.pending:
+                waiting += 1
             st.event.set()
+        # The loop's thread is these keys' only writer, and writes them
+        # in ONE call that no other thread's copy can fall into.
+        c = self.stats_counters
+        c.update(
+            wakes_deferred=c["wakes_deferred"] + len(wakes),
+            wakes_after_dispatch=c["wakes_after_dispatch"]
+            + (len(wakes) if enqueued else 0),
+            wake_defer_ns=c["wake_defer_ns"] + waiting * waited)
 
     def _push_queued_locked(self, req: _Request):
         """Heap key = (deadline, seq): admission prefers deadline slack
@@ -585,6 +625,7 @@ class LLMEngine:
         with tracing.device_span("llm.prefill.fanout"):
             _obs.record_decode_tokens(self._dep, len(batch))
             with self._lock:
+                visible_ns = time.perf_counter_ns()
                 c = self.stats_counters
                 c["prefill_batches"] += 1
                 c["prefill_rows_real"] += len(batch)
@@ -605,6 +646,7 @@ class LLMEngine:
                     c["admitted"] += 1
                     c["tokens_out"] += 1
                     req.stream.n_tokens += 1
+                    req.stream.visible_ns = visible_ns  # its first chunk
                     req.stream.pending.append([tok])
                     req.stream.event.set()
                     # TTFT: submit -> first token available for delivery.
@@ -674,8 +716,6 @@ class LLMEngine:
                     self.params, self._cache,
                     self._jnp.asarray(self._tokens),
                     self._jnp.asarray(self._pos))
-            # Anatomy host phase ends when the async dispatch returns.
-            t_dispatch = time.perf_counter()
             with tracing.device_span("llm.step.sync"):
                 # The device has this step: wake the LAST step's streams
                 # now, so their pollers take the lock and the interpreter
@@ -710,23 +750,24 @@ class LLMEngine:
         step_s = time.perf_counter() - t0
         self._init_s.setdefault("first_step", step_s)
         with tracing.device_span("llm.step.fanout") as ds:
-            self._step_fanout(active, nxt, step_s,
-                              max(0.0, t_dispatch - t0), step_span, ds)
+            self._step_fanout(active, nxt, step_s, step_span, ds)
         if self.step_throttle_s:
             self._flush_wakes(enqueued=False)  # nothing follows for a while
             time.sleep(self.step_throttle_s)
         return True
 
     def _step_fanout(self, active: List[int], nxt, step_s: float,
-                     host_s: float, step_span: Optional[dict], ds) -> None:
+                     step_span: Optional[dict], ds) -> None:
         """After the step's sync: tokens to their streams under the lock,
-        then the step's metrics, anatomy and store span (all of it is
-        the ``llm.step.fanout`` device span ``ds``). A token is visible
+        then the step's metrics and store span (all of it is the
+        ``llm.step.fanout`` device span ``ds``). A token is visible
         to every drain from its append here. A stream that ends with it
         is woken here (``_finish_locked``); one that goes on decoding is
         put on ``_wakes`` and woken after the next enqueue (``_loop``)."""
         with self._lock:
             produced = 0
+            # the one clock read of this fan-out's tokens
+            self._fanout_ns = visible_ns = time.perf_counter_ns()
             for slot in active:
                 req = self._slot_req[slot]
                 if req is None:
@@ -739,13 +780,14 @@ class LLMEngine:
                 req.remaining -= 1
                 produced += 1
                 req.stream.n_tokens += 1
+                if not req.stream.pending:
+                    req.stream.visible_ns = visible_ns
                 req.stream.pending.append([tok])
                 if req.remaining <= 0 or tok == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
                 else:
+                    # (the list was emptied when this step was enqueued)
                     self._wakes.append(req.stream)
-            # (the list was emptied when this step was enqueued)
-            self.stats_counters["wakes_deferred"] += len(self._wakes)
             self.stats_counters["steps"] += 1
             for i, key in enumerate(self._step_counters):
                 self.stats_counters[key] = self.stats_counters.get(key, 0) \
@@ -763,17 +805,6 @@ class LLMEngine:
         ds.set_metadata(tokens=produced)
         _obs.record_decode_step(self._dep, step_s, len(active), produced)
         _obs.record_decode_itl(self._dep, itl, produced)
-        # Step anatomy: host = dispatch wall, compute = the sync wall
-        # after it (the step's np.asarray IS the device wait); a
-        # single-replica engine has no gang barrier, so sync is 0 and
-        # host + compute partition step_s exactly.
-        try:
-            _goodput.record_anatomy(
-                f"serve:{self._dep}", 0,
-                {"data_wait": 0.0, "host": host_s,
-                 "compute": max(0.0, step_s - host_s), "sync": 0.0})
-        except Exception:
-            pass
         if step_span is not None:
             step_span["attributes"]["tokens"] = produced
             tracing.finish_span(step_span)
@@ -824,7 +855,7 @@ class LLMEngine:
 
     def _reap_streams(self):
         self._last_reap = time.monotonic()
-        cutoff = time.monotonic() - _STREAM_TTL_S
+        cutoff = time.perf_counter_ns() - int(_STREAM_TTL_S * 1e9)
         with self._lock:
             # Fully-delivered streams leave the table at delivery
             # (_drain_locked); only DONE streams nobody polls linger.
@@ -893,9 +924,17 @@ class LLMEngine:
         return [self.llm_submit(r.get("tokens"), r.get("max_tokens"),
                                 r.get("deadline_ts")) for r in requests]
 
-    def _drain_locked(self, rid: str, st: _Stream) -> dict:
+    def _drain_locked(self, rid: str, st: _Stream, now_ns: int) -> dict:
+        """Hand out what is pending (caller holds the lock and read the
+        clock, once for all it drains) and count how long it lay there."""
         chunks, st.pending = st.pending, []
-        st.last_poll = time.monotonic()
+        st.last_poll = now_ns
+        if chunks:
+            n, lag = len(chunks), now_ns - st.visible_ns
+            c = self.stats_counters
+            c["deliver_lag_ns"] += n * lag
+            b = (lag // 250_000).bit_length()
+            c["deliver_lag_hist"][b if b < _LAG_TOP else _LAG_TOP] += n
         resp = {"chunks": chunks, "done": st.done, "shed": st.shed,
                 "error": st.error}
         if st.done and not st.pending:
@@ -910,30 +949,55 @@ class LLMEngine:
         step's fan-out on, and its poller is told once the next program
         is enqueued (``_loop``): a poll may therefore return a token it
         was not woken for, and the wake-up that follows it no chunk
-        (``done`` false, no error), as after a time-out."""
+        (``done`` false, no error), as after a time-out. ``held_ns`` is
+        what this call spent in here up to its drain, the wait included
+        (the drain's clock read is the last one: a poll pays two): a
+        caller that times the round trip owes the rest to the way here
+        and back."""
+        t0 = time.perf_counter_ns()
         with self._lock:
             st = self._streams.get(rid)
         if st is None:
             return {"chunks": [], "done": True, "shed": None,
-                    "error": f"unknown stream {rid!r}"}
+                    "error": f"unknown stream {rid!r}", "held_ns": 0}
         st.event.wait(max(0.0, float(timeout_s)))
+        if not tracing.profiling():
+            return self._next_drain(rid, st, t0)
+        # While a profile is taken, the poller's work on its clock: from
+        # the wait's return on, and not around it (a span over a blocked
+        # thread would own every idle gap of the device).
+        with tracing.device_span("llm.next.drain") as ds:
+            resp = self._next_drain(rid, st, t0)
+            ds.set_metadata(chunks=len(resp["chunks"]))
+        return resp
+
+    def _next_drain(self, rid: str, st: _Stream, t0: int) -> dict:
+        """``llm_next`` after its wait: the drain under the lock, its
+        counts, and ``held_ns`` since the call's entry at ``t0``."""
         with self._lock:
-            resp = self._drain_locked(rid, st)
+            now_ns = time.perf_counter_ns()
+            resp = self._drain_locked(rid, st, now_ns)
+            c = self.stats_counters
+            c["next_calls"] += 1
             if not st.done:
                 st.event.clear()
+                if not resp["chunks"]:
+                    c["next_empty"] += 1
+        resp["held_ns"] = now_ns - t0
         return resp
 
     def llm_poll(self, rids: List[str]) -> Dict[str, dict]:
         """Non-blocking batched drain (the bench's collector lane)."""
         out = {}
         with self._lock:
+            now_ns = time.perf_counter_ns()
             for rid in rids:
                 st = self._streams.get(rid)
                 if st is None:
                     out[rid] = {"chunks": [], "done": True, "shed": None,
                                 "error": f"unknown stream {rid!r}"}
                 else:
-                    out[rid] = self._drain_locked(rid, st)
+                    out[rid] = self._drain_locked(rid, st, now_ns)
         return out
 
     def llm_cancel(self, rid: str) -> bool:
@@ -1008,6 +1072,9 @@ class LLMEngine:
             active = sum(1 for r in self._slot_req if r is not None)
             queued = self._n_queued
             c = dict(self.stats_counters)
+            c["deliver_lag_hist"] = list(c["deliver_lag_hist"])
+        # chunks drained by either lane: every one is in a bucket
+        c["deliver_chunks"] = sum(c["deliver_lag_hist"])
         steps = c["steps"]
         return {
             "model": self.model,
@@ -1062,10 +1129,4 @@ class LLMEngine:
         if not self._loop_thread.is_alive():
             self._fail_unserved("engine stopped")
         _metrics.retract_loop_series(["llm.engine"])
-        # The engine's per-step anatomy gauges (phase seconds)
-        # must not outlive it on the scrape (LC001 discipline).
-        try:
-            _goodput.retract_trial(f"serve:{self._dep}")
-        except Exception:
-            pass
         return not self._loop_thread.is_alive()

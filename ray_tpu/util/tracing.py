@@ -197,6 +197,23 @@ def device_span(name: str, **attrs):
     return jax.profiler.TraceAnnotation(name, **attrs)
 
 
+_profiler_on = None  # jax's own switch, bound at the first call with jax
+
+
+def profiling() -> bool:
+    """Is a profile being taken, so that a :func:`device_span` would be
+    recorded? For a path that runs thousands of times a second, where
+    even an inactive annotation's context manager shows (a poll of
+    ``llm_next``): a branch on this costs a tenth of a microsecond."""
+    global _profiler_on
+    if _profiler_on is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return False
+        _profiler_on = jax.profiler.TraceAnnotation.is_enabled
+    return _profiler_on()
+
+
 def _sampled(parent: Optional[dict]) -> bool:
     """Is a span with this explicit ``parent`` recorded? Roots obey the
     switch; a span under a parent follows its parent's trace."""

@@ -154,6 +154,15 @@ def test_engine_records_a_request_with_context_and_nothing_after():
 PREFILL = ["llm.prefill.dispatch", "llm.prefill.sync", "llm.prefill.fanout"]
 STEP = ["llm.step.select", "llm.step.dispatch", "llm.step.sync",
         "llm.step.fanout"]
+# what a caller's thread annotates inside ``llm_next``
+# (tests/test_llm_delivery.py); everything else is the loop's
+DRAINS = ("llm.next.drain",)
+
+
+def _loop_events(events):
+    """The loop's own phases, and the lines the drains were on."""
+    return ([e for e in events if e[0] not in DRAINS],
+            {e[4] for e in events if e[0] in DRAINS})
 
 
 def test_loop_phases_in_a_profile_in_order_with_attributes(tmp_path):
@@ -170,8 +179,10 @@ def test_loop_phases_in_a_profile_in_order_with_attributes(tmp_path):
         after = time.time_ns()
     finally:
         eng.shutdown_engine()
+    events, drain_lines = _loop_events(events)
     loop_lines = {e[4] for e in events}
     assert len(loop_lines) == 1         # every phase is on the loop thread
+    assert drain_lines and not drain_lines & loop_lines    # the caller's
     names = [e[0] for e in events]
     # never two open at once: each ends before the next starts
     assert all(a[2] <= b[1] for a, b in zip(events, events[1:]))
@@ -216,6 +227,7 @@ def test_no_phase_is_left_open_when_a_step_raises(tmp_path, how):
         assert eng.llm_stats()["errors"] == 1
     finally:
         eng.shutdown_engine()
+    events, _ = _loop_events(events)
     names = [e[0] for e in events]
     # the profile holds only spans that ended, and they never overlap:
     # the raise closed whatever was open

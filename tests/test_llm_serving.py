@@ -985,7 +985,8 @@ def test_a_late_wake_up_finds_nothing_and_harms_nothing():
     want = _naive_generate(alone, None, PROMPT, 8, None)
     assert first["chunks"] == [want[:1]] and second["chunks"] == [want[1:2]]
     assert third == {"chunks": [], "done": False, "shed": None,
-                     "error": None}
+                     "error": None, "held_ns": third["held_ns"]}
+    assert 0 < third["held_ns"] < 1e9     # what the poll spent in the engine
     assert rest == want[2:] and not last["error"]
     assert st["wakes_deferred"] == st["wakes_after_dispatch"] == 6
 
