@@ -378,13 +378,18 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 # between steps, so these functions are shape-stable by construction:
 #
 # * ``gpt2_init_cache``   — slot-indexed ring KV-cache in device memory,
-#   ``[n_layer, slots, cache_len, n_head, head_dim]`` in the activation
-#   dtype (bf16 by default — no fp32 cache copy ever materializes);
+#   ``[n_layer, slots, cache_len, W]`` in the activation dtype (bf16 by
+#   default — no fp32 cache copy ever materializes): a token's row is its
+#   heads MERGED and padded with zero columns to whole 128-lane tiles
+#   (``ops/attention.merged_row_width``; XL: 1600 -> 1664), because only
+#   then does the TPU's compiler keep a row contiguous, and a row written
+#   is 13 tiles and not 4,800 (PR 42);
 # * ``gpt2_prefill_chunk`` — the second jitted shape: ``[rows, C]`` tokens
-#   of a prompt at a start offset, writing their K/V into the slot's cache
-#   rows, attending over what earlier chunks left there, and giving the
-#   logits of the chunk's last real position (the last chunk's sample the
-#   FIRST token); ``gpt2_prefill`` is whole prompts through it;
+#   of a prompt at a start offset, attending over what earlier chunks left
+#   in the slot's cache rows and over the chunk's own K/V, writing those
+#   rows there, and giving the logits of the chunk's last real position (the
+#   last chunk's sample the FIRST token); ``gpt2_prefill`` is whole prompts
+#   through it;
 # * ``gpt2_decode_step``  — one token for every slot: attend over the
 #   valid cache window and this token's own K/V, next-token logits, and
 #   this token's K/V written at the slot's ring cursor.
@@ -392,19 +397,26 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 # The stacked cache never travels through the layer loop as ``lax.scan``'s
 # ``xs -> ys``; a step writes only its new rows, in place in the buffer
 # the engine donates (the contract and its reasons: ``ops/attention.py``).
-# Which side of the loop writes them was settled per program by reading
-# what the TPU's compiler makes of it (``tests/test_serving_programs_v5e.py``):
+# BOTH programs read the cache as it was and write after the layer loop,
+# which is what lets the compiler keep the row-minor layout from the entry
+# through the loop to the donated output in both, so that neither re-lays
+# the shared buffer out (``tests/test_serving_programs_v5e.py`` reads what
+# the TPU's compiler makes of each):
 #
 # * decode reads each layer's block as a read-only ``xs``, the loop's
-#   ``ys`` are only the new rows ``[n_layer, S, H, hd]``, and one
+#   ``ys`` are only the new rows ``[n_layer, S, W]``, and one
 #   ``cache_write`` after the loop puts them at the cursors; attention
 #   takes the new token's score and value beside the old window
 #   (``cached_decode_attention``), so it is the same softmax over the same
-#   keys as a write-then-read. (With the cache in the loop's carry the
-#   compiler re-laid the whole cache out, padded, for the one-row writes.)
-# * a prefill chunk's loop carries the stacked cache: each layer writes
-#   its ``[C, H, hd]`` row blocks in place and then cuts the slot's key
-#   window (not a layer's block) out of the stack it carries.
+#   keys as a write-then-read.
+# * a prefill chunk's loop cuts the slot's rows ``< start`` (a window, not
+#   a layer's block) out of the stack, takes the chunk's own ``[C, W]``
+#   rows beside them, causal among themselves, in one softmax over both
+#   (``merged_chunk_attention``), hands the rows out as ``ys`` ``[n_layer,
+#   R, C, W]``, and one ``cache_write`` after the loop puts the blocks at
+#   ``start``. (A loop that wrote the rows into the cache it carried and
+#   then cut the window out of the same carry made the compiler re-lay the
+#   whole cache out, four cache-sized copies a chunk.)
 #
 # Ring semantics: the write cursor is ``pos % cache_len`` and the
 # attention mask covers ``min(pos + 1, cache_len)`` entries — a
@@ -415,8 +427,12 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 
 def gpt2_init_cache(cfg: GPT2Config, slots: int, cache_len: int) -> Params:  # decode-path
     """Ring KV-cache for ``slots`` concurrent sequences (bf16 by default:
-    the cache rides ``cfg.dtype``, never fp32)."""
-    shape = (cfg.n_layer, slots, cache_len, cfg.n_head, cfg.head_dim)
+    the cache rides ``cfg.dtype``, never fp32), a token's row merged and
+    lane-padded; the pad columns are zeros and stay zeros."""
+    from ray_tpu.ops.attention import merged_row_width
+
+    shape = (cfg.n_layer, slots, cache_len,
+             merged_row_width(cfg.n_head, cfg.head_dim))
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -469,8 +485,8 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
         with jax.named_scope("attn_proj"):
             qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            k_new = k_new.reshape(s, h, hd).astype(k_cache.dtype)
-            v_new = v_new.reshape(s, h, hd).astype(v_cache.dtype)
+            k_new = _merged_row(k_new, k_cache)
+            v_new = _merged_row(v_new, v_cache)
         with jax.named_scope("attn"):
             attn = cached_decode_attention(
                 q.reshape(s, h, hd), k_cache, v_cache, k_new, v_new,
@@ -493,6 +509,14 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
             "sd,vd->sv", x, params["wte"].astype(dt),
             preferred_element_type=jnp.float32)
     return logits, cache
+
+
+def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
+    """A projection's K or V rows [..., D] as the cache holds them: its
+    type, zeros in the pad columns."""
+    from ray_tpu.ops.attention import merged_rows
+
+    return merged_rows(rows.astype(cache.dtype), cache.shape[-1])
 
 
 def _mlp_block(x: jax.Array, p: Params, dt) -> jax.Array:
@@ -533,35 +557,33 @@ def gpt2_prefill_chunk(params: Params, cache: Params, tokens: jax.Array,
                    cfg.seq_len - 1)
     with jax.named_scope("embed"):
         x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[pos]
-    from ray_tpu.ops.attention import (cache_write_prompt,
-                                       cached_chunk_attention)
+    from ray_tpu.ops.attention import (cache_write_chunk,
+                                       merged_chunk_attention)
 
-    def block(carry, layer):
-        x, k_all, v_all = carry  # the stacked cache, written in place
-        p, i = layer
+    def block(x, layer):
+        p, i = layer  # the stacked cache is read as it was: rows out as ys
         with jax.named_scope("ln"):
             y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         with jax.named_scope("attn_proj"):
             qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
             q, k_, v_ = jnp.split(qkv, 3, axis=-1)
-        with jax.named_scope("cache_write"):
-            k_all = cache_write_prompt(
-                k_all, i, k_.reshape(r, c, h, hd), slots, start)
-            v_all = cache_write_prompt(
-                v_all, i, v_.reshape(r, c, h, hd), slots, start)
+            k_ = _merged_row(k_, cache["k"])
+            v_ = _merged_row(v_, cache["v"])
         with jax.named_scope("attn"):
-            attn = cached_chunk_attention(
-                q.reshape(r, c, h, hd), k_all, v_all, i, slots, start,
-                window)
+            attn = merged_chunk_attention(
+                q.reshape(r, c, h, hd), cache["k"], cache["v"], k_, v_, i,
+                slots, start, window)
         with jax.named_scope("attn_proj"):
             x = x + attn.reshape(r, c, d) @ p["attn_out_w"].astype(dt) \
                 + p["attn_out_b"].astype(dt)
         x = _mlp_block(x, p, dt)
-        return (x, k_all, v_all), None
+        return x, (k_, v_)
 
-    (x, k_all, v_all), _ = jax.lax.scan(
-        block, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(cfg.n_layer)))
+    x, (k_rows, v_rows) = jax.lax.scan(
+        block, x, (params["blocks"], jnp.arange(cfg.n_layer)))
+    with jax.named_scope("cache_write"):
+        cache = {"k": cache_write_chunk(cache["k"], k_rows, slots, start),
+                 "v": cache_write_chunk(cache["v"], v_rows, slots, start)}
     with jax.named_scope("ln"):
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     with jax.named_scope("head"):
@@ -569,7 +591,7 @@ def gpt2_prefill_chunk(params: Params, cache: Params, tokens: jax.Array,
         logits = jnp.einsum(
             "rd,vd->rv", last, params["wte"].astype(dt),
             preferred_element_type=jnp.float32)
-    return logits, {"k": k_all, "v": v_all}
+    return logits, cache
 
 
 def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,

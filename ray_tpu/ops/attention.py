@@ -100,21 +100,51 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
 # -- KV cache of the serving path ---------------------------------------------
 #
 # Shared by the GPT-2 and Llama decode APIs (``models/gpt2.py`` /
-# ``models/llama.py``): the head-count axis differs (full vs GQA
-# ``n_kv_head``) but the contract is identical, so it lives here once.
+# ``models/llama.py``) and the hybrids: the head-count axis differs (full vs
+# GQA ``n_kv_head``) but the contract is identical, so it lives here once.
 #
-# The cache is ONE stacked array ``[n_layer, S, L, H, hd]`` per K and V,
-# donated by the engine. Inside the layer loop it is only read; the only
-# bytes written in a step are the new rows, and they go into the stacked
-# buffer in place: never cut a layer's ``[S, L, H, hd]`` block out of the
-# stack, write into it and put it back (the cache as ``lax.scan``'s
-# ``xs -> ys``), which copies the whole cache twice a step (PERF.md
-# section 6, PR 25). Both writes are static-count ``dynamic_update_slice``s
-# whose update is row-sized. On the TPU the stacked cache lies with
-# ``cache_len`` minor-most (the runtime's compact layout where ``H x hd``
-# would pad), and XLA keeps that layout through these updates; a scatter,
-# or the same updates under a ``fori_loop``, make it re-lay out the whole
-# cache around the write.
+# The cache is ONE stacked array per K and V, donated by the engine. Inside
+# the layer loop it is only read; the only bytes written in a step are the
+# new rows, and they go into the stacked buffer in place: never cut a
+# layer's block out of the stack, write into it and put it back (the cache
+# as ``lax.scan``'s ``xs -> ys``), which copies the whole cache twice a step
+# (PERF.md section 6, PR 25). Both writes are static-count
+# ``dynamic_update_slice``s whose update is row-sized; a scatter, or the same
+# updates under a ``fori_loop``, make XLA re-lay out the whole cache around
+# the write. What a row is differs by the cache's rank:
+#
+# * rank 5, ``[n_layer, S, L, G, hd]`` (Llama, the hybrids' few attention
+#   layers, the latent rows): heads apart. Where ``G x hd`` is no whole
+#   number of 128-lane tiles the TPU's compiler lays it out with ``L``
+#   minor-most, and one token's row is then ``G x hd`` tiles of 4 KB.
+# * rank 4, ``[n_layer, S, L, W]`` (GPT-2, PR 42): a token's row MERGED, all
+#   heads side by side, and padded with zero columns to whole lane tiles,
+#   ``W = merged_row_width(H, hd)``. Only with the pad does the compiler
+#   keep the row minor-most (``{3,2,1,0}``; at XL 1600 columns are 12.5
+#   tiles and ``L`` goes minor again, tests/test_serving_programs_v5e.py), so
+#   that a row is ``W / 128`` tiles. The pad belongs to no head: queries
+#   hold zeros there and a head's columns are picked out of the sums. The
+#   layout survives a program only if the program reads the cache BEFORE it
+#   writes it: a loop that writes rows into the cache it carries and then
+#   reads a window from the same carry made the compiler re-lay the whole
+#   cache out and back. So both merged-row programs read the cache as it
+#   was, take their own new rows beside it, and write after the layer loop
+#   (``cache_write_token`` / ``cache_write_chunk``).
+
+
+def merged_row_width(n_head: int, head_dim: int) -> int:
+    """Columns of a merged K or V row: the heads side by side, padded to
+    whole 128-lane tiles. A row that fits inside one tile (the toy sizes)
+    has no tile boundary to straddle and is left as it is, so a toy
+    cache's bytes are still its shape's."""
+    d = n_head * head_dim
+    return d if d <= 128 else -(-d // 128) * 128
+
+
+def merged_rows(x: jax.Array, w: int) -> jax.Array:
+    """[..., H * hd], the heads side by side as a projection gives them,
+    -> [..., W]: zeros in the pad columns."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, w - x.shape[-1])])
 
 
 # decode-path  # jax-hot-path: the KV cache stays in the activation dtype
@@ -123,13 +153,31 @@ def cache_write_token(cache: jax.Array, rows: jax.Array,
     """Ring-cursor write of ONE token's K or V rows, every layer at once,
     after the layer loop.
 
-    cache [N, S, L, H, hd], rows [N, S, H, hd] (the loop's stacked new
-    rows), cursor [S] int32 — slot ``s``'s rows land at
-    ``cache[:, s, cursor[s]]``; nothing else of the cache is touched."""
+    cache [N, S, L, H, hd] and rows [N, S, H, hd] (the loop's stacked new
+    rows), or merged: cache [N, S, L, W] and rows [N, S, W]; cursor [S]
+    int32 — slot ``s``'s rows land at ``cache[:, s, cursor[s]]``; nothing
+    else of the cache is touched."""
     rows = rows.astype(cache.dtype)
     for s in range(rows.shape[1]):
         cache = jax.lax.dynamic_update_slice(
-            cache, rows[:, s, None, None], (0, s, cursor[s], 0, 0))
+            cache, rows[:, s, None, None],
+            (0, s, cursor[s]) + (0,) * (cache.ndim - 3))
+    return cache
+
+
+# decode-path  # jax-hot-path: the KV cache stays in the activation dtype
+def cache_write_chunk(cache: jax.Array, rows: jax.Array, slots: jax.Array,
+                      start: jax.Array) -> jax.Array:
+    """Prefill write of a chunk's merged rows, every layer at once, after
+    the layer loop: row block ``rows[:, i]`` ([N, C, W], the loop's stacked
+    rows) lands at ``cache[:, slots[i], start[i] : start[i] + C]`` of the
+    merged cache [N, S, L, W]. Sequential over the (small, static) row
+    axis; distinct slots make the order immaterial (rows that share a
+    scratch slot write only garbage there)."""
+    rows = rows.astype(cache.dtype)
+    for i in range(rows.shape[1]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[:, i, None], (0, slots[i], start[i], 0))
     return cache
 
 
@@ -189,6 +237,93 @@ def cached_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     return out.reshape(r, c, h, hd).astype(q.dtype)
 
 
+def _own_columns(g: int, hd: int, cols: int) -> jax.Array:
+    """[g, cols] bool: column w of a group of merged columns is head
+    ``w // hd``'s (a pad column, past ``g * hd``, is nobody's)."""
+    return jnp.arange(cols)[None, :] // hd == jnp.arange(g)[:, None]
+
+
+def _heads_apart(rows: jax.Array, g: int, hd: int) -> jax.Array:
+    """Merged query columns [..., cols] -> [..., g, cols], one row a head
+    of the group: the head's own ``hd`` columns and zeros everywhere else,
+    so a product over the columns of a merged key row is the head's own
+    product plus exact zeros."""
+    return jnp.where(_own_columns(g, hd, rows.shape[-1]),
+                     rows[..., None, :], 0)
+
+
+def _heads_merged(sums: jax.Array, hd: int) -> jax.Array:
+    """The pick back: sums over merged value rows [..., g, cols], a row a
+    head, -> [..., cols], every column from its own head's row."""
+    g, cols = sums.shape[-2:]
+    return jnp.sum(jnp.where(_own_columns(g, hd, cols), sums, 0), axis=-2)
+
+
+def _lane_groups(rows: jax.Array, hd: int) -> jax.Array:
+    """Merged rows [..., W] cut at whole lane tiles, [..., W / 128, 128]
+    (GPT-2's two heads a tile): the cut is a free reshape and a product
+    over a tile does ``128 / hd`` times the needed operations, where one
+    over the whole row does ``H`` times. A head size that does not divide
+    a tile, or a row narrower than one, keeps the row whole, [..., 1, W]."""
+    w = rows.shape[-1]
+    cols = w if 128 % hd or w % 128 else 128
+    return rows.reshape(*rows.shape[:-1], w // cols, cols)
+
+
+def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
+                           k_own: jax.Array, v_own: jax.Array,
+                           layer: jax.Array, slots: jax.Array,
+                           start: jax.Array, window: int) -> jax.Array:
+    """``cached_chunk_attention`` over a cache of merged rows, WITHOUT the
+    chunk's rows being in the cache yet.
+
+    q [R, C, H, hd]; k_all / v_all the stacked cache [N, S, L, W] as it was
+    before this chunk (read only: the caller writes after its layer loop,
+    ``cache_write_chunk``); k_own / v_own [R, C, W], the chunk's own merged
+    rows in the cache's type; slots, start [R] int32. Query i sees the
+    slot's rows ``< start`` as earlier chunks left them (cut out of the
+    stack: the first ``window - C`` rows, all that a start within the
+    caller's bound ``start + C <= window`` can name) and the chunk's own
+    rows ``<= i``: the same softmax over the same keys as a write and then
+    a read, in the same arithmetic (float32 scores, one maximum and one
+    sum over both parts, probabilities in the values' type, float32
+    sums). -> [R, C, H, hd] in q's type."""
+    r, c, h, hd = q.shape
+    w = k_all.shape[-1]
+    old = window - c
+
+    def cut(cache):
+        return jnp.stack([jax.lax.dynamic_slice(
+            cache, (layer, slots[i], 0, 0), (1, 1, old, w))[0, 0]
+            for i in range(r)])
+
+    rows = _lane_groups(
+        merged_rows(q.reshape(r, c, h * hd), w).astype(k_all.dtype), hd)
+    # [R, C, T, g, cols]: g heads a group of columns
+    rows = _heads_apart(rows, -(-rows.shape[-1] // hd), hd)
+    # (keys, values, who sees them): the slot's old rows, then the chunk's
+    parts = [(k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
+    if old:
+        parts.insert(0, (cut(k_all), cut(v_all),
+                         jnp.arange(old)[None, None, :]
+                         < start[:, None, None]))               # [R, 1, L]
+    scores = [jnp.where(
+        seen[:, None, None],
+        jnp.einsum("rqtgw,rktw->rtgqk", rows, _lane_groups(k, hd),
+                   preferred_element_type=jnp.float32) * hd ** -0.5, -1e30)
+        for k, _, seen in parts]
+    top = functools.reduce(jnp.maximum, [s.max(axis=-1) for s in scores])
+    probs = [jnp.exp(s - top[..., None]) for s in scores]
+    total = sum(p.sum(axis=-1) for p in probs)
+    out = sum(jnp.einsum("rtgqk,rktw->rqtgw",
+                         (p / total[..., None]).astype(v.dtype),
+                         _lane_groups(v, hd),
+                         preferred_element_type=jnp.float32)
+              for p, (_, v, _) in zip(probs, parts))
+    out = _heads_merged(out, hd).reshape(r, c, w)
+    return out[..., :h * hd].reshape(r, c, h, hd).astype(q.dtype)
+
+
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             k_new: jax.Array, v_new: jax.Array,
                             cursor: jax.Array, valid: jax.Array,
@@ -208,12 +343,17 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     drops the row the cursor overwrites, as ever. Where k/v hold FEWER
     heads than q (grouped queries: [S, L, G, hd] and [S, G, hd], H a
     multiple of G) each K/V head serves its H // G query heads as it lies,
-    with no expanded copy of the window. fp32 scores/softmax,
-    output cast to the activation dtype — shared by both model families'
-    decode steps so the masking/scaling contract lives here once.
+    with no expanded copy of the window. Where k/v are MERGED rows ([S, L,
+    W] and [S, W], ``merged_row_width``) they are read as they lie too.
+    fp32 scores/softmax, output cast to the activation dtype — shared by
+    the model families' decode steps so the masking/scaling contract lives
+    here once.
     ``scale`` multiplies the scores where a model publishes its own
     constant; None divides them by ``hd ** 0.5`` as ever."""
     hd = q.shape[-1]
+    if k.ndim == 3:
+        return _merged_decode_attention(q, k, v, k_new, v_new, cursor,
+                                        valid, out_dtype, scale)
     if k.shape[2] != q.shape[1]:
         return _grouped_decode_attention(q, k, v, k_new, v_new, cursor,
                                          valid, out_dtype, scale)
@@ -259,6 +399,38 @@ def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
     out = out + weight_new[..., None] \
         * v_new.astype(jnp.float32)[:, :, None, :]
     return out.reshape(s, h, hd).astype(out_dtype)
+
+
+def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
+                             out_dtype, scale=None):
+    """``cached_decode_attention`` over merged rows: k/v [S, L, W], k_new /
+    v_new [S, W]. The same softmax over the same keys; each head's query
+    stands in its own columns of a row-wide vector, so both products read
+    the layer's block as it lies (two MXU products a layer) and the other
+    columns add exact zeros."""
+    s, h, hd = q.shape
+    n_rows, w = k.shape[1:]
+    q = _heads_apart(merged_rows(q.reshape(s, h * hd), w).astype(k.dtype),
+                     h, hd)  # [S, H, W]
+    idx = jnp.arange(n_rows)
+    at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
+    mask = (idx[None, :] < valid[:, None])[:, None, :]
+    scores = jnp.einsum("shw,slw->shl", q, k,
+                        preferred_element_type=jnp.float32)
+    score_new = jnp.einsum("shw,sw->sh", q, k_new,
+                           preferred_element_type=jnp.float32)
+    scores = _scaled(jnp.where(at_cursor, score_new[..., None], scores),
+                     hd, scale)
+    weights = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    weight_new = jnp.sum(jnp.where(at_cursor, weights, 0.0), axis=-1)
+    # float32 probabilities into the sums, as the heads-apart forms have
+    # them: at the default precision the MXU would round them to bfloat16
+    out = jnp.einsum("shl,slw->shw", jnp.where(at_cursor, 0.0, weights),
+                     v.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    out = out + weight_new[..., None] * v_new.astype(jnp.float32)[:, None]
+    out = _heads_merged(out, hd)  # [S, W]
+    return out[:, :h * hd].reshape(s, h, hd).astype(out_dtype)
 
 
 # -- latent cache of the serving path ------------------------------------------

@@ -528,7 +528,8 @@ def test_the_guard_refuses_the_cache_through_xs_and_ys():
 
     def through(cache):
         def layer(x, block):
-            return x, jax.lax.dynamic_update_slice(block, rows, (0, 5, 0, 0))
+            return x, jax.lax.dynamic_update_slice(
+                block, rows, (0, 5) + (0,) * (block.ndim - 2))
         return jax.lax.scan(layer, 0.0, cache)[1]
 
     faults = _cache_moves(jax.make_jaxpr(through)(cache).jaxpr, cache.shape)
@@ -537,7 +538,8 @@ def test_the_guard_refuses_the_cache_through_xs_and_ys():
     def whole_layer(cache):   # in the carry, but a block at a time
         def layer(c, i):
             block = jax.lax.dynamic_index_in_dim(c, i, 0) + 1.0
-            return jax.lax.dynamic_update_slice(c, block, (i, 0, 0, 0, 0)), None
+            return jax.lax.dynamic_update_slice(
+                c, block, (i,) + (0,) * (c.ndim - 1)), None
         return jax.lax.scan(layer, cache, jnp.arange(cache.shape[0]))[0]
 
     faults = _cache_moves(jax.make_jaxpr(whole_layer)(cache).jaxpr,

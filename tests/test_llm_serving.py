@@ -200,6 +200,14 @@ class _Gpt2Oracle:
     name, cfg = "gpt2", GPT2_FP32
     init, init_cache = gpt2.gpt2_init, gpt2.gpt2_init_cache
     prefill, decode = gpt2.gpt2_prefill, gpt2.gpt2_decode_step
+    prefill_chunk = gpt2.gpt2_prefill_chunk
+
+    @classmethod
+    def heads(cls, cache):
+        """The merged, lane-padded rows as the oracle's [N, S, L, H, hd]."""
+        h, hd = cls.cfg.n_head, cls.cfg.head_dim
+        return {n: a[..., :h * hd].reshape(*a.shape[:3], h, hd)
+                for n, a in cache.items()}
 
     @staticmethod
     def embed(params, tokens, pos, cfg):
@@ -228,6 +236,8 @@ class _LlamaOracle:
     name, cfg = "llama", LLAMA_FP32
     init, init_cache = llama.llama_init, llama.llama_init_cache
     prefill, decode = llama.llama_prefill, llama.llama_decode_step
+    prefill_chunk = llama.llama_prefill_chunk
+    heads = staticmethod(lambda cache: cache)   # heads apart as it lies
 
     @staticmethod
     def embed(params, tokens, pos, cfg):
@@ -258,11 +268,25 @@ class _LlamaOracle:
         return llama._rms_norm(x, params["final_norm"]) @ params["lm_head"]
 
 
+def _gpt2_rows_of(n_head, d_model):
+    """The GPT-2 oracle at another head count: what the merged, lane-padded
+    rows look like changes with it (``_Gpt2Oracle``'s 4 heads of 16 are half
+    a lane tile, which ``merged_row_width`` leaves unpadded)."""
+    return type(f"_Gpt2Oracle{n_head}x{d_model // n_head}", (_Gpt2Oracle,), {
+        "name": f"gpt2-{n_head}x{d_model // n_head}",
+        "cfg": dataclasses.replace(GPT2_FP32, n_head=n_head,
+                                   d_model=d_model)})
+
+
+# XL's 25 heads of 64: 1600 columns padded to 1664, the last lane tile half
+# a head's and half nobody's; 124M's 12 of 64: 768 columns, no pad.
 FAMILIES = pytest.mark.parametrize(
-    "fam", [_Gpt2Oracle, _LlamaOracle], ids=lambda f: f.name)
+    "fam", [_Gpt2Oracle, _gpt2_rows_of(25, 1600), _gpt2_rows_of(12, 768),
+            _LlamaOracle], ids=lambda f: f.name)
 
 
 def _oracle_decode(fam, params, cache, tokens, pos):
+    """``cache`` and the cache returned are heads apart (``fam.heads``)."""
     cfg = fam.cfg
     s, cache_len = tokens.shape[0], cache["k"].shape[2]
     cursor, valid = pos % cache_len, jnp.minimum(pos + 1, cache_len)
@@ -283,6 +307,7 @@ def _oracle_decode(fam, params, cache, tokens, pos):
 
 
 def _oracle_prefill(fam, params, cache, tokens, slots, lengths):
+    """``cache`` and the cache returned are heads apart (``fam.heads``)."""
     cfg = fam.cfg
     r, p_len = tokens.shape
     rep = cfg.n_head // cache["k"].shape[3]
@@ -322,6 +347,13 @@ def _live_rows(cache, live, pos):
             for n in ("k", "v") for s in live]
 
 
+def _pad_columns(fam, cache):
+    """A merged cache's columns that belong to no head (none: empty)."""
+    used = fam.cfg.n_head * fam.cfg.head_dim
+    return [np.asarray(cache[n][..., used:]) for n in ("k", "v")
+            if cache[n].ndim == 4]
+
+
 CACHE_LEN = 8
 LIVE = (0, 2)            # slots 1 and 3 are free and hold garbage
 START = np.array([5, 0, 2, 0], np.int32)   # slot 0 wraps first, at pos 8
@@ -337,7 +369,7 @@ def test_decode_step_matches_oracle(fam, steps):
     both live slots have wrapped."""
     params = fam.init(jax.random.PRNGKey(3), fam.cfg)
     got_cache = _garbage_cache(fam, 4, CACHE_LEN, seed=7)
-    want_cache = jax.tree.map(jnp.copy, got_cache)
+    want_cache = fam.heads(got_cache)
     step = jax.jit(lambda p, c, t, n: fam.decode(p, c, t, n, fam.cfg))
     oracle = jax.jit(lambda p, c, t, n: _oracle_decode(fam, p, c, t, n))
     rng = np.random.default_rng(11)
@@ -357,9 +389,36 @@ def test_decode_step_matches_oracle(fam, steps):
     np.testing.assert_allclose(np.asarray(got)[list(LIVE)],
                                np.asarray(want)[list(LIVE)],
                                rtol=1e-5, atol=1e-5)
-    for g, w in zip(_live_rows(got_cache, LIVE, pos),
+    for g, w in zip(_live_rows(fam.heads(got_cache), LIVE, pos),
                     _live_rows(want_cache, LIVE, pos)):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+@FAMILIES
+def test_the_pad_columns_stay_zero_after_every_write(fam):
+    """A merged row's pad columns belong to no head: from ``init_cache`` on,
+    a prefill in chunks (one from row 0, one from mid-prompt) and decode
+    steps up to a wrapped ring write zeros there, in every slot, the free
+    ones' garbage rows too. (A cache with the heads apart has no pad.)"""
+    from ray_tpu.models.prefill import whole_prompts
+
+    params = fam.init(jax.random.PRNGKey(3), fam.cfg)
+    cache = fam.init_cache(fam.cfg, 4, CACHE_LEN)
+    tokens = np.zeros((2, 6), np.int32)
+    tokens[0], tokens[1, :2] = [5, 9, 2, 17, 3, 8], [7, 1]
+    _, cache = whole_prompts(
+        fam.prefill_chunk, params, cache, jnp.asarray(tokens),
+        jnp.asarray([0, 2], jnp.int32), jnp.asarray([6, 2], jnp.int32),
+        fam.cfg, chunk=3)
+    pos = np.array([6, 0, 2, 0], np.int32)
+    for i in range(4):  # slot 0 wraps at pos 8
+        _, cache = fam.decode(params, cache, jnp.asarray([3, 1, 4, 1]) + i,
+                              jnp.asarray(pos + i), fam.cfg)
+    for pad in _pad_columns(fam, cache):
+        assert pad.shape[-1] == cache["k"].shape[-1] \
+            - fam.cfg.n_head * fam.cfg.head_dim
+        np.testing.assert_array_equal(pad, 0)
+    assert float(jnp.abs(cache["k"][:, 0, 0]).max()) > 0  # rows were written
 
 
 @FAMILIES
@@ -395,7 +454,7 @@ def test_prefill_scratch_rows_leave_other_slots_untouched(fam):
     got, after = fam.prefill(params, jax.tree.map(jnp.copy, before),
                              jnp.asarray(tokens), slots, lengths, fam.cfg)
     want, oracle_after = _oracle_prefill(
-        fam, params, before, jnp.asarray(tokens), slots, lengths)
+        fam, params, fam.heads(before), jnp.asarray(tokens), slots, lengths)
     np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2],
                                rtol=1e-5, atol=1e-5)
     for n in ("k", "v"):
@@ -404,7 +463,7 @@ def test_prefill_scratch_rows_leave_other_slots_untouched(fam):
                                           np.asarray(before[n][:, slot]))
         for slot in (3, 1):
             np.testing.assert_allclose(
-                np.asarray(after[n][:, slot, :8]),
+                np.asarray(fam.heads(after)[n][:, slot, :8]),
                 np.asarray(oracle_after[n][:, slot, :8]),
                 rtol=1e-5, atol=1e-5)
             np.testing.assert_array_equal(
@@ -424,7 +483,8 @@ def test_decode_after_prefill_reads_the_rows_prefill_wrote(fam):
     args = (jnp.asarray(tokens), jnp.asarray([2, 3], jnp.int32),
             jnp.asarray([5, 1], jnp.int32))
     first, got_cache = fam.prefill(params, cache, *args, fam.cfg)
-    want_first, want_cache = _oracle_prefill(fam, params, cache, *args)
+    want_first, want_cache = _oracle_prefill(fam, params, fam.heads(cache),
+                                             *args)
     cur = jnp.zeros(4, jnp.int32).at[2].set(jnp.argmax(first[0]))
     pos = jnp.zeros(4, jnp.int32).at[2].set(5)
     got, _ = fam.decode(params, got_cache, cur, pos, fam.cfg)
@@ -437,6 +497,49 @@ def test_decode_after_prefill_reads_the_rows_prefill_wrote(fam):
     bent = {"k": got_cache["k"].at[:, 2, 1].add(1.0), "v": got_cache["v"]}
     moved, _ = fam.decode(params, bent, cur, pos, fam.cfg)
     assert float(jnp.max(jnp.abs(moved[2] - got[2]))) > 1e-3
+
+
+@pytest.mark.parametrize("shape", [{}, {"n_head": 25, "d_model": 1600},
+                                   {"n_head": 12, "d_model": 768}],
+                         ids=["4x16", "25x64", "12x64"])
+def test_bfloat16_programs_stay_within_the_cells_limit_of_float32(shape):
+    """The two programs as the GPT-2 cells run them (bfloat16, merged
+    bfloat16 rows) against the float32 full-context forward on the same
+    weights, fed its greedy tokens: a prompt in three chunks (from row 0,
+    from mid-prompt, a ragged last one) and six decode steps, every row of
+    logits within the relative L2 the benchmark's configuration allows
+    (``serve_logits_rel_l2`` 3e-2: today's limit, not a new one)."""
+    from ray_tpu.models.prefill import whole_prompts
+
+    served = dataclasses.replace(gpt2.GPT2Config.tiny(), **shape)
+    assert served.dtype == jnp.bfloat16
+    exact = dataclasses.replace(served, dtype=jnp.float32)
+    params = gpt2.gpt2_init(jax.random.PRNGKey(5), served)
+    prompt = [5, 9, 2, 17, 3, 11, 60, 7, 1, 4, 33]
+    want_tokens = _naive_generate(
+        _compiled(gpt2.gpt2_forward, params, exact, 32), params, prompt, 7,
+        exact)
+    toks = prompt + want_tokens
+    want = gpt2.gpt2_forward(params, jnp.asarray([toks], jnp.int32), exact)[0]
+    cache = gpt2.gpt2_init_cache(served, 3, 32)
+    assert cache["k"].dtype == jnp.bfloat16 and cache["k"].ndim == 4
+    got, cache = whole_prompts(
+        gpt2.gpt2_prefill_chunk, params, cache,
+        jnp.asarray([prompt], jnp.int32), jnp.asarray([1], jnp.int32),
+        jnp.asarray([len(prompt)], jnp.int32), served, chunk=4)
+    rows = [got[0]]
+    step = jax.jit(lambda c, t, n: gpt2.gpt2_decode_step(
+        params, c, t, n, served))
+    for i in range(6):
+        at = len(prompt) + i
+        lg, cache = step(cache, jnp.asarray([0, toks[at], 0], jnp.int32),
+                         jnp.asarray([0, at, 0], jnp.int32))
+        rows.append(lg[1])
+    for i, row in enumerate(rows):
+        ref = np.asarray(want[len(prompt) - 1 + i], np.float64)
+        err = np.linalg.norm(np.asarray(row, np.float64) - ref) \
+            / np.linalg.norm(ref)
+        assert err < 3e-2, (i, err)
 
 
 # -- scheduler: slots, admission, deadlines ---------------------------------

@@ -23,10 +23,14 @@ F32 = jnp.float32
 # (float32 tiny config, init, init_cache, prefill_chunk, whole-window
 # prefill, full-context forward); the hybrid's scan blocks by 4 so that a
 # chunk of 4 and a window of 16 block alike.
+def _gpt2(**shape):
+    return (dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=F32, **shape),
+            gpt2.gpt2_init, gpt2.gpt2_init_cache, gpt2.gpt2_prefill_chunk,
+            gpt2.gpt2_prefill, gpt2.gpt2_forward)
+
+
 FAMILIES = {
-    "gpt2": (dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=F32),
-             gpt2.gpt2_init, gpt2.gpt2_init_cache, gpt2.gpt2_prefill_chunk,
-             gpt2.gpt2_prefill, gpt2.gpt2_forward),
+    "gpt2": _gpt2(),
     "llama": (dataclasses.replace(llama.LlamaConfig.tiny(), dtype=F32),
               llama.llama_init, llama.llama_init_cache,
               llama.llama_prefill_chunk, llama.llama_prefill,
@@ -50,6 +54,17 @@ FAMILIES = {
         deepseek_v2.deepseek_v2_prefill, deepseek_v2.deepseek_v2_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
+# GPT-2's merged, lane-padded rows at the head counts it is served with: XL's
+# 25 heads of 64 (1600 columns padded to 1664, a lane tile shared by two
+# heads and the last one half empty) and 124M's 12 of 64 (768, no pad); the
+# tiny one's 4 of 16 are half a tile and stay unpadded; 3 heads of 48 (144
+# columns padded to 256) do not divide a tile: both run the chunk's products
+# over the whole row (``_lane_groups``' other arm).
+# Model functions only, no engine.
+FAMILIES.update({"gpt2-25x64": _gpt2(n_head=25, d_model=1600),
+                 "gpt2-12x64": _gpt2(n_head=12, d_model=768),
+                 "gpt2-3x48": _gpt2(n_head=3, d_model=144)})
+every_row_width = pytest.mark.parametrize("family", list(FAMILIES))
 CHUNK, MAX_PROMPT, CACHE_LEN, SLOTS = 4, 16, 24, 4
 
 
@@ -101,6 +116,12 @@ def _whole(family, params, cache, prompt, slot):
     return logits[0], cache
 
 
+def _holds_rows(path, a):
+    """K/V or latent rows [layer, slot, row, head, hd], GPT-2's merged
+    [layer, slot, row, W]; the rest is Mamba state."""
+    return a.ndim == 5 or jax.tree_util.keystr(path) in ("['k']", "['v']")
+
+
 def _assert_same_state(family, got, want, slot, n):
     """The slot's real K/V rows and its whole Mamba state agree; every
     other slot is bit for bit what it was in both."""
@@ -108,7 +129,7 @@ def _assert_same_state(family, got, want, slot, n):
                             jax.tree.leaves(want)):
         a, b = np.asarray(a), np.asarray(b)
         name = jax.tree_util.keystr(path)
-        if a.ndim == 5:          # k / v [layer, slot, row, head, hd]
+        if _holds_rows(path, a):  # [layer, slot, row, ...]
             np.testing.assert_allclose(a[:, slot, :n], b[:, slot, :n],
                                        rtol=1e-5, atol=1e-5, err_msg=name)
             others = [s for s in range(SLOTS) if s != slot]
@@ -121,7 +142,7 @@ def _assert_same_state(family, got, want, slot, n):
                                        err_msg=name)
 
 
-@every_family
+@every_row_width
 @pytest.mark.parametrize("n", [CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK,
                                MAX_PROMPT])
 def test_chunks_leave_what_the_whole_window_leaves(family, n):
@@ -139,7 +160,7 @@ def test_chunks_leave_what_the_whole_window_leaves(family, n):
                                rtol=2e-4, atol=2e-4)
 
 
-@every_family
+@every_row_width
 def test_a_first_chunk_begins_anew_whatever_the_slot_held(family):
     """``start == 0``: the K/V rows a used slot holds are not seen, its
     convolution tail and SSM state are not continued."""
@@ -152,7 +173,7 @@ def test_a_first_chunk_begins_anew_whatever_the_slot_held(family):
     np.testing.assert_array_equal(np.asarray(used), np.asarray(fresh))
 
 
-@every_family
+@every_row_width
 def test_the_whole_window_form_is_a_loop_over_the_chunk_function(family):
     """``<family>_prefill`` on [R, P] (what the benchmark's reference check
     calls) cut into chunks gives what it gives in one chunk, rows of
@@ -172,9 +193,10 @@ def test_the_whole_window_form_is_a_loop_over_the_chunk_function(family):
                                    chunk=CHUNK)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    for a, b in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_cache),
+                            jax.tree.leaves(want_cache)):
         a, b = np.asarray(a), np.asarray(b)
-        if a.ndim == 5:  # K/V: a prompt's own rows (past them, pad garbage)
+        if _holds_rows(path, a):  # a prompt's own rows (past them, garbage)
             a, b = (np.concatenate([x[:, slot, :n] for slot, n in
                                     zip((3, 0, 2), lens)], 1) for x in (a, b))
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)

@@ -8,7 +8,10 @@ here is a time. It holds what ``test_the_cache_is_only_written_by_rows`` cannot
 see from the jaxpr: that XLA keeps the stacked cache's layout through the
 row writes (a scatter, or the same updates under a ``fori_loop``, made it
 re-lay out the whole cache around them), so the layer loop moves no
-layer-sized block and the program's temp space holds no second cache.
+layer-sized block and the program's temp space holds no second cache; and,
+since PR 42, that the layout it keeps, in BOTH programs from the entry to
+the donated output, is the one with a token's merged, lane-padded row
+contiguous (``{3,2,1,0}``), and that the pad is what buys it.
 
 The topology is described inside a fixture, in this one file: only the
 worker that runs this file loads the TPU's library.
@@ -28,7 +31,9 @@ XL = gpt2.GPT2Config(vocab_size=50304, n_layer=48, n_head=25, d_model=1600,
                      seq_len=1024)
 SLOTS, CACHE_LEN, PROMPT_LEN = 9, 1024, 768
 CHUNK = chunk_len(PROMPT_LEN)  # as the engine derives it: 256
-LAYER_BLOCK = SLOTS * CACHE_LEN * XL.n_head * XL.head_dim
+ROW = 1664  # 25 heads of 64 merged, padded to whole lane tiles: 13 x 128
+LAYER_BLOCK = SLOTS * CACHE_LEN * ROW
+CACHE_DIMS = f"{XL.n_layer},{SLOTS},{CACHE_LEN},{ROW}"
 
 
 @pytest.fixture(scope="module")
@@ -45,13 +50,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def compiled(one_chip):
-    """Both programs as the engine jits them (cache donated), compiled
-    once for the module, with the persistent cache out of the way: such
-    a compile is written to it but cannot be read back without a chip."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
+def _programs(one_chip, cache=None):
+    """name -> (function, abstract arguments) as the engine jits them, on
+    the parameters as it stores them (PR 29: bfloat16 but for the norms);
+    ``cache`` stands in for ``gpt2_init_cache``'s where given."""
     def sds(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
@@ -60,19 +62,25 @@ def compiled(one_chip):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     made = jax.eval_shape(lambda: gpt2.gpt2_init(jax.random.PRNGKey(0), XL))
-    # as the engine stores them (PR 29): bfloat16 but for the norms
     params = sds(jax.tree.map(
         lambda a, dt: jax.ShapeDtypeStruct(a.shape, dt), made,
         XL.serving_dtypes(made)))
-    cache = sds(jax.eval_shape(
+    cache = sds(cache or jax.eval_shape(
         lambda: gpt2.gpt2_init_cache(XL, SLOTS, CACHE_LEN)))
-    programs = {
+    return {
         "decode": (lambda p, c, t, n: gpt2.gpt2_decode_step(p, c, t, n, XL),
                    (params, cache, i32(SLOTS), i32(SLOTS))),
         "prefill": (lambda p, c, t, s, at, n: gpt2.gpt2_prefill_chunk(
             p, c, t, s, at, n, XL, window=key_window(PROMPT_LEN, CHUNK)),
                     (params, cache, i32(1, CHUNK), i32(1), i32(1), i32(1))),
     }
+
+
+def _compile(programs):
+    """Cache donated, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -84,7 +92,16 @@ def compiled(one_chip):
         cc.reset_cache()
 
 
-BLOCK_DIMS = f"{SLOTS},{CACHE_LEN},{XL.n_head},{XL.head_dim}"
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """Both programs, compiled once for the module."""
+    programs = _programs(one_chip)
+    assert programs["decode"][1][1]["k"].shape == (
+        XL.n_layer, SLOTS, CACHE_LEN, ROW)
+    return _compile(programs)
+
+
+BLOCK_DIMS = f"{SLOTS},{CACHE_LEN},{ROW}"
 PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple")
 
 
@@ -98,63 +115,114 @@ def _loop_bodies(hlo_text):
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_no_layer_sized_block_is_moved_inside_the_layer_loop(compiled, which):
-    """In the parent's programs the loop held ``copy.31/33/34/35`` (a
-    layer's block of the cache re-laid out on its way in and out), a
-    ``dynamic-slice`` that cut it out of the stack and a
-    ``dynamic-update-slice`` that put it back. Now nothing in a loop body
-    makes a layer's block, and what makes a whole cache there (the
-    prefill's in-place row writes) takes no layer's block to do it."""
-    made_block = re.compile(
-        rf"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[(1,)?{BLOCK_DIMS}\]\S* ([\w\-]+)\(")
-    made_cache = re.compile(
-        rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[{XL.n_layer},{BLOCK_DIMS}\]\S* "
-        r"([\w\-]+)\((.*)")
+    """Before PR 25 the loop held ``copy.31/33/34/35`` (a layer's block of
+    the cache re-laid out on its way in and out), a ``dynamic-slice`` that
+    cut it out of the stack and a ``dynamic-update-slice`` that put it
+    back. Nothing in a loop body makes a layer's block or a whole cache
+    (since PR 42 neither program writes inside its loop), and nowhere in
+    the program is an array as large as the cache, or as a layer's block,
+    copied or transposed."""
+    made_large = re.compile(
+        rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[(?:{XL.n_layer},|1,)?{BLOCK_DIMS}\]"
+        r"\S* ([\w\-]+)\(")
+    text = compiled[which].as_text()
     moved, lines = [], 0
-    for body in _loop_bodies(compiled[which].as_text()):
-        blocks = set()
+    for body in _loop_bodies(text):
         for line in body.splitlines()[1:]:
             lines += 1
-            m = made_block.match(line)
-            if m:
-                blocks.add(m.group(1))
-                if m.group(3) not in PASSES_ON:
-                    moved.append(line.strip()[:140])
-        for line in body.splitlines()[1:]:
-            m = made_cache.match(line)
-            if m and m.group(1) not in PASSES_ON and \
-                    blocks & set(re.findall(r"%[\w.\-]+", m.group(2))):
+            m = made_large.match(line)
+            if m and m.group(1) not in PASSES_ON:
                 moved.append(line.strip()[:140])
     assert lines > 20, "found no layer loop to read"
     assert moved == []
+    copied = [line.strip()[:140] for line in text.splitlines()
+              if (m := made_large.match(line))
+              and m.group(1) in ("copy", "transpose")]
+    assert copied == []
+
+
+# what one execution of the chunk program stacks as its layer loop's
+# ``ys``: the chunk's own K and V rows of every layer, [48, 1, 256, 1664]
+CHUNK_ROWS = 2 * XL.n_layer * CHUNK * ROW * 2
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_temp_space_holds_no_second_cache_and_no_copy_of_the_weights(
         compiled, which):
-    """The stacked cache is 2.83 GB and is updated in the donated buffer
-    (before PR 25 the programs took 6.03 and 6.49 GB of temp space). The
-    weights come in as the engine stores them, 3.14 GB, and no bfloat16
-    copy of them is made (3.15 and 3.37 GB of temp space until PR 29): what
-    is left is the embedding laid out for the head (0.16 GB) and the
-    activations, 0.17 GB in both: the chunk's are under the [4, 768]
-    lane's 0.24 GB."""
+    """The stacked cache is 2.94 GB (2.83 GB of rows and their pad) and is
+    updated in the donated buffer (before PR 25 the programs took 6.03 and
+    6.49 GB of temp space). The weights come in as the engine stores
+    them, 3.14 GB, and no bfloat16 copy of them is made (3.15 and 3.37 GB
+    of temp space until PR 29): what is left is the embedding laid out for
+    the head (0.16 GB) and the activations, 0.17 GB in both, under the
+    [4, 768] lane's 0.24 GB. The chunk's stacked rows (2 x 41 MB, written
+    after its loop since PR 42) fit within that: they are allowed for by
+    name, so a limit that had to grow would say by how much."""
     mem = compiled[which].memory_analysis()
     cache_bytes = 2 * XL.n_layer * LAYER_BLOCK * 2
     assert mem.alias_size_in_bytes >= cache_bytes
-    assert mem.temp_size_in_bytes < {"decode": 0.4e9, "prefill": 0.24e9}[which]
+    assert mem.temp_size_in_bytes < {
+        "decode": 0.4e9, "prefill": 0.16e9 + CHUNK_ROWS}[which]
     assert mem.argument_size_in_bytes < cache_bytes + 2.02 * XL.n_params
 
 
+def _cache_layouts(text):
+    """Every layout the program names an array of the cache's shape in
+    (a trailing S(n) names a memory space, not a layout)."""
+    return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+        rf"bf16\[{CACHE_DIMS}\](\{{[^}}]*\}})", text)}
+
+
+def _entry_layouts(text, dims):
+    """(parameters', results') layouts of the arrays of shape ``dims`` in
+    the module's ``entry_computation_layout``."""
+    entry = re.search(
+        r"entry_computation_layout=\{\((.*)\)->\((.*?)\)\}(?:, \w+=|$)",
+        text.split("\n", 1)[0])
+    find = rf"bf16\[{dims}\](\{{[^}}]*\}})"
+    return re.findall(find, entry.group(1)), re.findall(find, entry.group(2))
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_cache_is_row_minor_from_the_entry_to_the_donated_output(
+        compiled, which):
+    """Both programs take the cache and hand it back with a token's row
+    minor-most (``{3,2,1,0}``: a row written is 13 tiles of 4 KB, where
+    ``cache_len`` minor-most made it 4,800), and name it in no other
+    layout anywhere between: each reads the cache as it was and writes its
+    rows after the layer loop. (A chunk that wrote its rows into the cache
+    its loop carried and then cut its key window out of the same carry
+    made the compiler re-lay the whole cache out and back, four cache-sized
+    copies a call; a layout only one program keeps is a copy a call.)"""
+    text = compiled[which].as_text()
+    took, gave = _entry_layouts(text, CACHE_DIMS)
+    assert len(took) == len(gave) == 2
+    for layout in took + gave:
+        assert layout.startswith("{3,2,1,0"), layout
+    assert len(_cache_layouts(text)) == 1, _cache_layouts(text)
+
+
 def test_the_chunk_keeps_the_cache_in_the_layout_the_step_reads(compiled):
-    """The chunk program writes its rows into, and cuts its key window out
-    of, the stacked cache its layer loop carries. Everywhere either program
-    names an array of the cache's shape it has ONE layout, the same in both
-    (on this runtime ``cache_len`` minor-most): no program re-lays the
-    cache out around a write, and what a chunk leaves is what the step
-    reads."""
-    # (a trailing S(n) names a memory space, not a layout)
-    layouts = {which: {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
-        rf"bf16\[{XL.n_layer},{BLOCK_DIMS}\](\{{[^}}]*\}})", prog.as_text())}
-        for which, prog in compiled.items()}
+    """ONE layout, the same in both programs: what a chunk leaves is what
+    the step reads, and neither re-lays the shared, donated buffer out."""
+    layouts = {which: _cache_layouts(prog.as_text())
+               for which, prog in compiled.items()}
     assert len(layouts["prefill"]) == 1, layouts
     assert layouts["prefill"] == layouts["decode"]
+
+
+def test_the_merged_row_without_its_pad_is_not_row_minor(one_chip):
+    """Why the pad: the same decode step over merged rows of 1600 columns
+    (12.5 lane tiles) compiles with ``cache_len`` minor-most again, as the
+    heads-apart cache did; 64 more columns a row (4 % of the cache) are
+    what keeps a row contiguous."""
+    unpadded = jax.eval_shape(lambda: {n: jnp.zeros(
+        (XL.n_layer, SLOTS, CACHE_LEN, XL.d_model), XL.dtype)
+        for n in ("k", "v")})
+    programs = _programs(one_chip, cache=unpadded)
+    text = _compile({"decode": programs["decode"]})["decode"].as_text()
+    took, gave = _entry_layouts(
+        text, f"{XL.n_layer},{SLOTS},{CACHE_LEN},{XL.d_model}")
+    assert len(took) == 2
+    for layout in took + gave:
+        assert layout.startswith("{2,3,1,0"), layout
