@@ -3,10 +3,12 @@
 Flagship: GPT-2 (the benchmark's training cells); Nemotron-H (a
 hybrid of Mamba-2, attention and latent-MoE layers) and Granite 4.0-H (a
 Mamba-2 mixer or attention, then gated experts, in every layer) are served
-only, as is DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a
-latent cache, group-limited experts; not exported here, so that a process
-which serves another family never imports it: ``LLMEngine`` resolves it by
-name). Models are plain
+only, as are DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a
+latent cache, group-limited experts) and Falcon-H1 (``models/falcon_h1.py``:
+rotary grouped-query attention AND a Mamba-2 mixer side by side in every
+layer, both caches a layer, fourteen muP multipliers); those two are not
+exported here, so that a process which serves another family never imports
+them: ``LLMEngine`` resolves them by name. Models are plain
 functions over parameter pytrees — no framework Module state — so the same
 code runs under any mesh and any rules table.
 """

@@ -1,7 +1,8 @@
 """The Mamba-2 mixer the served hybrids share (``models/nemotron_h.py``,
-``models/granite_hybrid.py``): its weights, its share of a serving cache,
-one token a slot (``mamba_step``) and rows of tokens that begin or continue
-a slot's state (``mamba_rows`` over the blocked scan ``ssd_scan``).
+``models/granite_hybrid.py``, ``models/falcon_h1.py``): its weights, its
+share of a serving cache, one token a slot (``mamba_step``) and rows of
+tokens that begin or continue a slot's state (``mamba_rows`` over the
+blocked scan ``ssd_scan``).
 
 It takes its sizes as ``Mamba2Dims`` and not a family's configuration: two
 copies of a scan are where a later change speeds one model and forgets the
@@ -9,7 +10,8 @@ other. The equations (H heads of P channels, d_inner = H P, G groups of
 B / C, state N, kernel K; ``benchmark/reference/`` writes them out plainly
 for each family):
 
-  ``[z, xBC, dt] = in_proj(y)``; ``xBC = silu(causal_conv1d(xBC) + b)``,
+  ``[z, xBC, dt] = in_proj(y)`` (each part times its factor where a model
+  publishes ``in_multipliers``); ``xBC = silu(causal_conv1d(xBC) + b)``,
   split x [H, P], B and C [G, N]; ``dt = softplus(dt + dt_bias)``,
   ``A = -exp(a_log)``; per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
   B_t^T``, ``y_t = S_t C_t + D x_t``;
@@ -32,6 +34,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = dict[str, Any]
 
@@ -47,6 +50,10 @@ class Mamba2Dims:
     eps: float
     dtype: Any = jnp.bfloat16        # activations and matmuls
     state_dtype: Any = jnp.float32   # the SSM state in a cache
+    # What multiplies ``in_proj``'s output columns, by part: (z, x, B, C,
+    # dt), a published vector of a model that scales them apart; None: the
+    # output as it is, and nothing traced.
+    in_multipliers: tuple | None = None
 
     @property
     def d_inner(self) -> int:
@@ -62,16 +69,17 @@ class Mamba2Dims:
 
 
 def mixer_init(keys, d_model: int, dims: Mamba2Dims, param_dtype, normal,
-               out_std: float) -> Params:
+               out_std: float, in_std: float = 0.02) -> Params:
     """A mixer's seeded weights, drawing from the iterator ``keys`` (six of
     them): ``dt`` log-uniform in [1e-3, 1e-1] through the inverse softplus,
-    ``A`` uniform in [1, 16], matrices ``normal(key, shape, std, dtype)``."""
+    ``A`` uniform in [1, 16], matrices ``normal(key, shape, std, dtype)``
+    (``in_proj`` at ``in_std``, ``out_proj`` at ``out_std``)."""
     h, di, pd = dims.heads, dims.d_inner, param_dtype
     dt = jnp.exp(jax.random.uniform(
         next(keys), (h,), jnp.float32, math.log(1e-3), math.log(1e-1)))
     dt = jnp.maximum(dt, 1e-4)
     return dict(
-        in_proj=normal(next(keys), (d_model, dims.in_width), 0.02, pd),
+        in_proj=normal(next(keys), (d_model, dims.in_width), in_std, pd),
         conv_w=jax.random.uniform(
             next(keys), (dims.kernel, dims.conv_dim), jnp.float32,
             -0.5, 0.5).astype(pd),
@@ -113,9 +121,22 @@ def gated_group_norm(y, z, w, dims: Mamba2Dims):
         dims.dtype)
 
 
+def in_multiplier_vector(dims: Mamba2Dims):
+    """``dims.in_multipliers`` laid over ``in_proj``'s ``in_width`` output
+    columns, float32 (numpy): z, x, B, C, dt, each part its own factor."""
+    gn = dims.groups * dims.state
+    widths = (dims.d_inner, dims.d_inner, gn, gn, dims.heads)
+    return np.repeat(np.asarray(dims.in_multipliers, np.float32), widths)
+
+
 def ssm_inputs(proj: jax.Array, dims: Mamba2Dims):
     """``in_proj``'s output split: z [.., d_inner], xBC [.., conv_dim],
-    and ``dt`` before its softplus [.., H]."""
+    and ``dt`` before its softplus [.., H]; each part times its entry of
+    ``dims.in_multipliers`` where the model has them (in float32, rounded
+    once to ``proj``'s type)."""
+    if dims.in_multipliers is not None:
+        proj = (proj.astype(jnp.float32)
+                * in_multiplier_vector(dims)).astype(proj.dtype)
     di = dims.d_inner
     return (proj[..., :di], proj[..., di:di + dims.conv_dim],
             proj[..., di + dims.conv_dim:])

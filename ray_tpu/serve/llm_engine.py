@@ -167,9 +167,16 @@ def _model_bundle(model: str, config, preset: str):
                          else m.DeepseekV2Config())
         return (cfg, m.deepseek_v2_init, m.deepseek_v2_init_cache,
                 m.deepseek_v2_prefill_chunk, m.deepseek_v2_decode_step)
+    if model == "falcon_h1":
+        from ray_tpu.models import falcon_h1 as m
+
+        cfg = config or (m.FalconH1Config.tiny() if preset == "tiny"
+                         else m.FalconH1Config())
+        return (cfg, m.falcon_h1_init, m.falcon_h1_init_cache,
+                m.falcon_h1_prefill_chunk, m.falcon_h1_decode_step)
     raise ValueError(
-        f"unknown model family {model!r} "
-        f"(want gpt2|llama|nemotron_h|granite_hybrid|deepseek_v2)")
+        f"unknown model family {model!r} (want gpt2|llama|nemotron_h|"
+        f"granite_hybrid|deepseek_v2|falcon_h1)")
 
 
 def _stored_params(init, key, cfg):

@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import (deepseek_v2, gpt2, granite_hybrid, llama,
-                            nemotron_h)
+from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
+                            llama, nemotron_h)
 from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
 from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -52,6 +52,11 @@ FAMILIES = {
         deepseek_v2.deepseek_v2_init, deepseek_v2.deepseek_v2_init_cache,
         deepseek_v2.deepseek_v2_prefill_chunk,
         deepseek_v2.deepseek_v2_prefill, deepseek_v2.deepseek_v2_forward),
+    "falcon_h1": (falcon_h1.FalconH1Config.tiny(
+        dtype=F32, param_dtype=F32, chunk_size=4),
+        falcon_h1.falcon_h1_init, falcon_h1.falcon_h1_init_cache,
+        falcon_h1.falcon_h1_prefill_chunk, falcon_h1.falcon_h1_prefill,
+        falcon_h1.falcon_h1_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 # GPT-2's merged, lane-padded rows at the head counts it is served with: XL's
