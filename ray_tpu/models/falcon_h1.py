@@ -28,8 +28,15 @@ plainly; the tests hold this file to it.
 The cache: there is no layer pattern, so EVERY layer owns a K/V ring
 (rotated keys at their true positions: a wrapped ring is a window), a
 convolution tail and a float32 SSM state. The rings are one stacked array
-[layer, slot, row, K/V head, head_dim] as the other families', the tails
-one array and the state one array a layer (``ops/mamba2.init_state``). In
+[layer, slot, row, W] for K and one for V, a token's K/V heads MERGED in
+one row (GPT-2's layout, ``ops/attention.py``'s rank 4; at the published
+4 heads of 128 a row is four whole lane tiles and has no pad), the tails
+one array and the state one array a layer (``ops/mamba2.init_state``). Both
+programs read the rings as they were and write their new rows once a stack
+after the layer loop: with a ring in EVERY layer, windows re-laid out for
+the heads-apart products were half of a step and a write inside the loop
+cost a chunk two copies of whole stacks (PERF.md section 6, PR 44). The
+two hybrids with an attention layer in ten keep their rings heads apart. In
 a decode step a layer's ring read and its state rewrite depend on nothing
 of each other.
 
@@ -47,9 +54,10 @@ import jax.numpy as jnp
 
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2
-from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
-                                   cached_chunk_attention,
-                                   cached_decode_attention, causal_attention)
+from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
+                                   cached_decode_attention, causal_attention,
+                                   merged_chunk_attention, merged_row_width,
+                                   merged_rows)
 from ray_tpu.ops.rotary import rotate
 
 Params = dict[str, Any]
@@ -317,12 +325,23 @@ def _embed(params: Params, tokens: jax.Array, cfg: FalconH1Config):
 
 def falcon_h1_init_cache(cfg: FalconH1Config, slots: int,
                          cache_len: int) -> Params:  # decode-path
-    """For EVERY layer a K/V ring (one stacked array each for K and V, as
-    the other families'), the convolution's tail and the float32 SSM state
+    """For EVERY layer a K/V ring (one stacked array each for K and V,
+    [layer, slot, row, W]: a token's K/V heads merged in one row,
+    ``merged_row_width``, which both programs read as it lies; the same
+    bytes as heads apart wherever the row needs no pad, as at the published
+    and the tiny sizes), the convolution's tail and the float32 SSM state
     (``ops/mamba2.init_state``): one pytree, which the engine donates."""
-    kv = (cfg.n_layer, slots, cache_len, cfg.n_kv_head, cfg.head_dim)
+    kv = (cfg.n_layer, slots, cache_len,
+          merged_row_width(cfg.n_kv_head, cfg.head_dim))
     return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
             **mamba2.init_state(cfg.mamba, cfg.n_layer, slots)}
+
+
+def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
+    """A token's K or V heads [..., G, hd] as the cache holds them: side
+    by side in one row [..., W], in its type."""
+    return merged_rows(rows.reshape(*rows.shape[:-2], -1).astype(cache.dtype),
+                       cache.shape[-1])
 
 
 # jax-hot-path: traced into the engine's single compiled decode step
@@ -331,9 +350,11 @@ def falcon_h1_decode_step(params: Params, cache: Params, tokens: jax.Array,
                           ) -> tuple[jax.Array, Params]:
     """One decode iteration for every slot: tokens [S] int32, pos [S]
     int32 -> (logits [S, V] fp32, new cache). The K/V part keeps
-    ``gpt2_decode_step``'s ring contract (the rings are read as they were
-    and every layer's new rows written after the loop); the keys are
-    rotated before they are stored, so a wrapped ring is a window."""
+    ``gpt2_decode_step``'s ring contract and its layout (the rings of
+    merged rows are read as they were, each layer's block as it lies with
+    the five query heads of a K/V head standing in its columns, and every
+    layer's new rows written after the loop); the keys are rotated before
+    they are stored, so a wrapped ring is a window."""
     s = tokens.shape[0]
     dt_ = cfg.dtype
     cache_len = cache["k"].shape[2]
@@ -346,8 +367,8 @@ def falcon_h1_decode_step(params: Params, cache: Params, tokens: jax.Array,
         with jax.named_scope("ln"):
             y = _rms_norm(x, p["norm"], cfg.eps)
         q, k_new, v_new = _qkv(p, y, pos, cfg)
-        k_new = k_new.astype(cache["k"].dtype)
-        v_new = v_new.astype(cache["v"].dtype)
+        k_new = _merged_row(k_new, cache["k"])
+        v_new = _merged_row(v_new, cache["v"])
         with jax.named_scope("attn"):
             attn = cached_decode_attention(
                 q, cache["k"][i], cache["v"][i], k_new, v_new, cursor,
@@ -373,19 +394,24 @@ def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
           window: int | None = None):
     """Rows of T tokens through every layer: tokens [R, T], lengths [R].
     Without a cache, whole rows from nothing. With one, row r is a chunk
-    of a prompt at positions ``start[r] + i``: every layer writes the
-    chunk's K/V rows into ``slots[r]``'s ring and reads the slot's window
-    back, and continues the slot's SSM state, leaving there, in place, its
-    state after the row's real tokens (``nemotron_h._rows``'s contract).
+    of a prompt at positions ``start[r] + i``: every layer reads
+    ``slots[r]``'s rows ``< start`` as earlier chunks left them, BEFORE the
+    chunk's own are written, and takes the chunk's own beside them
+    (``merged_chunk_attention``: the softmax a write and then a read give);
+    the nine layers' rows are written once a stack after the loop
+    (``cache_write_chunk``, ``gpt2_prefill_chunk``'s order: a write inside
+    the loop made the compiler copy whole stacks). Every layer continues
+    the slot's SSM state, leaving there, in place, its state after the
+    row's real tokens (``nemotron_h._rows``'s contract).
     -> (hidden [R, T, D] before ``norm_f``, the cache)."""
     r, t = tokens.shape
     dt_ = cfg.dtype
     x = _embed(params, tokens, cfg)
     pos = jnp.arange(t)[None, :] + (0 if start is None else start[:, None])
     if cache is not None:
-        k_all, v_all, conv_all = cache["k"], cache["v"], cache["conv"]
-        ssm_all = list(cache["ssm"])
-        window = window or k_all.shape[2]
+        conv_all, ssm_all = cache["conv"], list(cache["ssm"])
+        k_rows, v_rows = [], []
+        window = window or cache["k"].shape[2]
         goes_on = start > 0  # [R]: the slot holds this prompt's state
     for i, p in enumerate(params["layers"]):
         with jax.named_scope("ln"):
@@ -400,12 +426,14 @@ def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
                     jnp.repeat(v_, rep, axis=2), use_flash=False)
             ssm, _, _ = mamba2.mamba_rows(p, y_ssm, lengths, cfg.mamba)
         else:
-            with jax.named_scope("cache_write"):
-                k_all = cache_write_prompt(k_all, i, k_, slots, start)
-                v_all = cache_write_prompt(v_all, i, v_, slots, start)
+            k_ = _merged_row(k_, cache["k"])
+            v_ = _merged_row(v_, cache["v"])
             with jax.named_scope("attn"):
-                attn = cached_chunk_attention(q, k_all, v_all, i, slots,
-                                              start, window)
+                attn = merged_chunk_attention(
+                    q, cache["k"], cache["v"], k_, v_, i, slots, start,
+                    window)
+            k_rows.append(k_)
+            v_rows.append(v_)
             ssm, conv_all, ssm_all[i] = mamba2.rows_through_cache(
                 p, y_ssm, lengths, conv_all, ssm_all[i], i, slots, goes_on,
                 cfg.mamba)
@@ -413,6 +441,11 @@ def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,
             attn = attn.reshape(r, t, -1) @ p["wo"].astype(dt_)
         x = _mlp(p, _mixer_sum(x, attn, ssm, cfg), cfg)
     if cache is not None:
+        with jax.named_scope("cache_write"):
+            k_all = cache_write_chunk(cache["k"], jnp.stack(k_rows), slots,
+                                      start)
+            v_all = cache_write_chunk(cache["v"], jnp.stack(v_rows), slots,
+                                      start)
         cache = {"k": k_all, "v": v_all, "conv": conv_all,
                  "ssm": tuple(ssm_all)}
     return x, cache

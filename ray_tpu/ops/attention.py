@@ -113,27 +113,43 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
 # updates under a ``fori_loop``, make XLA re-lay out the whole cache around
 # the write. What a row is differs by the cache's rank:
 #
-# * rank 5, ``[n_layer, S, L, G, hd]`` (Llama, the hybrids' few attention
-#   layers, the latent rows): heads apart. Where ``G x hd`` is no whole
-#   number of 128-lane tiles the TPU's compiler lays it out with ``L``
-#   minor-most, and one token's row is then ``G x hd`` tiles of 4 KB.
-# * rank 4, ``[n_layer, S, L, W]`` (GPT-2, PR 42): a token's row MERGED, all
-#   heads side by side, and padded with zero columns to whole lane tiles,
-#   ``W = merged_row_width(H, hd)``. Only with the pad does the compiler
-#   keep the row minor-most (``{3,2,1,0}``; at XL 1600 columns are 12.5
-#   tiles and ``L`` goes minor again, tests/test_serving_programs_v5e.py), so
-#   that a row is ``W / 128`` tiles. The pad belongs to no head: queries
-#   hold zeros there and a head's columns are picked out of the sums. The
+# * rank 5, ``[n_layer, S, L, G, hd]`` (Llama, Nemotron-H's and Granite's few
+#   attention layers, the latent rows): heads apart. Where ``G x hd`` is no
+#   whole number of 128-lane tiles the TPU's compiler lays it out with ``L``
+#   minor-most, and one token's row is then ``G x hd`` tiles of 4 KB. The
+#   grouped products (``_grouped_decode_attention``) want a window with
+#   heads major of rows, so the compiler re-lays each window out before it
+#   reads it. These families keep this rank: the op is under a tenth of
+#   their steps (one attention layer in ten or so), so they have nothing
+#   to gain from another layout and their programs stay what they were.
+# * rank 4, ``[n_layer, S, L, W]`` (GPT-2, PR 42; Falcon-H1, PR 44, whose
+#   EVERY layer owns a ring: eighteen re-laid windows a step were half of
+#   it): a token's row MERGED, all K/V heads side by side, and padded with
+#   zero columns to whole lane tiles, ``W = merged_row_width(G, hd)``. Only
+#   with the pad does the compiler keep the row minor-most (``{3,2,1,0}``;
+#   at XL 1600 columns are 12.5 tiles and ``L`` goes minor again,
+#   tests/test_serving_programs_v5e.py), so that a row is ``W / 128`` tiles
+#   (Falcon-H1's 4 x 128 are four whole tiles and its row has no pad). The
+#   pad belongs to no head: queries hold zeros there and a head's columns
+#   are picked out of the sums. With FEWER K/V heads than query heads
+#   (grouped queries, ``_kv_heads``) each query head stands in ITS K/V
+#   head's columns, a group's heads sharing them. The
 #   layout survives a program only if the program reads the cache BEFORE it
 #   writes it: a loop that writes rows into the cache it carries and then
 #   reads a window from the same carry made the compiler re-lay the whole
-#   cache out and back. So both merged-row programs read the cache as it
-#   was, take their own new rows beside it, and write after the layer loop
-#   (``cache_write_token`` / ``cache_write_chunk``).
+#   cache out and back (or, short of memory, copy whole stacks). So both
+#   merged-row programs read the cache as it was, take their own new rows
+#   beside it, and write after the layer loop (``cache_write_token`` /
+#   ``cache_write_chunk``).
+#
+# A family picks its layout once, in its ``init_cache``; the ops below take
+# the path the rank of what they are handed names. The same bytes reshaped
+# inside an op are another tiled layout on the chip, a copy: only a cache
+# STORED as merged rows is read as it lies.
 
 
 def merged_row_width(n_head: int, head_dim: int) -> int:
-    """Columns of a merged K or V row: the heads side by side, padded to
+    """Columns of a merged K or V row: the (K/V) heads side by side, padded to
     whole 128-lane tiles. A row that fits inside one tile (the toy sizes)
     has no tile boundary to straddle and is left as it is, so a toy
     cache's bytes are still its shape's."""
@@ -259,6 +275,54 @@ def _heads_merged(sums: jax.Array, hd: int) -> jax.Array:
     return jnp.sum(jnp.where(_own_columns(g, hd, cols), sums, 0), axis=-2)
 
 
+def _kv_heads(h: int, hd: int, w: int) -> int:
+    """K/V heads a merged row of ``w`` columns holds under ``h`` query
+    heads of ``hd``: ``h`` where the row has columns for every query head
+    (GPT-2; a pad is nobody's), else ``w // hd``, each serving ``h`` over it
+    query heads (grouped queries: such a row is stored without a pad)."""
+    if h * hd <= w:
+        return h
+    g = w // hd
+    if g * hd != w or h % g:
+        raise ValueError(
+            f"merged rows of {w} columns are no whole number of K/V heads "
+            f"of {hd} that {h} query heads divide into")
+    return g
+
+
+def _group_columns(heads: int, n_kv: int, hd: int) -> jax.Array:
+    """[heads, n_kv * hd] bool: column w is K/V head ``w // hd``'s, and
+    that is query head j's own where ``j // (heads // n_kv)`` names it."""
+    return jnp.arange(n_kv * hd)[None, :] // hd \
+        == jnp.arange(heads)[:, None] // (heads // n_kv)
+
+
+def _heads_in_group_columns(q: jax.Array, n_kv: int) -> jax.Array:
+    """Grouped queries [..., n_kv * R, hd], R query heads under each of
+    ``n_kv`` K/V heads whose columns lie side by side, -> [..., n_kv * R,
+    n_kv * hd]: query head j's numbers stand in K/V head ``j // R``'s
+    columns and zeros in the others (``_heads_apart`` with a group's heads
+    sharing columns). One K/V head's queries are its product's rows as
+    they are: no zero column is made."""
+    if n_kv == 1:
+        return q
+    heads, hd = q.shape[-2:]
+    return jnp.where(_group_columns(heads, n_kv, hd),
+                     jnp.tile(q, (1,) * (q.ndim - 1) + (n_kv,)), 0)
+
+
+def _heads_out_of_group_columns(sums: jax.Array, n_kv: int) -> jax.Array:
+    """The pick back: sums over merged value rows [..., n_kv * R, n_kv *
+    hd], a row a query head, -> [..., n_kv * R, hd], each head's own
+    K/V head's columns."""
+    if n_kv == 1:
+        return sums
+    heads, cols = sums.shape[-2:]
+    hd = cols // n_kv
+    own = jnp.where(_group_columns(heads, n_kv, hd), sums, 0)
+    return jnp.sum(own.reshape(*sums.shape[:-1], n_kv, hd), axis=-2)
+
+
 def _lane_groups(rows: jax.Array, hd: int) -> jax.Array:
     """Merged rows [..., W] cut at whole lane tiles, [..., W / 128, 128]
     (GPT-2's two heads a tile): the cut is a free reshape and a product
@@ -266,8 +330,13 @@ def _lane_groups(rows: jax.Array, hd: int) -> jax.Array:
     over the whole row does ``H`` times. A head size that does not divide
     a tile, or a row narrower than one, keeps the row whole, [..., 1, W]."""
     w = rows.shape[-1]
-    cols = w if 128 % hd or w % 128 else 128
+    cols = _lane_cols(w, hd)
     return rows.reshape(*rows.shape[:-1], w // cols, cols)
+
+
+def _lane_cols(w: int, hd: int) -> int:
+    """Columns of one of ``_lane_groups``' groups of a row of ``w``."""
+    return w if 128 % hd or w % 128 else 128
 
 
 def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
@@ -280,7 +349,9 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     q [R, C, H, hd]; k_all / v_all the stacked cache [N, S, L, W] as it was
     before this chunk (read only: the caller writes after its layer loop,
     ``cache_write_chunk``); k_own / v_own [R, C, W], the chunk's own merged
-    rows in the cache's type; slots, start [R] int32. Query i sees the
+    rows in the cache's type; slots, start [R] int32. A row holds H heads
+    (and a pad) or, grouped queries, ``W // hd`` K/V heads that H is a
+    multiple of (``_kv_heads``). Query i sees the
     slot's rows ``< start`` as earlier chunks left them (cut out of the
     stack: the first ``window - C`` rows, all that a start within the
     caller's bound ``start + C <= window`` can name) and the chunk's own
@@ -297,10 +368,20 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
             cache, (layer, slots[i], 0, 0), (1, 1, old, w))[0, 0]
             for i in range(r)])
 
-    rows = _lane_groups(
-        merged_rows(q.reshape(r, c, h * hd), w).astype(k_all.dtype), hd)
-    # [R, C, T, g, cols]: g heads a group of columns
-    rows = _heads_apart(rows, -(-rows.shape[-1] // hd), hd)
+    g = _kv_heads(h, hd, w)
+    if g == h:
+        rows = _lane_groups(
+            merged_rows(q.reshape(r, c, h * hd), w).astype(k_all.dtype), hd)
+        # [R, C, T, g, cols]: g heads a group of columns
+        rows = _heads_apart(rows, -(-rows.shape[-1] // hd), hd)
+    else:
+        # grouped queries: a group of columns holds ``per`` K/V heads and
+        # their ``per * h // g`` query heads are the product's rows (at
+        # hd = 128 a lane group IS one K/V head)
+        tiles = w // _lane_cols(w, hd)
+        per = g // tiles
+        rows = _heads_in_group_columns(
+            q.astype(k_all.dtype).reshape(r, c, tiles, h // tiles, hd), per)
     # (keys, values, who sees them): the slot's old rows, then the chunk's
     parts = [(k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
     if old:
@@ -320,8 +401,11 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
                          _lane_groups(v, hd),
                          preferred_element_type=jnp.float32)
               for p, (_, v, _) in zip(probs, parts))
-    out = _heads_merged(out, hd).reshape(r, c, w)
-    return out[..., :h * hd].reshape(r, c, h, hd).astype(q.dtype)
+    if g == h:
+        out = _heads_merged(out, hd).reshape(r, c, w)[..., :h * hd]
+    else:
+        out = _heads_out_of_group_columns(out, per)
+    return out.reshape(r, c, h, hd).astype(q.dtype)
 
 
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -344,7 +428,9 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     heads than q (grouped queries: [S, L, G, hd] and [S, G, hd], H a
     multiple of G) each K/V head serves its H // G query heads as it lies,
     with no expanded copy of the window. Where k/v are MERGED rows ([S, L,
-    W] and [S, W], ``merged_row_width``) they are read as they lie too.
+    W] and [S, W], ``merged_row_width`` of as many heads as q has or of
+    the K/V heads that number is a multiple of) they are read as they lie
+    too, with no re-laid copy either.
     fp32 scores/softmax, output cast to the activation dtype — shared by
     the model families' decode steps so the masking/scaling contract lives
     here once.
@@ -405,13 +491,19 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
                              out_dtype, scale=None):
     """``cached_decode_attention`` over merged rows: k/v [S, L, W], k_new /
     v_new [S, W]. The same softmax over the same keys; each head's query
-    stands in its own columns of a row-wide vector, so both products read
+    stands in its own columns of a row-wide vector (grouped queries: in
+    its K/V head's, which the group's heads share), so both products read
     the layer's block as it lies (two MXU products a layer) and the other
     columns add exact zeros."""
     s, h, hd = q.shape
     n_rows, w = k.shape[1:]
-    q = _heads_apart(merged_rows(q.reshape(s, h * hd), w).astype(k.dtype),
-                     h, hd)  # [S, H, W]
+    g = _kv_heads(h, hd, w)
+    if g == h:
+        q = _heads_apart(
+            merged_rows(q.reshape(s, h * hd), w).astype(k.dtype),
+            h, hd)  # [S, H, W]
+    else:
+        q = _heads_in_group_columns(q.astype(k.dtype), g)  # [S, H, W]
     idx = jnp.arange(n_rows)
     at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
     mask = (idx[None, :] < valid[:, None])[:, None, :]
@@ -429,8 +521,11 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
                      v.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     out = out + weight_new[..., None] * v_new.astype(jnp.float32)[:, None]
-    out = _heads_merged(out, hd)  # [S, W]
-    return out[:, :h * hd].reshape(s, h, hd).astype(out_dtype)
+    if g == h:
+        out = _heads_merged(out, hd)[:, :h * hd]  # [S, H * hd]
+    else:
+        out = _heads_out_of_group_columns(out, g)  # [S, H, hd]
+    return out.reshape(s, h, hd).astype(out_dtype)
 
 
 # -- latent cache of the serving path ------------------------------------------

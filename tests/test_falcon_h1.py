@@ -177,7 +177,7 @@ def test_weights_are_bfloat16_the_head_is_its_own_and_every_layer_has_both():
         assert p["wk"].shape == (48, 2 * 16)
     cache = fh.falcon_h1_init_cache(cfg, 3, 16)
     # a K/V ring AND a tail AND a float32 state for each of the 3 layers
-    assert cache["k"].shape == cache["v"].shape == (3, 3, 16, 2, 16)
+    assert cache["k"].shape == cache["v"].shape == (3, 3, 16, 2 * 16)
     assert cache["conv"].shape == (3, 3, 3, cfg.mamba.conv_dim)
     assert [(s.shape, s.dtype) for s in cache["ssm"]] \
         == [((3, 8, 8, 24), jnp.float32)] * 3
@@ -510,17 +510,26 @@ def test_the_mixers_column_multipliers_in_the_step_and_in_rows_alike():
 # engine programs at its tiny preset, taken on the parent of the PR that
 # added Falcon-H1 (PR 43), as ``tests/test_deepseek_v2.py`` holds the four
 # older families' (which that file still holds through this PR: the
-# mixer's column multipliers and the rotary helper were ADDED).
+# mixer's column multipliers and the rotary helper were ADDED). Falcon-H1's
+# own two are the programs of PR 44, whose rings hold merged rows that the
+# step reads as they lie and the chunk reads before it writes: that PR
+# changed them ON PURPOSE (and no other family's); a later one that does
+# replaces these lines and says so.
 LOWERED = {
     ("deepseek_v2", "decode"):
         "49e82efdba2cf0ae90ad81e9be4d1dd37f7b5fbf9a921f2de9ba58ed47100ec8",
     ("deepseek_v2", "prefill"):
         "52a5ec015c69fe816fa3668bc8bb7a33010803b7015750ae1fa51f9b931e67d0",
+    ("falcon_h1", "decode"):
+        "644359f4a9a49274d0ebf41f83523eb66d5a7e48dbc37f0a11b4183d109ff9c7",
+    ("falcon_h1", "prefill"):
+        "0e302753a3af60ac69badd51fccf8994c05d93eb66f0c8b4624a87981698c5c1",
 }
 
 
 @pytest.mark.parametrize("model, program", sorted(LOWERED))
-def test_the_fifth_familys_programs_are_what_they_were(model, program):
+def test_the_fifth_and_sixth_familys_programs_are_what_they_were(model,
+                                                                   program):
     from ray_tpu.serve.llm_engine import _model_bundle
 
     cfg, init, init_cache, chunk, step = _model_bundle(model, None, "tiny")

@@ -1,5 +1,5 @@
 """What the TPU's compiler makes of Falcon-H1's two serving programs
-(PR 43).
+(PR 43; the rings as merged rows, PR 44).
 
 Compile-only, for one described v5e chip, at the published widths of
 ``benchmark/configs/falcon-h1-34b-instruct.json`` and the shapes of the cell
@@ -10,8 +10,11 @@ nothing runs, so nothing here is a time. It holds that both programs fit the
 chip beside their arguments (the decode step's float32 pass over 33 K/V
 windows in nine layers included), that the donated cache is updated in its
 own buffers, that no program makes a float32 array as long as a ring or
-copies a layer's state, and that the chunk program keeps the cache in the
-step's layout: XLA's choices decide that, not the jaxpr.
+copies a layer's state, that the step re-lays no ring out (the rings hold a
+token's K/V heads merged in one row of 512 columns, four whole lane tiles,
+and both products read them as they lie), that the chunk program writes
+each stack once and makes no other array that large, and that it keeps the
+cache in the step's layout: XLA's choices decide that, not the jaxpr.
 
 The topology is described inside a fixture, in this one file: only the
 worker that runs this file loads the TPU's library.
@@ -115,7 +118,7 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     """4.205 B bfloat16 parameters (8.41 GB) and 4.37 GB of cache are the
     arguments; the cache is aliased to the output, so it is held once."""
     mem = compiled[which].memory_analysis()
-    cache_bytes = 2 * nbytes((9, 33, 5120, 4, 128), 2) \
+    cache_bytes = 2 * nbytes((9, 33, 5120, 512), 2) \
         + nbytes((9, 3, 33, cfg.mamba.conv_dim), 2) \
         + nbytes((9, 33, 32, 128, 256), 4) + 4
     assert cache_bytes == 33 * 9 * 14_710_784 + 4 == 4_369_102_852
@@ -126,16 +129,18 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert 12.77e9 < mem.argument_size_in_bytes < 12.80e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # a chunk holds its scores over the 4096-row window (84 MB in float32 a
-    # layer) and little else. The step holds 2.8 GB: the compiler re-lays
-    # every layer's K and V window out, [row, head] -> [head, row], for the
-    # grouped products (the test below), and keeps the eighteen copies of
-    # 173 MB alive side by side. PERF.md section 7 names the debt.
-    assert mem.temp_size_in_bytes < {"decode": 2.9e9, "prefill": 0.6e9}[which]
+    # layer), the nine layers' old rows cut out of both stacks at once and
+    # little else: 0.75 GB. The step holds 0.04 GB. It held 2.8 GB while the
+    # rings were [row, K/V head, head_dim] and the compiler re-laid every
+    # layer's K and V window out for the grouped products, eighteen copies
+    # of 173 MB alive side by side: that debt (PERF.md section 7, PR 43) is
+    # paid, PR 44.
+    assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 0.9e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
                    r"([\w\-]+)\(")
-RING = nbytes((33, 5120, 4, 128), 1)     # elements of a layer's K or V ring
+RING = nbytes((33, 5120, 512), 1)        # elements of a layer's K or V ring
 STATE = nbytes((33, 32, 128, 256), 1)    # elements of a layer's SSM state
 
 
@@ -167,20 +172,43 @@ def test_no_float32_array_as_long_as_a_ring_and_no_state_is_copied(compiled,
     33 x 32 x 128 x 256 float32 (138 MB). Neither program widens a ring to
     float32 (346 MB a layer: the step's float32 scores are over 20 heads x
     5120 rows, 13.5 MB), and neither copies a state: it is rewritten inside
-    its donated buffer. What the step DOES make is a bfloat16 copy of each
-    layer's K and V window with rows and heads swapped, eighteen in all
-    (a later PR that reads the rings as they lie brings this to zero: the
-    bound is from above)."""
+    its donated buffer. Nor does either make a copy of a ring in any type:
+    the step made eighteen, each layer's K and V window with rows and heads
+    swapped, until the rings held merged rows (PR 44)."""
     text = compiled[which].as_text()
     made = list(_arrays_made(_unfused(text)))
     assert len(made) > 50, "read no program"
     assert [m for m in made if m[0] == "f32" and m[1] >= RING] == []
     assert [m for m in made if m[1] in (STATE, 9 * STATE)] == []
     # (inside fusions too: a fusion whose root is a copy writes it out)
-    relaid = [m for m in _arrays_made(text)
-              if m[1] == RING and m[2] == "copy"]
-    assert all(m[0] == "bf16" for m in relaid)
-    assert len(relaid) <= {"decode": 18, "prefill": 0}[which]
+    assert [m for m in _arrays_made(text)
+            if m[1] == RING and m[2] == "copy"] == []
+
+
+RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+STACK = 9 * RING                         # elements of the whole K or V stack
+HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
+        compiled):
+    """The K stack and the V stack are 1.56 GB each. The chunk program
+    read the slot's old rows before it writes its own, so all it does to a
+    stack is ONE row-sized ``dynamic-update-slice`` into the donated
+    buffer, after the layer loop: no fusion, copy or anything else, inside
+    a fusion or outside, gives out an array that large (a tuple's members
+    counted each). While the write stood inside the loop, two fusions each
+    gave a whole stack out anew, 14 of a chunk's 26.5 ms (PERF.md section
+    6, PR 43)."""
+    made = []
+    for line in compiled["prefill"].as_text().splitlines():
+        m = RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                nbytes([int(d) for d in dims.split(",")], 1) >= STACK
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 2, made
+    assert len({stack for _, stack in made}) == 2, made
 
 
 def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
@@ -193,6 +221,6 @@ def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
         return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
             shape + r"(\{[^}]*\})", compiled[which].as_text())}
 
-    for shape in (r"bf16\[9,33,5120,4,128\]", r"f32\[33,32,128,256\]"):
+    for shape in (r"bf16\[9,33,5120,512\]", r"f32\[33,32,128,256\]"):
         assert len(layouts(shape, "prefill")) == 1, shape
         assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
