@@ -6,7 +6,10 @@ Mamba-2 mixer or attention, then gated experts, in every layer) are served
 only, as are DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a
 latent cache, group-limited experts) and Falcon-H1 (``models/falcon_h1.py``:
 rotary grouped-query attention AND a Mamba-2 mixer side by side in every
-layer, both caches a layer, fourteen muP multipliers); those two are not
+layer, both caches a layer, fourteen muP multipliers) and Qwen3-Next
+(``models/qwen3_next.py``: three Gated DeltaNet layers to one gated-
+attention layer, a float32 delta-rule state beside K/V rings, top-10-of-512
+experts and a gated shared expert in every layer); those three are not
 exported here, so that a process which serves another family never imports
 them: ``LLMEngine`` resolves them by name. Models are plain
 functions over parameter pytrees — no framework Module state — so the same

@@ -157,6 +157,38 @@ def dt_and_a(p: Params, dt_raw: jax.Array):
     return dt, -jnp.exp(p["a_log"].astype(jnp.float32))
 
 
+def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array):
+    """The causal depthwise convolution of ONE token a slot: tail [K-1, S, C]
+    the last inputs, x [S, C] this token's, w [K, C]. -> (the window
+    [K, S, C] in the tail's type, whose rows 1.. are the new tail, and the
+    convolution [S, C] float32, no bias). Shared with ``ops/gated_delta.py``."""
+    window = jnp.concatenate(
+        [tail, x.astype(tail.dtype)[None]], axis=0)  # [K, S, C]
+    return window, jnp.einsum("ksc,kc->sc", window.astype(jnp.float32),
+                              w.astype(jnp.float32))
+
+
+def conv_rows(x: jax.Array, tail: jax.Array | None, w: jax.Array):
+    """The causal depthwise convolution of rows x [R, T, C] that continue
+    from ``tail`` [R, K-1, C] (None: rows that begin), w [K, C]. -> (the
+    inputs with their left context [R, K-1 + T, C], the convolution
+    [R, T, C] float32, no bias)."""
+    k, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))) if tail is None \
+        else jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = w.astype(jnp.float32)
+    return padded, sum(padded[:, j:j + t].astype(jnp.float32) * w[j]
+                       for j in range(k))
+
+
+def conv_tail(padded: jax.Array, lengths: jax.Array, k: int) -> jax.Array:
+    """The convolution's tail after ``lengths`` [R] real tokens of
+    ``conv_rows``' ``padded``: the inputs at length - (K-1) .. length - 1,
+    the old tail before 0. -> [K-1, R, C]."""
+    at = lengths[None, :] + jnp.arange(k - 1)[:, None]  # into `padded`
+    return padded[jnp.arange(padded.shape[0])[None, :], at]
+
+
 def mamba_step(p: Params, y: jax.Array, tail: jax.Array, state: jax.Array,
                dims: Mamba2Dims):
     """One token a slot. y [S, D] (normed), tail [K-1, S, C] the last
@@ -168,11 +200,8 @@ def mamba_step(p: Params, y: jax.Array, tail: jax.Array, state: jax.Array,
     with jax.named_scope("ssm_proj"):
         z, xbc, dt_raw = ssm_inputs(y @ p["in_proj"].astype(dt_), dims)
     with jax.named_scope("conv"):
-        window = jnp.concatenate(
-            [tail, xbc.astype(tail.dtype)[None]], axis=0)  # [K, S, C]
-        conv = jnp.einsum("ksc,kc->sc", window.astype(jnp.float32),
-                          p["conv_w"].astype(jnp.float32)) \
-            + p["conv_b"].astype(jnp.float32)
+        window, conv = conv_step(tail, xbc, p["conv_w"])
+        conv = conv + p["conv_b"].astype(jnp.float32)
         xs, b, c = ssm_split(jax.nn.silu(conv).astype(dt_), dims)
     with jax.named_scope("ssm_update"):
         dt, a = dt_and_a(p, dt_raw)  # [S, H], [H]
@@ -262,15 +291,10 @@ def mamba_rows(p: Params, y: jax.Array, lengths: jax.Array,
     with jax.named_scope("ssm_proj"):
         z, xbc, dt_raw = ssm_inputs(y @ p["in_proj"].astype(dt_), dims)
     with jax.named_scope("conv"):
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))) if tail is None \
-            else jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-        w = p["conv_w"].astype(jnp.float32)
-        conv = sum(padded[:, j:j + t].astype(jnp.float32) * w[j]
-                   for j in range(k)) + p["conv_b"].astype(jnp.float32)
+        padded, conv = conv_rows(xbc, tail, p["conv_w"])
+        conv = conv + p["conv_b"].astype(jnp.float32)
         xs, b, c = ssm_split(jax.nn.silu(conv).astype(dt_), dims)
-        # the inputs at length - (K-1) .. length - 1, the old tail before 0
-        at = lengths[None, :] + jnp.arange(k - 1)[:, None]  # into `padded`
-        tail = padded[jnp.arange(r)[None, :], at]  # [K-1, R, C]
+        tail = conv_tail(padded, lengths, k)
     with jax.named_scope("ssm_scan"):
         dt, a = dt_and_a(p, dt_raw)
         dt = jnp.where(jnp.arange(t)[None, :, None] < lengths[:, None, None],

@@ -174,9 +174,16 @@ def _model_bundle(model: str, config, preset: str):
                          else m.FalconH1Config())
         return (cfg, m.falcon_h1_init, m.falcon_h1_init_cache,
                 m.falcon_h1_prefill_chunk, m.falcon_h1_decode_step)
+    if model == "qwen3_next":
+        from ray_tpu.models import qwen3_next as m
+
+        cfg = config or (m.Qwen3NextConfig.tiny() if preset == "tiny"
+                         else m.Qwen3NextConfig())
+        return (cfg, m.qwen3_next_init, m.qwen3_next_init_cache,
+                m.qwen3_next_prefill_chunk, m.qwen3_next_decode_step)
     raise ValueError(
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h|"
-        f"granite_hybrid|deepseek_v2|falcon_h1)")
+        f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next)")
 
 
 def _stored_params(init, key, cfg):

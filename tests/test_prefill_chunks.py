@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            llama, nemotron_h)
+                            llama, nemotron_h, qwen3_next)
 from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
 from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -57,6 +57,13 @@ FAMILIES = {
         falcon_h1.falcon_h1_init, falcon_h1.falcon_h1_init_cache,
         falcon_h1.falcon_h1_prefill_chunk, falcon_h1.falcon_h1_prefill,
         falcon_h1.falcon_h1_forward),
+    # (one period, L L L F: this file compares states at 1e-5, and two
+    # periods of float32 sums in another order pass that by a third)
+    "qwen3_next": (qwen3_next.Qwen3NextConfig.tiny(
+        dtype=F32, param_dtype=F32, scan_block=4, n_layer=4),
+        qwen3_next.qwen3_next_init, qwen3_next.qwen3_next_init_cache,
+        qwen3_next.qwen3_next_prefill_chunk, qwen3_next.qwen3_next_prefill,
+        qwen3_next.qwen3_next_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 # GPT-2's merged, lane-padded rows at the head counts it is served with: XL's

@@ -1,0 +1,225 @@
+"""What the TPU's compiler makes of Qwen3-Next's two serving programs.
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/qwen3-next-80b-a3b-instruct.json`` and the shapes of the
+cell ``serve_qwen3next_mixedctx_sat`` (64 slots and the scratch one, a
+float32 delta state [65, 32, 128, 128] in six of eight layers beside rings
+of 18432 merged rows in the other two, 128 held experts a layer, prompts of
+up to 16384 tokens in the engine's [1, 256] chunks over a key window of
+16384): nothing runs, so nothing here is a time. It holds that both programs
+fit the chip beside their arguments (13.08 GB of weights and cache), that
+the donated cache is updated in its own buffers, that no program makes a
+float32 array as long as a ring or copies a layer's delta state, that the
+step re-lays no ring out (a token's two K/V heads of 256 lanes merged in one
+row of 512 columns, four whole lane tiles, read as they lie), that the chunk
+program writes each stack once and makes no other array that large, and
+that it keeps the cache in the step's layout: XLA's choices decide that, not
+the jaxpr.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import qwen3_next as qn
+from ray_tpu.models.prefill import chunk_len, key_window
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return load_json(os.path.join(
+        REPO, "benchmark", "deployments",
+        "qwen3next_1chip_b64.json"))["engine"]
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "qwen3_next.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "qwen3-next-80b-a3b-instruct.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg, engine):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = engine["max_batch"] + 1
+    chunk = chunk_len(engine["max_prompt_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (65, 256, 16384, 18432)
+    params = sds(jax.eval_shape(
+        lambda: qn.qwen3_next_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: qn.qwen3_next_init_cache(
+        cfg, slots, engine["cache_len"])))
+    programs = {
+        "decode": (lambda p, c, t, n: qn.qwen3_next_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(slots), i32(slots))),
+        "prefill": (lambda p, c, t, s, at, n: qn.qwen3_next_prefill_chunk(
+            p, c, t, s, at, n, cfg, window=window),
+            (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+                for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
+                                                        which):
+    """3.667 B bfloat16 parameters (7.33 GB) and 5.74 GB of cache are the
+    arguments; the cache is aliased to the output, so it is held once."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = 2 * nbytes((2, 65, 18432, 512), 2) \
+        + nbytes((6, 3, 65, cfg.delta.conv_dim), 2) \
+        + 6 * nbytes((65, 32, 128, 128), 4) + 4
+    assert cache_bytes == 65 * (6 * 2_146_304 + 18432 * 4096) + 4 \
+        == 5_744_394_244
+    assert mem.alias_size_in_bytes >= cache_bytes
+    gb = {k: getattr(mem, k + "_size_in_bytes") / 1e9
+          for k in ("argument", "temp", "alias", "output")}
+    print(which, gb)
+    assert 13.07e9 < mem.argument_size_in_bytes < 13.10e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
+    # the step holds its float32 scores over 65 rings of 18432 rows a head
+    # (77 MB a layer) and the experts' [128, 65, 1024] product, and no copy
+    # of a ring or a state: 0.05 GB. A chunk holds its scores over the
+    # 16384-row window (268 MB in float32 a full layer), both stacks' old
+    # rows cut out and the experts' [128, 256, 1024] float32 product
+    # (134 MB): 0.35 GB.
+    assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 0.6e9}[which]
+
+
+SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                   r"([\w\-]+)\(")
+RING = nbytes((65, 18432, 512), 1)       # elements of a layer's K or V ring
+STATE = nbytes((65, 32, 128, 128), 1)    # elements of a layer's delta state
+
+
+def _unfused(hlo_text):
+    """The text of every computation but the ones a ``fusion`` calls:
+    inside a fusion a slice or a convert is a step of one loop, not a
+    buffer."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    return "\n".join(block for block in hlo_text.split("\n\n")
+                     if block.lstrip().split(" ", 1)[0] not in fused)
+
+
+def _arrays_made(hlo_text):
+    """(type, elements, opcode) of every instruction of ``hlo_text`` that
+    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
+    slices."""
+    for line in hlo_text.splitlines():
+        m = SHAPE.match(line)
+        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
+                                "dynamic-slice"):
+            yield m.group(1), nbytes(
+                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_float32_array_as_long_as_a_ring_and_no_state_is_copied(compiled,
+                                                                   which):
+    """A full layer's ring is 65 x 18432 x 512 bfloat16 (1.23 GB), a linear
+    layer's state 65 x 32 x 128 x 128 float32 (136 MB). Neither program
+    widens a ring to float32 (2.45 GB a layer: it would not fit), and
+    neither copies a state: it is rewritten inside its donated buffer. Nor
+    does either make a copy of a ring in any type."""
+    text = compiled[which].as_text()
+    made = list(_arrays_made(_unfused(text)))
+    assert len(made) > 50, "read no program"
+    assert [m for m in made if m[0] == "f32" and m[1] >= RING] == []
+    assert [m for m in made if m[1] in (STATE, 6 * STATE)] == []
+    # (inside fusions too: a fusion whose root is a copy writes it out)
+    assert [m for m in _arrays_made(text)
+            if m[1] == RING and m[2] == "copy"] == []
+
+
+RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+STACK = 2 * RING                         # elements of the whole K or V stack
+HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
+        compiled):
+    """The K stack and the V stack are 2.45 GB each. The chunk program
+    reads the slot's old rows before it writes its own, so all it does to a
+    stack is ONE row-sized ``dynamic-update-slice`` into the donated
+    buffer, after the layer loop: no fusion, copy or anything else, inside
+    a fusion or outside, gives out an array that large (a tuple's members
+    counted each)."""
+    made = []
+    for line in compiled["prefill"].as_text().splitlines():
+        m = RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                nbytes([int(d) for d in dims.split(",")], 1) >= STACK
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 2, made
+    assert len({stack for _, stack in made}) == 2, made
+
+
+def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
+    """The stacked K/V rings and a layer's delta state (5.72 of the
+    cache's 5.74 GB): each shape has one layout as a whole array in the
+    chunk program, and it is the decode program's, so neither is re-laid
+    out between the two; the rings are row-minor (a merged row of 512
+    columns is four whole lane tiles)."""
+    def layouts(shape, which):
+        # (a trailing S(n) names a memory space, not a layout)
+        return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+            shape + r"(\{[^}]*\})", compiled[which].as_text())}
+
+    for shape in (r"bf16\[2,65,18432,512\]", r"f32\[65,32,128,128\]"):
+        assert len(layouts(shape, "prefill")) == 1, shape
+        assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
+    assert all(found.startswith("{3,2,1,0")
+               for found in layouts(r"bf16\[2,65,18432,512\]", "decode"))
