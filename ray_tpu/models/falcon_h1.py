@@ -57,7 +57,7 @@ from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
                                    merged_chunk_attention, merged_row_width,
-                                   merged_rows)
+                                   merged_rows, ring_rows_counted)
 from ray_tpu.ops.rotary import rotate
 
 Params = dict[str, Any]
@@ -347,9 +347,11 @@ def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
 # jax-hot-path: traced into the engine's single compiled decode step
 def falcon_h1_decode_step(params: Params, cache: Params, tokens: jax.Array,
                           pos: jax.Array, cfg: FalconH1Config
-                          ) -> tuple[jax.Array, Params]:
+                          ) -> tuple[jax.Array, Params, dict]:
     """One decode iteration for every slot: tokens [S] int32, pos [S]
-    int32 -> (logits [S, V] fp32, new cache). The K/V part keeps
+    int32 -> (logits [S, V] fp32, new cache, counters ``ring_rows_read``
+    and ``ring_rows_held``: what the step's attention read of the rings,
+    ``ops/attention.ring_rows_counted``). The K/V part keeps
     ``gpt2_decode_step``'s ring contract and its layout (the rings of
     merged rows are read as they were, each layer's block as it lies with
     the five query heads of a K/V head standing in its columns, and every
@@ -371,8 +373,8 @@ def falcon_h1_decode_step(params: Params, cache: Params, tokens: jax.Array,
         v_new = _merged_row(v_new, cache["v"])
         with jax.named_scope("attn"):
             attn = cached_decode_attention(
-                q, cache["k"][i], cache["v"][i], k_new, v_new, cursor,
-                valid, dt_)
+                q, cache["k"], cache["v"], k_new, v_new, cursor, valid,
+                dt_, layer=i)
         with jax.named_scope("attn_proj"):
             attn = attn.reshape(s, -1) @ p["wo"].astype(dt_)
         ssm, conv_all, ssm_all[i] = mamba2.step_through_cache(
@@ -385,7 +387,8 @@ def falcon_h1_decode_step(params: Params, cache: Params, tokens: jax.Array,
         k_all = cache_write_token(cache["k"], jnp.stack(k_rows), cursor)
         v_all = cache_write_token(cache["v"], jnp.stack(v_rows), cursor)
     return _head(x, params, cfg), {
-        "k": k_all, "v": v_all, "conv": conv_all, "ssm": tuple(ssm_all)}
+        "k": k_all, "v": v_all, "conv": conv_all, "ssm": tuple(ssm_all)
+    }, ring_rows_counted(cache["k"], valid)
 
 
 def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,

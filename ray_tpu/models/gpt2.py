@@ -403,10 +403,12 @@ def gpt2_loss(params: Params, batch: dict[str, jax.Array], cfg: GPT2Config) -> j
 # the shared buffer out (``tests/test_serving_programs_v5e.py`` reads what
 # the TPU's compiler makes of each):
 #
-# * decode reads each layer's block as a read-only ``xs``, the loop's
-#   ``ys`` are only the new rows ``[n_layer, S, W]``, and one
-#   ``cache_write`` after the loop puts them at the cursors; attention
-#   takes the new token's score and value beside the old window
+# * decode's loop runs over the layer's index with the stacked cache
+#   closed over, read only (a layer's block as the scan's ``xs`` would be
+#   copied out for the attention's kernel), the loop's ``ys`` are only the
+#   new rows ``[n_layer, S, W]``, and one ``cache_write`` after the loop
+#   puts them at the cursors; attention takes the stack, the layer's index
+#   and the new token's score and value beside the old window
 #   (``cached_decode_attention``), so it is the same softmax over the same
 #   keys as a write-then-read.
 # * a prefill chunk's loop cuts the slot's rows ``< start`` (a window, not
@@ -451,17 +453,29 @@ _SERVED_IN_DTYPE = frozenset({
     "mlp_in_w", "mlp_in_b", "mlp_out_w", "mlp_out_b"})
 
 
-# jax-hot-path: traced into the engine's single compiled decode step
 def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
                      pos: jax.Array, cfg: GPT2Config
                      ) -> tuple[jax.Array, Params]:
+    """``gpt2_decode_step_counted`` without its counters: (logits, new
+    cache), what the step has always returned and its callers outside the
+    engine unpack."""
+    return gpt2_decode_step_counted(params, cache, tokens, pos, cfg)[:2]
+
+
+# jax-hot-path: traced into the engine's single compiled decode step
+def gpt2_decode_step_counted(params: Params, cache: Params,
+                             tokens: jax.Array, pos: jax.Array,
+                             cfg: GPT2Config
+                             ) -> tuple[jax.Array, Params, dict]:
     """One decode iteration for every slot.
 
     tokens [S] int32 (the slot's current token), pos [S] int32 (its
     absolute position). Attends over the valid window with each token's
     own K/V in the place its ring cursor names, writes those rows there
     after the layer loop, and returns (logits [S, V] fp32, new cache:
-    the given one with S rows a layer changed, in place when donated).
+    the given one with S rows a layer changed, in place when donated,
+    counters ``ring_rows_read`` and ``ring_rows_held``: what the step's
+    attention read of the rings, ``ops/attention.ring_rows_counted``).
     Free slots simply compute garbage into their own cache rows — the
     fixed shape is the point."""
     s = tokens.shape[0]
@@ -476,21 +490,27 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
             + params["wpe"].astype(dt)[wpe_pos]
 
     from ray_tpu.ops.attention import (cache_write_token,
-                                       cached_decode_attention)
+                                       cached_decode_attention,
+                                       ring_rows_counted)
+
+    counted = ring_rows_counted(cache["k"], valid)
 
     def block(x, layer):
-        p, k_cache, v_cache = layer  # read only: the rows go out as ys
+        # the stacked cache is read as it was, a layer by its index (a
+        # layer's slice as the scan's ``xs`` would be copied out for the
+        # attention's kernel): the rows go out as ys
+        p, i = layer
         with jax.named_scope("ln"):
             y = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         with jax.named_scope("attn_proj"):
             qkv = y @ p["attn_qkv_w"].astype(dt) + p["attn_qkv_b"].astype(dt)
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            k_new = _merged_row(k_new, k_cache)
-            v_new = _merged_row(v_new, v_cache)
+            k_new = _merged_row(k_new, cache["k"])
+            v_new = _merged_row(v_new, cache["v"])
         with jax.named_scope("attn"):
             attn = cached_decode_attention(
-                q.reshape(s, h, hd), k_cache, v_cache, k_new, v_new,
-                cursor, valid, dt)
+                q.reshape(s, h, hd), cache["k"], cache["v"], k_new, v_new,
+                cursor, valid, dt, layer=i)
         with jax.named_scope("attn_proj"):
             x = x + attn.reshape(s, d) @ p["attn_out_w"].astype(dt) \
                 + p["attn_out_b"].astype(dt)
@@ -498,7 +518,7 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
         return x, (k_new, v_new)
 
     x, (k_rows, v_rows) = jax.lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"]))
+        block, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     with jax.named_scope("cache_write"):
         cache = {"k": cache_write_token(cache["k"], k_rows, cursor),
                  "v": cache_write_token(cache["v"], v_rows, cursor)}
@@ -508,7 +528,7 @@ def gpt2_decode_step(params: Params, cache: Params, tokens: jax.Array,
         logits = jnp.einsum(
             "sd,vd->sv", x, params["wte"].astype(dt),
             preferred_element_type=jnp.float32)
-    return logits, cache
+    return logits, cache, counted
 
 
 def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:

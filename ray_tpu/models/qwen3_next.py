@@ -55,7 +55,7 @@ from ray_tpu.ops import gated_delta
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
                                    merged_chunk_attention, merged_row_width,
-                                   merged_rows)
+                                   merged_rows, ring_rows_counted)
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
@@ -399,7 +399,9 @@ def qwen3_next_decode_step(params: Params, cache: Params, tokens: jax.Array,
                            ) -> tuple[jax.Array, Params, dict]:
     """One decode iteration for every slot: tokens [S] int32, pos [S]
     int32 -> (logits [S, V] fp32, new cache, counters ``experts_hit`` and
-    ``expert_rows`` over the step's layers). Every row is computed, free
+    ``expert_rows`` over the step's layers, and ``ring_rows_read`` and
+    ``ring_rows_held``: what the full layers' attention read of the rings,
+    ``ops/attention.ring_rows_counted``). Every row is computed, free
     slots and the scratch one too, so the counters count what the step
     really routed. The K/V part keeps ``gpt2_decode_step``'s ring contract
     and its layout (rings of merged rows read as they were, every full
@@ -425,8 +427,8 @@ def qwen3_next_decode_step(params: Params, cache: Params, tokens: jax.Array,
             v_new = _merged_row(v_new, cache["v"])
             with jax.named_scope("attn"):
                 attn = cached_decode_attention(
-                    q, cache["k"][i_f], cache["v"][i_f], k_new, v_new,
-                    cursor, valid, dt_)
+                    q, cache["k"], cache["v"], k_new, v_new, cursor, valid,
+                    dt_, layer=i_f)
             out = _attn_out(p, attn, gate, cfg)
             k_rows.append(k_new)
             v_rows.append(v_new)
@@ -441,7 +443,8 @@ def qwen3_next_decode_step(params: Params, cache: Params, tokens: jax.Array,
     return _head(x, params, cfg), {
         "k": k_all, "v": v_all, "conv": conv_all,
         "delta": tuple(delta_all),
-        "counted": cache["counted"]}, held_counters(counts)
+        "counted": cache["counted"]}, {
+            **held_counters(counts), **ring_rows_counted(cache["k"], valid)}
 
 
 def _rows(params: Params, tokens: jax.Array, lengths: jax.Array,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops import ring_decode
 from ray_tpu.ops.flash_attention import flash_block, flash_causal_attention
 
 # Sequence length at/above which the flash kernel pays for itself.
@@ -140,7 +141,11 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
 #   cache out and back (or, short of memory, copy whole stacks). So both
 #   merged-row programs read the cache as it was, take their own new rows
 #   beside it, and write after the layer loop (``cache_write_token`` /
-#   ``cache_write_chunk``).
+#   ``cache_write_chunk``). The decode step hands the STACK and a layer's
+#   index to ``cached_decode_attention`` (PR 48): rings of whole blocks of
+#   rows go through the kernel of ``ops/ring_decode.py``, which fetches a
+#   slot's blocks up to its last live row and no further, and a custom
+#   call handed a layer's slice would have it copied out first.
 #
 # A family picks its layout once, in its ``init_cache``; the ops below take
 # the path the rank of what they are handed names. The same bytes reshaped
@@ -411,8 +416,8 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             k_new: jax.Array, v_new: jax.Array,
                             cursor: jax.Array, valid: jax.Array,
-                            out_dtype, scale: float | None = None
-                            ) -> jax.Array:
+                            out_dtype, scale: float | None = None,
+                            layer=None) -> jax.Array:
     """One query token per slot over the slot's ring-cache window, the
     token itself included, WITHOUT its row being in the cache yet.
 
@@ -430,16 +435,24 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     with no expanded copy of the window. Where k/v are MERGED rows ([S, L,
     W] and [S, W], ``merged_row_width`` of as many heads as q has or of
     the K/V heads that number is a multiple of) they are read as they lie
-    too, with no re-laid copy either.
+    too, with no re-laid copy either. A family that holds merged rows hands
+    in its STACKED cache [N, S, L, W] and ``layer``, which of the N (an int
+    or an int32 scalar): rings of whole blocks of rows of whole lane tiles
+    then go through the kernel of ``ops/ring_decode.py``, which stops each
+    slot at its last live block and is handed the stack because a slice
+    handed to a custom call would be copied out first; any other merged
+    ring is cut out of the stack and read whole.
     fp32 scores/softmax, output cast to the activation dtype — shared by
     the model families' decode steps so the masking/scaling contract lives
     here once.
     ``scale`` multiplies the scores where a model publishes its own
     constant; None divides them by ``hd ** 0.5`` as ever."""
     hd = q.shape[-1]
-    if k.ndim == 3:
+    if layer is not None and not ring_decode.takes_kernel(*k.shape[2:]):
+        k, v, layer = k[layer], v[layer], None
+    if layer is not None or k.ndim == 3:
         return _merged_decode_attention(q, k, v, k_new, v_new, cursor,
-                                        valid, out_dtype, scale)
+                                        valid, out_dtype, scale, layer)
     if k.shape[2] != q.shape[1]:
         return _grouped_decode_attention(q, k, v, k_new, v_new, cursor,
                                          valid, out_dtype, scale)
@@ -457,6 +470,23 @@ def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      v.astype(jnp.float32))
     out = out + weight_new[..., None] * v_new.astype(jnp.float32)
     return out.astype(out_dtype)
+
+
+def ring_rows_counted(k_all: jax.Array, valid: jax.Array) -> dict:
+    """What one decode step's ``cached_decode_attention`` calls read of a
+    stacked cache of merged rows [N, S, L, W] over slots of ``valid`` [S]
+    live rows, and what the rings hold: ``ring_rows_read`` (over layers and
+    slots, whole blocks up to the last live one where the kernel runs, every
+    row where XLA reads the ring whole) and ``ring_rows_held`` (N x S x L),
+    the int32 scalars a family's decode step returns behind its tokens."""
+    n, s, n_rows, w = k_all.shape
+    held = jnp.int32(n * s * n_rows)
+    if not ring_decode.takes_kernel(n_rows, w):
+        return {"ring_rows_read": held, "ring_rows_held": held}
+    block = ring_decode.BLOCK_ROWS
+    return {"ring_rows_read": (n * jnp.sum(-(-valid // block) * block)
+                               ).astype(jnp.int32),
+            "ring_rows_held": held}
 
 
 def _scaled(scores, hd: int, scale: float | None):
@@ -488,15 +518,18 @@ def _grouped_decode_attention(q, k, v, k_new, v_new, cursor, valid,
 
 
 def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
-                             out_dtype, scale=None):
+                             out_dtype, scale=None, layer=None):
     """``cached_decode_attention`` over merged rows: k/v [S, L, W], k_new /
     v_new [S, W]. The same softmax over the same keys; each head's query
     stands in its own columns of a row-wide vector (grouped queries: in
     its K/V head's, which the group's heads share), so both products read
     the layer's block as it lies (two MXU products a layer) and the other
-    columns add exact zeros."""
+    columns add exact zeros. With ``layer``, k/v are the stacked cache [N,
+    S, L, W] and the kernel makes the sums, up to each slot's last live
+    block of rows; without, XLA does over the whole ring (the form the
+    kernel is held to)."""
     s, h, hd = q.shape
-    n_rows, w = k.shape[1:]
+    w = k.shape[-1]
     g = _kv_heads(h, hd, w)
     if g == h:
         q = _heads_apart(
@@ -504,7 +537,28 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
             h, hd)  # [S, H, W]
     else:
         q = _heads_in_group_columns(q.astype(k.dtype), g)  # [S, H, W]
-    idx = jnp.arange(n_rows)
+    if layer is None:
+        out = _whole_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd,
+                               scale)
+    else:
+        # whole sublane tiles of heads: a zero query's row is cut off again
+        out = ring_decode.ring_decode_attention(
+            jnp.pad(q, ((0, 0), (0, -h % 8), (0, 0))), k, v, layer, k_new,
+            v_new, cursor, valid,
+            functools.partial(_scaled, hd=hd, scale=scale))[:, :h]
+    if g == h:
+        out = _heads_merged(out, hd)[:, :h * hd]  # [S, H * hd]
+    else:
+        out = _heads_out_of_group_columns(out, g)  # [S, H, hd]
+    return out.reshape(s, h, hd).astype(out_dtype)
+
+
+def _whole_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd, scale):
+    """float32 sums [S, H, W] over merged value rows of queries q [S, H, W]
+    that stand in their own columns: every ring row is read, a mask keeps
+    the live ones, the new token's score stands at the cursor's row and its
+    value is added beside the window's."""
+    idx = jnp.arange(k.shape[1])
     at_cursor = (idx[None, :] == cursor[:, None])[:, None, :]  # [S, 1, L]
     mask = (idx[None, :] < valid[:, None])[:, None, :]
     scores = jnp.einsum("shw,slw->shl", q, k,
@@ -520,12 +574,7 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
     out = jnp.einsum("shl,slw->shw", jnp.where(at_cursor, 0.0, weights),
                      v.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    out = out + weight_new[..., None] * v_new.astype(jnp.float32)[:, None]
-    if g == h:
-        out = _heads_merged(out, hd)[:, :h * hd]  # [S, H * hd]
-    else:
-        out = _heads_out_of_group_columns(out, g)  # [S, H, hd]
-    return out.reshape(s, h, hd).astype(out_dtype)
+    return out + weight_new[..., None] * v_new.astype(jnp.float32)[:, None]
 
 
 # -- latent cache of the serving path ------------------------------------------

@@ -138,7 +138,7 @@ def _model_bundle(model: str, config, preset: str):
         cfg = config or (m.GPT2Config.tiny() if preset == "tiny"
                          else m.GPT2Config.small())
         return (cfg, m.gpt2_init, m.gpt2_init_cache, m.gpt2_prefill_chunk,
-                m.gpt2_decode_step)
+                m.gpt2_decode_step_counted)
     if model == "llama":
         from ray_tpu.models import llama as m
 
@@ -845,6 +845,11 @@ class LLMEngine:
         mark the stream, wake pollers, count the outcome."""
         if slot is not None and self._slot_req[slot] is req:
             self._slot_req[slot] = None
+            # A free slot goes on through the step: at position 0 its
+            # attention reads one block of its ring and not the dead
+            # context (what it computes, and the row it writes at 0, no
+            # one reads; the next prefill writes the slot's rows from 0).
+            self._pos[slot] = 0
         st = req.stream
         if st.done:
             return

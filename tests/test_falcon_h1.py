@@ -98,7 +98,7 @@ def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
         lengths, cfg, chunk=chunk)
     out, rows, free = [logits], jnp.arange(r), jnp.zeros(1, jnp.int32)
     step = jax.jit(lambda c, t, n: fh.falcon_h1_decode_step(
-        params, c, t, n, cfg))
+        params, c, t, n, cfg)[:2])
     for i in range(steps):
         logits, cache = step(
             cache, jnp.concatenate([tokens[rows, lengths + i], free]),
@@ -224,12 +224,16 @@ def test_the_programs_hold_the_types_the_file_states(program):
     assert {"bf16", "f32"} <= types
     assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
                                                   "i4", "u4"))}, types
-    logits, new_cache = jax.eval_shape(fn, *args)
+    logits, new_cache, *counted = jax.eval_shape(fn, *args)
     assert logits.dtype == jnp.float32
     assert [s.dtype for s in new_cache["ssm"]] == [jnp.float32] * 3
     assert new_cache["k"].dtype == new_cache["conv"].dtype == jnp.bfloat16
     assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
     assert "counted" not in new_cache  # no experts: nothing to count
+    # the step says what its attention read of the rings (PR 48)
+    assert [sorted(c) for c in counted] == (
+        [["ring_rows_held", "ring_rows_read"]] if program == "decode"
+        else [])
 
 
 def test_forward_agrees_with_the_reference(params, tokens, want):
@@ -287,7 +291,7 @@ def test_prefill_in_toy_chunks_then_decode_through_the_cache(chunks,
             jnp.full(1, n, jnp.int32))
     out = [logits[0]]
     step = jax.jit(lambda c, t, n: fh.falcon_h1_decode_step(
-        params, c, t, n, cfg))
+        params, c, t, n, cfg)[:2])
     for i in range(steps):
         toks = jnp.zeros(2, jnp.int32).at[1].set(row[0, length + i])
         pos = jnp.zeros(2, jnp.int32).at[1].set(length + i)
@@ -514,14 +518,17 @@ def test_the_mixers_column_multipliers_in_the_step_and_in_rows_alike():
 # own two are the programs of PR 44, whose rings hold merged rows that the
 # step reads as they lie and the chunk reads before it writes: that PR
 # changed them ON PURPOSE (and no other family's); a later one that does
-# replaces these lines and says so.
+# replaces these lines and says so. PR 48 replaced Falcon-H1's decode
+# program ON PURPOSE (the attention is handed the stacked cache and the
+# layer's index, and the step returns what it read of the rings); its chunk
+# program and DeepSeek-V2's two held through it.
 LOWERED = {
     ("deepseek_v2", "decode"):
         "49e82efdba2cf0ae90ad81e9be4d1dd37f7b5fbf9a921f2de9ba58ed47100ec8",
     ("deepseek_v2", "prefill"):
         "52a5ec015c69fe816fa3668bc8bb7a33010803b7015750ae1fa51f9b931e67d0",
     ("falcon_h1", "decode"):
-        "644359f4a9a49274d0ebf41f83523eb66d5a7e48dbc37f0a11b4183d109ff9c7",
+        "12254346a7750fae85518cd98839101153ec8b1c10b65ef6b911c45a458fd552",
     ("falcon_h1", "prefill"):
         "0e302753a3af60ac69badd51fccf8994c05d93eb66f0c8b4624a87981698c5c1",
 }
@@ -636,7 +643,12 @@ def test_the_tiny_preset_engine_and_the_bundles_error_text():
                     cache_len=16, max_prompt_len=8)
     try:
         assert len(eng.generate([1, 2, 3], 4)) == 4
-        assert eng._step_counters == ()
+        # no experts to count; the rings' rows read and held (PR 48): a toy
+        # row keeps the XLA arm, which reads every row it holds
+        assert eng._step_counters == ("ring_rows_held", "ring_rows_read")
+        stats = eng.llm_stats()
+        assert stats["ring_rows_read"] == stats["ring_rows_held"] \
+            == stats["steps"] * eng._cfg.n_layer * 3 * 16
     finally:
         eng.shutdown_engine()
     with pytest.raises(ValueError, match=r"gpt2\|llama\|nemotron_h\|"

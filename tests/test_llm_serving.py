@@ -584,6 +584,67 @@ def test_slot_recycle_and_admission_queue(model):
         eng.shutdown_engine()
 
 
+@every_family
+def test_a_freed_slot_steps_on_at_position_0_and_live_tokens_are_the_same(
+        model):
+    """Where a slot frees, its position goes back to 0, so that the step's
+    attention reads one block of the free slot's ring and not the dead
+    request's context (PR 48). What a free slot computes no one reads:
+    requests of different lengths on two slots, one ending while the other
+    goes on and a third taking the freed slot, get the tokens they get
+    with the position left where the dead request stood (the engine as it
+    was), which are the tokens each would get alone."""
+    cfg, fwd = SERVED[model]
+    asked = {0: ([3, 7, 11], 2), 1: ([4, 7, 11, 2], 12), 2: ([5, 9], 7),
+             3: ([6, 1, 8, 8, 2], 4)}
+
+    def served(reset):
+        eng = _engine(model=model, max_batch=2, prefill_rows=1,
+                      max_new_cap=16)
+        if not reset:
+            finish = eng._finish_locked
+
+            def leave_the_position(req, *a, slot=None, **kw):
+                was = None if slot is None else int(eng._pos[slot])
+                finish(req, *a, slot=slot, **kw)
+                if slot is not None:
+                    eng._pos[slot] = was
+
+            eng._finish_locked = leave_the_position
+        got, errors = {}, []
+
+        def one(i, rid):
+            try:
+                got[i], last = _drain(eng, rid)
+                assert not last["error"] and not last["shed"], last
+            except BaseException as e:  # noqa: BLE001
+                errors.append(repr(e))
+
+        try:
+            threads = [threading.Thread(
+                target=one, args=(i, eng.llm_submit(prompt, n)))
+                for i, (prompt, n) in asked.items()]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not errors, errors
+            assert eng.llm_stats()["completed"] == len(asked)
+            return got, [int(p) for p in eng._pos[:eng.max_batch]], \
+                eng.params
+        finally:
+            eng.shutdown_engine()
+
+    with_reset, free_at, params = served(reset=True)
+    as_it_was, stood_at, _ = served(reset=False)
+    assert free_at == [0, 0] and min(stood_at) > 0
+    assert with_reset == as_it_was
+    alone = _compiled(fwd, params, cfg, 32)
+    for i, (prompt, n) in asked.items():
+        assert with_reset[i] == _naive_generate(alone, None, prompt, n,
+                                                None), i
+
+
 def test_deadline_shed_mid_decode_frees_slot():
     """A deadline dying mid-decode sheds TYPED (reason=decode) at the
     next step boundary, frees the slot, and the engine keeps serving."""

@@ -7,7 +7,18 @@ lane tile, and XL's 25 heads of 64 padded 1600 -> 1664) and GROUPED queries
 lane tiles with a lane group a K/V head; its tiny preset's 4 over 2 of 16, a
 row under one tile). Float32 throughout, on the CPU: what is held is the
 softmax over the same keys, not a chip's rounding.
+
+Since PR 48 a family hands ``cached_decode_attention`` its STACKED merged
+cache and a layer's index, and rings of whole blocks of rows of whole lane
+tiles go through the kernel of ``ops/ring_decode.py`` (interpret mode here),
+which stops each slot at its last live block. It is held to the XLA arm over
+the same layer cut out of the stack, at the three rows the serving cells
+store (GPT-2 XL's, Falcon-H1's, Qwen3-Next's 16 query heads over 2 K/V heads
+of 256), at contexts on every side of a block's edge, side by side in one
+call.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +26,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import attention as ops
+from ray_tpu.ops import ring_decode
 
 # (query heads, K/V heads, head size)
 ROWS = {"gpt2-toy-4:4:16": (4, 4, 16), "gpt2-xl-25:25:64": (25, 25, 64),
@@ -152,3 +164,138 @@ def test_a_row_that_is_no_whole_number_of_kv_heads_is_refused():
             jnp.zeros((1, 6, 48)), jnp.zeros((1, 4, 256)),
             jnp.zeros((1, 4, 256)), jnp.zeros((1, 256)), jnp.zeros((1, 256)),
             jnp.zeros(1, jnp.int32), jnp.ones(1, jnp.int32), jnp.float32)
+
+
+# -- the kernel: the stacked cache and a layer's index --------------------------
+
+KERNEL_ROWS = {"gpt2-xl-25:25:64": (25, 25, 64),
+               "falcon-h1-20:4:128": (20, 4, 128),
+               "qwen3-next-16:2:256": (16, 2, 256)}
+BLOCK = ring_decode.BLOCK_ROWS
+LONG_RING = 2 * BLOCK
+# a slot each, side by side in ONE call: position of the new token
+CONTEXTS = {
+    "context-of-1-a-free-slot": 0,
+    "one-short-of-a-blocks-edge": BLOCK - 2,
+    "on-a-blocks-edge": BLOCK - 1,
+    "one-past-a-blocks-edge": BLOCK,
+    "a-full-ring": LONG_RING - 1,
+    "a-wrapped-ring": LONG_RING + 5,
+    "wrapped-many-times-cursor-at-the-end": 5 * LONG_RING - 1,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_step(row, dtype, scale=None):
+    """One step over a layer of a two-layer stack, every context of
+    CONTEXTS a slot: (the kernel's, the XLA arm's over the layer cut out,
+    the heads-apart form's), each [S, H, hd] float32, and cursor, valid."""
+    h, g, hd = KERNEL_ROWS[row]
+    dtype = jnp.dtype(dtype)
+    w = ops.merged_row_width(g, hd)
+    slots = len(CONTEXTS)
+    q = _normal(11, slots, h, hd).astype(dtype)
+    k = _normal(12, 2, slots, LONG_RING, g, hd).astype(dtype)
+    v = _normal(13, 2, slots, LONG_RING, g, hd).astype(dtype)
+    k_new = _normal(14, slots, g, hd).astype(dtype)
+    v_new = _normal(15, slots, g, hd).astype(dtype)
+    pos = jnp.asarray(list(CONTEXTS.values()), jnp.int32)
+    cursor, valid = pos % LONG_RING, jnp.minimum(pos + 1, LONG_RING)
+    at = jnp.arange(slots)
+    # what the new token must not be mistaken for stands at its row
+    k, v = k.at[:, at, cursor].set(50.0), v.at[:, at, cursor].set(-50.0)
+    merged = [_merged(a, w) for a in (k, v, k_new, v_new)]
+    assert ring_decode.takes_kernel(LONG_RING, w)
+    got = ops.cached_decode_attention(
+        q, merged[0], merged[1], merged[2], merged[3], cursor, valid,
+        jnp.float32, scale, layer=1)
+    xla = ops.cached_decode_attention(
+        q, merged[0][1], merged[1][1], merged[2], merged[3], cursor, valid,
+        jnp.float32, scale)
+    apart = ops.cached_decode_attention(q, k[1], v[1], k_new, v_new, cursor,
+                                        valid, jnp.float32, scale)
+    return tuple(np.asarray(a) for a in (got, xla, apart, cursor, valid))
+
+
+@pytest.mark.parametrize("context", list(CONTEXTS))
+@pytest.mark.parametrize("row", list(KERNEL_ROWS))
+def test_the_kernel_gives_the_xla_arms_softmax_to_rounding(row, context):
+    """float32: the same softmax over the same keys, whichever side of a
+    block's edge the context ends, whatever the neighbours' contexts."""
+    got, xla, apart, cursor, valid = _kernel_step(row, "float32")
+    i = list(CONTEXTS).index(context)
+    assert valid[i] == min(CONTEXTS[context] + 1, LONG_RING)
+    assert got.shape == xla.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got[i], xla[i], rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got[i], apart[i], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("context", list(CONTEXTS))
+@pytest.mark.parametrize("row", list(KERNEL_ROWS))
+def test_the_kernel_in_bfloat16_is_as_near_the_heads_apart_form(row, context):
+    """bfloat16 operands, float32 scores and sums: the kernel lies within
+    the XLA arm's own distance from the heads-apart form (which computes
+    in float32 from the same bfloat16 numbers)."""
+    got, xla, apart, _, _ = _kernel_step(row, "bfloat16")
+    i = list(CONTEXTS).index(context)
+    own = np.abs(xla[i] - apart[i]).max()
+    assert np.abs(got[i] - apart[i]).max() <= 2 * own + 1e-5
+    assert np.abs(apart[i]).max() > 0.1  # not a comparison of zeros
+
+
+@pytest.mark.parametrize("scale", [None, 0.0625, 0.2])
+def test_the_kernel_takes_a_models_own_scale(scale):
+    got, xla, apart, _, _ = _kernel_step("falcon-h1-20:4:128", "float32",
+                                         scale)
+    np.testing.assert_allclose(got, xla, rtol=2e-5, atol=2e-6)
+    if scale is not None:
+        plain = _kernel_step("falcon-h1-20:4:128", "float32")[0]
+        assert np.abs(got - plain).max() > 1e-3  # the scale was applied
+
+
+@pytest.mark.parametrize("ring,row,kernel", [
+    (LONG_RING, (25, 25, 64), True), (3 * BLOCK, (16, 2, 256), True),
+    (LONG_RING + 8, (20, 4, 128), False),  # no whole number of blocks
+    (LONG_RING, (4, 4, 16), False),        # a toy row inside one lane tile
+])
+def test_ring_rows_read_is_whole_blocks_up_to_each_slots_last(ring, row,
+                                                              kernel):
+    """The counter a decode step returns: where the kernel runs,
+    ``ceil(valid / block) * block`` rows a slot a layer; where XLA reads
+    the ring whole (a toy row, a ring of no whole blocks), every row."""
+    h, g, hd = row
+    w = ops.merged_row_width(g, hd)
+    assert ring_decode.takes_kernel(ring, w) is kernel
+    valid = jnp.asarray([1, BLOCK - 1, BLOCK, BLOCK + 1, ring], jnp.int32)
+    counted = ops.ring_rows_counted(jnp.zeros((3, 5, ring, w), jnp.bfloat16),
+                                    valid)
+    assert {k: v.dtype for k, v in counted.items()} == {
+        "ring_rows_read": jnp.int32, "ring_rows_held": jnp.int32}
+    assert int(counted["ring_rows_held"]) == 3 * 5 * ring
+    want = 3 * sum(-(-int(n) // BLOCK) * BLOCK for n in valid) if kernel \
+        else 3 * 5 * ring
+    assert int(counted["ring_rows_read"]) == want
+
+
+def test_a_ring_the_kernel_does_not_take_is_cut_out_of_the_stack():
+    """A stacked toy cache and a layer's index give what the layer's own
+    slice gives: the XLA arm, no kernel in the traced step."""
+    h, g, hd = ROWS["gpt2-toy-4:4:16"]
+    w = ops.merged_row_width(g, hd)
+    k, v = _normal(16, LAYERS, SLOTS, RING, w), _normal(17, LAYERS, SLOTS,
+                                                        RING, w)
+    q, k_new, v_new = _normal(18, SLOTS, h, hd), _normal(19, SLOTS, w), \
+        _normal(20, SLOTS, w)
+    cursor = jnp.asarray([0, 5, RING - 1], jnp.int32)
+    step = lambda k, v, layer: ops.cached_decode_attention(
+        q, k, v, k_new, v_new, cursor, cursor + 1, jnp.float32, layer=layer)
+    np.testing.assert_array_equal(
+        np.asarray(step(k, v, 1)),
+        np.asarray(ops.cached_decode_attention(
+            q, k[1], v[1], k_new, v_new, cursor, cursor + 1, jnp.float32)))
+    assert "pallas_call" not in str(jax.make_jaxpr(step)(k, v, 1))
+    ring = jnp.zeros((LAYERS, SLOTS, LONG_RING, 128), jnp.float32)
+    new = jnp.zeros((SLOTS, 128), jnp.float32)
+    assert str(jax.make_jaxpr(lambda k, layer: ops.cached_decode_attention(
+        jnp.zeros((SLOTS, 2, 64)), k, k, new, new, cursor, cursor + 1,
+        jnp.float32, layer=layer))(ring, 1)).count("pallas_call") == 1

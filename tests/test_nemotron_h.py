@@ -418,19 +418,23 @@ def test_the_engine_serves_the_references_greedy_continuation(runtime):
 
 
 def test_a_dense_familys_engine_and_decode_program_are_as_before():
-    """GPT-2 returns no counters: its engine reports no new key, and the
-    engine's step program is the model's own decode step and an argmax,
-    nothing joined to its tokens."""
+    """Llama returns no counters (no experts, and its rings are no merged
+    rows: GPT-2, which this test used to take, has counted the rows its
+    rings' kernel reads since PR 48): its engine reports no new key, and
+    the engine's step program is the model's own decode step and an
+    argmax, nothing joined to its tokens."""
+    from ray_tpu.models import llama
     from ray_tpu.serve.llm_engine import LLMEngine
 
-    cfg = dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=jnp.float32)
-    eng = LLMEngine(model="gpt2", config=cfg, max_batch=2, cache_len=16,
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    eng = LLMEngine(model="llama", config=cfg, max_batch=2, cache_len=16,
                     max_prompt_len=8)
     try:
         assert len(eng.generate([1, 2, 3], 4)) == 4
         stats = eng.llm_stats()
         assert not {"experts_hit", "expert_rows", "expert_layers",
-                    "experts_held"} & set(stats)
+                    "experts_held", "ring_rows_read",
+                    "ring_rows_held"} & set(stats)
         assert eng._step_counters == ()
         toks, pos = jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32)
         before = eng._compiles["decode"]
@@ -439,8 +443,8 @@ def test_a_dense_familys_engine_and_decode_program_are_as_before():
         eng._compiles["decode"] = before  # tracing it again counted one
 
         def plain(params, cache, tokens, pos):
-            logits, cache = gpt2.gpt2_decode_step(params, cache, tokens,
-                                                  pos, cfg)
+            logits, cache = llama.llama_decode_step(params, cache, tokens,
+                                                    pos, cfg)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
         plain_jaxpr = jax.make_jaxpr(jax.jit(plain, donate_argnums=(1,)))(

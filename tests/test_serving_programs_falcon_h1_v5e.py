@@ -98,8 +98,13 @@ def compiled(one_chip, cfg, engine):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-                for name, (fn, args) in programs.items()}
+        # the kernels pick interpret mode from the process's backend, the
+        # CPU here: while the programs are traced it says the chip's, so
+        # the step holds its kernel (PR 48), not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
@@ -211,6 +216,37 @@ def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
     assert len({stack for _, stack in made}) == 2, made
 
 
+def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
+        compiled):
+    """PR 48: the decode attention of every ring-holding layer is ONE
+    custom call of the kernel of ``ops/ring_decode.py``, handed the K and V
+    STACKS as they lie and the layer's index. Nothing, inside a fusion or
+    outside, gives out an array of a layer's ring's size or of a stack's
+    but the row-sized writes into the donated stacks after the layer loop
+    (a slot's row a write, each into the buffer the last one left): a
+    layer's slice handed to the kernel would be copied out first, a whole
+    layer's rings a layer, the traffic the kernel is there to save
+    (``ring_decode_attention``'s operand IS the stack)."""
+    text = compiled["decode"].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 9
+    for line in calls:
+        assert "ring_decode_attention" in line
+        operands = re.findall(r"(\w+\[[\d,]*\])", re.search(
+            r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
+        assert operands.count("bf16[9,33,5120,512]") == 2, operands
+    made = []
+    for line in text.splitlines():
+        m = RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                nbytes([int(d) for d in dims.split(",")], 1) in (RING, STACK)
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    assert {op for op, _ in made} == {"dynamic-update-slice"}, made
+    assert len(made) % 2 == 0 and len(made) >= 2
+
+
 def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
     """The stacked K/V rings and a layer's SSM state (4.36 of the cache's
     4.37 GB): each shape has one layout as a whole array in the chunk
@@ -218,8 +254,12 @@ def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
     between the two."""
     def layouts(shape, which):
         # (a trailing S(n) names a memory space, not a layout)
+        # (nor is what the kernel's custom call asks of its operands,
+        # ``operand_layout_constraints``: an order of dimensions, no tiling)
+        text = re.sub(r"operand_layout_constraints=\{[^=]*\}, ", "",
+                      compiled[which].as_text())
         return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
-            shape + r"(\{[^}]*\})", compiled[which].as_text())}
+            shape + r"(\{[^}]*\})", text)}
 
     for shape in (r"bf16\[9,33,5120,512\]", r"f32\[33,32,128,256\]"):
         assert len(layouts(shape, "prefill")) == 1, shape

@@ -78,15 +78,21 @@ def _programs(one_chip, cache=None):
 
 def _compile(programs):
     """Cache donated, with the persistent cache out of the way: such a
-    compile is written to it but cannot be read back without a chip."""
+    compile is written to it but cannot be read back without a chip. The
+    kernels pick interpret mode from the process's backend, which is the
+    CPU here: while these programs are traced it says what the described
+    chip's would, so that the decode step holds its kernel (PR 48) and
+    not the interpreter's loops."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-                for name, (fn, args) in programs.items()}
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
@@ -141,6 +147,38 @@ def test_no_layer_sized_block_is_moved_inside_the_layer_loop(compiled, which):
     assert copied == []
 
 
+def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
+        compiled):
+    """PR 48: the decode attention is ONE custom call of the kernel of
+    ``ops/ring_decode.py`` in the layer loop's body (so once a layer),
+    handed the K and V STACKS as they lie and the layer's index: the scan
+    runs over the index with the cache closed over, since a layer's slice
+    as the scan's ``xs`` would be copied out for the kernel first, a whole
+    layer's rings a layer, the traffic the kernel is there to save. So
+    nothing anywhere in the program, inside a fusion or outside, gives out
+    an array of a layer's block's size or of a stack's but the row-sized
+    writes into the donated stacks after the loop."""
+    text = compiled["decode"].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "ring_decode_attention" in calls[0]
+    in_loop = [body for body in _loop_bodies(text) if calls[0] in body]
+    assert len(in_loop) == 1
+    operands = re.findall(r"(\w+\[[\d,]*\])", re.search(
+        r"operand_layout_constraints=\{(.*?)\}, \w+=", calls[0]).group(1))
+    assert operands.count(f"bf16[{CACHE_DIMS}]") == 2, operands
+    result = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+    made = []
+    for line in text.splitlines():
+        m = result.match(line)
+        # (the loop hands the stacks it closes over through its carry)
+        if m and m.group(2) not in PASSES_ON + ("while",) and any(
+                dims in (BLOCK_DIMS, "1," + BLOCK_DIMS, CACHE_DIMS)
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append(m.group(2))
+    assert set(made) == {"dynamic-update-slice"}, made
+
+
 # what one execution of the chunk program stacks as its layer loop's
 # ``ys``: the chunk's own K and V rows of every layer, [48, 1, 256, 1664]
 CHUNK_ROWS = 2 * XL.n_layer * CHUNK * ROW * 2
@@ -168,7 +206,10 @@ def test_temp_space_holds_no_second_cache_and_no_copy_of_the_weights(
 
 def _cache_layouts(text):
     """Every layout the program names an array of the cache's shape in
-    (a trailing S(n) names a memory space, not a layout)."""
+    (a trailing S(n) names a memory space, not a layout; what the kernel's
+    custom call asks of its operands, ``operand_layout_constraints``, names
+    an order of dimensions and no tiling, and is no array's layout)."""
+    text = re.sub(r"operand_layout_constraints=\{[^=]*\}, ", "", text)
     return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
         rf"bf16\[{CACHE_DIMS}\](\{{[^}}]*\}})", text)}
 
