@@ -23,9 +23,11 @@ from __future__ import annotations
 import asyncio
 import math
 import os
+import queue
 import random
 import threading
 import time
+import uuid
 from contextlib import nullcontext
 from typing import Any, Dict, List, Optional
 
@@ -174,7 +176,13 @@ class Replica:
                 "phases": phases, "replica": self._replica_tag}
 
     def get_num_ongoing(self) -> int:
-        return self.num_ongoing
+        """Calls in flight, and the open streams that hold none of their
+        own: a callable whose streams share one long-poll a client
+        process (``LLMEngine.open_streams``) says how many are open
+        beside that call, so the autoscaling probe still reads an open
+        stream as one request."""
+        streams = getattr(self.callable, "open_streams", None)
+        return self.num_ongoing + (streams() if streams is not None else 0)
 
     def reconfigure(self, user_config):
         if hasattr(self.callable, "reconfigure"):
@@ -769,6 +777,7 @@ def reset_routers() -> None:
         _routers.clear()
     with _stream_tables_lock:
         _stream_tables.clear()
+    _stop_stream_pollers()
 
 
 def routed_call(deployment_name: str, method: str, args: tuple, kwargs: dict,
@@ -918,9 +927,9 @@ _STREAM_TABLE_TTL_S = 2.0
 _stream_tables: Dict[str, tuple] = {}
 _stream_tables_lock = threading.Lock()
 
-# Long-poll budget per llm_next call; the outer RPC timeout adds slack
-# so a partitioned replica fails the stream FAST (typed, bounded by
-# _STREAM_POLL_S + _STREAM_RPC_SLACK_S), never hangs it.
+# Long-poll budget per llm_poll call; the outer RPC timeout adds slack
+# so a partitioned replica fails its streams FAST (typed, bounded by
+# _STREAM_POLL_S + _STREAM_RPC_SLACK_S), never hangs them.
 _STREAM_POLL_S = 1.0
 _STREAM_RPC_SLACK_S = 25.0
 
@@ -961,6 +970,157 @@ def _stream_rpc(backend, actor_id: str, method: str, args: tuple,
 STREAM_KEEPALIVE = {"__stream_keepalive__": True}
 
 
+# -- one poller a (client process, replica) ---------------------------------
+
+# How long a poller's thread outlives its last stream: a closed loop's
+# next request finds the thread it left.
+_POLLER_LINGER_S = 2.0
+# (id(backend), replica actor id) -> the poller of this process's streams
+# on that replica. The one lock guards the table and every poller's state.
+_stream_pollers: Dict[tuple, "_StreamPoller"] = {}
+_stream_pollers_lock = threading.Lock()
+
+
+class _StreamPoller:
+    """All the streams this process holds on one replica, and the ONE
+    thread that drains them: a loop of ``llm_poll(poller=<pid>)``, each a
+    blocking call that brings what every stream was given since the last,
+    handed on to the streams' queues (``_stream_call_impl`` reads them).
+    A turn of a 64-slot engine is one call and 64 ``put``s here, not 64
+    calls, each with the interpreter to win on both ends.
+
+    A stream's id is the engine's to give, so its first chunk may come
+    back before its submitter has a queue for it: what comes for a stream
+    nobody has registered is kept (``early``) while a submit is on its
+    way (``submitting``) and dropped otherwise, which is also how the
+    later chunks of a consumer that left are dropped."""
+
+    def __init__(self, key: tuple, backend, aid: str):
+        self.key, self.backend, self.aid = key, backend, aid
+        self.pid = f"{os.getpid():x}-{uuid.uuid4().hex[:12]}"
+        self.queues: Dict[str, queue.SimpleQueue] = {}
+        self.early: Dict[str, list] = {}
+        self.submitting = 0
+        # why the thread ended, once it has: what a late register gets
+        self.ended: Optional[BaseException] = None
+        self.stop = False
+        self.changed = threading.Condition(_stream_pollers_lock)
+        self.thread = threading.Thread(
+            target=self._run, daemon=True, name="serve-stream-poller")
+
+    def _settle_locked(self) -> None:
+        """A submit came back, one way or the other."""
+        self.submitting -= 1
+        if not self.submitting:
+            self.early.clear()  # whoever these were for has left
+        self.changed.notify()
+
+    def abandon(self) -> None:
+        with _stream_pollers_lock:
+            self._settle_locked()
+
+    def register(self, rid: str) -> queue.SimpleQueue:
+        """The queue of the stream a submit under ``pid`` returned."""
+        q: queue.SimpleQueue = queue.SimpleQueue()
+        with _stream_pollers_lock:
+            for item in self.early.pop(rid, ()):
+                q.put(item)
+            if self.ended is not None:
+                q.put(self.ended)
+            else:
+                self.queues[rid] = q
+            self._settle_locked()
+        return q
+
+    def unregister(self, rid: str) -> None:
+        with _stream_pollers_lock:
+            self.queues.pop(rid, None)
+
+    def _end_locked(self, why: BaseException) -> None:
+        """The thread ends: every stream it still holds ends with
+        ``why``, and the next stream to this replica starts a new one."""
+        self.ended = why
+        for q in self.queues.values():
+            q.put(why)
+        self.queues.clear()
+        self.early.clear()
+        if _stream_pollers.get(self.key) is self:
+            del _stream_pollers[self.key]
+
+    def _wait_for_streams(self) -> bool:
+        """Block while there is nothing to poll for; False once the
+        thread is to end (stopped, or a linger long with no stream and no
+        submit on its way)."""
+        with self.changed:
+            while not self.changed.wait_for(
+                    lambda: self.queues or self.stop, _POLLER_LINGER_S) \
+                    and self.submitting:
+                pass
+            if self.queues and not self.stop:
+                return True
+            self._end_locked(RuntimeError(
+                "serve was shut down with the stream open" if self.stop
+                else "stream poller retired"))
+            return False
+
+    def _run(self) -> None:
+        try:
+            while self._wait_for_streams():
+                # Polls go meta-less (the legacy bare-result path): a
+                # long-poll is transport, not a request — it must not
+                # enter the request histograms or be shed by the
+                # replica's arrival check.
+                t0 = time.perf_counter_ns()
+                out = _stream_rpc(
+                    self.backend, self.aid, "llm_poll", (),
+                    {"poller": self.pid, "timeout_s": _STREAM_POLL_S},
+                    None, timeout=_STREAM_POLL_S + _STREAM_RPC_SLACK_S)
+                trip_ns = time.perf_counter_ns() - t0
+                held_ns = out.pop("held_ns", 0)
+                with _stream_pollers_lock:
+                    for rid, r in out.items():
+                        item = (r, trip_ns, held_ns)
+                        q = self.queues.get(rid)
+                        if q is not None:
+                            q.put(item)
+                            if r.get("done"):
+                                del self.queues[rid]
+                        elif self.submitting:
+                            self.early.setdefault(rid, []).append(item)
+        except BaseException as e:  # a dead replica, a backend shut down
+            # under the call: every stream hears it before the thread ends
+            with _stream_pollers_lock:
+                self._end_locked(e)
+            if not isinstance(e, Exception):
+                raise
+
+
+def _poller_for(backend, aid: str) -> _StreamPoller:
+    """This process's poller for that replica, with one more submit on
+    its way (``register`` or ``abandon`` settles it)."""
+    key = (id(backend), aid)
+    with _stream_pollers_lock:
+        poller = _stream_pollers.get(key)
+        if poller is None:
+            poller = _stream_pollers[key] = _StreamPoller(key, backend, aid)
+            poller.thread.start()
+        poller.submitting += 1
+        return poller
+
+
+def _stop_stream_pollers() -> None:
+    """End every poller's thread and wait for it: an idle one ends at
+    once, one inside a call when the call returns (a long-poll's
+    time-out at the latest)."""
+    with _stream_pollers_lock:
+        pollers = list(_stream_pollers.values())
+        for p in pollers:
+            p.stop = True
+            p.changed.notify()
+    for p in pollers:
+        p.thread.join(timeout=2 * _STREAM_POLL_S)
+
+
 def stream_call(deployment_name: str, args: tuple, kwargs: dict,
                 request_meta: Optional[dict] = None, backend=None,
                 poll_s: float = _STREAM_POLL_S,
@@ -968,13 +1128,19 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
     """Route one STREAMING request: generator of token chunks.
 
     The replica's callable must speak the LLM engine protocol
-    (``llm_submit`` -> stream id, ``llm_next`` -> chunk drain; see
-    ``serve/llm_engine.py``). The stream pins to ONE replica for its
-    whole life — the KV-cache slot lives there. Submit retries across
-    replicas on a dead pick; a replica dying MID-stream fails the
-    stream fast (the slot died with the worker), and a deadline that
+    (``llm_submit(..., poller=<id>)`` -> stream id,
+    ``llm_poll(poller=<id>)`` -> what every stream of that poller was
+    given; see ``serve/llm_engine.py``). The stream pins to ONE replica
+    for its whole life — the KV-cache slot lives there — and shares this
+    process's one poller thread for that replica with every other stream
+    the process holds there (``_StreamPoller``): the generator reads a
+    queue and makes no call of its own. Submit retries across replicas
+    on a dead pick; a replica dying MID-stream fails every stream of its
+    poller fast (the slots died with the worker), and a deadline that
     expires mid-decode surfaces as a typed :class:`RequestShedError`
-    (reason=decode) shed by the engine at a step boundary.
+    (reason=decode) shed by the engine at a step boundary. A consumer
+    that closes the generator early sends no cancel: what still comes for
+    its stream is dropped.
 
     When the caller traces (``trace_ctx`` in the request meta), the
     whole stream is one ``serve.stream`` span: downstream hops — the
@@ -985,7 +1151,9 @@ def stream_call(deployment_name: str, args: tuple, kwargs: dict,
 
     ``backend`` defaults to this process's backend; the ``ray://``
     proxy passes its own ClusterBackend explicitly (its process-global
-    backend belongs to the CLIENT side)."""
+    backend belongs to the CLIENT side). ``poll_s`` is how long the
+    stream waits on its queue before it looks at ``keepalive_every``
+    again."""
     meta = dict(request_meta or {})
     trace_parent = meta.get("trace_ctx")
     if not trace_parent:
@@ -1029,14 +1197,17 @@ def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
                       request_meta: Optional[dict], backend,
                       poll_s: float, keepalive_every: Optional[float],
                       tally: Optional[dict]):
-    """The stream itself. For a traced stream (``tally`` is its span's
-    attributes) what its polls cost is kept in locals, two clock reads
-    and three additions a poll, and written as the generator ends:
-    ``polls`` of ``llm_next``, the round trips' sum ``rpc_ns`` and the
-    sum ``held_ns`` of what the engine said it spent inside each.
-    Durations on both ends, so no clock is shared:
-    ``(rpc_ns - held_ns) / polls`` is one routed poll's way there and
-    back. An untraced stream pays a branch a poll."""
+    """The stream itself: one ``llm_submit`` under this process's poller
+    for the replica it picked, then the stream's queue, which that
+    poller's thread fills. For a traced stream (``tally`` is its span's
+    attributes) what its polls cost is kept in locals and written as the
+    generator ends: ``polls``, the poller's calls that brought this
+    stream something, the sum ``rpc_ns`` of those calls' round trips (the
+    poller times every call, two clock reads a turn) and the sum
+    ``held_ns`` of what the engine said it spent inside each. Durations
+    on both ends, so no clock is shared: ``(rpc_ns - held_ns) / polls``
+    is one routed poll's way there and back. An untraced stream pays a
+    branch an item."""
     if backend is None:
         from ray_tpu._private import worker as _worker
 
@@ -1052,15 +1223,29 @@ def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
     from ray_tpu.core.object_ref import ActorError, GetTimeoutError
 
     last_err: Optional[BaseException] = None
-    resp = None
-    aid = None
+    rid = poller = None
     for attempt in range(3):
         try:
             replicas = _stream_replicas(
                 backend, deployment_name, refresh=attempt > 0)
             aid = replicas[random.randrange(len(replicas))]
-            resp = _stream_rpc(backend, aid, "llm_submit", args, kwargs,
-                               meta, timeout=60.0)
+            poller = _poller_for(backend, aid)
+            try:
+                resp = _stream_rpc(
+                    backend, aid, "llm_submit", args,
+                    {**kwargs, "poller": poller.pid}, meta, timeout=60.0)
+                if isinstance(resp, dict) and resp.get("__serve_envelope__"):
+                    shed = resp.get("shed")
+                    if shed:
+                        raise RequestShedError(
+                            f"stream to {deployment_name!r} shed at "
+                            f"admission", reason=shed)
+                    rid = resp.get("result")
+                else:
+                    rid = resp
+            except BaseException:
+                poller.abandon()
+                raise
             break
         except (ValueError, RequestShedError):
             raise
@@ -1079,44 +1264,35 @@ def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
             time.sleep(0.2 * (attempt + 1))
     else:
         raise last_err
-    if isinstance(resp, dict) and resp.get("__serve_envelope__"):
-        shed = resp.get("shed")
-        if shed:
-            raise RequestShedError(
-                f"stream to {deployment_name!r} shed at admission",
-                reason=shed)
-        rid = resp.get("result")
-    else:
-        rid = resp
+    q = poller.register(rid)
     last_yield = time.monotonic()
     timed = tally is not None
-    polls = rpc_ns = held_ns = t0 = 0
+    polls = rpc_ns = held_ns = 0
     try:
         while True:
-            # Polls go meta-less (the legacy bare-result path): a
-            # long-poll is transport, not a request — it must not enter
-            # the request histograms or be shed by the replica's arrival
-            # check.
+            try:
+                item = q.get(timeout=poll_s)
+            except queue.Empty:
+                if keepalive_every is not None \
+                        and time.monotonic() - last_yield >= keepalive_every:
+                    # Deep-queued stream: nothing to say yet, but the
+                    # consumer's transport (the ray:// proxy RPC) needs
+                    # frames to not time out while the request waits for
+                    # a slot.
+                    yield STREAM_KEEPALIVE
+                    last_yield = time.monotonic()
+                continue
+            if isinstance(item, BaseException):
+                raise item  # the poller's call failed: a dead replica
+            r, trip_ns, held = item
             if timed:
-                t0 = time.perf_counter_ns()
-            r = _stream_rpc(backend, aid, "llm_next", (rid,),
-                            {"timeout_s": poll_s}, None,
-                            timeout=poll_s + _STREAM_RPC_SLACK_S)
-            if timed:
-                rpc_ns += time.perf_counter_ns() - t0
                 polls += 1
-                held_ns += r.get("held_ns", 0)
+                rpc_ns += trip_ns
+                held_ns += held
             chunks = r.get("chunks") or ()
             for chunk in chunks:
                 yield chunk
             if chunks:
-                last_yield = time.monotonic()
-            elif keepalive_every is not None \
-                    and time.monotonic() - last_yield >= keepalive_every:
-                # Deep-queued stream: nothing to say yet, but the
-                # consumer's transport (the ray:// proxy RPC) needs frames
-                # to not time out while the request waits for a slot.
-                yield STREAM_KEEPALIVE
                 last_yield = time.monotonic()
             if r.get("done"):
                 shed = r.get("shed")
@@ -1130,6 +1306,7 @@ def _stream_call_impl(deployment_name: str, args: tuple, kwargs: dict,
                         f"stream to {deployment_name!r} failed: {err}")
                 return
     finally:
+        poller.unregister(rid)
         if timed:
             tally.update(polls=polls, rpc_ns=rpc_ns, held_ns=held_ns)
 

@@ -31,10 +31,15 @@ engine, mounted as an ordinary Serve deployment callable:
   ``reason="decode"``, counted in ``ray_tpu_serve_shed_total``), never
   a hang; admission prefers requests by deadline slack.
 * **Token streaming.** Every request is a stream of per-step token
-  chunks drained by ``llm_next``/``llm_poll`` long-polls — the
-  transport ``serve._private.stream_call`` (handle ``.stream()``, HTTP
-  chunked transfer, the ``ray://`` proxy's server-streaming RPC) builds
-  on.
+  chunks, drained by long-polls. ``llm_next`` long-polls ONE stream
+  (``generate()`` and any direct speaker of the protocol).
+  ``llm_poll(poller=...)`` long-polls EVERY stream submitted under that
+  poller id in one call: a client process names itself at
+  ``llm_submit(..., poller=<id>)``, the engine keeps one event a poller,
+  and one blocked call a step takes what all its streams were given —
+  the transport ``serve._private.stream_call`` (handle ``.stream()``,
+  HTTP chunked transfer, the ``ray://`` proxy's server-streaming RPC)
+  builds on: a poll a step a client process, not a poll a token a stream.
 
 Failpoints ``serve.llm.before_admit`` / ``serve.llm.before_step`` let
 chaos crash, delay or hang the scheduler mid-iteration; the loop
@@ -81,12 +86,14 @@ _LAG_TOP = len(DELIVER_LAG_EDGES_MS)
 class _Stream:
     """One request's token stream: per-step chunks pending delivery plus
     the terminal state. ``event`` is set whenever there is something new
-    to deliver (chunks or the terminal transition). ``visible_ns`` is
-    when ``pending`` last turned from empty to non-empty, ``last_poll``
-    the last drain, both ``time.perf_counter_ns()``."""
+    to deliver (chunks or the terminal transition); a stream submitted
+    under a poller shares its ``poller``'s event, so whatever tells the
+    stream tells the poller's one blocked call. ``visible_ns`` is when
+    ``pending`` last turned from empty to non-empty, ``last_poll`` the
+    last drain, both ``time.perf_counter_ns()``."""
 
     __slots__ = ("pending", "done", "shed", "error", "delivered",
-                 "last_poll", "event", "n_tokens", "visible_ns")
+                 "last_poll", "event", "n_tokens", "visible_ns", "poller")
 
     def __init__(self):
         self.pending: List[List[int]] = []
@@ -98,6 +105,24 @@ class _Stream:
         self.event = threading.Event()
         self.n_tokens = 0
         self.visible_ns = 0
+        self.poller: Optional[_Poller] = None
+
+
+class _Poller:
+    """The streams one client process holds on this engine and the ONE
+    event its blocked ``llm_poll`` waits on. ``waiting`` counts the calls
+    inside that wait: a poller with no stream left is forgotten, but not
+    from under a call, or a stream submitted meanwhile would set an event
+    nobody waits on. All of it is read and written under the engine's
+    lock."""
+
+    __slots__ = ("pid", "event", "streams", "waiting")
+
+    def __init__(self, pid: str):
+        self.pid = pid
+        self.event = threading.Event()
+        self.streams: Dict[str, _Stream] = {}
+        self.waiting = 0
 
 
 class _Request:
@@ -233,8 +258,10 @@ class LLMEngine:
             ...
 
     ``__call__``/``generate`` are the blocking request/response lane;
-    ``llm_submit``/``llm_next``/``llm_poll`` are the streaming protocol
-    ``stream_call`` drives.
+    ``llm_submit``/``llm_next``/``llm_poll`` are the streaming protocol:
+    ``stream_call`` submits under its process's poller id and one thread
+    of that process drains all its streams with ``llm_poll(poller=...)``,
+    ``generate`` long-polls its own stream with ``llm_next``.
     """
 
     def __init__(self, model: str = "gpt2", config=None,
@@ -366,6 +393,9 @@ class LLMEngine:
         self._queue: List[tuple] = []
         self._n_queued = 0
         self._streams: Dict[str, _Stream] = {}
+        # poller id -> the streams submitted under it that were not
+        # handed out to their end yet, and the event they share
+        self._pollers: Dict[str, _Poller] = {}
         self._lock = threading.Lock()
         self._wake = threading.Event()
         self._stop = False
@@ -385,18 +415,19 @@ class LLMEngine:
         # stream's ``pending`` (under the lock) when ``_step_fanout``
         # returns, but the poller of a stream that goes on decoding is
         # told only once the device has its next program: every woken
-        # ``llm_next`` wants the lock and the interpreter, one a slot,
-        # and ahead of the loop's own dispatch they kept the device
-        # waiting for it. The streams owed a wake-up wait here; only the
-        # loop's thread touches the list (``_flush_wakes``). Why no order
-        # of set, drain and clear loses a token or delivers one twice:
-        # the token is in ``pending`` under the lock BEFORE its event can
-        # be set, and ``llm_next`` drains and clears under the same lock;
+        # long-poll wants the lock and the interpreter, and ahead of the
+        # loop's own dispatch they kept the device waiting for it. The
+        # streams owed a wake-up wait here; only the loop's thread
+        # touches the list (``_flush_wakes``). Why no order of set, drain
+        # and clear loses a token or delivers one twice: the token is in
+        # ``pending`` under the lock BEFORE its event can be set, and a
+        # long-poll (``_drain``) drains and clears under the same lock;
         # so a set that comes late finds either the token still pending
         # (the woken poll takes it) or already drained by a poll that
         # timed out or was woken for a neighbour token (the woken poll
-        # returns no chunk, as a time-out does, and ``stream_call`` goes
-        # round again).
+        # returns no chunk, as a time-out does, and its caller goes round
+        # again). A poller's call drains ALL its streams, so it is told
+        # of a put-off token only where one is still pending.
         self._wakes: List[_Stream] = []
         # when the fan-out that filled ``_wakes`` made its tokens visible
         self._fanout_ns = 0
@@ -422,10 +453,14 @@ class LLMEngine:
             # Both are counted where the wake-ups are set, in one write
             # (``_flush_wakes``), so no snapshot reads them a step apart.
             "wakes_deferred": 0, "wakes_after_dispatch": 0,
-            # The token's way out. Long-polls served (``llm_next``) and
-            # those that came back with no chunk and ``done`` false (a
-            # time-out, or the wake-up for a token an earlier poll took).
-            "next_calls": 0, "next_empty": 0,
+            # The token's way out. Long-polls served, of one stream
+            # (``llm_next``) or of a poller's streams
+            # (``llm_poll(poller=...)``: those are ``next_batched``
+            # too), and those that came back with no chunk and nothing
+            # ended (a time-out, or the wake-up for a token an earlier
+            # poll took). One body serves both (``_wait_drain``) and
+            # counts a call once.
+            "next_calls": 0, "next_empty": 0, "next_batched": 0,
             # How long the drained chunks lay in ``pending``: drain time
             # minus ``visible_ns``, summed and in the buckets of
             # DELIVER_LAG_EDGES_MS (``llm_stats()`` adds ``deliver_chunks``,
@@ -450,9 +485,11 @@ class LLMEngine:
     def _loop(self):  # jax-hot-path
         """The scheduler: admit, step, go round. A token is VISIBLE the
         moment ``_step_fanout`` (or ``_prefill_batch``) appends it to its
-        stream under the lock: ``llm_poll`` and any ``llm_next`` that
-        drains see it from then on. Its poller is TOLD (the stream's
-        event set) at once for a first token and a terminal transition,
+        stream under the lock: every drain (``llm_next``, ``llm_poll``)
+        sees it from then on. Its poller is TOLD (the stream's event set,
+        which is its poller's where it was submitted under one: one
+        blocked call for all of a client process's streams) at once for a
+        first token and a terminal transition,
         and for a decode step's token only after the next enqueue: once
         the next step's ``_step_fn`` call has returned or an admission
         turn's first chunk is dispatched, whichever comes first; and
@@ -491,20 +528,30 @@ class LLMEngine:
 
     def _flush_wakes(self, enqueued: bool) -> None:
         """Set the event of every stream the last decode step put off
-        (loop thread only). ``enqueued``: a program was handed to the
-        device since, which is what the wake-ups waited for."""
+        (loop thread only): its own, or ONCE its poller's, however many
+        of the poller's streams are owed, and only where a token still
+        waits (a call woken since for a first token or an ended
+        neighbour took them all: only this thread appends, so what reads
+        empty here stays empty). ``enqueued``: a program was handed to
+        the device since, which is what the wake-ups waited for."""
         wakes = self._wakes
         if not wakes:
             return
         self._wakes = []
         waited = time.perf_counter_ns() - self._fanout_ns
         waiting = 0
+        pollers = set()
         for st in wakes:
             # still pending after the clock was read: its drain comes
             # later than this, so its lag holds at least ``waited``
             if st.pending:
                 waiting += 1
-            st.event.set()
+                if st.poller is not None:
+                    pollers.add(st.poller)
+            if st.poller is None:
+                st.event.set()
+        for p in pollers:
+            p.event.set()
         # The loop's thread is these keys' only writer, and writes them
         # in ONE call that no other thread's copy can fall into.
         c = self.stats_counters
@@ -878,9 +925,9 @@ class LLMEngine:
         with self._lock:
             # Fully-delivered streams leave the table at delivery
             # (_drain_locked); only DONE streams nobody polls linger.
-            for rid in [r for r, s in self._streams.items()
-                        if s.done and s.last_poll < cutoff]:
-                del self._streams[rid]
+            for rid, st in [(r, s) for r, s in self._streams.items()
+                            if s.done and s.last_poll < cutoff]:
+                self._forget_stream_locked(rid, st)
 
     # -- request surface (called through Replica.handle_request) ----------
 
@@ -906,10 +953,13 @@ class LLMEngine:
                                         self.max_new_cap))
 
     def llm_submit(self, prompt, max_new_tokens=None,
-                   deadline_ts: Optional[float] = None) -> str:
+                   deadline_ts: Optional[float] = None,
+                   poller: Optional[str] = None) -> str:
         """Admit a request into the engine queue; returns the stream id.
         A full queue sheds typed (reason=decode) instead of erroring —
-        admission under a full BATCH merely queues."""
+        admission under a full BATCH merely queues. ``poller``: the
+        stream belongs to that poller, and ``llm_poll(poller=...)``
+        delivers it, a call that is blocked at this moment included."""
         prompt, max_new = self._normalize(prompt, max_new_tokens)
         # The caller's span context rides the serve request scope (set
         # by Replica.handle_request); read on THIS thread, before the
@@ -933,7 +983,11 @@ class LLMEngine:
             self._phase_span_locked(req, "llm.queue")
             self.stats_counters["queue_peak"] = max(
                 self.stats_counters["queue_peak"], self._n_queued)
-            self._streams[rid] = req.stream
+            self._streams[rid] = st = req.stream
+            if poller is not None:
+                st.poller = p = self._poller_locked(poller)
+                st.event = p.event
+                p.streams[rid] = st
         self._wake.set()
         return rid
 
@@ -958,8 +1012,24 @@ class LLMEngine:
                 "error": st.error}
         if st.done and not st.pending:
             st.delivered = True
-            self._streams.pop(rid, None)
+            self._forget_stream_locked(rid, st)
         return resp
+
+    def _poller_locked(self, pid: str) -> _Poller:
+        p = self._pollers.get(pid)
+        if p is None:
+            p = self._pollers[pid] = _Poller(pid)
+        return p
+
+    def _forget_stream_locked(self, rid: str, st: _Stream) -> None:
+        """The stream leaves the engine's tables; a poller whose last
+        stream left, and that no call waits on, goes with it."""
+        self._streams.pop(rid, None)
+        p = st.poller
+        if p is not None:
+            p.streams.pop(rid, None)
+            if not p.streams and not p.waiting:
+                self._pollers.pop(p.pid, None)
 
     def llm_next(self, rid: str, timeout_s: float = 2.0) -> dict:
         """Long-poll one stream: blocks until it is told of a chunk or
@@ -979,45 +1049,101 @@ class LLMEngine:
         if st is None:
             return {"chunks": [], "done": True, "shed": None,
                     "error": f"unknown stream {rid!r}", "held_ns": 0}
-        st.event.wait(max(0.0, float(timeout_s)))
+        out = self._wait_drain(st.event, timeout_s, t0,
+                               lambda: ((rid, st),), batched=False)
+        return {**out[rid], "held_ns": out["held_ns"]}
+
+    def _wait_drain(self, event: threading.Event, timeout_s: float,
+                    t0: int, streams, batched: bool) -> dict:
+        """A long-poll's body, whichever lane: wait on ``event``, then
+        the drain (``_drain``) with ``held_ns`` since the call's entry at
+        ``t0`` beside the streams' responses, keyed by stream id."""
+        event.wait(max(0.0, float(timeout_s)))
         if not tracing.profiling():
-            return self._next_drain(rid, st, t0)
+            return self._drain(event, t0, streams, batched)[0]
         # While a profile is taken, the poller's work on its clock: from
         # the wait's return on, and not around it (a span over a blocked
         # thread would own every idle gap of the device).
         with tracing.device_span("llm.next.drain") as ds:
-            resp = self._next_drain(rid, st, t0)
-            ds.set_metadata(chunks=len(resp["chunks"]))
-        return resp
+            out, chunks = self._drain(event, t0, streams, batched)
+            ds.set_metadata(chunks=chunks)
+        return out
 
-    def _next_drain(self, rid: str, st: _Stream, t0: int) -> dict:
-        """``llm_next`` after its wait: the drain under the lock, its
-        counts, and ``held_ns`` since the call's entry at ``t0``."""
+    def _drain(self, event: threading.Event, t0: int, streams,
+               batched: bool) -> tuple:
+        """Under ONE acquisition of the lock and one clock read: hand out
+        what the streams of ``streams()`` hold, clear the event they were
+        told through (all that was pending is taken, and an ended stream
+        is gone), count the call (``next_calls``; ``next_empty`` where it
+        brought no chunk and no end). Returns
+        ``{rid: response, "held_ns": drain's clock - t0}`` and the chunks
+        drained."""
         with self._lock:
             now_ns = time.perf_counter_ns()
-            resp = self._drain_locked(rid, st, now_ns)
+            out = {rid: self._drain_locked(rid, st, now_ns)
+                   for rid, st in streams()}
+            event.clear()
+            chunks = sum(len(r["chunks"]) for r in out.values())
             c = self.stats_counters
             c["next_calls"] += 1
-            if not st.done:
-                st.event.clear()
-                if not resp["chunks"]:
-                    c["next_empty"] += 1
-        resp["held_ns"] = now_ns - t0
-        return resp
+            c["next_batched"] += batched
+            if not chunks and not any(r["done"] for r in out.values()):
+                c["next_empty"] += 1
+        out["held_ns"] = now_ns - t0
+        return out, chunks
 
-    def llm_poll(self, rids: List[str]) -> Dict[str, dict]:
-        """Non-blocking batched drain (the bench's collector lane)."""
-        out = {}
+    def llm_poll(self, rids: Optional[List[str]] = None,
+                 poller: Optional[str] = None,
+                 timeout_s: float = 0.0) -> Dict[str, dict]:
+        """Batched drain. ``llm_poll(rids)`` takes, without waiting, what
+        those streams hold (the bench's collector lane).
+
+        ``llm_poll(poller=<id>, timeout_s=...)`` is the long-poll of a
+        client process: it blocks until the poller is told of anything (a
+        first token, a chunk whose next step is enqueued, a terminal
+        transition of any stream submitted under that id, one submitted
+        while this call waits included) or the time-out, then drains
+        every stream of the poller that holds a chunk or has ended, and
+        returns ``{rid: {"chunks", "done", "shed", "error"}}`` for those
+        alone, with ``"held_ns"`` beside them as ``llm_next`` says it.
+        One call at a time a poller."""
+        if poller is None:
+            out = {}
+            with self._lock:
+                now_ns = time.perf_counter_ns()
+                for rid in rids or ():
+                    st = self._streams.get(rid)
+                    if st is None:
+                        out[rid] = {"chunks": [], "done": True,
+                                    "shed": None,
+                                    "error": f"unknown stream {rid!r}"}
+                    else:
+                        out[rid] = self._drain_locked(rid, st, now_ns)
+            return out
+        t0 = time.perf_counter_ns()
         with self._lock:
-            now_ns = time.perf_counter_ns()
-            for rid in rids:
-                st = self._streams.get(rid)
-                if st is None:
-                    out[rid] = {"chunks": [], "done": True, "shed": None,
-                                "error": f"unknown stream {rid!r}"}
-                else:
-                    out[rid] = self._drain_locked(rid, st, now_ns)
-        return out
+            p = self._poller_locked(poller)
+            p.waiting += 1
+
+        def ready():  # under the lock, at the drain
+            p.waiting -= 1
+            if not p.streams and not p.waiting:
+                self._pollers.pop(p.pid, None)
+            return [(rid, st) for rid, st in p.streams.items()
+                    if st.pending or st.done]
+
+        return self._wait_drain(p.event, timeout_s, t0, ready, batched=True)
+
+    def open_streams(self) -> int:
+        """Streams of pollers that have not ended, less the pollers'
+        calls in flight: what ``Replica.get_num_ongoing`` adds to its
+        count of calls, so that an open stream reads as one request there
+        (as when each held a long-poll of its own) and the one call that
+        carries a poller's streams is not counted beside them."""
+        with self._lock:
+            return sum(
+                sum(not st.done for st in p.streams.values()) - p.waiting
+                for p in self._pollers.values())
 
     def llm_cancel(self, rid: str) -> bool:
         """Cancel a stream: a queued request leaves the queue, an

@@ -1191,6 +1191,257 @@ def test_wake_counters_count_exactly(how, after_dispatch):
         == after_dispatch
 
 
+# -- one poller for many streams (``llm_poll(poller=...)``) -------------------
+
+
+def _poll_to_the_end(eng, pid, rids, timeout_s=2.0, took=None):
+    """Drain a poller's streams to their ends with its batched long-poll:
+    ``{rid: tokens}``, ``{rid: last response}``, the calls made."""
+    out = {rid: [] for rid in rids}
+    last, calls = {}, 0
+    deadline = time.monotonic() + 60
+    while len(last) < len(rids) and time.monotonic() < deadline:
+        t0 = time.monotonic()
+        resp = eng.llm_poll(poller=pid, timeout_s=timeout_s)
+        if took is not None:
+            took.append(time.monotonic() - t0)
+        calls += 1
+        assert resp.pop("held_ns") > 0
+        for rid, r in resp.items():
+            assert rid not in last, "a stream spoke after its end"
+            assert r["chunks"] or r["done"]    # only those with something
+            for chunk in r["chunks"]:
+                out[rid].extend(chunk)
+            if r["done"]:
+                last[rid] = r
+    assert len(last) == len(rids), "streams did not end"
+    return out, last, calls
+
+
+@pytest.mark.parametrize("poll_s", [0.001, 2.0],
+                         ids=["polls_time_out", "polls_are_woken"])
+def test_two_pollers_get_their_own_streams_tokens_once_in_order(poll_s):
+    """Eight streams over four slots, four to a poller, a thread a poller
+    and the interpreter switching every 10 us: each stream's tokens are
+    the ones it would get alone, whether the batched polls are woken or
+    time out and drain first, and a poller never sees the other's."""
+    import sys
+
+    cfg, fwd = SERVED["gpt2"]
+    eng = _engine(max_batch=4, max_new_cap=16)
+    asked = {i: ([i + 1, 7, 11, i + 2], 5 + i) for i in range(8)}
+    got, calls, errors = {}, {}, []
+
+    def one(pid, rids):
+        try:
+            out, last, calls[pid] = _poll_to_the_end(
+                eng, pid, list(rids), timeout_s=poll_s)
+            assert not any(r["error"] or r["shed"] for r in last.values())
+            for rid, i in rids.items():
+                got[i] = out[rid]
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        eng.generate(PROMPT, 2)
+        before = eng.llm_stats()
+        mine = {pid: {eng.llm_submit(*asked[i], poller=pid): i
+                      for i in asked if i % 2 == k}
+                for k, pid in enumerate(("even", "odd"))}
+        assert set(eng._pollers) == {"even", "odd"}
+        threads = [threading.Thread(target=one, args=item)
+                   for item in mine.items()]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        st = eng.llm_stats()
+        alone = _compiled(fwd, eng.params, cfg, 32)
+    finally:
+        sys.setswitchinterval(interval)
+        eng.shutdown_engine()
+    assert not errors, errors
+    for i, (prompt, n) in asked.items():
+        assert got[i] == _naive_generate(alone, None, prompt, n, None), i
+    chunks = sum(n for _, n in asked.values())
+    assert st["next_calls"] - before["next_calls"] == sum(calls.values()) \
+        == st["next_batched"] - before["next_batched"]
+    assert st["deliver_chunks"] - before["deliver_chunks"] == chunks
+    if poll_s == 2.0:
+        # woken calls: four streams step together, a call takes several
+        assert sum(calls.values()) < chunks
+    # a wake-up is still counted a stream
+    assert st["wakes_deferred"] == sum(n - 2 for _, n in asked.values())
+    assert not eng._pollers and not eng._streams
+
+
+@pytest.mark.parametrize("beside", ["an_idle_poller", "a_slowed_stream"])
+def test_a_stream_submitted_under_a_blocked_call_is_that_calls(beside):
+    """The poller's call is inside its wait (20 s) when the stream is
+    submitted: its first token ends THAT call, as soon as the prefill has
+    it: no first token waits for a time-out. Beside a stream whose steps
+    take 0.4 s the call may end a moment sooner, for that stream's token
+    (the put-off wake-up of the step before, set as the next step or the
+    new prompt's first chunk is enqueued), and a call right after it
+    brings the first token, before the next step."""
+    eng = _engine(max_batch=2, max_new_cap=64)
+    got = []
+    try:
+        eng.generate(PROMPT, 2)
+        old = None
+        if beside == "a_slowed_stream":
+            real = eng._step_fn
+
+            def slow(*a):
+                time.sleep(0.4)
+                return real(*a)
+
+            eng._step_fn = slow
+            old = eng.llm_submit(PROMPT, 40, poller="p")
+            while old not in eng.llm_poll(poller="p", timeout_s=30.0):
+                pass                       # its first token: decoding now
+            # the next call would be woken for the old stream's tokens
+            # too: wait one out, so what follows starts after a wake-up
+            assert eng.llm_poll(poller="p", timeout_s=30.0)[old]["chunks"]
+
+        def call():
+            t0 = time.monotonic()
+            while True:
+                resp = eng.llm_poll(poller="p", timeout_s=20.0)
+                got.append((resp, time.monotonic() - t0))
+                if len(got) == 6 or any(r != old for r in resp
+                                        if r != "held_ns"):
+                    return
+
+        t = threading.Thread(target=call)
+        t.start()
+        deadline = time.monotonic() + 30
+        while not (eng._pollers.get("p") and eng._pollers["p"].waiting) \
+                and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert eng._pollers["p"].waiting == 1
+        new = eng.llm_submit([3, 1, 4], 8, poller="p")
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert len(got) == 1 or (old and len(got) <= 3), got
+        resp, took = got[-1]
+        assert took < 5.0                  # nowhere near the 20 s
+        assert len(resp[new]["chunks"]) == 1 and not resp[new]["done"]
+        rest, last, _ = _poll_to_the_end(
+            eng, "p", [r for r in (old, new) if r])
+        assert len(rest[new]) == 7 and not last[new]["error"]
+    finally:
+        eng.shutdown_engine()
+
+
+def test_a_poller_is_told_once_a_flush_however_many_streams(monkeypatch):
+    """Two streams of one poller decoding side by side, nobody polling:
+    the prefill's first tokens and the two ends tell the poller a stream
+    each, a decode step's put-off wake-ups tell it ONCE for both, and
+    ``wakes_deferred`` still counts a stream a wake-up."""
+    log = []
+    real = llm_engine._Poller.__init__
+
+    def init(p, pid):
+        real(p, pid)
+        p.event = _LoggedEvent(log, pid)
+
+    monkeypatch.setattr(llm_engine._Poller, "__init__", init)
+    eng = _engine(max_batch=2, prefill_rows=2)
+    try:
+        eng.generate(PROMPT, 2)
+        before = eng.llm_stats()
+        rids = [eng.llm_submit([i + 1, 2, 3], 6, poller="p")
+                for i in (0, 1)]
+        deadline = time.monotonic() + 60
+        while eng.llm_stats()["completed"] - before["completed"] < 2 \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        st = eng.llm_stats()
+        resp = eng.llm_poll(poller="p", timeout_s=5.0)
+    finally:
+        eng.shutdown_engine()
+    # 2 first tokens + 4 flushes (tokens 2 to 5 of both) + 2 ends
+    assert log == [("set", "p")] * 8, log
+    assert st["wakes_deferred"] - before["wakes_deferred"] == 8
+    assert st["wakes_after_dispatch"] - before["wakes_after_dispatch"] == 8
+    assert [len(resp[rid]["chunks"]) for rid in rids] == [6, 6]
+    assert all(resp[rid]["done"] for rid in rids)
+
+
+def test_a_flush_says_nothing_of_tokens_an_earlier_call_took():
+    """A call woken for one stream's end takes its neighbour's put-off
+    token with it: the flush that follows finds nothing pending and does
+    not wake the poller for nothing (``next_empty`` stays 0)."""
+    eng = _engine(max_batch=2, prefill_rows=2, max_new_cap=64)
+    before_call = _Gate()
+    try:
+        eng.generate(PROMPT, 2)
+        real = eng._step_fn
+
+        def gated(*a):
+            before_call.stop()
+            return real(*a)
+
+        eng._step_fn = gated
+        before = eng.llm_stats()
+        short = eng.llm_submit([1, 2, 3], 2, poller="p")
+        long_ = eng.llm_submit([4, 5, 6], 4, poller="p")
+        before_call.reached()              # both prefilled, no step yet
+        first = eng.llm_poll(poller="p", timeout_s=30.0)
+        assert {short, long_} <= set(first)
+        before_call.let()                  # step 1: short ends, long_ owed
+        before_call.reached()              # ... fanned out, flush not yet
+        second = eng.llm_poll(poller="p", timeout_s=30.0)
+        assert second[short]["done"] and second[long_]["chunks"]
+        before_call.let()                  # step 2 enqueued: the flush
+        before_call.reached()              # step 2 fanned out, its flush not
+        # the flush after step 2's enqueue found step 1's token gone and
+        # set nothing; step 2's token is pending and not yet announced
+        assert not eng._pollers["p"].event.is_set()
+        st = eng.llm_stats()
+        before_call.open()
+        rest, last, _ = _poll_to_the_end(eng, "p", [long_])
+    finally:
+        before_call.open()
+        eng.shutdown_engine()
+    assert len(rest[long_]) == 2 and not last[long_]["error"]
+    assert st["next_empty"] == before["next_empty"]
+    # it was counted all the same: the token's wake-up was owed and put off
+    assert st["wakes_deferred"] - before["wakes_deferred"] >= 1
+
+
+def test_the_engine_forgets_a_poller_with_its_last_stream(monkeypatch):
+    eng = _engine(max_batch=2)
+    try:
+        eng.generate(PROMPT, 2)
+        # a call with no stream: known while it waits, gone when it ends
+        assert set(eng.llm_poll(poller="p", timeout_s=0.01)) == {"held_ns"}
+        assert not eng._pollers
+        rid = eng.llm_submit(PROMPT, 3, poller="p")
+        assert list(eng._pollers) == ["p"]
+        assert eng.open_streams() == 1
+        _poll_to_the_end(eng, "p", [rid])
+        assert not eng._pollers and not eng._streams
+        assert eng.open_streams() == 0
+        # a vanished client's ended stream is reaped, its poller with it
+        rid = eng.llm_submit(PROMPT, 2, poller="gone")
+        deadline = time.monotonic() + 30
+        while not eng._streams[rid].done and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert eng.open_streams() == 0     # ended: no longer a request
+        monkeypatch.setattr(llm_engine, "_STREAM_TTL_S", 0.0)
+        eng._reap_streams()
+        assert not eng._pollers and not eng._streams
+        # the one-stream lane on an unknown poller's stream id
+        assert eng.llm_next(rid)["error"].startswith("unknown stream")
+    finally:
+        eng.shutdown_engine()
+
+
 # -- streaming transports ---------------------------------------------------
 
 
