@@ -1,19 +1,23 @@
 """Model zoo: pure-JAX pytree models designed for pjit sharding.
 
-Flagship: GPT-2 (the benchmark's training cells); Nemotron-H (a
-hybrid of Mamba-2, attention and latent-MoE layers) and Granite 4.0-H (a
-Mamba-2 mixer or attention, then gated experts, in every layer) are served
-only, as are DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a
-latent cache, group-limited experts) and Falcon-H1 (``models/falcon_h1.py``:
-rotary grouped-query attention AND a Mamba-2 mixer side by side in every
-layer, both caches a layer, fourteen muP multipliers) and Qwen3-Next
-(``models/qwen3_next.py``: three Gated DeltaNet layers to one gated-
-attention layer, a float32 delta-rule state beside K/V rings, top-10-of-512
-experts and a gated shared expert in every layer); those three are not
-exported here, so that a process which serves another family never imports
-them: ``LLMEngine`` resolves them by name. Models are plain
-functions over parameter pytrees — no framework Module state — so the same
-code runs under any mesh and any rules table.
+Exported here: GPT-2 (the benchmark's training cells, and served), Llama
+(served), the sparse ``moe`` model (trained only), and two served-only
+hybrids, Nemotron-H (Mamba-2, attention and latent-MoE layers) and Granite
+4.0-H (a Mamba-2 mixer or attention, then gated experts, in every layer).
+
+Not exported here, so that a process which serves another family never
+imports them; ``LLMEngine`` resolves them by name, as it does all seven
+served families (``serve/llm_engine._model_bundle``): DeepSeek-V2
+(``models/deepseek_v2.py``: latent attention over a latent cache, group-
+limited experts), Falcon-H1 (``models/falcon_h1.py``: rotary grouped-query
+attention AND a Mamba-2 mixer side by side in every layer, both caches a
+layer, fourteen muP multipliers) and Qwen3-Next (``models/qwen3_next.py``:
+three Gated DeltaNet layers to one gated-attention layer, a float32
+delta-rule state beside K/V rings, top-10-of-512 experts and a gated shared
+expert in every layer). ``models/resnet.py`` is imported by its path too.
+
+Models are plain functions over parameter pytrees — no framework Module
+state — so the same code runs under any mesh and any rules table.
 """
 
 from ray_tpu.models.gpt2 import GPT2Config, gpt2_forward, gpt2_init, gpt2_loss

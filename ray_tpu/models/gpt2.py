@@ -69,7 +69,7 @@ class GPT2Config:
     # LayerNorm forward saves only fp32 mean/rstd, and ONE backward
     # kernel per row-block fuses dx/dscale/dbias with the residual-add
     # gradient, so the fp32 LN recompute chain XLA materializes
-    # (PROFILE.md sink #3, ~15ms/step) never reaches HBM. The MLP GELU
+    # (~15ms/step in an early profile) never reaches HBM. The MLP GELU
     # rides a fused tanh backward epilogue. Shapes the TPU lane layout
     # can't tile (D % 128 != 0) fall back to the plain-XLA chain.
     fused_norm: bool = False
@@ -78,7 +78,7 @@ class GPT2Config:
     # the full [B, T, V] logits tensor is NEVER materialized — fwd or
     # bwd (per-chunk remat recomputes chunk logits in backward). Cuts
     # the loss-path HBM footprint by n_chunks x, unblocking larger
-    # batches (PROFILE.md: fp32 [16,1024,50304] logits forced spills at
+    # batches (fp32 [16,1024,50304] logits forced spills at
     # batch >= 24). Must divide vocab_size.
     ce_vocab_chunks: int = 1
     mesh: Any = dataclasses.field(default=None, compare=False)
@@ -624,11 +624,3 @@ def gpt2_prefill(params: Params, cache: Params, tokens: jax.Array,
 
     return whole_prompts(gpt2_prefill_chunk, params, cache, tokens, slots,
                          lengths, cfg)
-
-
-def gpt2_flops_per_token(cfg: GPT2Config, seq_len: int | None = None) -> float:
-    """Training FLOPs/token: 6*N for matmuls + attention score/value FLOPs.
-
-    Standard estimate (PaLM appendix B): 6*n_params + 12*L*D*T (causal)."""
-    t = seq_len or cfg.seq_len
-    return 6 * cfg.n_params + 12 * cfg.n_layer * cfg.d_model * t // 2

@@ -433,10 +433,3 @@ def llama_prefill(params: Params, cache: Params, tokens: jax.Array,
 
     return whole_prompts(llama_prefill_chunk, params, cache, tokens, slots,
                          lengths, cfg)
-
-
-def llama_flops_per_token(cfg: LlamaConfig,
-                          seq_len: int | None = None) -> float:
-    """6*N matmul FLOPs + causal attention score/value FLOPs."""
-    t = seq_len or cfg.seq_len
-    return 6 * cfg.n_params + 12 * cfg.n_layer * cfg.d_model * t // 2

@@ -1,8 +1,8 @@
 """Pallas TPU fused normalization + elementwise-epilogue kernels.
 
-Attacks PROFILE.md sink #3 (~15ms of the 128ms GPT-2 step, ~1.3ms/layer):
-the fp32 layernorm/elementwise *backward* fusions XLA materializes
-through HBM. Same playbook as the flash-attention backward that took
+Attacks the third sink of an early profile of the GPT-2 step (~15ms of
+128ms, ~1.3ms/layer): the fp32 layernorm/elementwise *backward* fusions
+XLA materializes through HBM. Same playbook as the flash-attention backward that took
 39%→52% MFU: fuse the backward chain into one Pallas kernel per
 row-block grid cell, keep fp32 statistics in VMEM, never round-trip
 fp32 intermediates through HBM.

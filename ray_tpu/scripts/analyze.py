@@ -2,9 +2,8 @@
 
 Runs the ``ray_tpu.util.analyze`` passes over the package (or explicit
 paths), applies the committed ``ANALYZE_BASELINE.json`` allowlist, and
-exits non-zero on any NEW finding — the same contract as
-``bench_log --check``: drift fails loud, at review time, not at 3am in
-a chaos soak.
+exits non-zero on any NEW finding: drift fails loud, at review time,
+not at 3am in a chaos soak.
 
 Usage:
     python -m ray_tpu.scripts.analyze [paths...]
@@ -13,8 +12,6 @@ Usage:
         [--no-baseline] [--baseline-file F] [--json]
         [--diff REV]           # only findings on lines changed since REV
         [--write-baseline]     # re-emit the baseline from current findings
-        [--out MICROBENCH.json]  # merge-preserve an `analyze` section
-                                 # (the perfsuite stage)
 
 Baseline workflow: a justified finding is allowlisted by adding its
 stable key (printed with --json, or by --write-baseline) to
@@ -51,51 +48,6 @@ def _write_baseline(result: dict, path: str,
         fh.write("\n")
 
 
-def _merge_out(result: dict, out_path: str) -> None:
-    """Merge-preserve an `analyze` section into MICROBENCH.json (the
-    perfsuite stage): rule counts are the trend the suite tracks —
-    the gate itself is the exit code."""
-    import os
-    import time
-
-    artifact = {}
-    if os.path.exists(out_path):
-        try:
-            with open(out_path) as fh:
-                artifact = json.load(fh)
-        except ValueError:
-            artifact = {}
-    artifact["analyze"] = {
-        "ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "files_scanned": result["n_files"],
-        "passes": sorted(analyze.PASSES),
-        "rule_counts": result["rule_counts"],
-        "new_rule_counts": result["new_rule_counts"],
-        "baselined": len(result["allowed"]),
-        "new": len(result["new"]),
-        "stale_baseline": len(result["stale_baseline"]),
-        "ok": result["ok"],
-    }
-    with open(out_path, "w") as fh:
-        json.dump(artifact, fh, indent=1, sort_keys=True)
-        fh.write("\n")
-    # Timestamped trail line too (committed only on an accelerator —
-    # the on-chip perf session records that its tree passed the gate).
-    try:
-        from ray_tpu.scripts import bench_log
-
-        bench_log.record_analyze(
-            rule_counts=result["rule_counts"],
-            new=len(result["new"]),
-            baselined=len(result["allowed"]),
-            stale_baseline=len(result["stale_baseline"]),
-            ok=result["ok"],
-            device=bench_log.device_kind(),
-        )
-    except Exception:
-        pass
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="ray-tpu analyze",
@@ -124,9 +76,6 @@ def main(argv=None) -> int:
     ap.add_argument("--write-baseline", action="store_true",
                     help="write ANALYZE_BASELINE.json from current "
                          "findings (preserves existing justifications)")
-    ap.add_argument("--out", default=None, metavar="MICROBENCH",
-                    help="merge-preserve an `analyze` rule-count "
-                         "section into this artifact (perfsuite stage)")
     args = ap.parse_args(argv)
 
     if args.write_baseline and (args.paths or args.diff or args.rules):
@@ -158,12 +107,10 @@ def main(argv=None) -> int:
               f"{path}")
         return 0
 
-    if args.out:
-        _merge_out(result, args.out)
-
     if args.as_json:
         print(json.dumps({
             "ok": result["ok"],
+            "files_scanned": result["n_files"],
             "rule_counts": result["rule_counts"],
             "new": [f.to_dict() for f in result["new"]],
             "baselined": [f.to_dict() for f in result["allowed"]],

@@ -40,8 +40,8 @@ Usage::
 
     python -m ray_tpu.scripts.chaos_soak --seed 7 --duration 20
 
-``bench_log.record_chaos_soak`` prints the evidence line (committed to
-BENCH_TPU_SESSIONS.jsonl only on an accelerator).
+The result (fault counts, violations, per-fault MTTR, the seed that
+replays it) is printed as one JSON line and written nowhere.
 """
 
 from __future__ import annotations
@@ -52,12 +52,6 @@ import os
 import random
 import threading
 import time
-
-
-def _device_kind() -> str:
-    from ray_tpu.scripts.bench_log import device_kind
-
-    return device_kind()
 
 
 class _Soak:
@@ -1029,7 +1023,6 @@ class _Soak:
         import ray_tpu
         from ray_tpu.cluster.cluster_utils import Cluster
         from ray_tpu.core.config import config
-        from ray_tpu.scripts import bench_log
 
         # One knob seeds every chaos RNG in this process AND (via env)
         # every process the cluster spawns; restored on exit so an
@@ -1048,7 +1041,7 @@ class _Soak:
         config.override("spill_uri", f"file://{spill_dir}")
         config.override("target_block_size_bytes", 256 << 10)
         try:
-            return self._run_seeded(ray_tpu, Cluster, bench_log)
+            return self._run_seeded(ray_tpu, Cluster)
         finally:
             if prev_env_seed is None:
                 os.environ.pop("RAY_TPU_CHAOS_SEED", None)
@@ -1059,7 +1052,7 @@ class _Soak:
             config.reset("target_block_size_bytes")
             shutil.rmtree(spill_dir, ignore_errors=True)
 
-    def _run_seeded(self, ray_tpu, Cluster, bench_log) -> dict:
+    def _run_seeded(self, ray_tpu, Cluster) -> dict:
         ray_tpu.shutdown()
         cluster = Cluster()
         cluster.add_node(num_cpus=4)  # driver node: survives
@@ -1240,42 +1233,49 @@ class _Soak:
             serve.shutdown()
         except Exception:
             pass
-        entry = bench_log.record_chaos_soak(
-            seed=self.seed,
-            duration_s=self.duration_s,
-            faults=self.faults,
-            violations=self.violations,
-            mttr_ms=self.mttr_ms,
-            tasks_ok=self.tasks_ok,
-            actor_calls_ok=self.actor_calls_ok,
-            puts_ok=self.puts_ok,
-            device=_device_kind(),
-            script="chaos_soak",
-            serve_ok=self.serve_ok,
-            serve_shed=self.serve_shed,
-            llm_ok=self.llm_ok,
-            llm_shed=self.llm_shed,
-            llm_failed_fast=self.llm_failed_fast,
-            train_reports=self.train_reports,
-            train_goodput=self.train_goodput,
-            gang_goodput=self.gang_goodput,
-            gang_reschedules=self.gang_reschedules,
-            dataflow_ok=self.dataflow_ok,
-            dataflow_failed=self.dataflow_failed,
-            dataflow_spilled=self.dataflow_spilled,
-            dataflow_restores=self.dataflow_restores,
-            signal_queries_ok=self.signal_queries_ok,
-            signal_queries_failed=self.signal_queries_failed,
-            signal_slo_transitions=self.signal_slo_transitions,
-            signal_missed_evals=self.signal_missed_evals,
-            autoscaler_rounds_ok=self.autoscaler_rounds_ok,
-            autoscaler_rounds_failed=self.autoscaler_rounds_failed,
-            autoscaler_launches=self.autoscaler_launches,
-            autoscaler_launch_failures=self.autoscaler_launch_failures,
-            autoscaler_quarantines=self.autoscaler_quarantines,
-            autoscaler_scale_downs=self.autoscaler_scale_downs,
-            autoscaler_preemptions=self.autoscaler_preemptions,
-        )
+        # The seed makes any line replayable:
+        # RAY_TPU_CHAOS_SEED=<seed> python -m ray_tpu.scripts.chaos_soak
+        entry: dict = {
+            "seed": self.seed,
+            "duration_s": round(float(self.duration_s), 1),
+            "faults": dict(self.faults),
+            "faults_injected": sum(self.faults.values()),
+            "violations": list(self.violations),
+            "n_violations": len(self.violations),
+            "tasks_ok": self.tasks_ok,
+            "actor_calls_ok": self.actor_calls_ok,
+            "puts_ok": self.puts_ok,
+            "serve_ok": self.serve_ok,
+            "serve_shed": self.serve_shed,
+            "llm_ok": self.llm_ok,
+            "llm_shed": self.llm_shed,
+            "llm_failed_fast": self.llm_failed_fast,
+            "train_reports": self.train_reports,
+            "train_goodput": self.train_goodput,
+            "gang_goodput": self.gang_goodput,
+            "gang_reschedules": self.gang_reschedules,
+            "dataflow_ok": self.dataflow_ok,
+            "dataflow_failed": self.dataflow_failed,
+            "dataflow_spilled": self.dataflow_spilled,
+            "dataflow_restores": self.dataflow_restores,
+            "signal_queries_ok": self.signal_queries_ok,
+            "signal_queries_failed": self.signal_queries_failed,
+            "signal_slo_transitions": self.signal_slo_transitions,
+            "signal_missed_evals": self.signal_missed_evals,
+            "autoscaler_rounds_ok": self.autoscaler_rounds_ok,
+            "autoscaler_rounds_failed": self.autoscaler_rounds_failed,
+            "autoscaler_launches": self.autoscaler_launches,
+            "autoscaler_launch_failures": self.autoscaler_launch_failures,
+            "autoscaler_quarantines": self.autoscaler_quarantines,
+            "autoscaler_scale_downs": self.autoscaler_scale_downs,
+            "autoscaler_preemptions": self.autoscaler_preemptions,
+        }
+        if self.mttr_ms:
+            entry["mttr_ms"] = {
+                "mean": round(sum(self.mttr_ms) / len(self.mttr_ms), 1),
+                "max": round(max(self.mttr_ms), 1),
+                "n": len(self.mttr_ms),
+            }
         ray_tpu.shutdown()
         cluster.shutdown()
         return entry

@@ -5,7 +5,7 @@ dominates (MLPerf TPU-pod studies, PAPERS.md): a heartbeat-timeout crash
 detection burns ``node_death_timeout_s`` of dead time per preemption,
 while a proactive drain reconstructs actors on surviving nodes before
 the departing node exits. This script measures both paths on a local
-multi-node ``Cluster`` and emits one ``drain_recovery_ms`` record:
+multi-node ``Cluster`` and prints how long each took:
 
     python -m ray_tpu.scripts.drain_bench
 
@@ -17,29 +17,16 @@ killed outright) to the group's reservation being CREATED again on
 healthy nodes. ``--gang`` runs it for both triggers, plus a seeded
 preemption schedule against an elastic ``DataParallelTrainer``
 (num_workers=2, min_workers=1) whose downtime ledger must attribute
-every lost second to preemption/drain/reschedule — the committed
-``goodput_pct`` envelope. ``--out`` merges a ``gang_recovery`` section
-into a MICROBENCH-style artifact.
-
-Records append to the committed ``BENCH_TPU_SESSIONS.jsonl`` evidence
-trail only when run on a real accelerator cluster
-(``bench_log.record_drain_recovery`` / ``record_gang_recovery`` gate on
-device); elsewhere the JSON lines are just printed.
+every lost second to preemption/drain/reschedule, or the script exits
+non-zero. The JSON lines are printed and written nowhere.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import threading
 import time
-
-
-def _device_kind() -> str:
-    from ray_tpu.scripts.bench_log import device_kind
-
-    return device_kind()
 
 
 def _wait_actor_on_other_node(head, actor_id: str, avoid_node: str,
@@ -319,58 +306,28 @@ def run_gang(seed: int) -> dict:
 
 
 def main(argv=None) -> dict:
-    from ray_tpu.scripts import bench_log
-
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--gang", action="store_true",
                     help="also run the gang-recovery MTTR probe + the "
                          "seeded elastic-goodput envelope")
     ap.add_argument("--seed", type=int, default=12,
                     help="preemption-schedule seed for the gang "
-                         "goodput envelope (committed with the "
-                         "artifact so the run is replayable)")
-    ap.add_argument("--out", default=None,
-                    help="merge the gang_recovery section into this "
-                         "MICROBENCH-style artifact")
+                         "goodput envelope (printed on failure so "
+                         "the run is replayable)")
     args = ap.parse_args(argv)
 
-    device = _device_kind()
     drain_s = _one_round(proactive=True)
     crash_s = _one_round(proactive=False)
-    entry = bench_log.record_drain_recovery(
-        drain_s * 1000, crash_s * 1000, device=device)
+    entry = {
+        "proactive_drain_ms": round(drain_s * 1000, 1),
+        "crash_detection_ms": round(crash_s * 1000, 1),
+    }
     print(json.dumps(entry))
     if not args.gang:
         return entry
 
     gang = run_gang(args.seed)
-    for trigger, rnd in gang["mttr"].items():
-        line = bench_log.record_gang_recovery(
-            rnd["pg_reschedule_ms"], trigger=trigger,
-            bundles=rnd["bundles"], bundles_lost=rnd["bundles_lost"],
-            device=device, script="drain_bench")
-        print(json.dumps(line))
     env = gang["goodput_envelope"]
-    if env.get("goodput_pct") is not None:
-        bench_log.record_goodput(
-            trial="gang", goodput_pct=env["goodput_pct"],
-            wall_s=env["goodput"].get("wall_s") or 0.0,
-            downtime_s=env["goodput"].get("downtime_s") or 0.0,
-            by_cause=env["goodput"].get("by_cause") or {},
-            device=device, script="drain_bench", seed=args.seed)
-    if args.out:
-        # Merge-preserve: every perfsuite stage owns one section.
-        payload = {}
-        if os.path.exists(args.out):
-            with open(args.out) as f:
-                try:
-                    payload = json.load(f)
-                except ValueError:
-                    payload = {}
-        payload["gang_recovery"] = gang
-        with open(args.out, "w") as f:
-            json.dump(payload, f, indent=1, sort_keys=True)
-            f.write("\n")
     print(json.dumps(gang, default=str))
     ok = (env["completed"] and env["faults_injected"]
           and env["downtime_fully_attributed"]

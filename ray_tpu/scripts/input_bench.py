@@ -21,13 +21,11 @@ the workload):
   with no error, its goodput %% computed, and the downtime attributed
   to the drain/preemption cause — never unaccounted wall time.
 
-Machine-independent shape results (counts, phase coverage, agreement
-booleans, attribution) merge into MICROBENCH.json under
-``input_pipeline`` (perfsuite ``--input-pipeline`` stage); latency and
-stall numbers ride along for context. ``bench_log.record_input_pipeline``
-/ ``record_goodput`` commit evidence lines on-chip.
+What it checks is machine-independent (counts, phase coverage,
+agreement booleans, attribution); the latency and stall numbers it
+prints ride along for context and are written nowhere.
 
-Run: python -m ray_tpu.scripts.input_bench [--out MICROBENCH.json]
+Run: python -m ray_tpu.scripts.input_bench
      [--device] [--drain] [--blocks 8] [--batch-size 64]
 """
 
@@ -35,16 +33,9 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import threading
 import time
-
-
-def _device_kind() -> str:
-    from ray_tpu.scripts.bench_log import device_kind
-
-    return device_kind()
 
 
 def _obs():
@@ -465,9 +456,6 @@ def main() -> None:
     ap = argparse.ArgumentParser(
         description="Input-pipeline / training-goodput harness with "
                     "client/server stall-fraction cross-check")
-    ap.add_argument("--out", default=None,
-                    help="merge the input_pipeline section into this "
-                         "MICROBENCH-style artifact")
     ap.add_argument("--blocks", type=int, default=8)
     ap.add_argument("--batch-size", type=int, default=64)
     ap.add_argument("--steps", type=int, default=6)
@@ -487,38 +475,6 @@ def main() -> None:
               device=args.device, drain=args.drain, steps=args.steps,
               workers=args.workers, cluster=args.cluster)
 
-    from ray_tpu.scripts import bench_log
-
-    device = _device_kind()
-    entry = bench_log.record_input_pipeline(
-        client=res["pipeline"]["client"],
-        server=res["pipeline"]["server"],
-        agreement=res["pipeline"]["agreement"],
-        n_batches=res["pipeline"]["n_batches"],
-        device=device, script="input_bench")
-    res["evidence"] = {"committed_to": entry.get("committed_to")}
-    gp = (res.get("goodput_drain") or {})
-    if gp.get("goodput_pct") is not None:
-        bench_log.record_goodput(
-            trial="train", goodput_pct=gp["goodput_pct"],
-            wall_s=gp.get("wall_s") or 0.0,
-            downtime_s=gp.get("downtime_s") or 0.0,
-            by_cause=gp.get("by_cause") or {},
-            device=device, script="input_bench")
-
-    if args.out:
-        # Merge-preserve: every perfsuite stage owns one section.
-        payload = {}
-        if os.path.exists(args.out):
-            with open(args.out) as f:
-                try:
-                    payload = json.load(f)
-                except ValueError:
-                    payload = {}
-        payload["input_pipeline"] = res
-        with open(args.out, "w") as f:
-            json.dump(payload, f, indent=1, sort_keys=True)
-            f.write("\n")
     print(json.dumps(res, indent=1, default=str))
     if not res["agreement"]["ok"]:
         print("input_bench: CLIENT/SERVER DISAGREE — the goodput "

@@ -30,12 +30,8 @@ Usage:
         [--tasks 2000] [--actors 200] [--broadcast-mb 256]
         [--queued 0] [--head-scale] [--head-nodes 64]
         [--head-queued 100000] [--head-actors 1000]
-        [--out MICROBENCH.json]
 
-With --out pointing at MICROBENCH.json the results merge under
-"scalability" (real cluster) and "head_scale" keys (the per-op numbers
-from microbench.py stay put), and ``bench_log.record_scalebench``
-appends the evidence line.
+Each section's result is printed as JSON and written nowhere.
 """
 
 from __future__ import annotations
@@ -632,58 +628,22 @@ def main():
                          "waves against a provider-backed fake fleet")
     ap.add_argument("--burst-waves", type=int, default=5)
     ap.add_argument("--burst-seed", type=int, default=0)
-    ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
     # Head-scale first: its RSS-growth number needs a process that has
     # not already ballooned through the real-cluster section.
-    head_res = None
     if args.head_scale or args.skip_cluster:
         head_res = run_head_scale(
             args.head_nodes, args.head_queued, args.head_actors,
             args.head_subs, args.head_spans)
         print(json.dumps(head_res, indent=1))
-    res = None
     if not args.skip_cluster and not args.demand_burst:
         res = run(args.nodes, args.cpus, args.tasks, args.actors,
                   args.broadcast_mb, queued=args.queued)
         print(json.dumps(res, indent=1))
-    fleet_res = None
     if args.demand_burst:
         fleet_res = run_demand_burst(args.burst_waves, args.burst_seed)
         print(json.dumps(fleet_res, indent=1))
-    if args.out:
-        merged = {}
-        if os.path.exists(args.out):
-            with open(args.out) as f:
-                merged = json.load(f)
-        if res is not None:
-            merged["scalability"] = res
-        if head_res is not None:
-            merged["head_scale"] = head_res
-        if fleet_res is not None:
-            merged["fleet_scaling"] = fleet_res
-        with open(args.out, "w") as f:
-            json.dump(merged, f, indent=1)
-            f.write("\n")
-    from ray_tpu.scripts import bench_log
-
-    if res is not None or head_res is not None:
-        entry = bench_log.record_scalebench(
-            scalability=res, head_scale=head_res)
-        print(json.dumps({"bench_log": entry.get("committed_to")}),
-              file=sys.stderr)
-    if fleet_res is not None:
-        entry = bench_log.record_fleet_scaling(
-            scale_up_ms={k: v for k, v in
-                         fleet_res["scale_up_ms"].items()
-                         if k in ("p50", "p99")},
-            bin_pack_efficiency=fleet_res["bin_pack_efficiency"],
-            scale_down=fleet_res["scale_down"],
-            waves=fleet_res["waves"], seed=fleet_res["seed"],
-            device=bench_log.device_kind())
-        print(json.dumps({"bench_log": entry.get("committed_to")}),
-              file=sys.stderr)
 
 
 if __name__ == "__main__":

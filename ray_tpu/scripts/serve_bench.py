@@ -16,12 +16,11 @@ already-expired budget must come back 503/shed and land in
 ``ray_tpu_serve_shed_total``) and — when tracing — one end-to-end
 traced request whose ingress/route/replica spans must share a trace id.
 
-Machine-independent shape results (counts, agreement booleans, phases
-observed) merge into MICROBENCH.json under ``serve`` (perfsuite
-``--serve`` stage); latency numbers ride along for context only.
-``bench_log.record_serve_latency`` commits an evidence line on-chip.
+What it checks is machine-independent (counts, agreement booleans,
+phases observed); the latencies it prints ride along for context only
+and are written nowhere.
 
-Run: python -m ray_tpu.scripts.serve_bench [--out MICROBENCH.json]
+Run: python -m ray_tpu.scripts.serve_bench
      [--mode http|handle] [--connections 8] [--requests 25] [--cluster]
 """
 
@@ -35,12 +34,6 @@ import threading
 import time
 
 DEPLOYMENT = "serve_bench_echo"
-
-
-def _device_kind() -> str:
-    from ray_tpu.scripts.bench_log import device_kind
-
-    return device_kind()
 
 
 class _Stream:
@@ -382,9 +375,6 @@ def main() -> None:
     ap = argparse.ArgumentParser(
         description="Serve concurrent-stream load harness with "
                     "client/server latency cross-check")
-    ap.add_argument("--out", default=None,
-                    help="merge the serve section into this "
-                         "MICROBENCH-style artifact")
     ap.add_argument("--mode", choices=["http", "handle"], default="http")
     ap.add_argument("--connections", type=int, default=8)
     ap.add_argument("--requests", type=int, default=25)
@@ -397,39 +387,9 @@ def main() -> None:
                          "backend (events ship over the worker plane)")
     args = ap.parse_args()
 
-    from ray_tpu.scripts import bench_log
-
     res = run(mode=args.mode, connections=args.connections,
               requests_per_conn=args.requests, sleep_ms=args.sleep_ms,
               batch=args.batch, cluster=args.cluster)
-
-    # Only a lint-valid line may enter the committed trail: a
-    # degenerate run (every stream request failed -> no client
-    # latencies) must not poison BENCH_TPU_SESSIONS.jsonl with a line
-    # tier-1's evidence check would reject forever after.
-    if res["client"]["p50_ms"] is not None:
-        entry = bench_log.record_serve_latency(
-            client=res["client"], server=res["server"],
-            agreement=res["agreement"], mode=res["mode"],
-            connections=res["connections"],
-            n_requests=res["client"]["count"], device=_device_kind(),
-            script="serve_bench")
-        res["evidence"] = {k: entry[k] for k in ("committed_to",)
-                           if k in entry}
-
-    if args.out:
-        # Merge-preserve: every perfsuite stage owns one section.
-        payload = {}
-        if os.path.exists(args.out):
-            with open(args.out) as f:
-                try:
-                    payload = json.load(f)
-                except ValueError:
-                    payload = {}
-        payload["serve"] = res
-        with open(args.out, "w") as f:
-            json.dump(payload, f, indent=1, sort_keys=True)
-            f.write("\n")
     print(json.dumps(res, indent=1, default=str))
     if not res["agreement"]["ok"]:
         print("serve_bench: CLIENT/SERVER DISAGREE — the serve metrics "

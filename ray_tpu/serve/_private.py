@@ -824,7 +824,7 @@ def routed_call(deployment_name: str, method: str, args: tuple, kwargs: dict,
             # route = time actually spent in assign, ACCUMULATED across
             # attempts — a dead-replica retry must not fold the failed
             # attempt's RPC time + backoff into the route histogram
-            # (PROFILE.md reads "growing route" as a capacity signal;
+            # (a growing route phase reads as a capacity signal;
             # retry losses land in the serialize remainder instead).
             route_s = 0.0
             for attempt in range(4):

@@ -7,8 +7,7 @@ GC-finalizer deadlock (a non-reentrant lock reachable from ``__del__``/
 lock-order discipline the round-6 head shard split could only *document*
 in a comment, and blocking RPC/sqlite work under a shard lock that
 serialized the control plane. This package turns those postmortems into
-AST-level passes that run in tier-1, the same way ``bench_log --check``
-turned evidence hygiene into a gate.
+AST-level passes that run in tier-1.
 
 Passes (rule-id prefix):
 

@@ -347,7 +347,7 @@ def run(paths: Optional[Sequence[str]] = None,
         baseline_file: Optional[str] = None,
         diff_rev: Optional[str] = None,
         root: Optional[str] = None) -> dict:
-    """One-call API (the CLI, perfsuite stage and tier-1 test share it).
+    """One-call API (the CLI and the tier-1 test share it).
 
     Returns ``{findings, new, allowed, stale_baseline, rule_counts,
     ok}`` where ``ok`` means zero unbaselined findings (stale baseline

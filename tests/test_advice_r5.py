@@ -1,4 +1,4 @@
-"""Regression tests for round-4 advisor findings (ADVICE.md r4).
+"""Regression tests for round-4 advisor findings (round 4).
 
 Covers: variant-expanding searchers run to exhaustion (not capped at
 num_samples), Trial persistence uses a monotonic version (not id()),

@@ -1,5 +1,5 @@
 """Regression tests for the round-5 advisor findings fixed in this PR
-(ADVICE.md r5): registry pairing (CQL/bandits), warm-up priority creep in
+(round 5): registry pairing (CQL/bandits), warm-up priority creep in
 the prioritized replay buffer, sklearn fit_time scope, DDPPO actor
 lifecycle, and the on-chip bench evidence trail."""
 
@@ -188,26 +188,3 @@ def test_ddppo_context_manager_stops_workers():
     assert all(a["state"] == "DEAD" for a in state.list_actors()
                if a["class_name"] == "DDPPOWorker")
     algo.stop()  # idempotent
-
-
-def test_bench_log_records_on_chip_only(tmp_path, monkeypatch):
-    import json
-
-    from ray_tpu.scripts import bench_log
-
-    dest = tmp_path / "sessions.jsonl"
-    monkeypatch.setenv(bench_log.ENV_VAR, str(dest))
-    assert bench_log.record_if_on_chip(
-        {"script": "bench", "device": "TPU v5e", "value": 46.0}) == str(dest)
-    # CPU fallback numbers are NOT evidence and must not be recorded.
-    assert bench_log.record_if_on_chip(
-        {"script": "bench", "device": "cpu", "value": 1.0}) is None
-    assert bench_log.record_if_on_chip({"script": "bench"}) is None
-    lines = [json.loads(line) for line in dest.read_text().splitlines()]
-    assert len(lines) == 1
-    assert lines[0]["device"] == "TPU v5e"
-    assert "ts" in lines[0] and "iso" in lines[0]
-    # Explicitly disabled: empty env var.
-    monkeypatch.setenv(bench_log.ENV_VAR, "")
-    assert bench_log.record_if_on_chip(
-        {"script": "bench", "device": "TPU v5e"}) is None

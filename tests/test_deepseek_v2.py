@@ -703,21 +703,32 @@ def test_the_tiny_preset_engine_and_the_bundles_error_text():
         _model_bundle("mamba", None, "tiny")
 
 
-# -- the families that were here ------------------------------------------------
+# -- every family's two programs ------------------------------------------------
 
 # sha256 of the lowered text (StableHLO, no locations) of each family's two
-# engine programs at its tiny preset, taken on the parent of the PR that
-# added latent attention (PR 38). That PR only ADDED functions to
-# ``ops/attention.py`` and ``ops/moe.py``: these programs are bit for bit
-# what they were. A PR that changes one of them ON PURPOSE replaces its
-# line here and says so; one that did not mean to has found out. PR 42
-# replaced GPT-2's two ON PURPOSE (its cache holds merged rows, written
-# after both layer loops); the six others held through it. PR 48 replaced
-# GPT-2's decode program ON PURPOSE (its scan runs over the layer's index
-# with the stacked cache closed over, which the attention is handed whole
-# with that index, and the step returns what it read of the rings); its
-# chunk program and the six others held through it, Granite's and
-# Nemotron's among them.
+# engine programs at its tiny preset: the one table every family's pair is
+# pinned in (PR 50 merged ``tests/test_falcon_h1.py``'s four lines into it
+# and added Qwen3-Next's two, all values as they stood). A PR that changes
+# a program ON PURPOSE replaces its line here and says so; one that did not
+# mean to has found out. The four older families' were taken on the parent
+# of the PR that added latent attention (PR 38), which only ADDED functions
+# to ``ops/attention.py`` and ``ops/moe.py``. PR 42 replaced GPT-2's two ON
+# PURPOSE (its cache holds merged rows, written after both layer loops);
+# the six others held through it. PR 48 replaced GPT-2's decode program ON
+# PURPOSE (its scan runs over the layer's index with the stacked cache
+# closed over, which the attention is handed whole with that index, and the
+# step returns what it read of the rings); its chunk program and the six
+# others held through it, Granite's and Nemotron's among them.
+# DeepSeek-V2's two were taken on the parent of the PR that added Falcon-H1
+# (PR 43: the mixer's column multipliers and the rotary helper were ADDED).
+# Falcon-H1's own two are the programs of PR 44, whose rings hold merged
+# rows that the step reads as they lie and the chunk reads before it
+# writes: that PR changed them ON PURPOSE (and no other family's). PR 48
+# replaced Falcon-H1's decode program ON PURPOSE (the attention is handed
+# the stacked cache and the layer's index, and the step returns what it
+# read of the rings); its chunk program and DeepSeek-V2's two held through
+# it. Qwen3-Next's two were taken on PR 50's parent (PR 49), the programs
+# of PR 48.
 LOWERED = {
     ("gpt2", "decode"):
         "2b81ed566a4807464776b4b17fb93f366083f68456236ee2eeb73d0f4b83d1e2",
@@ -735,11 +746,23 @@ LOWERED = {
         "f8cf6f550050d746a18138136189602e5fd5eb651fc23d3b8c81cc4729d5aac6",
     ("granite_hybrid", "prefill"):
         "75223bed40a6807b543fa8559dfab0c621e3691d3403d64521cd23710aabe7c0",
+    ("deepseek_v2", "decode"):
+        "49e82efdba2cf0ae90ad81e9be4d1dd37f7b5fbf9a921f2de9ba58ed47100ec8",
+    ("deepseek_v2", "prefill"):
+        "52a5ec015c69fe816fa3668bc8bb7a33010803b7015750ae1fa51f9b931e67d0",
+    ("falcon_h1", "decode"):
+        "12254346a7750fae85518cd98839101153ec8b1c10b65ef6b911c45a458fd552",
+    ("falcon_h1", "prefill"):
+        "0e302753a3af60ac69badd51fccf8994c05d93eb66f0c8b4624a87981698c5c1",
+    ("qwen3_next", "decode"):
+        "471f52060158e3f86c7209cf7ab4c7cbbd63e0e50d67e77b9bd0c3214cd5f6c9",
+    ("qwen3_next", "prefill"):
+        "7652be0b12c44a4698adcd67e1e99dad4412f86d60108e1d4c4cf6475f8b871f",
 }
 
 
 @pytest.mark.parametrize("model, program", sorted(LOWERED))
-def test_the_other_families_programs_are_what_they_were(model, program):
+def test_every_familys_programs_are_what_they_were(model, program):
     from ray_tpu.serve.llm_engine import _model_bundle
 
     cfg, init, init_cache, chunk, step = _model_bundle(model, None, "tiny")

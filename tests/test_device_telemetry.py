@@ -95,34 +95,7 @@ def test_capture_jax_profiler_and_broken_profiler_fallback(monkeypatch):
     assert res["kind"] == "stack_sampler"
 
 
-# -- unit: bench_log / grafana satellites ----------------------------------
-
-
-def test_record_task_overhead(tmp_path, monkeypatch):
-    from ray_tpu.scripts import bench_log
-
-    recs = [
-        {"name": "noop", "submitted_at": 10.0, "start_time": 10.002,
-         "phases": {"get_args": 1_000_000, "execute": 2_000_000,
-                    "put_outputs": 500_000}},
-        {"name": "noop", "submitted_at": 10.0, "start_time": 10.010,
-         "phases": {"get_args": 3_000_000, "execute": 8_000_000,
-                    "put_outputs": 700_000}},
-        {"name": "pending", "submitted_at": 11.0, "start_time": None},
-    ]
-    log = tmp_path / "bench.jsonl"
-    monkeypatch.setenv(bench_log.ENV_VAR, str(log))
-    entry = bench_log.record_task_overhead(recs, device="")
-    assert entry["n_tasks"] == 2
-    assert entry["submit_to_start"]["p50_ms"] <= \
-        entry["submit_to_start"]["p99_ms"]
-    assert entry["phases"]["execute"]["p99_ms"] == 8.0
-    assert entry["committed_to"] is None  # cpu/no device: print-only
-    entry = bench_log.record_task_overhead(recs, device="tpu-v4")
-    assert entry["committed_to"] == str(log)
-    line = json.loads(log.read_text().splitlines()[-1])
-    assert line["bench"] == "task_overhead"
-    assert line["phases"]["get_args"]["count"] == 2
+# -- unit: grafana satellites --------------------------------------------
 
 
 def test_merge_prometheus_series_identity():

@@ -5,12 +5,12 @@ layer, a wrapped ring of rotated keys, each of the fourteen multipliers moved
 alone, the controls that must fail the limit the benchmark's configuration
 states, the vocabulary's slices against the uncut head, the types the
 programs compute in, the shared ops this family added to (``ops/rotary.py``,
-``ops/mamba2.py``'s column multipliers), the other families' programs bit for
-bit, and the engine on the normal path with its counters.
+``ops/mamba2.py``'s column multipliers), and the engine on the normal path
+with its counters (its two programs are held bit for bit by
+``tests/test_deepseek_v2.py``'s table).
 """
 
 import dataclasses
-import hashlib
 import os
 import re
 
@@ -508,51 +508,6 @@ def test_the_mixers_column_multipliers_in_the_step_and_in_rows_alike():
     traced = lambda d: str(jax.make_jaxpr(
         lambda y: mamba2.mamba_step(p, y, tail, state, d)[0])(y[:, 0]))
     assert traced(plain).count(" mul ") + 1 == traced(dims).count(" mul ")
-
-
-# sha256 of the lowered text (StableHLO, no locations) of DeepSeek-V2's two
-# engine programs at its tiny preset, taken on the parent of the PR that
-# added Falcon-H1 (PR 43), as ``tests/test_deepseek_v2.py`` holds the four
-# older families' (which that file still holds through this PR: the
-# mixer's column multipliers and the rotary helper were ADDED). Falcon-H1's
-# own two are the programs of PR 44, whose rings hold merged rows that the
-# step reads as they lie and the chunk reads before it writes: that PR
-# changed them ON PURPOSE (and no other family's); a later one that does
-# replaces these lines and says so. PR 48 replaced Falcon-H1's decode
-# program ON PURPOSE (the attention is handed the stacked cache and the
-# layer's index, and the step returns what it read of the rings); its chunk
-# program and DeepSeek-V2's two held through it.
-LOWERED = {
-    ("deepseek_v2", "decode"):
-        "49e82efdba2cf0ae90ad81e9be4d1dd37f7b5fbf9a921f2de9ba58ed47100ec8",
-    ("deepseek_v2", "prefill"):
-        "52a5ec015c69fe816fa3668bc8bb7a33010803b7015750ae1fa51f9b931e67d0",
-    ("falcon_h1", "decode"):
-        "12254346a7750fae85518cd98839101153ec8b1c10b65ef6b911c45a458fd552",
-    ("falcon_h1", "prefill"):
-        "0e302753a3af60ac69badd51fccf8994c05d93eb66f0c8b4624a87981698c5c1",
-}
-
-
-@pytest.mark.parametrize("model, program", sorted(LOWERED))
-def test_the_fifth_and_sixth_familys_programs_are_what_they_were(model,
-                                                                   program):
-    from ray_tpu.serve.llm_engine import _model_bundle
-
-    cfg, init, init_cache, chunk, step = _model_bundle(model, None, "tiny")
-    params = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: init_cache(cfg, 3, 16))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    if program == "decode":
-        text = jax.jit(lambda p, c, t, n: step(p, c, t, n, cfg)).lower(
-            params, cache, i32(3), i32(3)).as_text()
-    else:
-        text = jax.jit(lambda p, c, t, s, a, n: chunk(
-            p, c, t, s, a, n, cfg, window=8)).lower(
-                params, cache, i32(1, 4), i32(1), i32(1), i32(1)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == LOWERED[model, program], (
-            f"{model}'s {program} program is not the one it was")
 
 
 def test_the_programs_name_the_scopes_the_readers_read():

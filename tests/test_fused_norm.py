@@ -2,7 +2,7 @@
 the plain-JAX chains they replace — interpret-mode parity on CPU, the
 same contract the flash-attention kernels carry.
 
-Covers PROFILE.md sink #3 (round 7): forward AND gradient parity for
+Covers forward AND gradient parity for
 LayerNorm (GPT-2 D=768 shape), RMSNorm (Llama D=1024 shape), and the
 tanh-GELU epilogue, including the dscale/dbias column reductions and
 the fused residual-add gradient; odd-shape XLA fallback asserted via

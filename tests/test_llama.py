@@ -8,7 +8,6 @@ import pytest
 
 from ray_tpu.models.llama import (
     LlamaConfig,
-    llama_flops_per_token,
     llama_forward,
     llama_init,
     llama_loss,
@@ -148,10 +147,7 @@ def test_sharded_forward_on_mesh(devices8):
     assert np.isfinite(float(metrics["loss"]))
 
 
-def test_flops_accounting():
-    cfg = LlamaConfig.small()
-    assert llama_flops_per_token(cfg) > 6 * cfg.n_params
-    # n_params formula matches the actual tree.
+def test_n_params_counts_the_tree():
     params = llama_init(jax.random.key(0), CFG)
     counted = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     assert counted == CFG.n_params

@@ -22,7 +22,8 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import gpt2, llama, nemotron_h
+from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
+                            llama, nemotron_h, qwen3_next)
 from ray_tpu.serve import _observability as obs
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve._observability import RequestShedError
@@ -57,14 +58,23 @@ def _clean_between_tests():
 GPT2_FP32 = dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=jnp.float32)
 LLAMA_FP32 = dataclasses.replace(llama.LlamaConfig.tiny(),
                                  dtype=jnp.float32)
-NEMOTRON_FP32 = nemotron_h.NemotronHConfig.tiny(
-    dtype=jnp.float32, param_dtype=jnp.float32)
-# Every family the engine serves: its float32 tiny config and the
-# full-context forward its served tokens are held to.
+_FP32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
+NEMOTRON_FP32 = nemotron_h.NemotronHConfig.tiny(**_FP32)
+# Every family the engine serves (the arms of ``_model_bundle``): its
+# float32 tiny config and the full-context forward its served tokens are
+# held to.
 SERVED = {
     "gpt2": (GPT2_FP32, gpt2.gpt2_forward),
     "llama": (LLAMA_FP32, llama.llama_forward),
     "nemotron_h": (NEMOTRON_FP32, nemotron_h.nemotron_h_forward),
+    "granite_hybrid": (granite_hybrid.GraniteHybridConfig.tiny(**_FP32),
+                       granite_hybrid.granite_hybrid_forward),
+    "deepseek_v2": (deepseek_v2.DeepseekV2Config.tiny(**_FP32),
+                    deepseek_v2.deepseek_v2_forward),
+    "falcon_h1": (falcon_h1.FalconH1Config.tiny(**_FP32),
+                  falcon_h1.falcon_h1_forward),
+    "qwen3_next": (qwen3_next.Qwen3NextConfig.tiny(**_FP32),
+                   qwen3_next.qwen3_next_forward),
 }
 every_family = pytest.mark.parametrize("model", list(SERVED))
 PROMPT = [5, 9, 2, 17, 3]

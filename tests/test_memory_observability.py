@@ -57,22 +57,6 @@ def test_attr_rides_serialization_meta():
     assert ser.meta_field(b"not-msgpack", "attr", {}) == {}
 
 
-def test_record_memory_pressure(tmp_path):
-    from ray_tpu.scripts import bench_log
-
-    samples = [
-        {"used": 10, "capacity": 100, "num_evictions": 1},
-        {"used": 50, "capacity": 100, "num_evictions": 4},
-        {"used": 30, "capacity": 100, "num_evictions": 4},
-    ]
-    entry = bench_log.record_memory_pressure(
-        samples, device="cpu", path=str(tmp_path / "log.jsonl"))
-    assert entry["peak_used_bytes"] == 50
-    assert entry["peak_occupancy"] == 0.5
-    assert entry["evictions"] == 3
-    assert entry["committed_to"] is None  # cpu runs never commit
-
-
 def test_shm_stats_info_raise_on_unlinked_segment(tmp_path):
     """A live handle whose segment another process unlinked must fail
     LOUD from stats()/info(), not return recycled-memory garbage

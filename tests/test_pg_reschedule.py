@@ -657,12 +657,12 @@ def test_tune_gang_trial_drain_exempt_from_max_failures():
         c.shutdown()
 
 
-# -- seeded preemption schedule (the committed envelope) --------------------
+# -- seeded preemption schedule (drain_bench's envelope) --------------------
 
 
 @pytest.mark.slow
 def test_seeded_gang_preemption_schedule_envelope():
-    """The committed MICROBENCH `gang_recovery` scenario end to end:
+    """``drain_bench --gang``'s goodput envelope end to end:
     seed 12's drain+kill schedule against the elastic gang — trial
     completes, PG ends ALIVE on healthy nodes, downtime 100%%
     attributed to planned causes, budget intact."""

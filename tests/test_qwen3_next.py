@@ -6,9 +6,8 @@ must fail the limit the benchmark's configuration states, the four expert
 shares against the uncut layer and the four vocabulary slices against the
 uncut head, the router against its two-step form, the types the programs
 compute in, the scopes the readers read, and the engine on the normal path
-with its counters. The other six families' programs are held bit for bit by
-``tests/test_deepseek_v2.py`` and ``tests/test_falcon_h1.py``, which this
-PR leaves as they are.
+with its counters. Every family's two programs, this one's among them, are
+held bit for bit by ``tests/test_deepseek_v2.py``'s one table.
 """
 
 import dataclasses

@@ -6,10 +6,9 @@ cluster (plus a parked-queue audit), and a reduced head-at-scale pass
 with the span cap lowered so the retention/drop machinery is observed.
 
 Full envelope runs: ``python -m ray_tpu.scripts.scalebench --nodes 4
---queued 100000 --head-scale`` (see SCALING.md round 6).
+--queued 100000 --head-scale`` (SCALING.md, "Head at scale",
+describes what it drives).
 """
-
-import os
 
 import pytest
 
@@ -18,14 +17,10 @@ from ray_tpu.core.config import config
 
 @pytest.mark.slow
 def test_scalebench_small_shape():
-    os.environ["RAY_TPU_BENCH_LOG"] = ""  # never write the evidence trail
-    try:
-        from ray_tpu.scripts import scalebench
+    from ray_tpu.scripts import scalebench
 
-        res = scalebench.run(nodes=4, cpus=2, tasks=2000, actors=64,
-                             broadcast_mb=16, queued=2000)
-    finally:
-        os.environ.pop("RAY_TPU_BENCH_LOG", None)
+    res = scalebench.run(nodes=4, cpus=2, tasks=2000, actors=64,
+                         broadcast_mb=16, queued=2000)
     # Shape + liveness invariants (rates are box-dependent; the
     # INVARIANTS are not).
     assert res["burst_nodes_used"]["value"] >= 2  # burst actually spread

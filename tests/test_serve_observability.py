@@ -17,7 +17,6 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve, state
-from ray_tpu.scripts import bench_log
 from ray_tpu.serve import _observability as obs
 from ray_tpu.util import metrics, tracing
 
@@ -398,34 +397,6 @@ def test_grafana_dashboard_has_serve_panels():
 # -- evidence lint ----------------------------------------------------------
 
 
-def test_bench_log_validates_serve_latency(tmp_path):
-    path = str(tmp_path / "trail.jsonl")
-    # script= provenance rides along (as serve_bench emits it): the
-    # 'bench' shape must win over the throughput-point 'script' shape.
-    entry = bench_log.record_serve_latency(
-        client={"p50_ms": 3.2, "p99_ms": 9.9, "count": 10},
-        server={"count": 10, "p50_ms": 3.0},
-        agreement={"ok": True, "count_exact": True},
-        mode="http", connections=4, n_requests=10,
-        device="tpu", path=path, script="serve_bench")
-    assert entry["committed_to"] == path
-    assert bench_log.check_file(path) == []
-
-    # A client-only line (no server view / verdict) must fail the lint.
-    with open(path, "a") as f:
-        f.write(json.dumps({
-            "bench": "serve_latency", "ts": 1.0, "device": "tpu",
-            "client": {"p50_ms": 1.0, "p99_ms": 2.0}}) + "\n")
-    problems = "\n".join(bench_log.check_file(path))
-    assert "server.count" in problems and "agreement.ok" in problems
-
-    # CPU numbers stay out of the trail entirely.
-    assert bench_log.record_serve_latency(
-        client={"p50_ms": 1, "p99_ms": 2}, server={"count": 1},
-        agreement={"ok": True}, device="cpu",
-        path=path)["committed_to"] is None
-
-
 def test_handle_options_deadline_semantics():
     from ray_tpu.serve._private import DeploymentHandle
 
@@ -455,11 +426,10 @@ def test_traceparent_helpers_roundtrip():
 # -- cross-check + cluster federation (these re-init the runtime: last) ----
 
 
-def test_serve_bench_client_server_crosscheck(monkeypatch):
+def test_serve_bench_client_server_crosscheck():
     """Small in-process serve_bench run: the client-side latencies and
     the server-side histograms must agree (count exact, quantiles
     within bucket resolution)."""
-    monkeypatch.setenv("RAY_TPU_BENCH_LOG", "")
     from ray_tpu.scripts import serve_bench
 
     res = serve_bench.run(mode="handle", connections=3,
@@ -553,11 +523,10 @@ def test_federation_one_scrape_and_dead_replica_pruned():
 
 
 @pytest.mark.slow
-def test_serve_bench_smoke_slow(monkeypatch):
+def test_serve_bench_smoke_slow():
     """Standing harness gate (test_scalebench_smoke pattern): the full
     serve_bench shape — HTTP mode, batching, sheds, trace check — runs
     end to end and the client/server cross-check holds."""
-    monkeypatch.setenv("RAY_TPU_BENCH_LOG", "")
     from ray_tpu.scripts import serve_bench
 
     res = serve_bench.run(mode="http", connections=6,

@@ -24,9 +24,11 @@ from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle, _stored_params
 
 # Sizes no other test uses, so that `jax.live_arrays()` can be asked for a
 # float32 array of a weight's shape (the workers run many files a process).
+# Not a sequence of 40: Qwen3-Next's tiny preset has a float32 [40, 48]
+# shared expert, which `tests/test_qwen3_next.py` keeps alive in its worker.
 CONFIGS = {
     "gpt2": gpt2.GPT2Config(vocab_size=136, n_layer=3, n_head=3, d_model=48,
-                            seq_len=40),
+                            seq_len=44),
     "llama": dataclasses.replace(llama.LlamaConfig.tiny(), vocab_size=136,
                                  n_layer=3),
     "nemotron_h": nh.NemotronHConfig.tiny(),

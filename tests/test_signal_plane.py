@@ -117,6 +117,40 @@ def test_ring_retention_and_series_cap_evictions_counted():
     assert ring2.evictions["stale"] == 1
 
 
+def test_ring_memory_plateaus_past_retention_and_cap():
+    """Bounded, not merely slow-growing: a many-node scrape ingested far
+    past the retention window and over the series cap, 5 % of its
+    series churning a label value each snapshot (restarting workers),
+    holds no more traced memory at the end than 1.5x what it held once
+    warm, and what it let go is counted by reason."""
+    import tracemalloc
+
+    nodes, per_node, max_series = 16, 40, 400
+    ring = MetricsRing(history_s=10.0, max_series=max_series,
+                       scrape_interval_s=0.5)
+
+    def exposition(snap):
+        return "\n".join(
+            f'ray_tpu_worker_cpu_percent{{node_id="n{n:02d}",'
+            f'worker_id="w{s}g{snap if s % 20 == 0 else 0}"}} '
+            f'{float(snap + s)}'
+            for n in range(nodes) for s in range(per_node))
+
+    tracemalloc.start()
+    try:
+        for snap in range(120):  # 60 s of scrapes through a 10 s window
+            ring.ingest_text(1_000_000.0 + 0.5 * snap, exposition(snap))
+            if snap == 40:
+                warm = tracemalloc.get_traced_memory()[0]
+        end = tracemalloc.get_traced_memory()[0]
+    finally:
+        tracemalloc.stop()
+    assert ring.series_count() <= max_series
+    assert end < warm * 1.5, (warm, end)
+    assert ring.evictions["series_cap"] > 0
+    assert ring.age_out_node("n15") > 0
+
+
 def test_ring_dead_node_age_out():
     ring = MetricsRing(history_s=60.0, scrape_interval_s=1.0)
     ring.ingest(0.0, {"g": {_lbl(node_id="a", w="1"): 1.0,

@@ -245,6 +245,37 @@ def test_dynamic_split_local_backend(local_runtime):
         config.reset("target_block_size_bytes")
 
 
+def test_block_splits_counter_agrees_with_stage_stats(local_runtime):
+    """The metrics plane counts the splits the stage's stats count:
+    ``ray_tpu_block_splits_total`` grows by exactly the extra blocks the
+    run made."""
+    from ray_tpu import data as rtd
+    from ray_tpu.serve import _observability as obs
+    from ray_tpu.train import _observability as tob
+
+    config.override("target_block_size_bytes", 64 << 10)
+    try:
+        before = obs.parse_prometheus(tob.scrape_text())
+        ds = rtd.from_numpy(np.arange(131072.0), parallelism=4) \
+            .map_batches(lambda b: {"data": b["data"] + 1})
+        assert ds.count() == 131072
+        stage = next(s for s in ds.stats().lineage()
+                     if "map_batches" in s.name)
+        splits = stage.extra["splits"]
+        assert splits == ds.num_blocks - 4 > 0
+
+        def counted():
+            delta = obs.diff_parsed(
+                before, obs.parse_prometheus(tob.scrape_text()))
+            return sum(obs.sum_counter(
+                delta, "ray_tpu_block_splits_total", "stage").values())
+
+        wait_for(lambda: counted() >= splits, msg="splits counted")
+        assert counted() == splits
+    finally:
+        config.reset("target_block_size_bytes")
+
+
 # -- split + spill + restore on the cluster backend ------------------------
 
 

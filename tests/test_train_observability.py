@@ -19,7 +19,6 @@ import pytest
 import ray_tpu
 from ray_tpu import data, state, train
 from ray_tpu.data.dataset import DatasetStats
-from ray_tpu.scripts import bench_log
 from ray_tpu.serve import _observability as obs
 from ray_tpu.train import _observability as tob
 from ray_tpu.train import session
@@ -381,42 +380,6 @@ def test_timeline_contains_data_and_train_spans():
 # -- evidence lint ----------------------------------------------------------
 
 
-def test_bench_log_validates_input_pipeline_and_goodput(tmp_path):
-    path = str(tmp_path / "ev.jsonl")
-    entry = bench_log.record_input_pipeline(
-        client={"stall_fraction": 0.2, "wait_s": 0.1},
-        server={"stall_fraction": 0.21,
-                "counts": {"wait": 16, "user": 16}},
-        agreement={"ok": True}, n_batches=16,
-        device="tpu", path=path)
-    assert entry["committed_to"] == path
-    assert bench_log.check_line(json.loads(
-        open(path).read().splitlines()[0])) == []
-
-    # Client-only stall (no server view) must fail the lint.
-    bad = dict(entry)
-    bad.pop("committed_to")
-    bad["server"] = {"counts": {}}
-    assert any("stall_fraction" in e for e in bench_log.check_line(bad))
-    bad2 = dict(entry)
-    bad2.pop("committed_to")
-    bad2["agreement"] = {}
-    assert any("agreement" in e for e in bench_log.check_line(bad2))
-
-    gentry = bench_log.record_goodput(
-        trial="train", goodput_pct=92.5, wall_s=10.0, downtime_s=0.75,
-        by_cause={"drain:preempt": 0.75}, device="tpu", path=path)
-    assert gentry["committed_to"] == path
-    gline = json.loads(open(path).read().splitlines()[1])
-    assert bench_log.check_line(gline) == []
-    gbad = dict(gline)
-    gbad.pop("by_cause")
-    assert any("by_cause" in e for e in bench_log.check_line(gbad))
-    # CPU lines never enter the committed trail.
-    assert bench_log.record_if_on_chip(
-        {"bench": "goodput", "device": "cpu"}, path) is None
-
-
 # -- cluster backend: federation + dead-rank retraction ---------------------
 
 
@@ -484,11 +447,10 @@ def test_cluster_federation_and_rank_gauge_retraction():
 
 
 @pytest.mark.slow
-def test_input_bench_smoke_slow(monkeypatch):
+def test_input_bench_smoke_slow():
     """Standing harness gate: the full input_bench shape — pipeline
     stall cross-check, exact train phase counts, goodput-under-drain
     with cause attribution — runs end to end and agrees."""
-    monkeypatch.setenv("RAY_TPU_BENCH_LOG", "")
     from ray_tpu.scripts import input_bench
 
     res = input_bench.run(blocks=4, batch_size=32, steps=3, workers=2,
