@@ -6,15 +6,20 @@ hybrids, Nemotron-H (Mamba-2, attention and latent-MoE layers) and Granite
 4.0-H (a Mamba-2 mixer or attention, then gated experts, in every layer).
 
 Not exported here, so that a process which serves another family never
-imports them; ``LLMEngine`` resolves them by name, as it does all seven
-served families (``serve/llm_engine._model_bundle``): DeepSeek-V2
-(``models/deepseek_v2.py``: latent attention over a latent cache, group-
+imports them; ``LLMEngine`` resolves them by name, as it does all eight
+served families (gpt2, llama, nemotron_h, granite_hybrid, deepseek_v2,
+falcon_h1, qwen3_next, smallthinker; ``serve/llm_engine._model_bundle``):
+DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a latent cache, group-
 limited experts), Falcon-H1 (``models/falcon_h1.py``: rotary grouped-query
 attention AND a Mamba-2 mixer side by side in every layer, both caches a
-layer, fourteen muP multipliers) and Qwen3-Next (``models/qwen3_next.py``:
+layer, fourteen muP multipliers), Qwen3-Next (``models/qwen3_next.py``:
 three Gated DeltaNet layers to one gated-attention layer, a float32
 delta-rule state beside K/V rings, top-10-of-512 experts and a gated shared
-expert in every layer). ``models/resnet.py`` is imported by its path too.
+expert in every layer) and SmallThinker (``models/smallthinker.py``: one
+global attention layer without a position embedding to three rotary window
+layers, window rings beside full rings in one cache, a router read before
+attention, gated-ReLU experts all held). ``models/resnet.py`` is imported
+by its path too.
 
 Models are plain functions over parameter pytrees — no framework Module
 state — so the same code runs under any mesh and any rules table.

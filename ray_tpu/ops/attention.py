@@ -147,6 +147,13 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
 #   slot's blocks up to its last live row and no further, and a custom
 #   call handed a layer's slice would have it copied out first.
 #
+# * rank 4 twice over (SmallThinker, PR 51): window layers beside global
+#   ones hold TWO such stacks of different lengths in one cache, the window
+#   layers' rings as long as the window and no longer. A ring that has
+#   wrapped is a window for the decode step as it stands; a prompt chunk
+#   reads it by POSITION (``wrapped_chunk_attention``, ``ring_positions``)
+#   and writes at ``start mod L`` (``cache_write_ring_chunk``).
+#
 # A family picks its layout once, in its ``init_cache``; the ops below take
 # the path the rank of what they are handed names. The same bytes reshaped
 # inside an op are another tiled layout on the chip, a copy: only a cache
@@ -364,35 +371,61 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     a read, in the same arithmetic (float32 scores, one maximum and one
     sum over both parts, probabilities in the values' type, float32
     sums). -> [R, C, H, hd] in q's type."""
-    r, c, h, hd = q.shape
+    c = q.shape[1]
     w = k_all.shape[-1]
     old = window - c
-
-    def cut(cache):
-        return jnp.stack([jax.lax.dynamic_slice(
-            cache, (layer, slots[i], 0, 0), (1, 1, old, w))[0, 0]
-            for i in range(r)])
-
-    g = _kv_heads(h, hd, w)
-    if g == h:
-        rows = _lane_groups(
-            merged_rows(q.reshape(r, c, h * hd), w).astype(k_all.dtype), hd)
-        # [R, C, T, g, cols]: g heads a group of columns
-        rows = _heads_apart(rows, -(-rows.shape[-1] // hd), hd)
-    else:
-        # grouped queries: a group of columns holds ``per`` K/V heads and
-        # their ``per * h // g`` query heads are the product's rows (at
-        # hd = 128 a lane group IS one K/V head)
-        tiles = w // _lane_cols(w, hd)
-        per = g // tiles
-        rows = _heads_in_group_columns(
-            q.astype(k_all.dtype).reshape(r, c, tiles, h // tiles, hd), per)
+    rows = _chunk_query_rows(q, w, k_all.dtype)
     # (keys, values, who sees them): the slot's old rows, then the chunk's
     parts = [(k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
     if old:
-        parts.insert(0, (cut(k_all), cut(v_all),
+        parts.insert(0, (_slot_rows(k_all, layer, slots, old),
+                         _slot_rows(v_all, layer, slots, old),
                          jnp.arange(old)[None, None, :]
                          < start[:, None, None]))               # [R, 1, L]
+    return _chunk_softmax(q, rows, parts, w)
+
+
+def _slot_rows(cache: jax.Array, layer, slots: jax.Array,
+               n_rows: int) -> jax.Array:
+    """The first ``n_rows`` rows of each of ``slots``' rings in ``layer`` of
+    a stacked cache of merged rows [N, S, L, W], cut out as they lie:
+    [R, n_rows, W]."""
+    return jnp.stack([jax.lax.dynamic_slice(
+        cache, (layer, slots[i], 0, 0),
+        (1, 1, n_rows, cache.shape[-1]))[0, 0]
+        for i in range(slots.shape[0])])
+
+
+def _chunk_query_rows(q: jax.Array, w: int, dtype) -> jax.Array:
+    """A chunk's queries q [R, C, H, hd] as the products over merged rows
+    of ``w`` columns take them, [R, C, T, g, cols]: each head's numbers in
+    its own columns of a lane group (grouped queries: in its K/V head's),
+    zeros in the others."""
+    r, c, h, hd = q.shape
+    g = _kv_heads(h, hd, w)
+    if g == h:
+        rows = _lane_groups(
+            merged_rows(q.reshape(r, c, h * hd), w).astype(dtype), hd)
+        # [R, C, T, g, cols]: g heads a group of columns
+        return _heads_apart(rows, -(-rows.shape[-1] // hd), hd)
+    # grouped queries: a group of columns holds ``per`` K/V heads and
+    # their ``per * h // g`` query heads are the product's rows (at
+    # hd = 128 a lane group IS one K/V head)
+    tiles = w // _lane_cols(w, hd)
+    per = g // tiles
+    return _heads_in_group_columns(
+        q.astype(dtype).reshape(r, c, tiles, h // tiles, hd), per)
+
+
+def _chunk_softmax(q: jax.Array, rows: jax.Array, parts: list,
+                   w: int) -> jax.Array:
+    """ONE softmax of a chunk's queries (``_chunk_query_rows``) over several
+    parts of keys, each (keys [R, K, W], values [R, K, W], seen, which
+    broadcasts against [R, C, K]): float32 scores, one maximum and one sum
+    over all parts, probabilities in the values' type, float32 sums.
+    -> [R, C, H, hd] in q's type."""
+    r, c, h, hd = q.shape
+    g = _kv_heads(h, hd, w)
     scores = [jnp.where(
         seen[:, None, None],
         jnp.einsum("rqtgw,rktw->rtgqk", rows, _lane_groups(k, hd),
@@ -409,8 +442,78 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     if g == h:
         out = _heads_merged(out, hd).reshape(r, c, w)[..., :h * hd]
     else:
-        out = _heads_out_of_group_columns(out, per)
+        out = _heads_out_of_group_columns(
+            out, g // (w // _lane_cols(w, hd)))
     return out.reshape(r, c, h, hd).astype(q.dtype)
+
+
+def ring_positions(start: jax.Array, n_rows: int) -> jax.Array:
+    """What a ring of ``n_rows`` rows holds of a prompt whose positions
+    ``< start`` were written at ``position mod n_rows``: [..., n_rows]
+    int32, row j's position, the largest ``p < start`` with ``p mod n_rows
+    == j``; negative where the prompt has written no such row yet (the row
+    is a former tenant's, or nothing)."""
+    last = start[..., None] - 1
+    return last - jnp.mod(last - jnp.arange(n_rows), n_rows)
+
+
+def wrapped_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
+                            k_own: jax.Array, v_own: jax.Array,
+                            layer: jax.Array, slots: jax.Array,
+                            start: jax.Array) -> jax.Array:
+    """``merged_chunk_attention`` over a ring that may have WRAPPED: a
+    window layer's chunk, masked by position.
+
+    q [R, C, H, hd], the queries of positions ``start[r] + i``; k_all /
+    v_all the stacked cache [N, S, L, W] of merged rows as it was before
+    this chunk (read only), a slot's ring of ``L`` rows holding position p
+    at row ``p mod L``: the WINDOW is the ring's length; k_own / v_own [R,
+    C, W] the chunk's own merged rows; slots, start [R] int32. Query i sees
+    the keys at positions ``start + i - L < p <= start + i``: of the ring
+    as it lies the rows whose position (``ring_positions``) is inside that
+    and not negative, whatever ``start`` (before the first wrap, at it and
+    windows on: one program), and of the chunk's own rows those ``<= i``
+    (``C <= L``: none of them is out of the window). The caller then writes
+    the chunk's rows at ``start mod L`` (``cache_write_ring_chunk``; ``C``
+    divides ``L``, so a chunk never straddles the ring's end). The same
+    arithmetic as ``merged_chunk_attention``. -> [R, C, H, hd] in q's
+    type."""
+    c = q.shape[1]
+    n_rows, w = k_all.shape[2:]
+    if n_rows % c:
+        raise ValueError(f"a chunk of {c} rows does not divide a ring of "
+                         f"{n_rows}: it would straddle the ring's end")
+    rows = _chunk_query_rows(q, w, k_all.dtype)
+    held = ring_positions(start, n_rows)[:, None, :]              # [R, 1, L]
+    sees = (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [R, C, 1]
+    parts = [(_slot_rows(k_all, layer, slots, n_rows),
+              _slot_rows(v_all, layer, slots, n_rows),
+              (held >= 0) & (held > sees - n_rows)),
+             (k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
+    return _chunk_softmax(q, rows, parts, w)
+
+
+# decode-path  # jax-hot-path: the KV cache stays in the activation dtype
+def cache_write_ring_chunk(cache: jax.Array, rows: jax.Array,
+                           slots: jax.Array, start: jax.Array,
+                           lengths: jax.Array) -> jax.Array:
+    """``cache_write_chunk`` into rings that wrap: row block ``rows[:, i]``
+    ([N, C, W]) lands at ``cache[:, slots[i], start[i] mod L :][:C]`` (``C``
+    divides ``L``). Only the chunk's first ``lengths[i]`` rows are real:
+    the others keep what the ring held, because in a ring that has wrapped
+    that is the prompt's own rows a window back, which the tokens after the
+    prompt's end still see (a straight cache has nothing there yet)."""
+    rows = rows.astype(cache.dtype)
+    n, _, c, w = rows.shape
+    n_rows = cache.shape[2]
+    real = jnp.arange(c)[None, :] < lengths[:, None]  # [R, C]
+    for i in range(rows.shape[1]):
+        at = (0, slots[i], jnp.mod(start[i], n_rows), 0)
+        was = jax.lax.dynamic_slice(cache, at, (n, 1, c, w))
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.where(real[i][None, None, :, None],
+                             rows[:, i, None], was), at)
+    return cache
 
 
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,

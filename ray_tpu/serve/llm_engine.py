@@ -206,9 +206,16 @@ def _model_bundle(model: str, config, preset: str):
                          else m.Qwen3NextConfig())
         return (cfg, m.qwen3_next_init, m.qwen3_next_init_cache,
                 m.qwen3_next_prefill_chunk, m.qwen3_next_decode_step)
+    if model == "smallthinker":
+        from ray_tpu.models import smallthinker as m
+
+        cfg = config or (m.SmallThinkerConfig.tiny() if preset == "tiny"
+                         else m.SmallThinkerConfig())
+        return (cfg, m.smallthinker_init, m.smallthinker_init_cache,
+                m.smallthinker_prefill_chunk, m.smallthinker_decode_step)
     raise ValueError(
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h|"
-        f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next)")
+        f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next|smallthinker)")
 
 
 def _stored_params(init, key, cfg):

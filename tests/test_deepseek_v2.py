@@ -728,7 +728,9 @@ def test_the_tiny_preset_engine_and_the_bundles_error_text():
 # the stacked cache and the layer's index, and the step returns what it
 # read of the rings); its chunk program and DeepSeek-V2's two held through
 # it. Qwen3-Next's two were taken on PR 50's parent (PR 49), the programs
-# of PR 48.
+# of PR 48. PR 51 cut ``merged_chunk_attention`` into the helpers it now
+# shares with ``wrapped_chunk_attention`` and moved no line of any of these
+# fourteen; SmallThinker's two were taken on PR 51's own tree.
 LOWERED = {
     ("gpt2", "decode"):
         "2b81ed566a4807464776b4b17fb93f366083f68456236ee2eeb73d0f4b83d1e2",
@@ -758,6 +760,10 @@ LOWERED = {
         "471f52060158e3f86c7209cf7ab4c7cbbd63e0e50d67e77b9bd0c3214cd5f6c9",
     ("qwen3_next", "prefill"):
         "7652be0b12c44a4698adcd67e1e99dad4412f86d60108e1d4c4cf6475f8b871f",
+    ("smallthinker", "decode"):
+        "61c70262047138a883ddfd3fae330f4ce2e263f2aa35727442cf15cfca7b7112",
+    ("smallthinker", "prefill"):
+        "4b49b998b731ec92566cd01523ded5c351bb0ee26ee1801526436068bb183c4b",
 }
 
 

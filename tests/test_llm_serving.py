@@ -23,7 +23,7 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            llama, nemotron_h, qwen3_next)
+                            llama, nemotron_h, qwen3_next, smallthinker)
 from ray_tpu.serve import _observability as obs
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve._observability import RequestShedError
@@ -75,6 +75,11 @@ SERVED = {
                   falcon_h1.falcon_h1_forward),
     "qwen3_next": (qwen3_next.Qwen3NextConfig.tiny(**_FP32),
                    qwen3_next.qwen3_next_forward),
+    # (window rings of 8 rows beside the global rings of ``cache_len``: the
+    # engine's chunk, the longest prompt's 8 tokens, is one whole ring, and
+    # every generation here outlives the window)
+    "smallthinker": (smallthinker.SmallThinkerConfig.tiny(**_FP32),
+                     smallthinker.smallthinker_forward),
 }
 every_family = pytest.mark.parametrize("model", list(SERVED))
 PROMPT = [5, 9, 2, 17, 3]

@@ -16,6 +16,12 @@ the same layer cut out of the stack, at the three rows the serving cells
 store (GPT-2 XL's, Falcon-H1's, Qwen3-Next's 16 query heads over 2 K/V heads
 of 256), at contexts on every side of a block's edge, side by side in one
 call.
+
+Since PR 51 a window layer's prompt chunk reads a ring that may have WRAPPED
+by position (``wrapped_chunk_attention``): held to the plain masked softmax
+over the prompt's own keys for a start of 0, a chunk short of the window, at
+the window and several windows on; ``ring_positions`` and the ring's own
+write (``cache_write_ring_chunk``) beside it.
 """
 
 import functools
@@ -299,3 +305,96 @@ def test_a_ring_the_kernel_does_not_take_is_cut_out_of_the_stack():
     assert str(jax.make_jaxpr(lambda k, layer: ops.cached_decode_attention(
         jnp.zeros((SLOTS, 2, 64)), k, k, new, new, cursor, cursor + 1,
         jnp.float32, layer=layer))(ring, 1)).count("pallas_call") == 1
+
+
+# -- a chunk over a ring that has wrapped ---------------------------------------
+
+WINDOW = 3 * CHUNK          # a window layer's ring IS its window
+WRAPPED_ROWS = {**{r: ROWS[r] for r in ROWS if ROWS[r][0] != ROWS[r][1]},
+                "smallthinker-28:4:128": (28, 4, 128),
+                "gpt2-toy-4:4:16": ROWS["gpt2-toy-4:4:16"]}
+# where the chunk starts, a row of the call each (any whole number of chunks)
+STARTS = {"at-0-a-recycled-slot": 0, "a-chunk-short-of-the-window":
+          WINDOW - CHUNK, "at-the-window": WINDOW, "a-chunk-past-it":
+          WINDOW + CHUNK, "several-windows-on": 5 * WINDOW + 2 * CHUNK}
+
+
+@functools.lru_cache(maxsize=None)
+def _wrapped_chunk(row):
+    """Every start of STARTS a row of ONE call, each over a slot of its own
+    whose ring was filled as a prompt fills it (position p at row ``p mod
+    WINDOW``, what it held before, another tenant's noise, left where the
+    prompt has not written): (the op's, the plain masked softmax over the
+    prompt's own keys at their positions)."""
+    h, g, hd = WRAPPED_ROWS[row]
+    w = ops.merged_row_width(g, hd)
+    starts = np.asarray(list(STARTS.values()), np.int32)
+    n, longest = len(starts), int(starts.max()) + CHUNK
+    slots = jnp.asarray(np.arange(n)[::-1].copy(), jnp.int32)
+    q = _normal(21, n, CHUNK, h, hd)
+    keys = _normal(22, n, longest, g, hd)   # a prompt a row, every position
+    values = _normal(23, n, longest, g, hd)
+    k_all = np.array(50.0 + _normal(24, LAYERS, n, WINDOW, g, hd))
+    v_all = np.array(-50.0 + _normal(25, LAYERS, n, WINDOW, g, hd))
+    for i, start in enumerate(starts):
+        for p in range(start):              # later positions overwrite
+            k_all[1, int(slots[i]), p % WINDOW] = keys[i, p]
+            v_all[1, int(slots[i]), p % WINDOW] = values[i, p]
+    at = starts[:, None] + np.arange(CHUNK)[None, :]
+    k_own = jnp.stack([keys[i, at[i]] for i in range(n)])
+    v_own = jnp.stack([values[i, at[i]] for i in range(n)])
+    got = ops.wrapped_chunk_attention(
+        q, _merged(jnp.asarray(k_all), w), _merged(jnp.asarray(v_all), w),
+        _merged(k_own, w), _merged(v_own, w), 1, slots,
+        jnp.asarray(starts))
+    pos = np.arange(longest)[None, None, :]
+    seen = (pos <= at[:, :, None]) & (pos > at[:, :, None] - WINDOW)
+    return np.asarray(got), _plain(q, keys, values, seen)
+
+
+@pytest.mark.parametrize("start", list(STARTS))
+@pytest.mark.parametrize("row", list(WRAPPED_ROWS))
+def test_a_chunk_over_a_wrapped_ring_sees_the_window_by_position(row, start):
+    """``wrapped_chunk_attention`` against the plain softmax over the keys
+    ``start + i - WINDOW < p <= start + i`` of the prompt itself: the ring
+    read as it lies before the first wrap, at it and windows on, in ONE
+    call; a recycled slot's rows, which no position of this prompt names,
+    are not seen."""
+    got, plain = _wrapped_chunk(row)
+    i = list(STARTS).index(start)
+    assert got.shape == plain.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got[i], plain[i], rtol=2e-5, atol=2e-6)
+
+
+def test_ring_positions_names_each_rows_position_or_none():
+    got = np.asarray(ops.ring_positions(jnp.asarray([0, 3, 8, 13]), 8))
+    assert (got[0] < 0).all()                      # nothing written yet
+    assert got[1].tolist()[:3] == [0, 1, 2] and (got[1][3:] < 0).all()
+    assert got[2].tolist() == list(range(8))       # exactly full
+    assert got[3].tolist() == [8, 9, 10, 11, 12, 5, 6, 7]  # wrapped
+
+
+@pytest.mark.parametrize("start, real", [(0, 4), (8, 3), (20, 1), (28, 0)])
+def test_a_ring_chunk_write_lands_mod_the_ring_and_keeps_its_padded_rows(
+        start, real):
+    """``cache_write_ring_chunk``: the chunk's real rows at ``start mod L``
+    in its slot, the padded rows and everything else as they were."""
+    cache = _normal(31, LAYERS, SLOTS, WINDOW, 32)
+    rows = _normal(32, LAYERS, 1, CHUNK, 32)
+    got = np.asarray(ops.cache_write_ring_chunk(
+        cache, rows, jnp.asarray([1]), jnp.asarray([start]),
+        jnp.asarray([real])))
+    want = np.array(cache)
+    at = start % WINDOW
+    want[:, 1, at:at + real] = np.asarray(rows)[:, 0, :real]
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_chunk_that_does_not_divide_the_ring_is_refused():
+    w = 32
+    with pytest.raises(ValueError, match="straddle the ring's end"):
+        ops.wrapped_chunk_attention(
+            jnp.zeros((1, 5, 4, 16)), jnp.zeros((1, 1, WINDOW, w)),
+            jnp.zeros((1, 1, WINDOW, w)), jnp.zeros((1, 5, w)),
+            jnp.zeros((1, 5, w)), 0, jnp.zeros(1, jnp.int32),
+            jnp.zeros(1, jnp.int32))

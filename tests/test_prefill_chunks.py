@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            llama, nemotron_h, qwen3_next)
+                            llama, nemotron_h, qwen3_next, smallthinker)
 from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
 from ray_tpu.serve.llm_engine import LLMEngine
 
@@ -64,6 +64,14 @@ FAMILIES = {
         qwen3_next.qwen3_next_init, qwen3_next.qwen3_next_init_cache,
         qwen3_next.qwen3_next_prefill_chunk, qwen3_next.qwen3_next_prefill,
         qwen3_next.qwen3_next_forward),
+    # (window rings of 16 rows: this file's whole-window pass is ONE chunk
+    # of MAX_PROMPT rows, which must divide a ring; the wraps are
+    # tests/test_smallthinker.py's)
+    "smallthinker": (smallthinker.SmallThinkerConfig.tiny(
+        dtype=F32, param_dtype=F32, window=16),
+        smallthinker.smallthinker_init, smallthinker.smallthinker_init_cache,
+        smallthinker.smallthinker_prefill_chunk,
+        smallthinker.smallthinker_prefill, smallthinker.smallthinker_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 # GPT-2's merged, lane-padded rows at the head counts it is served with: XL's
@@ -130,8 +138,11 @@ def _whole(family, params, cache, prompt, slot):
 
 def _holds_rows(path, a):
     """K/V or latent rows [layer, slot, row, head, hd], GPT-2's merged
-    [layer, slot, row, W]; the rest is Mamba state."""
-    return a.ndim == 5 or jax.tree_util.keystr(path) in ("['k']", "['v']")
+    [layer, slot, row, W] (SmallThinker's two stacks of them, ``k_full``
+    and ``k_win``); the rest is Mamba state."""
+    return a.ndim == 5 or jax.tree_util.keystr(path) in (
+        "['k']", "['v']", "['k_full']", "['v_full']", "['k_win']",
+        "['v_win']")
 
 
 def _assert_same_state(family, got, want, slot, n):

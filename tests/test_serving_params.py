@@ -20,6 +20,7 @@ import pytest
 
 from ray_tpu.models import gpt2, llama
 from ray_tpu.models import nemotron_h as nh
+from ray_tpu.models import smallthinker
 from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle, _stored_params
 
 # Sizes no other test uses, so that `jax.live_arrays()` can be asked for a
@@ -32,6 +33,7 @@ CONFIGS = {
     "llama": dataclasses.replace(llama.LlamaConfig.tiny(), vocab_size=136,
                                  n_layer=3),
     "nemotron_h": nh.NemotronHConfig.tiny(),
+    "smallthinker": smallthinker.SmallThinkerConfig.tiny(),
 }
 NORMS = {"gpt2": {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
                   "lnf_scale", "lnf_bias"},
@@ -182,10 +184,12 @@ def test_no_program_casts_a_parameter_the_engine_stored(name):
         assert set(_casts_of_inputs(jaxpr, handed)) == as_read
 
 
-def test_a_family_that_stores_what_it_multiplies_with_gets_its_arrays_back():
-    """``nemotron_h`` is handed back leaf for leaf: the very arrays
-    ``nemotron_h_init`` returned, none cast, none copied."""
-    cfg, init, *_ = _family("nemotron_h")
+@pytest.mark.parametrize("name", ["nemotron_h", "smallthinker"])
+def test_a_family_that_stores_what_it_multiplies_with_gets_its_arrays_back(
+        name):
+    """The family is handed back leaf for leaf: the very arrays its
+    ``init`` returned, none cast, none copied."""
+    cfg, init, *_ = _family(name)
     made = []
 
     def recording(key, cfg):

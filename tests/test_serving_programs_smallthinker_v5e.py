@@ -1,0 +1,265 @@
+"""What the TPU's compiler makes of SmallThinker's two serving programs.
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/smallthinker-21b-a3b-instruct.json`` and the shapes of
+the cell ``serve_smallthinker_mixedwin_sat`` (48 slots and the scratch one,
+TWO stacks of rings of merged rows of 512 columns in one donated pytree: the
+two global layers' of 16384 rows and the six window layers' of 4096, 64
+experts a layer all held, prompts of up to 14336 tokens in the engine's
+[1, 256] chunks over a key window of 14336): nothing runs, so nothing here
+is a time. It holds that both programs fit the chip beside their arguments
+(12.33 GB of weights and cache), that the donated cache is updated in its
+own buffers, that no program makes a float32 array as long as a ring or a
+copy of a ring or a stack, that the step reads all eight layers' rings
+through the kernel of ``ops/ring_decode.py``, each STACK handed whole with
+its layer's index, that the chunk program writes each of the four stacks
+once (the window stacks' write keeps the rows past the chunk's real tokens:
+a row-sized read beside it, no more) and makes no other array that large,
+and that it keeps both stacks in the step's layout, row-minor: XLA's
+choices decide that, not the jaxpr.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import smallthinker as st
+from ray_tpu.models.prefill import chunk_len, key_window
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 15.75 * 2 ** 30
+FULL = "bf16[2,49,16384,512]"
+WIN = "bf16[6,49,4096,512]"
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return load_json(os.path.join(
+        REPO, "benchmark", "deployments",
+        "smallthinker_1chip_b48.json"))["engine"]
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "smallthinker.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "smallthinker-21b-a3b-instruct.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg, engine):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = engine["max_batch"] + 1
+    chunk = chunk_len(engine["max_prompt_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (49, 256, 14336, 16384)
+    params = sds(jax.eval_shape(
+        lambda: st.smallthinker_init(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: st.smallthinker_init_cache(
+        cfg, slots, engine["cache_len"])))
+    programs = {
+        "decode": (lambda p, c, t, n: st.smallthinker_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(slots), i32(slots))),
+        "prefill": (lambda p, c, t, s, at, n: st.smallthinker_prefill_chunk(
+            p, c, t, s, at, n, cfg, window=window),
+            (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        # the kernels pick interpret mode from the process's backend, the
+        # CPU here: while the programs are traced it says the chip's, so
+        # the step holds its kernel (PR 48), not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
+                                                        which):
+    """3.286 B bfloat16 parameters (6.57 GB) and 5.75 GB of cache are the
+    arguments; the cache is aliased to the output, so it is held once."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = 2 * nbytes((2, 49, 16384, 512), 2) \
+        + 2 * nbytes((6, 49, 4096, 512), 2) + 4
+    assert cache_bytes == 49 * 117_440_512 + 4 == 5_754_585_092
+    assert mem.alias_size_in_bytes >= cache_bytes
+    gb = {k: getattr(mem, k + "_size_in_bytes") / 1e9
+          for k in ("argument", "temp", "alias", "output")}
+    print(which, gb)
+    assert 12.32e9 < mem.argument_size_in_bytes < 12.35e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
+    # the step holds the experts' [64, 49, 1536] product and no copy of a
+    # ring. A chunk holds its float32 scores over the 14336-row window of a
+    # global layer (28 heads x 256 x 14080 x 4 B = 404 MB, and their
+    # exponentials), a window layer's over 4352 keys (125 MB), the rows cut
+    # out of the stacks and the experts' [64, 256, 1536] float32 product.
+    assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 1.6e9}[which]
+
+
+SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                   r"([\w\-]+)\(")
+RINGS = {nbytes((49, 16384, 512), 1),    # elements of a global layer's ring
+         nbytes((49, 4096, 512), 1)}     # and of a window layer's
+STACKS = {nbytes((2, 49, 16384, 512), 1), nbytes((6, 49, 4096, 512), 1)}
+
+
+def _unfused(hlo_text):
+    """The text of every computation but the ones a ``fusion`` calls:
+    inside a fusion a slice or a convert is a step of one loop, not a
+    buffer."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    return "\n".join(block for block in hlo_text.split("\n\n")
+                     if block.lstrip().split(" ", 1)[0] not in fused)
+
+
+def _arrays_made(hlo_text):
+    """(type, elements, opcode) of every instruction of ``hlo_text`` that
+    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
+    slices."""
+    for line in hlo_text.splitlines():
+        m = SHAPE.match(line)
+        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
+                                "dynamic-slice"):
+            yield m.group(1), nbytes(
+                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_float32_array_as_long_as_a_ring_and_no_ring_is_copied(compiled,
+                                                                  which):
+    """A window layer's ring is 49 x 4096 x 512 bfloat16 (206 MB), a global
+    layer's four times that. Neither program widens a ring to float32, and
+    neither makes a copy of a ring or of a stack in any type."""
+    text = compiled[which].as_text()
+    made = list(_arrays_made(_unfused(text)))
+    assert len(made) > 50, "read no program"
+    assert [m for m in made if m[0] == "f32" and m[1] >= min(RINGS)] == []
+    assert [m for m in _arrays_made(text)
+            if m[1] in RINGS | STACKS and m[2] == "copy"] == []
+
+
+RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def _made_as_large_as(text, sizes):
+    """(opcode, first operand) of every instruction that gives out an array
+    of one of ``sizes`` elements (a tuple's members counted each) and does
+    not merely hand one on."""
+    made = []
+    for line in text.splitlines():
+        m = RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                nbytes([int(d) for d in dims.split(",")], 1) in sizes
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    return made
+
+
+def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
+        compiled):
+    """K and V of the global stack are 1.64 GB each, of the window stack
+    1.23 GB each. The chunk program reads a slot's old rows before it writes
+    its own, so all it does to a stack is ONE row-sized
+    ``dynamic-update-slice`` into the donated buffer, after the layer loop
+    (the window stacks' update is the chunk's rows where they are real and
+    the ring's own where they are not: a read of 256 rows a layer, no more):
+    no fusion, copy or anything else, inside a fusion or outside, gives out
+    an array as large as a stack or a ring."""
+    made = _made_as_large_as(compiled["prefill"].as_text(), RINGS | STACKS)
+    assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 4, made
+    assert len({stack for _, stack in made}) == 4, made
+
+
+def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
+        compiled):
+    """The decode attention of every layer, global or window, is ONE custom
+    call of the kernel of ``ops/ring_decode.py`` (rings of 16384 and of 4096
+    rows are whole blocks of 256, rows of 512 columns whole lane tiles),
+    handed its kind's K and V STACKS as they lie and the layer's index in
+    that stack: two calls on the global stacks, six on the window stacks.
+    Nothing gives out an array of a ring's size or of a stack's but the
+    row-sized writes into the donated stacks after the layer loop."""
+    text = compiled["decode"].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 8
+    handed = []
+    for line in calls:
+        assert "ring_decode_attention" in line
+        operands = re.findall(r"(\w+\[[\d,]*\])", re.search(
+            r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
+        handed.append((operands.count(FULL), operands.count(WIN)))
+    assert sorted(handed) == [(0, 2)] * 6 + [(2, 0)] * 2, handed
+    made = _made_as_large_as(text, RINGS | STACKS)
+    assert {op for op, _ in made} == {"dynamic-update-slice"}, made
+    assert len(made) % 4 == 0 and len(made) >= 4
+
+
+def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
+    """Both stacks: each shape has one layout as a whole array in the chunk
+    program, and it is the decode program's, so neither is re-laid out
+    between the two; the rings are row-minor (a merged row of 512 columns
+    is four whole lane tiles)."""
+    def layouts(shape, which):
+        # (a trailing S(n) names a memory space, not a layout)
+        # (nor is what the kernel's custom call asks of its operands,
+        # ``operand_layout_constraints``: an order of dimensions, no tiling)
+        text = re.sub(r"operand_layout_constraints=\{[^=]*\}, ", "",
+                      compiled[which].as_text())
+        return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+            re.escape(shape) + r"(\{[^}]*\})", text)}
+
+    for shape in (FULL, WIN):
+        assert len(layouts(shape, "prefill")) == 1, shape
+        assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
+        assert all(found.startswith("{3,2,1,0")
+                   for found in layouts(shape, "decode")), shape
