@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ray_tpu.ops import moe_experts
+
 
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
                     dtype=jnp.float32) -> dict:
@@ -188,18 +190,6 @@ def moe_ffn_ep_local(px, p_router, p_win, p_wout, *, n_experts: int,
 # own experts add for the tokens routed to them. What the absent experts
 # would have added is left out (the chips that hold them add it).
 
-# Up to this many rows "every held expert over every row" is the cheaper
-# of the two forms on a v5e: it reads each expert's weights once, at the
-# memory's pace (one 256-token prefill chunk of the Nemotron cell: 7.05 GB
-# of experts in 9.4 ms), where the TPU's grouped product pays a cost a
-# group that does not shrink with the rows (2.8 ms a product of 128 groups
-# at 5,632 rows against 5 ms at 90,112: 28 ms a chunk; PERF.md section 6,
-# PR 31). An engine's prefill chunk is this many tokens of one request
-# (``models/prefill.py``), so a chunk and a decode step take the batched
-# form and only a wider lane the grouped one. Past it the batched form's
-# wasted products (every expert over every row) cost more than the sort.
-DENSE_ROWS = 256
-
 
 def route(x: jax.Array, w_gate: jax.Array, bias: jax.Array, top_k: int,
           scale: float) -> tuple[jax.Array, jax.Array]:
@@ -270,64 +260,104 @@ def dropless_experts(h: jax.Array, ids: jax.Array, weights: jax.Array,
     ``sum_k weights[t, k] * expert_{ids[t, k]}(h[t])`` over the pairs whose
     expert is held, and counts [E] int32: the pairs each held expert took).
 
-    Two static shapes of one result, chosen by the row count alone. Past
-    ``DENSE_ROWS`` rows the pairs are sorted by expert, absent ones last,
-    and the two products are ``jax.lax.ragged_dot`` over the held experts'
-    groups, so rows of absent experts are not computed. Up to it every held
-    expert is applied to every row in one batched product and the routing
-    weights mask it: with so few rows a group's row tile would cover them
-    all anyway. bfloat16 products accumulate in float32."""
+    One result in two forms, chosen by the experts' widths alone
+    (``moe_experts.takes_kernel``). Experts of whole lane tiles, which is
+    every published width, go through the Pallas kernel of
+    ``ops/moe_experts.py`` at any row count: the pairs are sorted by expert,
+    an expert that took no row is neither read nor computed and one that
+    took rows is multiplied with those rows only (on a v5e it is the faster
+    form at all five serving shapes from 33 rows to 1,024, against both the
+    batched product it replaced and the TPU's grouped product: PERF.md
+    section 6, PR 52). Toy widths (the tiny presets the CPU tests run)
+    apply every held expert to every row in one batched XLA product and
+    mask it by the routing weights. Products in the rows' type accumulate
+    in float32, the activation and the sum over a token's experts are
+    float32."""
     n_held = w1.shape[0]
     local = ids - first
     held = (local >= 0) & (local < n_held)
     if live is not None:
         held = held & live[:, None]
     weights = jnp.where(held, weights, 0.0)
-    share = _grouped_share if ids.shape[0] > DENSE_ROWS else _batched_share
-    return share(h, local, held, weights, w1.astype(h.dtype),
-                 w2.astype(h.dtype), activation)
+    w1, w2 = w1.astype(h.dtype), w2.astype(h.dtype)
+    share = _kernel_share if moe_experts.takes_kernel(
+        h.shape[1], w1.shape[2], w2.shape[1]) else _batched_share
+    return share(h, local, held, weights, w1, w2, activation)
 
 
 def held_counters(counts: list) -> dict:
     """Over a step's expert layers (``dropless_experts``' counts, one [E]
-    a layer): the held experts that took at least one row, and the
-    token-expert pairs that landed here."""
+    a layer): the held experts that took at least one row, the
+    token-expert pairs that landed here, and the row tiles the kernel
+    computed for them (``ceil(count / ROW_TILE)`` an expert:
+    ``expert_rows`` over ``expert_row_tiles x ROW_TILE`` is the tiles'
+    fill, ``experts_hit`` over the held the share of experts fetched)."""
     if not counts:
-        return {"experts_hit": jnp.int32(0), "expert_rows": jnp.int32(0)}
+        return {"experts_hit": jnp.int32(0), "expert_rows": jnp.int32(0),
+                "expert_row_tiles": jnp.int32(0)}
     stacked = jnp.stack(counts)
     return {"experts_hit": jnp.sum(stacked > 0, dtype=jnp.int32),
-            "expert_rows": jnp.sum(stacked, dtype=jnp.int32)}
+            "expert_rows": jnp.sum(stacked, dtype=jnp.int32),
+            "expert_row_tiles": jnp.sum(moe_experts.row_tiles(stacked),
+                                        dtype=jnp.int32)}
 
 
-def _grouped_share(h, local, held, weights, w1, w2, activation):
-    """The pairs sorted by expert, the absent and the padding last; two
-    grouped products over the held experts' groups; each pair's row back
-    at its token."""
-    t, k = local.shape
+def _tile_table(count, pairs):
+    """The kernel's row tiles for experts that took ``count`` [E] of a
+    lane's ``pairs`` sorted pairs -> each tile's (expert, first pair in the
+    sorted order, pairs), [``n_tiles``] int32 each. A tile past the last
+    live one has no pairs and names that one's expert: its weight blocks
+    stand still and nothing is fetched for it. Written as comparisons of
+    every tile with every expert (a few hundred by a hundred at most), which
+    the compiler makes one fusion of, where a search and gathers from
+    [E]-long tables would each be an operation a layer."""
+    tile, n_held = moe_experts.ROW_TILE, count.shape[0]
+    tiles = moe_experts.row_tiles(count)
+    e = jnp.arange(n_held, dtype=jnp.int32)
+    tile_end = jnp.sum(jnp.where(e[:, None] <= e[None, :], tiles[:, None],
+                                 0), axis=0)  # the running sum, inclusive
+    i = jnp.arange(moe_experts.n_tiles(pairs, n_held), dtype=jnp.int32)
+    live = i < tile_end[-1]
+    at = jnp.where(live, i, jnp.maximum(tile_end[-1] - 1, 0))
+    before = at[:, None] >= tile_end[None, :]  # [tile, expert]: e < its own
+    expert = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32),
+                         n_held - 1)
+    mine = e[None, :] == expert[:, None]
+    nth = at - jnp.sum(jnp.where(before, tiles, 0), axis=1)  # of its expert
+    start = jnp.sum(jnp.where(before, count, 0), axis=1) + nth * tile
+    left = jnp.sum(jnp.where(mine, count, 0), axis=1) - nth * tile
+    return expert, start, jnp.where(live, jnp.minimum(left, tile), 0)
+
+
+def _kernel_share(h, local, held, weights, w1, w2, activation):
+    """The pairs sorted by expert and cut into the kernel's row tiles; the
+    products, and each pair's way back to its token, are the kernel's. A
+    lane longer than the kernel holds goes through it a block of rows at a
+    time."""
+    n_rows, k = local.shape
     n_held = w1.shape[0]
-    with jax.named_scope("moe_dispatch"):
-        key = jnp.where(held, local, n_held).reshape(-1)
-        order = jnp.argsort(key)
-        counts = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
-                         dtype=jnp.int32)
-        rows = h[order // k]  # [T * K, D], sorted by expert
-    with jax.named_scope("experts"):
-        a = activation(jax.lax.ragged_dot(rows, w1, counts,
-                                          preferred_element_type=h.dtype))
-        y = jax.lax.ragged_dot(a, w2, counts, preferred_element_type=h.dtype)
+    out, counts = [], jnp.zeros((n_held,), jnp.int32)
+    for r in range(0, n_rows, moe_experts.LANE_ROWS):
+        lane = slice(r, r + moe_experts.LANE_ROWS)
+        with jax.named_scope("moe_dispatch"):
+            key = jnp.where(held[lane], local[lane], n_held).reshape(-1)
+            order = jnp.argsort(key).astype(jnp.int32)
+            count = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                            axis=0, dtype=jnp.int32)
+            tiles = _tile_table(count, key.shape[0])
+        with jax.named_scope("experts"):
+            out.append(moe_experts.grouped_experts(
+                h[lane], *tiles, order // k,
+                weights[lane].reshape(-1)[order], w1, w2, activation))
+        counts = counts + count
     with jax.named_scope("moe_combine"):
-        back = jnp.zeros((t * k,), jnp.int32).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32))
-        # rows past the held groups were not computed: whatever the product
-        # left there is not a number to weigh
-        y = jnp.where(held[..., None], y[back].reshape(t, k, -1), 0)
-        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
-    return out, counts
+        return jnp.concatenate(out), counts
 
 
 def _batched_share(h, local, held, weights, w1, w2, activation):
     """Every held expert over every row in one batched product a matrix,
-    masked by each row's weight on each expert."""
+    masked by each row's weight on each expert: the form of widths that
+    are no whole lane tiles."""
     with jax.named_scope("moe_dispatch"):
         onehot = (local[..., None] == jnp.arange(w1.shape[0])) \
             & held[..., None]  # [T, K, E]

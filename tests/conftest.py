@@ -45,3 +45,35 @@ def devices8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 cpu devices, got {len(devs)}"
     return devs[:8]
+
+
+@pytest.fixture(scope="session")
+def experts_through_the_kernel():
+    """What the five expert families' compile-only files hold of a program
+    compiled for the chip (``ops/moe_experts.py``): ``check(compiled,
+    layers, experts, d, f1, f)`` holds that the experts' product is the
+    kernel's custom call once an expert layer, under scope ``experts``,
+    handed each layer's ``w1`` and ``w2`` stacks as they lie (bfloat16, no
+    float32 copy of either anywhere), and that the TPU's grouped product is
+    nowhere."""
+    import re
+
+    def check(compiled, layers, experts, d, f1, f):
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and "%grouped_experts" in line]
+        assert len(calls) == layers, (len(calls), layers)
+        w1, w2 = f"bf16[{experts},{d},{f1}]", f"bf16[{experts},{f},{d}]"
+        for line in calls:
+            assert re.search(r'op_name="[^"]*/experts/grouped_experts/',
+                             line), line[-300:]
+            handed = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                               line).group(1)
+            assert handed.count(w1) == f1 // f and handed.count(w2) == 1, \
+                handed
+        assert "ragged" not in text
+        assert f"f32[{experts},{d},{f1}]" not in text
+        assert f"f32[{experts},{f},{d}]" not in text
+
+    return check

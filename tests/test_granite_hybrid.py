@@ -165,7 +165,8 @@ def test_the_programs_hold_the_types_the_file_states(program):
     assert all(v.dtype == jnp.int32 and v.shape == ()
                for c in counted for v in c.values())
     assert [set(c) for c in counted] == (
-        [{"experts_hit", "expert_rows"}] if program == "decode" else []) \
+        [{"experts_hit", "expert_rows", "expert_row_tiles"}]
+        if program == "decode" else []) \
         + [{"prefill_expert_rows"}]
 
 
@@ -425,7 +426,8 @@ def test_the_tiny_preset_engine_and_the_bundles_error_text():
                     cache_len=16, max_prompt_len=8)
     try:
         assert len(eng.generate([1, 2, 3], 4)) == 4
-        assert eng._step_counters == ("expert_rows", "experts_hit")
+        assert eng._step_counters == ("expert_row_tiles", "expert_rows",
+                                      "experts_hit")
         assert eng.llm_stats()["prefill_expert_rows"] == int(
             eng._cache["counted"]["prefill_expert_rows"]) > 0
     finally:

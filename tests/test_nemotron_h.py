@@ -259,8 +259,8 @@ def test_a_thousand_steps_keep_the_state_a_prefill_computes(params):
 def test_prefill_then_decode_through_the_cache(params, tokens, want, lane):
     """Rows of different ``length`` in one padded lane, then 10 decode
     steps: every logits row is the reference's at that position. The wider
-    lane holds 288 rows for the experts, past ``DENSE_ROWS``: the grouped
-    products, where the narrower one runs the batched ones."""
+    lane holds 288 rows for the experts (the TPU's grouped product took it
+    until PR 52; toy widths keep the batched one at any row count)."""
     lengths = [30, 19, 5]
     got, _ = serve_rows(params, tokens, lengths, 10, lane=lane)
     for i, n in enumerate(lengths):
@@ -414,6 +414,8 @@ def test_the_engine_serves_the_references_greedy_continuation(runtime):
     assert steps >= 10
     assert 0 < stats["experts_hit"] <= steps * 2 * 4
     assert stats["experts_hit"] <= stats["expert_rows"] <= steps * 2 * 12
+    # a tile is up to 128 pairs of one expert, so a hit expert is one tile
+    assert stats["expert_row_tiles"] == stats["experts_hit"]
     ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
 
 
@@ -432,7 +434,8 @@ def test_a_dense_familys_engine_and_decode_program_are_as_before():
     try:
         assert len(eng.generate([1, 2, 3], 4)) == 4
         stats = eng.llm_stats()
-        assert not {"experts_hit", "expert_rows", "expert_layers",
+        assert not {"experts_hit", "expert_rows", "expert_row_tiles",
+                    "expert_layers",
                     "experts_held", "ring_rows_read",
                     "ring_rows_held"} & set(stats)
         assert eng._step_counters == ()

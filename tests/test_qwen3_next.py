@@ -595,8 +595,9 @@ def test_the_tiny_preset_engine_and_the_bundles_error_text():
         assert len(eng.generate([1, 2, 3], 4)) == 4
         # the experts', and the rings' rows read and held (PR 48): a toy
         # row keeps the XLA arm, which reads every row of the full layers'
-        assert eng._step_counters == ("expert_rows", "experts_hit",
-                                      "ring_rows_held", "ring_rows_read")
+        assert eng._step_counters == ("expert_row_tiles", "expert_rows",
+                                      "experts_hit", "ring_rows_held",
+                                      "ring_rows_read")
         stats = eng.llm_stats()
         assert stats["ring_rows_read"] == stats["ring_rows_held"] \
             == stats["steps"] * eng._cache["k"].shape[0] * 3 * 16

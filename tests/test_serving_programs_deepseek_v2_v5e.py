@@ -94,8 +94,13 @@ def compiled(one_chip, cfg, engine):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-                for name, (fn, args) in programs.items()}
+        # ``jax.default_backend()`` chooses a kernel's interpret mode and is
+        # the CPU here: while the programs are traced it says the chip's, so
+        # they hold the experts' kernel (PR 52), not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
@@ -130,6 +135,22 @@ def test_the_rings_are_read_in_blocks_where_they_lie(compiled, which):
     assert not re.search(r"f32\[[\d,]*\b16384\b[\d,]*\]", text)
     assert not re.search(r"bf16\[65,16896,[\d,]*\]", text)  # a layer's rings
     assert re.search(r" while\(", text)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_experts_run_through_the_kernel(compiled, cfg, which,
+                                            experts_through_the_kernel):
+    """PR 52: 65 rows a step, 256 a chunk, and in both programs the routed
+    experts' two products are ONE custom call of the kernel of
+    ``ops/moe_experts.py`` an expert layer (the first layer's feed-forward
+    is dense), under scope ``experts``, handed the layer's 20 x 5120 x 3072
+    and 20 x 1536 x 5120 stacks as they lie; no grouped product and no
+    float32 copy of a stack."""
+    assert (cfg.experts_held[1], cfg.d_model, cfg.expert_ff) \
+        == (20, 5120, 1536)
+    experts_through_the_kernel(compiled[which],
+                               cfg.n_layer - cfg.first_dense, 20, 5120,
+                               3072, 1536)
 
 
 def test_the_chunk_keeps_the_rings_in_the_steps_unpadded_layout(compiled):

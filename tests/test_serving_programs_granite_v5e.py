@@ -94,8 +94,13 @@ def compiled(one_chip, cfg, engine):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-                for name, (fn, args) in programs.items()}
+        # ``jax.default_backend()`` chooses a kernel's interpret mode and is
+        # the CPU here: while the programs are traced it says the chip's, so
+        # they hold the experts' kernel (PR 52), not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
@@ -169,13 +174,20 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, which):
     assert moved == []
 
 
-def test_both_programs_run_the_batched_products(compiled):
-    """33 rows a step, 256 a chunk (``ops/moe.DENSE_ROWS``, which this
-    family shares with the other hybrid and does not move): every held
-    expert over every row, no sort and no grouped product in either of the
-    engine's programs."""
+def test_both_programs_run_the_experts_through_the_kernel(
+        compiled, cfg, experts_through_the_kernel):
+    """PR 52 (until then: one batched product a matrix): 33 rows a step, 256 a chunk, and in both of the engine's programs the
+    gated experts' two products are ONE custom call of the kernel of
+    ``ops/moe_experts.py`` a layer, under scope ``experts``, handed the
+    layer's 36 x 4096 x 1536 and 36 x 768 x 4096 stacks as they lie (``w1``
+    twice: a block of F takes the same columns of its gate and up halves).
+    No sort's rows, no ``[E, T, F]`` in float32 (the batched product's, 56
+    MB a chunk) and no float32 copy of a stack."""
+    assert (cfg.experts_held[1], cfg.d_model, cfg.expert_ff) \
+        == (36, 4096, 768)
     for which in ("decode", "prefill"):
-        assert "ragged" not in compiled[which].as_text()
+        experts_through_the_kernel(compiled[which], len(cfg.layer_types), 36,
+                                   4096, 1536, 768)
 
 
 def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):

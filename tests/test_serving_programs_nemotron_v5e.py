@@ -83,8 +83,13 @@ def compiled(one_chip, cfg):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return {name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-                for name, (fn, args) in programs.items()}
+        # ``jax.default_backend()`` chooses a kernel's interpret mode and is
+        # the CPU here: while the programs are traced it says the chip's, so
+        # they hold the experts' kernel (PR 52), not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
@@ -157,17 +162,26 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
     assert moved == []
 
 
-def test_both_programs_run_batched_products_and_a_wide_lane_grouped_ones(
-        compiled, one_chip, cfg):
-    """65 rows a step, 256 a chunk (``ops/moe.DENSE_ROWS``): every held
-    expert over every row, one batched product a matrix and no sort, in
-    both of the engine's programs. The whole-window form on two prompts of
-    1024 (what the benchmark's reference check calls: 512 rows a chunk)
-    sorts the pairs by expert and runs the TPU's grouped product (a
-    ``ragged-dot`` custom call), and is ONE traced copy of the layers
-    looped over its four chunks: ten grouped products, not forty."""
+def test_both_programs_and_the_wide_lane_run_the_experts_through_the_kernel(
+        compiled, one_chip, cfg, experts_through_the_kernel):
+    """PR 52 (until then: batched products here, the TPU's grouped product
+    in the wide lane): 65 rows a step, 256 a chunk, and in both of the
+    engine's programs the experts' two products are ONE custom call of the
+    kernel of ``ops/moe_experts.py`` an ``E`` layer, under scope
+    ``experts``, handed the layer's expert stacks as they lie. ``[E, T, F]`` in float32, which
+    the batched product of every held expert over every row made (352 MB a
+    chunk), is gone: the step keeps under 0.05 GB of its own and the chunk
+    under 0.15 GB. The whole-window form on two prompts of 1024 (what the
+    benchmark's reference check calls: 512 rows a chunk, one lane of the
+    kernel) runs the same kernel, and is ONE traced copy of the layers
+    looped over its four chunks: five calls, not twenty."""
+    shape = (128, 1024, 2688, 2688)  # held, latent, w1's and w2's F
+    assert shape == (cfg.experts_held[1], cfg.latent, cfg.expert_ff,
+                     cfg.expert_ff)
     for which in ("decode", "prefill"):
-        assert "ragged" not in compiled[which].as_text()
+        experts_through_the_kernel(compiled[which], cfg.count("E"), *shape)
+        assert compiled[which].memory_analysis().temp_size_in_bytes \
+            < {"decode": 0.05e9, "prefill": 0.15e9}[which]
 
     def sds(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -182,14 +196,15 @@ def test_both_programs_run_batched_products_and_a_wide_lane_grouped_ones(
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        whole = jax.jit(lambda p, c, t, s, n: nh.nemotron_h_prefill(
-            p, c, t, s, n, cfg), donate_argnums=(1,)).lower(
-                params, cache, i32(2, PROMPT_LEN), i32(2), i32(2)).compile()
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            whole = jax.jit(lambda p, c, t, s, n: nh.nemotron_h_prefill(
+                p, c, t, s, n, cfg), donate_argnums=(1,)).lower(
+                    params, cache, i32(2, PROMPT_LEN), i32(2),
+                    i32(2)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
-    grouped = len(re.findall(r" custom-call\(.*ragged-dot", whole.as_text()))
-    # (a product may take more than one call; two copies would take twice)
-    assert 2 * cfg.count("E") <= grouped < 4 * cfg.count("E")
+    experts_through_the_kernel(whole, cfg.count("E"), *shape)
     assert whole.memory_analysis().temp_size_in_bytes < 1.0e9
 
 

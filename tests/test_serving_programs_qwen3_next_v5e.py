@@ -225,7 +225,8 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
     (``ring_decode_attention``'s operand IS the stack)."""
     text = compiled["decode"].as_text()
     calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "%grouped_experts" not in line]  # the experts': PR 52
     assert len(calls) == 2
     for line in calls:
         assert "ring_decode_attention" in line
@@ -241,6 +242,18 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
             made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
     assert {op for op, _ in made} == {"dynamic-update-slice"}, made
     assert len(made) % 2 == 0 and len(made) >= 2
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_experts_run_through_the_kernel(compiled, cfg, which,
+                                            experts_through_the_kernel):
+    """PR 52: in both of the engine's programs the gated experts' two
+    products are ONE custom call of the kernel of ``ops/moe_experts.py`` a
+    layer, under scope ``experts``, handed the layer's 128 x 2048 x 1024 and 128 x 512 x 2048
+    stacks as they lie; no grouped product, no float32 copy of a stack."""
+    assert (cfg.d_model, cfg.expert_ff) == (2048, 512)
+    experts_through_the_kernel(compiled[which], cfg.n_layer, 128, 2048,
+                               1024, 512)
 
 
 def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):

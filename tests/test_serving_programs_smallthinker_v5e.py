@@ -230,7 +230,8 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
     row-sized writes into the donated stacks after the layer loop."""
     text = compiled["decode"].as_text()
     calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "%grouped_experts" not in line]  # the experts': PR 52
     assert len(calls) == 8
     handed = []
     for line in calls:
@@ -242,6 +243,18 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
     made = _made_as_large_as(text, RINGS | STACKS)
     assert {op for op, _ in made} == {"dynamic-update-slice"}, made
     assert len(made) % 4 == 0 and len(made) >= 4
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_experts_run_through_the_kernel(compiled, cfg, which,
+                                            experts_through_the_kernel):
+    """PR 52: in both of the engine's programs the gated experts' two
+    products are ONE custom call of the kernel of ``ops/moe_experts.py`` a
+    layer, under scope ``experts``, handed the layer's 64 x 2560 x 1536 and 64 x 768 x 2560
+    stacks as they lie; no grouped product, no float32 copy of a stack."""
+    assert (cfg.d_model, cfg.expert_ff) == (2560, 768)
+    experts_through_the_kernel(compiled[which], cfg.n_layer, 64, 2560,
+                               1536, 768)
 
 
 def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):

@@ -487,7 +487,7 @@ def test_the_engine_serves_the_references_greedy_tokens(runtime):
     assert stats["prefill_chunks"] == 7 + 2 and stats["window_rows"] == 8
     for key in ("ring_rows_read", "ring_rows_held", "window_rows_read",
                 "window_rows_held", "experts_hit", "expert_rows",
-                "prefill_expert_rows"):
+                "expert_row_tiles", "prefill_expert_rows"):
         assert stats[key] > 0, key
     assert stats["prefill_expert_rows"] == (26 + 7) * 3 * 8
     ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
