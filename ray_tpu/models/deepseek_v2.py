@@ -24,9 +24,10 @@ The cache holds, a token a layer, ``[c_kv | k_pe]`` after norm and rotation:
 form over it (``q_lat_h = q_nope_h W_uk,h^T`` scored against the latent
 rows, the probabilities' sum of latents times ``W_uv,h``: scope ``absorb``
 around ``attn``); a prefill chunk DECOMPRESSES the rows it may see, a block
-of keys at a time (scope ``kv_up`` inside ``attn``). At a chunk of 256
-queries the two forms cost about the same a key row (54 M operations
-decompressed against 71 M absorbed); with one query a slot only the
+of keys at a time (scope ``kv_up`` inside ``attn``). At the engine's chunk
+of 512 queries a key row costs 75 M operations decompressed against 142 M
+absorbed (54 M against 71 M at 256 queries: the decompression is paid once
+a key row however many queries score it); with one query a slot only the
 absorbed one reads the cache once.
 
 Rotary lanes: lane ``i`` of a ``rope_dim`` vector pairs with lane ``i +

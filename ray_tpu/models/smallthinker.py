@@ -54,7 +54,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.prefill import chunk_len, whole_prompts
+from ray_tpu.models.prefill import (chunk_len, token_parameters,
+                                    whole_prompts)
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_ring_chunk,
                                    cache_write_token, cached_decode_attention,
                                    merged_chunk_attention, merged_row_width,
@@ -519,7 +520,8 @@ def smallthinker_prefill(params: Params, cache: Params, tokens: jax.Array,
     to divide a window ring). Logits at each prompt's last real token."""
     return whole_prompts(
         smallthinker_prefill_chunk, params, cache, tokens, slots, lengths,
-        cfg, chunk=chunk or min(chunk_len(tokens.shape[1]), cfg.window))
+        cfg, chunk=chunk or min(chunk_len(
+            tokens.shape[1], *token_parameters(cfg, params)), cfg.window))
 
 
 def smallthinker_forward(params: Params, tokens: jax.Array,

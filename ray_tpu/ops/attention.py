@@ -447,6 +447,20 @@ def _chunk_softmax(q: jax.Array, rows: jax.Array, parts: list,
     return out.reshape(r, c, h, hd).astype(q.dtype)
 
 
+# Queries a chunk scores at a time where a longer chunk's scores would
+# leave the chip's fast memory (PERF.md section 6, PR 53; a chunk is 512
+# tokens where a token multiplies with a share of the experts,
+# ``models/prefill.chunk_len``). A window layer's float32 scores of 256
+# queries of 28 heads over a ring of 4,096 rows (117 MB) stay there and
+# those of 512 (235 MB) do not: six layers cost 6.2 ms a chunk of 512 in
+# one piece where two chunks of 256 cost 2.3. A latent block's scores are
+# [128, 256, 256] (34 MB) at 256 queries and 67 MB at 512, where the cell
+# lost 8 % of its rate in one piece and gained 8 % in groups of 256 against
+# a block decompressed once. The scores over a global layer's whole window
+# are in memory at either length and stay one piece.
+CHUNK_QUERIES = 256
+
+
 def ring_positions(start: jax.Array, n_rows: int) -> jax.Array:
     """What a ring of ``n_rows`` rows holds of a prompt whose positions
     ``< start`` were written at ``position mod n_rows``: [..., n_rows]
@@ -486,11 +500,19 @@ def wrapped_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     rows = _chunk_query_rows(q, w, k_all.dtype)
     held = ring_positions(start, n_rows)[:, None, :]              # [R, 1, L]
     sees = (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [R, C, 1]
-    parts = [(_slot_rows(k_all, layer, slots, n_rows),
-              _slot_rows(v_all, layer, slots, n_rows),
-              (held >= 0) & (held > sees - n_rows)),
-             (k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
-    return _chunk_softmax(q, rows, parts, w)
+    ring_k, ring_v = (_slot_rows(x, layer, slots, n_rows)
+                      for x in (k_all, v_all))
+    in_ring = (held >= 0) & (held > sees - n_rows)                # [R, C, L]
+    own = jnp.tril(jnp.ones((c, c), bool))[None]
+    # a group of queries at a time over the ring and the chunk's rows up to
+    # the group's last (``CHUNK_QUERIES``); a chunk that is no whole number
+    # of groups, a shorter one among them, is one softmax over all, as ever
+    g = CHUNK_QUERIES if c % CHUNK_QUERIES == 0 else c
+    return jnp.concatenate([_chunk_softmax(
+        q[:, at:at + g], rows[:, at:at + g],
+        [(ring_k, ring_v, in_ring[:, at:at + g]),
+         (k_own[:, :at + g], v_own[:, :at + g],
+          own[:, at:at + g, :at + g])], w) for at in range(0, c, g)], axis=1)
 
 
 # decode-path  # jax-hot-path: the KV cache stays in the activation dtype
@@ -815,8 +837,9 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
     sees the slot's rows ``<= start + i``. The ring is read in blocks of
     ``block`` rows up to the last one that holds such a row (``start +
     C``): each block's keys and values are decompressed (scope ``kv_up``),
-    scored against the chunk's queries and folded into a running softmax,
-    so the work follows the keys in sight and not the longest prompt. ->
+    scored against the chunk's queries, ``CHUNK_QUERIES`` of them at a
+    time, and folded into their running softmax, so the work follows the
+    keys in sight and not the longest prompt. ->
     [R, C, H, v] in q's type. Operands in the cache's type, float32
     scores, statistics and sums."""
     r, c, h, _ = q_nope.shape
@@ -825,6 +848,12 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
     block = min(block, n_rows)
     dt_ = cache.dtype
     w_uk, w_uv = w_uk.astype(dt_), w_uv.astype(dt_)
+
+    # a block is decompressed once and meets the chunk's queries a group
+    # at a time (``CHUNK_QUERIES``; a chunk that is no whole number of
+    # groups, a shorter one among them, goes whole)
+    g = CHUNK_QUERIES if c % CHUNK_QUERIES == 0 else c
+    groups = [slice(at, at + g) for at in range(0, c, g)]
 
     def one_row(i):  # over its own slot and its own keys
         qn, qp = q_nope[i].astype(dt_), q_pe[i].astype(dt_)
@@ -838,24 +867,31 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
             with jax.named_scope("kv_up"):
                 k = jnp.einsum("br,rhd->bhd", rows[:, :rank], w_uk)
                 v = jnp.einsum("br,rhd->bhd", rows[:, :rank], w_uv)
-            seen = (idx[None, :] <= sees[:, None]) & fresh[None, :]  # [C, B]
-            scores = (jnp.einsum("chd,bhd->hcb", qn, k,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("chp,bp->hcb", qp, rows[:, rank:],
-                                   preferred_element_type=jnp.float32)) \
-                * scale
-            return _online_softmax(
-                carry, scores, seen[None],
-                lambda p: jnp.einsum("hcb,bhd->hcd", p.astype(v.dtype), v,
-                                     preferred_element_type=jnp.float32))
+
+            def fold(of, carry):  # the group's queries over this block
+                seen = (idx[None, :] <= sees[of][:, None]) \
+                    & fresh[None, :]  # [G, B]
+                scores = (jnp.einsum("chd,bhd->hcb", qn[of], k,
+                                     preferred_element_type=jnp.float32)
+                          + jnp.einsum("chp,bp->hcb", qp[of], rows[:, rank:],
+                                       preferred_element_type=jnp.float32)) \
+                    * scale
+                return _online_softmax(
+                    carry, scores, seen[None],
+                    lambda p: jnp.einsum(
+                        "hcb,bhd->hcd", p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32))
+
+            return tuple(fold(of, part) for of, part in zip(groups, carry))
 
         n_blocks = jnp.minimum(start[i] + c + block - 1,
                                n_rows + block - 1) // block
-        _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (
-            jnp.full((h, c), -1e30, jnp.float32),
-            jnp.zeros((h, c), jnp.float32),
-            jnp.zeros((h, c, v_dim), jnp.float32)))
-        return jnp.swapaxes(acc / l[..., None], 0, 1)  # [C, H, v]
+        parts = jax.lax.fori_loop(0, n_blocks, body, tuple((
+            jnp.full((h, g), -1e30, jnp.float32),
+            jnp.zeros((h, g), jnp.float32),
+            jnp.zeros((h, g, v_dim), jnp.float32)) for _ in groups))
+        return jnp.concatenate([jnp.swapaxes(acc / l[..., None], 0, 1)
+                                for _, l, acc in parts])  # [C, H, v]
 
     # a few rows, each a loop of its own length
     return jnp.stack([one_row(i) for i in range(r)]).astype(q_nope.dtype)

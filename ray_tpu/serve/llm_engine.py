@@ -253,8 +253,17 @@ class LLMEngine:
     ``prefill_chunks`` executions for ``prefill_rows_real`` requests).
     ``prefill_rows`` bounds how many requests one turn admits between
     two decode steps; ``prefill_chunk`` defaults to the rule of
-    ``models/prefill.py`` (tests at toy widths pass a small one), and the
-    cache must hold whole chunks up to ``max_prompt_len``.
+    ``models/prefill.py``: the power of two, from 256 up, at which a
+    chunk's operations reach the chip's ridge for the weights it reads
+    once, about 240 x ``params_stored`` / ``params_a_token`` (both in
+    ``llm_stats()``) and no more than one lane of the experts' kernel
+    (PERF.md section 6, PR 52's layer-alone table and PR 53's cells):
+    256 where a token multiplies with every stored matrix, 512 where it
+    takes ``top_k`` of the held experts; the longest prompt if that is
+    shorter, and 256 again where the cache holds whole chunks of 256 up
+    to ``max_prompt_len`` but not of 512 (tests at toy widths pass a
+    small one). The cache must hold whole chunks up to
+    ``max_prompt_len``.
 
     Deploy it like any Serve class::
 
@@ -283,14 +292,21 @@ class LLMEngine:
         import jax
         import numpy as np
 
-        from ray_tpu.models.prefill import chunk_len, key_window
+        from ray_tpu.models.prefill import (chunk_len, key_window,
+                                            token_parameters)
         from ray_tpu.util.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
-        # The chunk is the engine's, by rule (models/prefill.py); the
+        cfg, init, init_cache, prefill_chunk_fn, decode = _model_bundle(
+            model, config, preset)
+        # The chunk is the engine's, by rule (models/prefill.py), from the
+        # stored leaves' shapes and the configuration's routing; the
         # argument is for tests at toy widths, where a rule made for a
         # chip's ridge would never cut a prompt.
-        chunk = int(prefill_chunk or chunk_len(max_prompt_len))
+        self._rule_params = token_parameters(cfg, jax.eval_shape(
+            lambda: init(jax.random.PRNGKey(seed), cfg)))
+        chunk = int(prefill_chunk or chunk_len(
+            max_prompt_len, *self._rule_params, cache_len=cache_len))
         window = key_window(max_prompt_len, chunk)
         if chunk < 1 or window > cache_len:
             raise ValueError(
@@ -316,8 +332,6 @@ class LLMEngine:
         self._dep = deployment or "llm"
         self._dep_explicit = deployment is not None
 
-        cfg, init, init_cache, prefill_chunk_fn, decode = _model_bundle(
-            model, config, preset)
         if model == "gpt2" and self.max_prompt_len > cfg.seq_len:
             # gpt2's learned position table bounds the prefill window;
             # fail at bind time, not per-request inside the jit.
@@ -1235,6 +1249,10 @@ class LLMEngine:
             "max_prompt_len": self.max_prompt_len,
             "prefill_rows": self.prefill_rows,
             "prefill_chunk": self.prefill_chunk,
+            # what the chunk's rule read (models/prefill.chunk_len): the
+            # parameters stored and those one token multiplies with
+            "params_stored": self._rule_params[0],
+            "params_a_token": self._rule_params[1],
             "active": active,
             "queued": queued,
             "compiles": dict(self._compiles),

@@ -438,24 +438,28 @@ def test_prefill_in_toy_chunks_then_decode_through_the_cache(chunks,
     assert int(counted["experts_hit"]) <= int(counted["expert_rows"])
 
 
-def test_long_rows_cross_the_ops_own_blocks():
+@pytest.mark.parametrize("chunk, ring", [(256, 1300), (512, 1600)])
+def test_long_rows_cross_the_ops_own_blocks(chunk, ring):
     """At the blocks the ops really use (256 keys a chunk block, 1024 a
     decode block): a prompt of 1100 tokens in chunks of 256 in a ring of
     1300 rows (up to six chunk blocks and two decode blocks, the last of
-    each moved back inside the ring) against the reference, in float32."""
+    each moved back inside the ring) against the reference, in float32;
+    and in the expert families' chunks of 512, whose queries meet a
+    decompressed block in two groups of ``CHUNK_QUERIES``."""
     cfg = ds.DeepseekV2Config.tiny(dtype=F32, param_dtype=F32, n_layer=2,
                                    vocab_size=64)
     params = moved(ds.deepseek_v2_init(jax.random.PRNGKey(4), cfg))
-    length, ring = 1100, 1300
+    length, window = 1100, -(-1100 // chunk) * chunk
     assert ring > attn_ops.LATENT_DECODE_BLOCK > attn_ops.LATENT_CHUNK_BLOCK
+    assert chunk // attn_ops.CHUNK_QUERIES == chunk // 256
     row = jnp.asarray(np.random.default_rng(9).integers(
         0, cfg.vocab_size, (1, length + 2), dtype=np.int32))
     want = reference.forward(to_ref(params), row, **ref_kwargs(cfg))
     cache = ds.deepseek_v2_init_cache(cfg, 2, ring)
-    prompt = jnp.pad(row[:, :length], ((0, 0), (0, 1280 - length)))
+    prompt = jnp.pad(row[:, :length], ((0, 0), (0, window - length)))
     logits, cache = jax.jit(lambda c: whole_prompts(
         ds.deepseek_v2_prefill_chunk, params, c, prompt, jnp.ones(
-            1, jnp.int32), jnp.asarray([length]), cfg, chunk=256))(cache)
+            1, jnp.int32), jnp.asarray([length]), cfg, chunk=chunk))(cache)
     out = [logits[0]]
     for i in range(2):
         toks = jnp.zeros(2, jnp.int32).at[1].set(row[0, length + i])

@@ -320,8 +320,9 @@ STARTS = {"at-0-a-recycled-slot": 0, "a-chunk-short-of-the-window":
 
 
 @functools.lru_cache(maxsize=None)
-def _wrapped_chunk(row):
-    """Every start of STARTS a row of ONE call, each over a slot of its own
+def _wrapped_chunk(row, group=ops.CHUNK_QUERIES):
+    """Every start of STARTS a row of ONE call (its queries in groups of
+    ``group``, where that divides the chunk), each over a slot of its own
     whose ring was filled as a prompt fills it (position p at row ``p mod
     WINDOW``, what it held before, another tenant's noise, left where the
     prompt has not written): (the op's, the plain masked softmax over the
@@ -343,10 +344,12 @@ def _wrapped_chunk(row):
     at = starts[:, None] + np.arange(CHUNK)[None, :]
     k_own = jnp.stack([keys[i, at[i]] for i in range(n)])
     v_own = jnp.stack([values[i, at[i]] for i in range(n)])
-    got = ops.wrapped_chunk_attention(
-        q, _merged(jnp.asarray(k_all), w), _merged(jnp.asarray(v_all), w),
-        _merged(k_own, w), _merged(v_own, w), 1, slots,
-        jnp.asarray(starts))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ops, "CHUNK_QUERIES", group)
+        got = ops.wrapped_chunk_attention(
+            q, _merged(jnp.asarray(k_all), w), _merged(jnp.asarray(v_all), w),
+            _merged(k_own, w), _merged(v_own, w), 1, slots,
+            jnp.asarray(starts))
     pos = np.arange(longest)[None, None, :]
     seen = (pos <= at[:, :, None]) & (pos > at[:, :, None] - WINDOW)
     return np.asarray(got), _plain(q, keys, values, seen)
@@ -364,6 +367,19 @@ def test_a_chunk_over_a_wrapped_ring_sees_the_window_by_position(row, start):
     i = list(STARTS).index(start)
     assert got.shape == plain.shape and got.dtype == np.float32
     np.testing.assert_allclose(got[i], plain[i], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("row", list(WRAPPED_ROWS))
+def test_a_long_chunks_queries_meet_the_ring_a_group_at_a_time(row):
+    """A chunk longer than ``CHUNK_QUERIES`` (the expert families'
+    512 tokens; here 4 queries in groups of 2) scores a group of its
+    queries at a time, each over the ring and the chunk's rows up to the
+    group's last: the same softmax at every start."""
+    assert CHUNK % 2 == 0 and CHUNK % ops.CHUNK_QUERIES
+    got, plain = _wrapped_chunk(row, group=2)
+    np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got, _wrapped_chunk(row)[0], rtol=2e-5,
+                               atol=2e-6)
 
 
 def test_ring_positions_names_each_rows_position_or_none():

@@ -8,16 +8,19 @@ chunks.
 """
 
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.loading import load_json, load_module
 from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
                             llama, nemotron_h, qwen3_next, smallthinker)
-from ray_tpu.models.prefill import chunk_len, key_window, whole_prompts
-from ray_tpu.serve.llm_engine import LLMEngine
+from ray_tpu.models.prefill import (chunk_len, key_window, token_parameters,
+                                    whole_prompts)
+from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
 
 F32 = jnp.float32
 # (float32 tiny config, init, init_cache, prefill_chunk, whole-window
@@ -106,16 +109,16 @@ def _used_cache(family, seed):
         init_cache(cfg, SLOTS, CACHE_LEN))
 
 
-def _in_chunks(family, params, cache, prompt, slot):
-    """As the engine runs one request: ``ceil(len / CHUNK)`` calls of the
+def _in_chunks(family, params, cache, prompt, slot, chunk=CHUNK):
+    """As the engine runs one request: ``ceil(len / chunk)`` calls of the
     chunk function with R = 1. -> (the last call's logits [V], the cache)."""
     cfg, chunk_fn = FAMILIES[family][0], FAMILIES[family][3]
     run = jax.jit(lambda c, t, at, n: chunk_fn(
         params, c, t, jnp.full(1, slot, jnp.int32), at, n, cfg,
-        window=key_window(MAX_PROMPT, CHUNK)))
-    for at in range(0, len(prompt), CHUNK):
-        piece = prompt[at:at + CHUNK]
-        toks = np.zeros((1, CHUNK), np.int32)
+        window=key_window(MAX_PROMPT, chunk)))
+    for at in range(0, len(prompt), chunk):
+        piece = prompt[at:at + chunk]
+        toks = np.zeros((1, chunk), np.int32)
         toks[0, :len(piece)] = piece
         logits, cache = run(cache, jnp.asarray(toks),
                             jnp.full(1, at, jnp.int32),
@@ -181,6 +184,25 @@ def test_chunks_leave_what_the_whole_window_leaves(family, n):
     full = forward(params, jnp.asarray(prompt)[None], cfg)[0, -1]
     np.testing.assert_allclose(np.asarray(got), np.asarray(full),
                                rtol=2e-4, atol=2e-4)
+
+
+@every_family
+@pytest.mark.parametrize("n", [CHUNK + 2, 2 * CHUNK, MAX_PROMPT - 1], ids=[
+    "ends-inside-a-chunk", "ends-at-a-boundary", "several-chunks"])
+def test_where_a_prompt_is_cut_changes_nothing(family, n):
+    """The chunk's length is the rule's to choose (``chunk_len``: C where
+    a token multiplies with every stored matrix, 2 C where it takes a share
+    of the experts): the same prompt through chunks of C and of 2 C leaves
+    the same greedy token, the same logits and the same state."""
+    params, prompt = _params(family), _prompt(n, seed=50 + n)
+    cache = _used_cache(family, seed=6)
+    got, got_cache = _in_chunks(family, params, cache, prompt, slot=1)
+    want, want_cache = _in_chunks(family, params, cache, prompt, slot=1,
+                                  chunk=2 * CHUNK)
+    assert int(jnp.argmax(got)) == int(jnp.argmax(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    _assert_same_state(family, got_cache, want_cache, 1, n)
 
 
 @every_row_width
@@ -287,20 +309,110 @@ def test_one_two_and_three_chunks_run_one_program_and_add_up(family):
     assert st["prefill_tokens_real"] == sum(lens[:3]) + MAX_PROMPT
 
 
-def test_the_chunk_is_the_engines_by_rule_and_must_fit_the_cache():
-    """256 tokens, or the longest prompt if shorter; a slot's rows must
-    hold whole chunks up to the longest prompt."""
-    assert chunk_len(768) == chunk_len(1024) == 256 and chunk_len(16) == 16
-    assert key_window(768, 256) == 768 and key_window(700, 256) == 768
-    with pytest.raises(ValueError, match="must fit the cache"):
-        LLMEngine(model="gpt2", config=FAMILIES["gpt2"][0], cache_len=18,
-                  max_prompt_len=MAX_PROMPT + 1, prefill_chunk=CHUNK)
-    eng = LLMEngine(model="gpt2", config=FAMILIES["gpt2"][0], cache_len=32,
-                    max_prompt_len=16)
-    try:
-        assert eng.llm_stats()["prefill_chunk"] == 16
-    finally:
-        eng.shutdown_engine()
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# model -> (its configuration as a cell runs it, that cell's deployment)
+PUBLISHED = {
+    "gpt2": ("gpt2-xl-1.5b", "gpt2xl_1chip_b8"),
+    "falcon_h1": ("falcon-h1-34b-instruct", "falconh1_1chip_b32"),
+    "nemotron_h": ("nemotron3-super-120b-a12b", "nemotron3s_1chip_b64"),
+    "granite_hybrid": ("granite-4.0-h-small", "granite4hs_1chip_b32"),
+    "deepseek_v2": ("deepseek-v2", "dsv2_1chip_b64"),
+    "qwen3_next": ("qwen3-next-80b-a3b-instruct", "qwen3next_1chip_b64"),
+    "smallthinker": ("smallthinker-21b-a3b-instruct",
+                     "smallthinker_1chip_b48"),
+}
+
+
+def _published(model):
+    """(the program's configuration at the published widths, ``top_k`` and
+    held counts, the deployment's engine settings) of ``model``'s cell."""
+    config, deployment = (os.path.join(REPO, "benchmark", kind, name + ".json")
+                          for kind, name in zip(("configs", "deployments"),
+                                                PUBLISHED[model]))
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      model + ".py"))
+    return (family.system_config(load_json(config)),
+            load_json(deployment)["engine"])
+
+
+def _case(model, chunk, sizes="published", **engine):
+    """``sizes``: the cell's configuration and deployment (``engine``
+    changes some of its settings), the family's own ``default``
+    configuration, or this file's ``tiny`` one, which is built too."""
+    return model, sizes, engine, chunk
+
+
+@pytest.mark.parametrize("model, sizes, engine, chunk", [pytest.param(
+    *case, id=name) for name, case in {
+        # a token multiplies with every stored matrix: 256
+        "dense-gpt2-xl": _case("gpt2", 256),
+        "dense-falcon-h1": _case("falcon_h1", 256),
+        "dense-llama": _case("llama", 256, "default", max_prompt_len=1024,
+                             cache_len=1024),
+        # ... with top_k of the held experts: one lane of their kernel
+        "experts-nemotron-h": _case("nemotron_h", 512),
+        "experts-granite": _case("granite_hybrid", 512),
+        "experts-deepseek-v2": _case("deepseek_v2", 512),
+        "experts-qwen3-next": _case("qwen3_next", 512),
+        "experts-smallthinker": _case("smallthinker", 512),
+        "experts-tiny": _case("granite_hybrid", 512, "tiny",
+                              max_prompt_len=700, cache_len=1024),
+        # no longer than the longest prompt
+        "short-prompts-dense": _case("gpt2", 16, "tiny", max_prompt_len=16,
+                                     cache_len=32),
+        "short-prompts-experts": _case("granite_hybrid", 300,
+                                       max_prompt_len=300),
+        # whole chunks of 256 fit a slot's rows and of 512 do not: 256, and
+        # the engine does not raise where it did not before (key_window of
+        # 700 tokens in chunks of 256 is 768 rows)
+        "cache-of-768-rows": _case("deepseek_v2", 256, max_prompt_len=700,
+                                   cache_len=768),
+        "cache-of-768-rows-tiny": _case("qwen3_next", 256, "tiny",
+                                        max_prompt_len=700, cache_len=768),
+        "cache-of-768-rows-dense": _case("gpt2", 256, max_prompt_len=768,
+                                         cache_len=768),
+        # ... and whole chunks that fit no way are refused
+        "must-fit-the-cache": _case("gpt2", None, "tiny", cache_len=18,
+                                    max_prompt_len=MAX_PROMPT + 1,
+                                    prefill_chunk=CHUNK),
+    }.items()])
+def test_the_chunk_is_the_engines_by_rule_and_must_fit_the_cache(
+        model, sizes, engine, chunk):
+    """The fewest tokens, a power of two from 256 up, at which a chunk's
+    operations reach the ridge for the weights it reads once, read from the
+    stored leaves' shapes and the configuration's ``top_k`` of
+    ``n_experts``: 256 for the families without experts, one lane of the
+    experts' kernel for the five with them at their cells' own sizes; the
+    longest prompt if shorter; what a slot's rows hold in whole chunks;
+    and ``llm_stats()`` says what the rule read."""
+    assert key_window(768, 256) == key_window(700, 256) == 768
+    cfg = None
+    if sizes == "published":
+        cfg, deployment = _published(model)
+        engine = {**deployment, **engine}
+    elif sizes == "tiny":
+        cfg = FAMILIES[model][0]
+    cfg, init = _model_bundle(model, cfg, "full")[:2]
+    longest, rows = engine["max_prompt_len"], engine["cache_len"]
+    read = token_parameters(cfg, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    assert (read[1] < read[0]) == hasattr(cfg, "top_k")
+    got = engine.get("prefill_chunk") or chunk_len(longest, *read, rows)
+    if chunk is None:
+        assert key_window(longest, got) > rows
+        with pytest.raises(ValueError, match="must fit the cache"):
+            LLMEngine(model=model, config=cfg, **engine)
+        return
+    assert got == chunk and key_window(longest, got) <= rows
+    if sizes == "tiny":
+        eng = LLMEngine(model=model, config=cfg, **engine)
+        try:
+            st = eng.llm_stats()
+        finally:
+            eng.shutdown_engine()
+        assert st["prefill_chunk"] == chunk
+        assert (st["params_stored"], st["params_a_token"]) == read
+        assert read[0] == sum(a.size for a in jax.tree.leaves(eng.params))
 
 
 def test_what_a_cache_counts_adds_up_in_llm_stats():

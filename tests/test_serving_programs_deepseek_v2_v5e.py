@@ -4,7 +4,7 @@
 Compile-only, for one described v5e chip, at the published widths of
 ``benchmark/configs/deepseek-v2.json`` and the shapes of the cell
 ``serve_dsv2_longctx_sat`` (64 slots and the scratch one, rings of 16896
-latent rows, prompts of up to 16384 tokens in the engine's [1, 256]
+latent rows, prompts of up to 16384 tokens in the engine's [1, 512]
 chunks): nothing runs, so nothing here is a time. It holds that both
 programs fit the chip beside their arguments with next to nothing of their
 own (no float32 copy of a window, no scores over a whole ring: both
@@ -25,7 +25,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import deepseek_v2 as ds
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -75,12 +76,14 @@ def compiled(one_chip, cfg, engine):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     slots = engine["max_batch"] + 1
-    chunk = chunk_len(engine["max_prompt_len"])
-    window = key_window(engine["max_prompt_len"], chunk)
-    assert (slots, chunk, window, engine["cache_len"]) \
-        == (65, 256, 16384, 16896)
     params = sds(jax.eval_shape(
         lambda: ds.deepseek_v2_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (65, 512, 16384, 16896)
     cache = sds(jax.eval_shape(lambda: ds.deepseek_v2_init_cache(
         cfg, slots, engine["cache_len"])))
     programs = {
@@ -140,7 +143,7 @@ def test_the_rings_are_read_in_blocks_where_they_lie(compiled, which):
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_experts_run_through_the_kernel(compiled, cfg, which,
                                             experts_through_the_kernel):
-    """PR 52: 65 rows a step, 256 a chunk, and in both programs the routed
+    """PR 52: 65 rows a step, 512 a chunk, and in both programs the routed
     experts' two products are ONE custom call of the kernel of
     ``ops/moe_experts.py`` an expert layer (the first layer's feed-forward
     is dense), under scope ``experts``, handed the layer's 20 x 5120 x 3072

@@ -29,7 +29,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import falcon_h1 as fh
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -79,12 +80,14 @@ def compiled(one_chip, cfg, engine):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     slots = engine["max_batch"] + 1
-    chunk = chunk_len(engine["max_prompt_len"])
+    params = sds(jax.eval_shape(
+        lambda: fh.falcon_h1_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
     window = key_window(engine["max_prompt_len"], chunk)
     assert (slots, chunk, window, engine["cache_len"]) \
         == (33, 256, 4096, 5120)
-    params = sds(jax.eval_shape(
-        lambda: fh.falcon_h1_init(jax.random.PRNGKey(0), cfg)))
     cache = sds(jax.eval_shape(lambda: fh.falcon_h1_init_cache(
         cfg, slots, engine["cache_len"])))
     programs = {

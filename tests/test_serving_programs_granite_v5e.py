@@ -4,7 +4,7 @@
 Compile-only, for one described v5e chip, at the published widths of
 ``benchmark/configs/granite-4.0-h-small.json`` and the shapes of the cell
 ``serve_granite4hs_longdoc_sat`` (32 slots and the scratch one, rings of
-8448 rows, prompts of up to 8192 tokens in the engine's [1, 256] chunks
+8448 rows, prompts of up to 8192 tokens in the engine's [1, 512] chunks
 over a key window of 8192): nothing runs, so nothing here is a time. It
 holds that both programs fit the chip beside their arguments (the decode
 step's float32 pass over 33 K/V windows included), that the donated cache
@@ -24,7 +24,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import granite_hybrid as gh
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -74,12 +75,14 @@ def compiled(one_chip, cfg, engine):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     slots = engine["max_batch"] + 1
-    chunk = chunk_len(engine["max_prompt_len"])
-    window = key_window(engine["max_prompt_len"], chunk)
-    assert (slots, chunk, window, engine["cache_len"]) \
-        == (33, 256, 8192, 8448)
     params = sds(jax.eval_shape(
         lambda: gh.granite_hybrid_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (33, 512, 8192, 8448)
     cache = sds(jax.eval_shape(lambda: gh.granite_hybrid_init_cache(
         cfg, slots, engine["cache_len"])))
     programs = {
@@ -128,9 +131,11 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.alias_size_in_bytes >= cache_bytes
     assert 11.9e9 < mem.argument_size_in_bytes < 11.95e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
-    # the step holds next to nothing of its own; a chunk holds its scores
-    # over the 8192-row window (268 MB in float32) and its experts' rows
-    assert mem.temp_size_in_bytes < {"decode": 0.1e9, "prefill": 0.6e9}[which]
+    # the step holds next to nothing of its own; a chunk holds its 512
+    # queries' scores over the 8192-row window (537 MB in float32) and its
+    # experts' rows: 0.65 GB (0.36 at the 256 queries of before PR 53)
+    assert mem.temp_size_in_bytes < {"decode": 0.1e9,
+                                     "prefill": 0.75e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
@@ -176,7 +181,7 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, which):
 
 def test_both_programs_run_the_experts_through_the_kernel(
         compiled, cfg, experts_through_the_kernel):
-    """PR 52 (until then: one batched product a matrix): 33 rows a step, 256 a chunk, and in both of the engine's programs the
+    """PR 52 (until then: one batched product a matrix): 33 rows a step, 512 a chunk, and in both of the engine's programs the
     gated experts' two products are ONE custom call of the kernel of
     ``ops/moe_experts.py`` a layer, under scope ``experts``, handed the
     layer's 36 x 4096 x 1536 and 36 x 768 x 4096 stacks as they lie (``w1``

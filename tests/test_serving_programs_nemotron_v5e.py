@@ -4,8 +4,8 @@ programs (PR 28).
 Compile-only, for one described v5e chip, at the published widths of
 ``benchmark/configs/nemotron3-super-120b-a12b.json`` and the cell's shapes
 (64 slots and the scratch one, a 2048-row cache, prompts of up to 1024
-tokens in the engine's [1, 256] prefill chunks, PR 31; a [4, 1024] lane
-before): nothing runs, so nothing here is a time. It holds that both programs fit
+tokens in the engine's [1, 512] prefill chunks, PR 53; [1, 256] since
+PR 31, a [4, 1024] lane before): nothing runs, so nothing here is a time. It holds that both programs fit
 the chip beside their arguments, that the donated cache (K/V rows, the
 convolution tails, the float32 SSM state) is updated in its own buffers,
 and that no program copies a layer's expert stack or the whole state: XLA's
@@ -24,11 +24,11 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import nemotron_h as nh
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SLOTS, CACHE_LEN, PROMPT_LEN = 65, 2048, 1024
-CHUNK = chunk_len(PROMPT_LEN)  # as the engine derives it: 256
 HBM = 15.75 * 2 ** 30
 
 
@@ -72,12 +72,15 @@ def compiled(one_chip, cfg):
         lambda: nh.nemotron_h_init(jax.random.PRNGKey(0), cfg)))
     cache = sds(jax.eval_shape(
         lambda: nh.nemotron_h_init_cache(cfg, SLOTS, CACHE_LEN)))
+    chunk = chunk_len(PROMPT_LEN, *token_parameters(cfg, params),
+                      cache_len=CACHE_LEN)
+    assert chunk == 512  # as the engine derives it
     programs = {
         "decode": (lambda p, c, t, n: nh.nemotron_h_decode_step(
             p, c, t, n, cfg), (params, cache, i32(SLOTS), i32(SLOTS))),
         "prefill": (lambda p, c, t, s, at, n: nh.nemotron_h_prefill_chunk(
-            p, c, t, s, at, n, cfg, window=key_window(PROMPT_LEN, CHUNK)),
-                    (params, cache, i32(1, CHUNK), i32(1), i32(1), i32(1))),
+            p, c, t, s, at, n, cfg, window=key_window(PROMPT_LEN, chunk)),
+                    (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
     }
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -165,16 +168,17 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
 def test_both_programs_and_the_wide_lane_run_the_experts_through_the_kernel(
         compiled, one_chip, cfg, experts_through_the_kernel):
     """PR 52 (until then: batched products here, the TPU's grouped product
-    in the wide lane): 65 rows a step, 256 a chunk, and in both of the
+    in the wide lane): 65 rows a step, 512 a chunk, and in both of the
     engine's programs the experts' two products are ONE custom call of the
     kernel of ``ops/moe_experts.py`` an ``E`` layer, under scope
     ``experts``, handed the layer's expert stacks as they lie. ``[E, T, F]`` in float32, which
     the batched product of every held expert over every row made (352 MB a
     chunk), is gone: the step keeps under 0.05 GB of its own and the chunk
     under 0.15 GB. The whole-window form on two prompts of 1024 (what the
-    benchmark's reference check calls: 512 rows a chunk, one lane of the
-    kernel) runs the same kernel, and is ONE traced copy of the layers
-    looped over its four chunks: five calls, not twenty."""
+    benchmark's reference check calls: two rows of the rule's 512 tokens a
+    chunk, two lanes of the kernel) runs the same kernel, and is ONE traced
+    copy of the layers looped over its two chunks: ten calls, not
+    twenty."""
     shape = (128, 1024, 2688, 2688)  # held, latent, w1's and w2's F
     assert shape == (cfg.experts_held[1], cfg.latent, cfg.expert_ff,
                      cfg.expert_ff)
@@ -204,7 +208,7 @@ def test_both_programs_and_the_wide_lane_run_the_experts_through_the_kernel(
                     i32(2)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
-    experts_through_the_kernel(whole, cfg.count("E"), *shape)
+    experts_through_the_kernel(whole, 2 * cfg.count("E"), *shape)
     assert whole.memory_analysis().temp_size_in_bytes < 1.0e9
 
 

@@ -5,7 +5,7 @@ Compile-only, for one described v5e chip, at the published widths of
 cell ``serve_qwen3next_mixedctx_sat`` (64 slots and the scratch one, a
 float32 delta state [65, 32, 128, 128] in six of eight layers beside rings
 of 18432 merged rows in the other two, 128 held experts a layer, prompts of
-up to 16384 tokens in the engine's [1, 256] chunks over a key window of
+up to 16384 tokens in the engine's [1, 512] chunks over a key window of
 16384): nothing runs, so nothing here is a time. It holds that both programs
 fit the chip beside their arguments (13.08 GB of weights and cache), that
 the donated cache is updated in its own buffers, that no program makes a
@@ -29,7 +29,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import qwen3_next as qn
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -79,12 +80,14 @@ def compiled(one_chip, cfg, engine):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     slots = engine["max_batch"] + 1
-    chunk = chunk_len(engine["max_prompt_len"])
-    window = key_window(engine["max_prompt_len"], chunk)
-    assert (slots, chunk, window, engine["cache_len"]) \
-        == (65, 256, 16384, 18432)
     params = sds(jax.eval_shape(
         lambda: qn.qwen3_next_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (65, 512, 16384, 18432)
     cache = sds(jax.eval_shape(lambda: qn.qwen3_next_init_cache(
         cfg, slots, engine["cache_len"])))
     programs = {
@@ -136,11 +139,12 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # the step holds its float32 scores over 65 rings of 18432 rows a head
     # (77 MB a layer) and the experts' [128, 65, 1024] product, and no copy
-    # of a ring or a state: 0.05 GB. A chunk holds its scores over the
-    # 16384-row window (268 MB in float32 a full layer), both stacks' old
-    # rows cut out and the experts' [128, 256, 1024] float32 product
-    # (134 MB): 0.35 GB.
-    assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 0.6e9}[which]
+    # of a ring or a state: 0.05 GB. A chunk holds its 512 queries'
+    # scores over the 16384-row window (537 MB in float32 a full layer) and
+    # both stacks' old rows cut out: 0.62 GB (0.35 at the 256 queries of
+    # before PR 53).
+    assert mem.temp_size_in_bytes < {"decode": 0.2e9,
+                                     "prefill": 0.75e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "

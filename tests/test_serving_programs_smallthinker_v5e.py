@@ -6,7 +6,7 @@ the cell ``serve_smallthinker_mixedwin_sat`` (48 slots and the scratch one,
 TWO stacks of rings of merged rows of 512 columns in one donated pytree: the
 two global layers' of 16384 rows and the six window layers' of 4096, 64
 experts a layer all held, prompts of up to 14336 tokens in the engine's
-[1, 256] chunks over a key window of 14336): nothing runs, so nothing here
+[1, 512] chunks over a key window of 14336): nothing runs, so nothing here
 is a time. It holds that both programs fit the chip beside their arguments
 (12.33 GB of weights and cache), that the donated cache is updated in its
 own buffers, that no program makes a float32 array as long as a ring or a
@@ -31,7 +31,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import smallthinker as st
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -83,12 +84,14 @@ def compiled(one_chip, cfg, engine):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     slots = engine["max_batch"] + 1
-    chunk = chunk_len(engine["max_prompt_len"])
-    window = key_window(engine["max_prompt_len"], chunk)
-    assert (slots, chunk, window, engine["cache_len"]) \
-        == (49, 256, 14336, 16384)
     params = sds(jax.eval_shape(
         lambda: st.smallthinker_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (49, 512, 14336, 16384)
     cache = sds(jax.eval_shape(lambda: st.smallthinker_init_cache(
         cfg, slots, engine["cache_len"])))
     programs = {
@@ -138,9 +141,10 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # the step holds the experts' [64, 49, 1536] product and no copy of a
     # ring. A chunk holds its float32 scores over the 14336-row window of a
-    # global layer (28 heads x 256 x 14080 x 4 B = 404 MB, and their
-    # exponentials), a window layer's over 4352 keys (125 MB), the rows cut
-    # out of the stacks and the experts' [64, 256, 1536] float32 product.
+    # global layer (28 heads x 512 x 13824 x 4 B = 793 MB, and their
+    # exponentials), a window layer's over 4608 keys (264 MB) and the rows
+    # cut out of the stacks: 1.09 GB (0.66 at the 256 queries of before
+    # PR 53).
     assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 1.6e9}[which]
 
 

@@ -25,12 +25,13 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import gpt2
-from ray_tpu.models.prefill import chunk_len, key_window
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
 
 XL = gpt2.GPT2Config(vocab_size=50304, n_layer=48, n_head=25, d_model=1600,
                      seq_len=1024)
 SLOTS, CACHE_LEN, PROMPT_LEN = 9, 1024, 768
-CHUNK = chunk_len(PROMPT_LEN)  # as the engine derives it: 256
+CHUNK = 256  # as the engine derives it (held where the programs are made)
 ROW = 1664  # 25 heads of 64 merged, padded to whole lane tiles: 13 x 128
 LAYER_BLOCK = SLOTS * CACHE_LEN * ROW
 CACHE_DIMS = f"{XL.n_layer},{SLOTS},{CACHE_LEN},{ROW}"
@@ -65,6 +66,8 @@ def _programs(one_chip, cache=None):
     params = sds(jax.tree.map(
         lambda a, dt: jax.ShapeDtypeStruct(a.shape, dt), made,
         XL.serving_dtypes(made)))
+    assert CHUNK == chunk_len(PROMPT_LEN, *token_parameters(XL, params),
+                              cache_len=CACHE_LEN)
     cache = sds(cache or jax.eval_shape(
         lambda: gpt2.gpt2_init_cache(XL, SLOTS, CACHE_LEN)))
     return {
