@@ -6,9 +6,10 @@ hybrids, Nemotron-H (Mamba-2, attention and latent-MoE layers) and Granite
 4.0-H (a Mamba-2 mixer or attention, then gated experts, in every layer).
 
 Not exported here, so that a process which serves another family never
-imports them; ``LLMEngine`` resolves them by name, as it does all eight
+imports them; ``LLMEngine`` resolves them by name, as it does all nine
 served families (gpt2, llama, nemotron_h, granite_hybrid, deepseek_v2,
-falcon_h1, qwen3_next, smallthinker; ``serve/llm_engine._model_bundle``):
+falcon_h1, qwen3_next, smallthinker, exaone_moe;
+``serve/llm_engine._model_bundle``):
 DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a latent cache, group-
 limited experts), Falcon-H1 (``models/falcon_h1.py``: rotary grouped-query
 attention AND a Mamba-2 mixer side by side in every layer, both caches a
@@ -18,8 +19,13 @@ delta-rule state beside K/V rings, top-10-of-512 experts and a gated shared
 expert in every layer) and SmallThinker (``models/smallthinker.py``: one
 global attention layer without a position embedding to three rotary window
 layers, window rings beside full rings in one cache, a router read before
-attention, gated-ReLU experts all held). ``models/resnet.py`` is imported
-by its path too.
+attention, gated-ReLU experts all held) and K-EXAONE
+(``models/exaone_moe.py``: three rotary window layers of 128 keys to one
+global layer without a position embedding, each sublayer's norm after it, a
+sigmoid router beside a shared expert, and a multi-token-prediction module
+that the engine serves as the model's own draft: a step verifies two rows a
+slot and yields one or two tokens). ``models/resnet.py`` is imported by its
+path too.
 
 Models are plain functions over parameter pytrees — no framework Module
 state — so the same code runs under any mesh and any rules table.

@@ -154,6 +154,12 @@ def _mesh_flash_attention(q, k, v, softmax_scale):
 #   reads it by POSITION (``wrapped_chunk_attention``, ``ring_positions``)
 #   and writes at ``start mod L`` (``cache_write_ring_chunk``).
 #
+# * a verify step (K-EXAONE, PR 54): a few consecutive query rows a slot
+#   over the same two stacks, the window rings exactly 128 rows: every ring
+#   read as it was, the new rows scored apart and causal among themselves
+#   (``cached_verify_attention``), all of them written after the layer
+#   loop. A chunk there is several window rings long.
+#
 # A family picks its layout once, in its ``init_cache``; the ops below take
 # the path the rank of what they are handed names. The same bytes reshaped
 # inside an op are another tiled layout on the chip, a copy: only a cache
@@ -487,16 +493,19 @@ def wrapped_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     as it lies the rows whose position (``ring_positions``) is inside that
     and not negative, whatever ``start`` (before the first wrap, at it and
     windows on: one program), and of the chunk's own rows those ``<= i``
-    (``C <= L``: none of them is out of the window). The caller then writes
-    the chunk's rows at ``start mod L`` (``cache_write_ring_chunk``; ``C``
-    divides ``L``, so a chunk never straddles the ring's end). The same
+    (where ``C <= L`` none of them is out of the window; a chunk of several
+    windows, ``C`` a multiple of ``L``, also drops its own rows ``<= i -
+    L``). The caller then writes the chunk's rows at ``start mod L``
+    (``cache_write_ring_chunk``; ``C`` divides ``L`` or is whole rings
+    long, so a chunk never straddles the ring's end). The same
     arithmetic as ``merged_chunk_attention``. -> [R, C, H, hd] in q's
     type."""
     c = q.shape[1]
     n_rows, w = k_all.shape[2:]
-    if n_rows % c:
-        raise ValueError(f"a chunk of {c} rows does not divide a ring of "
-                         f"{n_rows}: it would straddle the ring's end")
+    if n_rows % c and c % n_rows:
+        raise ValueError(f"a chunk of {c} rows neither divides a ring of "
+                         f"{n_rows} nor is whole rings long: it would "
+                         f"straddle the ring's end")
     rows = _chunk_query_rows(q, w, k_all.dtype)
     held = ring_positions(start, n_rows)[:, None, :]              # [R, 1, L]
     sees = (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [R, C, 1]
@@ -504,6 +513,9 @@ def wrapped_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
                       for x in (k_all, v_all))
     in_ring = (held >= 0) & (held > sees - n_rows)                # [R, C, L]
     own = jnp.tril(jnp.ones((c, c), bool))[None]
+    if c > n_rows:
+        # a chunk of several windows: its own rows leave the window too
+        own = own & ~jnp.tril(jnp.ones((c, c), bool), -n_rows)[None]
     # a group of queries at a time over the ring and the chunk's rows up to
     # the group's last (``CHUNK_QUERIES``); a chunk that is no whole number
     # of groups, a shorter one among them, is one softmax over all, as ever
@@ -521,13 +533,17 @@ def cache_write_ring_chunk(cache: jax.Array, rows: jax.Array,
                            lengths: jax.Array) -> jax.Array:
     """``cache_write_chunk`` into rings that wrap: row block ``rows[:, i]``
     ([N, C, W]) lands at ``cache[:, slots[i], start[i] mod L :][:C]`` (``C``
-    divides ``L``). Only the chunk's first ``lengths[i]`` rows are real:
+    divides ``L``; a chunk of several rings, ``C`` a multiple of ``L``,
+    leaves its last ``L`` real rows: ``_write_ring_last_rows``). Only the
+    chunk's first ``lengths[i]`` rows are real:
     the others keep what the ring held, because in a ring that has wrapped
     that is the prompt's own rows a window back, which the tokens after the
     prompt's end still see (a straight cache has nothing there yet)."""
     rows = rows.astype(cache.dtype)
     n, _, c, w = rows.shape
     n_rows = cache.shape[2]
+    if c > n_rows:
+        return _write_ring_last_rows(cache, rows, slots, start, lengths)
     real = jnp.arange(c)[None, :] < lengths[:, None]  # [R, C]
     for i in range(rows.shape[1]):
         at = (0, slots[i], jnp.mod(start[i], n_rows), 0)
@@ -538,13 +554,114 @@ def cache_write_ring_chunk(cache: jax.Array, rows: jax.Array,
     return cache
 
 
+def _write_ring_last_rows(cache, rows, slots, start, lengths):
+    """``cache_write_ring_chunk`` where the chunk is several rings long
+    (``C`` a multiple of ``L``): ring row j ends up with the LAST real
+    position that lies there, the largest ``p < start + lengths`` with ``p
+    mod L == j`` (``ring_positions``): the chunk's row ``p - start`` where
+    that is in the chunk, else what the ring held (an earlier chunk's row,
+    still in the window of the tokens after a prompt that ends early in
+    this chunk)."""
+    n, _, c, w = rows.shape
+    n_rows = cache.shape[2]
+    last = ring_positions(start + lengths, n_rows)         # [R, L]
+    takes = last >= start[:, None]
+    at = jnp.clip(last - start[:, None], 0, c - 1)
+    for i in range(rows.shape[1]):
+        where = (0, slots[i], 0, 0)
+        was = jax.lax.dynamic_slice(cache, where, (n, 1, n_rows, w))
+        new = jnp.take(rows[:, i], at[i], axis=1)[:, None]  # [N, 1, L, W]
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.where(takes[i][None, None, :, None], new, was), where)
+    return cache
+
+
+def cached_verify_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
+                            k_new: jax.Array, v_new: jax.Array,
+                            cursor: jax.Array, valid: jax.Array, out_dtype,
+                            layer, scale: float | None = None) -> jax.Array:
+    """``cached_decode_attention`` for R consecutive query rows a slot (a
+    verify step: the newest token and its drafts), over a STACKED cache of
+    merged rows, WITHOUT their rows being in the cache yet.
+
+    q [S, R, H, hd], row i at position ``p + i``; k_all / v_all [N, S, L,
+    W] as they were before this step (read only) and ``layer`` which of the
+    N; k_new / v_new [S, R, W] the rows' own merged K/V rows in the cache's
+    type; cursor [S] the ring row the FIRST new row will take (row i takes
+    ``(cursor + i) mod L``), valid [S] the live entries with the first row.
+    Query row i sees the new keys ``<= i`` (causal among themselves) and of
+    the ring every live row but those the new keys ``<= i`` will take: not
+    the places of LATER rows, which in a ring that has wrapped still hold
+    the oldest keys of an earlier row's window (a window ring of exactly
+    ``window`` rows serves both rows so, read before written). What a
+    rejected row wrote is the next step's cursor row or lies past its
+    ``valid``: never read. One softmax over both parts in
+    ``cached_decode_attention``'s arithmetic; rings of whole blocks of
+    whole lane tiles take the kernel of ``ops/ring_decode.py`` (the rows'
+    heads stacked), any other is cut out of the stack and read whole. One
+    row is ``cached_decode_attention`` itself. -> [S, R, H, hd]."""
+    s, r, h, hd = q.shape
+    if r == 1:
+        return cached_decode_attention(
+            q[:, 0], k_all, v_all, k_new[:, 0], v_new[:, 0], cursor, valid,
+            out_dtype, scale, layer)[:, None]
+    n_rows, w = k_all.shape[2:]
+    rows = _step_query_rows(q.reshape(s * r, h, hd), w,
+                            k_all.dtype).reshape(s, r, h, w)
+    if ring_decode.takes_kernel(n_rows, w):
+        # whole sublane tiles of heads a query row: a zero query's row is
+        # cut off again
+        pad = -h % 8
+        out = ring_decode.ring_decode_attention(
+            jnp.pad(rows, ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(
+                s, r * (h + pad), w), k_all, v_all, layer, k_new, v_new,
+            cursor, valid, functools.partial(_scaled, hd=hd, scale=scale))
+        out = out.reshape(s, r, h + pad, w)[:, :, :h]
+    else:
+        out = _verify_ring_sums(rows, k_all[layer], v_all[layer], k_new,
+                                v_new, cursor, valid, hd, scale)
+    return _step_own_columns(out.reshape(s * r, h, w), hd).reshape(
+        s, r, h, hd).astype(out_dtype)
+
+
+def _verify_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd, scale):
+    """``_whole_ring_sums`` for R query rows a slot: float32 sums [S, R, H,
+    W] of queries q [S, R, H, W] over a ring k / v [S, L, W] read whole and
+    the rows' own keys k_new / v_new [S, R, W]; who sees what is
+    ``cached_verify_attention``'s."""
+    n, r = k.shape[1], q.shape[1]
+    idx = jnp.arange(n)
+    # how many places past the cursor a ring row lies: new row j takes j
+    past = jnp.mod(idx[None, :] - cursor[:, None], n)[:, None, :]  # [S, 1, L]
+    seen = (idx[None, :] < valid[:, None])[:, None, :] \
+        & (past > jnp.arange(r)[None, :, None])                    # [S, R, L]
+    ring = jnp.einsum("srhw,slw->srhl", q, k,
+                      preferred_element_type=jnp.float32)
+    own = jnp.einsum("srhw,sjw->srhj", q, k_new,
+                     preferred_element_type=jnp.float32)
+    scores = jnp.concatenate([
+        jnp.where(seen[:, :, None, :], _scaled(ring, hd, scale), -1e30),
+        jnp.where(jnp.tril(jnp.ones((r, r), bool))[None, :, None, :],
+                  _scaled(own, hd, scale), -1e30)], axis=-1)
+    weights = jax.nn.softmax(scores, axis=-1)
+    # float32 probabilities into the sums, as ``_whole_ring_sums`` has them
+    return jnp.einsum("srhl,slw->srhw", weights[..., :n],
+                      v.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST) \
+        + jnp.einsum("srhj,sjw->srhw", weights[..., n:],
+                     v_new.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+
+
 def cached_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             k_new: jax.Array, v_new: jax.Array,
                             cursor: jax.Array, valid: jax.Array,
                             out_dtype, scale: float | None = None,
                             layer=None) -> jax.Array:
-    """One query token per slot over the slot's ring-cache window, the
-    token itself included, WITHOUT its row being in the cache yet.
+    """One query token per slot (ONE query row a slot; a verify step's few
+    consecutive rows are ``cached_verify_attention``'s) over the slot's
+    ring-cache window, the token itself included, WITHOUT its row being in
+    the cache yet.
 
     q [S, H, hd]; k/v [S, L, H, hd], the layer's cache as it was before
     this step (read only); k_new/v_new [S, H, hd], this token's rows in
@@ -653,15 +770,9 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
     S, L, W] and the kernel makes the sums, up to each slot's last live
     block of rows; without, XLA does over the whole ring (the form the
     kernel is held to)."""
-    s, h, hd = q.shape
+    _, h, hd = q.shape
     w = k.shape[-1]
-    g = _kv_heads(h, hd, w)
-    if g == h:
-        q = _heads_apart(
-            merged_rows(q.reshape(s, h * hd), w).astype(k.dtype),
-            h, hd)  # [S, H, W]
-    else:
-        q = _heads_in_group_columns(q.astype(k.dtype), g)  # [S, H, W]
+    q = _step_query_rows(q, w, k.dtype)
     if layer is None:
         out = _whole_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd,
                                scale)
@@ -671,11 +782,30 @@ def _merged_decode_attention(q, k, v, k_new, v_new, cursor, valid,
             jnp.pad(q, ((0, 0), (0, -h % 8), (0, 0))), k, v, layer, k_new,
             v_new, cursor, valid,
             functools.partial(_scaled, hd=hd, scale=scale))[:, :h]
+    return _step_own_columns(out, hd).astype(out_dtype)
+
+
+def _step_query_rows(q: jax.Array, w: int, dtype) -> jax.Array:
+    """A step's queries q [N, H, hd] as the products over merged rows of
+    ``w`` columns take them, [N, H, W] in ``dtype``: each head's numbers in
+    its own columns (grouped queries: in its K/V head's), zeros in the
+    others."""
+    n, h, hd = q.shape
+    g = _kv_heads(h, hd, w)
     if g == h:
-        out = _heads_merged(out, hd)[:, :h * hd]  # [S, H * hd]
-    else:
-        out = _heads_out_of_group_columns(out, g)  # [S, H, hd]
-    return out.reshape(s, h, hd).astype(out_dtype)
+        return _heads_apart(
+            merged_rows(q.reshape(n, h * hd), w).astype(dtype), h, hd)
+    return _heads_in_group_columns(q.astype(dtype), g)
+
+
+def _step_own_columns(sums: jax.Array, hd: int) -> jax.Array:
+    """The pick back of ``_step_query_rows``: sums [N, H, W] over merged
+    value rows, a row a head -> [N, H, hd], each head's own columns."""
+    n, h, w = sums.shape
+    g = _kv_heads(h, hd, w)
+    if g == h:
+        return _heads_merged(sums, hd)[:, :h * hd].reshape(n, h, hd)
+    return _heads_out_of_group_columns(sums, g)
 
 
 def _whole_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd, scale):

@@ -1,5 +1,6 @@
-"""Pallas TPU kernel: one query token a slot over a ring of MERGED rows that
-stops at the slot's own context.
+"""Pallas TPU kernel: one query token a slot (or, in a verify step, a few
+consecutive ones: ``ring_decode_attention`` says how many query rows it
+takes) over a ring of MERGED rows that stops at the slot's own context.
 
 ``cached_decode_attention``'s merged arm written out in XLA
 (``ops/attention.py``, ``_merged_decode_attention``) reads every ring whole,
@@ -72,6 +73,18 @@ def _weighted_sum(p: jax.Array, v: jax.Array) -> jax.Array:
     return sums[:h] + sums[h:2 * h] + sums[2 * h:]
 
 
+def _take_block(scores, v_ref, m_scr, l_scr, acc_scr):
+    """One block of masked scores [H, block] into the running (max, sum,
+    sums) of the flash kernel's scheme."""
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+    p = jnp.exp(scores - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_scr[...] = m_new
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + _weighted_sum(p, v_ref[...])
+
+
 def _kernel(layer_ref, valid_ref, cursor_ref, q_ref, k_new_ref, v_new_ref,
             k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scaled):
     """Grid (slot, row block), the blocks sequential: running (max, sum,
@@ -101,13 +114,58 @@ def _kernel(layer_ref, valid_ref, cursor_ref, q_ref, k_new_ref, v_new_ref,
             jnp.int32, scores.shape, 1)
         scores = jnp.where((row < valid) & (row != cursor), scores,
                            _NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + _weighted_sum(p, v_ref[...])
+        _take_block(scores, v_ref, m_scr, l_scr, acc_scr)
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _out():
+        o_ref[...] = acc_scr[...] / l_scr[...]
+
+
+def _verify_kernel(layer_ref, valid_ref, cursor_ref, q_ref, k_new_ref,
+                   v_new_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                   scaled, rows, n_rows):
+    """``_kernel`` for ``rows`` consecutive query rows a slot (their heads
+    stacked, row i's at ``i * H'``), query row i at the position of new key
+    i: it sees the new keys ``<= i`` (they open its running softmax) and of
+    the ring of ``n_rows`` every live row but those the new keys ``<= i``
+    will take, ``(cursor + j) mod n_rows`` for ``j <= i``: a LATER row's
+    place still holds a key the earlier query may need (in a ring that has
+    wrapped, the oldest key of its window)."""
+    del layer_ref  # the index maps' alone
+    s, b = pl.program_id(0), pl.program_id(1)
+    valid, cursor = valid_ref[s], cursor_ref[s]
+    per = q_ref.shape[0] // rows  # heads a query row (with their pad)
+
+    @pl.when(b == 0)
+    def _new_tokens():
+        q = q_ref[...].astype(jnp.float32)
+        at = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // per
+        scores = [jnp.where(at >= j, scaled(jnp.sum(
+            q * k_new_ref[j:j + 1, :].astype(jnp.float32), axis=-1,
+            keepdims=True)), _NEG_INF) for j in range(rows)]
+        top = functools.reduce(jnp.maximum, scores)
+        probs = [jnp.exp(x - top) for x in scores]
+        m_scr[...] = top
+        l_scr[...] = sum(probs)
+        acc_scr[...] = sum(
+            p * v_new_ref[j:j + 1, :].astype(jnp.float32)
+            for j, p in enumerate(probs))
+
+    @pl.when(b * BLOCK_ROWS < valid)
+    def _live_block():
+        scores = scaled(jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32))  # [rows * H', block]
+        row = b * BLOCK_ROWS + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        at = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // per
+        seen = row < valid
+        for j in range(rows):
+            taken = cursor + j
+            taken = jnp.where(taken >= n_rows, taken - n_rows, taken)
+            seen = seen & ~((row == taken) & (at >= j))
+        scores = jnp.where(seen, scores, _NEG_INF)
+        _take_block(scores, v_ref, m_scr, l_scr, acc_scr)
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _out():
@@ -125,9 +183,22 @@ def ring_decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     v_new [S, W] this token's merged rows; cursor, valid [S] int32;
     ``scaled`` what turns float32 products into scores. -> float32 sums
     [S, H, W] over merged value rows, a row a head: the caller picks each
-    head's own columns."""
+    head's own columns.
+
+    ONE query row a slot as above, or R consecutive ones (a verify step):
+    k_new / v_new [S, R, W], the rows of positions ``p .. p + R - 1``, q
+    [S, R * H', W] with query row i's heads at ``i * H'`` (H' a multiple of
+    8), ``cursor`` and ``valid`` the FIRST new row's. Query row i sees the
+    new keys ``<= i`` and the ring without the rows those will take."""
     s, h, w = q.shape
     n_rows = k_all.shape[2]
+    rows = 1 if k_new.ndim == 2 else k_new.shape[1]
+    if rows == 1:
+        kernel = functools.partial(_kernel, scaled=scaled)
+        k_new, v_new = k_new[:, None], v_new[:, None]
+    else:
+        kernel = functools.partial(_verify_kernel, scaled=scaled, rows=rows,
+                                   n_rows=n_rows)
 
     def ring(i, b, layer_ref, valid_ref, cursor_ref):
         # Past the slot's last live block the index stands still, so the
@@ -145,14 +216,14 @@ def ring_decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
         return (i, 0, 0)
 
     return pl.pallas_call(
-        functools.partial(_kernel, scaled=scaled),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s, n_rows // BLOCK_ROWS),
             in_specs=[
                 pl.BlockSpec((None, h, w), slot),
-                pl.BlockSpec((None, 1, w), slot),
-                pl.BlockSpec((None, 1, w), slot),
+                pl.BlockSpec((None, rows, w), slot),
+                pl.BlockSpec((None, rows, w), slot),
                 pl.BlockSpec((None, None, BLOCK_ROWS, w), ring),
                 pl.BlockSpec((None, None, BLOCK_ROWS, w), ring),
             ],
@@ -168,5 +239,4 @@ def ring_decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
         interpret=jax.default_backend() == "cpu",
         name="ring_decode_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), valid.astype(jnp.int32),
-      cursor.astype(jnp.int32), q, k_new[:, None], v_new[:, None],
-      k_all, v_all)
+      cursor.astype(jnp.int32), q, k_new, v_new, k_all, v_all)

@@ -19,6 +19,14 @@ engine, mounted as an ordinary Serve deployment callable:
   (trace-time side effects, the ``fused_norm`` test idiom) prove no
   per-request recompile ever happens — the benchmark's serving cells
   require ``compiles == {decode: 1, prefill: 1}``.
+* **A step may yield more than one token a slot.** Where a family's
+  bundle holds a verify-and-draft step (a model that drafts for itself
+  with its own prediction module), the decode program runs a slot's
+  newest token AND its draft, and yields the main stack's one or two
+  greedy tokens and the next draft: still one array and one sync a step.
+  What is served is token for token what the family serves undrafted;
+  ``max_tokens`` and the end token cut inside a pair. The other
+  families run the programs and the host path they always ran.
 * **Slot-indexed ring KV-cache in device memory.** Per-slot write
   cursors via ``lax.dynamic_update_slice``; the cache rides the model's
   activation dtype (bf16 — no fp32 copy) and, for Llama, the GQA
@@ -156,7 +164,15 @@ class _Request:
 
 def _model_bundle(model: str, config, preset: str):
     """(config, init, init_cache, prefill_chunk, decode_step) for a model
-    family — resolved lazily so importing this module never pulls jax."""
+    family — resolved lazily so importing this module never pulls jax.
+
+    A family that drafts for itself adds a SIXTH element, its
+    verify-and-draft step (``models/exaone_moe.exaone_moe_verify_step``
+    has the contract: two rows a slot in, ``served [S, 4]`` out: how many
+    tokens, the tokens, the next draft). An engine serves with it where
+    the bundle has one: its prefill chunk then also takes ``follows`` and
+    returns the first draft's logits; ``decode_step`` stays the family's
+    undrafted step, which tests hold the drafted engine's tokens to."""
     if model == "gpt2":
         from ray_tpu.models import gpt2 as m
 
@@ -213,9 +229,18 @@ def _model_bundle(model: str, config, preset: str):
                          else m.SmallThinkerConfig())
         return (cfg, m.smallthinker_init, m.smallthinker_init_cache,
                 m.smallthinker_prefill_chunk, m.smallthinker_decode_step)
+    if model == "exaone_moe":
+        from ray_tpu.models import exaone_moe as m
+
+        cfg = config or (m.ExaoneMoeConfig.tiny() if preset == "tiny"
+                         else m.ExaoneMoeConfig())
+        return (cfg, m.exaone_moe_init, m.exaone_moe_init_cache,
+                m.exaone_moe_prefill_chunk, m.exaone_moe_decode_step,
+                m.exaone_moe_verify_step)
     raise ValueError(
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h|"
-        f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next|smallthinker)")
+        f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next|smallthinker|"
+        f"exaone_moe)")
 
 
 def _stored_params(init, key, cfg):
@@ -247,7 +272,8 @@ class LLMEngine:
     family's ``serving_dtypes``) and keeps nothing of what it was cast
     from: the step reads the bytes it multiplies with and no others.
 
-    Its two programs are the ``[max_batch + 1]`` decode step and the
+    Its two programs are the ``[max_batch + 1]`` decode step (two rows a
+    slot where the family drafts for itself: ``_model_bundle``) and the
     ``[1, prefill_chunk]`` prefill chunk; a prompt runs the second once
     for every ``prefill_chunk`` tokens (``llm_stats()``:
     ``prefill_chunks`` executions for ``prefill_rows_real`` requests).
@@ -297,8 +323,12 @@ class LLMEngine:
         from ray_tpu.util.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
-        cfg, init, init_cache, prefill_chunk_fn, decode = _model_bundle(
-            model, config, preset)
+        cfg, init, init_cache, prefill_chunk_fn, decode, *verify = \
+            _model_bundle(model, config, preset)
+        # the family's verify-and-draft step, where its bundle has one: a
+        # step then yields one or two tokens a slot (_step_fanout)
+        verify = verify[0] if verify else None
+        self._drafting = verify is not None
         # The chunk is the engine's, by rule (models/prefill.py), from the
         # stored leaves' shapes and the configuration's routing; the
         # argument is for tests at toy widths, where a rule made for a
@@ -369,6 +399,13 @@ class LLMEngine:
         self._model_stats = dict(
             getattr(cfg, "serving_stats", lambda: {})())
 
+        def with_counters(out, counted):
+            # the step's counters behind what it hands out: ONE array
+            self._step_counters = tuple(sorted(counted))
+            return self._jnp.concatenate([out, self._jnp.stack(
+                [counted[k] for k in self._step_counters]).astype(
+                    self._jnp.int32)])
+
         def step_fn(params, cache, tokens, pos):
             self._compiles["decode"] += 1  # trace-time: fires per compile
             logits, cache, *counted = decode(params, cache, tokens, pos, cfg)
@@ -376,11 +413,32 @@ class LLMEngine:
                 nxt = self._jnp.argmax(logits, axis=-1).astype(
                     self._jnp.int32)
             if counted:
-                self._step_counters = tuple(sorted(counted[0]))
-                nxt = self._jnp.concatenate([nxt, self._jnp.stack(
-                    [counted[0][k] for k in self._step_counters]).astype(
-                        self._jnp.int32)])
+                nxt = with_counters(nxt, counted[0])
             return nxt, cache
+
+        def verify_fn(params, cache, tokens, pos):
+            # tokens [S, 2]: a slot's newest token and its draft. The ONE
+            # array the step syncs on: served [S, 4] (how many tokens, the
+            # tokens, the next draft) flattened, then the counters.
+            self._compiles["decode"] += 1
+            _, cache, counted, served, *_ = verify(
+                params, cache, tokens, pos, cfg)
+            return with_counters(served.reshape(-1).astype(
+                self._jnp.int32), counted), cache
+
+        def draft_prefill_fn(params, cache, packed):
+            # packed [1, chunk + 4]: ``prefill_fn``'s and the prompt's
+            # token after the chunk (negative where the prompt ends in
+            # it); -> the first token and the first draft
+            self._compiles["prefill"] += 1
+            logits, cache, draft_logits = prefill_chunk_fn(
+                params, cache, packed[:, :chunk], packed[:, chunk],
+                packed[:, chunk + 1], packed[:, chunk + 2], cfg,
+                window=window, follows=packed[:, chunk + 3])
+            with jax.named_scope("head"):
+                return (self._jnp.argmax(
+                    self._jnp.concatenate([logits, draft_logits]),
+                    axis=-1).astype(self._jnp.int32), cache)
 
         def prefill_fn(params, cache, packed):
             # packed [1, chunk + 3] int32: the chunk's tokens, then its
@@ -400,10 +458,22 @@ class LLMEngine:
         # the big buffer). CPU test runs warn that donation was unused.
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
+        if self._drafting:
+            # (a profile shows these two as jit_verify_fn and
+            # jit_draft_prefill_fn)
+            step_fn, prefill_fn = verify_fn, draft_prefill_fn
         self._step_fn = jax.jit(step_fn, donate_argnums=(1,))
         self._prefill_fn = jax.jit(prefill_fn, donate_argnums=(1,))
 
-        self._tokens = np.zeros(self.max_batch + 1, np.int32)
+        # What a step is handed of each slot: its newest token and, where
+        # the family drafts, its draft for the position after, side by side
+        # ([S, 2]: ``_tokens`` and ``_draft`` are its columns).
+        self._step_in = np.zeros(
+            (self.max_batch + 1, 2) if self._drafting
+            else self.max_batch + 1, np.int32)
+        self._tokens = self._step_in[:, 0] if self._drafting \
+            else self._step_in
+        self._draft = self._step_in[:, 1] if self._drafting else None
         self._pos = np.zeros(self.max_batch + 1, np.int32)
         self._slot_req: List[Optional[_Request]] = [None] * self.max_batch
         # Admission queue: a HEAP keyed (deadline slack, seq) — the 10k
@@ -457,6 +527,11 @@ class LLMEngine:
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
             "errors": 0, "tokens_out": 0, "queue_peak": 0,
             "occupancy_sum": 0, "ring_wraps": 0,
+            # A drafting family's steps: drafts verified (one a slot a
+            # step) and those the device's comparison accepted, each of
+            # which made its step yield a second token. tokens_out over
+            # occupancy_sum is then the tokens a step a slot.
+            "draft_proposed": 0, "draft_accepted": 0,
             # The prefill lane's fill: admission turns that ran a
             # prefill, requests in them, their (truncated) prompt tokens,
             # executions of the chunk program (chunks a request =
@@ -681,9 +756,13 @@ class LLMEngine:
                 lengths.append(len(prompt))
                 for at in range(0, len(prompt), chunk):
                     piece = prompt[at:at + chunk]
-                    packed = np.zeros((1, chunk + 3), np.int32)
+                    packed = np.zeros(
+                        (1, chunk + 3 + self._drafting), np.int32)
                     packed[0, :len(piece)] = piece
-                    packed[0, chunk:] = slot, at, len(piece)
+                    packed[0, chunk:chunk + 3] = slot, at, len(piece)
+                    if self._drafting:
+                        packed[0, chunk + 3] = prompt[at + chunk] \
+                            if at + chunk < len(prompt) else -1
                     tok, self._cache = self._prefill_fn(
                         self.params, self._cache, packed)
                     n_chunks += 1
@@ -697,7 +776,7 @@ class LLMEngine:
         with tracing.device_span("llm.prefill.sync"):
             # The one intentional sync per request: first tokens must
             # reach the streams now.  # analyze: ignore[JX002]
-            first = [int(np.asarray(tok)[0]) for tok in first]  # analyze: ignore[JX002]
+            first = [np.asarray(tok) for tok in first]  # analyze: ignore[JX002]
             # what the programs have counted in the cache up to here
             # (a sparse model's token-expert pairs; most families: none)
             counted = {key: int(np.asarray(n)) for key, n in  # analyze: ignore[JX002]
@@ -720,8 +799,10 @@ class LLMEngine:
                 self._counted_seen = counted
                 for i, req in enumerate(batch):
                     slot = slots[i]
-                    tok = first[i]
+                    tok = int(first[i][0])
                     self._tokens[slot] = tok
+                    if self._drafting:
+                        self._draft[slot] = int(first[i][1])
                     self._pos[slot] = lengths[i]
                     self._slot_req[slot] = req
                     req.remaining = req.max_new - 1
@@ -796,7 +877,7 @@ class LLMEngine:
                                      epoch_ns=time.time_ns()):
                 nxt, self._cache = self._step_fn(
                     self.params, self._cache,
-                    self._jnp.asarray(self._tokens),
+                    self._jnp.asarray(self._step_in),
                     self._jnp.asarray(self._pos))
             with tracing.device_span("llm.step.sync"):
                 # The device has this step: wake the LAST step's streams
@@ -845,50 +926,80 @@ class LLMEngine:
         ``llm.step.fanout`` device span ``ds``). A token is visible
         to every drain from its append here. A stream that ends with it
         is woken here (``_finish_locked``); one that goes on decoding is
-        put on ``_wakes`` and woken after the next enqueue (``_loop``)."""
+        put on ``_wakes`` and woken after the next enqueue (``_loop``).
+
+        A drafting family's step yields ONE chunk of one or two tokens a
+        slot (``served [S, 4]`` at the head of ``nxt``): the position moves
+        by what the device accepted, the chunk is cut where ``max_tokens``
+        or the end token falls inside a pair."""
+        drafting = self._drafting
         with self._lock:
-            produced = 0
+            produced = chunks = drafted = accepted = 0
             # the one clock read of this fan-out's tokens
             self._fanout_ns = visible_ns = time.perf_counter_ns()
             for slot in active:
                 req = self._slot_req[slot]
                 if req is None:
                     continue  # cancelled while the step was in flight
-                tok = int(nxt[slot])
-                self._tokens[slot] = tok
-                self._pos[slot] += 1
-                if int(self._pos[slot]) % self.cache_len == 0:
+                if drafting:
+                    n = int(nxt[4 * slot])
+                    toks = [int(t) for t in
+                            nxt[4 * slot + 1:4 * slot + 1 + n]]
+                    self._draft[slot] = int(nxt[4 * slot + 3])
+                    drafted += 1
+                    accepted += n - 1
+                else:
+                    toks = [int(nxt[slot])]
+                self._tokens[slot] = toks[-1]
+                was = int(self._pos[slot])
+                self._pos[slot] = now = was + len(toks)
+                # a wrap is the position CROSSING a multiple of the ring
+                if now // self.cache_len != was // self.cache_len:
                     self.stats_counters["ring_wraps"] += 1
-                req.remaining -= 1
-                produced += 1
-                req.stream.n_tokens += 1
+                if drafting:
+                    toks = toks[:req.remaining]
+                    if self.eos_token in toks:
+                        toks = toks[:toks.index(self.eos_token) + 1]
+                req.remaining -= len(toks)
+                produced += len(toks)
+                chunks += 1
+                req.stream.n_tokens += len(toks)
                 if not req.stream.pending:
                     req.stream.visible_ns = visible_ns
-                req.stream.pending.append([tok])
-                if req.remaining <= 0 or tok == self.eos_token:
+                req.stream.pending.append(toks)
+                if req.remaining <= 0 or toks[-1] == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
                 else:
                     # (the list was emptied when this step was enqueued)
                     self._wakes.append(req.stream)
             self.stats_counters["steps"] += 1
+            counters_at = (self.max_batch + 1) * (4 if drafting else 1)
             for i, key in enumerate(self._step_counters):
                 self.stats_counters[key] = self.stats_counters.get(key, 0) \
-                    + int(nxt[self.max_batch + 1 + i])
+                    + int(nxt[counters_at + i])
             self.stats_counters["tokens_out"] += produced
             self.stats_counters["occupancy_sum"] += len(active)
+            self.stats_counters["draft_proposed"] += drafted
+            self.stats_counters["draft_accepted"] += accepted
             # ITL (TPOT): delivery-to-delivery gap. All slots advance
-            # in lockstep, so every token this step produced arrived
+            # in lockstep, so every CHUNK this step produced arrived
             # the same gap after its stream's previous one — one event
-            # carries the shared gap plus the token count.
+            # carries the shared gap plus the chunk count.
             done_at = time.time()
             itl = step_s if self._last_tokens_at is None \
                 else max(0.0, done_at - self._last_tokens_at)
             self._last_tokens_at = done_at
         ds.set_metadata(tokens=produced)
         _obs.record_decode_step(self._dep, step_s, len(active), produced)
-        _obs.record_decode_itl(self._dep, itl, produced)
+        # a chunk's FIRST token came the gap after its stream's last one;
+        # the second token of an accepted pair came with it, no gap apart
+        _obs.record_decode_itl(self._dep, itl, chunks)
+        _obs.record_decode_itl(self._dep, 0.0, produced - chunks)
         if step_span is not None:
             step_span["attributes"]["tokens"] = produced
+            if drafting:
+                step_span["attributes"].update(
+                    drafted=drafted, accepted=accepted)
             tracing.finish_span(step_span)
 
     def _phase_span_locked(self, req: _Request, name: Optional[str],

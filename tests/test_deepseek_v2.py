@@ -775,6 +775,14 @@ LOWERED = {
         "f51bfa1a6e6b246ee6b4732d5732f6b31b5f7973ea2481160110a2bdff97084e",
     ("smallthinker", "prefill"):
         "4b49b998b731ec92566cd01523ded5c351bb0ee26ee1801526436068bb183c4b",
+    # PR 54: the ninth family; its undrafted step, its verify-and-draft
+    # step (what its engine runs) and its chunk (the module's pass in it)
+    ("exaone_moe", "decode"):
+        "3a4b199002ab32e6c5fce00b9aabf1e2a8cf97651785ab6ed7bca63e6eca43af",
+    ("exaone_moe", "verify"):
+        "db132a14a010f4d5bace24c22505d6fb25cbed8246ddeb6e467f111047bdd3bc",
+    ("exaone_moe", "prefill"):
+        "845f58142694409faac7e39d5f7448b2822bab95606b6b9fac193c4796b4b92b",
 }
 
 
@@ -782,13 +790,17 @@ LOWERED = {
 def test_every_familys_programs_are_what_they_were(model, program):
     from ray_tpu.serve.llm_engine import _model_bundle
 
-    cfg, init, init_cache, chunk, step = _model_bundle(model, None, "tiny")
+    cfg, init, init_cache, chunk, step, *verify = _model_bundle(
+        model, None, "tiny")
     params = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: init_cache(cfg, 3, 16))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     if program == "decode":
         text = jax.jit(lambda p, c, t, n: step(p, c, t, n, cfg)).lower(
             params, cache, i32(3), i32(3)).as_text()
+    elif program == "verify":  # a self-drafting family's sixth element
+        text = jax.jit(lambda p, c, t, n: verify[0](p, c, t, n, cfg)).lower(
+            params, cache, i32(3, 2), i32(3)).as_text()
     else:
         text = jax.jit(lambda p, c, t, s, a, n: chunk(
             p, c, t, s, a, n, cfg, window=8)).lower(

@@ -22,8 +22,9 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            llama, nemotron_h, qwen3_next, smallthinker)
+from ray_tpu.models import (deepseek_v2, exaone_moe, falcon_h1, gpt2,
+                            granite_hybrid, llama, nemotron_h, qwen3_next,
+                            smallthinker)
 from ray_tpu.serve import _observability as obs
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve._observability import RequestShedError
@@ -80,6 +81,11 @@ SERVED = {
     # every generation here outlives the window)
     "smallthinker": (smallthinker.SmallThinkerConfig.tiny(**_FP32),
                      smallthinker.smallthinker_forward),
+    # (served by its verify-and-draft step: two rows a slot a step, one or
+    # two tokens a slot; the tokens are the main stack's greedy ones)
+    "exaone_moe": (exaone_moe.ExaoneMoeConfig.tiny(**_FP32),
+                   lambda params, tokens, cfg: exaone_moe.exaone_moe_forward(
+                       params, tokens, cfg)[0]),
 }
 every_family = pytest.mark.parametrize("model", list(SERVED))
 PROMPT = [5, 9, 2, 17, 3]

@@ -414,3 +414,161 @@ def test_a_chunk_that_does_not_divide_the_ring_is_refused():
             jnp.zeros((1, 1, WINDOW, w)), jnp.zeros((1, 5, w)),
             jnp.zeros((1, 5, w)), 0, jnp.zeros(1, jnp.int32),
             jnp.zeros(1, jnp.int32))
+
+
+# -- a verify step: a few consecutive query rows a slot (PR 54) ----------------
+
+# (K-EXAONE's 8 : 1 grouping at a quarter of its 64 : 8 heads: the kernel in
+# interpret mode is slow, and the compile-only file holds the real widths)
+VERIFY_ROWS = {"k-exaone-16:2:128": (16, 2, 128), "many-heads-8:8:32": (8, 8, 32),
+               "falcon-h1-tiny-4:2:16": ROWS["falcon-h1-tiny-4:2:16"]}
+# position of a slot's FIRST new row, a slot each, in rings of LONG_RING
+VERIFY_AT = {
+    "a-free-slot": 0, "inside-the-first-block": 7,
+    "the-pair-ends-a-block": BLOCK - 2, "the-pair-straddles-two-blocks":
+    BLOCK - 1, "the-pair-fills-the-ring": LONG_RING - 2,
+    "the-second-row-wraps-to-row-0": LONG_RING - 1,
+    "a-wrapped-ring": LONG_RING + 5,
+    "wrapped-many-times-the-second-row-wraps": 5 * LONG_RING - 1}
+
+
+@functools.lru_cache(maxsize=None)
+def _verify_step(row, n_rows, rows=2, dtype="float32"):
+    """``rows`` consecutive query rows a slot over a layer of a two-layer
+    stack of rings of ``n_rows`` filled as a context fills them (position p
+    at row ``p mod n_rows``): (the op's result [S, R, H, hd], the plain
+    softmax of query row i at ``pos + i`` over the positions ``pos + i -
+    n_rows < p <= pos + i``, the new rows among them). What the ring holds
+    at the places the new rows will take is the oldest keys of the FIRST
+    row's window where the ring has wrapped, and noise where it has not."""
+    h, g, hd = VERIFY_ROWS[row]
+    dtype = jnp.dtype(dtype)
+    w = ops.merged_row_width(g, hd)
+    pos = np.asarray(list(VERIFY_AT.values()), np.int32)
+    slots, longest = len(pos), int(pos.max()) + rows
+    q = _normal(41, slots, rows, h, hd).astype(dtype)
+    keys = _normal(42, slots, longest, g, hd).astype(dtype)
+    values = _normal(43, slots, longest, g, hd).astype(dtype)
+    k_all = np.array(50.0 + _normal(44, 2, slots, n_rows, g, hd), np.float32)
+    v_all = np.array(-50.0 + _normal(45, 2, slots, n_rows, g, hd),
+                     np.float32)
+    for s, p in enumerate(pos):
+        for at in range(max(0, p - n_rows), p):   # the last n_rows before p
+            k_all[1, s, at % n_rows] = keys[s, at]
+            v_all[1, s, at % n_rows] = values[s, at]
+    new = pos[:, None] + np.arange(rows)[None, :]
+    k_new = jnp.stack([keys[s, new[s]] for s in range(slots)])
+    v_new = jnp.stack([values[s, new[s]] for s in range(slots)])
+    got = ops.cached_verify_attention(
+        q, _merged(jnp.asarray(k_all).astype(dtype), w),
+        _merged(jnp.asarray(v_all).astype(dtype), w), _merged(k_new, w),
+        _merged(v_new, w), jnp.asarray(pos % n_rows),
+        jnp.asarray(np.minimum(pos + 1, n_rows)), jnp.float32, layer=1)
+    at = np.arange(longest)[None, None, :]
+    seen = (at <= new[:, :, None]) & (at > new[:, :, None] - n_rows)
+    return np.asarray(got), _plain(q.astype(jnp.float32), keys, values, seen)
+
+
+@pytest.mark.parametrize("at", list(VERIFY_AT))
+@pytest.mark.parametrize("row", list(VERIFY_ROWS))
+@pytest.mark.parametrize("arm", ["kernel", "xla"])
+def test_a_verify_steps_rows_see_the_ring_and_each_other_causally(arm, row,
+                                                                  at):
+    """``cached_verify_attention``, both arms (rings of whole blocks of
+    whole lane tiles through the kernel of ``ops/ring_decode.py``, its
+    rows' heads stacked; any other ring read whole in XLA), against the
+    plain softmax by position: the second row sees the first's key and its
+    own, the first sees neither the second's key nor loses the key whose
+    place the second will take, whichever side of a block's edge or of the
+    ring's end the pair falls."""
+    h, g, hd = VERIFY_ROWS[row]
+    n_rows = LONG_RING if arm == "kernel" else LONG_RING - 8
+    w = ops.merged_row_width(g, hd)
+    if ring_decode.takes_kernel(n_rows, w) != (arm == "kernel"):
+        pytest.skip("a toy row inside one lane tile keeps the XLA arm")
+    got, plain = _verify_step(row, n_rows)
+    i = list(VERIFY_AT).index(at)
+    assert got.shape == plain.shape == (len(VERIFY_AT), 2, h, hd)
+    np.testing.assert_allclose(got[i], plain[i], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("arm", ["kernel", "xla"])
+def test_three_rows_a_slot_and_one(arm):
+    """The op is written for any few rows: three go as two do, and one row
+    is ``cached_decode_attention`` itself."""
+    n_rows = LONG_RING if arm == "kernel" else LONG_RING - 8
+    got, plain = _verify_step("many-heads-8:8:32", n_rows, rows=3)
+    np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-6)
+    one, plain = _verify_step("many-heads-8:8:32", n_rows, rows=1)
+    np.testing.assert_allclose(one, plain, rtol=2e-5, atol=2e-6)
+
+
+def test_the_verify_kernel_in_bfloat16_is_as_near_as_the_xla_arm():
+    row = "k-exaone-16:2:128"
+    got, plain = _verify_step(row, LONG_RING, dtype="bfloat16")
+    xla, _ = _verify_step(row, LONG_RING - 8, dtype="bfloat16")
+    # (other rings, the same distribution: a bound, not a comparison)
+    assert np.abs(got - plain).max() <= 2 * np.abs(xla - _verify_step(
+        row, LONG_RING - 8, dtype="bfloat16")[1]).max() + 1e-5
+    assert np.abs(plain).max() > 0.1
+
+
+def test_the_verify_step_calls_the_kernel_once_a_ring_of_whole_blocks():
+    ring = jnp.zeros((LAYERS, SLOTS, LONG_RING, 128), jnp.float32)
+    new = jnp.zeros((SLOTS, 2, 128), jnp.float32)
+    cursor = jnp.zeros(SLOTS, jnp.int32)
+    call = lambda k: ops.cached_verify_attention(
+        jnp.zeros((SLOTS, 2, 2, 64)), k, k, new, new, cursor, cursor + 1,
+        jnp.float32, layer=1)
+    assert str(jax.make_jaxpr(call)(ring)).count("pallas_call") == 1
+    assert "pallas_call" not in str(jax.make_jaxpr(call)(ring[:, :, :128]))
+
+
+# -- a chunk of several windows over a ring of one (PR 54) ---------------------
+
+
+@pytest.mark.parametrize("start", [0, 2 * WINDOW, 10 * WINDOW])
+@pytest.mark.parametrize("row", list(WRAPPED_ROWS))
+def test_a_chunk_of_two_windows_sees_each_querys_own_window(row, start):
+    """``wrapped_chunk_attention`` where the chunk is two rings long: a
+    query sees the ring by position (the window before the chunk) and of
+    the chunk's own rows those inside ITS window, not every earlier one."""
+    h, g, hd = WRAPPED_ROWS[row]
+    w = ops.merged_row_width(g, hd)
+    c = 2 * WINDOW
+    q = _normal(51, 1, c, h, hd)
+    keys = _normal(52, 1, start + c, g, hd)
+    values = _normal(53, 1, start + c, g, hd)
+    k_all = np.array(50.0 + _normal(54, LAYERS, 2, WINDOW, g, hd))
+    v_all = np.array(-50.0 + _normal(55, LAYERS, 2, WINDOW, g, hd))
+    for p in range(start):
+        k_all[1, 1, p % WINDOW] = keys[0, p]
+        v_all[1, 1, p % WINDOW] = values[0, p]
+    got = ops.wrapped_chunk_attention(
+        q, _merged(jnp.asarray(k_all), w), _merged(jnp.asarray(v_all), w),
+        _merged(keys[:, start:], w), _merged(values[:, start:], w), 1,
+        jnp.asarray([1]), jnp.asarray([start]))
+    at = start + np.arange(c)[None, :, None]
+    pos = np.arange(start + c)[None, None, :]
+    seen = (pos <= at) & (pos > at - WINDOW)
+    np.testing.assert_allclose(np.asarray(got), _plain(q, keys, values, seen),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("start, real", [
+    (0, 2 * WINDOW), (0, WINDOW + 5), (0, 5), (2 * WINDOW, WINDOW - 1),
+    (4 * WINDOW, 1), (4 * WINDOW, 0)])
+def test_a_long_chunks_write_leaves_the_last_window_of_real_rows(start,
+                                                                 real):
+    """``cache_write_ring_chunk`` for a chunk of two rings: ring row j ends
+    up with the LAST real position that lies there, the chunk's where it
+    has one and what the ring held where it has not."""
+    cache = _normal(61, LAYERS, SLOTS, WINDOW, 32)
+    rows = _normal(62, LAYERS, 1, 2 * WINDOW, 32)
+    got = np.asarray(ops.cache_write_ring_chunk(
+        cache, rows, jnp.asarray([1]), jnp.asarray([start]),
+        jnp.asarray([real])))
+    want = np.array(cache)
+    for i in range(real):    # in order: later positions overwrite
+        want[:, 1, (start + i) % WINDOW] = np.asarray(rows)[:, 0, i]
+    np.testing.assert_array_equal(got, want)

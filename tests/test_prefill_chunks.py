@@ -320,6 +320,7 @@ PUBLISHED = {
     "qwen3_next": ("qwen3-next-80b-a3b-instruct", "qwen3next_1chip_b64"),
     "smallthinker": ("smallthinker-21b-a3b-instruct",
                      "smallthinker_1chip_b48"),
+    "exaone_moe": ("k-exaone-236b-a23b", "kexaone_1chip_b64"),
 }
 
 
@@ -355,6 +356,7 @@ def _case(model, chunk, sizes="published", **engine):
         "experts-deepseek-v2": _case("deepseek_v2", 512),
         "experts-qwen3-next": _case("qwen3_next", 512),
         "experts-smallthinker": _case("smallthinker", 512),
+        "experts-exaone-moe": _case("exaone_moe", 512),
         "experts-tiny": _case("granite_hybrid", 512, "tiny",
                               max_prompt_len=700, cache_len=1024),
         # no longer than the longest prompt
