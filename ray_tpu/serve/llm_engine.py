@@ -27,6 +27,13 @@ engine, mounted as an ordinary Serve deployment callable:
   What is served is token for token what the family serves undrafted;
   ``max_tokens`` and the end token cut inside a pair. The other
   families run the programs and the host path they always ran.
+* **One step ahead.** The loop enqueues decode step n + 1 before it reads
+  step n: the step's tokens stay on the device (step n's output, with the
+  first tokens of requests admitted since put into their slots, also from
+  the device), so the chip does not wait for the read, the fan-out and
+  the runtime's hand-over between two steps. At most one step is
+  dispatched and unread; its fan-out belongs to the requests it was
+  dispatched FOR (``_loop``).
 * **Slot-indexed ring KV-cache in device memory.** Per-slot write
   cursors via ``lax.dynamic_update_slice``; the cache rides the model's
   activation dtype (bf16 — no fp32 copy) and, for Llama, the GQA
@@ -135,8 +142,8 @@ class _Poller:
 
 class _Request:
     __slots__ = ("rid", "prompt", "max_new", "deadline_ts", "submitted",
-                 "remaining", "retries", "stream", "seq", "trace_ctx",
-                 "span")
+                 "remaining", "unread", "retries", "stream", "seq",
+                 "trace_ctx", "span")
 
     def __init__(self, rid: str, prompt: List[int], max_new: int,
                  deadline_ts: Optional[float], seq: int,
@@ -147,6 +154,11 @@ class _Request:
         self.deadline_ts = deadline_ts
         self.submitted = time.time()
         self.remaining = max_new
+        # Results dispatched for it and not fanned out yet (its prompt's
+        # last chunk, a decode step's row): each yields a token at least,
+        # so it is owed a further step while ``remaining > unread``. The
+        # loop's thread alone reads and writes it.
+        self.unread = 0
         self.retries = 0
         self.stream = _Stream()
         self.seq = seq  # FIFO tiebreak for slack ordering
@@ -160,6 +172,31 @@ class _Request:
         # lock; every terminal path closes via _finish_locked.
         self.trace_ctx = trace_ctx
         self.span: Optional[dict] = None
+
+
+class _Step:
+    """A decode step on the device and not read yet. ``out`` is the one
+    array the step hands back, ``rows`` the ``(slot, request)`` pairs it
+    was dispatched FOR: its fan-out is theirs, whoever holds the slots when
+    it is read. ``ahead``: another step was unread when this one was
+    dispatched."""
+
+    __slots__ = ("out", "rows", "ahead")
+
+    def __init__(self, out, rows: List[tuple], ahead: bool):
+        self.out, self.rows, self.ahead = out, rows, ahead
+
+
+class _Firsts:
+    """An admission turn's results on the device and not read yet:
+    ``rows`` holds ``(slot, request, the last chunk's token array)`` of
+    each admitted request, ``counted`` what the programs had counted in
+    the cache once the turn's last chunk ran (one array, or None)."""
+
+    __slots__ = ("rows", "counted")
+
+    def __init__(self, rows: List[tuple], counted):
+        self.rows, self.counted = rows, counted
 
 
 def _model_bundle(model: str, config, preset: str):
@@ -391,10 +428,6 @@ class LLMEngine:
         # ONE array the step syncs on, and add up in stats_counters; a
         # family that returns none runs the program it always ran.
         self._step_counters: tuple = ()
-        # What a family's programs count in the cache itself (the leaves
-        # of ``cache["counted"]``, int32 scalars that never stop rising
-        # and so wrap): the last value read of each (_prefill_batch).
-        self._counted_seen: Dict[str, int] = {}
         # What the model says of itself beside its counters (llm_stats).
         self._model_stats = dict(
             getattr(cfg, "serving_stats", lambda: {})())
@@ -453,6 +486,34 @@ class LLMEngine:
                 return (self._jnp.argmax(logits, axis=-1).astype(
                     self._jnp.int32), cache)
 
+        n_slots = self.max_batch + 1
+
+        def carry_fn(out, pos, ahead):
+            # What the next step is handed of every slot, from the last
+            # step's ``out`` as it lies on the device: the slot's newest
+            # token (and its draft, where the family drafts) and its
+            # position. ``pos`` is the host's, which stands where the
+            # last step it READ left it; ``ahead`` marks the rows of the
+            # unread step, which are past it by what that step yielded: one
+            # token, or what ``served [S, 4]`` says the device accepted.
+            if not self._drafting:
+                return out[:n_slots], pos + ahead
+            served = out[:4 * n_slots].reshape(n_slots, 4)
+            newest = self._jnp.where(served[:, 0] > 1, served[:, 2],
+                                     served[:, 1])
+            return (self._jnp.stack([newest, served[:, 3]], axis=1),
+                    pos + ahead * served[:, 0])
+
+        def put_fn(tokens, tok, slot):
+            # an admitted request's first token (and first draft) into its
+            # slot, from the chunk program's result on the device
+            return tokens.at[slot].set(tok.reshape(tokens.shape[1:]))
+
+        def counted_fn(counted):
+            return self._jnp.stack(
+                [counted[k] for k in self._counted_keys]).astype(
+                    self._jnp.int32)
+
         # Donate the cache: the engine holds the ONLY reference and the
         # step replaces it, so XLA can update in place (2x HBM saved on
         # the big buffer). CPU test runs warn that donation was unused.
@@ -464,17 +525,39 @@ class LLMEngine:
             step_fn, prefill_fn = verify_fn, draft_prefill_fn
         self._step_fn = jax.jit(step_fn, donate_argnums=(1,))
         self._prefill_fn = jax.jit(prefill_fn, donate_argnums=(1,))
+        # The three helpers that keep a step's inputs on the device: a few
+        # int32 operations each, none of them the engine's two programs
+        # (``compiles`` counts those alone).
+        self._carry_fn = jax.jit(carry_fn)
+        self._put_fn = jax.jit(put_fn)
+        self._counted_fn = jax.jit(counted_fn)
+        # What a family's programs count in the cache itself (the leaves
+        # of ``cache["counted"]``, int32 scalars that never stop rising
+        # and so wrap): their names, and the last value read of each
+        # (``_first_fanout``).
+        self._counted_keys = tuple(sorted(self._cache.get("counted", {})))
+        self._counted_seen: Dict[str, int] = {}
 
-        # What a step is handed of each slot: its newest token and, where
-        # the family drafts, its draft for the position after, side by side
-        # ([S, 2]: ``_tokens`` and ``_draft`` are its columns).
-        self._step_in = np.zeros(
-            (self.max_batch + 1, 2) if self._drafting
-            else self.max_batch + 1, np.int32)
-        self._tokens = self._step_in[:, 0] if self._drafting \
-            else self._step_in
-        self._draft = self._step_in[:, 1] if self._drafting else None
-        self._pos = np.zeros(self.max_batch + 1, np.int32)
+        # A step is handed, of each slot, its newest token (and, where the
+        # family drafts, its draft for the position after, side by side:
+        # [S, 2]) and its position. The tokens never come to the host on
+        # their way from one step to the next: they are ``carry_fn`` of
+        # the newest step's output (``_out``, read or not) with
+        # ``_fresh``, the first tokens of the slots admitted since that
+        # step was dispatched, put over it. Before the first step there is
+        # no output, and the slots' tokens are these zeros.
+        self._no_tokens = self._jnp.zeros(
+            (n_slots, 2) if self._drafting else n_slots, self._jnp.int32)
+        self._out = None
+        self._fresh: Dict[int, object] = {}
+        # What is dispatched and not read, in the device's order: at most
+        # one decode step (``_Step``) and the admission turns behind it
+        # (``_Firsts``). Only the loop's thread touches it (and
+        # ``shutdown_engine``, once that thread has ended).
+        self._outstanding: List[object] = []
+        # A slot's position as of the last result READ for it (a freed
+        # slot's is 0, an admitted one's its prompt's length).
+        self._pos = np.zeros(n_slots, np.int32)
         self._slot_req: List[Optional[_Request]] = [None] * self.max_batch
         # Admission queue: a HEAP keyed (deadline slack, seq) — the 10k
         # flagship load would pay an O(n log n) re-sort per scheduler
@@ -504,21 +587,24 @@ class LLMEngine:
         self._last_tokens_at: Optional[float] = None
         # Enqueue first, wake later. A decode step's token is in its
         # stream's ``pending`` (under the lock) when ``_step_fanout``
-        # returns, but the poller of a stream that goes on decoding is
-        # told only once the device has its next program: every woken
-        # long-poll wants the lock and the interpreter, and ahead of the
-        # loop's own dispatch they kept the device waiting for it. The
-        # streams owed a wake-up wait here; only the loop's thread
-        # touches the list (``_flush_wakes``). Why no order of set, drain
-        # and clear loses a token or delivers one twice: the token is in
-        # ``pending`` under the lock BEFORE its event can be set, and a
-        # long-poll (``_drain``) drains and clears under the same lock;
-        # so a set that comes late finds either the token still pending
-        # (the woken poll takes it) or already drained by a poll that
-        # timed out or was woken for a neighbour token (the woken poll
-        # returns no chunk, as a time-out does, and its caller goes round
-        # again). A poller's call drains ALL its streams, so it is told
-        # of a put-off token only where one is still pending.
+        # appends it, and the poller of a stream that goes on decoding is
+        # told at the tail of that turn's fan-out, outside the lock: by
+        # then the device already has its next program, because the turn
+        # enqueued the step after this one BEFORE it read this one. Every
+        # woken long-poll wants the lock and the interpreter; woken ahead
+        # of the loop's dispatch they once kept the device waiting for it,
+        # now they share the host with a turn the device does not wait
+        # for. The streams of one fan-out owed a wake-up wait here; only
+        # the loop's thread touches the list (``_flush_wakes``). Why no
+        # order of set, drain and clear loses a token or delivers one
+        # twice: the token is in ``pending`` under the lock BEFORE its
+        # event can be set, and a long-poll (``_drain``) drains and clears
+        # under the same lock; so a set that comes late finds either the
+        # token still pending (the woken poll takes it) or already drained
+        # by a poll that timed out or was woken for a neighbour token (the
+        # woken poll returns no chunk, as a time-out does, and its caller
+        # goes round again). A poller's call drains ALL its streams, so it
+        # is told of a put-off token only where one is still pending.
         self._wakes: List[_Stream] = []
         # when the fan-out that filled ``_wakes`` made its tokens visible
         self._fanout_ns = 0
@@ -527,6 +613,13 @@ class LLMEngine:
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
             "errors": 0, "tokens_out": 0, "queue_peak": 0,
             "occupancy_sum": 0, "ring_wraps": 0,
+            # The step ahead: steps dispatched while another was
+            # dispatched and unread (of ``steps``; both counted where the
+            # step is read), and slot-rows a step computed for a request
+            # that had ended, been cancelled or been shed before the step
+            # was read (such a row is dropped, and is not in
+            # ``occupancy_sum``, the rows that served a live request).
+            "steps_ahead": 0, "rows_dropped": 0,
             # A drafting family's steps: drafts verified (one a slot a
             # step) and those the device's comparison accepted, each of
             # which made its step yield a second token. tokens_out over
@@ -541,11 +634,11 @@ class LLMEngine:
             "prefill_batches": 0, "prefill_rows_real": 0,
             "prefill_tokens_real": 0, "prefill_tokens_lane": 0,
             "prefill_chunks": 0,
-            # Wake-ups of decode steps put off until after the next
-            # enqueue (a stream a step that goes on decoding), and how
+            # Wake-ups of decode steps put off to the tail of their
+            # fan-out (a stream a step that goes on decoding), and how
             # many of them were then set with a program on the device;
-            # the rest were set because nothing followed (a step that
-            # raised before its enqueue, a throttle, the idle wait).
+            # the rest were set with nothing behind their step (the
+            # next step's dispatch raised, a throttle sleeps next).
             # Both are counted where the wake-ups are set, in one write
             # (``_flush_wakes``), so no snapshot reads them a step apart.
             "wakes_deferred": 0, "wakes_after_dispatch": 0,
@@ -579,22 +672,42 @@ class LLMEngine:
     # -- scheduler loop ----------------------------------------------------
 
     def _loop(self):  # jax-hot-path
-        """The scheduler: admit, step, go round. A token is VISIBLE the
-        moment ``_step_fanout`` (or ``_prefill_batch``) appends it to its
-        stream under the lock: every drain (``llm_next``, ``llm_poll``)
-        sees it from then on. Its poller is TOLD (the stream's event set,
-        which is its poller's where it was submitted under one: one
-        blocked call for all of a client process's streams) at once for a
-        first token and a terminal transition,
-        and for a decode step's token only after the next enqueue: once
-        the next step's ``_step_fn`` call has returned or an admission
-        turn's first chunk is dispatched, whichever comes first; and
-        where no program follows: when a step raises before its enqueue,
-        before ``step_throttle_s`` sleeps, before the idle wait. A turn
-        whose admission raised goes on to its step, and a request the
-        stop, a cancel or a shed ends is woken by its terminal
-        transition, so a token's wake-up never waits longer than one
-        turn's host work."""
+        """The scheduler, one step ahead of what it reads. A turn
+        (``_step_once``, then ``_admit_once``):
+
+        1. *select*: deadline eviction, then the rows of the next step:
+           the slots whose request is live and still owed a token AFTER
+           what is dispatched and unread for it (by count: ``remaining``
+           over ``unread``; an end token the host does not know yet);
+        2. *dispatch* step n + 1: its tokens are step n's output ON THE
+           DEVICE, with the first tokens of the requests admitted since
+           step n was dispatched put into their slots, also from the
+           device; its positions are the host's, and the device adds what
+           the unread step n moved a row by;
+        3. *sync*: read step n, which the device ended about a step ago
+           or is ending, then the first tokens of the requests whose
+           chunks were dispatched last turn;
+        4. *fanout* of step n and of those first tokens; then admission:
+           the chunks go to the device BEHIND step n + 1, and their first
+           token is read in the next turn's sync.
+
+        So at any moment at most one decode step is dispatched and
+        unread. A turn that finds nothing to enqueue reads what is
+        outstanding before the loop waits; a dispatch that raises, a
+        ``step_throttle_s`` that sleeps next and the loop's end leave
+        nothing outstanding and unread (``_step_once`` reads the turn's
+        own step too where nothing may stay behind).
+
+        A token is VISIBLE the moment a fan-out appends it to its stream
+        under the lock: every drain (``llm_next``, ``llm_poll``) sees it
+        from then on. Its poller is TOLD (the stream's event set, which is
+        its poller's where it was submitted under one: one blocked call
+        for all of a client process's streams) at once for a first token
+        and a terminal transition, and for a decode step's token at the
+        tail of its fan-out, outside the lock (``_flush_wakes``): the
+        device has the step after it by then. A request the stop, a
+        cancel or a shed ends is woken by its terminal transition, so no
+        wake-up waits for anything but its own turn's fan-out."""
         while not self._stop:
             did = False
             try:
@@ -615,21 +728,25 @@ class LLMEngine:
                 with tracing.device_span("llm.loop.reap"):
                     self._reap_streams()
             if not did:
-                # an idle engine owes nobody (every stream still on the
-                # list has ended, which woke it: this empties the list)
-                self._flush_wakes(enqueued=False)
                 with tracing.device_span("llm.loop.wait"):
                     self._wake.wait(0.02)
                 self._wake.clear()
+        try:
+            # the loop ends with nothing dispatched and unread: what the
+            # device still holds is delivered before the streams are ended
+            self._step_once(settle=True)
+        except BaseException:
+            _metrics.count_loop_restart("llm.engine")
 
     def _flush_wakes(self, enqueued: bool) -> None:
-        """Set the event of every stream the last decode step put off
+        """Set the event of every stream the fan-out just made put off
         (loop thread only): its own, or ONCE its poller's, however many
         of the poller's streams are owed, and only where a token still
         waits (a call woken since for a first token or an ended
         neighbour took them all: only this thread appends, so what reads
-        empty here stays empty). ``enqueued``: a program was handed to
-        the device since, which is what the wake-ups waited for."""
+        empty here stays empty). ``enqueued``: the device holds a program
+        behind the step that was fanned out, which is what the wake-ups
+        were put off for."""
         wakes = self._wakes
         if not wakes:
             return
@@ -740,16 +857,19 @@ class LLMEngine:
         return True
 
     def _prefill_batch(self, batch: List[_Request], slots: List[int]):  # jax-hot-path
-        """Every admitted request's prompt, whole, before the next decode
-        step: ``ceil(len / prefill_chunk)`` executions of the one chunk
-        program each, dispatched back to back (the device runs them in
-        order on the cache each hands the next), then one sync on each
-        request's last chunk's token, in admission order."""
+        """Every admitted request's prompt, whole, behind whatever the
+        device already has: ``ceil(len / prefill_chunk)`` executions of
+        the one chunk program each, dispatched back to back (the device
+        runs them in order on the cache each hands the next). Nothing is
+        read here: each request's last chunk's token stays on the device,
+        where the next step takes it from (``_fresh``), and comes to the
+        host in the next turn's sync (``_Firsts``). From here on the
+        requests hold their slots."""
         np = self._np
         chunk = self.prefill_chunk
         t0 = time.perf_counter()
         with tracing.device_span("llm.prefill.dispatch") as ds:
-            first, lengths, n_chunks = [], [], 0
+            rows, lengths, n_chunks = [], [], 0
             for req, slot in zip(batch, slots):
                 # truncate to the longest prompt the slot's rows hold
                 prompt = req.prompt[-self.max_prompt_len:]
@@ -766,61 +886,73 @@ class LLMEngine:
                     tok, self._cache = self._prefill_fn(
                         self.params, self._cache, packed)
                     n_chunks += 1
-                    # the device has work: tell the last step's streams
-                    # (after the turn's FIRST chunk; a no-op from then on)
-                    self._flush_wakes(enqueued=True)
-                first.append(tok)  # the last chunk's
+                rows.append((slot, req, tok))  # the last chunk's
+            # What the programs have counted in the cache up to here (a
+            # sparse model's token-expert pairs; most families: none),
+            # copied out while the cache is this turn's: the next step
+            # donates it, and a leaf of the cache it hands back would make
+            # the read wait for that step.
+            counted = self._counted_fn(self._cache["counted"]) \
+                if self._counted_keys else None
             tokens_real = sum(lengths)
             ds.set_metadata(rows=len(batch), tokens_real=tokens_real,
                             chunks=n_chunks)
-        with tracing.device_span("llm.prefill.sync"):
-            # The one intentional sync per request: first tokens must
-            # reach the streams now.  # analyze: ignore[JX002]
-            first = [np.asarray(tok) for tok in first]  # analyze: ignore[JX002]
-            # what the programs have counted in the cache up to here
-            # (a sparse model's token-expert pairs; most families: none)
-            counted = {key: int(np.asarray(n)) for key, n in  # analyze: ignore[JX002]
-                       self._cache.get("counted", {}).items()}
         self._init_s.setdefault("first_prefill", time.perf_counter() - t0)
+        with self._lock:
+            c = self.stats_counters
+            c["prefill_batches"] += 1
+            c["prefill_rows_real"] += len(batch)
+            c["prefill_tokens_real"] += tokens_real
+            c["prefill_chunks"] += n_chunks
+            c["prefill_tokens_lane"] += n_chunks * chunk
+            for (slot, req, tok), length in zip(rows, lengths):
+                self._slot_req[slot] = req
+                self._pos[slot] = length
+                self._fresh[slot] = tok
+                req.unread += 1
+        self._outstanding.append(_Firsts(rows, counted))
+
+    def _first_fanout(self, firsts: _Firsts, toks: list, counted) -> int:
+        """An admission turn's first tokens to their streams (read in the
+        turn after the one that dispatched their chunks), and what the
+        chunk programs counted; returns the tokens handed out. A request
+        that was cancelled or shed meanwhile no longer holds its slot: its
+        token is dropped."""
         now = time.time()
-        with tracing.device_span("llm.prefill.fanout"):
-            _obs.record_decode_tokens(self._dep, len(batch))
-            with self._lock:
-                visible_ns = time.perf_counter_ns()
-                c = self.stats_counters
-                c["prefill_batches"] += 1
-                c["prefill_rows_real"] += len(batch)
-                c["prefill_tokens_real"] += tokens_real
-                c["prefill_chunks"] += n_chunks
-                c["prefill_tokens_lane"] += n_chunks * chunk
-                for key, n in counted.items():
-                    c[key] = c.get(key, 0) + (
-                        n - self._counted_seen.get(key, 0)) % 2 ** 32
-                self._counted_seen = counted
-                for i, req in enumerate(batch):
-                    slot = slots[i]
-                    tok = int(first[i][0])
-                    self._tokens[slot] = tok
-                    if self._drafting:
-                        self._draft[slot] = int(first[i][1])
-                    self._pos[slot] = lengths[i]
-                    self._slot_req[slot] = req
-                    req.remaining = req.max_new - 1
-                    c["admitted"] += 1
-                    c["tokens_out"] += 1
-                    req.stream.n_tokens += 1
-                    req.stream.visible_ns = visible_ns  # its first chunk
-                    req.stream.pending.append([tok])
-                    req.stream.event.set()
-                    # TTFT: submit -> first token available for delivery.
-                    _obs.record_ttft(self._dep, max(0.0, now - req.submitted))
-                    # First token exists: prefill phase ends HERE (the TTFT
-                    # decomposition keys on the prefill span's end), decode
-                    # phase runs until the terminal transition.
-                    self._phase_span_locked(req, "llm.decode")
-                    if req.remaining <= 0 or tok == self.eos_token:
-                        self._finish_locked(req, done=True, slot=slot)
-                self._last_tokens_at = now
+        delivered = 0
+        with self._lock:
+            visible_ns = time.perf_counter_ns()
+            c = self.stats_counters
+            for key, n in zip(self._counted_keys,
+                              () if counted is None else counted):
+                n = int(n)
+                c[key] = c.get(key, 0) + (
+                    n - self._counted_seen.get(key, 0)) % 2 ** 32
+                self._counted_seen[key] = n
+            for (slot, req, _), first in zip(firsts.rows, toks):
+                req.unread -= 1
+                if self._slot_req[slot] is not req:
+                    continue
+                tok = int(first[0])
+                delivered += 1
+                req.remaining -= 1
+                c["admitted"] += 1
+                c["tokens_out"] += 1
+                req.stream.n_tokens += 1
+                req.stream.visible_ns = visible_ns  # its first chunk
+                req.stream.pending.append([tok])
+                req.stream.event.set()
+                # TTFT: submit -> first token available for delivery.
+                _obs.record_ttft(self._dep, max(0.0, now - req.submitted))
+                # First token exists: prefill phase ends HERE (the TTFT
+                # decomposition keys on the prefill span's end), decode
+                # phase runs until the terminal transition.
+                self._phase_span_locked(req, "llm.decode")
+                if req.remaining <= 0 or tok == self.eos_token:
+                    self._finish_locked(req, done=True, slot=slot)
+            self._last_tokens_at = now
+        _obs.record_decode_tokens(self._dep, delivered)
+        return delivered
 
     def _log_first_failure(self, what: str) -> None:
         """Call from an ``except`` block: logs the active traceback the
@@ -830,73 +962,117 @@ class LLMEngine:
             logger.exception("llm engine %s failed (first failure; later "
                              "ones are only counted)", what)
 
-    def _step_once(self) -> bool:  # jax-hot-path  # step-timed
+    def _step_once(self, settle: bool = False) -> bool:  # jax-hot-path  # step-timed
+        """One turn's select, dispatch, sync and fanout (``_loop`` has the
+        order and why). ``settle``: dispatch nothing, read everything (the
+        loop's last act)."""
         np = self._np
+        outstanding = self._outstanding
         with tracing.device_span("llm.step.select") as ds, self._lock:
             now = time.time()
             # Deadline eviction happens at the step boundary: the slot
-            # frees NOW, before compute, and the shed is typed.
+            # frees NOW, before the next step is enqueued, and the shed
+            # is typed.
             for slot in range(self.max_batch):
                 req = self._slot_req[slot]
                 if req is not None and req.deadline_ts is not None \
                         and now > req.deadline_ts:
                     self._finish_locked(req, shed="decode", slot=slot)
-            active = [i for i in range(self.max_batch)
-                      if self._slot_req[i] is not None]
-            ds.set_metadata(occupancy=len(active))
-            if not active:
+            # owed a token after all that is dispatched and unread for it
+            rows = [] if settle else [
+                (slot, req) for slot, req in enumerate(self._slot_req)
+                if req is not None and req.remaining > req.unread]
+            ds.set_metadata(occupancy=len(rows))
+            if not rows and not outstanding:
                 return False
-            # Per-decode-step span: ONE per engine step (not one per
-            # traced request per step — that would square the span
-            # volume), parented under the oldest traced request's
-            # decode span so it lands inside a real trace.
+            # The rows of the unread step that are still their request's
+            # stand one step past the host's position (a slot that changed
+            # hands since stands where its new holder's prompt ends).
+            unread = next((d for d in outstanding
+                           if isinstance(d, _Step)), None)
+            ahead = np.zeros(self.max_batch + 1, np.int32)
+            for slot, req in unread.rows if unread else ():
+                if self._slot_req[slot] is req:
+                    ahead[slot] = 1
+            # Per-turn span: ONE per engine step (not one per traced
+            # request per step — that would square the span volume),
+            # parented under the oldest traced request's decode span so
+            # it lands inside a real trace. It runs from the turn's
+            # enqueue to the fan-out of the step the turn READ, and is
+            # recorded where the turn read one.
             step_parent = None
-            for slot in active:
-                req = self._slot_req[slot]
-                if req is not None and req.span is not None and (
+            for _, req in (*rows, *(unread.rows if unread else ())):
+                if req.span is not None and (
                         step_parent is None
                         or req.submitted < step_parent[0]):
                     step_parent = (req.submitted, req.span)
         step_span = tracing.start_span(
-            "llm.step", {"occupancy": len(active)},
+            "llm.step", {"occupancy": len(rows)},
             parent={"trace_id": step_parent[1]["trace_id"],
                     "span_id": step_parent[1]["span_id"]},
             cat="llm") if step_parent is not None else None
         t0 = time.perf_counter()
+        failed = None
+        if rows:
+            try:
+                # The failpoint lives INSIDE the error-counted region: a
+                # raise-armed before_step must trip the 3-strike fail-fast
+                # (streams error out), not silently skip every step while
+                # the site stays armed — that would be the hang the
+                # never-hang contract forbids.
+                failpoints.hit("serve.llm.before_step")
+                # epoch_ns ties this thread's clock (time.time_ns, the span
+                # store's) to the profile's: a reader takes the offset as
+                # the median over these anchors.
+                with tracing.device_span("llm.step.dispatch",
+                                         epoch_ns=time.time_ns()):
+                    self._dispatch(rows, ahead, unread is not None)
+            except BaseException as e:  # noqa: BLE001 — re-raised below
+                # the step already on the device keeps its tokens: this
+                # turn reads and delivers all that is outstanding
+                failed = e
+                self._log_first_failure("decode step")
+        # What this turn reads: all that was enqueued before its own step,
+        # and that step too where nothing may stay behind (no step follows
+        # for a while).
+        keep = 1 if rows and failed is None and not self.step_throttle_s \
+            else 0
+        due = outstanding[:len(outstanding) - keep]
         try:
-            # The failpoint lives INSIDE the error-counted region: a
-            # raise-armed before_step must trip the 3-strike fail-fast
-            # (streams error out), not silently skip every step while
-            # the site stays armed — that would be the hang the
-            # never-hang contract forbids.
-            failpoints.hit("serve.llm.before_step")
-            # epoch_ns ties this thread's clock (time.time_ns, the span
-            # store's) to the profile's: a reader takes the offset as
-            # the median over these anchors.
-            with tracing.device_span("llm.step.dispatch",
-                                     epoch_ns=time.time_ns()):
-                nxt, self._cache = self._step_fn(
-                    self.params, self._cache,
-                    self._jnp.asarray(self._step_in),
-                    self._jnp.asarray(self._pos))
             with tracing.device_span("llm.step.sync"):
-                # The device has this step: wake the LAST step's streams
-                # now, so their pollers take the lock and the interpreter
-                # while the sync below waits (at the head of this span,
-                # not in one of its own: a turn stays select, dispatch,
-                # sync, fanout, and the host's share of it, select +
-                # dispatch + fanout, does not hold the wake-ups).
-                self._flush_wakes(enqueued=True)
-                # The one intentional sync per decode step (tokens fan
-                # out to streams from host memory).
-                nxt = np.asarray(nxt)  # analyze: ignore[JX002]
-        except BaseException as e:
-            # A raise before the enqueue (an armed before_step, a step
-            # that fails to dispatch) makes nobody wait for the next
-            # attempt; after the enqueue this finds the list empty.
-            self._flush_wakes(enqueued=False)
+                # The intentional syncs of a turn (tokens fan out to
+                # streams from host memory): the device ended these about
+                # a step ago.
+                read = []
+                for d in due:
+                    try:
+                        read.append(self._sync(d))
+                    except BaseException as e:  # noqa: BLE001 — its rows fail
+                        read.append(e)
+                        if failed is None:
+                            failed = e
+                            self._log_first_failure("decode step")
+            step_s = time.perf_counter() - t0
+            if rows and failed is None:
+                self._init_s.setdefault("first_step", step_s)
+            with tracing.device_span("llm.step.fanout") as ds:
+                tokens = 0
+                for d, host in zip(due, read):
+                    if isinstance(host, BaseException):
+                        self._lost_fanout(d, host)
+                    elif isinstance(d, _Step):
+                        tokens += self._step_fanout(d, host, step_s,
+                                                    step_span)
+                        step_span = None  # recorded with its tokens
+                    else:
+                        tokens += self._first_fanout(d, *host)
+                ds.set_metadata(tokens=tokens)
+                self._flush_wakes(enqueued=keep > 0)
+        finally:
+            # outstanding until handed out (``llm_stats()`` says so)
+            del outstanding[:len(due)]
+        if failed is not None:
             tracing.finish_span(step_span, "ERROR: step")
-            self._log_first_failure("decode step")
             self._step_errors_row += 1
             self.stats_counters["errors"] += 1
             if self._step_errors_row >= _MAX_STEP_ERRORS:
@@ -906,27 +1082,70 @@ class LLMEngine:
                         if req is not None:
                             self._finish_locked(
                                 req, error="decode step failing "
-                                f"repeatedly: {e!r}", slot=slot)
+                                f"repeatedly: {failed!r}", slot=slot)
                 self._step_errors_row = 0
-            raise
+            raise failed
         self._step_errors_row = 0
-        step_s = time.perf_counter() - t0
-        self._init_s.setdefault("first_step", step_s)
-        with tracing.device_span("llm.step.fanout") as ds:
-            self._step_fanout(active, nxt, step_s, step_span, ds)
-        if self.step_throttle_s:
-            self._flush_wakes(enqueued=False)  # nothing follows for a while
+        if self.step_throttle_s and not settle:
             time.sleep(self.step_throttle_s)
         return True
 
-    def _step_fanout(self, active: List[int], nxt, step_s: float,
-                     step_span: Optional[dict], ds) -> None:
-        """After the step's sync: tokens to their streams under the lock,
-        then the step's metrics and store span (all of it is the
-        ``llm.step.fanout`` device span ``ds``). A token is visible
-        to every drain from its append here. A stream that ends with it
-        is woken here (``_finish_locked``); one that goes on decoding is
-        put on ``_wakes`` and woken after the next enqueue (``_loop``).
+    def _dispatch(self, rows: List[tuple], ahead, behind: bool) -> None:  # jax-hot-path
+        """Enqueue a decode step for ``rows``. Every live slot is handed
+        exactly the token and position a loop that read each step before
+        the next would hand it; what a free slot or the spare slot is
+        handed (a stale token, position 0) no one reads. ``behind``: an
+        unread step lies before this one."""
+        # A COPY of the positions: the runtime may read a host array it
+        # was handed only when the program runs, behind the unread step,
+        # and this turn's fan-out moves ``_pos`` before that.
+        pos = self._pos.copy()
+        if self._out is None:
+            tokens, pos = self._no_tokens, self._jnp.asarray(pos)
+        else:
+            tokens, pos = self._carry_fn(self._out, pos, ahead)
+        # in program order after each admitted slot's last chunk
+        for slot, tok in self._fresh.items():
+            tokens = self._put_fn(tokens, tok, self._np.int32(slot))
+        out, self._cache = self._step_fn(self.params, self._cache,
+                                         tokens, pos)
+        self._fresh.clear()
+        self._out = out
+        for _, req in rows:
+            req.unread += 1
+        self._outstanding.append(_Step(out, rows, behind))
+
+    def _sync(self, d):
+        """Bring a dispatched result to the host (the turn's sync)."""
+        np = self._np
+        if isinstance(d, _Step):
+            return np.asarray(d.out)  # analyze: ignore[JX002]
+        return ([np.asarray(tok) for _, _, tok in d.rows],  # analyze: ignore[JX002]
+                None if d.counted is None else np.asarray(d.counted))  # analyze: ignore[JX002]
+
+    def _lost_fanout(self, d, error: BaseException) -> None:
+        """A result the device could not hand over: its tokens are lost,
+        so the requests it was dispatched for end with the error (a stream
+        that went on would lack a token)."""
+        with self._lock:
+            for slot, req, *_ in d.rows:
+                req.unread -= 1
+                if self._slot_req[slot] is req:
+                    self._finish_locked(
+                        req, error=f"decode step lost: {error!r}",
+                        slot=slot)
+
+    def _step_fanout(self, step: _Step, nxt, step_s: float,
+                     step_span: Optional[dict]) -> int:
+        """After the sync: the step's tokens to the streams of the
+        requests it was dispatched FOR, under the lock, then the step's
+        metrics and store span (inside the ``llm.step.fanout`` device
+        span); returns the tokens handed out. A slot whose request ended, was cancelled or was shed
+        since the dispatch (it may hold a NEW request by now) has its row
+        dropped. A token is visible to every drain from its append here.
+        A stream that ends with it is woken here (``_finish_locked``); one
+        that goes on decoding is put on ``_wakes`` and woken at the tail
+        of this fan-out (``_step_once``).
 
         A drafting family's step yields ONE chunk of one or two tokens a
         slot (``served [S, 4]`` at the head of ``nxt``): the position moves
@@ -934,23 +1153,22 @@ class LLMEngine:
         or the end token falls inside a pair."""
         drafting = self._drafting
         with self._lock:
-            produced = chunks = drafted = accepted = 0
+            produced = chunks = drafted = accepted = dropped = 0
             # the one clock read of this fan-out's tokens
             self._fanout_ns = visible_ns = time.perf_counter_ns()
-            for slot in active:
-                req = self._slot_req[slot]
-                if req is None:
-                    continue  # cancelled while the step was in flight
+            for slot, req in step.rows:
+                req.unread -= 1
+                if self._slot_req[slot] is not req:
+                    dropped += 1
+                    continue
                 if drafting:
                     n = int(nxt[4 * slot])
                     toks = [int(t) for t in
                             nxt[4 * slot + 1:4 * slot + 1 + n]]
-                    self._draft[slot] = int(nxt[4 * slot + 3])
                     drafted += 1
                     accepted += n - 1
                 else:
                     toks = [int(nxt[slot])]
-                self._tokens[slot] = toks[-1]
                 was = int(self._pos[slot])
                 self._pos[slot] = now = was + len(toks)
                 # a wrap is the position CROSSING a multiple of the ring
@@ -970,17 +1188,20 @@ class LLMEngine:
                 if req.remaining <= 0 or toks[-1] == self.eos_token:
                     self._finish_locked(req, done=True, slot=slot)
                 else:
-                    # (the list was emptied when this step was enqueued)
                     self._wakes.append(req.stream)
-            self.stats_counters["steps"] += 1
+            c = self.stats_counters
             counters_at = (self.max_batch + 1) * (4 if drafting else 1)
             for i, key in enumerate(self._step_counters):
-                self.stats_counters[key] = self.stats_counters.get(key, 0) \
-                    + int(nxt[counters_at + i])
-            self.stats_counters["tokens_out"] += produced
-            self.stats_counters["occupancy_sum"] += len(active)
-            self.stats_counters["draft_proposed"] += drafted
-            self.stats_counters["draft_accepted"] += accepted
+                c[key] = c.get(key, 0) + int(nxt[counters_at + i])
+            # the loop's thread is these keys' only writer: ONE call
+            c.update(
+                steps=c["steps"] + 1,
+                steps_ahead=c["steps_ahead"] + step.ahead,
+                rows_dropped=c["rows_dropped"] + dropped,
+                tokens_out=c["tokens_out"] + produced,
+                occupancy_sum=c["occupancy_sum"] + chunks,
+                draft_proposed=c["draft_proposed"] + drafted,
+                draft_accepted=c["draft_accepted"] + accepted)
             # ITL (TPOT): delivery-to-delivery gap. All slots advance
             # in lockstep, so every CHUNK this step produced arrived
             # the same gap after its stream's previous one — one event
@@ -989,8 +1210,7 @@ class LLMEngine:
             itl = step_s if self._last_tokens_at is None \
                 else max(0.0, done_at - self._last_tokens_at)
             self._last_tokens_at = done_at
-        ds.set_metadata(tokens=produced)
-        _obs.record_decode_step(self._dep, step_s, len(active), produced)
+        _obs.record_decode_step(self._dep, step_s, chunks, produced)
         # a chunk's FIRST token came the gap after its stream's last one;
         # the second token of an accepted pair came with it, no gap apart
         _obs.record_decode_itl(self._dep, itl, chunks)
@@ -1001,6 +1221,7 @@ class LLMEngine:
                 step_span["attributes"].update(
                     drafted=drafted, accepted=accepted)
             tracing.finish_span(step_span)
+        return produced
 
     def _phase_span_locked(self, req: _Request, name: Optional[str],
                            status: str = "OK") -> None:
@@ -1279,12 +1500,14 @@ class LLMEngine:
 
     def llm_cancel(self, rid: str) -> bool:
         """Cancel a stream: a queued request leaves the queue, an
-        active one frees its slot at the cancel (the in-flight step's
-        token for it is discarded). The stream terminates with a
-        'cancelled' error; returns whether the request was still live.
-        A request mid-admission (its prefill in flight) is in neither
-        table and returns False — it completes normally and is reaped;
-        the window is one prefill call."""
+        active one frees its slot at the cancel (what is dispatched and
+        unread for it, a step's row or its first token, is dropped when
+        it is read, whoever holds the slot by then). The stream
+        terminates with a 'cancelled' error; returns whether the request
+        was still live. A request holds its slot from the dispatch of its
+        prompt's chunks; while the loop's thread is dispatching them it
+        is in neither table and the cancel returns False — it completes
+        normally and is reaped."""
         with self._lock:
             for slot in range(self.max_batch):
                 req = self._slot_req[slot]
@@ -1366,6 +1589,8 @@ class LLMEngine:
             "params_a_token": self._rule_params[1],
             "active": active,
             "queued": queued,
+            # results dispatched and not read (a step, admission turns)
+            "outstanding": len(self._outstanding),
             "compiles": dict(self._compiles),
             "init_s": dict(self._init_s),
             "param_bytes": dict(self._param_bytes),
@@ -1400,9 +1625,9 @@ class LLMEngine:
                     self._finish_locked(req, error=error)
 
     def shutdown_engine(self) -> bool:
-        """Stop the loop and wait for its thread (a step or a prefill in
-        flight ends first); ``serve.shutdown()`` calls this through the
-        replica. True once the thread has ended."""
+        """Stop the loop and wait for its thread (it reads and delivers
+        what it still has on the device, then ends); ``serve.shutdown()``
+        calls this through the replica. True once the thread has ended."""
         with self._lock:  # ``llm_submit`` reads it under the same lock
             self._stop = True
         self._wake.set()

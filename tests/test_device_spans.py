@@ -151,7 +151,9 @@ def test_engine_records_a_request_with_context_and_nothing_after():
 
 # -- the engine loop ----------------------------------------------------------
 
-PREFILL = ["llm.prefill.dispatch", "llm.prefill.sync", "llm.prefill.fanout"]
+# (an admission turn dispatches and reads nothing: its first tokens come
+# to the host in the next decode turn's sync)
+PREFILL = ["llm.prefill.dispatch"]
 STEP = ["llm.step.select", "llm.step.dispatch", "llm.step.sync",
         "llm.step.fanout"]
 # what a caller's thread annotates inside ``llm_next``
@@ -186,15 +188,19 @@ def test_loop_phases_in_a_profile_in_order_with_attributes(tmp_path):
     names = [e[0] for e in events]
     # never two open at once: each ends before the next starts
     assert all(a[2] <= b[1] for a, b in zip(events, events[1:]))
-    # one admission: admit -> prefill x3 -> the first step's four phases
+    # one admission: admit -> the chunks' dispatch -> the first turn's four
+    # phases (it enqueues step 1, then reads and hands out the first token)
     i = names.index("llm.prefill.dispatch")
     assert names[i - 1] == "llm.admit"
-    assert names[i:i + 3] == PREFILL
+    assert names[i:i + 1] == PREFILL
     j = names.index("llm.step.dispatch")
-    assert i + 3 <= j - 1 and names[j - 1:j + 3] == STEP
-    # 4 tokens: 1 from the prefill, 3 decode steps, each a whole turn
+    assert i + 1 <= j - 1 and names[j - 1:j + 3] == STEP
+    # 4 tokens: 1 from the prefill, 3 decode steps, each enqueued a turn
+    # before it is read; the last turn enqueues nothing and reads step 3
     assert names.count("llm.step.dispatch") == 3
-    assert names.count("llm.step.sync") == names.count("llm.step.fanout") == 3
+    assert names.count("llm.step.sync") == names.count("llm.step.fanout") == 4
+    k = len(names) - 1 - names[::-1].index("llm.step.fanout")
+    assert names[k - 2:k + 1] == [STEP[0], STEP[2], STEP[3]]
     assert "llm.loop.wait" in names
     stats = {n: s for n, _, _, s, _ in reversed(events)}   # first of each
     assert int(stats["llm.prefill.dispatch"]["rows"]) == 1
@@ -232,11 +238,12 @@ def test_no_phase_is_left_open_when_a_step_raises(tmp_path, how):
     # the profile holds only spans that ended, and they never overlap:
     # the raise closed whatever was open
     assert all(a[2] <= b[1] for a, b in zip(events, events[1:]))
-    # the failed turn has no sync and no fanout; the two good ones do
-    assert names.count("llm.step.select") >= 3
+    # the failed turn enqueued nothing and still read what was outstanding
+    # (the first token); then two steps, each read a turn after its enqueue
+    assert names.count("llm.step.select") >= 4
     assert names.count("llm.step.dispatch") == (2 if how == "failpoint"
                                                 else 3)
-    assert names.count("llm.step.sync") == names.count("llm.step.fanout") == 2
+    assert names.count("llm.step.sync") == names.count("llm.step.fanout") == 4
 
 
 def test_prefill_lane_counters_count_exactly():

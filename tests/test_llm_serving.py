@@ -673,6 +673,10 @@ def test_deadline_shed_mid_decode_frees_slot():
     eng = _engine(max_batch=2, max_new_tokens=500, max_new_cap=1000,
                   step_throttle_s=0.02)
     try:
+        # both programs compiled: a first token is read a turn after its
+        # chunks are dispatched, and a budget that dies before that read
+        # (in a compile) sheds the request with no token at all
+        eng.generate(PROMPT, 2)
         rid = eng.llm_submit(PROMPT, 500,
                              deadline_ts=time.time() + 0.3)
         got_tokens = 0
@@ -792,6 +796,272 @@ def test_compile_counters_single_shape(model):
         assert eng.llm_stats()["compiles"] == {"decode": 1, "prefill": 1}
     finally:
         eng.shutdown_engine()
+
+
+# -- one step ahead (PR 55) ----------------------------------------------------
+#
+# The loop enqueues step n + 1 before it reads step n. These four hold, for
+# every served family and against each request generated ALONE, that every
+# live slot is still handed the token and position it would be handed by a
+# loop that read first: with slots ending by count and by end token under
+# an unread step, a slot changing hands under one, a dispatch that raises
+# over one, and the compile count after all of it. One engine a family
+# serves all four, in this order, so the last one's counts cover the lot.
+
+
+@pytest.fixture(scope="module")
+def ahead_engine():
+    engines = {}
+
+    def get(model):
+        if model not in engines:
+            eng = engines[model] = _engine(
+                model=model, max_batch=2, prefill_rows=2, max_new_cap=16)
+            # A host array on a 64-byte boundary is handed to the CPU's
+            # runtime WITHOUT a copy, and a program that runs later (behind
+            # an unread step) reads it as it is then: the engine has to
+            # hand over positions that its fan-out will not move.
+            raw = np.zeros(eng.max_batch + 1 + 16, np.int32)
+            at = (-raw.ctypes.data % 64) // 4
+            eng._pos = raw[at:at + eng.max_batch + 1]
+            assert eng._pos.ctypes.data % 64 == 0
+        return engines[model]
+
+    yield get
+    for eng in engines.values():
+        eng.shutdown_engine()
+
+
+def _alone(model, eng, asked):
+    """What each ``(prompt, n)`` of ``asked`` gets generated alone."""
+    cfg, fwd = SERVED[model]
+    alone = _compiled(fwd, eng.params, cfg, 32)
+    return {i: _naive_generate(alone, None, prompt, n, None)
+            for i, (prompt, n) in asked.items()}
+
+
+def _serve_all(eng, asked, **submit):
+    """Submit all of ``asked`` at once, a poller thread each:
+    ``{i: (tokens, last response)}``."""
+    got, errors = {}, []
+
+    def one(i, rid):
+        try:
+            got[i] = _drain(eng, rid)
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    threads = [threading.Thread(
+        target=one, args=(i, eng.llm_submit(prompt, n, **submit)))
+        for i, (prompt, n) in asked.items()]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors and not any(t.is_alive() for t in threads), errors
+    return got
+
+
+def _settled(eng):
+    """The engine's counters once nothing is dispatched and unread."""
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        st = eng.llm_stats()
+        if not st["outstanding"] and not st["active"] and not st["queued"]:
+            return st
+        time.sleep(0.005)
+    raise AssertionError(f"the engine did not settle: {eng.llm_stats()}")
+
+
+@every_family
+def test_slots_end_by_count_and_by_end_token_under_an_unread_step(
+        model, ahead_engine):
+    """Eight requests of staggered lengths on two slots, and an end token
+    the model emits: a slot that ends by count is known to the host before
+    the step is read, one that ends by its end token is stepped once more
+    (the row is dropped); each successor gets the tokens it gets alone."""
+    eng = ahead_engine(model)
+    asked = {i: ([i + 1, 7, 11][:1 + i % 3] + [2 + i], 2 + (3 * i) % 7)
+             for i in range(8)}
+    alone = _alone(model, eng, asked)
+
+    def cut(toks, eos):
+        return toks[:toks.index(eos) + 1] if eos in toks else toks
+
+    # the end token that cuts some generations short and leaves others
+    # their count
+    def kinds(eos):
+        short = sum(len(cut(t, eos)) < len(t) for t in alone.values())
+        return min(short, len(alone) - short)
+
+    eos = max({t for toks in alone.values() for t in toks}, key=kinds)
+    assert kinds(eos) >= 1, alone
+    before = _settled(eng)
+    eng.eos_token = eos
+    try:
+        got = _serve_all(eng, asked)
+    finally:
+        eng.eos_token = None
+    st = _settled(eng)
+    for i, (tokens, last) in got.items():
+        assert not last["error"] and not last["shed"], last
+        assert tokens == cut(alone[i], eos), (i, eos)
+    assert st["completed"] - before["completed"] == len(asked)
+    # every generation cut short had a step enqueued for its next token
+    short = sum(len(cut(t, eos)) < len(t) for t in alone.values())
+    dropped = st["rows_dropped"] - before["rows_dropped"]
+    assert dropped >= short if eng._drafting else dropped == short, (
+        dropped, short, eos, alone)
+    assert st["steps_ahead"] > before["steps_ahead"]
+
+
+@every_family
+def test_a_slot_changes_hands_under_an_unread_step(model, ahead_engine):
+    """A cancel, then a deadline's eviction, land between a step's
+    dispatch and its read, with a request queued for the slot: it is
+    admitted at once, its stream holds its own tokens only, the other
+    slot's stream goes on undisturbed, and the rows the steps computed for
+    the request that left are counted as dropped."""
+    eng = ahead_engine(model)
+    asked = {"stays": ([4, 7, 11, 2], 14), "heir": ([9, 1, 8], 5),
+             "heir2": ([6, 6, 3, 1, 2], 4)}
+    alone = _alone(model, eng, asked)
+    before_read = _Gate()
+    host = eng._sync
+    _stop_before_read(eng, before_read)
+    got, errors = {}, []
+
+    def one(i, rid):
+        try:
+            got[i] = _drain(eng, rid)
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    def submit(i):
+        rid = eng.llm_submit(*asked[i])
+        t = threading.Thread(target=one, args=(i, rid))
+        t.start()
+        return t
+
+    try:
+        st0 = _settled(eng)
+        threads = [submit("stays")]
+        leaves = eng.llm_submit(PROMPT, 16)
+        # both decoding: a step for both is dispatched and unread, and the
+        # step after it is enqueued
+        for _ in range(2):
+            before_read.reached()
+            before_read.let()
+        before_read.reached()
+        assert eng.llm_stats()["active"] == 2
+        threads.append(submit("heir"))                # queued for a slot
+        assert eng.llm_cancel(leaves)
+        for _ in range(3):   # the two steps computed a row for it; a third
+            before_read.let()                         # holds the heir
+            before_read.reached()
+        st1 = eng.llm_stats()
+        assert st1["rows_dropped"] - st0["rows_dropped"] == 2
+        # ... and a deadline that dies under an unread step: the step read
+        # now still hands its token out, the next select evicts
+        evicted = eng.llm_submit([3, 3, 5], 16)
+        threads.append(submit("heir2"))               # queued behind it
+        before_read.open()
+        threads[1].join(timeout=60)                   # the heir ends
+        # (evicted holds the heir's slot now, or will; wait for its token)
+        assert eng.llm_next(evicted, timeout_s=30.0)["chunks"]
+        before_read.shut()
+        before_read.reached()
+        [victim] = [r for r in eng._slot_req
+                    if r is not None and r.prompt == [3, 3, 5]]
+        victim.deadline_ts = time.time() - 1.0
+        dropped = eng.llm_stats()["rows_dropped"]
+        before_read.open()
+        tokens, last = _drain(eng, evicted)
+        assert last["shed"] == "decode", last
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors and not any(t.is_alive() for t in threads), errors
+        st2 = _settled(eng)
+    finally:
+        before_read.open()
+        eng._sync = host
+    for i, (tokens, last) in got.items():
+        assert not last["error"] and not last["shed"], (i, last)
+        assert tokens == alone[i], i
+    # the one step enqueued before the eviction computed a row for it
+    assert st2["rows_dropped"] - dropped == 1
+    assert st2["shed"] - st0["shed"] == 1
+
+
+@every_family
+def test_a_dispatch_that_raises_loses_no_token_of_the_step_before(
+        model, ahead_engine):
+    """``serve.llm.before_step`` raises with a step dispatched and unread:
+    that step's tokens are delivered. Once, and the stream goes on to its
+    end with the tokens it gets alone; armed for good, three in a row fail
+    the streams, with a prefix of them, and nothing is left unread."""
+    eng = ahead_engine(model)
+    asked = {"once": ([2, 9, 4], 9), "for_good": ([8, 1, 1, 6], 12),
+             "after": ([5, 5, 2], 4)}
+    alone = _alone(model, eng, asked)
+    before_read = _Gate()
+    host = eng._sync
+    _stop_before_read(eng, before_read)
+    try:
+        st0 = _settled(eng)
+        for i, arm in (("once", "raise,once"), ("for_good", "raise")):
+            rid = eng.llm_submit(*asked[i])
+            before_read.reached()         # a step unread, the next enqueued
+            before_read.let()
+            before_read.reached()
+            failpoints.arm("serve.llm.before_step", arm)
+            before_read.open()
+            tokens, last = _drain(eng, rid)
+            failpoints.reset()
+            st = _settled(eng)            # nothing outstanding and unread
+            if i == "once":
+                assert not last["error"] and tokens == alone[i], last
+                assert st["errors"] - st0["errors"] == 1
+            else:
+                assert "decode step failing repeatedly" in last["error"]
+                # the first token, two steps read at the gate, and the step
+                # that was on the device when the first dispatch raised
+                assert len(tokens) >= 4 and tokens == alone[i][:len(tokens)]
+                assert st["errors"] - st0["errors"] == 1 + 3 + 1
+            before_read.shut()
+        before_read.open()
+        assert eng.generate(*asked["after"]) == alone["after"]  # recovered
+    finally:
+        failpoints.reset()
+        before_read.open()
+        eng._sync = host
+
+
+@every_family
+def test_nothing_is_outstanding_when_the_last_request_ends(
+        model, ahead_engine):
+    """After all of the above on this engine (whichever ran): the last
+    stream ends by its end token with a step enqueued behind it, the loop
+    reads that step before it waits, the steps ahead never outnumber the
+    steps, and each program was compiled and cached ONCE, helpers
+    included: every call presented the same kinds of argument."""
+    eng = ahead_engine(model)
+    asked = {0: ([7, 2, 9, 4], 10)}
+    alone = _alone(model, eng, asked)[0]
+    eng.eos_token = alone[4]
+    try:
+        tokens = eng.generate(*asked[0])
+    finally:
+        eng.eos_token = None
+    assert tokens == alone[:alone.index(alone[4]) + 1]
+    st = _settled(eng)
+    assert st["outstanding"] == 0 and st["active"] == 0
+    assert 0 < st["steps_ahead"] <= st["steps"]
+    assert st["rows_dropped"] >= 1
+    assert st["compiles"] == {"decode": 1, "prefill": 1}
+    assert [f._cache_size() for f in (
+        eng._step_fn, eng._prefill_fn, eng._carry_fn, eng._put_fn)] \
+        == [1, 1, 1, 1]
 
 
 def test_ttft_histogram_exact_counts():
@@ -923,6 +1193,39 @@ class _Gate:
         self._open = True
         self._go.release()
 
+    def shut(self):
+        """Stop the loop's thread at its next arrival again."""
+        self._open = False
+
+
+def _stop_before_flush(eng, gate, log=None):
+    """The loop's thread stops at ``gate`` between a fan-out that owes
+    wake-ups (tokens pending under the lock) and their flush; ``log``
+    notes what was owed."""
+    real = eng._flush_wakes
+
+    def gated(enqueued):
+        if eng._wakes:
+            if log is not None:
+                log.append(("owed", list(eng._wakes)))
+            gate.stop()
+        return real(enqueued)
+
+    eng._flush_wakes = gated
+
+
+def _stop_before_read(eng, gate):
+    """The loop's thread stops at ``gate`` before it reads a decode step
+    (its sync), with the step after it already enqueued."""
+    real = eng._sync
+
+    def gated(d):
+        if isinstance(d, llm_engine._Step):
+            gate.stop()
+        return real(d)
+
+    eng._sync = gated
+
 
 def _drain(eng, rid, timeout_s=2.0, took=None):
     """Poll one stream to its end: (tokens, last response). ``took``
@@ -988,16 +1291,16 @@ def test_concurrent_streams_get_their_own_tokens_once_in_order(poll_s):
 
 
 def test_a_steps_streams_are_woken_after_the_next_enqueue(monkeypatch):
-    """Step n's wake-ups follow the return of call n + 1 and nothing
-    else; where an admission comes between them, its FIRST chunk's
-    dispatch, before the turn's other chunks and its sync."""
+    """Step n's wake-ups come at the tail of its fan-out and nowhere else,
+    and step n + 1 was enqueued before step n was read: a turn is enqueue,
+    read, fan out, wake; an admission's chunks go out behind all of it."""
     log = []
     _log_sets(monkeypatch, log)
     eng = _engine(max_batch=2, prefill_chunk=4, cache_len=64,
                   max_new_cap=64)
     try:
         eng.generate(PROMPT, 2)          # both programs compiled
-        step, chunk = eng._step_fn, eng._prefill_fn
+        step, chunk, host = eng._step_fn, eng._prefill_fn, eng._sync
 
         def logged_step(*a):
             time.sleep(0.005)            # the second request arrives mid-decode
@@ -1010,43 +1313,48 @@ def test_a_steps_streams_are_woken_after_the_next_enqueue(monkeypatch):
             log.append(("enqueued", "chunk"))
             return out
 
+        def logged_sync(d):
+            log.append(("read", type(d).__name__))
+            return host(d)
+
         eng._step_fn, eng._prefill_fn = logged_step, logged_chunk
-        real_span = tracing.device_span
-
-        @contextlib.contextmanager
-        def span(name, **kw):
-            with real_span(name, **kw) as ds:
-                yield ds
-            if name == "llm.step.fanout":
-                log.append(("owed", list(eng._wakes)))
-            elif name == "llm.prefill.sync":
-                log.append(("synced",))
-
-        monkeypatch.setattr(tracing, "device_span", span)
+        eng._sync = logged_sync
+        gate = _Gate()
+        gate.open()
+        _stop_before_flush(eng, gate, log)   # (logs what is owed, never stops)
         del log[:]
         a = eng.llm_submit(PROMPT, 40)
-        assert eng.llm_next(a, timeout_s=30.0)["chunks"]   # decoding now
+        had = 0
+        while had < 3:                   # decoding now: steps' tokens came
+            had += len(eng.llm_next(a, timeout_s=30.0)["chunks"])
         b = eng.llm_submit([3, 1, 4, 1, 5, 9, 2, 6], 6)    # two chunks
         assert len(_drain(eng, b)[0]) == 6
-        assert len(_drain(eng, a)[0]) == 39
+        assert had + len(_drain(eng, a)[0]) == 40
         st = eng.llm_stats()
     finally:
         eng.shutdown_engine()
     # only the loop's thread wrote the log, so it is in program order
-    owed_at = [i for i, e in enumerate(log) if e[0] == "owed" and e[1]]
+    owed_at = [i for i, e in enumerate(log) if e[0] == "owed"]
     assert len(owed_at) >= 38            # a's steps but its last
-    after_a_chunk = 0
-    for i in owed_at:
+    chunks_behind = 0
+    for n, i in enumerate(owed_at):
         owed = log[i][1]
-        assert log[i + 1][0] == "enqueued", log[i:i + 3]
-        woken = log[i + 2:i + 2 + len(owed)]
-        assert woken == [("set", st_) for st_ in owed]
-        if log[i + 1][1] == "chunk":
-            # the turn's second chunk and its sync come after the sets
-            rest = [e[:2] for e in log[i + 2 + len(owed):i + 5 + len(owed)]]
-            assert rest[:2] == [("enqueued", "chunk"), ("synced",)], rest
-            after_a_chunk += 1
-    assert after_a_chunk == 1
+        # the wake-ups, all of them, at once
+        assert log[i + 1:i + 1 + len(owed)] == [("set", st_) for st_ in owed]
+        # since the last flush: the next step enqueued, THEN this one read
+        turn = [e[:2] for e in log[owed_at[n - 1] if n else 0:i]
+                if e[0] in ("enqueued", "read")]
+        turn = [e for e in turn if e != ("enqueued", "chunk")
+                and e != ("read", "_Firsts")]
+        # (the first flush's stretch also holds the turn that enqueued
+        # step 1 with no step to read)
+        assert turn[-2:] == [("enqueued", "step"), ("read", "_Step")], turn
+        assert n == 0 or len(turn) == 2, turn
+        # an admission's chunks follow the wake-ups, back to back
+        rest = [e[:2] for e in log[i + 1 + len(owed):i + 3 + len(owed)]]
+        if rest == [("enqueued", "chunk")] * 2:
+            chunks_behind += 1
+    assert chunks_behind == 1
     assert st["wakes_deferred"] == st["wakes_after_dispatch"] \
         == sum(len(log[i][1]) for i in owed_at) == 38 + 4
     # ... and no stream was told anywhere else: beside those, only the
@@ -1117,55 +1425,37 @@ def test_no_poller_waits_out_its_timeout(what):
 
 
 def test_a_late_wake_up_finds_nothing_and_harms_nothing():
-    """A poll that times out drains the token its stream's wake-up has
-    not announced yet; the late wake-up then ends the next poll at once
+    """A poll that times out between a token's append and its wake-up
+    drains the token; the late wake-up then ends the next poll at once
     with no chunk and no error; every token arrives once."""
     cfg, fwd = SERVED["gpt2"]
     eng = _engine(max_batch=2)
-    before_call, before_sync = _Gate(), _Gate()
+    before_flush, before_read = _Gate(), _Gate()
     try:
         eng.generate(PROMPT, 2)
-        real = eng._step_fn
-
-        class Unread:
-            """The step's tokens; the loop's sync stops at the gate."""
-
-            def __init__(self, nxt):
-                self.nxt = nxt
-
-            def __array__(self, *_a, **_kw):
-                before_sync.stop()
-                return np.asarray(self.nxt)
-
-        def gated(*a):
-            before_call.stop()
-            nxt, cache = real(*a)
-            return Unread(nxt), cache
-
-        eng._step_fn = gated
+        _stop_before_flush(eng, before_flush)
+        _stop_before_read(eng, before_read)
         rid = eng.llm_submit(PROMPT, 8)
-        before_call.reached()             # step 1 not enqueued yet
         first = eng.llm_next(rid, timeout_s=30.0)
-        before_call.let()
-        before_sync.reached()
-        before_sync.let()                 # step 1 fans out: token 2 pending
-        before_call.reached()             # ... and its wake-up is put off
+        before_read.reached()             # step 2 enqueued, step 1 unread
+        before_read.let()                 # step 1 fans out: token 2 pending
+        before_flush.reached()            # ... and its wake-up not yet set
         t0 = time.monotonic()
         second = eng.llm_next(rid, timeout_s=0.05)
         assert time.monotonic() - t0 >= 0.05          # it was not woken
-        before_call.let()                 # step 2 enqueued: the late set
-        before_sync.reached()             # ... and step 2 not fanned out
+        before_flush.let()                # the late set
+        before_read.reached()             # ... and step 2 not fanned out
         t0 = time.monotonic()
         third = eng.llm_next(rid, timeout_s=20.0)
         assert time.monotonic() - t0 < 1.0            # woken, for nothing
-        before_call.open()
-        before_sync.open()
+        before_flush.open()
+        before_read.open()
         rest, last = _drain(eng, rid)
         st = eng.llm_stats()
         alone = _compiled(fwd, eng.params, cfg, 16)
     finally:
-        before_call.open()
-        before_sync.open()
+        before_flush.open()
+        before_read.open()
         eng.shutdown_engine()
     want = _naive_generate(alone, None, PROMPT, 8, None)
     assert first["chunks"] == [want[:1]] and second["chunks"] == [want[1:2]]
@@ -1183,7 +1473,8 @@ def test_wake_counters_count_exactly(how, after_dispatch):
     request's first (the prefill's) and last (the terminal transition's)
     is a put-off wake-up, 3 + 6; all of them follow an enqueue unless
     the engine sleeps between steps (none does) or a step fails before
-    its enqueue (the two streams decoding then are woken at the raise)."""
+    its enqueue (the step before it is read all the same, and its two
+    streams are woken with nothing behind their step)."""
     eng = _engine(max_batch=2, prefill_rows=2,
                   step_throttle_s=0.001 if how == "throttled" else 0.0)
     try:
@@ -1193,8 +1484,9 @@ def test_wake_counters_count_exactly(how, after_dispatch):
             real, raised = eng._step_fn, []
 
             def flaky(*a):
-                # once, with both streams owed the last step's wake-up
-                if not raised and len(eng._wakes) == 2:
+                # once, with a step for both streams dispatched and unread
+                if not raised and [len(d.rows) for d in eng._outstanding
+                                   if isinstance(d, llm_engine._Step)] == [2]:
                     raised.append(1)
                     raise RuntimeError("injected")
                 return real(*a)
@@ -1398,36 +1690,29 @@ def test_a_flush_says_nothing_of_tokens_an_earlier_call_took():
     token with it: the flush that follows finds nothing pending and does
     not wake the poller for nothing (``next_empty`` stays 0)."""
     eng = _engine(max_batch=2, prefill_rows=2, max_new_cap=64)
-    before_call = _Gate()
+    before_flush = _Gate()
     try:
         eng.generate(PROMPT, 2)
-        real = eng._step_fn
-
-        def gated(*a):
-            before_call.stop()
-            return real(*a)
-
-        eng._step_fn = gated
+        _stop_before_flush(eng, before_flush)
         before = eng.llm_stats()
         short = eng.llm_submit([1, 2, 3], 2, poller="p")
         long_ = eng.llm_submit([4, 5, 6], 4, poller="p")
-        before_call.reached()              # both prefilled, no step yet
-        first = eng.llm_poll(poller="p", timeout_s=30.0)
-        assert {short, long_} <= set(first)
-        before_call.let()                  # step 1: short ends, long_ owed
-        before_call.reached()              # ... fanned out, flush not yet
+        first = {}
+        while not {short, long_} <= set(first):
+            first.update(eng.llm_poll(poller="p", timeout_s=30.0))
+        before_flush.reached()             # step 1: short ended, long_ owed
         second = eng.llm_poll(poller="p", timeout_s=30.0)
         assert second[short]["done"] and second[long_]["chunks"]
-        before_call.let()                  # step 2 enqueued: the flush
-        before_call.reached()              # step 2 fanned out, its flush not
-        # the flush after step 2's enqueue found step 1's token gone and
-        # set nothing; step 2's token is pending and not yet announced
+        before_flush.let()                 # the flush: step 1's token is gone
+        before_flush.reached()             # step 2 fanned out, its flush not
+        # step 1's flush found its token taken and set nothing; step 2's
+        # token is pending and not yet announced
         assert not eng._pollers["p"].event.is_set()
         st = eng.llm_stats()
-        before_call.open()
+        before_flush.open()
         rest, last, _ = _poll_to_the_end(eng, "p", [long_])
     finally:
-        before_call.open()
+        before_flush.open()
         eng.shutdown_engine()
     assert len(rest[long_]) == 2 and not last[long_]["error"]
     assert st["next_empty"] == before["next_empty"]
