@@ -96,6 +96,39 @@ _STREAM_TTL_S = 120.0
 # the bit length of its count of quarter milliseconds.
 DELIVER_LAG_EDGES_MS = (0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0)
 _LAG_TOP = len(DELIVER_LAG_EDGES_MS)
+# The loop's record of its own turns (``LLMEngine._turn_done``). The phases
+# of a pass of the loop, in the order of ``turn_phase_ns``: the seven
+# device spans as the loop names them, its idle wait, and ``other``, the
+# pass less these.
+TURN_PHASES = ("llm.admit", "llm.prefill.dispatch", "llm.step.select",
+               "llm.step.dispatch", "llm.step.sync", "llm.step.fanout",
+               "llm.loop.reap", "llm.loop.wait", "other")
+_PHASE_AT = {name: i for i, name in enumerate(TURN_PHASES)}
+_SYNC_AT = _PHASE_AT["llm.step.sync"]
+# Upper edges, in ms, of the first fifteen buckets of the four
+# ``turn_hist_*`` lists (the sixteenth holds 16 s and more): each twice the
+# one before, so a turn's bucket is the bit length of its whole
+# milliseconds.
+TURN_EDGES_MS = tuple(2 ** i for i in range(15))
+_TURN_TOP = len(TURN_EDGES_MS)
+# The allocator's numbers a kept turn reads from
+# ``jax.Device.memory_stats()`` (-1 where the backend gives none).
+MEMORY_KEYS = ("num_allocs", "bytes_in_use", "bytes_reserved",
+               "largest_free_block_bytes")
+# ``slow_turns`` is ONE flat list of integers, this many a kept turn, in
+# this order: when the turn began (``time.perf_counter_ns``) and how long
+# it was, its phases, what it did, the ``llm.step.sync`` of the turn after
+# it (-1 until that turn has ended), the allocator's numbers at the turn's
+# end, and how far each had moved since the loop's last ``llm.loop.reap``.
+SLOW_TURN_FIELDS = (
+    "start_ns", "turn_ns", *TURN_PHASES, "chunks", "admitted", "rows",
+    "steps_read", "firsts_read", "outstanding", "next_sync_ns",
+    *MEMORY_KEYS, *(k + "_since_reap" for k in MEMORY_KEYS))
+_NEXT_SYNC_AT = SLOW_TURN_FIELDS.index("next_sync_ns")
+SLOW_TURNS = 8                       # kept at most
+SLOW_TURN_AGE_NS = 60_000_000_000    # and no longer than this
+# a plain turn this long is a stall, and is logged
+SLOW_TURN_WARN_NS = 1_000_000_000
 
 
 class _Stream:
@@ -172,6 +205,47 @@ class _Request:
         # lock; every terminal path closes via _finish_locked.
         self.trace_ctx = trace_ctx
         self.span: Optional[dict] = None
+
+
+class _Turn:
+    """One pass of the loop's body as the loop's thread times it: when it
+    began, the nanoseconds of each phase (``TURN_PHASES``; ``other`` is
+    filled in where the pass ends) and what it did."""
+
+    __slots__ = ("t0", "ns", "chunks", "admitted", "rows", "steps_read",
+                 "firsts_read")
+
+    def __init__(self):
+        self.t0 = time.perf_counter_ns()
+        self.ns = [0] * len(TURN_PHASES)
+        self.chunks = self.admitted = self.rows = 0
+        self.steps_read = self.firsts_read = 0
+
+    @property
+    def plain(self) -> bool:
+        """It read a step with no prefill in front of it."""
+        return bool(self.steps_read) and not self.chunks \
+            and not self.firsts_read
+
+
+class _Phase:
+    """One phase of a turn: the profiler's annotation
+    (``tracing.device_span``) and, at the same two edges, the loop's own
+    clock, added to the turn's nanoseconds of that phase."""
+
+    __slots__ = ("_ns", "_at", "_span", "_t0")
+
+    def __init__(self, ns: List[int], at: int, span):
+        self._ns, self._at, self._span = ns, at, span
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        out = self._span.__exit__(*exc)
+        self._ns[self._at] += time.perf_counter_ns() - self._t0
+        return out
 
 
 class _Step:
@@ -611,7 +685,7 @@ class LLMEngine:
         self._last_reap = time.monotonic()
         self.stats_counters = {
             "steps": 0, "admitted": 0, "completed": 0, "shed": 0,
-            "errors": 0, "tokens_out": 0, "queue_peak": 0,
+            "errors": 0, "tokens_out": 0,
             "occupancy_sum": 0, "ring_wraps": 0,
             # The step ahead: steps dispatched while another was
             # dispatched and unread (of ``steps``; both counted where the
@@ -664,7 +738,40 @@ class LLMEngine:
             # time minus the fan-out's. The rest of ``deliver_lag_ns``
             # is the interpreter, the lock and the poller's thread.
             "wake_defer_ns": 0,
+            # The loop's record of its own turns, integers and flat lists
+            # of integers that its thread alone writes, once a pass
+            # (``_turn_done``). ``turn_ns`` and ``turn_phase_ns`` (in the
+            # order of TURN_PHASES) add up every pass of the loop, idle
+            # ones too, so the phases sum to ``turn_ns`` and both to the
+            # loop's life; ``turns`` counts the passes that did something.
+            # Of those, by class (plain: read a step, dispatched no chunk,
+            # read no first token; prefill: the rest), how many fell into
+            # each bucket of TURN_EDGES_MS and the nanoseconds they sum
+            # to. ``slow_turns``: the longest of the last minute, at most
+            # SLOW_TURNS, SLOW_TURN_FIELDS a turn.
+            "turns": 0, "turn_ns": 0,
+            "turn_phase_ns": [0] * len(TURN_PHASES),
+            "turn_hist_plain": [0] * (_TURN_TOP + 1),
+            "turn_hist_plain_ns": [0] * (_TURN_TOP + 1),
+            "turn_hist_prefill": [0] * (_TURN_TOP + 1),
+            "turn_hist_prefill_ns": [0] * (_TURN_TOP + 1),
+            "slow_turns": [],
         }
+        # The loop's thread's own: the pass it is in; the kept turns one
+        # list each (``slow_turns`` is their copy, laid end to end where
+        # ``_slow_changed``), the shortest's length once SLOW_TURNS are
+        # kept (a turn enters by beating it), the one kept last while it
+        # is owed its ``next_sync_ns`` (and whether it is logged then);
+        # the device whose allocator a kept turn reads, and what it read
+        # at the last reap.
+        self._turn = _Turn()
+        self._slow: List[List[int]] = []
+        self._slow_floor = -1
+        self._slow_changed = False
+        self._slow_last: Optional[tuple] = None
+        self._device = next(iter(
+            jax.tree_util.tree_leaves(self._cache)[0].devices()))
+        self._mem_at_reap = self._memory()
         self._loop_thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine-loop")
         self._loop_thread.start()
@@ -709,6 +816,7 @@ class LLMEngine:
         cancel or a shed ends is woken by its terminal transition, so no
         wake-up waits for anything but its own turn's fan-out."""
         while not self._stop:
+            turn = self._turn = _Turn()
             did = False
             try:
                 did = self._admit_once() or did
@@ -725,18 +833,116 @@ class LLMEngine:
                 # in _step_once); this tick records the loop survival.
                 _metrics.count_loop_restart("llm.engine")
             if time.monotonic() - self._last_reap > 5.0:
-                with tracing.device_span("llm.loop.reap"):
+                with self._phase("llm.loop.reap"):
                     self._reap_streams()
+                    self._reap_turns()
             if not did:
-                with tracing.device_span("llm.loop.wait"):
+                with self._phase("llm.loop.wait"):
                     self._wake.wait(0.02)
                 self._wake.clear()
+            self._turn_done(turn, did)
+        turn = self._turn = _Turn()
+        did = False
         try:
             # the loop ends with nothing dispatched and unread: what the
             # device still holds is delivered before the streams are ended
-            self._step_once(settle=True)
+            did = self._step_once(settle=True)
         except BaseException:
             _metrics.count_loop_restart("llm.engine")
+        self._turn_done(turn, did)
+
+    # -- the loop's record of its own turns (its thread only) --------------
+
+    def _phase(self, name: str, **attrs) -> _Phase:
+        """``tracing.device_span(name)``, and the running turn's clock at
+        the same edges: the counters and a profile's annotations cut the
+        turn alike."""
+        return _Phase(self._turn.ns, _PHASE_AT[name],
+                      tracing.device_span(name, **attrs))
+
+    def _memory(self) -> List[int]:
+        st = self._device.memory_stats() or {}
+        return [int(st.get(k, -1)) for k in MEMORY_KEYS]
+
+    def _reap_turns(self) -> None:
+        """With the streams' reap, every 5 s: the allocator's numbers a
+        kept turn's are held against, and the kept turns that are older
+        than a minute forgotten (the next turns take their places)."""
+        self._mem_at_reap = self._memory()
+        cutoff = time.perf_counter_ns() - SLOW_TURN_AGE_NS
+        if any(e[0] < cutoff for e in self._slow):
+            self._slow = [e for e in self._slow if e[0] >= cutoff]
+            self._slow_floor = -1
+            self._slow_changed = True
+
+    def _turn_done(self, turn: _Turn, did: bool) -> None:
+        """A pass of the loop has ended: its times go into the counters.
+        Every list is written anew and put into ``stats_counters`` in the
+        ONE ``update`` below, with the sums: no other thread's copy
+        (``llm_stats()``) falls between two of them or sees a list change
+        under it. An ordinary turn costs the clock reads of its phases,
+        three short lists and one comparison with the shortest kept turn."""
+        total = time.perf_counter_ns() - turn.t0
+        ns = turn.ns
+        ns[-1] = total - sum(ns)    # ``other``
+        c = self.stats_counters
+        new = {"turn_ns": c["turn_ns"] + total,
+               "turn_phase_ns": [a + b for a, b in
+                                 zip(c["turn_phase_ns"], ns)]}
+        if did:
+            new["turns"] = c["turns"] + 1
+            key = "turn_hist_plain" if turn.plain else "turn_hist_prefill"
+            b = min((total // 1_000_000).bit_length(), _TURN_TOP)
+            for k, add in ((key, 1), (key + "_ns", total)):
+                new[k] = hist = list(c[k])
+                hist[b] += add
+            if self._slow_last is not None:
+                # the turn before this one was kept: this turn's sync
+                # says whether the device had stood still with it
+                (last, warn), self._slow_last = self._slow_last, None
+                last[_NEXT_SYNC_AT] = ns[_SYNC_AT]
+                self._slow_changed = True
+                if warn:
+                    self._log_stall(last)
+            if total > self._slow_floor:
+                self._keep_slow(turn, total)
+        if self._slow_changed:
+            self._slow_changed = False
+            new["slow_turns"] = [x for e in self._slow for x in e]
+        c.update(new)
+
+    def _keep_slow(self, turn: _Turn, total: int) -> None:
+        """The turn is among the longest the list holds (the reap forgets
+        those of more than a minute ago): keep it, with the allocator's
+        numbers as they stand at its end."""
+        mem = self._memory()
+        entry = [turn.t0, total, *turn.ns, turn.chunks, turn.admitted,
+                 turn.rows, turn.steps_read, turn.firsts_read,
+                 len(self._outstanding), -1, *mem,
+                 *(a - b for a, b in zip(mem, self._mem_at_reap))]
+        slow = self._slow
+        if len(slow) == SLOW_TURNS:
+            slow.remove(min(slow, key=lambda e: e[1]))
+        slow.append(entry)
+        self._slow_floor = min(e[1] for e in slow) \
+            if len(slow) == SLOW_TURNS else -1
+        self._slow_changed = True
+        self._slow_last = (entry,
+                           turn.plain and total >= SLOW_TURN_WARN_NS)
+
+    def _log_stall(self, kept: List[int]) -> None:
+        """One line for a plain turn of SLOW_TURN_WARN_NS or more: a
+        decode step with no prefill in front of it took that long to come
+        to the host. A next sync as long as any plain turn's says the
+        device itself stood still, one near nothing that the step after it
+        ran on time and only this one's hand-over was late."""
+        t = dict(zip(SLOW_TURN_FIELDS, kept))
+        logger.warning(
+            "llm engine loop stalled: a plain turn of %.1f ms, "
+            "llm.step.sync %.1f ms of it, the next turn's %.1f ms; "
+            "%d rows, %d outstanding", t["turn_ns"] * 1e-6,
+            t["llm.step.sync"] * 1e-6, t["next_sync_ns"] * 1e-6,
+            t["rows"], t["outstanding"])
 
     def _flush_wakes(self, enqueued: bool) -> None:
         """Set the event of every stream the fan-out just made put off
@@ -804,8 +1010,9 @@ class LLMEngine:
     def _admit_once(self) -> bool:
         # The loop's phases are device_spans (profiler annotations on
         # the device's clock; PERF.md lists the names, which readers of
-        # a captured profile rely on).
-        with tracing.device_span("llm.admit") as ds, self._lock:
+        # a captured profile rely on), each with the turn's own clock at
+        # its edges (``_phase``).
+        with self._phase("llm.admit") as ds, self._lock:
             now = time.time()
             self._shed_expired_locked(now)
             free = [i for i in range(self.max_batch)
@@ -868,7 +1075,7 @@ class LLMEngine:
         np = self._np
         chunk = self.prefill_chunk
         t0 = time.perf_counter()
-        with tracing.device_span("llm.prefill.dispatch") as ds:
+        with self._phase("llm.prefill.dispatch") as ds:
             rows, lengths, n_chunks = [], [], 0
             for req, slot in zip(batch, slots):
                 # truncate to the longest prompt the slot's rows hold
@@ -897,6 +1104,8 @@ class LLMEngine:
             tokens_real = sum(lengths)
             ds.set_metadata(rows=len(batch), tokens_real=tokens_real,
                             chunks=n_chunks)
+        self._turn.chunks += n_chunks
+        self._turn.admitted += len(batch)
         self._init_s.setdefault("first_prefill", time.perf_counter() - t0)
         with self._lock:
             c = self.stats_counters
@@ -968,7 +1177,7 @@ class LLMEngine:
         loop's last act)."""
         np = self._np
         outstanding = self._outstanding
-        with tracing.device_span("llm.step.select") as ds, self._lock:
+        with self._phase("llm.step.select") as ds, self._lock:
             now = time.time()
             # Deadline eviction happens at the step boundary: the slot
             # frees NOW, before the next step is enqueued, and the shed
@@ -985,6 +1194,8 @@ class LLMEngine:
             ds.set_metadata(occupancy=len(rows))
             if not rows and not outstanding:
                 return False
+            turn = self._turn
+            turn.rows = len(rows)
             # The rows of the unread step that are still their request's
             # stand one step past the host's position (a slot that changed
             # hands since stands where its new holder's prompt ends).
@@ -1024,8 +1235,8 @@ class LLMEngine:
                 # epoch_ns ties this thread's clock (time.time_ns, the span
                 # store's) to the profile's: a reader takes the offset as
                 # the median over these anchors.
-                with tracing.device_span("llm.step.dispatch",
-                                         epoch_ns=time.time_ns()):
+                with self._phase("llm.step.dispatch",
+                                 epoch_ns=time.time_ns()):
                     self._dispatch(rows, ahead, unread is not None)
             except BaseException as e:  # noqa: BLE001 — re-raised below
                 # the step already on the device keeps its tokens: this
@@ -1039,7 +1250,7 @@ class LLMEngine:
             else 0
         due = outstanding[:len(outstanding) - keep]
         try:
-            with tracing.device_span("llm.step.sync"):
+            with self._phase("llm.step.sync"):
                 # The intentional syncs of a turn (tokens fan out to
                 # streams from host memory): the device ended these about
                 # a step ago.
@@ -1055,16 +1266,18 @@ class LLMEngine:
             step_s = time.perf_counter() - t0
             if rows and failed is None:
                 self._init_s.setdefault("first_step", step_s)
-            with tracing.device_span("llm.step.fanout") as ds:
+            with self._phase("llm.step.fanout") as ds:
                 tokens = 0
                 for d, host in zip(due, read):
                     if isinstance(host, BaseException):
                         self._lost_fanout(d, host)
                     elif isinstance(d, _Step):
+                        turn.steps_read += 1
                         tokens += self._step_fanout(d, host, step_s,
                                                     step_span)
                         step_span = None  # recorded with its tokens
                     else:
+                        turn.firsts_read += len(d.rows)
                         tokens += self._first_fanout(d, *host)
                 ds.set_metadata(tokens=tokens)
                 self._flush_wakes(enqueued=keep > 0)
@@ -1334,8 +1547,6 @@ class LLMEngine:
                            trace_ctx=trace_ctx)
             self._push_queued_locked(req)
             self._phase_span_locked(req, "llm.queue")
-            self.stats_counters["queue_peak"] = max(
-                self.stats_counters["queue_peak"], self._n_queued)
             self._streams[rid] = st = req.stream
             if poller is not None:
                 st.poller = p = self._poller_locked(poller)
@@ -1572,6 +1783,8 @@ class LLMEngine:
             active = sum(1 for r in self._slot_req if r is not None)
             queued = self._n_queued
             c = dict(self.stats_counters)
+            # written in place by the drains; the loop's lists (turn_*,
+            # slow_turns) are put there whole and never written again
             c["deliver_lag_hist"] = list(c["deliver_lag_hist"])
         # chunks drained by either lane: every one is in a bucket
         c["deliver_chunks"] = sum(c["deliver_lag_hist"])
