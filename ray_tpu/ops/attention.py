@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import ring_decode
+from ray_tpu.ops import latent_chunk, ring_decode
 from ray_tpu.ops.flash_attention import flash_block, flash_causal_attention
 
 # Sequence length at/above which the flash kernel pays for itself.
@@ -858,6 +858,15 @@ def _whole_ring_sums(q, k, v, k_new, v_new, cursor, valid, hd, scale):
 # One layer's chunk of 256 queries over 4352 keys: 2.38 / 1.97 / 2.65 /
 # 3.12 / 5.63 ms at 128 / 256 / 384 / 512 / 1024 (a block that is no
 # multiple of 128 lanes costs five times as much).
+# Since PR 59 the chunk's XLA loop, and with it ``LATENT_CHUNK_BLOCK``, is
+# what the shapes run that ``latent_chunk.takes_kernel`` turns away: a rank
+# or a head of no whole 128-lane tiles (every tiny preset of the CPU tests
+# and of the benchmark's rehearsals), a ring of no whole kernel blocks, a
+# chunk of no whole sublane tiles. Whole-tile shapes, the published widths
+# among them, go through the kernel of ``ops/latent_chunk.py``, whose block
+# is its own (``latent_chunk.BLOCK_ROWS``). The 256 stands for the loop: it
+# was swept where a block's cost is its scores' trips to memory, and that is
+# still what the loop is wherever it runs.
 LATENT_DECODE_BLOCK = 1024
 LATENT_CHUNK_BLOCK = 256
 
@@ -965,19 +974,37 @@ def latent_chunk_attention(q_nope: jax.Array, q_pe: jax.Array,
     rope] the layer loop carries; w_uk [rank, H, nope] and w_uv [rank, H,
     v] the products that make a head's key and value of a latent. Query i
     sees the slot's rows ``<= start + i``. The ring is read in blocks of
-    ``block`` rows up to the last one that holds such a row (``start +
-    C``): each block's keys and values are decompressed (scope ``kv_up``),
-    scored against the chunk's queries, ``CHUNK_QUERIES`` of them at a
-    time, and folded into their running softmax, so the work follows the
-    keys in sight and not the longest prompt. ->
-    [R, C, H, v] in q's type. Operands in the cache's type, float32
-    scores, statistics and sums."""
+    rows up to the last one that holds such a row (``start + C``): each
+    block's keys and values are decompressed, scored against the chunk's
+    queries and folded into their running softmax, so the work follows the
+    keys in sight and not the longest prompt. -> [R, C, H, v] in q's type.
+    Operands in the cache's type, float32 scores, statistics and sums.
+
+    One algorithm, two implementations, chosen by whether Mosaic can tile
+    the shapes (``latent_chunk.takes_kernel``). Latents, keys and values of
+    whole 128-lane tiles over a ring of whole blocks: the Pallas kernel of
+    ``ops/latent_chunk.py``, a call a row, handed the row's own slot of
+    this layer copied out of the stack (a custom call's operand is
+    row-major; the stack lies ring-rows-minor), in which a block's keys,
+    values and scores never leave VMEM. Everything else (toy widths): the
+    XLA loop below over blocks of ``block`` rows, the block decompressed
+    under scope ``kv_up`` and met by the chunk's queries ``CHUNK_QUERIES``
+    at a time, its scores through memory."""
     r, c, h, _ = q_nope.shape
     rank, v_dim = w_uv.shape[0], w_uv.shape[-1]
     n_rows = cache.shape[2]
     block = min(block, n_rows)
     dt_ = cache.dtype
     w_uk, w_uv = w_uk.astype(dt_), w_uv.astype(dt_)
+    if latent_chunk.takes_kernel(c, rank, q_nope.shape[-1], v_dim, n_rows):
+        def ring_of(i):  # [L, W]: row i's slot of this layer, copied out
+            return jax.lax.dynamic_slice(
+                cache, (layer, slots[i], 0, 0, 0),
+                (1, 1, n_rows, 1, cache.shape[-1]))[0, 0, :, 0]
+        return jnp.stack([latent_chunk.latent_chunk_attention(
+            q_nope[i].astype(dt_), q_pe[i].astype(dt_), ring_of(i),
+            start[i], w_uk, w_uv, scale) for i in range(r)]).astype(
+                q_nope.dtype)
 
     # a block is decompressed once and meets the chunk's queries a group
     # at a time (``CHUNK_QUERIES``; a chunk that is no whole number of

@@ -9,8 +9,10 @@ chunks): nothing runs, so nothing here is a time. It holds that both
 programs fit the chip beside their arguments with next to nothing of their
 own (no float32 copy of a window, no scores over a whole ring: both
 attentions read the ring in blocks), that the donated cache is updated in
-its own buffers and lies in ONE unpadded layout in both programs, and that
-the chunk's attention is a loop whose trip count the positions decide.
+its own buffers and lies in ONE unpadded layout in both programs, that the
+step's attention is a loop whose trip count the positions decide and, since
+PR 59, that the chunk's is one call a layer of the kernel of
+``ops/latent_chunk.py``, whose loop is its own.
 
 The topology is described inside a fixture, in this one file: only the
 worker that runs this file loads the TPU's library.
@@ -131,13 +133,39 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, which):
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_rings_are_read_in_blocks_where_they_lie(compiled, which):
     """No float32 array as long as a ring, no second array of the rings'
-    size, and the block loop is there: a ``while`` in both programs."""
+    size, and the block loop is there: a ``while`` in the step's program,
+    none in the chunk's (PR 59: its blocks are walked inside the kernel)."""
     text = compiled[which].as_text()
     assert len(text) > 100_000, "read no program"
     assert not re.search(r"f32\[[\d,]*\b16896\b[\d,]*\]", text)
     assert not re.search(r"f32\[[\d,]*\b16384\b[\d,]*\]", text)
     assert not re.search(r"bf16\[65,16896,[\d,]*\]", text)  # a layer's rings
-    assert re.search(r" while\(", text)
+    assert bool(re.search(r" while\(", text)) == (which == "decode")
+
+
+def test_the_chunk_attends_through_the_kernel(compiled, cfg):
+    """PR 59: the chunk's attention is ONE custom call a layer of the
+    kernel of ``ops/latent_chunk.py`` under scope ``attn``, handed the
+    row's own slot of the layer's rings (one ring of 16896 rows copied out,
+    its 576 columns in five lane tiles) and the layer's ``w_uk`` and
+    ``w_uv``; a block's keys, values and scores live inside it, so the
+    program holds no float32 scores of a block ([128, 256, 256] a group of
+    queries before) and no decompressed block ([256, 128, 128])."""
+    text = compiled["prefill"].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "%latent_chunk_attention" in line]
+    assert len(calls) == cfg.n_layer == 5
+    for line in calls:
+        assert re.search(r'op_name="[^"]*/attn/[^"]*latent_chunk_attention/'
+                         r'pallas_call', line), line[-300:]
+        handed = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                           line).group(1)
+        assert handed.count("bf16[16896,640]") == 1, handed
+        assert handed.count("bf16[512,16384]") == 3, handed  # q, w_uk, w_uv
+    assert "%latent_chunk_attention" not in compiled["decode"].as_text()
+    assert not re.search(r"f32\[128,(256|512),(256|512)\]", text)
+    assert not re.search(r"bf16\[256,128,128\]", text)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
