@@ -6,9 +6,9 @@ hybrids, Nemotron-H (Mamba-2, attention and latent-MoE layers) and Granite
 4.0-H (a Mamba-2 mixer or attention, then gated experts, in every layer).
 
 Not exported here, so that a process which serves another family never
-imports them; ``LLMEngine`` resolves them by name, as it does all nine
+imports them; ``LLMEngine`` resolves them by name, as it does all ten
 served families (gpt2, llama, nemotron_h, granite_hybrid, deepseek_v2,
-falcon_h1, qwen3_next, smallthinker, exaone_moe;
+falcon_h1, qwen3_next, smallthinker, exaone_moe, keye_vl2;
 ``serve/llm_engine._model_bundle``):
 DeepSeek-V2 (``models/deepseek_v2.py``: latent attention over a latent cache, group-
 limited experts), Falcon-H1 (``models/falcon_h1.py``: rotary grouped-query
@@ -24,8 +24,12 @@ attention, gated-ReLU experts all held) and K-EXAONE
 global layer without a position embedding, each sublayer's norm after it, a
 sigmoid router beside a shared expert, and a multi-token-prediction module
 that the engine serves as the model's own draft: a step verifies two rows a
-slot and yields one or two tokens). ``models/resnet.py`` is imported by its
-path too.
+slot and yields one or two tokens) and Keye-VL-2.0's language model
+(``models/keye_vl2.py``: grouped-query attention that reads only the keys a
+learned indexer picks a query, an exact top-k without a sort, a decode step
+that gathers its picks and a chunk masked by its queries' sets, an
+indexer-key ring beside the K/V ring, softmax top-8-of-128 experts; text
+only). ``models/resnet.py`` is imported by its path too.
 
 Models are plain functions over parameter pytrees — no framework Module
 state — so the same code runs under any mesh and any rules table.

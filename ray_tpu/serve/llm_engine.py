@@ -348,10 +348,17 @@ def _model_bundle(model: str, config, preset: str):
         return (cfg, m.exaone_moe_init, m.exaone_moe_init_cache,
                 m.exaone_moe_prefill_chunk, m.exaone_moe_decode_step,
                 m.exaone_moe_verify_step)
+    if model == "keye_vl2":
+        from ray_tpu.models import keye_vl2 as m
+
+        cfg = config or (m.KeyeVL2Config.tiny() if preset == "tiny"
+                         else m.KeyeVL2Config())
+        return (cfg, m.keye_vl2_init, m.keye_vl2_init_cache,
+                m.keye_vl2_prefill_chunk, m.keye_vl2_decode_step)
     raise ValueError(
         f"unknown model family {model!r} (want gpt2|llama|nemotron_h|"
         f"granite_hybrid|deepseek_v2|falcon_h1|qwen3_next|smallthinker|"
-        f"exaone_moe)")
+        f"exaone_moe|keye_vl2)")
 
 
 def _stored_params(init, key, cfg):

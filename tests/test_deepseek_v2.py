@@ -783,6 +783,12 @@ LOWERED = {
         "db132a14a010f4d5bace24c22505d6fb25cbed8246ddeb6e467f111047bdd3bc",
     ("exaone_moe", "prefill"):
         "845f58142694409faac7e39d5f7448b2822bab95606b6b9fac193c4796b4b92b",
+    # PR 60: the tenth family (its step gathers the rows its indexer
+    # picks; its chunk's toy widths keep the XLA arm)
+    ("keye_vl2", "decode"):
+        "4e080fe1d920c5f1483c176d2b57892096201b2aa98fd1b01b2bc94e79d9cfdc",
+    ("keye_vl2", "prefill"):
+        "00874abaafcb7d620379a1362ee8f8793a36cb85eae52b6db3b7644c44e94a95",
 }
 
 

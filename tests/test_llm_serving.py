@@ -23,8 +23,8 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models import (deepseek_v2, exaone_moe, falcon_h1, gpt2,
-                            granite_hybrid, llama, nemotron_h, qwen3_next,
-                            smallthinker)
+                            granite_hybrid, keye_vl2, llama, nemotron_h,
+                            qwen3_next, smallthinker)
 from ray_tpu.serve import _observability as obs
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve._observability import RequestShedError
@@ -86,6 +86,10 @@ SERVED = {
     "exaone_moe": (exaone_moe.ExaoneMoeConfig.tiny(**_FP32),
                    lambda params, tokens, cfg: exaone_moe.exaone_moe_forward(
                        params, tokens, cfg)[0]),
+    # (a query reads the 16 keys its indexer picks: every generation here
+    # runs past 16 positions, so its later steps select)
+    "keye_vl2": (keye_vl2.KeyeVL2Config.tiny(**_FP32),
+                 keye_vl2.keye_vl2_forward),
 }
 every_family = pytest.mark.parametrize("model", list(SERVED))
 PROMPT = [5, 9, 2, 17, 3]

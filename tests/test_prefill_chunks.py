@@ -17,7 +17,8 @@ import pytest
 
 from benchmark.loading import load_json, load_module
 from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            llama, nemotron_h, qwen3_next, smallthinker)
+                            keye_vl2, llama, nemotron_h, qwen3_next,
+                            smallthinker)
 from ray_tpu.models.prefill import (chunk_len, key_window, token_parameters,
                                     whole_prompts)
 from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
@@ -75,6 +76,13 @@ FAMILIES = {
         smallthinker.smallthinker_init, smallthinker.smallthinker_init_cache,
         smallthinker.smallthinker_prefill_chunk,
         smallthinker.smallthinker_prefill, smallthinker.smallthinker_forward),
+    # (a query reads the 8 keys its indexer picks: prompts of up to
+    # MAX_PROMPT tokens select from their ninth token on, across chunks)
+    "keye_vl2": (keye_vl2.KeyeVL2Config.tiny(
+        dtype=F32, param_dtype=F32, index_topk=8),
+        keye_vl2.keye_vl2_init, keye_vl2.keye_vl2_init_cache,
+        keye_vl2.keye_vl2_prefill_chunk, keye_vl2.keye_vl2_prefill,
+        keye_vl2.keye_vl2_forward),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 # GPT-2's merged, lane-padded rows at the head counts it is served with: XL's
@@ -142,10 +150,11 @@ def _whole(family, params, cache, prompt, slot):
 def _holds_rows(path, a):
     """K/V or latent rows [layer, slot, row, head, hd], GPT-2's merged
     [layer, slot, row, W] (SmallThinker's two stacks of them, ``k_full``
-    and ``k_win``); the rest is Mamba state."""
+    and ``k_win``; Keye-VL-2.0's K/V rows ``kv`` and its indexer's keys
+    ``idx``); the rest is Mamba state."""
     return a.ndim == 5 or jax.tree_util.keystr(path) in (
         "['k']", "['v']", "['k_full']", "['v_full']", "['k_win']",
-        "['v_win']")
+        "['v_win']", "['kv']", "['idx']")
 
 
 def _assert_same_state(family, got, want, slot, n):
@@ -321,6 +330,7 @@ PUBLISHED = {
     "smallthinker": ("smallthinker-21b-a3b-instruct",
                      "smallthinker_1chip_b48"),
     "exaone_moe": ("k-exaone-236b-a23b", "kexaone_1chip_b64"),
+    "keye_vl2": ("keye-vl-2.0-30b-a3b", "keyevl2_1chip_b16"),
 }
 
 
@@ -357,6 +367,7 @@ def _case(model, chunk, sizes="published", **engine):
         "experts-qwen3-next": _case("qwen3_next", 512),
         "experts-smallthinker": _case("smallthinker", 512),
         "experts-exaone-moe": _case("exaone_moe", 512),
+        "experts-keye-vl2": _case("keye_vl2", 512),
         "experts-tiny": _case("granite_hybrid", 512, "tiny",
                               max_prompt_len=700, cache_len=1024),
         # no longer than the longest prompt

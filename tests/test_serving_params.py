@@ -20,7 +20,7 @@ import pytest
 
 from ray_tpu.models import gpt2, llama
 from ray_tpu.models import nemotron_h as nh
-from ray_tpu.models import exaone_moe, smallthinker
+from ray_tpu.models import exaone_moe, keye_vl2, smallthinker
 from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle, _stored_params
 
 # Sizes no other test uses, so that `jax.live_arrays()` can be asked for a
@@ -35,6 +35,7 @@ CONFIGS = {
     "nemotron_h": nh.NemotronHConfig.tiny(),
     "smallthinker": smallthinker.SmallThinkerConfig.tiny(),
     "exaone_moe": exaone_moe.ExaoneMoeConfig.tiny(),
+    "keye_vl2": keye_vl2.KeyeVL2Config.tiny(),
 }
 NORMS = {"gpt2": {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
                   "lnf_scale", "lnf_bias"},
@@ -186,7 +187,7 @@ def test_no_program_casts_a_parameter_the_engine_stored(name):
 
 
 @pytest.mark.parametrize("name", ["nemotron_h", "smallthinker",
-                                  "exaone_moe"])
+                                  "exaone_moe", "keye_vl2"])
 def test_a_family_that_stores_what_it_multiplies_with_gets_its_arrays_back(
         name):
     """The family is handed back leaf for leaf: the very arrays its

@@ -1,0 +1,317 @@
+"""What the TPU's compiler makes of Keye-VL-2.0's two serving programs.
+
+Compile-only, for one described v5e chip, at the published widths of
+``benchmark/configs/keye-vl-2.0-30b-a3b.json`` and the shapes of the cell
+``serve_keyevl2_sparsectx_sat`` (16 slots and the scratch one, TWO stacks
+of rings of 33792 rows in one donated pytree: K/V rows of 1024 columns, a
+token's merged K row and V row side by side, and the indexer's keys of 64;
+16 of 128 experts a layer held, prompts of up to 32768 tokens in the
+engine's [1, 512] chunks over a key window of 32768): nothing runs, so
+nothing here is a time. It holds that both programs fit the chip beside
+their arguments (11.7 GB of weights and cache) with less than 2 GB of
+temporaries, that the donated cache is updated in its own buffers, that
+neither program copies the K/V stack or a ring of it or widens one to
+float32, that each writes each stack once, that the step GATHERS its picked
+rows out of the K/V stack (one gather a layer, no slice of a ring as long
+as a context), that the chunk program attends through the kernel of
+``ops/sparse_chunk.py``, handed the K/V stack as it lies, and holds one
+branch a key window whose operands are the slot's indexer keys, not a
+stack, that no approximate top-k and no sort is on either path, and that
+the K/V stack keeps one row-minor layout in both programs.
+
+The topology is described inside a fixture, in this one file: only the
+worker that runs this file loads the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.loading import load_json, load_module
+from ray_tpu.models import keye_vl2 as kv
+from ray_tpu.models.prefill import (chunk_len, key_window,
+                                    token_parameters)
+from ray_tpu.ops.sparse_select import chunk_windows
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 15.75 * 2 ** 30
+KV = "bf16[8,17,33792,1024]"
+IDX = "bf16[8,17,33792,64]"
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return load_json(os.path.join(
+        REPO, "benchmark", "deployments",
+        "keyevl2_1chip_b16.json"))["engine"]
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    family = load_module(os.path.join(REPO, "benchmark", "families",
+                                      "keye_vl2.py"))
+    return family.system_config(load_json(os.path.join(
+        REPO, "benchmark", "configs", "keye-vl-2.0-30b-a3b.json")))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to say
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip, cfg, engine):
+    """Both programs as the engine jits them (cache donated), compiled
+    once for the module, with the persistent cache out of the way: such a
+    compile is written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = engine["max_batch"] + 1
+    params = sds(jax.eval_shape(
+        lambda: kv.keye_vl2_init(jax.random.PRNGKey(0), cfg)))
+    chunk = chunk_len(  # as the engine derives it
+        engine["max_prompt_len"], *token_parameters(cfg, params),
+        cache_len=engine["cache_len"])
+    window = key_window(engine["max_prompt_len"], chunk)
+    assert (slots, chunk, window, engine["cache_len"]) \
+        == (17, 512, 32768, 33792)
+    cache = sds(jax.eval_shape(lambda: kv.keye_vl2_init_cache(
+        cfg, slots, engine["cache_len"])))
+    programs = {
+        "decode": (lambda p, c, t, n: kv.keye_vl2_decode_step(
+            p, c, t, n, cfg), (params, cache, i32(slots), i32(slots))),
+        "prefill": (lambda p, c, t, s, at, n: kv.keye_vl2_prefill_chunk(
+            p, c, t, s, at, n, cfg, window=window),
+            (params, cache, i32(1, chunk), i32(1), i32(1), i32(1))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        # the kernels pick interpret mode from the process's backend, the
+        # CPU here: while the programs are traced it says the chip's, so
+        # the programs hold the experts' kernel, not the interpreter's loops
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            return {name: jax.jit(fn, donate_argnums=(1,)).lower(
+                *args).compile() for name, (fn, args) in programs.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
+                                                        which):
+    """852,988,928 bfloat16 parameters (1.71 GB) and 10.00 GB of cache are
+    the arguments; the cache is aliased to the output, so it is held once;
+    the temporaries stay under 2 GB, and with the kernel under 0.6 (a
+    chunk's scores never leave VMEM: what is left is its index scores, a
+    group of queries at a time, their keys and the selection's bias)."""
+    mem = compiled[which].memory_analysis()
+    cache_bytes = nbytes((8, 17, 33792, 1024), 2) \
+        + nbytes((8, 17, 33792, 64), 2) + 4
+    assert cache_bytes == 17 * 8 * 33792 * 2176 + 4 == 10_000_269_316
+    assert mem.alias_size_in_bytes >= cache_bytes
+    gb = {k: getattr(mem, k + "_size_in_bytes") / 1e9
+          for k in ("argument", "temp", "alias", "output")}
+    print(which, gb)
+    assert 11.70e9 < mem.argument_size_in_bytes < 11.72e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
+    assert mem.temp_size_in_bytes < {"decode": 0.5e9, "prefill": 0.6e9}[which]
+
+
+SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                   r"([\w\-]+)\(")
+RINGS = {nbytes((17, 33792, 1024), 1)}
+STACKS = {nbytes((8, 17, 33792, 1024), 1), nbytes((8, 17, 33792, 64), 1)}
+IDX_RING = nbytes((17, 33792, 64), 1)
+
+
+def _unfused(hlo_text):
+    """The text of every computation but the ones a ``fusion`` calls:
+    inside a fusion a slice or a convert is a step of one loop, not a
+    buffer."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    return "\n".join(block for block in hlo_text.split("\n\n")
+                     if block.lstrip().split(" ", 1)[0] not in fused)
+
+
+def _arrays_made(hlo_text):
+    """(type, elements, opcode) of every instruction of ``hlo_text`` that
+    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
+    slices."""
+    for line in hlo_text.splitlines():
+        m = SHAPE.match(line)
+        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
+                                "dynamic-slice"):
+            yield m.group(1), nbytes(
+                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_no_ring_is_copied_or_widened(compiled, which):
+    """A layer's K/V rings are 17 x 33792 x 1024 bfloat16 (1.18 GB).
+    Neither program widens them to float32, and neither makes a copy of
+    them or of a stack in any type."""
+    text = compiled[which].as_text()
+    made = list(_arrays_made(_unfused(text)))
+    assert len(made) > 50, "read no program"
+    assert [m for m in made if m[0] == "f32"
+            and m[1] >= nbytes((17, 33792, 512), 1)] == []
+    assert [m for m in _arrays_made(text)
+            if m[1] in RINGS | STACKS and m[2] == "copy"] == []
+
+
+RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def _made_as_large_as(text, sizes):
+    """(opcode, first operand) of every instruction that gives out an array
+    of one of ``sizes`` elements (a tuple's members counted each) and does
+    not merely hand one on."""
+    made = []
+    for line in text.splitlines():
+        m = RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                nbytes([int(d) for d in dims.split(",")], 1) in sizes
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    return made
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_each_stack_is_written_once_and_nothing_else_is_as_large(compiled,
+                                                                 which):
+    """The K/V stack is 9.41 GB, the indexer's keys 0.59 GB. Both programs
+    read the rings before they write their own rows, so all either does to
+    a stack is row-sized ``dynamic-update-slice``s into the donated buffer
+    after the layer loop (one a slot in the step, one in the chunk): no
+    fusion, copy or anything else gives out an array as large as a stack or
+    as a layer's K/V rings; in particular no branch of the chunk's
+    conditional takes a stack as its operand. (The step cuts a layer's
+    indexer keys out of their stack for its scores, 73 MB a layer: PERF.md
+    section 7 has it among what is left on the table.)"""
+    text = compiled[which].as_text()
+    made = _made_as_large_as(text, RINGS | STACKS)
+    assert {op for op, _ in made} == {"dynamic-update-slice"}, made
+    writes = 17 if which == "decode" else 1
+    assert len(made) == 2 * writes, made
+    cut = [op for op, _ in _made_as_large_as(text, {IDX_RING})]
+    assert len(cut) <= (16 if which == "decode" else 0), cut
+
+
+def _no_sort_under_attention(text):
+    """The router's top 8 of 128 and the experts' dispatch sort (128
+    numbers a token, the step's pairs by expert); nothing under ``attn``
+    sorts, and nothing anywhere takes an approximate top-k."""
+    sorts = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if re.search(r" sort\(|TopK|top_k", line)
+             and "op_name=" in line]
+    assert sorts, "read no program"
+    assert [name for name in sorts if "/attn/" in name] == []
+    assert "approx" not in text.lower()
+
+
+def test_the_step_gathers_its_rows_and_sorts_nothing(compiled):
+    """The step's attention reads ``topk`` rows a slot a layer: ONE gather
+    a layer out of the K/V stack as a table of rows, giving 17 x 2048 rows
+    of 1024 columns, and nothing cut out of a K/V ring. No sort, no
+    ``top_k`` and no approximate one on the path: the selection is a search
+    for the threshold (``ops/sparse_select.kth_largest``)."""
+    text = compiled["decode"].as_text()
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= bf16\[34816,1024\]\S* gather\(", line)]
+    assert len(gathers) == 8, len(gathers)
+    _no_sort_under_attention(text)
+
+
+def test_the_chunk_attends_through_its_kernel_and_sorts_nothing(compiled):
+    """One ``conditional`` a layer over ``chunk_windows`` (512 doubling to
+    32768: seven branches) that scores and picks, and one custom call of
+    the kernel of ``ops/sparse_chunk.py`` a layer, under scope
+    ``attn_sparse``, handed the K/V stack twice as it lies (its K half and
+    its V half are column blocks of one array) and the selection as two
+    bfloat16 biases; no sort or top-k of any kind."""
+    text = compiled["prefill"].as_text()
+    windows = chunk_windows(512, 32768)
+    assert windows == (512, 1024, 2048, 4096, 8192, 16384, 32768)
+    selects = [line for line in text.splitlines()
+               if " conditional(" in line
+               and "branch_computations" in line
+               and len(re.findall(r"branch_computations=\{([^}]*)\}",
+                                  line)[0].split(",")) == len(windows)]
+    assert len(selects) == 8, len(selects)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "sparse_chunk_attention" in line]
+    assert len(calls) == 8, len(calls)
+    for line in calls:
+        assert re.search(r'op_name="[^"]*/attn/attn_sparse/', line), \
+            line[-300:]
+        handed = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                           line).group(1)
+        assert handed.count(KV) == 2, handed
+        assert handed.count("bf16[512,32256]") == 1, handed
+    _no_sort_under_attention(text)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_experts_run_through_the_kernel(compiled, cfg, which,
+                                            experts_through_the_kernel):
+    """PR 52: in both of the engine's programs the gated experts' two
+    products are ONE custom call of the kernel of ``ops/moe_experts.py`` a
+    layer, under scope ``experts``, handed the layer's 16 x 2048 x 1536 and
+    16 x 768 x 2048 stacks as they lie (16 held experts at 1 to 3 rows an
+    expert a step take the kernel as 64 and 128 do)."""
+    assert (cfg.d_model, cfg.expert_ff, cfg.experts_held) \
+        == (2048, 768, (0, 16))
+    experts_through_the_kernel(compiled[which], cfg.n_layer, 16, 2048,
+                               1536, 768)
+
+
+def test_the_chunk_keeps_the_cache_in_the_steps_layout(compiled):
+    """Both stacks: each shape has one layout as a whole array in the chunk
+    program, and it is the decode program's, so none is re-laid out between
+    the two; the K/V rings are row-minor (a row of 1024 columns is eight
+    whole lane tiles), which is how the kernel takes them."""
+    def layouts(shape, which):
+        # (a trailing S(n) names a memory space, not a layout)
+        text = re.sub(r"operand_layout_constraints=\{[^=]*\}, ", "",
+                      compiled[which].as_text())
+        return {re.sub(r"S\(\d+\)", "", found) for found in re.findall(
+            re.escape(shape) + r"(\{[^}]*\})", text)}
+
+    for shape in (KV, IDX):
+        assert len(layouts(shape, "prefill")) == 1, shape
+        assert layouts(shape, "prefill") == layouts(shape, "decode"), shape
+    assert all(found.startswith("{3,2,1,0")
+               for found in layouts(KV, "decode"))
