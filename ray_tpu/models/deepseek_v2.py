@@ -143,7 +143,7 @@ class DeepseekV2Config:
         them (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters."""
         return {"expert_layers": self.n_layer - self.first_dense,
                 "experts_held": self.experts_held[1]}

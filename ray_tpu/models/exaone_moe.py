@@ -172,7 +172,7 @@ class ExaoneMoeConfig:
         them (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters
         (``SmallThinkerConfig.serving_stats``); the module's ring counts
         among the global ones."""

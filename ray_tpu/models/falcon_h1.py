@@ -150,7 +150,7 @@ class FalconH1Config:
         (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside the engine's own
         counters: the token-expert pairs its chunks make, none (the family
         has no experts). The benchmark's reader of the chunk program takes

@@ -125,7 +125,7 @@ class GraniteHybridConfig:
         them (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters."""
         return {"expert_layers": len(self.layer_types),
                 "experts_held": self.experts_held[1]}

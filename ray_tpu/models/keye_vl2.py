@@ -61,7 +61,8 @@ from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
-from ray_tpu.ops.sparse_select import (index_scores, select_mask, sort_keys,
+from ray_tpu.ops.sparse_select import (chunk_select, index_scores,
+                                       select_mask, sort_keys,
                                        sparse_chunk_attention,
                                        sparse_decode_attention)
 
@@ -135,17 +136,24 @@ class KeyeVL2Config:
         (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters, so
         that a reader holds no shape of its own: the ring bytes a token
-        takes in the K/V stacks and in the indexer's, and how many keys a
-        query may pick."""
+        takes in the K/V stacks and in the indexer's, how many keys a
+        query may pick, and which implementation a chunk program of
+        ``chunk`` tokens over a key window of ``window`` rows (the
+        engine's) picks and attends through
+        (``ops/sparse_select.chunk_select``: the choice is static, by
+        shapes alone; an engine with no ring to read runs the XLA arm)."""
         act = jnp.dtype(self.dtype).itemsize
         return {
             "expert_layers": self.n_layer,
             "experts_held": self.experts_held[1],
             "sparse_layers": self.n_layer,
             "sparse_topk": self.index_topk,
+            "sparse_chunk_select": chunk_select(
+                chunk, self.head_dim, self.row_width,
+                max(window - chunk, 0), self.index_heads, self.index_dim),
             "kv_bytes_per_token": 2 * self.n_layer * self.row_width * act,
             "index_bytes_per_token": self.n_layer * self.index_dim * act,
         }

@@ -131,7 +131,7 @@ class NemotronHConfig:
         stay in the type the configuration's file states for them."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters."""
         return {"expert_layers": self.count("E"),
                 "experts_held": self.experts_held[1]}

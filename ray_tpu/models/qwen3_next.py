@@ -161,7 +161,7 @@ class Qwen3NextConfig:
         (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters, so
         that a reader holds no shape of its own: a slot's delta state and
         convolution tails over the linear layers, and the ring bytes a

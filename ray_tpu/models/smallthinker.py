@@ -146,7 +146,7 @@ class SmallThinkerConfig:
         them (see ``NemotronHConfig.serving_dtypes``)."""
         return jax.tree.map(lambda x: x.dtype, params)
 
-    def serving_stats(self) -> dict:
+    def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters, so
         that a reader holds no shape of its own: the ring bytes a token
         takes in a global layer's ring and in a window layer's, and how

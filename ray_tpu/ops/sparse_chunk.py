@@ -18,8 +18,9 @@ each query head's float32 scores of the block meet the running maximum, sum
 and sums and are gone.
 
 The selection comes in as an additive BIAS in bfloat16, 0 where the query
-picked the key and -1e30 where it did not (``select_mask``'s mask, which
-also holds causality and the padded rows): one array for the ring's rows
+picked the key and -1e30 where it did not (what the kernel of
+``ops/sparse_pick.py`` writes: ``select_mask``'s mask, which also holds
+causality and the padded rows): one array for the ring's rows
 ``[C, keys]`` and one for the chunk's own ``[C, C]``, which are not in the
 ring yet (block 0 of the grid; the ring is read as it was). A ring block
 that lies wholly at or past ``start`` holds no key the chunk may see: its

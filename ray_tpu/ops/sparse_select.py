@@ -19,8 +19,14 @@ over positions run only where a row really has such ties). Nothing on the
 path may return another set (no ``approx_max_k``).
 
 A prompt chunk attends under the mask (``sparse_chunk_attention``: its
-queries' sets differ, so the keys in sight are scored for all and masked);
-a decode step turns its mask into positions (``mask_indices``: counts a
+queries' sets differ, so the keys in sight are scored for all and masked).
+At shapes Mosaic can tile (``chunk_select``) that is two Pallas kernels a
+layer: ``ops/sparse_pick.py`` makes the index scores and the exact top-k of
+a block of queries without their leaving VMEM and hands the selection on as
+a bias, and ``ops/sparse_chunk.py`` attends under it; ``index_scores``,
+``select_mask`` and the doubling windows of ``chunk_windows`` are then the
+decode step's, the toy widths' and the kernels' reference in the tests.
+A decode step turns its mask into positions (``mask_indices``: counts a
 block of 128 candidates, then the picked lane inside the block, all dense
 products) and GATHERS those rows out of the K/V rings
 (``sparse_decode_attention``): the step's traffic is ``topk`` rows a slot a
@@ -42,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import sparse_chunk
+from ray_tpu.ops import sparse_chunk, sparse_pick
 from ray_tpu.ops.attention import (_chunk_query_rows, _heads_merged,
                                    _heads_out_of_group_columns, _kv_heads,
                                    _lane_cols, _lane_groups, _online_softmax,
@@ -306,6 +312,42 @@ def _masked_softmax(q: jax.Array, rows: jax.Array, parts: list,
     return out.reshape(r, c, h, hd).astype(q.dtype)
 
 
+def chunk_select(c: int, hd: int, w: int, old: int, heads: int,
+                 di: int) -> str:
+    """Which of ``sparse_chunk_attention``'s two implementations a chunk
+    program of these shapes is built with, ``"kernel"`` or ``"xla"``: a
+    chunk of c queries with heads of ``hd`` lanes in merged K rows of ``w``
+    columns over ``old`` ring rows, ``heads`` indexer heads over keys of
+    ``di``. Both kernels or neither: the picking kernel's bias is what the
+    attention kernel reads. (The picking kernel holds the window in VMEM:
+    past some 171 thousand rows at the published widths it does not fit,
+    ``sparse_pick.query_rows``.)"""
+    both = sparse_chunk.takes_kernel(c, hd, w, old) \
+        and sparse_pick.takes_kernel(c, heads, di, old)
+    return "kernel" if both else "xla"
+
+
+def _chunk_through_kernels(q, kv_all, idx_all, kv_own, idx_own, q_idx, w_idx,
+                           layer, slots, start, lengths, old, topk):
+    """``sparse_chunk_attention`` at shapes Mosaic can tile: a call of each
+    kernel a row, no branch and no window but the whole one (both kernels
+    stop at ``start`` by themselves)."""
+    rows = range(q.shape[0])
+    with jax.named_scope("select"):
+        # the slot's indexer keys cut out of their stack and transposed:
+        # the stack lies ring-rows-minor, so this is the cut's own copy
+        # (a Mosaic operand would have the whole stack re-laid)
+        idx_old = jnp.swapaxes(_slot_rows(idx_all, layer, slots, old), 1, 2)
+        bias = [sparse_pick.sparse_pick(
+            q_idx[i].astype(idx_all.dtype), w_idx[i], idx_own[i], idx_old[i],
+            start[i], lengths[i], topk) for i in rows]
+    with jax.named_scope("attn_sparse"):
+        out = jnp.stack([sparse_chunk.sparse_chunk_attention(
+            q[i].astype(kv_all.dtype), kv_all, kv_own[i], *bias[i], layer,
+            slots[i], start[i]) for i in rows]).astype(q.dtype)
+    return out, jnp.stack([jnp.concatenate(b, axis=-1) for b in bias]) == 0
+
+
 def sparse_chunk_attention(q: jax.Array, kv_all: jax.Array,
                            idx_all: jax.Array, kv_own: jax.Array,
                            idx_own: jax.Array, q_idx: jax.Array,
@@ -325,18 +367,23 @@ def sparse_chunk_attention(q: jax.Array, kv_all: jax.Array,
     scored against the slot's rows ``< start`` and the chunk's own ``<= i``
     (scope ``indexer``), picks ``min(topk, start + i + 1)`` of them exactly
     (``select``) and attends under that mask (``attn_sparse``). One
-    algorithm, two implementations of the attention, chosen by whether
-    Mosaic can tile the shapes (``sparse_chunk.takes_kernel``): heads of
-    whole lane tiles over a window of whole blocks go through the Pallas
-    kernel of ``ops/sparse_chunk.py``, a call a row, handed the stack as
-    it lies and the selection as a bias, the scores never leaving VMEM and
-    the ring blocks past ``start`` never fetched; everything else (toy
-    widths) runs ``_masked_softmax`` inside the branch. The work follows
-    the keys in sight: the program holds one branch a window of
-    ``chunk_windows`` and a chunk runs the least that holds ``start + C``
-    rows; inside a branch the queries are scored a group at a time
-    (``_group``) and picked all at once (a pass of the selection costs a
-    launch whatever it reads).
+    algorithm, two implementations, chosen by whether Mosaic can tile the
+    shapes (``chunk_select``), never by a name or a flag. Heads of whole
+    lane tiles and an indexer key of whole sublane tiles over a window of
+    whole blocks go through two Pallas kernels, a call of each a row
+    (``_chunk_through_kernels``): ``ops/sparse_pick.py`` under scope
+    ``select``, handed the slot's indexer keys cut out of their stack once
+    and transposed, holds a block of queries' index scores in VMEM through
+    every pass of the selection and returns it as a bias; ``ops/
+    sparse_chunk.py`` under ``attn_sparse``, handed the K/V stack as it
+    lies and that bias, keeps the attention's scores in VMEM. Neither
+    reads a ring block past ``start``, so the program holds no branch and
+    no window but the whole one. Everything else (toy widths, a single
+    chunk over no ring) is XLA: ``index_scores`` a group of queries at a
+    time (``_group``) under ``indexer``, ``select_mask`` over all of them
+    at once (a pass of the selection costs a launch whatever it reads) and
+    ``_masked_softmax``, inside one branch a window of ``chunk_windows``,
+    of which a chunk runs the least that holds ``start + C`` rows.
     -> (out [R, C, H, hd] in q's type, mask [R, C, window] bool: what each
     query picked, over the slot's first ``window - C`` ring rows (ring row
     = position; none at or past ``start``) and then the chunk's own rows:
@@ -345,16 +392,17 @@ def sparse_chunk_attention(q: jax.Array, kv_all: jax.Array,
     r, c, h, hd = q.shape
     w = kv_all.shape[-1] // 2
     old_max = window - c
-    kernel = sparse_chunk.takes_kernel(c, hd, w, old_max)
+    if chunk_select(c, hd, w, old_max, *q_idx.shape[2:]) == "kernel":
+        return _chunk_through_kernels(q, kv_all, idx_all, kv_own, idx_own,
+                                      q_idx, w_idx, layer, slots, start,
+                                      lengths, old_max, topk)
     real = jnp.arange(c)[None, :] < lengths[:, None]              # [R, C]
     sees = start[:, None] + jnp.arange(c)[None, :]                # [R, C]
     own_seen = jnp.tril(jnp.ones((c, c), bool))[None] & real[:, :, None]
     # the slots' rows are cut out of the stacks ONCE, before the branches,
     # as long as the longest window: a branch that cut its own would take
-    # the stacks as operands, and a conditional's operands are copied. The
-    # kernel reads K and V in the stack; the XLA arm needs them cut too.
-    cut = [None if not old_max or (kernel and x is kv_all)
-           else _slot_rows(x, layer, slots, old_max)
+    # the stacks as operands, and a conditional's operands are copied
+    cut = [_slot_rows(x, layer, slots, old_max) if old_max else None
            for x in (kv_all, idx_all)]
 
     def picks(old, i_old):
@@ -381,18 +429,7 @@ def sparse_chunk_attention(q: jax.Array, kv_all: jax.Array,
     def under(window_):
         old = window_ - c
 
-        def masked(cut):
-            """The kernel's arm: the selection as a bias over ALL of the
-            window's rows (the rows past this branch's: not picked)."""
-            mask = picks(old, cut[1][:, :old] if old else None)
-            with jax.named_scope("select"):
-                bias = jnp.where(mask, 0.0, -1e30).astype(jnp.bfloat16)
-                return (jnp.pad(bias[..., :old],
-                                ((0, 0), (0, 0), (0, old_max - old)),
-                                constant_values=-1e30), bias[..., old:])
-
         def attended(cut):
-            """The XLA arm: the branch attends too."""
             kv_old, i_old = (x if x is None else x[:, :old] for x in cut)
             mask = picks(old, i_old)
             query_rows = _chunk_query_rows(q, w, kv_all.dtype)
@@ -414,19 +451,10 @@ def sparse_chunk_attention(q: jax.Array, kv_all: jax.Array,
                          ((0, 0), (0, 0), (0, old_max - old))),
                  mask[..., old:]], axis=-1)
 
-        return masked if kernel else attended
+        return attended
 
     windows = chunk_windows(c, window)
     need = jnp.max(start) + c
-    got = jax.lax.switch(
+    return jax.lax.switch(
         jnp.sum(need > jnp.asarray(np.array(windows, np.int32))),
         [under(w_) for w_ in windows], cut)
-    if not kernel:
-        return got
-    bias_old, bias_own = got
-    with jax.named_scope("attn_sparse"):
-        return jnp.stack([sparse_chunk.sparse_chunk_attention(
-            q[i].astype(kv_all.dtype), kv_all, kv_own[i], bias_old[i],
-            bias_own[i], layer, slots[i], start[i])
-            for i in range(r)]).astype(q.dtype), jnp.concatenate(
-                [bias_old, bias_own], axis=-1) == 0

@@ -510,8 +510,10 @@ class LLMEngine:
         # family that returns none runs the program it always ran.
         self._step_counters: tuple = ()
         # What the model says of itself beside its counters (llm_stats).
-        self._model_stats = dict(
-            getattr(cfg, "serving_stats", lambda: {})())
+        # Every family's takes the engine's chunk and key window: one
+        # whose chunk program depends on them says which program that is.
+        self._model_stats = dict(getattr(
+            cfg, "serving_stats", lambda chunk, window: {})(chunk, window))
 
         def with_counters(out, counted):
             # the step's counters behind what it hands out: ONE array

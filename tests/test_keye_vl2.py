@@ -192,8 +192,16 @@ def test_the_cache_is_two_stacks_of_rings(which):
             CONFIG, 17, 33792)
         assert stats == {
             "expert_layers": 8, "experts_held": 16, "sparse_layers": 8,
-            "sparse_topk": 2048, "kv_bytes_per_token": 8 * 2048,
+            "sparse_topk": 2048, "sparse_chunk_select": "xla",
+            "kv_bytes_per_token": 8 * 2048,
             "index_bytes_per_token": 8 * 128}
+        # the chunk program's arm is the engine's shapes' to say: chunks
+        # of 512 over the cell's window take both kernels, a single chunk
+        # over no ring, or a ragged one, the XLA arm
+        assert [cfg.serving_stats(*at)["sparse_chunk_select"]
+                for at in ((512, 32768), (512, 33792), (512, 512),
+                           (500, 32768))] == ["kernel", "kernel", "xla",
+                                              "xla"]
 
 
 def test_every_counter_of_the_cache_is_a_buffer_of_its_own():
@@ -281,22 +289,28 @@ def test_bfloat16_stays_near_the_reference(tokens):
 def test_the_chunk_program_through_its_kernel_is_the_references(monkeypatch,
                                                                 lengths):
     """The chunk program AS THE CHIP RUNS IT: heads of 128 lanes, chunks of
-    128 queries and a window of 128 + 512 ring rows take the Pallas kernel
-    of ``ops/sparse_chunk.py`` (interpret mode here), where the tiny
-    preset's widths take the XLA arm. Prompts in five chunks (the
+    128 queries, indexer keys of 64 and a window of 128 + 512 ring rows
+    take the Pallas kernels of ``ops/sparse_pick.py`` and
+    ``ops/sparse_chunk.py`` (interpret mode here), where the tiny preset's
+    widths take the XLA arm. Prompts in five chunks (the
     selection starts inside the second; one prompt ends inside a chunk and
     its row runs on padded), then decode steps, against the reference's
     full forward, in float32."""
-    from ray_tpu.ops import sparse_chunk
+    from ray_tpu.ops import sparse_chunk, sparse_pick
 
     cfg = kv.KeyeVL2Config.tiny(dtype=F32, param_dtype=F32, n_layer=2,
-                                head_dim=128, index_topk=160)
-    calls = []
-    kernel = sparse_chunk.sparse_chunk_attention
+                                head_dim=128, index_dim=64, index_topk=160)
+    assert cfg.serving_stats(128, 640)["sparse_chunk_select"] == "kernel"
+    calls, picks = [], []
+    kernel, pick = sparse_chunk.sparse_chunk_attention, sparse_pick.sparse_pick
     monkeypatch.setattr(
         sparse_chunk, "sparse_chunk_attention",
         lambda *a, **k: calls.append(a[0].shape) or kernel(*a, **k))
-    params = moved(kv.keye_vl2_init(jax.random.PRNGKey(2), cfg))
+    monkeypatch.setattr(
+        sparse_pick, "sparse_pick",
+        lambda *a, **k: picks.append(a[0].shape) or pick(*a, **k))
+    # (key 2's draw at these widths turns a pick: the test below)
+    params = moved(kv.keye_vl2_init(jax.random.PRNGKey(4), cfg))
     toks = jax.random.randint(jax.random.PRNGKey(3), (2, 648), 0,
                               cfg.vocab_size)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -304,8 +318,70 @@ def test_the_chunk_program_through_its_kernel_is_the_references(monkeypatch,
                             cache_len=1024, padded=640)
     # a call a row a layer in every trace of the chunk
     assert len(calls) >= 4 and set(calls) == {(128, 4, 128)}
+    # and a call of the picking kernel before each: (C, J, dI)
+    assert len(picks) == len(calls) and set(picks) == {(128, 3, 64)}
     want = reference_rows(params, cfg, toks, lengths, 3)
     assert rel_l2(got, want) < 2e-4
+
+
+def test_the_pick_that_key_2_turns_lies_at_its_threshold_in_both_arms(
+        monkeypatch):
+    """Why the test above draws its parameters from key 4: at these widths
+    key 2's hold a near-tie. In the first layer the query at position 502
+    of the first prompt scores keys 52 and 316 within 1e-5 of each other
+    (of the scores' scale; 1e-6 apart at 29) and they are its 160th and
+    161st: which of them is picked is float32's rounding to say, and the
+    reference's own changes with how it is compiled. The kernels and the
+    XLA arm pick the SAME sets; in that layer they are the reference's but
+    for, at most, those two keys of that query. (Where the pick is turned,
+    the next layer's queries that read row 502 stand 8.8e-4 from the
+    reference, in both arms alike and in the parent's programs too: my
+    runs, PR 61.)"""
+    from ray_tpu.ops import sparse_chunk
+
+    cfg = kv.KeyeVL2Config.tiny(dtype=F32, param_dtype=F32, n_layer=2,
+                                head_dim=128, index_dim=64, index_topk=160)
+    params = moved(kv.keye_vl2_init(jax.random.PRNGKey(2), cfg))
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 640), 0,
+                              cfg.vocab_size)
+    chunk, window, lengths = 128, 640, jnp.asarray([600, 300], jnp.int32)
+
+    def picked():
+        """-> ([layer, R, T, T] bool by position, logits at the ends)"""
+        cache = kv.keye_vl2_init_cache(cfg, 3, 1024)
+        one = jax.jit(lambda c, t, at, n: kv.keye_vl2_chunk_with_sets(
+            params, c, t, jnp.arange(2), at, n, cfg, window=window))
+        sets = np.zeros((cfg.n_layer, 2, window, window), bool)
+        ends = {}
+        for at in range(0, window, chunk):
+            logits, cache, masks = one(
+                cache, toks[:, at:at + chunk], jnp.full((2,), at, jnp.int32),
+                jnp.clip(lengths - at, 0, chunk))
+            masks = np.asarray(masks)
+            sets[:, :, at:at + chunk, :at] = masks[..., :at]
+            sets[:, :, at:at + chunk, at:at + chunk] \
+                = masks[..., window - chunk:]
+            for row, n in enumerate(np.asarray(lengths)):
+                if at < n <= at + chunk:
+                    ends[row] = logits[row]
+        return sets, jnp.stack([ends[0], ends[1]])
+
+    assert cfg.serving_stats(chunk, window)["sparse_chunk_select"] == "kernel"
+    got, logits = picked()
+    monkeypatch.setattr(sparse_chunk, "takes_kernel", lambda *a: False)
+    xla, logits_too = picked()
+    np.testing.assert_array_equal(got, xla)
+    assert rel_l2(logits, logits_too) < 2e-4
+    _, ref = jax.jit(lambda t: reference.forward(
+        to_ref(params, cfg), t, with_sets=True, **ref_kwargs(cfg)))(toks)
+    scores, want = (np.asarray(x) for x in ref[0])
+    real = np.arange(window)[None] < np.asarray(lengths)[:, None]
+    turned = np.argwhere((got[0] != want) & real[:, :, None])
+    assert {tuple(x) for x in turned.tolist()} <= {(0, 502, 52), (0, 502, 316)}
+    assert got[0, 0, 502].sum() == want[0, 502].sum() == 160
+    at = scores[0, 502, :503]
+    assert sorted(np.argsort(-at, kind="stable")[159:161]) == [52, 316]
+    assert abs(at[52] - at[316]) <= 1e-5 * np.abs(at).max()
 
 
 CONTROLS = {"indexer_rotary": "none", "indexer_key_norm": "none"}
@@ -633,6 +709,7 @@ def test_the_engine_serves_the_references_greedy_tokens(runtime):
             <= stats["sparse_keys_eligible"]
         assert "prefill_sparse_keys_selected" not in stats
         assert stats["sparse_topk"] == TOPK and stats["sparse_layers"] == 3
+        assert stats["sparse_chunk_select"] == "xla"  # toy widths
     finally:
         engine.shutdown_engine()
 

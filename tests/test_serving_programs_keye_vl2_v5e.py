@@ -13,11 +13,13 @@ temporaries, that the donated cache is updated in its own buffers, that
 neither program copies the K/V stack or a ring of it or widens one to
 float32, that each writes each stack once, that the step GATHERS its picked
 rows out of the K/V stack (one gather a layer, no slice of a ring as long
-as a context), that the chunk program attends through the kernel of
-``ops/sparse_chunk.py``, handed the K/V stack as it lies, and holds one
-branch a key window whose operands are the slot's indexer keys, not a
-stack, that no approximate top-k and no sort is on either path, and that
-the K/V stack keeps one row-minor layout in both programs.
+as a context), that the chunk program picks through the kernel of
+``ops/sparse_pick.py``, handed the slot's indexer keys cut out of their
+stack and never the stack, and attends through the kernel of
+``ops/sparse_chunk.py``, handed the K/V stack as it lies, with no
+``conditional`` over key windows left (PR 61), that no approximate top-k
+and no sort is on either path, and that the K/V stack keeps one row-minor
+layout in both programs.
 
 The topology is described inside a fixture, in this one file: only the
 worker that runs this file loads the TPU's library.
@@ -34,7 +36,6 @@ from benchmark.loading import load_json, load_module
 from ray_tpu.models import keye_vl2 as kv
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
-from ray_tpu.ops.sparse_select import chunk_windows
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
@@ -131,9 +132,10 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
     """852,988,928 bfloat16 parameters (1.71 GB) and 10.00 GB of cache are
     the arguments; the cache is aliased to the output, so it is held once;
-    the temporaries stay under 2 GB, and with the kernel under 0.6 (a
-    chunk's scores never leave VMEM: what is left is its index scores, a
-    group of queries at a time, their keys and the selection's bias)."""
+    the temporaries stay under 2 GB, and with the two kernels under 0.4
+    (neither the index scores nor the attention's leave VMEM: what is left,
+    0.25 GB, is the eight cuts of the slot's indexer keys, made together,
+    and a layer's bias)."""
     mem = compiled[which].memory_analysis()
     cache_bytes = nbytes((8, 17, 33792, 1024), 2) \
         + nbytes((8, 17, 33792, 64), 2) + 4
@@ -144,7 +146,7 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     print(which, gb)
     assert 11.70e9 < mem.argument_size_in_bytes < 11.72e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
-    assert mem.temp_size_in_bytes < {"decode": 0.5e9, "prefill": 0.6e9}[which]
+    assert mem.temp_size_in_bytes < {"decode": 0.5e9, "prefill": 0.4e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
@@ -215,8 +217,8 @@ def test_each_stack_is_written_once_and_nothing_else_is_as_large(compiled,
     a stack is row-sized ``dynamic-update-slice``s into the donated buffer
     after the layer loop (one a slot in the step, one in the chunk): no
     fusion, copy or anything else gives out an array as large as a stack or
-    as a layer's K/V rings; in particular no branch of the chunk's
-    conditional takes a stack as its operand. (The step cuts a layer's
+    as a layer's K/V rings; in particular neither kernel of the chunk
+    program has a stack re-laid for it. (The step cuts a layer's
     indexer keys out of their stack for its scores, 73 MB a layer: PERF.md
     section 7 has it among what is left on the table.)"""
     text = compiled[which].as_text()
@@ -254,34 +256,79 @@ def test_the_step_gathers_its_rows_and_sorts_nothing(compiled):
     _no_sort_under_attention(text)
 
 
-def test_the_chunk_attends_through_its_kernel_and_sorts_nothing(compiled):
-    """One ``conditional`` a layer over ``chunk_windows`` (512 doubling to
-    32768: seven branches) that scores and picks, and one custom call of
-    the kernel of ``ops/sparse_chunk.py`` a layer, under scope
-    ``attn_sparse``, handed the K/V stack twice as it lies (its K half and
-    its V half are column blocks of one array) and the selection as two
-    bfloat16 biases; no sort or top-k of any kind."""
+def test_the_chunk_picks_and_attends_through_its_kernels(compiled, cfg):
+    """No ``conditional`` over key windows any more (PR 61): a layer's
+    selection is ONE custom call of the kernel of ``ops/sparse_pick.py``
+    under scope ``select``, handed the slot's indexer keys cut out of their
+    stack and transposed (``bf16[64,32256]``, never the stack) and giving
+    the two bfloat16 biases that the one custom call of the kernel of
+    ``ops/sparse_chunk.py`` under ``attn_sparse`` reads beside the K/V
+    stack as it lies, twice (its K half and its V half are column blocks
+    of one array); no sort or top-k of any kind; and the configuration
+    says so of these shapes."""
     text = compiled["prefill"].as_text()
-    windows = chunk_windows(512, 32768)
-    assert windows == (512, 1024, 2048, 4096, 8192, 16384, 32768)
-    selects = [line for line in text.splitlines()
-               if " conditional(" in line
-               and "branch_computations" in line
-               and len(re.findall(r"branch_computations=\{([^}]*)\}",
-                                  line)[0].split(",")) == len(windows)]
-    assert len(selects) == 8, len(selects)
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line
-             and "sparse_chunk_attention" in line]
-    assert len(calls) == 8, len(calls)
-    for line in calls:
-        assert re.search(r'op_name="[^"]*/attn/attn_sparse/', line), \
-            line[-300:]
-        handed = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
-                           line).group(1)
+    assert " conditional(" not in text
+    assert cfg.serving_stats(512, 32768)["sparse_chunk_select"] == "kernel"
+
+    def calls_of(name, scope):
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and f"/{name}/" in line]
+        assert len(calls) == 8, (name, len(calls))
+        for line in calls:
+            assert re.search(rf'op_name="[^"]*/attn/{scope}/', line), \
+                line[-300:]
+        return [re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                          line).group(1) for line in calls]
+
+    for handed in calls_of("sparse_pick", "select"):
+        assert handed.count("bf16[64,32256]") == 1, handed
+        assert IDX not in handed and KV not in handed, handed
+    for handed in calls_of("sparse_chunk_attention", "attn_sparse"):
         assert handed.count(KV) == 2, handed
         assert handed.count("bf16[512,32256]") == 1, handed
     _no_sort_under_attention(text)
+
+
+@pytest.mark.parametrize("old, rows", [(45568, 128), (81408, 64),
+                                       (126464, 32), (171008, 16)])
+def test_the_picking_kernel_fits_vmem_over_the_longest_window_of_each_step(
+        one_chip, cfg, old, rows):
+    """The kernel's scratch and output block grow with the key window, so
+    ``sparse_pick.query_rows`` halves a grid step's queries as the window
+    grows (the configuration allows 262,144 positions): the kernel alone,
+    at the published chunk over the LONGEST window that each count of
+    queries is given, float32 keys (the reckoning's), compiles for the
+    chip; past the last of them ``chunk_select`` keeps the XLA arm."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from ray_tpu.ops import sparse_pick
+
+    heads, di = cfg.index_heads, cfg.index_dim
+    assert sparse_pick.query_rows(512, heads, di, old) == rows
+    assert sparse_pick.query_rows(512, heads, di, old + 512) \
+        == (rows // 2 if rows > 16 else 0)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            text = jax.jit(lambda *a: sparse_pick.sparse_pick(
+                *a, cfg.index_topk)).lower(
+                    sds((512, heads, di), jnp.float32),
+                    sds((512, heads), jnp.float32),
+                    sds((512, di), jnp.float32),
+                    sds((di, old), jnp.float32), sds((), jnp.int32),
+                    sds((), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])

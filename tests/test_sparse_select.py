@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import sparse_pick
 from ray_tpu.ops import sparse_select as ss
 from ray_tpu.ops.attention import merged_rows
 
@@ -121,12 +122,25 @@ def test_each_row_has_its_own_k():
     assert mask.sum(-1).tolist() == [0, 5, 80]
 
 
-def test_no_approximate_top_k_and_no_sort_on_the_path():
-    """The jaxpr of the selection holds comparisons and sums: no ``sort``,
-    ``top_k`` or ``approx_top_k``."""
-    text = str(jax.make_jaxpr(lambda a: ss.select_mask(
-        ss.sort_keys(a, a > -9), jnp.full((3,), 16, jnp.int32)))(
-            jnp.zeros((3, 100))))
+def _selection_jaxpr(which):
+    if which == "xla":
+        return jax.make_jaxpr(lambda a: ss.select_mask(
+            ss.sort_keys(a, a > -9), jnp.full((3,), 16, jnp.int32)))(
+                jnp.zeros((3, 100)))
+    return jax.make_jaxpr(lambda q, w, own, old: sparse_pick.sparse_pick(
+        q, w, own, old, 100, 128, 64, block=128))(
+            jnp.zeros((128, 2, 64), jnp.bfloat16), jnp.zeros((128, 2)),
+            jnp.zeros((128, 64), jnp.bfloat16),
+            jnp.zeros((64, 256), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("which", ["xla", "kernel"])
+def test_no_approximate_top_k_and_no_sort_on_the_path(which):
+    """The jaxpr of the selection, and of the kernel of
+    ``ops/sparse_pick.py`` with its body, holds comparisons and sums: no
+    ``sort``, ``top_k`` or ``approx_top_k``."""
+    text = str(_selection_jaxpr(which))
+    assert ("pallas_call" in text) == (which == "kernel")
     for word in ("sort", "top_k", "approx"):
         assert word not in text, word
 
@@ -351,29 +365,31 @@ def test_a_groups_scores_stay_within_the_limit(chunk, rows, keys, want):
 @pytest.mark.parametrize("start, length", [(0, 128), (128, 128), (384, 100),
                                            (512, 128), (896, 7)])
 def test_the_chunk_kernel_is_the_xla_arm(monkeypatch, start, length):
-    """Heads of 128 lanes over a window of whole blocks take the kernel of
-    ``ops/sparse_chunk.py``: against the XLA arm (the kernel turned away)
+    """Heads of 128 lanes and indexer keys of 64 over a window of whole
+    blocks take the kernels of ``ops/sparse_pick.py`` and
+    ``ops/sparse_chunk.py``: against the XLA arm (the kernels turned away)
     on the same rings, before the selection starts and past it, a ring
     block that holds nothing in sight, a padded chunk."""
     from ray_tpu.ops import sparse_chunk
 
-    h, g, hd, c, topk = 4, 2, 128, 128, 160
+    h, g, hd, c, topk, di = 4, 2, 128, 128, 160, 64
     w, n_rows, window = g * hd, 1152, 1152
     rng = np.random.default_rng(start + length)
     kv = jnp.asarray(rng.standard_normal((2, 2, n_rows, 2 * w)),
                      jnp.bfloat16)
-    idx = jnp.asarray(rng.standard_normal((2, 2, n_rows, DI)), jnp.bfloat16)
+    idx = jnp.asarray(rng.standard_normal((2, 2, n_rows, di)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((1, c, h, hd)), jnp.bfloat16)
     kv_own = jnp.asarray(rng.standard_normal((1, c, 2 * w)), jnp.bfloat16)
-    i_own = jnp.asarray(rng.standard_normal((1, c, DI)), jnp.bfloat16)
-    q_idx = jnp.asarray(rng.standard_normal((1, c, J, DI)), jnp.bfloat16)
+    i_own = jnp.asarray(rng.standard_normal((1, c, di)), jnp.bfloat16)
+    q_idx = jnp.asarray(rng.standard_normal((1, c, J, di)), jnp.bfloat16)
     w_idx = jnp.asarray(rng.standard_normal((1, c, J)), jnp.float32)
     args = (q, kv, idx, kv_own, i_own, q_idx, w_idx, 1, jnp.asarray([1]),
             jnp.asarray([start]), jnp.asarray([length]), window, topk)
-    assert sparse_chunk.takes_kernel(c, hd, w, window - c)
+    assert ss.chunk_select(c, hd, w, window - c, J, di) == "kernel"
     assert not sparse_chunk.takes_kernel(8, 16, 32, 56)
     got, mask = jax.jit(lambda: ss.sparse_chunk_attention(*args))()
     monkeypatch.setattr(sparse_chunk, "takes_kernel", lambda *a: False)
+    assert ss.chunk_select(c, hd, w, window - c, J, di) == "xla"
     want, mask_too = jax.jit(lambda: ss.sparse_chunk_attention(*args))()
     # both arms hand back the same sets, of the sizes the positions give
     np.testing.assert_array_equal(np.asarray(mask), np.asarray(mask_too))
@@ -383,3 +399,151 @@ def test_the_chunk_kernel_is_the_xla_arm(monkeypatch, start, length):
         np.asarray(got[0, :length], np.float32),
         np.asarray(want[0, :length], np.float32), rtol=2e-2, atol=2e-2)
     assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+# -- the picking kernel (interpret mode here) -------------------------------------
+
+PICK = dict(c=128, block=128, heads=3, di=64, old=512, topk=64)
+
+
+def _pick_inputs(rng, exact):
+    """A chunk's indexer operands over one slot's ring: small integers,
+    whose products and sums float32 holds exactly whatever their order, or
+    normal draws."""
+    def draw(shape, dtype):
+        if exact:
+            return jnp.asarray(rng.integers(-3, 4, shape), dtype)
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    c, j, di, old = (PICK[k] for k in ("c", "heads", "di", "old"))
+    return (draw((c, j, di), jnp.bfloat16), draw((c, j), jnp.float32),
+            draw((c, di), jnp.bfloat16), draw((old, di), jnp.bfloat16))
+
+
+def _xla_picks(q_idx, w_idx, idx_own, idx_old, start, length, topk):
+    """What the XLA arm picks: (mask [C, old + C] over the ring's rows and
+    then the chunk's own, the scores it picked by)."""
+    c, old = q_idx.shape[0], idx_old.shape[0]
+    real = (jnp.arange(c) < length)[:, None]
+    scores = jnp.concatenate([ss.index_scores(q_idx, w_idx, idx_old),
+                              ss.index_scores(q_idx, w_idx, idx_own)], -1)
+    seen = jnp.concatenate([(jnp.arange(old)[None] < start) & real,
+                            jnp.tril(jnp.ones((c, c), bool)) & real], -1)
+    mask = ss.select_mask(ss.sort_keys(scores, seen),
+                          jnp.minimum(topk, start + jnp.arange(c) + 1))
+    return np.asarray(mask), np.asarray(scores), np.asarray(seen)
+
+
+def _kernel_picks(q_idx, w_idx, idx_own, idx_old, start, length, topk, **kw):
+    bias = sparse_pick.sparse_pick(
+        q_idx, w_idx, idx_own, idx_old.T, jnp.int32(start),
+        jnp.int32(length), topk, block=PICK["block"], **kw)
+    assert [b.dtype for b in bias] == [jnp.bfloat16] * 2
+    bias = np.concatenate([np.asarray(b, np.float32) for b in bias], -1)
+    assert ((bias == 0) | (bias < -9e29)).all()
+    return bias == 0
+
+
+@pytest.mark.parametrize("start, length, plant, kw", [
+    (0, 128, None, {}),
+    (200, 128, None, {}),
+    (512, 128, None, {}),
+    (384, 50, None, {}),
+    (7, 128, None, {}),
+    (300, 128, "boundary", {}),
+    (512, 128, "all-equal", {}),
+    (200, 100, None, {"rows": 64}),
+], ids=["start-0", "start-inside-a-block", "start-at-the-windows-end",
+        "padded-queries-pick-nothing", "fewer-eligible-than-topk",
+        "ties-across-the-ring-and-own-rows", "every-key-equal",
+        "blocks-of-64-queries"])
+def test_the_picking_kernel_is_the_xla_selection_bit_for_bit(start, length,
+                                                             plant, kw):
+    """On scores that float32 holds exactly the kernel's bias is
+    ``select_mask(sort_keys(index_scores(...)))``'s mask: before the ring
+    holds a row, with ``start`` inside a block and at the window's end, a
+    padded chunk, queries that see fewer keys than ``topk`` (every eligible
+    key), and PLANTED ties (the chunk's own keys are copies of ring rows,
+    so a query's scores tie across the boundary, and the ring's rows, the
+    lower positions, win)."""
+    assert sparse_pick.takes_kernel(PICK["c"], PICK["heads"], PICK["di"],
+                                    PICK["old"], PICK["block"])
+    topk = PICK["topk"]
+    q_idx, w_idx, idx_own, idx_old = _pick_inputs(
+        np.random.default_rng(start + length), exact=True)
+    if plant == "boundary":
+        # own row i is ring row 2 i: (of those in sight) equal scores
+        idx_own = idx_old[::2][:PICK["c"]]
+        idx_old = idx_old.at[start - 40:start].set(idx_old[:40])
+    elif plant == "all-equal":
+        idx_old = jnp.broadcast_to(idx_old[:1], idx_old.shape)
+        idx_own = jnp.broadcast_to(idx_old[:1], idx_own.shape)
+    args = (q_idx, w_idx, idx_own, idx_old, start, length, topk)
+    want, scores, seen = _xla_picks(*args)
+    got = _kernel_picks(*args, **kw)
+    np.testing.assert_array_equal(got, want)
+    assert got.sum(-1).tolist() == [
+        min(topk, start + i + 1) if i < length else 0
+        for i in range(PICK["c"])]
+    if plant:  # a stable sort's first k won, and ties really were cut
+        old, cut, across = PICK["old"], 0, 0
+        for i in range(length):
+            np.testing.assert_array_equal(
+                got[i], _stable_topk(scores[i], seen[i], topk), str(i))
+            at = scores[i] == scores[i][got[i]].min()
+            left = at & seen[i] & ~got[i]
+            cut += left.any()
+            # a ring row in, an own row of the same score out
+            across += (at & got[i])[:old].any() and left[old:].any()
+        assert cut > 10 and across > 0, (cut, across)
+
+
+@pytest.mark.parametrize("start, length", [(130, 128), (512, 128), (401, 77)])
+def test_on_any_scores_the_kernels_picks_differ_only_at_the_threshold(
+        start, length):
+    """The kernel's float32 sum over the indexer's heads may round
+    otherwise than XLA's: the sets are of the same sizes, and a key that
+    only one of them holds lies within 1e-5 (of the scores' scale) of the
+    query's threshold."""
+    topk = PICK["topk"]
+    args = (*_pick_inputs(np.random.default_rng(start), exact=False), start,
+            length, topk)
+    want, scores, _ = _xla_picks(*args)
+    got = _kernel_picks(*args)
+    np.testing.assert_array_equal(got.sum(-1), want.sum(-1))
+    assert want.sum() > 0
+    for i in np.nonzero((got != want).any(-1))[0]:
+        tau = scores[i][want[i]].min()
+        gap = np.abs(scores[i][got[i] != want[i]] - tau).max()
+        assert gap <= 1e-5 * max(1.0, np.abs(scores[i]).max()), (i, gap)
+
+
+@pytest.mark.parametrize("c, hd, w, old, heads, di, want", [
+    (512, 128, 512, 33280, 16, 64, "kernel"),   # the published widths
+    (512, 128, 512, 32256, 16, 64, "kernel"),   # the cell's window
+    (128, 128, 256, 1024, 3, 64, "kernel"),
+    (512, 128, 512, 64512, 16, 64, "kernel"),   # a 64k window: 64 queries
+    (512, 128, 512, 171008, 16, 64, "kernel"),  # the longest: 16 queries
+    (512, 128, 512, 171520, 16, 64, "xla"),     # too long for VMEM
+    (512, 128, 512, 0, 16, 64, "xla"),          # no ring yet: one chunk
+    (512, 128, 512, 33000, 16, 64, "xla"),      # not whole blocks
+    (512, 128, 512, 33280, 16, 8, "xla"),       # a toy indexer
+    (8, 16, 32, 56, 3, 8, "xla"),               # the tiny preset
+], ids=["published", "the-cells-window", "the-tests", "a-64k-window",
+        "the-longest-window", "past-the-longest", "no-ring", "ragged-window",
+        "toy-indexer", "tiny"])
+def test_the_arm_is_chosen_by_the_shapes(c, hd, w, old, heads, di, want):
+    assert ss.chunk_select(c, hd, w, old, heads, di) == want
+
+
+@pytest.mark.parametrize("old, want", [
+    (512, 128), (33280, 128), (45568, 128), (46080, 64), (64512, 64),
+    (81408, 64), (81920, 32), (126976, 16), (171008, 16), (171520, 0)])
+def test_a_longer_window_takes_fewer_queries_a_step(old, want):
+    """The kernel's VMEM grows with the window (a ring row's key, and a
+    sort key and two bias buffers a query): the published chunk takes 128
+    queries a grid step up to 45,568 ring rows and halves them from there
+    (``tests/test_serving_programs_keye_vl2_v5e.py`` compiles the ends of
+    each range for the chip); a smaller cap stays a cap."""
+    assert sparse_pick.query_rows(512, 16, 64, old) == want
+    assert sparse_pick.query_rows(512, 16, 64, old, rows=32) == min(want, 32)
