@@ -66,9 +66,9 @@ from ray_tpu.models.prefill import (chunk_len, token_parameters,
 from ray_tpu.models.smallthinker import _whole_row_attention
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_ring_chunk,
                                    cache_write_token, cached_verify_attention,
-                                   merged_chunk_attention, merged_row_width,
-                                   merged_rows, ring_rows_counted,
-                                   wrapped_chunk_attention)
+                                   chunk_attention_arm, merged_chunk_attention,
+                                   merged_row_width, merged_rows,
+                                   ring_rows_counted, wrapped_chunk_attention)
 from ray_tpu.ops.moe import dropless_experts, held_counters, route
 from ray_tpu.ops.rotary import rotate
 
@@ -186,6 +186,8 @@ class ExaoneMoeConfig:
             "kv_bytes_per_token": (self.n_global + 1) * row,
             "window_kv_bytes_per_token": self.n_window * row,
             "draft_depth": 1,
+            "chunk_attention_arm": chunk_attention_arm(
+                chunk, self.head_dim, self.row_width, window),
         }
 
     @classmethod

@@ -56,8 +56,9 @@ from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
-                                   merged_chunk_attention, merged_row_width,
-                                   merged_rows, ring_rows_counted)
+                                   chunk_attention_arm, merged_chunk_attention,
+                                   merged_row_width, merged_rows,
+                                   ring_rows_counted)
 from ray_tpu.ops.rotary import rotate
 
 Params = dict[str, Any]
@@ -156,8 +157,14 @@ class FalconH1Config:
         has no experts). The benchmark's reader of the chunk program takes
         a program without this counter for one that cannot be read
         (PERF.md section 7 asks for its repair); a constant costs the
-        programs nothing."""
-        return {"prefill_expert_rows": 0}
+        programs nothing. And which implementation a chunk program of
+        ``chunk`` tokens over a key window of ``window`` rows (the engine's)
+        attends through (``ops/attention.chunk_attention_arm``: static, by
+        shapes alone)."""
+        return {"prefill_expert_rows": 0,
+                "chunk_attention_arm": chunk_attention_arm(
+                    chunk, self.head_dim,
+                    merged_row_width(self.n_kv_head, self.head_dim), window)}
 
     @classmethod
     def tiny(cls, **kw) -> "FalconH1Config":

@@ -54,8 +54,9 @@ from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
-                                   merged_chunk_attention, merged_row_width,
-                                   merged_rows, ring_rows_counted)
+                                   chunk_attention_arm, merged_chunk_attention,
+                                   merged_row_width, merged_rows,
+                                   ring_rows_counted)
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
@@ -164,11 +165,15 @@ class Qwen3NextConfig:
     def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters, so
         that a reader holds no shape of its own: a slot's delta state and
-        convolution tails over the linear layers, and the ring bytes a
-        token takes over the full ones."""
+        convolution tails over the linear layers, the ring bytes a token
+        takes over the full ones, and which implementation a chunk program
+        of ``chunk`` tokens over a key window of ``window`` rows (the
+        engine's) attends through in the full layers
+        (``ops/attention.chunk_attention_arm``: static, by shapes alone)."""
         d = self.delta
         state = jnp.dtype(self.delta_state_dtype).itemsize
         act = jnp.dtype(self.dtype).itemsize
+        width = merged_row_width(self.n_kv_head, self.head_dim)
         return {
             "expert_layers": self.n_layer,
             "experts_held": self.experts_held[1],
@@ -176,8 +181,9 @@ class Qwen3NextConfig:
             "delta_state_bytes_per_slot": self.count(LINEAR) * (
                 d.value_heads * d.key_dim * d.value_dim * state
                 + (d.kernel - 1) * d.conv_dim * act),
-            "kv_bytes_per_token": 2 * self.count(FULL) * merged_row_width(
-                self.n_kv_head, self.head_dim) * act,
+            "kv_bytes_per_token": 2 * self.count(FULL) * width * act,
+            "chunk_attention_arm": chunk_attention_arm(
+                chunk, self.head_dim, width, window),
         }
 
     @classmethod

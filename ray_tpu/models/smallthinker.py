@@ -58,9 +58,9 @@ from ray_tpu.models.prefill import (chunk_len, token_parameters,
                                     whole_prompts)
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_ring_chunk,
                                    cache_write_token, cached_decode_attention,
-                                   merged_chunk_attention, merged_row_width,
-                                   merged_rows, ring_rows_counted,
-                                   wrapped_chunk_attention)
+                                   chunk_attention_arm, merged_chunk_attention,
+                                   merged_row_width, merged_rows,
+                                   ring_rows_counted, wrapped_chunk_attention)
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
@@ -149,8 +149,11 @@ class SmallThinkerConfig:
     def serving_stats(self, chunk: int = 0, window: int = 0) -> dict:
         """What ``llm_stats()`` says of the model beside its counters, so
         that a reader holds no shape of its own: the ring bytes a token
-        takes in a global layer's ring and in a window layer's, and how
-        many rows a window ring holds."""
+        takes in a global layer's ring and in a window layer's, how many
+        rows a window ring holds, and which implementation a chunk program
+        of ``chunk`` tokens over a key window of ``window`` rows (the
+        engine's) attends through in the global layers
+        (``ops/attention.chunk_attention_arm``: static, by shapes alone)."""
         row = 2 * self.row_width * jnp.dtype(self.dtype).itemsize
         return {
             "expert_layers": self.n_layer,
@@ -160,6 +163,8 @@ class SmallThinkerConfig:
             "window_rows": self.window,
             "kv_bytes_per_token": self.n_global * row,
             "window_kv_bytes_per_token": self.n_window * row,
+            "chunk_attention_arm": chunk_attention_arm(
+                chunk, self.head_dim, self.row_width, window),
         }
 
     @classmethod

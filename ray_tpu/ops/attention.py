@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import latent_chunk, ring_decode
+from ray_tpu.ops import latent_chunk, merged_chunk, ring_decode
 from ray_tpu.ops.flash_attention import flash_block, flash_causal_attention
 
 # Sequence length at/above which the flash kernel pays for itself.
@@ -376,10 +376,22 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     rows ``<= i``: the same softmax over the same keys as a write and then
     a read, in the same arithmetic (float32 scores, one maximum and one
     sum over both parts, probabilities in the values' type, float32
-    sums). -> [R, C, H, hd] in q's type."""
+    sums). Which of two implementations runs is decided statically, by
+    the shapes (``merged_chunk.takes_kernel``): heads of whole lane tiles
+    over a window of whole blocks go through the Pallas kernel of
+    ``ops/merged_chunk.py``, which reads the ring's blocks out of the stacks
+    as they lie and stops at ``start``; everything else (GPT-2's heads of
+    64, the tiny presets) scores the whole window in XLA, below.
+    -> [R, C, H, hd] in q's type."""
     c = q.shape[1]
     w = k_all.shape[-1]
     old = window - c
+    if chunk_attention_arm(c, q.shape[-1], w, window) == "kernel":
+        # one call a row: the kernel reads the slot's ring out of the
+        # stacks as they lie, block by block and only up to ``start``
+        return jnp.stack([merged_chunk.merged_chunk_attention(
+            q[i], k_all, v_all, k_own[i], v_own[i], layer, slots[i],
+            start[i], old=old) for i in range(q.shape[0])])
     rows = _chunk_query_rows(q, w, k_all.dtype)
     # (keys, values, who sees them): the slot's old rows, then the chunk's
     parts = [(k_own, v_own, jnp.tril(jnp.ones((c, c), bool))[None])]
@@ -389,6 +401,16 @@ def merged_chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
                          jnp.arange(old)[None, None, :]
                          < start[:, None, None]))               # [R, 1, L]
     return _chunk_softmax(q, rows, parts, w)
+
+
+def chunk_attention_arm(c: int, hd: int, w: int, window: int) -> str:
+    """Which of ``merged_chunk_attention``'s two implementations a chunk
+    program of these shapes is built with, ``"kernel"`` or ``"xla"``: a
+    chunk of c queries with heads of ``hd`` lanes in merged rows of ``w``
+    columns over a key window of ``window`` rows (the chunk's own among
+    them). An engine with no ring to read runs the XLA arm."""
+    takes = merged_chunk.takes_kernel(c, hd, w, max(window - c, 0))
+    return "kernel" if takes else "xla"
 
 
 def _slot_rows(cache: jax.Array, layer, slots: jax.Array,

@@ -77,3 +77,39 @@ def experts_through_the_kernel():
         assert f"f32[{experts},{f},{d}]" not in text
 
     return check
+
+
+@pytest.fixture(scope="session")
+def chunk_attends_through_the_kernel():
+    """What the four merged-ring families' compile-only files hold of a
+    chunk program compiled for the chip (``ops/merged_chunk.py``, PR 64):
+    ``check(compiled, layers, stack, chunk, window)`` holds that the
+    full layers' attention is the kernel's custom call once a layer, under
+    scope ``attn``, handed the K stack and the V stack as they lie (``stack``
+    is their shape, ``[N, S, L, W]``); that nothing, inside a fusion or
+    outside, cuts the window's old rows out of a stack; and that no float32
+    array over those ``window - chunk`` rows (the XLA arm's scores, ``[heads,
+    chunk, window - chunk]``) is anywhere in the program."""
+    import re
+
+    def check(compiled, layers, stack, chunk, window):
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and "/merged_chunk_attention/" in line]
+        assert len(calls) == layers, (len(calls), layers)
+        handed_whole = "bf16[" + ",".join(str(d) for d in stack) + "]"
+        for line in calls:
+            assert re.search(r'op_name="[^"]*/attn/', line), line[-300:]
+            handed = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                               line).group(1)
+            assert handed.count(handed_whole) == 2, handed
+        old, w = window - chunk, stack[-1]
+        cut = re.compile(rf"= bf16\[(?:1,)*{old},{w}\]\S* "
+                         rf"(?:dynamic-slice|slice|copy)\(")
+        assert not cut.search(text), cut.search(text).group(0)
+        over_the_window = [dims for dims in set(re.findall(
+            r"f32\[([\d,]+)\]", text)) if str(old) in dims.split(",")]
+        assert over_the_window == []
+
+    return check

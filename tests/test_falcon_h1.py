@@ -128,7 +128,9 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def want(params, tokens):
-    return reference.forward(to_ref(params), tokens, **ref_kwargs(CFG))
+    # (jitted: op by op the reference costs several times as much, D19)
+    return jax.jit(lambda t: reference.forward(
+        to_ref(params), t, **ref_kwargs(CFG)))(tokens)
 
 
 def test_the_published_sizes_and_the_tiny_preset():
@@ -153,7 +155,8 @@ def test_the_published_sizes_and_the_tiny_preset():
         + list(tiny.ssm_multipliers) + list(tiny.mlp_multipliers)
     assert len(every) == 14 == len(FOURTEEN)
     assert all(v != 1 and np.log2(v) % 1 for v in every)
-    assert CFG.serving_stats() == {"prefill_expert_rows": 0}  # no experts
+    assert CFG.serving_stats() == {"prefill_expert_rows": 0,  # no experts
+                                   "chunk_attention_arm": "xla"}  # toy widths
     with pytest.raises(ValueError, match="five factors"):
         fh.FalconH1Config.tiny(ssm_multipliers=(1.0, 2.0))
     with pytest.raises(ValueError, match="gains"):
@@ -237,12 +240,13 @@ def test_the_programs_hold_the_types_the_file_states(program):
 
 
 def test_forward_agrees_with_the_reference(params, tokens, want):
-    got = fh.falcon_h1_forward(params, tokens, CFG)
+    forward = jax.jit(lambda p, t: fh.falcon_h1_forward(p, t, CFG))
+    got = forward(params, tokens)
     assert got.shape == want.shape == (3, 40, CFG.vocab_size)
     assert rel_l2(got, want) < 1e-4
     # a row longer than one block of the scan, and not a multiple of it
     assert tokens.shape[1] > 2 * CFG.chunk_size
-    odd = fh.falcon_h1_forward(params, tokens[:, :37], CFG)
+    odd = forward(params, tokens[:, :37])
     assert rel_l2(odd, want[:, :37]) < 1e-4
 
 

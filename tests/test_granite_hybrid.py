@@ -86,7 +86,9 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def want(params, tokens):
-    return reference.forward(to_ref(params, CFG), tokens, **ref_kwargs(CFG))
+    # (jitted: op by op the reference costs several times as much, D19)
+    return jax.jit(lambda t: reference.forward(
+        to_ref(params, CFG), t, **ref_kwargs(CFG)))(tokens)
 
 
 def test_the_published_layers_and_the_tiny_preset():
@@ -171,12 +173,13 @@ def test_the_programs_hold_the_types_the_file_states(program):
 
 
 def test_forward_agrees_with_the_reference(params, tokens, want):
-    got = gh.granite_hybrid_forward(params, tokens, CFG)
+    forward = jax.jit(lambda p, t: gh.granite_hybrid_forward(p, t, CFG))
+    got = forward(params, tokens)
     assert got.shape == want.shape == (3, 40, CFG.vocab_size)
     assert rel_l2(got, want) < 1e-4
     # a row longer than one block of the scan, and not a multiple of it
     assert tokens.shape[1] > 2 * CFG.chunk_size
-    odd = gh.granite_hybrid_forward(params, tokens[:, :37], CFG)
+    odd = forward(params, tokens[:, :37])
     assert rel_l2(odd, want[:, :37]) < 1e-4
 
 

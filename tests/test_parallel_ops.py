@@ -135,14 +135,17 @@ def test_ring_fused_matches_dense_impl(sp_mesh):
             return jnp.sum(out ** 2)
         return f
 
-    out_f = ring_causal_attention(q, k, v, sp_mesh, axis="sp",
-                                  batch_axes=("dp",), impl="fused")
-    out_d = ring_causal_attention(q, k, v, sp_mesh, axis="sp",
-                                  batch_axes=("dp",), impl="dense")
+    # (jitted: op by op the interpreted kernels cost minutes, PR 64)
+    out_f = jax.jit(lambda q, k, v: ring_causal_attention(
+        q, k, v, sp_mesh, axis="sp", batch_axes=("dp",), impl="fused"))(
+        q, k, v)
+    out_d = jax.jit(lambda q, k, v: ring_causal_attention(
+        q, k, v, sp_mesh, axis="sp", batch_axes=("dp",), impl="dense"))(
+        q, k, v)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
                                rtol=2e-5, atol=2e-5)
-    g_f = jax.grad(loss("fused"), argnums=(0, 1, 2))(q, k, v)
-    g_d = jax.grad(loss("dense"), argnums=(0, 1, 2))(q, k, v)
+    g_f = jax.jit(jax.grad(loss("fused"), argnums=(0, 1, 2)))(q, k, v)
+    g_d = jax.jit(jax.grad(loss("dense"), argnums=(0, 1, 2)))(q, k, v)
     for gf, gd in zip(g_f, g_d):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
                                    rtol=1e-4, atol=1e-4)

@@ -130,7 +130,9 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def want(params, tokens):
-    return reference.forward(to_ref(params), tokens, **ref_kwargs())
+    # (jitted: op by op the reference costs several times as much, D19)
+    return jax.jit(lambda t: reference.forward(
+        to_ref(params), t, **ref_kwargs()))(tokens)
 
 
 # -- sizes and types ----------------------------------------------------------
@@ -150,7 +152,12 @@ def test_the_published_sizes_and_the_tiny_preset():
     assert stats == {"expert_layers": 8, "experts_held": 128,
                      "linear_layers": 6,
                      "delta_state_bytes_per_slot": 6 * 2_146_304,
-                     "kv_bytes_per_token": 4096}
+                     "kv_bytes_per_token": 4096,
+                     "chunk_attention_arm": "xla"}  # no ring to read
+    # the engine's chunk and key window: heads of 256 over whole blocks
+    assert held.serving_stats(512, 16384)["chunk_attention_arm"] == "kernel"
+    assert qn.Qwen3NextConfig.tiny().serving_stats(512, 16384)[
+        "chunk_attention_arm"] == "xla"  # toy widths
     tiny = qn.Qwen3NextConfig.tiny()
     # value heads twice the key heads, dk != dv, grouped queries, a partial
     # rotary, a strict part of the router's experts, two periods
@@ -258,7 +265,8 @@ def test_the_programs_name_the_scopes_the_readers_read():
 
 
 def test_forward_agrees_with_the_reference(params, tokens, want):
-    got = qn.qwen3_next_forward(params, tokens, CFG)
+    got = jax.jit(lambda p, t: qn.qwen3_next_forward(p, t, CFG))(
+        params, tokens)
     assert got.shape == (3, 40, CFG.vocab_size) and got.dtype == F32
     assert rel_l2(got, want) < 2e-4
     assert float(jnp.std(want)) > 0.3  # logits worth comparing
@@ -349,6 +357,47 @@ def _without(params, leaf):
     return {**params, "layers": [
         {**p, leaf: jnp.zeros_like(p[leaf])} if leaf in p else p
         for p in params["layers"]]}
+
+
+def test_the_chunk_program_through_the_kernel_arm_is_the_xla_arms(
+        monkeypatch):
+    """The tiny preset's heads of 16 keep ``merged_chunk_attention``'s XLA
+    arm, which the chip never runs at the published widths (PR 64). Here the
+    chunk program END TO END through the kernel of ``ops/merged_chunk.py``
+    (interpret mode): heads of 128 lanes, chunks of 128 over a window of
+    one block more, two chunks of one prompt (the second a padded one, over
+    the rows the first wrote), float32, against the same program built on
+    the XLA arm."""
+    from ray_tpu.ops import merged_chunk
+
+    cfg = qn.Qwen3NextConfig.tiny(dtype=F32, param_dtype=F32, head_dim=128,
+                                  n_layer=2, full_attention_interval=2)
+    params = jax.jit(lambda key: qn.qwen3_next_init(key, cfg))(
+        jax.random.PRNGKey(0))  # (one compile, not one a leaf)
+    chunk, window = 128, 256
+    monkeypatch.setattr(merged_chunk, "block_rows", lambda old: 128)
+    assert cfg.serving_stats(chunk, window)["chunk_attention_arm"] == "kernel"
+
+    def run():
+        program = jax.jit(lambda p, c, t, s, at, n:
+                          qn.qwen3_next_prefill_chunk(p, c, t, s, at, n, cfg,
+                                                      window=window))
+        cache, said = qn.qwen3_next_init_cache(cfg, 2, 384), []
+        for i, real in enumerate((chunk, 100)):
+            tokens = jax.random.randint(jax.random.PRNGKey(10 + i),
+                                        (1, chunk), 0, cfg.vocab_size)
+            logits, cache = program(
+                params, cache, tokens, jnp.asarray([1]),
+                jnp.asarray([i * chunk]), jnp.asarray([real]))
+            said.append(np.asarray(logits))
+        return np.stack(said)
+
+    got = run()
+    monkeypatch.setattr(merged_chunk, "takes_kernel", lambda *a: False)
+    assert cfg.serving_stats(chunk, window)["chunk_attention_arm"] == "xla"
+    want = run()
+    assert np.abs(want).max() > 1
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
 @pytest.fixture(scope="module")

@@ -133,6 +133,19 @@ def nbytes(shape, itemsize):
     return n
 
 
+def test_the_chunks_global_layers_attend_through_the_kernel(
+        compiled, chunk_attends_through_the_kernel):
+    """PR 64: the global layer's attention and the prediction module's in
+    the chunk program are ONE custom call each of the kernel of
+    ``ops/merged_chunk.py``, handed the global K and V STACKS as they lie;
+    no ``dynamic-slice`` of the window's 3,584 old rows out of either, and
+    no float32 array over them (the XLA arm's scores were ``[64, 512,
+    3584]``, 470 MB a layer). The window layers keep
+    ``wrapped_chunk_attention``."""
+    chunk_attends_through_the_kernel(compiled["prefill"], 2,
+                                     (2, 65, 8192, 1024), 512, 4096)
+
+
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
@@ -149,10 +162,12 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert 12.97e9 < mem.argument_size_in_bytes < 12.99e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # the step holds 130 rows' products and no copy of a ring (0.12 GB); a
-    # chunk holds its float32 scores over the 4096-row window of the global
+    # chunk held its float32 scores over the 4096-row window of the global
     # layer and of the module (64 heads x 512 x 3584 x 4 B = 470 MB, and
-    # their exponentials) and the rows cut out of the stacks: 0.76 GB
-    assert mem.temp_size_in_bytes < {"decode": 0.25e9, "prefill": 1.1e9}[which]
+    # their exponentials) and the rows cut out of the stacks, 0.76 GB,
+    # until PR 64 (``ops/merged_chunk.py``: a block's scores in VMEM, the
+    # stacks read as they lie): 0.19 GB
+    assert mem.temp_size_in_bytes < {"decode": 0.25e9, "prefill": 0.4e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "

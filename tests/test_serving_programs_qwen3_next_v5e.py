@@ -10,7 +10,9 @@ up to 16384 tokens in the engine's [1, 512] chunks over a key window of
 fit the chip beside their arguments (13.08 GB of weights and cache), that
 the donated cache is updated in its own buffers, that no program makes a
 float32 array as long as a ring or copies a layer's delta state, that the
-step re-lays no ring out (a token's two K/V heads of 256 lanes merged in one
+chunk's full layers attend through the kernel of ``ops/merged_chunk.py``
+(PR 64: no window of old rows cut out of a stack, no float32 scores over
+it), that the step re-lays no ring out (a token's two K/V heads of 256 lanes merged in one
 row of 512 columns, four whole lane tiles, read as they lie), that the chunk
 program writes each stack once and makes no other array that large, and
 that it keeps the cache in the step's layout: XLA's choices decide that, not
@@ -139,12 +141,13 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # the step holds its float32 scores over 65 rings of 18432 rows a head
     # (77 MB a layer) and the experts' [128, 65, 1024] product, and no copy
-    # of a ring or a state: 0.05 GB. A chunk holds its 512 queries'
+    # of a ring or a state: 0.05 GB. A chunk held its 512 queries'
     # scores over the 16384-row window (537 MB in float32 a full layer) and
-    # both stacks' old rows cut out: 0.62 GB (0.35 at the 256 queries of
-    # before PR 53).
+    # both stacks' old rows cut out, 0.62 GB, until PR 64: the kernel of
+    # ``ops/merged_chunk.py`` keeps a block's scores in VMEM and reads the
+    # stacks as they lie, and the chunk's temp is 0.07 GB.
     assert mem.temp_size_in_bytes < {"decode": 0.2e9,
-                                     "prefill": 0.75e9}[which]
+                                     "prefill": 0.2e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
@@ -214,6 +217,17 @@ def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
             made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
     assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 2, made
     assert len({stack for _, stack in made}) == 2, made
+
+
+def test_the_chunks_full_layers_attend_through_the_kernel(
+        compiled, chunk_attends_through_the_kernel):
+    """PR 64: each of the two full layers' attention in the chunk program
+    is ONE custom call of the kernel of ``ops/merged_chunk.py``, handed the
+    K and V STACKS as they lie; no ``dynamic-slice`` of the window's 15,872
+    old rows out of either stack, and no float32 array over them (the XLA
+    arm's scores were ``[16, 512, 15872]``, 520 MB a layer)."""
+    chunk_attends_through_the_kernel(compiled["prefill"], 2,
+                                     (2, 65, 18432, 512), 512, 16384)
 
 
 def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(

@@ -140,12 +140,13 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert 12.32e9 < mem.argument_size_in_bytes < 12.35e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM, gb
     # the step holds the experts' [64, 49, 1536] product and no copy of a
-    # ring. A chunk holds its float32 scores over the 14336-row window of a
+    # ring. A chunk held its float32 scores over the 14336-row window of a
     # global layer (28 heads x 512 x 13824 x 4 B = 793 MB, and their
-    # exponentials), a window layer's over 4608 keys (264 MB) and the rows
-    # cut out of the stacks: 1.09 GB (0.66 at the 256 queries of before
-    # PR 53).
-    assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 1.6e9}[which]
+    # exponentials) and the rows cut out of the stacks, 1.09 GB in all,
+    # until PR 64 (``ops/merged_chunk.py``: a block's scores in VMEM, the
+    # stacks read as they lie); what is left is a window layer's scores
+    # over 4608 keys and its ring cut out: 0.23 GB.
+    assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 0.4e9}[which]
 
 
 SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
@@ -221,6 +222,18 @@ def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
     made = _made_as_large_as(compiled["prefill"].as_text(), RINGS | STACKS)
     assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 4, made
     assert len({stack for _, stack in made}) == 4, made
+
+
+def test_the_chunks_global_layers_attend_through_the_kernel(
+        compiled, chunk_attends_through_the_kernel):
+    """PR 64: each of the two global layers' attention in the chunk program
+    is ONE custom call of the kernel of ``ops/merged_chunk.py``, handed the
+    global K and V STACKS as they lie; no ``dynamic-slice`` of the window's
+    13,824 old rows out of either, and no float32 array over them (the XLA
+    arm's scores were ``[28, 512, 13824]``, 793 MB a layer). The window
+    layers keep ``wrapped_chunk_attention``."""
+    chunk_attends_through_the_kernel(compiled["prefill"], 2,
+                                     (2, 49, 16384, 512), 512, 14336)
 
 
 def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(

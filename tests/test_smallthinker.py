@@ -125,7 +125,9 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def want(params, tokens):
-    return reference.forward(to_ref(params), tokens, **ref_kwargs())
+    # (jitted: op by op the reference costs several times as much, D19)
+    return jax.jit(lambda t: reference.forward(
+        to_ref(params), t, **ref_kwargs()))(tokens)
 
 
 # -- sizes, the cache and types -----------------------------------------------
@@ -180,6 +182,11 @@ def test_the_cache_is_two_stacks_of_the_lengths_the_file_states(which):
         assert (stats["kv_bytes_per_token"],
                 stats["window_kv_bytes_per_token"],
                 stats["window_rows"]) == (2 * 2048, 6 * 2048, 4096)
+        # the engine's chunk and key window, and an engine with no ring
+        assert [cfg.serving_stats(*at)["chunk_attention_arm"]
+                for at in ((512, 14336), (0, 0))] == ["kernel", "xla"]
+    else:
+        assert cfg.serving_stats(512, 14336)["chunk_attention_arm"] == "xla"
 
 
 def _programs(cfg, chunk=4):
@@ -245,7 +252,8 @@ def test_the_programs_name_the_scopes_the_readers_read():
 def test_forward_agrees_with_the_reference(dtype, params, tokens, want):
     """Rows of 56 tokens: seven windows of eight."""
     if dtype == "float32":
-        got = st.smallthinker_forward(params, tokens, CFG)
+        got = jax.jit(lambda p, t: st.smallthinker_forward(p, t, CFG))(
+            params, tokens)
         assert got.dtype == jnp.float32 and got.shape == want.shape
         assert rel_l2(got, want) < 2e-5
         return
