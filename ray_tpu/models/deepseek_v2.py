@@ -47,7 +47,6 @@ the tests hold this file to it.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any
 
@@ -55,6 +54,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models.common import (_normal,
+                                   gate as _gate,
+                                   rms_norm as _rms_norm)
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
                                    latent_chunk_attention,
@@ -209,12 +211,6 @@ def _rotate(x: jax.Array, pos: jax.Array, cfg: DeepseekV2Config) -> jax.Array:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _layer_init(key, layer: int, cfg: DeepseekV2Config) -> Params:
     d, pd, std = cfg.d_model, cfg.param_dtype, 0.02
     h, rank = cfg.n_head, cfg.kv_rank
@@ -266,18 +262,6 @@ def deepseek_v2_init(rng: jax.Array, cfg: DeepseekV2Config) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _gate(ab: jax.Array) -> jax.Array:
-    """``silu(a) * b`` of ``[a, b]`` side by side in the last axis."""
-    half = ab.shape[-1] // 2
-    return jax.nn.silu(ab[..., :half]) * ab[..., half:]
 
 
 def _latent_inputs(p: Params, y: jax.Array, pos: jax.Array,

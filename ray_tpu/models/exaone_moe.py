@@ -53,12 +53,14 @@ Seeded weights (``exaone_moe_init``) are drawn by ``cfg.gains``: see there.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import (_normal,
+                                   merged_row as _merged_row,
+                                   rms_norm as _norm)
 from ray_tpu.models.prefill import (chunk_len, token_parameters,
                                     whole_prompts)
 # (whole rows from nothing, causal and within a window: the tests' form, the
@@ -67,8 +69,8 @@ from ray_tpu.models.smallthinker import _whole_row_attention
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_ring_chunk,
                                    cache_write_token, cached_verify_attention,
                                    chunk_attention_arm, merged_chunk_attention,
-                                   merged_row_width, merged_rows,
-                                   ring_rows_counted, wrapped_chunk_attention)
+                                   merged_row_width, ring_rows_counted,
+                                   wrapped_chunk_attention)
 from ray_tpu.ops.moe import dropless_experts, held_counters, route
 from ray_tpu.ops.rotary import rotate
 
@@ -208,12 +210,6 @@ class ExaoneMoeConfig:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _block_init(key, cfg: ExaoneMoeConfig, dense: bool) -> Params:
     """One block's matrices: attention with its two head norms, the two
     sublayer norms, and a dense or a sparse feed-forward part."""
@@ -284,13 +280,6 @@ def exaone_moe_init(rng: jax.Array, cfg: ExaoneMoeConfig) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    """The RMSNorm ``N(x; w)`` over the last axis."""
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _swiglu(ab: jax.Array) -> jax.Array:
@@ -409,13 +398,6 @@ def exaone_moe_init_cache(cfg: ExaoneMoeConfig, slots: int,
             "k_win": jnp.zeros(win, cfg.dtype),
             "v_win": jnp.zeros(win, cfg.dtype),
             "counted": {"prefill_expert_rows": jnp.zeros((), jnp.int32)}}
-
-
-def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
-    """A token's K or V heads [..., G, hd] as the cache holds them: side
-    by side in one row [..., W], in its type."""
-    return merged_rows(rows.reshape(*rows.shape[:-2], -1).astype(cache.dtype),
-                       cache.shape[-1])
 
 
 _STACK = {0: "full", 1: "win"}  # a layer's kind -> its stack's suffix

@@ -46,19 +46,20 @@ Seeded weights (``falcon_h1_init``) are drawn by ``cfg.gains``: see there.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import (_normal,
+                                   merged_row as _merged_row,
+                                   rms_norm as _rms_norm)
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
                                    chunk_attention_arm, merged_chunk_attention,
-                                   merged_row_width, merged_rows,
-                                   ring_rows_counted)
+                                   merged_row_width, ring_rows_counted)
 from ray_tpu.ops.rotary import rotate
 
 Params = dict[str, Any]
@@ -189,12 +190,6 @@ class FalconH1Config:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def init_stds(cfg: FalconH1Config) -> dict:
     """The standard deviation each matrix is drawn at (``GAINS`` says
     why): ``gain / (sqrt(fan_in) * multiplier)``."""
@@ -256,12 +251,6 @@ def falcon_h1_init(rng: jax.Array, cfg: FalconH1Config) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _times(x: jax.Array, m: float) -> jax.Array:
@@ -342,13 +331,6 @@ def falcon_h1_init_cache(cfg: FalconH1Config, slots: int,
           merged_row_width(cfg.n_kv_head, cfg.head_dim))
     return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
             **mamba2.init_state(cfg.mamba, cfg.n_layer, slots)}
-
-
-def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
-    """A token's K or V heads [..., G, hd] as the cache holds them: side
-    by side in one row [..., W], in its type."""
-    return merged_rows(rows.reshape(*rows.shape[:-2], -1).astype(cache.dtype),
-                       cache.shape[-1])
 
 
 # jax-hot-path: traced into the engine's single compiled decode step

@@ -34,12 +34,14 @@ family's returns.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import (_normal,
+                                   gate as _gate,
+                                   rms_norm as _rms_norm)
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
@@ -148,12 +150,6 @@ class GraniteHybridConfig:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _layer_init(key, kind: str, cfg: GraniteHybridConfig) -> Params:
     d, pd, std = cfg.d_model, cfg.param_dtype, 0.02
     keys = iter(jax.random.split(key, 12))
@@ -195,18 +191,6 @@ def granite_hybrid_init(rng: jax.Array, cfg: GraniteHybridConfig) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _gate(ab: jax.Array) -> jax.Array:
-    """``silu(a) * b`` of ``[a, b]`` side by side in the last axis."""
-    half = ab.shape[-1] // 2
-    return jax.nn.silu(ab[..., :half]) * ab[..., half:]
 
 
 def _moe(p: Params, y: jax.Array, cfg: GraniteHybridConfig,

@@ -48,12 +48,12 @@ Seeded weights (``keye_vl2_init``) are drawn by ``cfg.gains``: see there.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import _normal, gate as _gate, rms_norm as _norm
 from ray_tpu.models.prefill import (chunk_len, token_parameters,
                                     whole_prompts)
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
@@ -175,12 +175,6 @@ class KeyeVL2Config:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def init_stds(cfg: KeyeVL2Config) -> dict:
     """The standard deviation each matrix is drawn at (``GAINS`` says
     why): ``gain / sqrt(fan_in)``."""
@@ -247,13 +241,6 @@ def keye_vl2_init(rng: jax.Array, cfg: KeyeVL2Config) -> Params:
 # -- the parts ----------------------------------------------------------------
 
 
-def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    """The RMSNorm ``N(x; w)`` over the last axis."""
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
-
-
 def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
                 eps: float) -> jax.Array:
     """LayerNorm with weight and bias over the last axis."""
@@ -262,12 +249,6 @@ def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(
         x.dtype)
-
-
-def _gate(ab: jax.Array) -> jax.Array:
-    """``silu(a) * b`` of ``[a, b]`` side by side in the last axis."""
-    half = ab.shape[-1] // 2
-    return jax.nn.silu(ab[..., :half]) * ab[..., half:]
 
 
 def _qkv(p: Params, a: jax.Array, pos: jax.Array, cfg: KeyeVL2Config):

@@ -40,13 +40,13 @@ a deployment without speculative decoding does not serve it.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import _normal, rms_norm as _rms_norm
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2
 from ray_tpu.ops.attention import (cache_write_prompt, cache_write_token,
@@ -151,12 +151,6 @@ class NemotronHConfig:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def _layer_init(key, kind: str, cfg: NemotronHConfig) -> Params:
     d, pd = cfg.d_model, cfg.param_dtype
     out_std = 0.02 / math.sqrt(len(cfg.pattern))  # rescale_prenorm_residual
@@ -200,12 +194,6 @@ def nemotron_h_init(rng: jax.Array, cfg: NemotronHConfig) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _relu2(x: jax.Array) -> jax.Array:

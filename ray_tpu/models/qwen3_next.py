@@ -44,19 +44,20 @@ Seeded weights (``qwen3_next_init``) are drawn by ``cfg.gains``: see there.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import (_normal,
+                                   gate as _gate,
+                                   merged_row as _merged_row)
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_token,
                                    cached_decode_attention, causal_attention,
                                    chunk_attention_arm, merged_chunk_attention,
-                                   merged_row_width, merged_rows,
-                                   ring_rows_counted)
+                                   merged_row_width, ring_rows_counted)
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
@@ -206,12 +207,6 @@ class Qwen3NextConfig:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def init_stds(cfg: Qwen3NextConfig) -> dict:
     """The standard deviation each matrix is drawn at (``GAINS`` says
     why): ``gain / sqrt(fan_in)``."""
@@ -292,12 +287,6 @@ def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
-
-
-def _gate(ab: jax.Array) -> jax.Array:
-    """``silu(a) * b`` of ``[a, b]`` side by side in the last axis."""
-    half = ab.shape[-1] // 2
-    return jax.nn.silu(ab[..., :half]) * ab[..., half:]
 
 
 def _qkv(p: Params, y: jax.Array, pos: jax.Array, cfg: Qwen3NextConfig):
@@ -390,13 +379,6 @@ def qwen3_next_init_cache(cfg: Qwen3NextConfig, slots: int,
     return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
             **gated_delta.init_state(cfg.delta, cfg.count(LINEAR), slots),
             "counted": {"prefill_expert_rows": jnp.zeros((), jnp.int32)}}
-
-
-def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
-    """A token's K or V heads [..., G, hd] as the cache holds them: side
-    by side in one row [..., W], in its type."""
-    return merged_rows(rows.reshape(*rows.shape[:-2], -1).astype(cache.dtype),
-                       cache.shape[-1])
 
 
 # jax-hot-path: traced into the engine's single compiled decode step

@@ -48,19 +48,21 @@ Seeded weights (``smallthinker_init``) are drawn by ``cfg.gains``: see there.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import (_normal,
+                                   merged_row as _merged_row,
+                                   rms_norm as _norm)
 from ray_tpu.models.prefill import (chunk_len, token_parameters,
                                     whole_prompts)
 from ray_tpu.ops.attention import (cache_write_chunk, cache_write_ring_chunk,
                                    cache_write_token, cached_decode_attention,
                                    chunk_attention_arm, merged_chunk_attention,
-                                   merged_row_width, merged_rows,
-                                   ring_rows_counted, wrapped_chunk_attention)
+                                   merged_row_width, ring_rows_counted,
+                                   wrapped_chunk_attention)
 from ray_tpu.ops.moe import (dropless_experts, held_counters,
                              route_topk_softmax)
 from ray_tpu.ops.rotary import rotate
@@ -183,12 +185,6 @@ class SmallThinkerConfig:
 # -- parameters ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, std, dtype):
-    # under jit the float32 draw is never held whole beside its cast
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 def init_stds(cfg: SmallThinkerConfig) -> dict:
     """The standard deviation each matrix is drawn at (``GAINS`` says
     why): ``gain / sqrt(fan_in)``."""
@@ -243,13 +239,6 @@ def smallthinker_init(rng: jax.Array, cfg: SmallThinkerConfig) -> Params:
 
 
 # -- the parts ----------------------------------------------------------------
-
-
-def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    """The RMSNorm ``N(x; w)`` over the last axis."""
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _reglu(ab: jax.Array) -> jax.Array:
@@ -345,13 +334,6 @@ def smallthinker_init_cache(cfg: SmallThinkerConfig, slots: int,
             "k_win": jnp.zeros(win, cfg.dtype),
             "v_win": jnp.zeros(win, cfg.dtype),
             "counted": {"prefill_expert_rows": jnp.zeros((), jnp.int32)}}
-
-
-def _merged_row(rows: jax.Array, cache: jax.Array) -> jax.Array:
-    """A token's K or V heads [..., G, hd] as the cache holds them: side
-    by side in one row [..., W], in its type."""
-    return merged_rows(rows.reshape(*rows.shape[:-2], -1).astype(cache.dtype),
-                       cache.shape[-1])
 
 
 _STACK = {0: "full", 1: "win"}  # a layer's kind -> its stack's suffix

@@ -7,6 +7,7 @@ without TPU hardware (SURVEY.md §4.3).
 """
 
 import os
+import re
 
 import pytest
 
@@ -56,8 +57,6 @@ def experts_through_the_kernel():
     handed each layer's ``w1`` and ``w2`` stacks as they lie (bfloat16, no
     float32 copy of either anywhere), and that the TPU's grouped product is
     nowhere."""
-    import re
-
     def check(compiled, layers, experts, d, f1, f):
         text = compiled.as_text()
         calls = [line for line in text.splitlines()
@@ -90,8 +89,6 @@ def chunk_attends_through_the_kernel():
     outside, cuts the window's old rows out of a stack; and that no float32
     array over those ``window - chunk`` rows (the XLA arm's scores, ``[heads,
     chunk, window - chunk]``) is anywhere in the program."""
-    import re
-
     def check(compiled, layers, stack, chunk, window):
         text = compiled.as_text()
         calls = [line for line in text.splitlines()
@@ -113,3 +110,68 @@ def chunk_attends_through_the_kernel():
         assert over_the_window == []
 
     return check
+
+
+# -- reading a compiled program's text ----------------------------------------
+#
+# What the compile-only files (``tests/test_serving_programs*_v5e.py``) share
+# of reading ``compiled.as_text()``: plain functions, imported from here.
+
+HLO_SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                       r"([\w\-]+)\(")
+HLO_RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+# opcodes that hand an array on and make none
+HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
+# ... and those that work inside a buffer they were given
+PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple",
+             "fusion", "dynamic-update-slice", "custom-call", "while",
+             "conditional", "call", "opt-barrier")
+
+
+def nbytes(shape, itemsize):
+    n = itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
+def unfused(hlo_text):
+    """The text of every computation but the ones a ``fusion`` calls:
+    inside a fusion a slice or a convert is a step of one loop, not a
+    buffer."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    return "\n".join(block for block in hlo_text.split("\n\n")
+                     if block.lstrip().split(" ", 1)[0] not in fused)
+
+
+def unfused_lines(hlo_text):
+    """The instructions that make an array of their own: those of every
+    computation but the ones a ``fusion`` calls."""
+    for block in unfused(hlo_text).split("\n\n"):
+        yield from block.splitlines()[1:]
+
+
+def arrays_made(hlo_text):
+    """(type, elements, opcode) of every instruction of ``hlo_text`` that
+    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
+    slices."""
+    for line in hlo_text.splitlines():
+        m = HLO_SHAPE.match(line)
+        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
+                                "dynamic-slice"):
+            yield m.group(1), nbytes(
+                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
+
+
+def made_as_large_as(hlo_text, large):
+    """(opcode, first operand) of every instruction that gives out an array
+    whose element count ``large`` says yes to (a tuple's members counted
+    each) and does not merely hand one on."""
+    made = []
+    for line in hlo_text.splitlines():
+        m = HLO_RESULT.match(line)
+        if m and m.group(2) not in HANDED_ON and any(
+                large(nbytes([int(d) for d in dims.split(",")], 1))
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    return made

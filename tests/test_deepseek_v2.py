@@ -1,18 +1,17 @@
 """DeepSeek-V2 (``models/deepseek_v2.py``) against its plain reference
-(``benchmark/reference/deepseek_v2.py``) at toy widths on the CPU: the
-forward pass, prefill in toy chunks then decode steps through the latent
-cache (several blocks of keys, a clamped last block, a wrapped ring), the
-absorbed attention against the decompressed one on the same cache, the
-group-limited router on near-ties, YaRN's angles and scale, the eight
-groups' shares adding up to the uncut layer, the types the programs compute
-in with a float8 and a bfloat16-statistics control, the engine on the
-normal path with its counters, and every other family's lowered programs
-held to what they were before this family came.
+(``benchmark/reference/deepseek_v2.py``) at toy widths on the CPU: prefill in
+toy chunks then decode steps through the latent cache (several blocks of keys,
+a clamped last block, a wrapped ring), the absorbed attention against the
+decompressed one on the same cache, the group-limited router on near-ties,
+YaRN's angles and scale, the eight groups' shares adding up to the uncut
+layer, a float8 and a bfloat16-statistics control, and every other family's
+lowered programs held to what they were before this family came. The contracts
+every served family holds (sizes, types, the forward pass, the engine against
+the reference) are ``tests/test_served_family_contract.py``'s.
 """
 
 import dataclasses
 import hashlib
-import os
 import re
 
 import jax
@@ -20,94 +19,42 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import deepseek_v2 as ds
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import attention as attn_ops
 from ray_tpu.ops import moe as moe_ops
+from served_families import (FAMILIES, contract_params, contract_tokens,
+                             contract_want,
+                             deepseek_v2_rope_scaling as rope_scaling, rel_l2)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "deepseek_v2.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "deepseek_v2.py"))
+ROW = FAMILIES["deepseek_v2"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = ds.DeepseekV2Config.tiny(dtype=F32, param_dtype=F32)
-
-
-def rope_scaling(cfg):
-    return {"type": "yarn", "factor": cfg.yarn_factor,
-            "original_max_position_embeddings": cfg.yarn_original,
-            "beta_fast": cfg.beta_fast, "beta_slow": cfg.beta_slow,
-            "mscale": cfg.mscale, "mscale_all_dim": cfg.mscale_all_dim}
-
-
-def ref_kwargs(cfg, **over):
-    kw = dict(n_head=cfg.n_head, nope=cfg.nope_dim, rope=cfg.rope_dim,
-              v_dim=cfg.v_dim, eps=cfg.eps, rope_theta=cfg.rope_theta,
-              rope_scaling=rope_scaling(cfg), top_k=cfg.top_k,
-              n_group=cfg.n_group, topk_group=cfg.topk_group,
-              routed_scale=cfg.routed_scale,
-              first_expert=cfg.experts_held[0])
-    kw.update(over)
-    return kw
-
-
-def to_ref(params):
-    return family.to_reference(params, {})
-
-
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norm scales start at
-    one, and a dropped or swapped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
-    return jax.tree.map(
-        lambda x: x + (0.05 * jax.random.normal(
-            next(keys), x.shape, jnp.float32)).astype(x.dtype), params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
 
 
 @pytest.fixture(scope="module")
 def params():
-    return moved(ds.deepseek_v2_init(jax.random.PRNGKey(0), CFG))
+    return contract_params("deepseek_v2")
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    return jnp.asarray(np.random.default_rng(1).integers(
-        0, CFG.vocab_size, (3, 40), dtype=np.int32))
+    return contract_tokens("deepseek_v2")
 
 
 @pytest.fixture(scope="module")
-def want(params, tokens):
-    # (jitted: op by op the reference costs several times as much, D19)
-    return jax.jit(lambda t: reference.forward(
-        to_ref(params), t, **ref_kwargs(CFG)))(tokens)
+def want():
+    return contract_want("deepseek_v2")
 
 
-def test_the_published_sizes_and_the_tiny_preset():
-    cfg = ds.DeepseekV2Config()
-    assert (cfg.n_layer, cfg.first_dense, cfg.n_head) == (60, 1, 128)
-    assert (cfg.q_rank, cfg.kv_rank, cfg.nope_dim, cfg.rope_dim, cfg.v_dim) \
-        == (1536, 512, 128, 64, 128)
-    assert (cfg.n_experts, cfg.n_group, cfg.topk_group, cfg.top_k) \
-        == (160, 8, 3, 6)
-    assert cfg.shared_ff == 2 * cfg.expert_ff == 3072
-    assert cfg.is_dense(0) and not cfg.is_dense(1)
-    # ISSUE 38: s = 192 ** -0.5 * m(0.707) ** 2 = 0.07217 x 1.5896
-    assert cfg.softmax_scale == pytest.approx(0.07217 * 1.5896, rel=1e-4)
-    assert CFG.serving_stats() == {"expert_layers": 2, "experts_held": 8}
-    assert [CFG.is_dense(i) for i in range(3)] == [True, False, False]
-    with pytest.raises(ValueError, match="experts_held"):
-        ds.DeepseekV2Config.tiny(experts_held=(12, 8))
-    with pytest.raises(ValueError, match="groups"):
-        ds.DeepseekV2Config.tiny(n_group=3)
-    with pytest.raises(ValueError, match="groups"):
-        ds.DeepseekV2Config.tiny(topk_group=5)
+def moved(params, seed=6):
+    """``served_families.moved`` with the draw made in float32 and cast:
+    this file moves bfloat16 weights too (float32 ones get the same)."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
+    return jax.tree.map(
+        lambda x: x + (0.05 * jax.random.normal(
+            next(keys), x.shape, jnp.float32)).astype(x.dtype), params)
 
 
 def test_weights_are_stored_in_bfloat16_and_the_two_kinds_of_layer():
@@ -128,60 +75,6 @@ def test_weights_are_stored_in_bfloat16_and_the_two_kinds_of_layer():
     assert cache["latent"].dtype == jnp.bfloat16
     assert cfg.serving_dtypes(params) == jax.tree.map(
         lambda x: x.dtype, params)
-
-
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_types_the_file_states(program):
-    """``computes_in`` of the benchmark's configuration file, held by the
-    programs' own types: weights, products and cache rows in bfloat16 and
-    nothing narrower anywhere, float32 beside them (router, softmax
-    statistics, norms' statistics, rotary angles)."""
-    config = load_json(os.path.join(
-        REPO, "benchmark", "configs", "deepseek-v2.json"))
-    stated = family.system_config(config)
-    assert "bfloat16 weights" in config["computes_in"]
-    assert "float32 router" in config["computes_in"]
-    assert (stated.param_dtype, stated.dtype) == (jnp.bfloat16,) * 2
-    cfg = ds.DeepseekV2Config.tiny()  # the same defaults, a CPU's size
-    assert (cfg.param_dtype, cfg.dtype) == (jnp.bfloat16,) * 2
-    params = jax.eval_shape(
-        lambda: ds.deepseek_v2_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: ds.deepseek_v2_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    if program == "decode":
-        fn = lambda p, c, t, n: ds.deepseek_v2_decode_step(p, c, t, n, cfg)
-        args = (params, cache, i32(3), i32(3))
-    else:
-        fn = lambda p, c, t, s, n: ds.deepseek_v2_prefill_chunk(
-            p, c, t, s, jnp.zeros_like(s), n, cfg)
-        args = (params, cache, i32(1, 16), i32(1), i32(1))
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    # the attention's scores and the router's are float32 products
-    assert re.search(r"f32\[[0-9,]*\] = dot_general\[", text)
-    assert "preferred_element_type=float32" in text
-    logits, new_cache, *counted = jax.eval_shape(fn, *args)
-    assert logits.dtype == jnp.float32
-    assert new_cache["latent"].dtype == jnp.bfloat16
-    counted = [*counted, new_cache["counted"]]
-    assert all(v.dtype == jnp.int32 and v.shape == ()
-               for c in counted for v in c.values())
-    assert [set(c) for c in counted] == (
-        [{"experts_hit", "expert_rows", "expert_row_tiles",
-          "expert_tokens_here"}]
-        if program == "decode" else []) + [{"prefill_expert_rows"}]
-
-
-def test_forward_agrees_with_the_reference(params, tokens, want):
-    forward = jax.jit(lambda p, t: ds.deepseek_v2_forward(p, t, CFG))
-    got = forward(params, tokens)
-    assert got.shape == want.shape == (3, 40, CFG.vocab_size)
-    assert rel_l2(got, want) < 1e-4
-    odd = forward(params, tokens[:, :37])
-    assert rel_l2(odd, want[:, :37]) < 1e-4
 
 
 @pytest.mark.parametrize("term, without", [
@@ -209,8 +102,7 @@ def test_the_reference_without_a_term_is_another_model(
     elif without == "rotate":
         monkeypatch.setattr(reference, "rotate", lambda x, cos, sin: x)
         without = {}
-    other = reference.forward(to_ref(params), tokens,
-                              **ref_kwargs(CFG, **without))
+    other = ROW.reference_forward(params, CFG, **without)(tokens)
     assert rel_l2(other, want) > 5e-3, term
     got = ds.deepseek_v2_forward(params, tokens, CFG)
     assert rel_l2(got, other) > 5e-3, term
@@ -409,7 +301,7 @@ def test_prefill_in_toy_chunks_then_decode_through_the_cache(chunks,
     steps = 5
     row = jnp.asarray(np.random.default_rng(length).integers(
         0, CFG.vocab_size, (1, length + steps), dtype=np.int32))
-    want = reference.forward(to_ref(params), row, **ref_kwargs(CFG))
+    want = ROW.reference_forward(params, CFG)(row)
     cache = ds.deepseek_v2_init_cache(CFG, 2, 144)
     chunk = jax.jit(lambda c, t, at, n: ds.deepseek_v2_prefill_chunk(
         params, c, t, jnp.ones(1, jnp.int32), at, n, CFG, window=136))
@@ -457,7 +349,7 @@ def test_long_rows_cross_the_ops_own_blocks(chunk, ring):
     assert chunk // attn_ops.CHUNK_QUERIES == chunk // 256
     row = jnp.asarray(np.random.default_rng(9).integers(
         0, cfg.vocab_size, (1, length + 2), dtype=np.int32))
-    want = reference.forward(to_ref(params), row, **ref_kwargs(cfg))
+    want = ROW.reference_forward(params, cfg)(row)
     cache = ds.deepseek_v2_init_cache(cfg, 2, ring)
     prompt = jnp.pad(row[:, :length], ((0, 0), (0, window - length)))
     logits, cache = jax.jit(lambda c: whole_prompts(
@@ -530,7 +422,7 @@ def test_the_eight_groups_shares_add_up_to_the_uncut_layer():
     parts = [hidden(2 * g, 2) - alike for g in range(8)]
     assert all(float(jnp.abs(p).max()) > 0 for p in parts)  # every group
     total = alike + sum(parts)
-    uncut = reference.forward(to_ref(params), tokens, **ref_kwargs(cfg))
+    uncut = ROW.reference_forward(params, cfg)(tokens)
     assert rel_l2(ds._head(total, params, cfg), uncut) < 1e-4
     # and the uncut program is that sum too
     assert rel_l2(hidden(0, 16), total) < 1e-5
@@ -564,7 +456,7 @@ def test_bfloat16_against_the_reference_and_the_float8_control():
     length, steps = 37, 4
     row = jnp.asarray(np.random.default_rng(2).integers(
         0, cfg.vocab_size, (1, length + steps), dtype=np.int32))
-    want = reference.forward(to_ref(params), row, **ref_kwargs(cfg))[
+    want = ROW.reference_forward(params, cfg)(row)[
         0, length - 1:]
 
     @jax.jit
@@ -633,82 +525,6 @@ def test_bfloat16_statistics_fail_the_attentions_own_tolerance(seed,
     monkeypatch.setattr(attn_ops, "_online_softmax", _bf16_statistics)
     assert rel_l2(attend(), plain) > 2 * tolerance
 
-
-# -- the engine -----------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield serve
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_tokens(runtime):
-    """``LLMEngine(model="deepseek_v2")`` at the tiny sizes through
-    ``serve.run`` / ``handle.stream`` in float32: token for token the
-    reference's greedy choice, two compiled programs, and the step's and
-    the chunks' counters in ``llm_stats()``."""
-    import ray_tpu
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
-    handle = runtime.run(dep.bind(
-        model="deepseek_v2", config=CFG, seed=3, max_batch=3, cache_len=32,
-        max_prompt_len=16, prefill_rows=2, prefill_chunk=4))
-    params = ds.deepseek_v2_init(jax.random.PRNGKey(3), CFG)
-    ref, kw = to_ref(params), ref_kwargs(CFG)
-    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
-    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-    for prompt in prompts:
-        toks = list(prompt)
-        for _ in range(6):  # causal: one padded shape serves every length
-            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
-            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
-        assert served == toks[len(prompt):]
-        assert len(set(served)) > 2  # no fixed point
-    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
-    assert stats["compiles"] == {"decode": 1, "prefill": 1}
-    assert stats["model"] == "deepseek_v2"
-    assert stats["expert_layers"] == 2 and stats["experts_held"] == 8
-    steps = stats["steps"]
-    assert steps >= 10
-    # every step runs max_batch + 1 rows through 2 expert layers, top 3
-    assert 0 < stats["experts_hit"] <= steps * 2 * 8
-    assert stats["experts_hit"] <= stats["expert_rows"] <= steps * 2 * 12
-    assert 0 < stats["expert_tokens_here"] <= steps * 2 * 4
-    assert stats["expert_tokens_here"] <= stats["expert_rows"]
-    # the chunks: 2 + 3 executions, 14 real tokens, their pairs counted
-    assert stats["prefill_chunks"] == 5
-    assert stats["prefill_tokens_real"] == 14
-    assert 0 < stats["prefill_expert_rows"] <= 14 * 3 * 2
-    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text():
-    from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-
-    eng = LLMEngine(model="deepseek_v2", preset="tiny", max_batch=2,
-                    cache_len=16, max_prompt_len=8)
-    try:
-        assert len(eng.generate([1, 2, 3], 4)) == 4
-        assert eng._step_counters == ("expert_row_tiles", "expert_rows",
-                                      "expert_tokens_here", "experts_hit")
-        assert eng.llm_stats()["prefill_expert_rows"] == int(
-            eng._cache["counted"]["prefill_expert_rows"]) > 0
-    finally:
-        eng.shutdown_engine()
-    with pytest.raises(ValueError, match=r"granite_hybrid\|deepseek_v2"):
-        _model_bundle("mamba", None, "tiny")
 
 
 # -- every family's two programs ------------------------------------------------

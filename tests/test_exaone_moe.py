@@ -1,19 +1,17 @@
 """K-EXAONE (``models/exaone_moe.py``) against its plain reference
-(``benchmark/reference/exaone_moe.py``) at toy widths on the CPU: the
-forward pass and the prediction module's, prefill in toy chunks then
-VERIFY steps (two rows a slot, accepted and rejected drafts interleaved)
-through both stacks of rings with prompts that end before the window rings'
-first wrap, exactly at it and several wraps on, the controls that must fail
-the limit the benchmark's configuration states, the shares of all eight
-expert-parallel chips, the cache's two stacks, the types the programs
-compute in, the scopes the readers read, and the engine on the normal path:
-whatever the drafts are (the module's, an oracle's, always wrong ones), what
-it serves is token for token what it serves undrafted.
+(``benchmark/reference/exaone_moe.py``) at toy widths on the CPU: the forward
+pass and the prediction module's, prefill in toy chunks then VERIFY steps (two
+rows a slot, accepted and rejected drafts interleaved) through both stacks of
+rings with prompts that end before the window rings' first wrap, exactly at it
+and several wraps on, the controls that must fail the limit the benchmark's
+configuration states, the shares of all eight expert-parallel chips, the
+cache's two stacks, and the engine on the normal path (sizes, types and scopes
+are ``tests/test_served_family_contract.py``'s): whatever the drafts are (the
+module's, an oracle's, always wrong ones), what it serves is token for token
+what it serves undrafted.
 """
 
 import functools
-import os
-import re
 import time
 
 import jax
@@ -21,70 +19,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import exaone_moe as ex
 from ray_tpu.ops import attention
 from ray_tpu.ops.moe import dropless_experts, route
 from ray_tpu.serve import llm_engine
+from served_families import (FAMILIES, benchmark_file, contract_params, moved,
+                             rel_l2)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "exaone_moe.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "exaone_moe.py"))
-check_tool = load_module(os.path.join(REPO, "benchmark", "tools",
-                                      "serve_check_many.py"))
-CONFIG = load_json(os.path.join(REPO, "benchmark", "configs",
-                                "k-exaone-236b-a23b.json"))
+ROW = FAMILIES["exaone_moe"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+check_tool = benchmark_file("tools", "serve_check_many.py")
+CONFIG = ROW.CONFIG
+toy_file = ROW.toy_file
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = ex.ExaoneMoeConfig.tiny(dtype=F32, param_dtype=F32)
 L, G = "sliding_attention", "full_attention"
 
 
-def toy_file(cfg):
-    """The keys of a configuration file that ``families/exaone_moe.py``
-    reads, for ``cfg``'s sizes."""
-    return {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layer,
-            "layer_types": [L if w else G for w in cfg.window_layout],
-            "mlp_layer_types": ["dense"] * cfg.dense_layers
-            + ["sparse"] * (cfg.n_layer - cfg.dense_layers),
-            "sliding_windows": [cfg.window * w for w in cfg.window_layout],
-            "sliding_window": cfg.window,
-            "first_k_dense_replace": cfg.dense_layers,
-            "intermediate_size": cfg.dense_ff,
-            "moe_intermediate_size": cfg.expert_ff,
-            "num_experts": cfg.n_held,
-            "num_experts_published": cfg.n_experts,
-            "num_experts_per_tok": cfg.top_k, "num_shared_experts": 1,
-            "routed_scaling_factor": cfg.routed_scale,
-            "num_attention_heads": cfg.n_head,
-            "num_key_value_heads": cfg.n_kv_head, "head_dim": cfg.head_dim,
-            "rope_parameters": {"rope_theta": cfg.rope_theta},
-            "rms_norm_eps": cfg.eps, "vocab_size": cfg.vocab_size,
-            "max_position_embeddings": 64,
-            "assumed": {"init_gains": dict(cfg.gains)}}
-
-
-def to_ref(params, cfg=CFG):
-    return family.to_reference(params, toy_file(cfg))
-
-
-def ref_kwargs(cfg=CFG, **turned):
-    return {**family.reference_kwargs(toy_file(cfg)), **turned}
-
-
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norms start at 1, and
-    a dropped or swapped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 512))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
+@pytest.fixture(scope="module")
+def params():
+    return contract_params("exaone_moe")
 
 
 def median_l2(got, want):
@@ -173,37 +127,9 @@ def greedy_rows(cfg, params, prompt_rows, lengths, total):
 
 
 @pytest.fixture(scope="module")
-def params():
-    return moved(ex.exaone_moe_init(jax.random.PRNGKey(0), CFG))
-
-
-@pytest.fixture(scope="module")
 def tokens():
     return jnp.asarray(np.random.default_rng(0).integers(
         0, CFG.vocab_size, (3, 60), dtype=np.int32))
-
-
-def test_the_published_sizes_and_the_tiny_preset():
-    cfg = family.system_config(CONFIG)
-    assert cfg == ex.ExaoneMoeConfig(
-        vocab_size=19200, window_layout=(1, 1, 1, 0, 1),
-        experts_held=(0, 16))
-    assert (cfg.d_model, cfg.n_head, cfg.n_kv_head, cfg.head_dim,
-            cfg.dense_ff, cfg.expert_ff, cfg.shared_ff) \
-        == (6144, 64, 8, 128, 18432, 2048, 2048)
-    assert (cfg.n_window, cfg.n_global, cfg.n_held, cfg.row_width) \
-        == (4, 1, 16, 1024)
-    whole = ex.ExaoneMoeConfig()
-    assert (whole.n_layer, whole.n_window, whole.n_global, whole.n_held) \
-        == (48, 36, 12, 128)
-    tiny = ex.ExaoneMoeConfig.tiny()
-    assert (tiny.n_layer, tiny.n_window, tiny.n_global, tiny.window,
-            tiny.n_held, tiny.n_experts, tiny.top_k) == (6, 5, 1, 8, 4, 8, 3)
-    for bad in (dict(window_layout=(1, 2)), dict(n_head=3),
-                dict(experts_held=(4, 9)), dict(top_k=9),
-                dict(dense_layers=7), dict(gains=(("embed", 1.0),))):
-        with pytest.raises(ValueError):
-            ex.ExaoneMoeConfig.tiny(**bad)
 
 
 @pytest.mark.parametrize("which", ["tiny", "published"])
@@ -224,74 +150,6 @@ def test_the_cache_is_two_stacks_and_the_modules_ring_is_a_full_one(which):
                      for x in jax.tree.leaves(cache))
         assert nbytes == 65 * (2 * 8192 + 4 * 128) * 4096 + 4
         assert w == 1024  # eight K/V heads of 128: whole lane tiles, no pad
-
-
-def _programs(cfg, chunk=8):
-    params = jax.eval_shape(
-        lambda: ex.exaone_moe_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: ex.exaone_moe_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    return {
-        "decode": (lambda p, c, t, n: ex.exaone_moe_verify_step(
-            p, c, t, n, cfg), (params, cache, i32(3, 2), i32(3))),
-        "prefill": (
-            lambda p, c, t, s, a, n, f: ex.exaone_moe_prefill_chunk(
-                p, c, t, s, a, n, cfg, window=8, follows=f),
-            (params, cache, i32(1, chunk), i32(1), i32(1), i32(1), i32(1)))}
-
-
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_types_the_file_states(program):
-    """``computes_in`` of the benchmark's configuration file, held by the
-    programs' own types: weights and products in bfloat16 and nothing
-    narrower anywhere, float32 beside them, bfloat16 rings in and out."""
-    stated = family.system_config(CONFIG)
-    assert "bfloat16 weights" in CONFIG["computes_in"]
-    assert (stated.param_dtype, stated.dtype) == (jnp.bfloat16, jnp.bfloat16)
-    cfg = ex.ExaoneMoeConfig.tiny()  # the same defaults, a CPU's size
-    assert (cfg.param_dtype, cfg.dtype) == (stated.param_dtype, stated.dtype)
-    fn, args = _programs(cfg)[program]
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    logits, new_cache, *rest = jax.eval_shape(fn, *args)
-    assert logits.dtype == jnp.float32
-    assert new_cache["k_full"].shape == (2, 3, 16, 32)  # merged rows, 2 x 16
-    assert new_cache["k_win"].shape == (5, 3, 8, 32)
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), new_cache) \
-        == jax.tree.map(lambda a: (a.shape, a.dtype), args[1])
-    if program == "decode":
-        _, served, drafts = rest
-        assert logits.shape == drafts.shape == (3, 2, 256)
-        assert (served.shape, served.dtype) == ((3, 4), jnp.int32)
-    else:
-        assert logits.shape == rest[0].shape == (1, 256)
-
-
-def test_the_programs_name_the_scopes_the_readers_read():
-    texts = {name: jax.jit(fn).lower(*args).as_text(debug_info=True)
-             for name, (fn, args) in _programs(
-                 ex.ExaoneMoeConfig.tiny()).items()}
-    window = load_module(os.path.join(
-        REPO, "benchmark", "metrics", "decode_window_attention_time_pct.py"))
-    draft = load_module(os.path.join(
-        REPO, "benchmark", "metrics", "decode_draft_time_pct.py"))
-    for scope in ("embed", "ln", "router", "attn_proj", "rope", "attn",
-                  "cache_write", "moe_dispatch", "experts", "moe_combine",
-                  "shared_expert", "mlp", "head", "mtp_head", "mtp_proj") \
-            + window.KINDS + draft.PARTS:
-        for name, text in texts.items():
-            assert f"/{scope}/" in text, (name, scope)
-    for text in texts.values():
-        # the main stack under ``verify``, the module under ``mtp``, each
-        # with its attention under the outer scope the accepted readers read
-        assert "/verify/attn/attn_window/" in text
-        assert "/verify/attn/attn_global/" in text
-        assert "/mtp/attn/attn_global/" in text
-        assert "/mtp/attn/attn_window/" not in text
-        assert "/mtp/mtp_head/" in text and "/verify/head/" in text
 
 
 # -- against the reference ----------------------------------------------------
@@ -783,32 +641,3 @@ def test_the_inter_token_event_and_the_ring_wraps(monkeypatch):
     assert sum(n for _, n in gaps) == len(chunks) - 1   # a chunk, one gap
     assert sum(n for _, n in together) == chunks.count(2)
     assert sum(n for _, n in events) == 29
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text():
-    cfg, init, init_cache, chunk, step, verify = llm_engine._model_bundle(
-        "exaone_moe", None, "tiny")
-    assert cfg == ex.ExaoneMoeConfig.tiny()
-    assert (init, init_cache, chunk, step, verify) == (
-        ex.exaone_moe_init, ex.exaone_moe_init_cache,
-        ex.exaone_moe_prefill_chunk, ex.exaone_moe_decode_step,
-        ex.exaone_moe_verify_step)
-    with pytest.raises(ValueError) as err:
-        llm_engine._model_bundle("exaone", None, "tiny")
-    for name in ("gpt2", "llama", "nemotron_h", "granite_hybrid",
-                 "deepseek_v2", "falcon_h1", "qwen3_next", "smallthinker",
-                 "exaone_moe"):
-        assert name in str(err.value)
-    assert "SIXTH" in llm_engine._model_bundle.__doc__
-    # a prompt three and a half times the window passes the engine's check:
-    # cache_len bounds a context and the FULL rings, not the window rings
-    engine = llm_engine.LLMEngine(
-        model="exaone_moe", preset="tiny", max_batch=2, cache_len=32,
-        max_prompt_len=28, prefill_chunk=4)
-    try:
-        assert engine._cache["k_win"].shape[2] == 8
-        assert engine._cache["k_full"].shape[::2] == (2, 32)
-        assert len(engine.generate(list(range(1, 29)), 4)) == 4
-        assert engine.llm_stats()["draft_proposed"] > 0
-    finally:
-        engine.shutdown_engine()

@@ -1,76 +1,54 @@
 """Falcon-H1 (``models/falcon_h1.py``) against its plain reference
-(``benchmark/reference/falcon_h1.py``) at toy widths on the CPU: the forward
-pass, prefill in toy chunks then decode steps through BOTH caches of every
-layer, a wrapped ring of rotated keys, each of the fourteen multipliers moved
-alone, the controls that must fail the limit the benchmark's configuration
-states, the vocabulary's slices against the uncut head, the types the
-programs compute in, the shared ops this family added to (``ops/rotary.py``,
-``ops/mamba2.py``'s column multipliers), and the engine on the normal path
-with its counters (its two programs are held bit for bit by
-``tests/test_deepseek_v2.py``'s table).
+(``benchmark/reference/falcon_h1.py``) at toy widths on the CPU: prefill in
+toy chunks then decode steps through BOTH caches of every layer, a wrapped
+ring of rotated keys, each of the fourteen multipliers moved alone, the
+controls that must fail the limit the benchmark's configuration states, the
+vocabulary's slices against the uncut head, the shared ops this family added
+to (``ops/rotary.py``, ``ops/mamba2.py``'s column multipliers). The contracts
+every served family holds (sizes, types, scopes, the forward pass, the engine
+against the reference) are ``tests/test_served_family_contract.py``'s; its two
+programs are held bit for bit by ``tests/test_deepseek_v2.py``'s table.
 """
 
 import dataclasses
-import os
-import re
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import falcon_h1 as fh
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import mamba2, rotary
+from served_families import (FALCON_H1_SCALARS as SCALARS, FAMILIES,
+                             benchmark_file, contract_params, contract_tokens,
+                             contract_want, moved, rel_l2)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "falcon_h1.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "falcon_h1.py"))
-check_tool = load_module(os.path.join(REPO, "benchmark", "tools",
-                                      "serve_check_many.py"))
-CONFIG = load_json(os.path.join(REPO, "benchmark", "configs",
-                                "falcon-h1-34b-instruct.json"))
+ROW = FAMILIES["falcon_h1"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+check_tool = benchmark_file("tools", "serve_check_many.py")
+CONFIG = ROW.CONFIG
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = fh.FalconH1Config.tiny(dtype=F32, param_dtype=F32)
-SCALARS = ("embedding_multiplier", "lm_head_multiplier", "key_multiplier",
-           "attention_in_multiplier", "attention_out_multiplier",
-           "ssm_in_multiplier", "ssm_out_multiplier")
 FOURTEEN = [(name, None) for name in SCALARS] \
     + [("ssm_multipliers", i) for i in range(5)] \
     + [("mlp_multipliers", i) for i in range(2)]
 
 
-def ref_kwargs(cfg, **over):
-    kw = dict(eps=cfg.eps, n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
-              head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-              mamba_heads=cfg.mamba_heads,
-              mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
-              ssm_state=cfg.ssm_state, ssm_multipliers=cfg.ssm_multipliers,
-              mlp_multipliers=cfg.mlp_multipliers,
-              **{name: getattr(cfg, name) for name in SCALARS})
-    kw.update(over)
-    return kw
+@pytest.fixture(scope="module")
+def params():
+    return contract_params("falcon_h1")
 
 
-def to_ref(params):
-    return family.to_reference(params, None)
+@pytest.fixture(scope="module")
+def tokens():
+    return contract_tokens("falcon_h1")
 
 
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norm scales start at
-    one, and a dropped or swapped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
+@pytest.fixture(scope="module")
+def want():
+    return contract_want("falcon_h1")
 
 
 def with_multiplier(cfg, name, index, factor=1.7):
@@ -84,6 +62,18 @@ def with_multiplier(cfg, name, index, factor=1.7):
     return dataclasses.replace(cfg, **{name: value})
 
 
+@functools.lru_cache(maxsize=None)
+def _serving(cfg, chunk):
+    """The prompts' chunks and the step, each ONE compiled program a
+    (configuration, shape) for every test that runs them: the parameters
+    are arguments, not constants of the program."""
+    return (jax.jit(lambda params, c, prompts, slots, lengths: whole_prompts(
+        fh.falcon_h1_prefill_chunk, params, c, prompts, slots, lengths, cfg,
+        chunk=chunk)),
+        jax.jit(lambda params, c, t, n: fh.falcon_h1_decode_step(
+            params, c, t, n, cfg)[:2]))
+
+
 def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
                       cache_len=64, window=48):
     """The serving functions: the prompts (``tokens[r, :lengths[r]]``) in
@@ -93,76 +83,22 @@ def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
     prompts = jnp.where(jnp.arange(window)[None] < lengths[:, None],
                         tokens[:, :window], 0)
     cache = fh.falcon_h1_init_cache(cfg, r + 1, cache_len)
-    logits, cache = whole_prompts(
-        fh.falcon_h1_prefill_chunk, params, cache, prompts, jnp.arange(r),
-        lengths, cfg, chunk=chunk)
+    prefill, step = _serving(cfg, chunk)
+    logits, cache = prefill(params, cache, prompts, jnp.arange(r), lengths)
     out, rows, free = [logits], jnp.arange(r), jnp.zeros(1, jnp.int32)
-    step = jax.jit(lambda c, t, n: fh.falcon_h1_decode_step(
-        params, c, t, n, cfg)[:2])
     for i in range(steps):
         logits, cache = step(
-            cache, jnp.concatenate([tokens[rows, lengths + i], free]),
+            params, cache, jnp.concatenate([tokens[rows, lengths + i], free]),
             jnp.concatenate([lengths + i, free]))
         out.append(logits[:r])
     return jnp.stack(out, axis=1)
 
 
 def reference_rows(params, cfg, tokens, lengths, steps, **over):
-    full = reference.forward(to_ref(params), tokens,
-                             **ref_kwargs(cfg, **over))
+    full = ROW.reference_forward(params, cfg, **over)(tokens)
     rows = jnp.arange(tokens.shape[0])
     return jnp.stack([full[rows, lengths - 1 + i]
                       for i in range(steps + 1)], axis=1)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return moved(fh.falcon_h1_init(jax.random.PRNGKey(0), CFG))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return jnp.asarray(np.random.default_rng(1).integers(
-        0, CFG.vocab_size, (3, 40), dtype=np.int32))
-
-
-@pytest.fixture(scope="module")
-def want(params, tokens):
-    # (jitted: op by op the reference costs several times as much, D19)
-    return jax.jit(lambda t: reference.forward(
-        to_ref(params), t, **ref_kwargs(CFG)))(tokens)
-
-
-def test_the_published_sizes_and_the_tiny_preset():
-    cfg = fh.FalconH1Config()
-    assert (cfg.n_layer, cfg.d_model, cfg.vocab_size, cfg.d_ff) \
-        == (72, 5120, 261120, 21504)
-    assert (cfg.n_head, cfg.n_kv_head, cfg.head_dim) == (20, 4, 128)
-    assert cfg.rope_theta == 1e11
-    m = cfg.mamba
-    assert (m.heads, m.head_dim, m.groups, m.state, m.kernel, m.block) \
-        == (32, 128, 2, 256, 4, 128)
-    assert (m.d_inner, m.conv_dim, m.in_width) == (4096, 5120, 9248)
-    assert m.in_multipliers == cfg.ssm_multipliers
-    # the tiny preset keeps what makes the family: two groups, a state that
-    # is not the head size, d_inner that is not twice the hidden size,
-    # grouped queries, and no multiplier that a test could lose unseen
-    tiny = fh.FalconH1Config.tiny()
-    assert tiny.ssm_groups == 2 and tiny.ssm_state != tiny.mamba_head_dim
-    assert tiny.mamba.d_inner != 2 * tiny.d_model
-    assert tiny.n_kv_head < tiny.n_head
-    every = [getattr(tiny, n) for n in SCALARS] \
-        + list(tiny.ssm_multipliers) + list(tiny.mlp_multipliers)
-    assert len(every) == 14 == len(FOURTEEN)
-    assert all(v != 1 and np.log2(v) % 1 for v in every)
-    assert CFG.serving_stats() == {"prefill_expert_rows": 0,  # no experts
-                                   "chunk_attention_arm": "xla"}  # toy widths
-    with pytest.raises(ValueError, match="five factors"):
-        fh.FalconH1Config.tiny(ssm_multipliers=(1.0, 2.0))
-    with pytest.raises(ValueError, match="gains"):
-        fh.FalconH1Config.tiny(gains=(("embed", 1.0),))
-    with pytest.raises(ValueError, match="divide"):
-        fh.FalconH1Config.tiny(n_kv_head=3)
 
 
 def test_weights_are_bfloat16_the_head_is_its_own_and_every_layer_has_both():
@@ -196,60 +132,6 @@ def test_weights_are_bfloat16_the_head_is_its_own_and_every_layer_has_both():
     assert got == pytest.approx(std["w_down"], rel=0.05)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_types_the_file_states(program):
-    """``computes_in`` of the benchmark's configuration file, held by the
-    programs' own types: weights and products in bfloat16 and nothing
-    narrower anywhere, float32 beside them (softmax, rotary angles, dt / A,
-    norms' statistics, the multipliers' products), and a float32 state in
-    and out."""
-    stated = family.system_config(CONFIG)
-    assert CONFIG["assumed"]["ssm_state_dtype"] == "float32"
-    assert "bfloat16 weights" in CONFIG["computes_in"]
-    assert (stated.param_dtype, stated.dtype, stated.ssm_state_dtype) \
-        == (jnp.bfloat16, jnp.bfloat16, jnp.float32)
-    cfg = fh.FalconH1Config.tiny()  # the same defaults, a CPU's size
-    assert (cfg.param_dtype, cfg.dtype, cfg.ssm_state_dtype) \
-        == (stated.param_dtype, stated.dtype, stated.ssm_state_dtype)
-    params = jax.eval_shape(
-        lambda: fh.falcon_h1_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: fh.falcon_h1_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    if program == "decode":
-        fn = lambda p, c, t, n: fh.falcon_h1_decode_step(p, c, t, n, cfg)
-        args = (params, cache, i32(3), i32(3))
-    else:
-        fn = lambda p, c, t, s, n: fh.falcon_h1_prefill_chunk(
-            p, c, t, s, jnp.zeros_like(s), n, cfg)
-        args = (params, cache, i32(1, 16), i32(1), i32(1))
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    logits, new_cache, *counted = jax.eval_shape(fn, *args)
-    assert logits.dtype == jnp.float32
-    assert [s.dtype for s in new_cache["ssm"]] == [jnp.float32] * 3
-    assert new_cache["k"].dtype == new_cache["conv"].dtype == jnp.bfloat16
-    assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
-    assert "counted" not in new_cache  # no experts: nothing to count
-    # the step says what its attention read of the rings (PR 48)
-    assert [sorted(c) for c in counted] == (
-        [["ring_rows_held", "ring_rows_read"]] if program == "decode"
-        else [])
-
-
-def test_forward_agrees_with_the_reference(params, tokens, want):
-    forward = jax.jit(lambda p, t: fh.falcon_h1_forward(p, t, CFG))
-    got = forward(params, tokens)
-    assert got.shape == want.shape == (3, 40, CFG.vocab_size)
-    assert rel_l2(got, want) < 1e-4
-    # a row longer than one block of the scan, and not a multiple of it
-    assert tokens.shape[1] > 2 * CFG.chunk_size
-    odd = forward(params, tokens[:, :37])
-    assert rel_l2(odd, want[:, :37]) < 1e-4
-
-
 @pytest.mark.parametrize("name, index", FOURTEEN, ids=[
     n if i is None else f"{n}[{i}]" for n, i in FOURTEEN])
 def test_each_multiplier_moved_alone_moves_both_alike(params, tokens, want,
@@ -259,7 +141,7 @@ def test_each_multiplier_moved_alone_moves_both_alike(params, tokens, want,
     model it was (a multiplier that one of them dropped, applied twice or
     applied elsewhere would part them)."""
     cfg = with_multiplier(CFG, name, index)
-    other = reference.forward(to_ref(params), tokens, **ref_kwargs(cfg))
+    other = ROW.reference_forward(params, cfg)(tokens)
     got = fh.falcon_h1_forward(params, tokens, cfg)
     assert rel_l2(got, other) < 1e-4
     assert rel_l2(other, want) > 5e-3, (name, index)
@@ -282,7 +164,7 @@ def test_prefill_in_toy_chunks_then_decode_through_the_cache(chunks,
     steps, window = 5, 72
     row = jnp.asarray(np.random.default_rng(length).integers(
         0, cfg.vocab_size, (1, length + steps), dtype=np.int32))
-    want = reference.forward(to_ref(params), row, **ref_kwargs(cfg))
+    want = ROW.reference_forward(params, cfg)(row)
     cache = fh.falcon_h1_init_cache(cfg, 2, window + 8)
     chunk = jax.jit(lambda c, t, at, n: fh.falcon_h1_prefill_chunk(
         params, c, t, jnp.ones(1, jnp.int32), at, n, cfg, window=window))
@@ -423,14 +305,13 @@ def test_the_eight_vocabulary_slices_add_up_to_the_uncut_head(params,
     uncut head's, by the reference and by the program alike."""
     v = CFG.vocab_size // 8
     ids = tokens % v  # ids of the slice held here
-    uncut = reference.forward(to_ref(params), ids, **ref_kwargs(CFG))
+    uncut = ROW.reference_forward(params, CFG)(ids)
     small = dataclasses.replace(CFG, vocab_size=v)
     parts, served = [], []
     for s in range(8):
         share = {**params, "embed": params["embed"][:v],
                  "lm_head": params["lm_head"][s * v:(s + 1) * v]}
-        parts.append(reference.forward(to_ref(share), ids,
-                                       **ref_kwargs(small)))
+        parts.append(ROW.reference_forward(share, small)(ids))
         served.append(fh.falcon_h1_forward(share, ids, small))
     np.testing.assert_allclose(np.asarray(jnp.concatenate(parts, -1)),
                                np.asarray(uncut), rtol=1e-5, atol=1e-5)
@@ -513,103 +394,3 @@ def test_the_mixers_column_multipliers_in_the_step_and_in_rows_alike():
         lambda y: mamba2.mamba_step(p, y, tail, state, d)[0])(y[:, 0]))
     assert traced(plain).count(" mul ") + 1 == traced(dims).count(" mul ")
 
-
-def test_the_programs_name_the_scopes_the_readers_read():
-    """Every scope the three new readers (and the older ones) sum over is
-    on some operation's path in the program each names it for: the step's
-    ``ssm_update`` is the chunk's ``ssm_scan``, and the sum of the two
-    branches is an operation of its own (``mixer_sum``)."""
-    cfg = fh.FalconH1Config.tiny()
-    params = jax.eval_shape(
-        lambda: fh.falcon_h1_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: fh.falcon_h1_init_cache(cfg, 3, 16))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    step = jax.jit(lambda p, c, t, n: fh.falcon_h1_decode_step(
-        p, c, t, n, cfg)).lower(params, cache, i32(3), i32(3)).as_text(
-            debug_info=True)
-    chunk = jax.jit(lambda p, c, t, s, a, n: fh.falcon_h1_prefill_chunk(
-        p, c, t, s, a, n, cfg, window=8)).lower(
-            params, cache, i32(1, 4), i32(1), i32(1), i32(1)).as_text(
-                debug_info=True)
-    reader = load_module(os.path.join(
-        REPO, "benchmark", "metrics", "decode_parallel_mixer_time_pct.py"))
-    both = reader.ATTENTION + reader.STATE + (
-        "embed", "ln", "mlp", "head", "mixer_sum")
-    for scope in both:
-        if scope != "ssm_scan":
-            assert f"/{scope}/" in step, scope
-        if scope != "ssm_update":
-            assert f"/{scope}/" in chunk, scope
-
-
-# -- the engine ---------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield serve
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_tokens(runtime):
-    """``LLMEngine(model="falcon_h1")`` at the tiny preset's sizes through
-    ``serve.run`` / ``handle.stream`` in float32: token for token the
-    reference's greedy choice, two compiled programs, and what the model
-    says of its two caches in ``llm_stats()``."""
-    import ray_tpu
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
-    handle = runtime.run(dep.bind(
-        model="falcon_h1", config=CFG, seed=10, max_batch=3, cache_len=32,
-        max_prompt_len=16, prefill_rows=2, prefill_chunk=4))
-    params = fh.falcon_h1_init(jax.random.PRNGKey(10), CFG)
-    ref, kw = to_ref(params), ref_kwargs(CFG)
-    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
-    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-    for prompt in prompts:
-        toks = list(prompt)
-        for _ in range(6):  # causal: one padded shape serves every length
-            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
-            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
-        assert served == toks[len(prompt):]
-        assert len(set(served)) > 3  # no fixed point: it follows its context
-    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
-    assert stats["compiles"] == {"decode": 1, "prefill": 1}
-    assert stats["model"] == "falcon_h1"
-    assert stats["steps"] >= 10
-    # the chunks: 2 + 3 executions, 14 real tokens, no expert to count
-    assert stats["prefill_chunks"] == 5
-    assert stats["prefill_tokens_real"] == 14
-    assert stats["prefill_expert_rows"] == 0
-    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text():
-    from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-
-    eng = LLMEngine(model="falcon_h1", preset="tiny", max_batch=2,
-                    cache_len=16, max_prompt_len=8)
-    try:
-        assert len(eng.generate([1, 2, 3], 4)) == 4
-        # no experts to count; the rings' rows read and held (PR 48): a toy
-        # row keeps the XLA arm, which reads every row it holds
-        assert eng._step_counters == ("ring_rows_held", "ring_rows_read")
-        stats = eng.llm_stats()
-        assert stats["ring_rows_read"] == stats["ring_rows_held"] \
-            == stats["steps"] * eng._cfg.n_layer * 3 * 16
-    finally:
-        eng.shutdown_engine()
-    with pytest.raises(ValueError, match=r"gpt2\|llama\|nemotron_h\|"
-                       r"granite_hybrid\|deepseek_v2\|falcon_h1"):
-        _model_bundle("mamba", None, "tiny")

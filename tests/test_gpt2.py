@@ -71,8 +71,9 @@ def test_config_paths_match_baseline(remat, scan_layers):
     batch = {"tokens": tokens.astype(jnp.int32)}
     params = gpt2_init(jax.random.key(0), f32)
 
-    def loss_for(cfg):
-        return jax.value_and_grad(lambda p: gpt2_loss(p, batch, cfg))(params)
+    def loss_for(cfg):  # (one compiled program: op by op it costs tenfold)
+        return jax.jit(jax.value_and_grad(
+            lambda p: gpt2_loss(p, batch, cfg)))(params)
 
     base_loss, base_grads = loss_for(f32)
     cfg = dataclasses.replace(f32, remat=remat, scan_layers=scan_layers)

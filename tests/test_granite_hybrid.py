@@ -1,110 +1,41 @@
 """Granite 4.0-H (``models/granite_hybrid.py``) against its plain reference
-(``benchmark/reference/granite_hybrid.py``) at toy widths on the CPU: the
-forward pass, prefill in 1, 2, 17 and 32 toy chunks then decode steps through
-the cache, each published multiplier and the gate as a control (left out of
-the reference in turn, the comparison fails), the types the programs
-compute in, and the engine on the normal path with its counters.
+(``benchmark/reference/granite_hybrid.py``) at toy widths on the CPU: prefill
+in 1, 2, 17 and 32 toy chunks then decode steps through the cache, each
+published multiplier and the gate as a control (left out of the reference in
+turn, the comparison fails). The contracts every served family holds (sizes,
+types, the forward pass, the engine against the reference) are
+``tests/test_served_family_contract.py``'s.
 """
-
-import os
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models.prefill import whole_prompts
+from served_families import (FAMILIES, contract_params, contract_tokens,
+                             contract_want, moved, rel_l2)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "granite_hybrid.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "granite_hybrid.py"))
+ROW = FAMILIES["granite_hybrid"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = gh.GraniteHybridConfig.tiny(dtype=F32, param_dtype=F32)
-
-
-def ref_kwargs(cfg, **over):
-    kw = dict(layer_types=cfg.layer_types, eps=cfg.eps, n_head=cfg.n_head,
-              n_kv_head=cfg.n_kv_head, head_dim=cfg.head_dim,
-              mamba_heads=cfg.mamba_heads,
-              mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
-              ssm_state=cfg.ssm_state, top_k=cfg.top_k,
-              first_expert=cfg.experts_held[0],
-              embedding_multiplier=cfg.embedding_multiplier,
-              attention_multiplier=cfg.attention_multiplier,
-              residual_multiplier=cfg.residual_multiplier,
-              logits_scaling=cfg.logits_scaling)
-    kw.update(over)
-    return kw
-
-
-def to_ref(params, cfg):
-    return {"embed_tokens": params["embed"], "norm": params["norm_f"],
-            "layers": [{ref: p[name] for name, ref in {
-                **family.LAYER_NAMES, **family.MIXER_NAMES[kind]}.items()}
-                for kind, p in zip(cfg.layer_types, params["layers"])]}
-
-
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norm scales start at
-    one, and a dropped or swapped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
-
-
-def weighty(params):
-    """At their seeded scale the routed experts and attention add a
-    hundredth of what a Mamba mixer adds: make them count, so that a
-    fault in either is seen."""
-    big = {"w2": 6.0, "wo": 6.0}
-    return {**params, "layers": [
-        {k: v * big.get(k, 1.0) for k, v in p.items()}
-        for p in params["layers"]]}
 
 
 @pytest.fixture(scope="module")
 def params():
-    return weighty(moved(gh.granite_hybrid_init(jax.random.PRNGKey(0), CFG)))
+    return contract_params("granite_hybrid")
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    return jnp.asarray(np.random.default_rng(1).integers(
-        0, CFG.vocab_size, (3, 40), dtype=np.int32))
+    return contract_tokens("granite_hybrid")
 
 
 @pytest.fixture(scope="module")
-def want(params, tokens):
-    # (jitted: op by op the reference costs several times as much, D19)
-    return jax.jit(lambda t: reference.forward(
-        to_ref(params, CFG), t, **ref_kwargs(CFG)))(tokens)
-
-
-def test_the_published_layers_and_the_tiny_preset():
-    types = gh.GraniteHybridConfig().layer_types
-    assert len(types) == 40
-    assert [types.count(k) for k in ("mamba", "attention")] == [36, 4]
-    assert all(types[i:i + 10] == types[:10] for i in range(0, 40, 10))
-    assert types[:10].index("attention") == 5
-    assert set(CFG.layer_types) == {"mamba", "attention"}
-    assert CFG.attention_multiplier != CFG.head_dim ** -0.5
-    assert gh.GraniteHybridConfig().attention_multiplier == 1 / 128
-    assert CFG.serving_stats() == {"expert_layers": 3, "experts_held": 4}
-    with pytest.raises(ValueError, match="layer_types"):
-        gh.GraniteHybridConfig.tiny(layer_types=("mamba", "moe"))
-    with pytest.raises(ValueError, match="experts_held"):
-        gh.GraniteHybridConfig.tiny(experts_held=(6, 4))
+def want():
+    return contract_want("granite_hybrid")
 
 
 def test_weights_are_stored_in_bfloat16_and_the_head_is_the_embedding():
@@ -123,64 +54,6 @@ def test_weights_are_stored_in_bfloat16_and_the_head_is_the_embedding():
         == [((3, 8, 16, 16), jnp.float32)] * 2
     assert cfg.serving_dtypes(params) == jax.tree.map(
         lambda x: x.dtype, params)
-
-
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_types_the_file_states(program):
-    """``computes_in`` of the benchmark's configuration file, held by the
-    programs' own types: weights and products in bfloat16 and nothing
-    narrower anywhere, float32 beside them (router, softmax, dt / A, norms'
-    statistics), and a float32 state in and out."""
-    config = load_json(os.path.join(
-        REPO, "benchmark", "configs", "granite-4.0-h-small.json"))
-    stated = family.system_config(config)
-    assert config["assumed"]["ssm_state_dtype"] == "float32"
-    assert "bfloat16 weights" in config["computes_in"]
-    assert (stated.param_dtype, stated.dtype, stated.ssm_state_dtype) \
-        == (jnp.bfloat16, jnp.bfloat16, jnp.float32)
-    cfg = gh.GraniteHybridConfig.tiny()  # the same defaults, a CPU's size
-    assert (cfg.param_dtype, cfg.dtype, cfg.ssm_state_dtype) \
-        == (stated.param_dtype, stated.dtype, stated.ssm_state_dtype)
-    params = jax.eval_shape(
-        lambda: gh.granite_hybrid_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: gh.granite_hybrid_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    if program == "decode":
-        fn = lambda p, c, t, n: gh.granite_hybrid_decode_step(
-            p, c, t, n, cfg)
-        args = (params, cache, i32(3), i32(3))
-    else:
-        fn = lambda p, c, t, s, n: gh.granite_hybrid_prefill_chunk(
-            p, c, t, s, jnp.zeros_like(s), n, cfg)
-        args = (params, cache, i32(1, 16), i32(1), i32(1))
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    logits, new_cache, *counted = jax.eval_shape(fn, *args)
-    assert logits.dtype == jnp.float32
-    assert [s.dtype for s in new_cache["ssm"]] == [jnp.float32] * 2
-    # the step returns its counters third; the chunk program counts in
-    # the cache, which both hand on
-    counted = [*counted, new_cache["counted"]]
-    assert all(v.dtype == jnp.int32 and v.shape == ()
-               for c in counted for v in c.values())
-    assert [set(c) for c in counted] == (
-        [{"experts_hit", "expert_rows", "expert_row_tiles"}]
-        if program == "decode" else []) \
-        + [{"prefill_expert_rows"}]
-
-
-def test_forward_agrees_with_the_reference(params, tokens, want):
-    forward = jax.jit(lambda p, t: gh.granite_hybrid_forward(p, t, CFG))
-    got = forward(params, tokens)
-    assert got.shape == want.shape == (3, 40, CFG.vocab_size)
-    assert rel_l2(got, want) < 1e-4
-    # a row longer than one block of the scan, and not a multiple of it
-    assert tokens.shape[1] > 2 * CFG.chunk_size
-    odd = forward(params, tokens[:, :37])
-    assert rel_l2(odd, want[:, :37]) < 1e-4
 
 
 @pytest.mark.parametrize("term, without", [
@@ -202,8 +75,7 @@ def test_the_reference_without_a_term_is_another_model(
         monkeypatch.setattr(reference, "gated", lambda ab: jax.nn.silu(
             ab[..., :ab.shape[-1] // 2]))
         without = {}
-    other = reference.forward(to_ref(params, CFG), tokens,
-                              **ref_kwargs(CFG, **without))
+    other = ROW.reference_forward(params, CFG, **without)(tokens)
     assert rel_l2(other, want) > 2e-2, term
     got = gh.granite_hybrid_forward(params, tokens, CFG)
     assert rel_l2(got, other) > 2e-2, term
@@ -227,7 +99,7 @@ def test_prefill_in_toy_chunks_then_decode_through_the_cache(chunks,
     steps, window = 5, 128
     row = jnp.asarray(np.random.default_rng(length).integers(
         0, cfg.vocab_size, (1, length + steps), dtype=np.int32))
-    want = reference.forward(to_ref(params, cfg), row, **ref_kwargs(cfg))
+    want = ROW.reference_forward(params, cfg)(row)
     cache = gh.granite_hybrid_init_cache(cfg, 2, window + 8)
     chunk = jax.jit(lambda c, t, at, n: gh.granite_hybrid_prefill_chunk(
         params, c, t, jnp.ones(1, jnp.int32), at, n, cfg, window=window))
@@ -355,86 +227,3 @@ def test_a_published_scale_is_the_references(expand):
     other = _through_the_ops(q, k, v, None, expand)
     assert float(jnp.abs(other - want).max()) > 1e-2
 
-
-# -- the engine ---------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield serve
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_tokens(runtime):
-    """``LLMEngine(model="granite_hybrid", preset="tiny")``'s sizes
-    through ``serve.run`` / ``handle.stream`` in float32: token for token
-    the reference's greedy choice, two compiled programs, and the step's
-    and the chunks' counters in ``llm_stats()``."""
-    import dataclasses
-
-    import ray_tpu
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    # With the tied head and the published multiplier 12, seeded weights
-    # answer every token with itself (the embedding's own row leads its
-    # logits by several spreads: on the chip too, PERF.md section 7), and
-    # a greedy continuation would then say nothing of state or cache. At
-    # 0.3 the continuation depends on the whole context.
-    cfg = dataclasses.replace(CFG, embedding_multiplier=0.3)
-    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
-    handle = runtime.run(dep.bind(
-        model="granite_hybrid", config=cfg, seed=3, max_batch=3,
-        cache_len=32, max_prompt_len=16, prefill_rows=2, prefill_chunk=4))
-    params = gh.granite_hybrid_init(jax.random.PRNGKey(3), cfg)
-    ref, kw = to_ref(params, cfg), ref_kwargs(cfg)
-    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
-    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-    for prompt in prompts:
-        toks = list(prompt)
-        for _ in range(6):  # causal: one padded shape serves every length
-            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
-            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
-        assert served == toks[len(prompt):]
-        assert len(set(served)) > 3  # no fixed point
-    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
-    assert stats["compiles"] == {"decode": 1, "prefill": 1}
-    assert stats["model"] == "granite_hybrid"
-    assert stats["expert_layers"] == 3 and stats["experts_held"] == 4
-    steps = stats["steps"]
-    assert steps >= 10
-    # every step runs max_batch + 1 rows through 3 expert layers, top 3
-    assert 0 < stats["experts_hit"] <= steps * 3 * 4
-    assert stats["experts_hit"] <= stats["expert_rows"] <= steps * 3 * 12
-    # the chunks: 2 + 3 executions, 14 real tokens, their pairs counted
-    assert stats["prefill_chunks"] == 5
-    assert stats["prefill_tokens_real"] == 14
-    assert 0 < stats["prefill_expert_rows"] <= 14 * 3 * 3
-    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text():
-    from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-
-    eng = LLMEngine(model="granite_hybrid", preset="tiny", max_batch=2,
-                    cache_len=16, max_prompt_len=8)
-    try:
-        assert len(eng.generate([1, 2, 3], 4)) == 4
-        assert eng._step_counters == ("expert_row_tiles", "expert_rows",
-                                      "experts_hit")
-        assert eng.llm_stats()["prefill_expert_rows"] == int(
-            eng._cache["counted"]["prefill_expert_rows"]) > 0
-    finally:
-        eng.shutdown_engine()
-    with pytest.raises(ValueError,
-                       match=r"gpt2\|llama\|nemotron_h\|granite_hybrid"):
-        _model_bundle("mamba", None, "tiny")

@@ -1,113 +1,79 @@
 """Keye-VL-2.0's language model (``models/keye_vl2.py``) against its plain
 reference (``benchmark/reference/keye_vl2.py``) at toy widths on the CPU:
-prefill in toy chunks then decode steps through both stacks of rings,
-logits in float32, with prompts that end before the selection starts,
-exactly at it and well past it, with a chunk boundary inside the crossing,
-in slots other than 0 beside a scratch row; the controls that turn an
-``assumed`` reading the other way and must fail; M-RoPE with three equal
-streams against the one-stream rotation; one set a token for all heads; a
-free slot and a padded row that pick and count nothing; the counters; the
-share test (eight shares of the experts add up to the uncut layer); the
-cache's two stacks; the scopes the readers read; and the engine on the
-normal path. Every family's two programs, this one's among them, are held
-bit for bit by ``tests/test_deepseek_v2.py``'s one table.
+prefill in toy chunks then decode steps through both stacks of rings, logits
+in float32, with prompts that end before the selection starts, exactly at it
+and well past it, with a chunk boundary inside the crossing, in slots other
+than 0 beside a scratch row; the controls that turn an ``assumed`` reading the
+other way and must fail; M-RoPE with three equal streams against the
+one-stream rotation; one set a token for all heads; a free slot and a padded
+row that pick and count nothing; the counters; the share test (eight shares of
+the experts add up to the uncut layer); and the cache's two stacks. The
+contracts every served family holds (sizes, scopes, the forward pass, the
+engine against the reference) are ``tests/test_served_family_contract.py``'s.
+Every family's two programs, this one's among them, are held bit for bit by
+``tests/test_deepseek_v2.py``'s one table.
 """
 
-import os
-import re
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import keye_vl2 as kv
 from ray_tpu.ops.rotary import rotate
+from served_families import FAMILIES, contract_params, moved, rel_l2
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "keye_vl2.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "keye_vl2.py"))
-CONFIG = load_json(os.path.join(REPO, "benchmark", "configs",
-                                "keye-vl-2.0-30b-a3b.json"))
+ROW = FAMILIES["keye_vl2"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+CONFIG = ROW.CONFIG
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = kv.KeyeVL2Config.tiny(dtype=F32, param_dtype=F32)
 TOPK = CFG.index_topk  # 16
 
 
-def toy_file(cfg):
-    """The keys of a configuration file that ``families/keye_vl2.py``
-    reads, for ``cfg``'s sizes."""
-    half = cfg.head_dim // 2
-    return {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layer,
-            "num_attention_heads": cfg.n_head,
-            "num_key_value_heads": cfg.n_kv_head, "head_dim": cfg.head_dim,
-            "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.eps,
-            "rope_scaling": {"mrope_section": [half - 2 * (half // 3),
-                                               half // 3, half // 3],
-                             "rope_type": "default"},
-            "sa_config": {"indexer_head_dim": cfg.index_dim,
-                          "indexer_num_heads": cfg.index_heads,
-                          "indexer_num_kv_heads": 1, "topk": cfg.index_topk},
-            "num_experts": cfg.experts_held[1],
-            "num_local_experts": cfg.experts_held[1],
-            "num_experts_per_tok": cfg.top_k,
-            "moe_intermediate_size": cfg.expert_ff,
-            "vocab_size": cfg.vocab_size, "max_position_embeddings": 128,
-            "assumed": {"router_experts": cfg.n_experts,
-                        "init_gains": dict(cfg.gains)}}
+@pytest.fixture(scope="module")
+def params():
+    return contract_params("keye_vl2")
 
 
-def to_ref(params, cfg=CFG):
-    return family.to_reference(params, toy_file(cfg))
-
-
-def ref_kwargs(cfg=CFG, **turned):
-    return {**family.reference_kwargs(toy_file(cfg)),
-            "first_expert": cfg.experts_held[0], **turned}
-
-
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norms start at 1 and
-    the LayerNorm's bias at 0, and a dropped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 512))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
+@functools.lru_cache(maxsize=None)
+def _serving(cfg, chunk):
+    """The whole-window prefill and the step with its sets, each ONE
+    compiled program a (configuration, shape) for every test that runs
+    them: the parameters are arguments, not constants of the program."""
+    return (jax.jit(lambda params, c, prompts, slots, lengths:
+                    kv.keye_vl2_prefill(params, c, prompts, slots, lengths,
+                                        cfg, chunk=chunk)),
+            jax.jit(lambda params, c, t, n: kv.keye_vl2_step_with_sets(
+                params, c, t, n, cfg)))
 
 
 def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
                       cache_len=96, padded=64, slots=None, n_slots=None,
-                      with_sets=False):
+                      with_sets=False, fresh=False):
     """The serving functions: the prompts (``tokens[r, :lengths[r]]``) in
     chunks through ``keye_vl2_prefill_chunk``, then ``steps`` decode steps
     fed ``tokens``' continuation, the rows in ``slots`` (the first ones by
     default) of ``n_slots`` (one more than the rows: a scratch row that
     every step computes). -> logits [R, 1 + steps, V] (and every step's
-    counters and sets)."""
+    counters and sets). ``fresh``: traced anew, for a test that watches the
+    trace or has turned a function the programs call."""
     r = tokens.shape[0]
     n_slots = n_slots or r + 1
     slots = jnp.arange(r) if slots is None else jnp.asarray(slots)
     prompts = jnp.where(jnp.arange(padded)[None] < lengths[:, None],
                         tokens[:, :padded], 0)
     cache = kv.keye_vl2_init_cache(cfg, n_slots, cache_len)
-    logits, cache = jax.jit(lambda c: kv.keye_vl2_prefill(
-        params, c, prompts, slots, lengths, cfg, chunk=chunk))(cache)
+    prefill, step = (_serving.__wrapped__ if fresh else _serving)(cfg, chunk)
+    logits, cache = prefill(params, cache, prompts, slots, lengths)
     out, rows, kept = [logits], jnp.arange(r), []
-    step = jax.jit(lambda c, t, n: kv.keye_vl2_step_with_sets(
-        params, c, t, n, cfg))
     for i in range(steps):
         toks = jnp.zeros((n_slots,), jnp.int32).at[slots].set(
             tokens[rows, lengths + i])
         pos = jnp.zeros((n_slots,), jnp.int32).at[slots].set(lengths + i)
-        logits, cache, counters, sets, sizes = step(cache, toks, pos)
+        logits, cache, counters, sets, sizes = step(params, cache, toks, pos)
         out.append(logits[slots])
         kept.append((counters, sets, sizes))
     out = jnp.stack(out, axis=1)
@@ -115,16 +81,10 @@ def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
 
 
 def reference_rows(params, cfg, tokens, lengths, steps, **turned):
-    full = jax.jit(lambda t: reference.forward(
-        to_ref(params, cfg), t, **ref_kwargs(cfg, **turned)))(tokens)
+    full = ROW.reference_forward(params, cfg, **turned)(tokens)
     rows = jnp.arange(tokens.shape[0])
     return jnp.stack([full[rows, lengths - 1 + i] for i in range(steps + 1)],
                      axis=1)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return moved(kv.keye_vl2_init(jax.random.PRNGKey(0), CFG))
 
 
 @pytest.fixture(scope="module")
@@ -134,19 +94,6 @@ def tokens():
 
 
 # -- sizes ----------------------------------------------------------------------
-
-
-def test_the_published_sizes_and_the_tiny_preset():
-    cfg = kv.KeyeVL2Config()
-    assert (cfg.d_model, cfg.n_layer, cfg.n_head, cfg.n_kv_head,
-            cfg.head_dim, cfg.rope_theta) == (2048, 48, 32, 4, 128, 1e7)
-    assert (cfg.index_heads, cfg.index_dim, cfg.index_topk) == (16, 64, 2048)
-    assert (cfg.n_experts, cfg.experts_held, cfg.top_k, cfg.expert_ff) \
-        == (128, (0, 128), 8, 768)
-    assert cfg.row_width == 512
-    tiny = kv.KeyeVL2Config.tiny()
-    assert tiny.n_kv_head < tiny.n_head and tiny.top_k < tiny.n_experts
-    assert tiny.index_heads > 1 and tiny.index_topk == 16
 
 
 @pytest.mark.parametrize("bad", [
@@ -212,26 +159,6 @@ def test_every_counter_of_the_cache_is_a_buffer_of_its_own():
     assert len({x.unsafe_buffer_pointer() for x in leaves}) == len(leaves)
 
 
-def test_the_programs_name_the_scopes_the_readers_read(params):
-    cache = kv.keye_vl2_init_cache(CFG, 3, 32)
-    step = jax.jit(lambda c, t, n: kv.keye_vl2_decode_step(
-        params, c, t, n, CFG)).lower(
-            cache, jnp.zeros((3,), jnp.int32),
-            jnp.zeros((3,), jnp.int32)).as_text(debug_info=True)
-    chunk = jax.jit(lambda c, t: kv.keye_vl2_prefill_chunk(
-        params, c, t, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-        jnp.full((1,), 8, jnp.int32), CFG, window=32)).lower(
-            cache, jnp.zeros((1, 8), jnp.int32)).as_text(debug_info=True)
-    for text in (step, chunk):
-        # (in the chunk program a conditional's branch stands between
-        # ``attn`` and the three scopes inside it)
-        for scope in ("indexer", "select", "attn_sparse"):
-            assert re.search(rf'"[^"]*/attn/([^"]*/)?{scope}/', text), scope
-        for scope in ("attn_proj", "router", "experts", "cache_write",
-                      "head"):
-            assert re.search(rf'"[^"]*/{scope}/', text), scope
-
-
 # -- against the reference ----------------------------------------------------------
 
 # prompts' lengths by what their eight decode steps cross: TOPK is 16
@@ -261,12 +188,6 @@ def test_slots_other_than_the_first_beside_a_used_scratch_row(params, tokens):
     got = through_the_cache(CFG, params, tokens, lengths, 4, slots=[4, 0, 2],
                             n_slots=6)
     want = reference_rows(params, CFG, tokens, lengths, 4)
-    assert rel_l2(got, want) < 2e-5
-
-
-def test_forward_is_the_references(params, tokens):
-    got = kv.keye_vl2_forward(params, tokens[:, :40], CFG)
-    want = reference.forward(to_ref(params), tokens[:, :40], **ref_kwargs())
     assert rel_l2(got, want) < 2e-5
 
 
@@ -315,7 +236,7 @@ def test_the_chunk_program_through_its_kernel_is_the_references(monkeypatch,
                               cfg.vocab_size)
     lengths = jnp.asarray(lengths, jnp.int32)
     got = through_the_cache(cfg, params, toks, lengths, 3, chunk=128,
-                            cache_len=1024, padded=640)
+                            cache_len=1024, padded=640, fresh=True)
     # a call a row a layer in every trace of the chunk
     assert len(calls) >= 4 and set(calls) == {(128, 4, 128)}
     # and a call of the picking kernel before each: (C, J, dI)
@@ -576,8 +497,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tokens):
     whole = kv.KeyeVL2Config.tiny(dtype=F32, param_dtype=F32, n_layer=1)
     params = moved(kv.keye_vl2_init(jax.random.PRNGKey(4), whole))
     toks = tokens[:2, :24]
-    want = reference.forward(to_ref(params, whole), toks,
-                             **ref_kwargs(whole))
+    want = ROW.reference_forward(params, whole)(toks)
     layer = params["layers"][0]
 
     def hidden(cfg, p):  # the stream after the layer, before the last norm
@@ -658,70 +578,8 @@ def test_the_training_functions_refuse():
 
 
 def test_the_reference_has_a_loss_the_interface_asks_for(params, tokens):
-    loss, gnorm = reference.loss_and_grad_norm(
-        to_ref(params), tokens[:1, :8], **ref_kwargs())
+    kwargs = ref_kwargs()
+    loss, gnorm = jax.jit(lambda ref, t: reference.loss_and_grad_norm(
+        ref, t, **kwargs))(to_ref(params), tokens[:1, :8])
     assert np.isfinite(float(loss)) and float(gnorm) > 0
 
-
-# -- the engine ------------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_tokens(runtime):
-    """``LLMEngine(model="keye_vl2")`` on the normal path: prompts of
-    assorted lengths on both sides of ``topk``, each generation held to the
-    reference's greedy continuation; one compile a program; the step's and
-    the chunk program's counters in ``llm_stats()``."""
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    engine = LLMEngine(model="keye_vl2", config=CFG, seed=0, max_batch=3,
-                       cache_len=64, max_prompt_len=40, prefill_chunk=8,
-                       max_new_cap=10)
-    try:
-        ref, kw = to_ref(engine.params), ref_kwargs()
-        forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-        rng = np.random.default_rng(3)
-        for n in (3, 8, 15, 17, 33, 40):
-            prompt = rng.integers(1, 200, n).tolist()
-            got = engine.generate(prompt, 8)
-            toks = list(prompt)
-            for _ in range(8):  # causal: one padded shape serves every length
-                padded = jnp.asarray([toks + [0] * (48 - len(toks))])
-                toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-            assert got == toks[n:], n
-        stats = engine.llm_stats()
-        assert stats["compiles"] == {"decode": 1, "prefill": 1}
-        assert 0 < stats["sparse_keys_selected"] \
-            <= stats["sparse_keys_eligible"]
-        assert "prefill_sparse_keys_selected" not in stats
-        assert stats["sparse_topk"] == TOPK and stats["sparse_layers"] == 3
-        assert stats["sparse_chunk_select"] == "xla"  # toy widths
-    finally:
-        engine.shutdown_engine()
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text(runtime):
-    from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-
-    engine = LLMEngine(model="keye_vl2", preset="tiny", max_batch=2,
-                       cache_len=48, max_prompt_len=24, prefill_chunk=8)
-    try:
-        assert len(engine.generate([5, 9, 2, 17, 3], 20)) == 20
-    finally:
-        engine.shutdown_engine()
-    with pytest.raises(ValueError, match="keye_vl2"):
-        _model_bundle("nope", None, "tiny")

@@ -1,84 +1,40 @@
 """``models/nemotron_h.py`` against its plain reference
 (``benchmark/reference/nemotron_h.py``), at ``tiny`` size in float32 on
-seeded random weights: the whole forward pass, prefill then decoding through
-the cache (K/V rows and both Mamba states), the state's handling (a padded
-lane, a re-used slot, a free slot), and the engine serving it through
-``serve.run`` / ``handle.stream`` with its counters."""
+seeded random weights: prefill then decoding through the cache (K/V rows and
+both Mamba states) and the state's handling (a padded lane, a re-used slot,
+a free slot). The contracts every served family holds (sizes, types, the
+forward pass, the engine against the reference) are
+``tests/test_served_family_contract.py``'s."""
 
 import dataclasses
-import os
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_module
-from ray_tpu.models import gpt2
 from ray_tpu.models import nemotron_h as nh
+from served_families import (FAMILIES, benchmark_file, contract_params,
+                             contract_tokens, contract_want)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "nemotron_h.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "nemotron_h.py"))
-CFG = nh.NemotronHConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
-
-
-def ref_kwargs(cfg):
-    return dict(pattern=cfg.pattern, eps=cfg.eps, n_head=cfg.n_head,
-                n_kv_head=cfg.n_kv_head, head_dim=cfg.head_dim,
-                mamba_heads=cfg.mamba_heads,
-                mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
-                ssm_state=cfg.ssm_state, top_k=cfg.top_k,
-                routed_scale=cfg.routed_scale,
-                first_expert=cfg.experts_held[0])
-
-
-def to_ref(params, cfg):
-    return {"embeddings": params["embed"], "lm_head": params["lm_head"],
-            "norm_f": params["norm_f"],
-            "layers": [{ref: p[name] for name, ref
-                        in family.LAYER_NAMES[kind].items()}
-                       for kind, p in zip(cfg.pattern, params["layers"])]}
-
-
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the norm scales start at
-    one, and a dropped or swapped scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
+ROW = FAMILIES["nemotron_h"]
+reference, CFG = ROW.reference, ROW.cfg
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 
 
 @pytest.fixture(scope="module")
 def params():
-    return moved(nh.nemotron_h_init(jax.random.PRNGKey(0), CFG))
+    return contract_params("nemotron_h")
 
 
 @pytest.fixture(scope="module")
 def tokens():
-    return jnp.asarray(np.random.default_rng(1).integers(
-        0, CFG.vocab_size, (3, 40), dtype=np.int32))
+    return contract_tokens("nemotron_h")
 
 
 @pytest.fixture(scope="module")
-def want(params, tokens):
-    return reference.forward(to_ref(params, CFG), tokens, **ref_kwargs(CFG))
-
-
-def test_the_tiny_preset_holds_all_three_kinds_of_layer():
-    assert set(CFG.pattern) == {"M", "*", "E"}
-    assert CFG.experts_held[1] < CFG.n_experts  # a share, not the whole
-    assert nh.NemotronHConfig().pattern == nh.PUBLISHED_PATTERN
-    assert len(nh.PUBLISHED_PATTERN) == 88
-    assert [nh.PUBLISHED_PATTERN.count(k) for k in "ME*"] == [40, 40, 8]
-    with pytest.raises(ValueError, match="pattern"):
-        nh.NemotronHConfig.tiny(pattern="MXE")
-    with pytest.raises(ValueError, match="experts_held"):
-        nh.NemotronHConfig.tiny(experts_held=(6, 4))
+def want():
+    return contract_want("nemotron_h")
 
 
 def test_weights_are_stored_in_bfloat16_by_default():
@@ -91,56 +47,6 @@ def test_weights_are_stored_in_bfloat16_by_default():
     assert cache["k"].shape == (1, 3, 16, 2, 16)
     assert cache["conv"].shape == (2, 3, 3, cfg.conv_dim)
     assert [s.shape for s in cache["ssm"]] == [(3, 8, 16, 16)] * 2
-
-
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_precision_the_file_states(program):
-    """``correct`` cannot see the experts in float8 or the state in
-    bfloat16 through the logits (PERF.md section 7: both lie under the
-    rounding of bfloat16 activations), so the stated precision is held
-    here, by the programs' own types: from the benchmark's configuration
-    file, weights and products in bfloat16 and nothing narrower anywhere,
-    and a float32 state in and out (the router's float32 scores are
-    ``tests/test_moe_dropless.py``'s)."""
-    from benchmark.loading import load_json
-
-    config = load_json(os.path.join(
-        REPO, "benchmark", "configs", "nemotron3-super-120b-a12b.json"))
-    stated = family.system_config(config)
-    assert config["assumed"]["ssm_state_dtype"] == "float32"
-    assert (stated.param_dtype, stated.dtype, stated.ssm_state_dtype) \
-        == (jnp.bfloat16, jnp.bfloat16, jnp.float32)
-    cfg = nh.NemotronHConfig.tiny()  # the same defaults, at a CPU's size
-    assert (cfg.param_dtype, cfg.dtype, cfg.ssm_state_dtype) \
-        == (stated.param_dtype, stated.dtype, stated.ssm_state_dtype)
-    params = jax.eval_shape(
-        lambda: nh.nemotron_h_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: nh.nemotron_h_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    if program == "decode":
-        fn = lambda p, c, t, n: nh.nemotron_h_decode_step(p, c, t, n, cfg)
-        args = (params, cache, i32(3), i32(3))
-    else:
-        fn = lambda p, c, t, s, n: nh.nemotron_h_prefill(p, c, t, s, n, cfg)
-        args = (params, cache, i32(2, 16), i32(2), i32(2))
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    new_cache = jax.eval_shape(fn, *args)[1]
-    assert [s.dtype for s in new_cache["ssm"]] == [jnp.float32] * 2
-
-
-def test_forward_agrees_with_the_reference(params, tokens, want):
-    got = nh.nemotron_h_forward(params, tokens, CFG)
-    assert got.shape == want.shape == (3, 40, CFG.vocab_size)
-    assert float(jnp.abs(got - want).max()) < 1e-4
-    # a prompt longer than one chunk, and not a multiple of it
-    assert tokens.shape[1] > 2 * CFG.chunk_size
-    assert tokens.shape[1] % CFG.chunk_size == 0
-    odd = nh.nemotron_h_forward(params, tokens[:, :37], CFG)
-    assert float(jnp.abs(odd - want[:, :37]).max()) < 1e-4
 
 
 def test_the_reference_without_a_mechanism_is_another_model(params, tokens,
@@ -161,6 +67,13 @@ def test_the_reference_without_a_mechanism_is_another_model(params, tokens,
         assert float(jnp.abs(other - want).max()) > 1e-2
 
 
+# One compiled program a shape for every test of this file (the
+# configuration is a static argument): op by op a prefill or a step of this
+# family costs seconds.
+_prefill = jax.jit(nh.nemotron_h_prefill, static_argnums=5)
+_step = jax.jit(nh.nemotron_h_decode_step, static_argnums=4)
+
+
 def serve_rows(params, tokens, lengths, steps, cache=None, slots=None,
                cfg=CFG, lane=32):
     """Prefill rows of ``lengths`` in a ``lane``-wide padded lane, then
@@ -175,14 +88,14 @@ def serve_rows(params, tokens, lengths, steps, cache=None, slots=None,
     if cache is None:
         cache = nh.nemotron_h_init_cache(cfg, n_slots, max(64, lane))
     slots = jnp.arange(r) if slots is None else jnp.asarray(slots)
-    logits, cache = nh.nemotron_h_prefill(
+    logits, cache = _prefill(
         params, cache, jnp.asarray(prompts), slots, lengths, cfg)
     out = [logits]
     for s in range(steps):
         pos = jnp.zeros((n_slots,), jnp.int32).at[slots].set(lengths + s)
         toks = jnp.zeros((n_slots,), jnp.int32).at[slots].set(
             tokens[jnp.arange(r), lengths + s])
-        logits, cache, _ = nh.nemotron_h_decode_step(
+        logits, cache, _ = _step(
             params, cache, toks, pos, cfg)
         out.append(logits[slots])
     return jnp.stack(out, axis=1), cache
@@ -196,8 +109,7 @@ def test_the_routed_experts_keep_bfloat16s_precision(rows):
     same bfloat16-valued weights and the same routing (4e-3 and 5e-3
     measured); with the experts' matrices rounded to float8, the control of
     the configuration file's ``reason``, it lies 4e-2 off."""
-    tool = load_module(os.path.join(REPO, "benchmark", "tools",
-                                    "serve_check_many.py"))
+    tool = benchmark_file("tools", "serve_check_many.py")
     cfg = nh.NemotronHConfig.tiny()
     p = nh._layer_init(jax.random.PRNGKey(4), "E", cfg)
     # the routed part alone, at a size that counts beside rounding
@@ -234,7 +146,7 @@ def test_a_thousand_steps_keep_the_state_a_prefill_computes(params):
         def step(cache, at):
             tok, t = at
             zero = jnp.zeros((), jnp.int32)
-            _, cache, _ = nh.nemotron_h_decode_step(
+            _, cache, _ = _step(
                 params, cache, jnp.stack([tok, zero]), jnp.stack([t, zero]),
                 cfg)
             return cache, ()
@@ -242,7 +154,7 @@ def test_a_thousand_steps_keep_the_state_a_prefill_computes(params):
         stepped, _ = jax.jit(lambda cache: jax.lax.scan(
             step, cache, (tokens[0], jnp.arange(n, dtype=jnp.int32))))(
                 nh.nemotron_h_init_cache(cfg, 2, n))
-        _, filled = nh.nemotron_h_prefill(
+        _, filled = _prefill(
             params, nh.nemotron_h_init_cache(cfg, 2, n), tokens,
             jnp.asarray([0]), jnp.asarray([n]), cfg)
         wide = lambda s: np.asarray(s[0], np.float64)
@@ -295,7 +207,7 @@ def test_prefill_state_is_the_state_of_single_steps(params, tokens):
     for t in range(max(lengths)):
         pos = jnp.full((4,), t, jnp.int32)
         toks = jnp.concatenate([tokens[:, t], jnp.zeros((1,), jnp.int32)])
-        _, cache, _ = nh.nemotron_h_decode_step(params, cache, toks, pos, CFG)
+        _, cache, _ = _step(params, cache, toks, pos, CFG)
         for i, n in enumerate(lengths):
             if n == t + 1:
                 reached[i] = {"ssm": jnp.stack(cache["ssm"])[:, i],
@@ -318,7 +230,7 @@ def test_inactive_prefill_rows_write_to_the_scratch_slot(params, tokens):
     before = jax.tree.map(np.asarray, stacked(cache))
     prompts = np.zeros((2, 32), np.int32)
     prompts[0, :12] = np.asarray(tokens)[1, :12]
-    _, after = nh.nemotron_h_prefill(
+    _, after = _prefill(
         params, cache, jnp.asarray(prompts), jnp.asarray([1, 3]),
         jnp.asarray([12, 1]), CFG)
     after = stacked(after)
@@ -349,13 +261,13 @@ def test_the_kv_part_keeps_the_ring_contract(params, tokens):
     that is a window of ``cache_len`` rows."""
     cache = nh.nemotron_h_init_cache(CFG, 1, 8)
     for t in range(12):
-        _, cache, _ = nh.nemotron_h_decode_step(
+        _, cache, _ = _step(
             params, cache, tokens[0, t][None], jnp.asarray([t]), CFG)
     assert bool(jnp.isfinite(cache["k"]).all())
     # rows 8..11 overwrote rows 0..3
     fresh = nh.nemotron_h_init_cache(CFG, 1, 16)
     for t in range(12):
-        _, fresh, _ = nh.nemotron_h_decode_step(
+        _, fresh, _ = _step(
             params, fresh, tokens[0, t][None], jnp.asarray([t]), CFG)
     np.testing.assert_allclose(np.asarray(cache["k"][0, 0, :4]),
                                np.asarray(fresh["k"][0, 0, 8:12]),
@@ -363,60 +275,6 @@ def test_the_kv_part_keeps_the_ring_contract(params, tokens):
 
 
 # -- the engine ---------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield serve
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_continuation(runtime):
-    """``LLMEngine(model="nemotron_h")`` through ``serve.run`` /
-    ``handle.stream`` in float32: token for token the reference's greedy
-    choice, two compiled programs, and the step's counters in
-    ``llm_stats()`` without a second sync."""
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    import ray_tpu
-
-    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
-    handle = runtime.run(dep.bind(
-        model="nemotron_h", config=CFG, seed=3, max_batch=3, cache_len=32,
-        max_prompt_len=16, prefill_rows=2))
-    params = nh.nemotron_h_init(jax.random.PRNGKey(3), CFG)
-    ref, kw = to_ref(params, CFG), ref_kwargs(CFG)
-    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
-    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-    for prompt in prompts:
-        toks = list(prompt)
-        for _ in range(6):  # causal: one padded shape serves every length
-            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
-            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
-        assert served == toks[len(prompt):]
-    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
-    assert stats["compiles"] == {"decode": 1, "prefill": 1}
-    assert stats["model"] == "nemotron_h"
-    assert stats["expert_layers"] == 2 and stats["experts_held"] == 4
-    # every step runs max_batch + 1 rows through 2 expert layers, top 3:
-    # at most 4 * 3 pairs a layer land here, at most 4 experts are hit
-    steps = stats["steps"]
-    assert steps >= 10
-    assert 0 < stats["experts_hit"] <= steps * 2 * 4
-    assert stats["experts_hit"] <= stats["expert_rows"] <= steps * 2 * 12
-    # a tile is up to 128 pairs of one expert, so a hit expert is one tile
-    assert stats["expert_row_tiles"] == stats["experts_hit"]
-    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
 
 
 def test_a_dense_familys_engine_and_decode_program_are_as_before():

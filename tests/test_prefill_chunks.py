@@ -10,79 +10,21 @@ worker's, and this one was the whole run's longest).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import (deepseek_v2, falcon_h1, gpt2, granite_hybrid,
-                            keye_vl2, llama, nemotron_h, qwen3_next,
-                            smallthinker)
 from ray_tpu.models.prefill import key_window, whole_prompts
+from served_families import FAMILIES
 
-F32 = jnp.float32
-# (float32 tiny config, init, init_cache, prefill_chunk, whole-window
-# prefill, full-context forward); the hybrid's scan blocks by 4 so that a
-# chunk of 4 and a window of 16 block alike.
-def _gpt2(**shape):
-    return (dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=F32, **shape),
-            gpt2.gpt2_init, gpt2.gpt2_init_cache, gpt2.gpt2_prefill_chunk,
-            gpt2.gpt2_prefill, gpt2.gpt2_forward)
-
-
-FAMILIES = {
-    "gpt2": _gpt2(),
-    "llama": (dataclasses.replace(llama.LlamaConfig.tiny(), dtype=F32),
-              llama.llama_init, llama.llama_init_cache,
-              llama.llama_prefill_chunk, llama.llama_prefill,
-              llama.llama_forward),
-    "nemotron_h": (nemotron_h.NemotronHConfig.tiny(
-        dtype=F32, param_dtype=F32, chunk_size=4),
-        nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_init_cache,
-        nemotron_h.nemotron_h_prefill_chunk, nemotron_h.nemotron_h_prefill,
-        nemotron_h.nemotron_h_forward),
-    "granite_hybrid": (granite_hybrid.GraniteHybridConfig.tiny(
-        dtype=F32, param_dtype=F32, chunk_size=4),
-        granite_hybrid.granite_hybrid_init,
-        granite_hybrid.granite_hybrid_init_cache,
-        granite_hybrid.granite_hybrid_prefill_chunk,
-        granite_hybrid.granite_hybrid_prefill,
-        granite_hybrid.granite_hybrid_forward),
-    "deepseek_v2": (deepseek_v2.DeepseekV2Config.tiny(
-        dtype=F32, param_dtype=F32),
-        deepseek_v2.deepseek_v2_init, deepseek_v2.deepseek_v2_init_cache,
-        deepseek_v2.deepseek_v2_prefill_chunk,
-        deepseek_v2.deepseek_v2_prefill, deepseek_v2.deepseek_v2_forward),
-    "falcon_h1": (falcon_h1.FalconH1Config.tiny(
-        dtype=F32, param_dtype=F32, chunk_size=4),
-        falcon_h1.falcon_h1_init, falcon_h1.falcon_h1_init_cache,
-        falcon_h1.falcon_h1_prefill_chunk, falcon_h1.falcon_h1_prefill,
-        falcon_h1.falcon_h1_forward),
-    # (one period, L L L F: this file compares states at 1e-5, and two
-    # periods of float32 sums in another order pass that by a third)
-    "qwen3_next": (qwen3_next.Qwen3NextConfig.tiny(
-        dtype=F32, param_dtype=F32, scan_block=4, n_layer=4),
-        qwen3_next.qwen3_next_init, qwen3_next.qwen3_next_init_cache,
-        qwen3_next.qwen3_next_prefill_chunk, qwen3_next.qwen3_next_prefill,
-        qwen3_next.qwen3_next_forward),
-    # (window rings of 16 rows: this file's whole-window pass is ONE chunk
-    # of MAX_PROMPT rows, which must divide a ring; the wraps are
-    # tests/test_smallthinker.py's)
-    "smallthinker": (smallthinker.SmallThinkerConfig.tiny(
-        dtype=F32, param_dtype=F32, window=16),
-        smallthinker.smallthinker_init, smallthinker.smallthinker_init_cache,
-        smallthinker.smallthinker_prefill_chunk,
-        smallthinker.smallthinker_prefill, smallthinker.smallthinker_forward),
-    # (a query reads the 8 keys its indexer picks: prompts of up to
-    # MAX_PROMPT tokens select from their ninth token on, across chunks)
-    "keye_vl2": (keye_vl2.KeyeVL2Config.tiny(
-        dtype=F32, param_dtype=F32, index_topk=8),
-        keye_vl2.keye_vl2_init, keye_vl2.keye_vl2_init_cache,
-        keye_vl2.keye_vl2_prefill_chunk, keye_vl2.keye_vl2_prefill,
-        keye_vl2.keye_vl2_forward),
-}
-every_family = pytest.mark.parametrize("family", list(FAMILIES))
+# Every family but the self-drafting one (its chunk carries the module's
+# pass: ``tests/test_exaone_moe.py``), at ``Family.chunked``: the float32
+# tiny configuration with what ``Family.in_chunks`` says these files need.
+CHUNKED = [name for name in FAMILIES if name != "exaone_moe"]
+every_family = pytest.mark.parametrize("family", CHUNKED)
 # GPT-2's merged, lane-padded rows at the head counts it is served with: XL's
 # 25 heads of 64 (1600 columns padded to 1664, a lane tile shared by two
 # heads and the last one half empty) and 124M's 12 of 64 (768, no pad); the
@@ -90,16 +32,25 @@ every_family = pytest.mark.parametrize("family", list(FAMILIES))
 # columns padded to 256) do not divide a tile: both run the chunk's products
 # over the whole row (``_lane_groups``' other arm).
 # Model functions only, no engine.
-FAMILIES.update({"gpt2-25x64": _gpt2(n_head=25, d_model=1600),
-                 "gpt2-12x64": _gpt2(n_head=12, d_model=768),
-                 "gpt2-3x48": _gpt2(n_head=3, d_model=144)})
-every_row_width = pytest.mark.parametrize("family", list(FAMILIES))
+ROW_WIDTHS = {"gpt2-25x64": dict(n_head=25, d_model=1600),
+              "gpt2-12x64": dict(n_head=12, d_model=768),
+              "gpt2-3x48": dict(n_head=3, d_model=144)}
+every_row_width = pytest.mark.parametrize("family", CHUNKED + list(ROW_WIDTHS))
 CHUNK, MAX_PROMPT, CACHE_LEN, SLOTS = 4, 16, 24, 4
 
 
+def _row(family):
+    return FAMILIES[family.split("-")[0]]
+
+
+def _cfg(family):
+    return dataclasses.replace(_row(family).chunked,
+                               **ROW_WIDTHS.get(family, {}))
+
+
+@functools.lru_cache(maxsize=None)
 def _params(family):
-    cfg, init = FAMILIES[family][:2]
-    return init(jax.random.PRNGKey(31), cfg)
+    return _row(family).init(jax.random.PRNGKey(31), _cfg(family))
 
 
 def _prompt(n, seed=0):
@@ -108,25 +59,32 @@ def _prompt(n, seed=0):
 
 def _used_cache(family, seed):
     """A cache every part of which holds another request's leavings."""
-    cfg, _, init_cache = FAMILIES[family][:3]
     rng = np.random.default_rng(seed)
     return jax.tree.map(
         lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
-        init_cache(cfg, SLOTS, CACHE_LEN))
+        _row(family).init_cache(_cfg(family), SLOTS, CACHE_LEN))
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_program(family, chunk):
+    """The chunk function at R = 1 and C = ``chunk`` as ONE compiled program
+    a (family, chunk) a process, whatever the parameters, slot and test."""
+    cfg, chunk_fn = _cfg(family), _row(family).prefill_chunk
+    return jax.jit(lambda params, cache, toks, slot, at, n: chunk_fn(
+        params, cache, toks, slot, at, n, cfg,
+        window=key_window(MAX_PROMPT, chunk)))
 
 
 def _in_chunks(family, params, cache, prompt, slot, chunk=CHUNK):
     """As the engine runs one request: ``ceil(len / chunk)`` calls of the
     chunk function with R = 1. -> (the last call's logits [V], the cache)."""
-    cfg, chunk_fn = FAMILIES[family][0], FAMILIES[family][3]
-    run = jax.jit(lambda c, t, at, n: chunk_fn(
-        params, c, t, jnp.full(1, slot, jnp.int32), at, n, cfg,
-        window=key_window(MAX_PROMPT, chunk)))
+    run = _chunk_program(family, chunk)
     for at in range(0, len(prompt), chunk):
         piece = prompt[at:at + chunk]
         toks = np.zeros((1, chunk), np.int32)
         toks[0, :len(piece)] = piece
-        logits, cache = run(cache, jnp.asarray(toks),
+        logits, cache = run(params, cache, jnp.asarray(toks),
+                            jnp.full(1, slot, jnp.int32),
                             jnp.full(1, at, jnp.int32),
                             jnp.full(1, len(piece), jnp.int32))
     return logits[0], cache
@@ -135,14 +93,7 @@ def _in_chunks(family, params, cache, prompt, slot, chunk=CHUNK):
 def _whole(family, params, cache, prompt, slot):
     """One pass over the whole padded window: the chunk function at
     C = MAX_PROMPT, which is the lane the engine compiled before."""
-    cfg, chunk_fn = FAMILIES[family][0], FAMILIES[family][3]
-    toks = np.zeros((1, MAX_PROMPT), np.int32)
-    toks[0, :len(prompt)] = prompt
-    logits, cache = chunk_fn(
-        params, cache, jnp.asarray(toks), jnp.full(1, slot, jnp.int32),
-        jnp.zeros(1, jnp.int32), jnp.full(1, len(prompt), jnp.int32), cfg,
-        window=MAX_PROMPT)
-    return logits[0], cache
+    return _in_chunks(family, params, cache, prompt, slot, chunk=MAX_PROMPT)
 
 
 def _holds_rows(path, a):
@@ -198,12 +149,13 @@ def test_where_a_prompt_is_cut_changes_nothing(family, n):
 def test_a_first_chunk_begins_anew_whatever_the_slot_held(family):
     """``start == 0``: the K/V rows a used slot holds are not seen, its
     convolution tail and SSM state are not continued."""
-    cfg, _, init_cache = FAMILIES[family][:3]
     params, prompt = _params(family), _prompt(2 * CHUNK + 1, seed=3)
     used, _ = _in_chunks(family, params, _used_cache(family, seed=8),
                          prompt, slot=1)
-    fresh, _ = _in_chunks(family, params,
-                          init_cache(cfg, SLOTS, CACHE_LEN), prompt, slot=1)
+    fresh, _ = _in_chunks(
+        family, params,
+        _row(family).init_cache(_cfg(family), SLOTS, CACHE_LEN), prompt,
+        slot=1)
     np.testing.assert_array_equal(np.asarray(used), np.asarray(fresh))
 
 
@@ -213,7 +165,8 @@ def test_the_whole_window_form_is_a_loop_over_the_chunk_function(family):
     calls) cut into chunks gives what it gives in one chunk, rows of
     different lengths and a scratch row together, with ONE traced copy of
     the layers."""
-    cfg, _, _, chunk_fn, whole, _ = FAMILIES[family]
+    cfg, row = _cfg(family), _row(family)
+    chunk_fn, whole = row.prefill_chunk, row.prefill
     params = _params(family)
     lens = [MAX_PROMPT - 3, CHUNK, 1]
     toks = np.zeros((3, MAX_PROMPT), np.int32)
@@ -222,6 +175,8 @@ def test_the_whole_window_form_is_a_loop_over_the_chunk_function(family):
     args = (jnp.asarray(toks), jnp.asarray([3, 0, 2], jnp.int32),
             jnp.asarray(lens, jnp.int32))
     cache = _used_cache(family, seed=2)
+    # (op by op on purpose: under one jit each, Qwen3-Next's float32 sums
+    # take another order and pass 1e-5 by a third)
     want, want_cache = whole(params, cache, *args, cfg)
     got, got_cache = whole_prompts(chunk_fn, params, cache, *args, cfg,
                                    chunk=CHUNK)

@@ -6,38 +6,24 @@ counts its chunks; the chunk length is the engine's by rule and must fit
 the cache; what a cache counts adds up in ``llm_stats()``.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import granite_hybrid
 from ray_tpu.models.prefill import chunk_len, key_window, token_parameters
 from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-from test_prefill_chunks import (CACHE_LEN, CHUNK, FAMILIES, MAX_PROMPT,
-                                 SLOTS, _prompt, every_family)
+from served_families import FAMILIES, generated_alone
+from test_prefill_chunks import (CACHE_LEN, CHUNK, MAX_PROMPT, SLOTS,
+                                 _prompt, every_family)
 
 
 def _engine(family, **kw):
     kw.setdefault("max_batch", 2)
-    return LLMEngine(model=family, config=FAMILIES[family][0], seed=31,
+    return LLMEngine(model=family, config=FAMILIES[family].chunked, seed=31,
                      cache_len=CACHE_LEN, max_prompt_len=MAX_PROMPT,
                      prefill_chunk=CHUNK, **kw)
-
-
-def _naive(family, params, prompt, n):
-    cfg, forward = FAMILIES[family][0], FAMILIES[family][5]
-    fwd = jax.jit(lambda t: forward(params, t, cfg))
-    toks = [int(t) for t in prompt]
-    for _ in range(n):
-        padded = np.zeros((1, CACHE_LEN), np.int32)
-        padded[0, :len(toks)] = toks
-        toks.append(int(jnp.argmax(fwd(jnp.asarray(padded))[0,
-                                                            len(toks) - 1])))
-    return toks[len(prompt):]
 
 
 @every_family
@@ -50,8 +36,9 @@ def test_the_engine_serves_the_full_forward_across_chunk_boundaries(
     eng = _engine(family)
     try:
         prompt = _prompt(n, seed=20 + n).tolist()
-        assert eng.generate(prompt, 5) == _naive(family, eng.params,
-                                                 prompt, 5)
+        assert eng.generate(prompt, 5) == generated_alone(
+            family, eng.params, prompt, 5, cfg=FAMILIES[family].chunked,
+            width=CACHE_LEN)
         assert eng.llm_stats()["prefill_chunks"] == -(-n // CHUNK)
     finally:
         eng.shutdown_engine()
@@ -76,34 +63,6 @@ def test_one_two_and_three_chunks_run_one_program_and_add_up(family):
     assert st["prefill_chunks"] == 1 + 2 + 3 + MAX_PROMPT // CHUNK
     assert st["prefill_tokens_lane"] == st["prefill_chunks"] * CHUNK
     assert st["prefill_tokens_real"] == sum(lens[:3]) + MAX_PROMPT
-
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# model -> (its configuration as a cell runs it, that cell's deployment)
-PUBLISHED = {
-    "gpt2": ("gpt2-xl-1.5b", "gpt2xl_1chip_b8"),
-    "falcon_h1": ("falcon-h1-34b-instruct", "falconh1_1chip_b32"),
-    "nemotron_h": ("nemotron3-super-120b-a12b", "nemotron3s_1chip_b64"),
-    "granite_hybrid": ("granite-4.0-h-small", "granite4hs_1chip_b32"),
-    "deepseek_v2": ("deepseek-v2", "dsv2_1chip_b64"),
-    "qwen3_next": ("qwen3-next-80b-a3b-instruct", "qwen3next_1chip_b64"),
-    "smallthinker": ("smallthinker-21b-a3b-instruct",
-                     "smallthinker_1chip_b48"),
-    "exaone_moe": ("k-exaone-236b-a23b", "kexaone_1chip_b64"),
-    "keye_vl2": ("keye-vl-2.0-30b-a3b", "keyevl2_1chip_b16"),
-}
-
-
-def _published(model):
-    """(the program's configuration at the published widths, ``top_k`` and
-    held counts, the deployment's engine settings) of ``model``'s cell."""
-    config, deployment = (os.path.join(REPO, "benchmark", kind, name + ".json")
-                          for kind, name in zip(("configs", "deployments"),
-                                                PUBLISHED[model]))
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      model + ".py"))
-    return (family.system_config(load_json(config)),
-            load_json(deployment)["engine"])
 
 
 def _case(model, chunk, sizes="published", **engine):
@@ -161,10 +120,10 @@ def test_the_chunk_is_the_engines_by_rule_and_must_fit_the_cache(
     assert key_window(768, 256) == key_window(700, 256) == 768
     cfg = None
     if sizes == "published":
-        cfg, deployment = _published(model)
+        cfg, deployment = FAMILIES[model].cell()
         engine = {**deployment, **engine}
     elif sizes == "tiny":
-        cfg = FAMILIES[model][0]
+        cfg = FAMILIES[model].chunked
     cfg, init = _model_bundle(model, cfg, "full")[:2]
     longest, rows = engine["max_prompt_len"], engine["cache_len"]
     read = token_parameters(cfg, jax.eval_shape(
@@ -194,7 +153,7 @@ def test_what_a_cache_counts_adds_up_in_llm_stats():
     an admission turn and says in ``llm_stats()`` by how much each rose,
     across a wrap of the 32 bits too; a family whose cache carries none
     reports no such key."""
-    cfg = FAMILIES["granite_hybrid"][0]
+    cfg = FAMILIES["granite_hybrid"].chunked
     eng = _engine("granite_hybrid")
     try:
         lens = [CHUNK - 1, 2 * CHUNK + 1, MAX_PROMPT]

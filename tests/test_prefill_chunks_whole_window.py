@@ -11,10 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_prefill_chunks import (CHUNK, FAMILIES, MAX_PROMPT,
-                                 _assert_same_state, _in_chunks, _params,
-                                 _prompt, _used_cache, _whole,
-                                 every_row_width)
+from served_families import forward_fn
+from test_prefill_chunks import (CHUNK, MAX_PROMPT, _assert_same_state, _cfg,
+                                 _in_chunks, _params, _prompt, _used_cache,
+                                 _whole, every_row_width)
 
 
 @every_row_width
@@ -28,8 +28,11 @@ def test_chunks_leave_what_the_whole_window_leaves(family, n):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     _assert_same_state(family, got_cache, want_cache, 2, n)
-    # and the whole-window logits are the full-context forward's
-    cfg, forward = FAMILIES[family][0], FAMILIES[family][5]
-    full = forward(params, jnp.asarray(prompt)[None], cfg)[0, -1]
+    # and the whole-window logits are the full-context forward's (causal:
+    # one padded shape, compiled once a family, serves every length)
+    padded = np.zeros((1, MAX_PROMPT), np.int32)
+    padded[0, :n] = prompt
+    full = forward_fn(family.split("-")[0], _cfg(family))(
+        params, jnp.asarray(padded))[0, n - 1]
     np.testing.assert_allclose(np.asarray(got), np.asarray(full),
                                rtol=2e-4, atol=2e-4)

@@ -1,176 +1,96 @@
 """Qwen3-Next (``models/qwen3_next.py``) against its plain reference
-(``benchmark/reference/qwen3_next.py``) at toy widths on the CPU: the
-forward pass, prefill in toy chunks then decode steps through the three
-kinds of slot state, a wrapped ring of rotated keys, the ten controls that
-must fail the limit the benchmark's configuration states, the four expert
-shares against the uncut layer and the four vocabulary slices against the
-uncut head, the router against its two-step form, the types the programs
-compute in, the scopes the readers read, and the engine on the normal path
-with its counters. Every family's two programs, this one's among them, are
-held bit for bit by ``tests/test_deepseek_v2.py``'s one table.
+(``benchmark/reference/qwen3_next.py``) at toy widths on the CPU: prefill in
+toy chunks then decode steps through the three kinds of slot state, a wrapped
+ring of rotated keys, the ten controls that must fail the limit the
+benchmark's configuration states, the four expert shares against the uncut
+layer and the four vocabulary slices against the uncut head, the router
+against its two-step form. The contracts every served family holds (sizes,
+types, scopes, the forward pass, the engine against the reference) are
+``tests/test_served_family_contract.py``'s. Every family's two programs, this
+one's among them, are held bit for bit by ``tests/test_deepseek_v2.py``'s one
+table.
 """
 
 import dataclasses
 import functools
-import os
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import qwen3_next as qn
 from ray_tpu.models.prefill import whole_prompts
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.moe import route_topk_softmax
+from served_families import (FAMILIES, benchmark_file, contract_params,
+                             contract_tokens, contract_want, moved, rel_l2)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = load_module(os.path.join(REPO, "benchmark", "reference",
-                                     "qwen3_next.py"))
-family = load_module(os.path.join(REPO, "benchmark", "families",
-                                  "qwen3_next.py"))
-check_tool = load_module(os.path.join(REPO, "benchmark", "tools",
-                                      "serve_check_many.py"))
-CONFIG = load_json(os.path.join(REPO, "benchmark", "configs",
-                                "qwen3-next-80b-a3b-instruct.json"))
+ROW = FAMILIES["qwen3_next"]
+reference, family, CFG = ROW.reference, ROW.family, ROW.cfg
+check_tool = benchmark_file("tools", "serve_check_many.py")
+CONFIG = ROW.CONFIG
+to_ref, ref_kwargs = ROW.to_reference, ROW.reference_kwargs
 F32 = jnp.float32
-CFG = qn.Qwen3NextConfig.tiny(dtype=F32, param_dtype=F32)
 
 
-def toy_file(cfg):
-    """The keys of a configuration file that ``families/qwen3_next.py``
-    reads, for ``cfg``'s sizes."""
-    return {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layer,
-            "full_attention_interval": cfg.full_attention_interval,
-            "linear_num_key_heads": cfg.linear_key_heads,
-            "linear_num_value_heads": cfg.linear_value_heads,
-            "linear_key_head_dim": cfg.linear_key_dim,
-            "linear_value_head_dim": cfg.linear_value_dim,
-            "linear_conv_kernel_dim": cfg.conv_kernel,
-            "num_attention_heads": cfg.n_head,
-            "num_key_value_heads": cfg.n_kv_head, "head_dim": cfg.head_dim,
-            "partial_rotary_factor": cfg.partial_rotary_factor,
-            "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.eps,
-            "num_experts": cfg.experts_held[1],
-            "num_experts_published": cfg.n_experts,
-            "num_experts_per_tok": cfg.top_k,
-            "moe_intermediate_size": cfg.expert_ff,
-            "shared_expert_intermediate_size": cfg.shared_ff,
-            "vocab_size": cfg.vocab_size, "max_position_embeddings": 64,
-            "assumed": {"experts_held": list(cfg.experts_held),
-                        "scan_block": cfg.scan_block}}
+@pytest.fixture(scope="module")
+def params():
+    return contract_params("qwen3_next")
 
 
-def to_ref(params, cfg=CFG):
-    return family.to_reference(params, toy_file(cfg))
+@pytest.fixture(scope="module")
+def tokens():
+    return contract_tokens("qwen3_next")
 
 
-def ref_kwargs(cfg=CFG):
-    return family.reference_kwargs(toy_file(cfg))
+@pytest.fixture(scope="module")
+def want():
+    return contract_want("qwen3_next")
 
 
-def moved(params, seed=6):
-    """Every weight moved off its initial value: the zero-centred norms
-    start at 0 and the delta rule's own at 1, and a dropped or swapped
-    scale would go unseen."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 512))
-    return jax.tree.map(
-        lambda x: x + 0.05 * jax.random.normal(next(keys), x.shape, x.dtype),
-        params)
-
-
-def rel_l2(got, want):
-    return float(jnp.max(jnp.linalg.norm(got - want, axis=-1)
-                         / jnp.linalg.norm(want, axis=-1)))
+@functools.lru_cache(maxsize=None)
+def _serving(cfg, chunk):
+    """The prompts' chunks and the step, each ONE compiled program a
+    (configuration, shape) for every test that runs them: the parameters
+    are arguments, not constants of the program."""
+    return (jax.jit(lambda params, c, prompts, slots, lengths: whole_prompts(
+        qn.qwen3_next_prefill_chunk, params, c, prompts, slots, lengths,
+        cfg, chunk=chunk)),
+        jax.jit(lambda params, c, t, n: qn.qwen3_next_decode_step(
+            params, c, t, n, cfg)[:2]))
 
 
 def through_the_cache(cfg, params, tokens, lengths, steps, chunk=8,
-                      cache_len=64, window=48):
+                      cache_len=64, window=48, fresh=False):
     """The serving functions: the prompts (``tokens[r, :lengths[r]]``) in
     chunks through ``qwen3_next_prefill_chunk``, then ``steps`` decode steps
-    fed ``tokens``' continuation. -> logits [R, 1 + steps, V]."""
+    fed ``tokens``' continuation. -> logits [R, 1 + steps, V]. ``fresh``:
+    traced anew, for a control that has turned a function the programs
+    call."""
     r = tokens.shape[0]
     prompts = jnp.where(jnp.arange(window)[None] < lengths[:, None],
                         tokens[:, :window], 0)
     cache = qn.qwen3_next_init_cache(cfg, r + 1, cache_len)
-    logits, cache = jax.jit(lambda c: whole_prompts(
-        qn.qwen3_next_prefill_chunk, params, c, prompts, jnp.arange(r),
-        lengths, cfg, chunk=chunk))(cache)
+    prefill, step = (_serving.__wrapped__ if fresh else _serving)(cfg, chunk)
+    logits, cache = prefill(params, cache, prompts, jnp.arange(r), lengths)
     out, rows, free = [logits], jnp.arange(r), jnp.zeros(1, jnp.int32)
-    step = jax.jit(lambda c, t, n: qn.qwen3_next_decode_step(
-        params, c, t, n, cfg)[:2])
     for i in range(steps):
         logits, cache = step(
-            cache, jnp.concatenate([tokens[rows, lengths + i], free]),
+            params, cache, jnp.concatenate([tokens[rows, lengths + i], free]),
             jnp.concatenate([lengths + i, free]))
         out.append(logits[:r])
     return jnp.stack(out, axis=1)
 
 
 def reference_rows(params, cfg, tokens, lengths, steps):
-    full = jax.jit(lambda t: reference.forward(
-        to_ref(params, cfg), t, **ref_kwargs(cfg)))(tokens)
+    full = ROW.reference_forward(params, cfg)(tokens)
     rows = jnp.arange(tokens.shape[0])
     return jnp.stack([full[rows, lengths - 1 + i]
                       for i in range(steps + 1)], axis=1)
 
 
-@pytest.fixture(scope="module")
-def params():
-    return moved(qn.qwen3_next_init(jax.random.PRNGKey(0), CFG))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return jnp.asarray(np.random.default_rng(1).integers(
-        0, CFG.vocab_size, (3, 40), dtype=np.int32))
-
-
-@pytest.fixture(scope="module")
-def want(params, tokens):
-    # (jitted: op by op the reference costs several times as much, D19)
-    return jax.jit(lambda t: reference.forward(
-        to_ref(params), t, **ref_kwargs()))(tokens)
-
-
 # -- sizes and types ----------------------------------------------------------
-
-
-def test_the_published_sizes_and_the_tiny_preset():
-    cfg = qn.Qwen3NextConfig()
-    assert cfg.layer_types[:8] == ("linear_attention",) * 3 \
-        + ("full_attention",) + ("linear_attention",) * 3 \
-        + ("full_attention",)
-    assert (cfg.count("linear_attention"), cfg.count("full_attention")) \
-        == (36, 12)
-    assert (cfg.rotary_dim, cfg.head_dim, cfg.n_head, cfg.n_kv_head) \
-        == (64, 256, 16, 2)
-    held = family.system_config(CONFIG)
-    stats = held.serving_stats()
-    assert stats == {"expert_layers": 8, "experts_held": 128,
-                     "linear_layers": 6,
-                     "delta_state_bytes_per_slot": 6 * 2_146_304,
-                     "kv_bytes_per_token": 4096,
-                     "chunk_attention_arm": "xla"}  # no ring to read
-    # the engine's chunk and key window: heads of 256 over whole blocks
-    assert held.serving_stats(512, 16384)["chunk_attention_arm"] == "kernel"
-    assert qn.Qwen3NextConfig.tiny().serving_stats(512, 16384)[
-        "chunk_attention_arm"] == "xla"  # toy widths
-    tiny = qn.Qwen3NextConfig.tiny()
-    # value heads twice the key heads, dk != dv, grouped queries, a partial
-    # rotary, a strict part of the router's experts, two periods
-    assert tiny.linear_value_heads == 2 * tiny.linear_key_heads
-    assert tiny.linear_key_dim != tiny.linear_value_dim
-    assert tiny.n_kv_head < tiny.n_head
-    assert 0 < tiny.rotary_dim < tiny.head_dim
-    assert tiny.experts_held[1] < tiny.n_experts and tiny.experts_held[0] > 0
-    assert tiny.layer_types.count("full_attention") == 2
-    with pytest.raises(ValueError, match="experts_held"):
-        qn.Qwen3NextConfig.tiny(experts_held=(12, 8))
-    with pytest.raises(ValueError, match="pairs"):
-        qn.Qwen3NextConfig.tiny(partial_rotary_factor=0.45)
 
 
 def test_weights_are_bfloat16_and_drawn_by_the_gains():
@@ -199,77 +119,7 @@ def test_weights_are_bfloat16_and_drawn_by_the_gains():
         qn.Qwen3NextConfig.tiny(gains=(("embed", 1.0),))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_programs_hold_the_types_the_file_states(program):
-    """``computes_in`` of the benchmark's configuration file, held by the
-    programs' own types: weights and products in bfloat16 and nothing
-    narrower anywhere, float32 beside them, and a float32 delta state in
-    and out."""
-    stated = family.system_config(CONFIG)
-    assert CONFIG["assumed"]["delta_state_dtype"] == "float32"
-    assert "bfloat16 weights" in CONFIG["computes_in"]
-    assert (stated.param_dtype, stated.dtype, stated.delta_state_dtype) \
-        == (jnp.bfloat16, jnp.bfloat16, jnp.float32)
-    cfg = qn.Qwen3NextConfig.tiny()  # the same defaults, a CPU's size
-    assert (cfg.param_dtype, cfg.dtype, cfg.delta_state_dtype) \
-        == (stated.param_dtype, stated.dtype, stated.delta_state_dtype)
-    params = jax.eval_shape(
-        lambda: qn.qwen3_next_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: qn.qwen3_next_init_cache(cfg, 3, 16))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    if program == "decode":
-        fn = lambda p, c, t, n: qn.qwen3_next_decode_step(p, c, t, n, cfg)
-        args = (params, cache, i32(3), i32(3))
-    else:
-        fn = lambda p, c, t, s, n: qn.qwen3_next_prefill_chunk(
-            p, c, t, s, jnp.zeros_like(s), n, cfg)
-        args = (params, cache, i32(1, 16), i32(1), i32(1))
-    text = str(jax.make_jaxpr(fn)(*args))
-    types = set(re.findall(r"\b([a-z]+[0-9]+[a-z0-9_]*)\[", text))
-    assert {"bf16", "f32"} <= types
-    assert not {t for t in types if t.startswith(("f8", "f16", "i8", "u8",
-                                                  "i4", "u4"))}, types
-    logits, new_cache, *_ = jax.eval_shape(fn, *args)
-    assert logits.dtype == jnp.float32
-    assert [s.dtype for s in new_cache["delta"]] == [jnp.float32] * 6
-    assert [s.shape for s in new_cache["delta"]] == [(3, 4, 8, 12)] * 6
-    assert new_cache["k"].shape == (2, 3, 16, 32)  # merged rows, 2 x 16
-    assert new_cache["k"].dtype == new_cache["conv"].dtype == jnp.bfloat16
-    assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
-
-
-def test_the_programs_name_the_scopes_the_readers_read():
-    cfg = qn.Qwen3NextConfig.tiny()
-    params = jax.eval_shape(
-        lambda: qn.qwen3_next_init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: qn.qwen3_next_init_cache(cfg, 3, 16))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    step = jax.jit(lambda p, c, t, n: qn.qwen3_next_decode_step(
-        p, c, t, n, cfg)).lower(params, cache, i32(3), i32(3)).as_text(
-            debug_info=True)
-    chunk = jax.jit(lambda p, c, t, s, a, n: qn.qwen3_next_prefill_chunk(
-        p, c, t, s, a, n, cfg, window=8)).lower(
-            params, cache, i32(1, 4), i32(1), i32(1), i32(1)).as_text(
-                debug_info=True)
-    reader = load_module(os.path.join(
-        REPO, "benchmark", "metrics", "decode_linear_attention_time_pct.py"))
-    for scope in reader.LINEAR + reader.EXPERTS + reader.ATTENTION \
-            + ("embed", "ln", "head"):
-        if scope != "gdn_scan":
-            assert f"/{scope}/" in step, scope
-        if scope != "gdn_update":
-            assert f"/{scope}/" in chunk, scope
-
-
 # -- against the reference ----------------------------------------------------
-
-
-def test_forward_agrees_with_the_reference(params, tokens, want):
-    got = jax.jit(lambda p, t: qn.qwen3_next_forward(p, t, CFG))(
-        params, tokens)
-    assert got.shape == (3, 40, CFG.vocab_size) and got.dtype == F32
-    assert rel_l2(got, want) < 2e-4
-    assert float(jnp.std(want)) > 0.3  # logits worth comparing
 
 
 @pytest.mark.parametrize("chunk", [4, 8, 16])
@@ -492,7 +342,7 @@ def test_the_stated_limit_refuses_each_control(served, control, monkeypatch):
     if control in ("no_delta_term", "no_l2_on_q_and_k",
                    "no_shared_expert_gate", "norm_read_as_w"):
         want = reference_rows(params, cfg, tokens, lens, 6)
-    got = through_the_cache(cfg, params, tokens, lens, steps=6)
+    got = through_the_cache(cfg, params, tokens, lens, steps=6, fresh=True)
     assert rel_l2(got, want) > 2 * max(limit, TINY_SOUND), control
 
 
@@ -575,84 +425,3 @@ def test_the_router_is_softmax_over_all_then_the_top_renormalised():
         np.asarray(reference.gating(x, w, 10)[jnp.arange(33)[:, None], ids]),
         np.asarray(weights), rtol=1e-5)
 
-
-# -- the engine ---------------------------------------------------------------
-
-
-@pytest.fixture
-def runtime():
-    import ray_tpu
-    from ray_tpu import serve
-
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=4)
-    yield serve
-    try:
-        serve.shutdown()
-    except Exception:
-        pass
-    ray_tpu.shutdown()
-
-
-def test_the_engine_serves_the_references_greedy_tokens(runtime):
-    """``LLMEngine(model="qwen3_next")`` at the tiny preset's sizes through
-    ``serve.run`` / ``handle.stream`` in float32: token for token the
-    reference's greedy choice, two compiled programs, and what the model
-    says of its three kinds of state in ``llm_stats()``."""
-    import ray_tpu
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    dep = runtime.deployment(name="llm", max_concurrent_queries=16)(LLMEngine)
-    handle = runtime.run(dep.bind(
-        model="qwen3_next", config=CFG, seed=10, max_batch=3, cache_len=32,
-        max_prompt_len=16, prefill_rows=2, prefill_chunk=4))
-    params = qn.qwen3_next_init(jax.random.PRNGKey(10), CFG)
-    ref, kw = to_ref(params), ref_kwargs()
-    prompts = [[5, 9, 2, 17, 3], [11, 200, 4, 4, 8, 1, 99, 23, 54]]
-    forward = jax.jit(lambda t: reference.forward(ref, t, **kw))
-    for prompt in prompts:
-        toks = list(prompt)
-        for _ in range(6):  # causal: one padded shape serves every length
-            padded = jnp.asarray([toks + [0] * (16 - len(toks))])
-            toks.append(int(jnp.argmax(forward(padded)[0, len(toks) - 1])))
-        served = [t for chunk in handle.stream(prompt, 6) for t in chunk]
-        assert served == toks[len(prompt):]
-        assert len(set(served)) > 2  # no fixed point: it follows its context
-    stats = ray_tpu.get(handle.llm_stats.remote(), timeout=30)
-    assert stats["compiles"] == {"decode": 1, "prefill": 1}
-    assert stats["model"] == "qwen3_next"
-    assert stats["steps"] >= 10
-    # the chunks: 2 + 3 executions, 14 real tokens, their pairs counted
-    assert stats["prefill_chunks"] == 5
-    assert stats["prefill_tokens_real"] == 14
-    assert 0 < stats["prefill_expert_rows"] <= 14 * 3 * 8
-    assert 0 < stats["experts_hit"] <= stats["expert_rows"]
-    assert (stats["expert_layers"], stats["experts_held"],
-            stats["linear_layers"]) == (8, 8, 6)
-    assert stats["delta_state_bytes_per_slot"] == 6 * (
-        4 * 8 * 12 * 4 + 3 * (2 * 16 + 48) * 4)
-    assert stats["kv_bytes_per_token"] == 2 * 2 * 32 * 4
-    ray_tpu.get(handle.shutdown_engine.remote(), timeout=30)
-
-
-def test_the_tiny_preset_engine_and_the_bundles_error_text():
-    from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle
-
-    eng = LLMEngine(model="qwen3_next", preset="tiny", max_batch=2,
-                    cache_len=16, max_prompt_len=8)
-    try:
-        assert len(eng.generate([1, 2, 3], 4)) == 4
-        # the experts', and the rings' rows read and held (PR 48): a toy
-        # row keeps the XLA arm, which reads every row of the full layers'
-        assert eng._step_counters == ("expert_row_tiles", "expert_rows",
-                                      "experts_hit", "ring_rows_held",
-                                      "ring_rows_read")
-        stats = eng.llm_stats()
-        assert stats["ring_rows_read"] == stats["ring_rows_held"] \
-            == stats["steps"] * eng._cache["k"].shape[0] * 3 * 16
-    finally:
-        eng.shutdown_engine()
-    with pytest.raises(ValueError, match=r"gpt2\|llama\|nemotron_h\|"
-                       r"granite_hybrid\|deepseek_v2\|falcon_h1\|"
-                       r"qwen3_next"):
-        _model_bundle("mamba", None, "tiny")

@@ -10,7 +10,6 @@ here, not on the chip); a family that stores what it multiplies with gets
 its own arrays back; and ``llm_stats()`` says the bytes held by type.
 """
 
-import dataclasses
 import gc
 
 import jax
@@ -18,36 +17,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2, llama
-from ray_tpu.models import nemotron_h as nh
-from ray_tpu.models import exaone_moe, keye_vl2, smallthinker
 from ray_tpu.serve.llm_engine import LLMEngine, _model_bundle, _stored_params
+from served_families import FAMILIES, PROMPT
 
-# Sizes no other test uses, so that `jax.live_arrays()` can be asked for a
-# float32 array of a weight's shape (the workers run many files a process).
-# Not a sequence of 40: Qwen3-Next's tiny preset has a float32 [40, 48]
-# shared expert, which `tests/test_qwen3_next.py` keeps alive in its worker.
-CONFIGS = {
-    "gpt2": gpt2.GPT2Config(vocab_size=136, n_layer=3, n_head=3, d_model=48,
-                            seq_len=44),
-    "llama": dataclasses.replace(llama.LlamaConfig.tiny(), vocab_size=136,
-                                 n_layer=3),
-    "nemotron_h": nh.NemotronHConfig.tiny(),
-    "smallthinker": smallthinker.SmallThinkerConfig.tiny(),
-    "exaone_moe": exaone_moe.ExaoneMoeConfig.tiny(),
-    "keye_vl2": keye_vl2.KeyeVL2Config.tiny(),
-}
+# The configurations are the table's ``Family.serving``: the tiny presets
+# (bfloat16 programs), but GPT-2's and Llama's at sizes no other test uses,
+# so that `jax.live_arrays()` can be asked for a float32 array of a weight's
+# shape (the workers run many files a process). Not a sequence of 40:
+# Qwen3-Next's tiny preset has a float32 [40, 48] shared expert, which
+# `tests/test_qwen3_next.py` keeps alive in its worker.
 NORMS = {"gpt2": {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
                   "lnf_scale", "lnf_bias"},
          "llama": {"attn_norm", "mlp_norm", "final_norm"}}
 MAX_BATCH, CACHE_LEN, PROMPT_LEN, ROWS = 2, 32, 8, 2
-PROMPT = [5, 9, 2, 17, 3]
 
 
 def _family(name):
     """(cfg, init, init_cache, prefill_chunk, decode), as the engine gets
     them."""
-    return _model_bundle(name, CONFIGS[name], "tiny")
+    return _model_bundle(name, FAMILIES[name].serving, "tiny")
 
 
 def _stored(params, cfg):

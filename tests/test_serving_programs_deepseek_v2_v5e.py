@@ -25,28 +25,23 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
 from ray_tpu.models import deepseek_v2 as ds
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 RING = r"bf16\[5,65,16896,1,576\]"
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments", "dsv2_1chip_b64.json"))["engine"]
+    return FAMILIES["deepseek_v2"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "deepseek_v2.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "deepseek-v2.json")))
+    return FAMILIES["deepseek_v2"].cell()[0]
 
 
 @pytest.fixture(scope="module")

@@ -29,12 +29,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import arrays_made, nbytes, unfused
 from ray_tpu.models import exaone_moe as ex
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 FULL = "bf16[2,65,8192,1024]"
 WIN = "bf16[4,65,128,1024]"
@@ -42,16 +42,12 @@ WIN = "bf16[4,65,128,1024]"
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments", "kexaone_1chip_b64.json"))["engine"]
+    return FAMILIES["exaone_moe"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "exaone_moe.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "k-exaone-236b-a23b.json")))
+    return FAMILIES["exaone_moe"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -126,13 +122,6 @@ def compiled(one_chip, cfg, engine):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 def test_the_chunks_global_layers_attend_through_the_kernel(
         compiled, chunk_attends_through_the_kernel):
     """PR 64: the global layer's attention and the prediction module's in
@@ -170,31 +159,8 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.temp_size_in_bytes < {"decode": 0.25e9, "prefill": 0.4e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
 RING = nbytes((65, 8192, 1024), 1)     # elements of a full ring
 STACK = nbytes((2, 65, 8192, 1024), 1)
-
-
-def _unfused(hlo_text):
-    """The text of every computation but the ones a ``fusion`` calls:
-    inside a fusion a slice or a convert is a step of one loop, not a
-    buffer."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    return "\n".join(block for block in hlo_text.split("\n\n")
-                     if block.lstrip().split(" ", 1)[0] not in fused)
-
-
-def _arrays_made(hlo_text):
-    """(type, elements, opcode) of every instruction of ``hlo_text`` that
-    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
-    slices."""
-    for line in hlo_text.splitlines():
-        m = SHAPE.match(line)
-        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
-                                "dynamic-slice"):
-            yield m.group(1), nbytes(
-                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -204,10 +170,10 @@ def test_no_float32_array_as_long_as_a_ring_and_no_ring_is_copied(compiled,
     widens one to float32, and neither makes a copy of a ring or of the
     stack in any type."""
     text = compiled[which].as_text()
-    made = list(_arrays_made(_unfused(text)))
+    made = list(arrays_made(unfused(text)))
     assert len(made) > 50, "read no program"
     assert [m for m in made if m[0] == "f32" and m[1] >= RING] == []
-    assert [m for m in _arrays_made(text)
+    assert [m for m in arrays_made(text)
             if m[1] in (RING, STACK) and m[2] == "copy"] == []
 
 

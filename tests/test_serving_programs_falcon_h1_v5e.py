@@ -27,28 +27,23 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import arrays_made, made_as_large_as, nbytes, unfused
 from ray_tpu.models import falcon_h1 as fh
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments",
-        "falconh1_1chip_b32.json"))["engine"]
+    return FAMILIES["falcon_h1"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "falcon_h1.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "falcon-h1-34b-instruct.json")))
+    return FAMILIES["falcon_h1"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -113,13 +108,6 @@ def compiled(one_chip, cfg, engine):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 def test_the_chunks_layers_attend_through_the_kernel(
         compiled, chunk_attends_through_the_kernel):
     """PR 64: every one of the nine layers' attention in the chunk program
@@ -158,31 +146,8 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 0.75e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
 RING = nbytes((33, 5120, 512), 1)        # elements of a layer's K or V ring
 STATE = nbytes((33, 32, 128, 256), 1)    # elements of a layer's SSM state
-
-
-def _unfused(hlo_text):
-    """The text of every computation but the ones a ``fusion`` calls:
-    inside a fusion a slice or a convert is a step of one loop, not a
-    buffer."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    return "\n".join(block for block in hlo_text.split("\n\n")
-                     if block.lstrip().split(" ", 1)[0] not in fused)
-
-
-def _arrays_made(hlo_text):
-    """(type, elements, opcode) of every instruction of ``hlo_text`` that
-    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
-    slices."""
-    for line in hlo_text.splitlines():
-        m = SHAPE.match(line)
-        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
-                                "dynamic-slice"):
-            yield m.group(1), nbytes(
-                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -196,18 +161,16 @@ def test_no_float32_array_as_long_as_a_ring_and_no_state_is_copied(compiled,
     the step made eighteen, each layer's K and V window with rows and heads
     swapped, until the rings held merged rows (PR 44)."""
     text = compiled[which].as_text()
-    made = list(_arrays_made(_unfused(text)))
+    made = list(arrays_made(unfused(text)))
     assert len(made) > 50, "read no program"
     assert [m for m in made if m[0] == "f32" and m[1] >= RING] == []
     assert [m for m in made if m[1] in (STATE, 9 * STATE)] == []
     # (inside fusions too: a fusion whose root is a copy writes it out)
-    assert [m for m in _arrays_made(text)
+    assert [m for m in arrays_made(text)
             if m[1] == RING and m[2] == "copy"] == []
 
 
-RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
 STACK = 9 * RING                         # elements of the whole K or V stack
-HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
 
 
 def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
@@ -220,13 +183,8 @@ def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
     counted each). While the write stood inside the loop, two fusions each
     gave a whole stack out anew, 14 of a chunk's 26.5 ms (PERF.md section
     6, PR 43)."""
-    made = []
-    for line in compiled["prefill"].as_text().splitlines():
-        m = RESULT.match(line)
-        if m and m.group(2) not in HANDED_ON and any(
-                nbytes([int(d) for d in dims.split(",")], 1) >= STACK
-                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
-            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    made = made_as_large_as(compiled["prefill"].as_text(),
+                            lambda n: n >= STACK)
     assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 2, made
     assert len({stack for _, stack in made}) == 2, made
 
@@ -251,13 +209,8 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
         operands = re.findall(r"(\w+\[[\d,]*\])", re.search(
             r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
         assert operands.count("bf16[9,33,5120,512]") == 2, operands
-    made = []
-    for line in text.splitlines():
-        m = RESULT.match(line)
-        if m and m.group(2) not in HANDED_ON and any(
-                nbytes([int(d) for d in dims.split(",")], 1) in (RING, STACK)
-                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
-            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
+    made = made_as_large_as(text,
+                            lambda n: n in (RING, STACK))
     assert {op for op, _ in made} == {"dynamic-update-slice"}, made
     assert len(made) % 2 == 0 and len(made) >= 2
 

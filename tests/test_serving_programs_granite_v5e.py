@@ -22,28 +22,23 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import HLO_SHAPE, PASSES_ON, nbytes, unfused_lines
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments",
-        "granite4hs_1chip_b32.json"))["engine"]
+    return FAMILIES["granite_hybrid"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "granite_hybrid.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "granite-4.0-h-small.json")))
+    return FAMILIES["granite_hybrid"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -109,13 +104,6 @@ def compiled(one_chip, cfg, engine):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
@@ -138,23 +126,6 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                      "prefill": 0.75e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
-PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple",
-             "fusion", "dynamic-update-slice", "custom-call", "while",
-             "conditional", "call", "opt-barrier")
-
-
-def _unfused_lines(hlo_text):
-    """The instructions that make an array of their own: those of every
-    computation but the ones a ``fusion`` calls (inside a fusion a slice or
-    a convert is a step of one loop, not a buffer)."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    for block in hlo_text.split("\n\n"):
-        if block.lstrip().split(" ", 1)[0] not in fused:
-            yield from block.splitlines()[1:]
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_no_expert_stack_and_no_whole_state_is_copied(compiled, which):
     """A layer's expert stacks are 36 x 4096 x 1536 and 36 x 768 x 4096
@@ -167,8 +138,8 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, which):
     theirs = {nbytes((36, 4096, 1536), 1), nbytes((36, 768, 4096), 1),
               layer_state, 9 * layer_state, nbytes((33, 8448, 8, 128), 1)}
     moved, lines = [], 0
-    for line in _unfused_lines(compiled[which].as_text()):
-        m = SHAPE.match(line)
+    for line in unfused_lines(compiled[which].as_text()):
+        m = HLO_SHAPE.match(line)
         if not m or m.group(1) not in {"bf16", "f32"}:
             continue
         lines += 1

@@ -32,12 +32,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import arrays_made, made_as_large_as, nbytes, unfused
 from ray_tpu.models import keye_vl2 as kv
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 KV = "bf16[8,17,33792,1024]"
 IDX = "bf16[8,17,33792,64]"
@@ -45,17 +45,12 @@ IDX = "bf16[8,17,33792,64]"
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments",
-        "keyevl2_1chip_b16.json"))["engine"]
+    return FAMILIES["keye_vl2"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "keye_vl2.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "keye-vl-2.0-30b-a3b.json")))
+    return FAMILIES["keye_vl2"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -120,13 +115,6 @@ def compiled(one_chip, cfg, engine):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
@@ -149,32 +137,9 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.temp_size_in_bytes < {"decode": 0.5e9, "prefill": 0.4e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
 RINGS = {nbytes((17, 33792, 1024), 1)}
 STACKS = {nbytes((8, 17, 33792, 1024), 1), nbytes((8, 17, 33792, 64), 1)}
 IDX_RING = nbytes((17, 33792, 64), 1)
-
-
-def _unfused(hlo_text):
-    """The text of every computation but the ones a ``fusion`` calls:
-    inside a fusion a slice or a convert is a step of one loop, not a
-    buffer."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    return "\n".join(block for block in hlo_text.split("\n\n")
-                     if block.lstrip().split(" ", 1)[0] not in fused)
-
-
-def _arrays_made(hlo_text):
-    """(type, elements, opcode) of every instruction of ``hlo_text`` that
-    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
-    slices."""
-    for line in hlo_text.splitlines():
-        m = SHAPE.match(line)
-        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
-                                "dynamic-slice"):
-            yield m.group(1), nbytes(
-                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -183,30 +148,12 @@ def test_no_ring_is_copied_or_widened(compiled, which):
     Neither program widens them to float32, and neither makes a copy of
     them or of a stack in any type."""
     text = compiled[which].as_text()
-    made = list(_arrays_made(_unfused(text)))
+    made = list(arrays_made(unfused(text)))
     assert len(made) > 50, "read no program"
     assert [m for m in made if m[0] == "f32"
             and m[1] >= nbytes((17, 33792, 512), 1)] == []
-    assert [m for m in _arrays_made(text)
+    assert [m for m in arrays_made(text)
             if m[1] in RINGS | STACKS and m[2] == "copy"] == []
-
-
-RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
-HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
-
-
-def _made_as_large_as(text, sizes):
-    """(opcode, first operand) of every instruction that gives out an array
-    of one of ``sizes`` elements (a tuple's members counted each) and does
-    not merely hand one on."""
-    made = []
-    for line in text.splitlines():
-        m = RESULT.match(line)
-        if m and m.group(2) not in HANDED_ON and any(
-                nbytes([int(d) for d in dims.split(",")], 1) in sizes
-                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
-            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
-    return made
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -222,11 +169,11 @@ def test_each_stack_is_written_once_and_nothing_else_is_as_large(compiled,
     indexer keys out of their stack for its scores, 73 MB a layer: PERF.md
     section 7 has it among what is left on the table.)"""
     text = compiled[which].as_text()
-    made = _made_as_large_as(text, RINGS | STACKS)
+    made = made_as_large_as(text, lambda n: n in RINGS | STACKS)
     assert {op for op, _ in made} == {"dynamic-update-slice"}, made
     writes = 17 if which == "decode" else 1
     assert len(made) == 2 * writes, made
-    cut = [op for op, _ in _made_as_large_as(text, {IDX_RING})]
+    cut = [op for op, _ in made_as_large_as(text, lambda n: n == IDX_RING)]
     assert len(cut) <= (16 if which == "decode" else 0), cut
 
 

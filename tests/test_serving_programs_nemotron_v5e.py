@@ -22,22 +22,19 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import HLO_SHAPE, PASSES_ON, nbytes, unfused_lines
 from ray_tpu.models import nemotron_h as nh
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SLOTS, CACHE_LEN, PROMPT_LEN = 65, 2048, 1024
 HBM = 15.75 * 2 ** 30
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "nemotron_h.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "nemotron3-super-120b-a12b.json")))
+    return FAMILIES["nemotron_h"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -98,13 +95,6 @@ def compiled(one_chip, cfg):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
@@ -123,23 +113,6 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.temp_size_in_bytes < {"decode": 0.3e9, "prefill": 0.3e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
-PASSES_ON = ("get-tuple-element", "parameter", "bitcast", "tuple",
-             "fusion", "dynamic-update-slice", "custom-call", "while",
-             "conditional", "call", "opt-barrier")
-
-
-def _unfused_lines(hlo_text):
-    """The instructions that make an array of their own: those of every
-    computation but the ones a ``fusion`` calls (inside a fusion a slice or
-    a convert is a step of one loop, not a buffer)."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    for block in hlo_text.split("\n\n"):
-        if block.lstrip().split(" ", 1)[0] not in fused:
-            yield from block.splitlines()[1:]
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
     """A layer's expert stack is 128 x 1024 x 2688 bfloat16 (705 MB a
@@ -153,8 +126,8 @@ def test_no_expert_stack_and_no_whole_state_is_copied(compiled, cfg, which):
     theirs = {stack, layer_state, 5 * layer_state}
     sizes = {"bf16", "f32"}
     moved, lines = [], 0
-    for line in _unfused_lines(compiled[which].as_text()):
-        m = SHAPE.match(line)
+    for line in unfused_lines(compiled[which].as_text()):
+        m = HLO_SHAPE.match(line)
         if not m or m.group(1) not in sizes:
             continue
         lines += 1

@@ -29,12 +29,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark.loading import load_json, load_module
+from conftest import arrays_made, made_as_large_as, nbytes, unfused
 from ray_tpu.models import smallthinker as st
 from ray_tpu.models.prefill import (chunk_len, key_window,
                                     token_parameters)
+from served_families import FAMILIES
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30
 FULL = "bf16[2,49,16384,512]"
 WIN = "bf16[6,49,4096,512]"
@@ -42,17 +42,12 @@ WIN = "bf16[6,49,4096,512]"
 
 @pytest.fixture(scope="module")
 def engine():
-    return load_json(os.path.join(
-        REPO, "benchmark", "deployments",
-        "smallthinker_1chip_b48.json"))["engine"]
+    return FAMILIES["smallthinker"].cell()[1]
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    family = load_module(os.path.join(REPO, "benchmark", "families",
-                                      "smallthinker.py"))
-    return family.system_config(load_json(os.path.join(
-        REPO, "benchmark", "configs", "smallthinker-21b-a3b-instruct.json")))
+    return FAMILIES["smallthinker"].cell()[0]
 
 
 @pytest.fixture(scope="module")
@@ -117,13 +112,6 @@ def compiled(one_chip, cfg, engine):
         cc.reset_cache()
 
 
-def nbytes(shape, itemsize):
-    n = itemsize
-    for d in shape:
-        n *= d
-    return n
-
-
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
                                                         which):
@@ -149,32 +137,9 @@ def test_the_program_fits_the_chip_beside_its_arguments(compiled, cfg,
     assert mem.temp_size_in_bytes < {"decode": 0.2e9, "prefill": 0.4e9}[which]
 
 
-SHAPE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
-                   r"([\w\-]+)\(")
 RINGS = {nbytes((49, 16384, 512), 1),    # elements of a global layer's ring
          nbytes((49, 4096, 512), 1)}     # and of a window layer's
 STACKS = {nbytes((2, 49, 16384, 512), 1), nbytes((6, 49, 4096, 512), 1)}
-
-
-def _unfused(hlo_text):
-    """The text of every computation but the ones a ``fusion`` calls:
-    inside a fusion a slice or a convert is a step of one loop, not a
-    buffer."""
-    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo_text))
-    return "\n".join(block for block in hlo_text.split("\n\n")
-                     if block.lstrip().split(" ", 1)[0] not in fused)
-
-
-def _arrays_made(hlo_text):
-    """(type, elements, opcode) of every instruction of ``hlo_text`` that
-    makes an array by moving one: ``copy``, ``transpose``, ``convert`` and
-    slices."""
-    for line in hlo_text.splitlines():
-        m = SHAPE.match(line)
-        if m and m.group(3) in ("copy", "transpose", "convert", "slice",
-                                "dynamic-slice"):
-            yield m.group(1), nbytes(
-                [int(d) for d in m.group(2).split(",")], 1), m.group(3)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -184,29 +149,11 @@ def test_no_float32_array_as_long_as_a_ring_and_no_ring_is_copied(compiled,
     layer's four times that. Neither program widens a ring to float32, and
     neither makes a copy of a ring or of a stack in any type."""
     text = compiled[which].as_text()
-    made = list(_arrays_made(_unfused(text)))
+    made = list(arrays_made(unfused(text)))
     assert len(made) > 50, "read no program"
     assert [m for m in made if m[0] == "f32" and m[1] >= min(RINGS)] == []
-    assert [m for m in _arrays_made(text)
+    assert [m for m in arrays_made(text)
             if m[1] in RINGS | STACKS and m[2] == "copy"] == []
-
-
-RESULT = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
-HANDED_ON = ("parameter", "get-tuple-element", "tuple", "bitcast")
-
-
-def _made_as_large_as(text, sizes):
-    """(opcode, first operand) of every instruction that gives out an array
-    of one of ``sizes`` elements (a tuple's members counted each) and does
-    not merely hand one on."""
-    made = []
-    for line in text.splitlines():
-        m = RESULT.match(line)
-        if m and m.group(2) not in HANDED_ON and any(
-                nbytes([int(d) for d in dims.split(",")], 1) in sizes
-                for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
-            made.append((m.group(2), re.findall(r"\(%([\w.\-]+)", line)[0]))
-    return made
 
 
 def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
@@ -219,7 +166,8 @@ def test_the_chunk_writes_each_stack_once_and_makes_no_other_as_large(
     the ring's own where they are not: a read of 256 rows a layer, no more):
     no fusion, copy or anything else, inside a fusion or outside, gives out
     an array as large as a stack or a ring."""
-    made = _made_as_large_as(compiled["prefill"].as_text(), RINGS | STACKS)
+    made = made_as_large_as(compiled["prefill"].as_text(),
+                            lambda n: n in RINGS | STACKS)
     assert sorted(op for op, _ in made) == ["dynamic-update-slice"] * 4, made
     assert len({stack for _, stack in made}) == 4, made
 
@@ -257,7 +205,7 @@ def test_the_step_reads_its_rings_through_the_kernel_and_copies_none(
             r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
         handed.append((operands.count(FULL), operands.count(WIN)))
     assert sorted(handed) == [(0, 2)] * 6 + [(2, 0)] * 2, handed
-    made = _made_as_large_as(text, RINGS | STACKS)
+    made = made_as_large_as(text, lambda n: n in RINGS | STACKS)
     assert {op for op, _ in made} == {"dynamic-update-slice"}, made
     assert len(made) % 4 == 0 and len(made) >= 4
 
